@@ -63,11 +63,12 @@ _SPINE_MODULE_SUFFIX = os.sep.join(("engines", "spine.py"))
 # jax namespace calls that BUILD programs/wrappers without enqueueing
 # device work — owning one of these is not owning a stream.
 # TraceAnnotation is the profiler scope metrics.span opens (host-only);
-# jnp.dtype is a dtype constructor.
+# jnp.dtype is a dtype constructor; NamedSharding / PartitionSpec
+# describe a placement, they place nothing.
 _JAX_WRAPPER_TAILS = frozenset(
     {
         "jit", "ShapeDtypeStruct", "eval_shape", "shard_map", "tree_map",
-        "TraceAnnotation", "dtype",
+        "TraceAnnotation", "dtype", "NamedSharding", "PartitionSpec",
     }
 )
 

@@ -2,7 +2,7 @@
 
 ``jax.jit(..., donate_argnums=...)`` hands the argument's device buffer to
 XLA for in-place reuse — the continuous batcher's KV cache and the vector
-store's append buffers depend on it (docs/PERF.md).  After the call the
+store's append buffers depend on it.  After the call the
 donated array is *deleted*: any later read raises
 ``RuntimeError: Array has been deleted`` — but only on real backends under
 real donation (CPU tests often keep the buffer alive), so the bug class

@@ -2,7 +2,7 @@
 static arguments.
 
 A serving path owes every jit root a WARM, REUSED compilation cache
-(docs/PERF.md: one trace+compile costs seconds on a real chip; a retrace
+(one trace+compile costs seconds on a real chip, PERF.md; a retrace
 inside a request is a latency cliff the admission deadline then reads as
 an outage).  The compile audit (``analysis/compile_audit.py``) proves the
 steady state retrace-free; this rule catches the construction patterns

@@ -173,18 +173,18 @@ class GenerateEngine:
                     host_init=True,
                     bits=cfg.quant_bits,
                     host_seed=seed,
+                    mesh=mesh,
                 )
             else:
-                # host_init + host_seed: draw on host + device_put per
-                # tensor — the transfer path real checkpoints take, with
-                # the seed passed so init needs no key_data fetch (see
-                # init_decoder_params)
+                # host_init: draw on host + device_put per tensor, each
+                # straight into its mesh sharding (decoder.param_putter)
                 params = init_decoder_params(
                     jax.random.PRNGKey(seed),
                     cfg,
                     param_dtype=param_dtype or jnp.dtype(cfg.dtype),
                     host_init=True,
                     host_seed=seed,
+                    mesh=mesh,
                 )
         else:
             from docqa_tpu.models.quant import (
@@ -271,7 +271,7 @@ class GenerateEngine:
             cache,
             jnp.zeros((b,), jnp.int32),
             attn_lengths=prompt_lengths,
-            use_flash=self.use_flash,
+            use_flash=self.use_flash, mesh=self.mesh,
             last_token_only=True,
         )
         last = logits[:, -1]
@@ -298,7 +298,7 @@ class GenerateEngine:
                 tok[:, None],
                 cache,
                 lengths,
-                use_flash=self.use_flash,
+                use_flash=self.use_flash, mesh=self.mesh,
             )
             rng, sub = jax.random.split(rng)
             nxt = sample(
@@ -351,6 +351,7 @@ class GenerateEngine:
         logits, cache = decoder_forward(
             params, self.cfg, verify_in, cache, lengths,
             attn_lengths=lengths + K, use_flash=self.use_flash,
+            mesh=self.mesh,
         )
         g, m, cand, is_eos, eos_pos = accept_drafts(
             logits, drafts, self.gen.eos_id
@@ -402,7 +403,7 @@ class GenerateEngine:
         logits, cache = decoder_forward(
             params, self.cfg, ids, cache, jnp.zeros((b,), jnp.int32),
             attn_lengths=prompt_lengths, use_flash=self.use_flash,
-            last_token_only=True,
+            mesh=self.mesh, last_token_only=True,
         )
         first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         table = self._build_bigram(ids, prompt_lengths)
@@ -566,6 +567,62 @@ class GenerateEngine:
             # quietly reintroduce the unmeasured-HBM state
             log.exception("decode AOT memory analysis failed")
             return None
+
+    def kernel_selfcheck(self) -> dict:
+        """The Pallas flash kernel against ``attention_reference`` ON THE
+        ATTACHED DEVICE, at the decode shapes the batcher dispatches
+        (q_len 1 and the speculative verify width) with this model's
+        head geometry, window and mesh — a seeded small input, lengths
+        and offsets included.  Raises when they disagree: a kernel that
+        compiles but computes something else must fail the warm-up, not
+        serve.  Tolerance: both sides round an O(1) output to bf16
+        (ulp 2^-7 below 2.0), so two roundings apart is the most an
+        agreeing pair can differ."""
+        from docqa_tpu.ops.attention import (
+            attention_reference,
+            flash_attention,
+        )
+
+        cfg, tol = self.cfg, 2.0 ** -6
+        b = self.mesh.n_data if self.mesh is not None else 1
+        skv, dtype = 384, jnp.dtype(cfg.dtype)
+        kw = dict(causal=True, sliding_window=cfg.sliding_window)
+        kernel = jax.jit(
+            functools.partial(flash_attention, mesh=self.mesh, **kw)
+        )
+        q_lens = sorted({1, max(self.gen.speculative_k, 1)})
+
+        def _check_on_lane() -> float:
+            rng = np.random.default_rng(0)
+            worst = 0.0
+            for sq in q_lens:
+                q, k, v = (
+                    jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
+                    for shape in (
+                        (b, sq, cfg.num_heads, cfg.head_dim),
+                        (b, skv, cfg.num_kv_heads, cfg.head_dim),
+                        (b, skv, cfg.num_kv_heads, cfg.head_dim),
+                    )
+                )
+                lengths = jnp.full((b,), skv - 83, jnp.int32)
+                got = kernel(q, k, v, lengths=lengths, q_offset=lengths - sq)
+                with jax.default_matmul_precision("highest"):
+                    want = attention_reference(
+                        q, k, v, lengths=lengths, q_offset=lengths - sq, **kw
+                    )
+                err = jnp.max(jnp.abs(
+                    got.astype(jnp.float32) - want.astype(jnp.float32)
+                ))
+                worst = max(worst, float(err))
+            return worst
+
+        err = spine_run("kernel_check", _check_on_lane, stream="probe")
+        report = {"max_abs_err": err, "tolerance": tol, "q_lens": q_lens}
+        if not err <= tol:  # NaN fails too
+            raise AssertionError(
+                f"flash kernel disagrees with reference: {report}"
+            )
+        return report
 
     # ---- host API ------------------------------------------------------------
 

@@ -585,17 +585,22 @@ def share_alignment(block_size: int) -> int:
 def init_paged_pools(
     cfg: DecoderConfig, n_blocks: int, block_size: int,
     dtype: Optional["jnp.dtype"] = None,
+    sharding=None,
 ) -> PagedPools:
     """Flat per-layer K/V block pools: [n_blocks * block_size, kv_heads,
     head_dim].  Row ``b * block_size + o`` is offset ``o`` of block ``b``
     — the one flat axis both the prefill scatter and the decode gather
-    index, so a block id IS a row range."""
+    index, so a block id IS a row range.
+
+    ``sharding`` (``parallel.sharding.paged_pool_sharding`` on a mesh):
+    each pool is CREATED under it — every device zero-fills only its own
+    kv-head slice, nothing pool-sized is staged on one device first."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     shape = (n_blocks * block_size, cfg.num_kv_heads, cfg.head_dim)
     pools: PagedPools = {}
     for i in range(cfg.num_layers):
-        pools[f"k{i}"] = jnp.zeros(shape, dtype)
-        pools[f"v{i}"] = jnp.zeros(shape, dtype)
+        pools[f"k{i}"] = jnp.zeros(shape, dtype, device=sharding)
+        pools[f"v{i}"] = jnp.zeros(shape, dtype, device=sharding)
     return pools
 
 
@@ -691,6 +696,7 @@ def paged_decode_forward(
     block_size: int,
     rope_len: int,
     use_flash: bool = False,
+    mesh=None,  # MeshContext: the flash kernel shards over it (ops/attention)
 ):
     """Advance every lane ``s`` tokens against the block pool: write each
     new token's K/V at its table-mapped row, attend through the table.
@@ -729,6 +735,7 @@ def paged_decode_forward(
             q, pools[f"k{i}"], pools[f"v{i}"], block_tables, attn_lengths,
             block_size=block_size, q_offset=lengths,
             sliding_window=cfg.sliding_window, use_flash=use_flash,
+            mesh=mesh,
         )
 
     x = decoder_layer_stack(params, cfg, tok, rope_pos, rope_len, attend)

@@ -809,6 +809,12 @@ class EnginePool:
         replica 0's programs is the cost model of every replica's."""
         return self._replicas[0].batcher.annotate_costs()
 
+    @property
+    def decode_kernel_calls(self) -> Optional[int]:
+        """Mosaic custom calls in the shared decode program (batcher
+        passthrough; None before :meth:`annotate_costs`)."""
+        return self._replicas[0].batcher.decode_kernel_calls
+
     # ---- failover ------------------------------------------------------------
 
     def _on_worker_death(self, idx: int, batcher: ContinuousBatcher, queued):

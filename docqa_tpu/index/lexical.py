@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from docqa_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from docqa_tpu.engines.spine import spine_run

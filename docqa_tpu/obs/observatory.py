@@ -14,11 +14,11 @@ next to *measured device time* and
 
     MFU = flops / device_seconds / peak_flops
 
-is an attribution, not a wall-clock guess.  ``peak_flops`` is resolved
-from the real backend when one is attached; CPU smoke runs report
-against the projected v5e peak with ``peak_flops_source:
-"projected-v5e"`` — the same honesty labeling bench already uses for
-HBM (a CPU MFU is a *ratio shape*, not a chip claim).
+is an attribution, not a wall-clock guess.  Peaks come from ONE table
+keyed by jax's ``device_kind`` (:data:`DEVICE_PEAKS`, shared with
+``bench.py``); a device that is not in the table has no peak, so every
+``mfu`` / ``roofline_bound`` stays null there — a CPU run never reports
+utilization against somebody else's chip.
 
 Stdlib-only like the rest of ``docqa_tpu/obs`` (jax is only touched
 lazily inside ``annotate_lowered``/``detect_peak_flops``), so the spine
@@ -27,63 +27,57 @@ and telemetry can import it without dragging a backend in.
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Any, Dict, Optional
 
-# bf16 peak of the chip the projected numbers target (v5e: 197 TFLOP/s,
-# 819 GB/s HBM) — the ridge point flops/bytes = peak_flops/peak_bw
-# classifies a program compute- vs memory-bound on the roofline
-_V5E_PEAK_FLOPS = 197e12
-_V5E_PEAK_BYTES_S = 819e9
-
-_PEAK_BY_BACKEND = {
-    # conservative, dense-bf16 numbers; override via DOCQA_PEAK_FLOPS
-    "tpu": (_V5E_PEAK_FLOPS, "tpu-v5e-bf16"),
-    "gpu": (_V5E_PEAK_FLOPS, "projected-v5e"),
-    "cpu": (_V5E_PEAK_FLOPS, "projected-v5e"),
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# The ridge point flops/bytes = flops_bf16 / hbm_bytes_s classifies a
+# program compute- vs memory-bound on the roofline.
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "ops_int8": 393e12,
+        "hbm_bytes_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
 }
 
 
-def detect_peak_flops() -> Dict[str, Any]:
-    """(peak_flops, peak_bytes_s, source) for MFU math.  Env override
-    ``DOCQA_PEAK_FLOPS`` (absolute FLOP/s) wins; otherwise the attached
-    jax backend picks the row — never raises (obs must not)."""
-    env = os.environ.get("DOCQA_PEAK_FLOPS")
-    if env:
-        try:
-            return {
-                "peak_flops": float(env),
-                "peak_bytes_s": _V5E_PEAK_BYTES_S,
-                "peak_flops_source": "env:DOCQA_PEAK_FLOPS",
-            }
-        except ValueError:
-            pass
-    backend = "cpu"
+def device_peaks(device_kind: Optional[str]) -> Optional[Dict[str, Any]]:
+    """The peaks row for ``device_kind``, or None for a device the table
+    does not know (callers report no utilization then, never a default)."""
+    row = DEVICE_PEAKS.get(device_kind or "")
+    return dict(row, device_kind=device_kind) if row else None
+
+
+def detect_peak_flops() -> Optional[Dict[str, Any]]:
+    """``{peak_flops, peak_bytes_s, peak_flops_source, device_kind}`` of
+    the attached device, or None when it is not in :data:`DEVICE_PEAKS`
+    (or no backend answers) — never raises (obs must not)."""
     try:
         import jax
 
-        backend = jax.default_backend()
+        row = device_peaks(jax.devices()[0].device_kind)
     except Exception:
-        pass
-    peak, source = _PEAK_BY_BACKEND.get(backend, _PEAK_BY_BACKEND["cpu"])
+        return None
+    if row is None:
+        return None
     return {
-        "peak_flops": peak,
-        "peak_bytes_s": _V5E_PEAK_BYTES_S,
-        "peak_flops_source": source,
+        "device_kind": row["device_kind"],
+        "peak_flops": row["flops_bf16"],
+        "peak_bytes_s": row["hbm_bytes_s"],
+        "peak_flops_source": row["source"],
     }
 
 
 def parse_cost_analysis(lowered) -> Optional[Dict[str, float]]:
     """``{"flops", "bytes_accessed"}`` from a jax ``Lowered``/``Compiled``
     object's ``cost_analysis()``, or None when the backend offers no
-    usable estimate.  The ONE parser (jax returns a bare dict on newer
-    versions and a one-element list on older ones) — the compile audit
-    and the observatory must never drift on this shape."""
+    usable estimate.  The ONE parser — the compile audit and the
+    observatory must never drift on this shape."""
     try:
         ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
         if not ca:
             return None
         flops = float(ca.get("flops", 0.0) or 0.0)
@@ -176,11 +170,14 @@ class Observatory:
 
     def stats(self, peak: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         """Per-stage MFU / roofline table.  Stages with no registered
-        cost report device time only (``mfu: None``) — visible gaps
-        beat silently-wrong utilization."""
+        cost — and every stage on a device with no peaks row (``peak``
+        is then null) — report device time only (``mfu: None``): visible
+        gaps beat silently-wrong utilization."""
         peak = peak or detect_peak_flops()
-        peak_flops = peak["peak_flops"]
-        ridge = peak_flops / max(peak["peak_bytes_s"], 1.0)
+        peak_flops = peak["peak_flops"] if peak else None
+        ridge = (
+            peak_flops / max(peak["peak_bytes_s"], 1.0) if peak else None
+        )
         with self._lock:
             rows = {k: dict(v) for k, v in self._stages.items()}
         out: Dict[str, Any] = {"peak": peak, "stages": {}}
@@ -197,24 +194,25 @@ class Observatory:
                 "intensity_flops_per_byte": None,
                 "roofline_bound": None,
             }
-            if flops > 0.0 and dev > 0.0:
+            if flops > 0.0 and row["bytes"] > 0.0:
+                entry["intensity_flops_per_byte"] = round(
+                    flops / row["bytes"], 3
+                )
+            if peak and flops > 0.0 and dev > 0.0:
                 mfu = flops / dev / peak_flops
                 if mfu > 1.0:
                     # physically impossible: the stage's measured device
-                    # time under-covers the program's execution (e.g. a
-                    # synchronous-dispatch CPU backend runs the compute
-                    # inside the DISPATCH call, leaving the fetch ~0).
-                    # Report the raw ratio for debugging, never claim it
-                    # as utilization.
-                    entry["mfu"] = None
+                    # time under-covers the program's execution.  Report
+                    # the raw ratio for debugging, never claim it as
+                    # utilization.
                     entry["mfu_raw_invalid"] = round(mfu, 6)
                 else:
                     entry["mfu"] = round(mfu, 6)
                 if row["bytes"] > 0.0:
-                    intensity = flops / row["bytes"]
-                    entry["intensity_flops_per_byte"] = round(intensity, 3)
                     entry["roofline_bound"] = (
-                        "compute" if intensity >= ridge else "memory"
+                        "compute"
+                        if flops / row["bytes"] >= ridge
+                        else "memory"
                     )
             out["stages"][stage] = entry
         return out

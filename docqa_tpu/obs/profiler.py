@@ -29,7 +29,7 @@ from docqa_tpu.obs.export import coverage
 from docqa_tpu.obs.spans import Trace, percentile_nearest_rank
 
 # Stage → device/host classification along the one-fetch-per-dispatch
-# boundary (docs/PERF.md §1): a "device" span's wall time is dominated by
+# boundary: a "device" span's wall time is dominated by
 # blocking on the dispatch's single device→host fetch (i.e. device
 # execution); a "host" span is pure host work or waiting on host events.
 # Add new stages here when instrumenting a new engine path — the

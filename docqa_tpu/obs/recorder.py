@@ -3,8 +3,8 @@
 A ring buffer of the last N completed traces plus an always-keep ring of
 *anomalous* ones — deadline sheds, degraded answers, breaker-open
 requests, decode failures, and the slowest percentile by wall time.  The
-point is post-hoc diagnosis: when ``rag_load`` sustains 1 qps against a
-16 qps target (BENCH_r05), the recorder holds complete per-request
+point is post-hoc diagnosis: when a load run sustains a fraction of its
+target rate, the recorder holds complete per-request
 timelines that say which of queue-wait / admit / prefill / decode-chunk
 / result-wait ate the time — dumpable via ``/api/traces`` and
 ``scripts/trace_dump.py`` without having had profiling enabled ahead of

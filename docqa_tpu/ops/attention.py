@@ -286,7 +286,7 @@ def gather_paged_kv(pool, block_tables, block_size):
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            block_size, q_offset=None, sliding_window=None,
-                           scale=None, use_flash=False):
+                           scale=None, use_flash=False, mesh=None):
     """Decode-side attention through a block table (the decode half of
     Ragged Paged Attention).  XLA reference path: gather the pages into a
     per-sequence contiguous view, then run the standard masked kernel —
@@ -301,7 +301,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     """
     k = gather_paged_kv(k_pool, block_tables, block_size)
     v = gather_paged_kv(v_pool, block_tables, block_size)
-    attn_fn = flash_attention if use_flash else attention_reference
+    attn_fn = (
+        functools.partial(flash_attention, mesh=mesh)
+        if use_flash
+        else attention_reference
+    )
     return attn_fn(
         q, k, v, causal=True, lengths=lengths, q_offset=q_offset,
         sliding_window=sliding_window, scale=scale,
@@ -414,12 +418,19 @@ def flash_attention(
     block_q: int = 128,
     block_kv: int = 512,
     interpret: bool = False,
+    mesh=None,
 ):
     """Blockwise flash attention as a Pallas TPU kernel.
 
     Grid: (batch*q_heads, q_blocks, kv_blocks) — the kv axis is innermost so
     the online-softmax scratch carries across kv steps on one core.  GQA is
     handled by indexing the kv head as ``q_head // group``.
+
+    ``mesh`` (a ``MeshContext`` with more than one device): GSPMD cannot
+    partition a Mosaic custom call, so the kernel runs under ``shard_map``
+    — batch over the data axis, heads over the model axis (heads are
+    independent, and contiguous head shards keep every q head next to its
+    kv head), which is the layout the decoder's caches already have.
     """
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -427,8 +438,36 @@ def flash_attention(
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if sliding_window is not None and not causal:
         raise ValueError("sliding_window requires causal=True (bidirectional local attention is not implemented)")
-    groups = hq // hkv
     scale = scale if scale is not None else d ** -0.5
+    if lengths is None:
+        lengths = jnp.full((b,), skv, jnp.int32)
+    if q_offset is None:
+        q_offset = lengths - sq if causal else jnp.zeros((b,), jnp.int32)
+    if mesh is not None and mesh.n_devices > 1:
+        if hkv % mesh.n_model or b % mesh.n_data:
+            raise ValueError(
+                f"flash_attention on a {mesh.n_data}x{mesh.n_model} mesh "
+                f"needs kv heads ({hkv}) divisible by the model axis and "
+                f"batch ({b}) by the data axis"
+            )
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        local = functools.partial(
+            flash_attention, causal=causal, sliding_window=sliding_window,
+            scale=scale, block_q=block_q, block_kv=block_kv,
+            interpret=interpret,
+        )
+        heads = P(mesh.data_axis, None, mesh.model_axis, None)
+        lanes = P(mesh.data_axis)
+        return shard_map(
+            lambda q, k, v, n, off: local(q, k, v, lengths=n, q_offset=off),
+            mesh=mesh.mesh,
+            in_specs=(heads, heads, heads, lanes, lanes),
+            out_specs=heads,
+            check_vma=False,
+        )(q, k, v, lengths, q_offset)
+    groups = hq // hkv
 
     block_q = min(block_q, sq)
     block_kv = min(block_kv, skv)
@@ -442,11 +481,6 @@ def flash_attention(
         k = jnp.pad(k, ((0, 0), (0, pkv), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pkv), (0, 0), (0, 0)))
     sq_p, skv_p = sq + pq, skv + pkv
-
-    if lengths is None:
-        lengths = jnp.full((b,), skv, jnp.int32)
-    if q_offset is None:
-        q_offset = lengths - sq if causal else jnp.zeros((b,), jnp.int32)
 
     # [b, s, h, d] -> [b*h, s, d]
     qr = q.transpose(0, 2, 1, 3).reshape(b * hq, sq_p, d)
@@ -503,7 +537,7 @@ def flash_attention(
 # Dispatcher
 # --------------------------------------------------------------------------
 
-_FLASH_ONLY_KWARGS = ("block_q", "block_kv", "interpret")
+_FLASH_ONLY_KWARGS = ("block_q", "block_kv", "interpret", "mesh")
 
 
 def attention(q, k, v, **kwargs):

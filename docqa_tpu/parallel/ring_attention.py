@@ -34,7 +34,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from docqa_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from docqa_tpu.runtime.mesh import MeshContext

@@ -64,41 +64,49 @@ def cache_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     return out
 
 
-def shard_decoder_params(params, cfg: DecoderConfig, mesh: MeshContext):
+def decoder_param_sharding(
+    name: str, shape, cfg: DecoderConfig, mesh: MeshContext
+) -> NamedSharding:
+    """The target sharding of one decoder tensor (weights, quantization
+    scales, int4 grouped stores) — the ONE placement rule, used both to
+    re-shard an existing tree and to create tensors under their final
+    sharding at init (so nothing full-size ever lands on one device)."""
     from docqa_tpu.models.quant import SCALE_SUFFIX
 
     specs = decoder_param_pspecs(cfg, mesh.model_axis)
-
-    def spec_for(name, v):
-        if name.endswith(SCALE_SUFFIX):
-            # scales mirror their weight's sharding (models/quant.py):
-            # int8 scale [out] → P(out_spec); int4 grouped scale
-            # [groups, out] → the weight's own spec, because groups ride
-            # the in axis (sharded for row-parallel wo/w_down, replicated
-            # for column-parallel).  When a group spans shards (groups
-            # not divisible — tiny configs), replicate the groups axis:
-            # GSPMD broadcasts it into the dequant either way.
-            base = specs[name[: -len(SCALE_SUFFIX)]]
-            if v.ndim == 1:
-                return P(base[1])
+    if name.endswith(SCALE_SUFFIX):
+        # scales mirror their weight's sharding (models/quant.py):
+        # int8 scale [out] → P(out_spec); int4 grouped scale
+        # [groups, out] → the weight's own spec, because groups ride
+        # the in axis (sharded for row-parallel wo/w_down, replicated
+        # for column-parallel).  When a group spans shards (groups
+        # not divisible — tiny configs), replicate the groups axis:
+        # GSPMD broadcasts it into the dequant either way.
+        base = specs[name[: -len(SCALE_SUFFIX)]]
+        if len(shape) == 1:
+            spec = P(base[1])
+        else:
             d0 = base[0]
-            if d0 is not None and v.shape[0] % mesh.mesh.shape[d0]:
+            if d0 is not None and shape[0] % mesh.mesh.shape[d0]:
                 d0 = None
-            return P(d0, base[1])
+            spec = P(d0, base[1])
+    else:
         spec = specs[name]
-        if v.ndim == 3 and len(spec) == 2:
+        if len(shape) == 3 and len(spec) == 2:
             # int4 grouped 3-D store [groups, g, out] for a 2-D weight
             # spec [in, out]: the in-axis sharding moves to the groups
             # axis (whole groups per shard keeps scale rows local); the
             # in-group axis is never sharded
             d0 = spec[0]
-            if d0 is not None and v.shape[0] % mesh.mesh.shape[d0]:
+            if d0 is not None and shape[0] % mesh.mesh.shape[d0]:
                 d0 = None  # a group would span shards: replicate instead
-            return P(d0, None, spec[1])
-        return spec
+            spec = P(d0, None, spec[1])
+    return NamedSharding(mesh.mesh, spec)
 
+
+def shard_decoder_params(params, cfg: DecoderConfig, mesh: MeshContext):
     return {
-        k: jax.device_put(v, NamedSharding(mesh.mesh, spec_for(k, v)))
+        k: jax.device_put(v, decoder_param_sharding(k, v.shape, cfg, mesh))
         for k, v in params.items()
     }
 
@@ -121,7 +129,7 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     gather ride the unsharded row axis and insert no collective (the
     shard audit's decoder_paged_decode program holds that to the same
     one-all-reduce-per-Megatron-block budget as the dense programs)."""
-    spec = P(None, mesh.model_axis, None)
+    spec = paged_pool_sharding(mesh).spec
     out: Dict[str, P] = {}
     for i in range(cfg.num_layers):
         out[f"k{i}"] = spec
@@ -129,9 +137,7 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     return out
 
 
-def shard_paged_pools(pools, cfg: DecoderConfig, mesh: MeshContext):
-    specs = paged_pool_pspecs(cfg, mesh)
-    return {
-        k: jax.device_put(v, NamedSharding(mesh.mesh, specs[k]))
-        for k, v in pools.items()
-    }
+def paged_pool_sharding(mesh: MeshContext) -> NamedSharding:
+    """One pool's sharding (every layer's K and V pool shares it) — what
+    ``engines/paged.init_paged_pools`` allocates under on a mesh."""
+    return NamedSharding(mesh.mesh, P(None, mesh.model_axis, None))

@@ -2,10 +2,9 @@
 
 The reference system has no fault handling at all — services die on a
 missed HTTP call and poison messages are silently dropped (PAPER.md
-"What the reference is NOT").  BENCH_r05 showed the cost of the happy
-path alone: the open-loop QPS-16 run collapsed to ~1 sustained QPS with
-7.9 s p95 because requests queued with no deadline, no shedding, and no
-fallback.  This package supplies the four primitives every stage of the
+"What the reference is NOT").  The happy path alone has a cost too: an
+open-loop run above capacity collapses when requests queue with no
+deadline, no shedding, and no fallback.  This package supplies the four primitives every stage of the
 pipeline leans on:
 
 * :mod:`deadline` — an end-to-end request budget created at admission

@@ -6,8 +6,8 @@ A :class:`Deadline` is created once, at request admission (``service/app.py``
 ``engines/serve.py``.  Each stage calls :meth:`Deadline.check` (or inspects
 :meth:`Deadline.remaining`) *before* doing work, so a request that can no
 longer finish in time is shed at the first opportunity instead of queueing —
-the BENCH_r05 failure mode was exactly requests piling up 7.9 s past any
-useful completion time.
+the failure mode it prevents is requests piling up seconds past any useful
+completion time.
 
 Shedding raises :class:`DeadlineExceeded`, a ``TimeoutError`` subclass, so
 callers that already handle timeouts keep working, while the HTTP layer can

@@ -27,7 +27,6 @@ log = get_logger("docqa.native")
 _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
-_LIB_PATH = os.path.join(_REPO_ROOT, "native", "libdocqa_native.so")
 
 _DTYPE_F32, _DTYPE_BF16 = 0, 1
 _ERRORS = {
@@ -159,29 +158,32 @@ class _NativeLib:
 
 
 def load(build_if_missing: bool = True) -> Optional[_NativeLib]:
-    """Load (building on demand) the native library; None if unavailable."""
+    """Load (building on demand) the native library; None if unavailable.
+
+    The library's file name is keyed on the source and compile flags
+    (``native/build.py``), so only a build of THIS checkout's source is
+    ever loaded — a stray ``.so`` from another tree or another machine's
+    ``-march`` is not the file looked for."""
     global _cached, _load_failed
     with _lock:
         if _cached is not None:
             return _cached
         if _load_failed:
             return None
-        path = _LIB_PATH
-        if not os.path.exists(path) and build_if_missing:
-            try:
-                import importlib.util
+        try:
+            import importlib.util
 
-                spec = importlib.util.spec_from_file_location(
-                    "docqa_native_build",
-                    os.path.join(_REPO_ROOT, "native", "build.py"),
-                )
-                mod = importlib.util.module_from_spec(spec)
-                spec.loader.exec_module(mod)
-                path = mod.build()
-            except Exception:
-                log.exception("native build failed; using NumPy fallback")
-                _load_failed = True
-                return None
+            spec = importlib.util.spec_from_file_location(
+                "docqa_native_build",
+                os.path.join(_REPO_ROOT, "native", "build.py"),
+            )
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            path = mod.build() if build_if_missing else mod.lib_path()
+        except Exception:
+            log.exception("native build failed; using NumPy fallback")
+            _load_failed = True
+            return None
         if not os.path.exists(path):
             _load_failed = True
             return None

@@ -92,6 +92,20 @@ class DocQARuntime:
         from docqa_tpu.runtime.mesh import make_mesh, multihost_init
 
         self.cfg = cfg or load_config()
+        # set-up split served on /api/status ("boot"): seconds each
+        # construction phase took, so a cold start can be read apart
+        self.boot_s: dict = {}
+        t_boot = time.perf_counter()
+        from docqa_tpu.runtime.compile_cache import compile_cache_dir
+
+        self._compile_cache_dir = compile_cache_dir()
+        self._compile_cache_entries_at_boot = self._compile_cache_entries()
+
+        def _phase(name: str, t0: float) -> float:
+            now = time.perf_counter()
+            self.boot_s[name] = round(now - t0, 3)
+            return now
+
         # failure-path plumbing first: every dependency below is wrapped
         # by a breaker from this board (docs/RESILIENCE.md), and a
         # DOCQA_FAULTS env plan makes chaos drills run against the real
@@ -127,6 +141,7 @@ class DocQARuntime:
             strict_sync=self.cfg.dispatch.strict_sync,
         )
         self.mesh = make_mesh(self.cfg.mesh) if jax.device_count() > 1 else None
+        t_phase = time.perf_counter()
 
         if self.cfg.flags.use_fake_encoder:
             if self.cfg.encoder.checkpoint_dir:
@@ -163,6 +178,7 @@ class DocQARuntime:
         else:
             self.encoder = EncoderEngine(self.cfg.encoder, mesh=self.mesh)
 
+        t_phase = _phase("encoder_init", t_phase)
         # ---- store: restore-from-snapshot on boot (parity with the
         # reference's reload, indexer.py:97-101 — minus its unlocked-file
         # races).  A corrupt/mismatched snapshot logs and serves fresh, the
@@ -230,15 +246,16 @@ class DocQARuntime:
         else:
             self.search_index = self.store
 
+        t_phase = _phase("index_init", t_phase)
         if self.cfg.ner.train_steps > 0 or self.cfg.ner.params_path:
-            # default cache keeps restarts load-instead-of-retrain; the npz
-            # fingerprint invalidates it on any architecture change
+            # the cache rides the persistence root so restarts load
+            # instead of retrain (the npz fingerprint invalidates it on
+            # any architecture change); without a work_dir nothing is
+            # cached — the tagger trains at every boot
             params_path = self.cfg.ner.params_path or (
                 os.path.join(self.cfg.data.work_dir, "ner.npz")
                 if self.cfg.data.work_dir
-                else os.path.join(
-                    os.path.expanduser("~"), ".cache", "docqa_tpu", "ner.npz"
-                )
+                else None
             )
             self.deid = DeidEngine.trained(
                 self.cfg.ner,
@@ -248,6 +265,7 @@ class DocQARuntime:
             )
         else:  # plumbing mode (tests): random-init tagger
             self.deid = DeidEngine(self.cfg.ner)
+        t_phase = _phase("ner_load_or_train", t_phase)
         if self.cfg.decoder.checkpoint_dir and self.cfg.flags.use_fake_llm:
             # the fake path never decodes — don't pay a multi-GB weight
             # load for a generator nothing will invoke, but say so
@@ -294,6 +312,7 @@ class DocQARuntime:
             self.generator = GenerateEngine(
                 self.cfg.decoder, gen=self.cfg.generate, mesh=self.mesh
             )
+        t_phase = _phase("decoder_weight_init", t_phase)
         # Decode-engine POOL: the single submit surface for ALL generation
         # (BASELINE config 5, QPS 16 — and ROADMAP item 5's scale-out
         # spine).  The pool owns N ContinuousBatcher replicas with a
@@ -311,6 +330,7 @@ class DocQARuntime:
             self.batcher = EnginePool(
                 self.generator, cfg=self.cfg.pool, qos=self.cfg.qos
             )
+        t_phase = _phase("kv_pool_alloc", t_phase)
         summarizer_cfg = self.cfg.summarizer
         instruction_prompts = True
         if (
@@ -446,6 +466,7 @@ class DocQARuntime:
             except Exception:
                 log.exception("registry/index reconciliation failed")
 
+        t_phase = time.perf_counter()
         # ---- first-boot knowledge base (parity: indexer.py:102-107 indexed
         # default_data/*.csv into an otherwise-empty index)
         if self.cfg.data.bootstrap_dir and self.store.count == 0:
@@ -463,6 +484,7 @@ class DocQARuntime:
             )
             if n and self._index_dir:
                 self._snapshot()
+        _phase("bootstrap_index", t_phase)
         # Fused encode+search retrieval (one dispatch) applies when serving
         # exact search over the plain store with a real device encoder;
         # the hash-encoder fake keeps the generic two-step path; real
@@ -647,6 +669,55 @@ class DocQARuntime:
                 # ride the registry scrape like every other counter
                 extra_probes=(self.costs.telemetry_gauges,),
             )
+        _phase("total", t_boot)
+        # decode warm-up outcome, served on /api/status ("warmup"):
+        # pending → running → ok | failed (+ the error) | skipped
+        self.warmup_status: dict = {
+            "state": "skipped" if self.batcher is None else "pending"
+        }
+
+    def _compile_cache_entries(self) -> int:
+        try:
+            return len(os.listdir(self._compile_cache_dir))
+        except OSError:
+            return 0
+
+    def device_status(self) -> dict:
+        """What this process runs on, as JAX reports it — the ONE place
+        a client that must stay off JAX (chip_smoke.py) learns the
+        platform, the mesh and each device's memory in use."""
+        import jax
+
+        devices = jax.devices()
+        memory = []
+        for d in devices:
+            stats = d.memory_stats() or {}
+            memory.append(
+                {
+                    "id": d.id,
+                    "bytes_in_use": stats.get("bytes_in_use"),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                    "bytes_limit": stats.get("bytes_limit"),
+                }
+            )
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+            "mesh": (
+                {"data": self.mesh.n_data, "model": self.mesh.n_model}
+                if self.mesh is not None
+                else None
+            ),
+            "memory": memory,
+            # devices the vector index's rows are sharded over
+            "index_devices": self.store.n_devices,
+            "compile_cache": {
+                "dir": self._compile_cache_dir,
+                "entries_at_boot": self._compile_cache_entries_at_boot,
+                "entries": self._compile_cache_entries(),
+            },
+        }
 
     def _cost_pressure(self):
         """Shed-forensics pressure snapshot (obs/costs.py): per-class
@@ -699,6 +770,9 @@ class DocQARuntime:
         return self
 
     def _warmup_decode(self) -> None:
+        t0 = time.perf_counter()
+        status = self.warmup_status
+        status["state"] = "running"
         try:
             # compile the ragged-prefill token budgets plus the decode
             # chunk for the configured warm depth
@@ -720,6 +794,12 @@ class DocQARuntime:
             self.batcher.submit_ids(
                 [1, 2, 3], max_new_tokens=2, req_class="background"
             ).result(timeout=600)
+            # the /ask retrieval program (encode + top-k in one
+            # dispatch) at the question shape: it compiles on first use
+            # like the decode programs, and a first ask must not spend
+            # its deadline inside the compiler either
+            if self.store.count:
+                self.qa._retrieve("warm-up", k=self.qa.k)
             # register the warmed programs' cost_analysis() FLOPs with
             # the observatory (background probe items): /api/status and
             # bench then report per-stage MFU instead of wall guesses
@@ -727,12 +807,29 @@ class DocQARuntime:
                 self.batcher, "annotate_costs"
             ):
                 self.batcher.annotate_costs()
+            # which attention ran: Mosaic custom calls in the lowered
+            # decode program (0 = the XLA reference path), and — when
+            # the kernel is on — its agreement with that reference on
+            # THIS device at the decode shapes
+            status["decode_kernel_calls"] = getattr(
+                self.batcher, "decode_kernel_calls", None
+            )
+            if self.generator.use_flash:
+                status["kernel_check"] = self.generator.kernel_selfcheck()
+            status["state"] = "ok"
             log.info(
                 "decode programs warm (ragged token budgets, "
                 "warm depth %s)", depth,
             )
-        except Exception:
+        except Exception as e:
+            # serving continues cold (request-time degradation is
+            # product behaviour) but the failure is on /api/status for
+            # every operator and smoke test to see
+            status["state"] = "failed"
+            status["error"] = repr(e)[:500]
             log.exception("decode warmup failed (serving continues cold)")
+        finally:
+            status["seconds"] = round(time.perf_counter() - t0, 3)
 
     # ---- persistence hooks ---------------------------------------------------
 
@@ -922,6 +1019,14 @@ def make_app(rt: DocQARuntime):
             {
                 "service": "docqa-tpu",
                 "status": "running",
+                # platform / device_kind / count / mesh / per-device
+                # memory in use / compile-cache entries, from JAX itself
+                "device": rt.device_status(),
+                # set-up split (seconds per construction phase) and the
+                # decode warm-up's outcome: "failed" here means requests
+                # compile on the request path and will degrade
+                "boot": rt.boot_s,
+                "warmup": rt.warmup_status,
                 "indexed_vectors": rt.store.count,
                 "index_version": rt.store.version,
                 "queue_depths": {q: rt.broker.depth(q) for q in queues},
@@ -1684,6 +1789,9 @@ def make_app(rt: DocQARuntime):
 def serve(cfg: Optional[Config] = None, port: Optional[int] = None) -> None:
     from aiohttp import web
 
+    from docqa_tpu.runtime.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     rt = DocQARuntime(cfg).start()
     app = make_app(rt)
     try:

@@ -322,7 +322,7 @@ class TestJitPurity:
             "jit-purity",
             {
                 "mod.py": """
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 def build(mesh, lock):
                     def body(v):

@@ -396,7 +396,7 @@ class TestRetraceHazard:
             "retrace-hazard",
             {
                 "mod.py": """
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 def kernel(body, mesh, x):
                     return shard_map(body, mesh=mesh)(x)

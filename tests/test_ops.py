@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from docqa_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from docqa_tpu.ops import (
@@ -143,20 +143,60 @@ class TestAttention:
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
-    def test_flash_decode_step(self):
-        # q_len=1 against a long KV prefix — the generate() hot shape
+    @pytest.mark.parametrize("sq", [1, 4])
+    def test_flash_decode_shapes(self, sq):
+        # the batcher's decode shapes: q_len 1 (plain step) and 4 (the
+        # speculative verify) against a long KV prefix, with per-lane
+        # lengths, the q rows' absolute offsets, and a sliding window —
+        # block_q collapses to sq, the sub-tile case Mosaic must accept
         rng = np.random.default_rng(5)
-        b, skv, h, d = 2, 256, 4, 64
-        q = jnp.array(rng.normal(size=(b, 1, h, d)), jnp.float32)
-        k = jnp.array(rng.normal(size=(b, skv, h, d)), jnp.float32)
-        v = jnp.array(rng.normal(size=(b, skv, h, d)), jnp.float32)
+        b, skv, h, hkv, d = 2, 256, 4, 2, 64
+        q = jnp.array(rng.normal(size=(b, sq, h, d)), jnp.float32)
+        k = jnp.array(rng.normal(size=(b, skv, hkv, d)), jnp.float32)
+        v = jnp.array(rng.normal(size=(b, skv, hkv, d)), jnp.float32)
         lengths = jnp.array([100, 37], jnp.int32)
-        want = attention_reference(q, k, v, causal=True, lengths=lengths)
+        kw = dict(
+            causal=True, lengths=lengths, q_offset=lengths - sq,
+            sliding_window=48,
+        )
+        want = attention_reference(q, k, v, **kw)
         got = flash_attention(
-            q, k, v, causal=True, lengths=lengths,
-            block_q=128, block_kv=128, interpret=True,
+            q, k, v, block_kv=128, interpret=True, **kw
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+    def test_flash_on_a_mesh_lowers_for_tpu(self):
+        """GSPMD cannot partition a Mosaic call: on a mesh the kernel must
+        be shard_map-wrapped or the decode program does not lower at all.
+        Cross-lowered for TPU on the virtual 1x4 mesh (no chip needed)."""
+        from jax import export
+        from jax.sharding import NamedSharding
+
+        from docqa_tpu.runtime.mesh import host_cpu_mesh
+
+        mesh = host_cpu_mesh(4)
+        b, sq, skv, h, hkv, d = 2, 4, 512, 8, 4, 128
+        heads = NamedSharding(mesh.mesh, P("data", None, "model", None))
+        lanes = NamedSharding(mesh.mesh, P("data"))
+        args = (
+            jax.ShapeDtypeStruct((b, sq, h, d), jnp.bfloat16, sharding=heads),
+            jax.ShapeDtypeStruct((b, skv, hkv, d), jnp.bfloat16, sharding=heads),
+            jax.ShapeDtypeStruct((b, skv, hkv, d), jnp.bfloat16, sharding=heads),
+            jax.ShapeDtypeStruct((b,), jnp.int32, sharding=lanes),
+        )
+
+        def attend(use_mesh):
+            return jax.jit(
+                lambda q, k, v, n: flash_attention(
+                    q, k, v, causal=True, lengths=n, q_offset=n - sq,
+                    sliding_window=256, mesh=use_mesh,
+                )
+            )
+
+        exported = export.export(attend(mesh), platforms=["tpu"])(*args)
+        assert "tpu_custom_call" in exported.mlir_module()
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            export.export(attend(None), platforms=["tpu"])(*args)
 
     def test_sliding_window(self):
         rng = np.random.default_rng(6)

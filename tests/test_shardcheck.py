@@ -136,7 +136,7 @@ class TestMeshAxes:
                 "mod.py": _MESH_DECL + """
                 import jax
                 from jax.sharding import PartitionSpec as P
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 def build(mesh):
                     def body(v):
@@ -163,7 +163,7 @@ class TestMeshAxes:
                 "mod.py": _MESH_DECL + """
                 import jax
                 from jax.sharding import PartitionSpec as P
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 def build(mesh):
                     def body_a(v):
@@ -199,7 +199,7 @@ class TestMeshAxes:
                 import functools
                 import jax
                 from jax.sharding import PartitionSpec as P
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 def helper(v, axis_name):
                     n = jax.lax.psum(1, axis_name)

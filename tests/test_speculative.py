@@ -93,7 +93,7 @@ class TestExactness:
 
     def test_default_promotion_gated_by_token_equality(self):
         """speculative_k=4 is the SHIPPED default (promoted from a bench
-        knob per ROADMAP item 3 after BENCH_r04 measured 17.3->18.3 QPS)
+        knob)
         — this is its quality gate: the default config's output must
         equal speculative_k=0 token for token, both solo and through
         the continuous batcher."""

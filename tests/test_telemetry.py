@@ -517,10 +517,10 @@ class TestPerfGate:
     def test_degraded_run_skips_with_reason(self):
         import perf_gate
 
-        result = {"degraded": True, "degraded_reason": "tunnel down"}
+        result = {"degraded": True, "degraded_reason": "no accelerator"}
         report = perf_gate.gate(result, self._baseline())
         assert report["status"] == "skipped"
-        assert "tunnel down" in report["reason"]
+        assert "no accelerator" in report["reason"]
         assert "DEGRADED" in report["reason"]
 
     def test_missing_metric_fails(self):
