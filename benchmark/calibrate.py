@@ -1,0 +1,133 @@
+#!/usr/bin/env python3
+"""Read the two numbers every limit of ``correct`` is set from, on the
+chip, at the configuration's own size, many seeds in one process:
+
+* the largest error sound runs of the program give, and
+* the smallest error the lower-precision controls give (the plain
+  reference put in the program's place, one step below each thing the
+  configuration states — ``weights.controls_for``: int4 weights below
+  int8, int8 and float8 activations with the cache below bfloat16; int8
+  rows below bfloat16 rows for the store), and beside them what the
+  cache alone in 8 bits reads (``weights.kv_only_controls``).
+
+    python3 benchmark/calibrate.py --config benchmark/configs/<name>.json \
+        --seeds 1,2,3 [--retrieval-seeds 1,2,3]
+
+No server, no traffic, no timed window: weights from the seed, the
+program's paged forwards, the reference, the control.  The benchmark's own
+runs never call this; ``tests/benchmark`` holds the same comparison at a
+size a test run can hold.  PERF.md records what this printed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+import types
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH_DIR)
+for p in (ROOT, BENCH_DIR):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--overlay", default="")
+    ap.add_argument("--seeds", default="")
+    ap.add_argument("--retrieval-seeds", default="")
+    args = ap.parse_args()
+
+    import jax
+    import numpy as np
+
+    from docqa_tpu.config import load_config
+    from docqa_tpu.runtime.compile_cache import configure_compile_cache
+    from harness import check, weights
+    from harness.child import load_cell_config, program_overrides
+
+    configure_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    conf = load_cell_config(args.config, args.overlay)
+    cfg = load_config(env={}, overrides=program_overrides(conf))
+    devices = jax.devices()
+    print(f"device: {devices[0].platform} {devices[0].device_kind} x{len(devices)}")
+    mesh = None
+    if len(devices) > 1:
+        from docqa_tpu.runtime.mesh import make_mesh
+
+        mesh = make_mesh(cfg.mesh)
+    gen = cfg.generate
+    block = int(gen.kv_block_size)
+    capacity = -(-cfg.decoder.max_seq_len // block) * block
+    n_blocks = max(int(gen.kv_pool_tokens) // block,
+                   gen.max_concurrent * capacity // block)
+    use_flash = (
+        jax.default_backend() == "tpu" and cfg.decoder.head_dim % 64 == 0
+    )
+    out = {"decoder": [], "retrieval": []}
+    for seed in [int(s) for s in args.seeds.split(",") if s]:
+        t = time.monotonic()
+        params = weights.make_decoder_params(cfg.decoder, seed % (2**31), mesh)
+        engine = types.SimpleNamespace(
+            cfg=cfg.decoder, params=params, use_flash=use_flash
+        )
+        row = check.decoder_check(
+            engine, seed, n_blocks=n_blocks, block_size=block,
+            seq_capacity=capacity, n_lanes=int(gen.max_concurrent),
+            step_width=max(1, int(gen.speculative_k)), mesh=mesh, control=True,
+        )
+        row.update(seed=seed, seconds=round(time.monotonic() - t, 1))
+        out["decoder"].append(row)
+        print("decoder", json.dumps(row), flush=True)
+        del params, engine
+    for seed in [int(s) for s in args.retrieval_seeds.split(",") if s]:
+        from docqa_tpu.index.store import VectorStore
+
+        rng = np.random.default_rng([seed % (2**31), 3])
+        n, dim = int(conf["corpus"]["rows"]), cfg.store.dim
+        rows = rng.standard_normal((n, dim), dtype=np.float32)
+        store = VectorStore(cfg.store, mesh=mesh)
+        store.add(rows, [{"doc_id": "fill"}] * n)
+        stored = check.to_bf16(check.unit_rows(rows))
+        del rows
+        q = check.retrieval_queries(stored, seed)
+        k = int(cfg.store.default_k)
+        row = {
+            "seed": seed,
+            "program": check.retrieval_error(
+                stored, q, k, check.store_search(store)
+            ),
+            "control": check.retrieval_error(
+                stored, q, k, check.control_search(stored)
+            ),
+        }
+        out["retrieval"].append(row)
+        print("retrieval", json.dumps(row), flush=True)
+        del store, stored
+    if out["decoder"]:
+        rows = out["decoder"]
+        print("decoder: largest program error",
+              max(r["program"]["worst_row"] for r in rows),
+              "smallest control error",
+              min(r["control"]["worst_row"] for r in rows),
+              "kv pool bits", sorted({r["kv_bits"] for r in rows}))
+        for group in ("controls", "kv_only"):
+            for name in rows[0][group]:
+                values = [r[group][name]["worst_row"] for r in rows]
+                print(f"  {group} {name}: {min(values):.6g} .. {max(values):.6g}")
+    if out["retrieval"]:
+        print("retrieval: largest program error",
+              max(r["program"] for r in out["retrieval"]),
+              "smallest control error",
+              min(r["control"] for r in out["retrieval"]))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,168 @@
+"""The plain reference against the program at a tiny preset on the CPU,
+with the lower-precision control failing the same comparison."""
+
+import dataclasses
+import os
+import sys
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)), "benchmark"))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from docqa_tpu.config import DecoderConfig  # noqa: E402
+from harness import check, reference, weights  # noqa: E402
+
+TINY = DecoderConfig(
+    vocab_size=512, hidden_dim=64, num_layers=2, num_heads=4, num_kv_heads=2,
+    head_dim=16, mlp_dim=128, max_seq_len=1024, rope_theta=10000.0,
+    sliding_window=1024, dtype="bfloat16",
+)
+# the limit of this file: above the largest program error over the seeds
+# below (0.0231), below the smallest control error (0.0348: int8 weights
+# under bfloat16; int8 activations 0.036, float8 0.12, int4 weights 0.51)
+LIMIT = 0.03
+SEEDS = [1, 2, 3, 2**31 + 4]
+
+
+def engine_for(cfg, seed):
+    import types
+
+    params = weights.make_decoder_params(cfg, seed % (2**31))
+    return types.SimpleNamespace(cfg=cfg, params=params, use_flash=False)
+
+
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "bf16"])
+def test_reference_agrees_with_decoder_forward_in_float32(quantized):
+    """Same weights, float32 activations in the program: the reference is
+    the same mathematics (tolerance: float32 rounding over two layers)."""
+    from docqa_tpu.models.decoder import decoder_forward, init_kv_cache
+
+    cfg = dataclasses.replace(
+        TINY, dtype="float32", quantize_weights=quantized, quant_bits=8
+    )
+    params = weights.make_decoder_params(cfg, 5)
+    ids = jnp.asarray(np.random.default_rng(0).integers(5, 512, (2, 48)), jnp.int32)
+    cache = init_kv_cache(cfg, 2, 64)
+    got, _ = decoder_forward(params, cfg, ids, cache, jnp.zeros((2,), jnp.int32))
+    rows = jnp.broadcast_to(jnp.arange(48)[None], (2, 48)).astype(jnp.int32)
+    want = reference.forward_logits(params, cfg, ids, rows)
+    err = check.logit_error(np.asarray(got), np.asarray(want))
+    assert err["worst_row"] < 2e-4, err
+
+
+def test_the_sliding_window_masks_old_keys():
+    cfg = dataclasses.replace(TINY, dtype="float32", sliding_window=8)
+    params = weights.make_decoder_params(cfg, 6)
+    ids = jnp.asarray(np.random.default_rng(1).integers(5, 512, (1, 32)), jnp.int32)
+    rows = jnp.asarray([[31]], jnp.int32)
+    windowed = reference.forward_logits(params, cfg, ids, rows)
+    wide = reference.forward_logits(
+        params, dataclasses.replace(cfg, sliding_window=None), ids, rows
+    )
+    assert float(jnp.abs(windowed - wide).max()) > 1e-3
+    # tokens older than the window cannot matter
+    changed = ids.at[0, :16].set(7)
+    again = reference.forward_logits(params, cfg, changed, rows)
+    # (two layers widen the receptive field to 2 x window)
+    np.testing.assert_allclose(np.asarray(again), np.asarray(windowed), atol=1e-5)
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "bf16"])
+def test_paged_path_passes_and_the_lower_precision_control_fails(seed, quantized):
+    cfg = dataclasses.replace(TINY, quantize_weights=quantized, quant_bits=8)
+    out = check.decoder_check(
+        engine_for(cfg, seed), seed, n_blocks=256, block_size=16,
+        seq_capacity=1024, n_lanes=4, step_width=4, control=True,
+    )
+    assert out["program"]["worst_row"] < LIMIT, out
+    assert out["control"]["worst_row"] > LIMIT, out
+    assert set(out["controls"]) >= {"a_int8", "a_fp8"}
+    assert all(c["worst_row"] > LIMIT for c in out["controls"].values()), out
+    # the cache alone in int8 is NOT failed by the logits: the exact
+    # comparison of the pool's type is what holds it
+    assert out["kv_only"]["kv_int8"]["worst_row"] < LIMIT
+    assert out["kv_bits"] == 16
+    assert check.kv_bits_missing(16, out["kv_bits"]) == 0
+
+
+def test_a_narrower_kv_pool_than_stated_is_caught_exactly():
+    assert check.kv_bits_missing(16, 8) == 8
+    assert check.kv_bits_missing(16, 32) == 0
+
+
+@pytest.mark.parametrize("name", ["a_int8", "a_fp8"])
+def test_an_activation_control_rounds_matmul_inputs_and_the_cache(name):
+    control = weights.controls_for(TINY)[name]
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((3, 5, 64)),
+                    jnp.float32)
+    for fn in (control.act, control.kv):
+        y = np.asarray(fn(x))
+        assert y.shape == x.shape and not np.array_equal(y, np.asarray(x))
+        # a scale per row of the last axis: the largest entry survives
+        np.testing.assert_allclose(np.abs(y).max(-1), np.abs(x).max(-1),
+                                   rtol=1e-6)
+    levels = np.unique(np.round(
+        np.asarray(weights.act_int8(x))[0, 0] / (np.abs(x[0, 0]).max() / 127)
+    ))
+    assert len(levels) <= 255 and np.abs(levels).max() <= 127
+
+
+def test_a_wrong_program_fails():
+    """The comparison is not an easy pass: one wrong weight matrix in the
+    program's tree (the reference keeps the right one) is caught."""
+    cfg = dataclasses.replace(TINY, quantize_weights=False)
+    engine = engine_for(cfg, 9)
+    ids, lengths = check.sample_prompts(9, cfg.vocab_size, 4, 8)
+    want = check.reference_logits(engine.params, cfg, ids, lengths, 9)
+    broken = dict(engine.params)
+    broken["l1_wo"] = broken["l1_wo"] * 0.5
+    engine.params = broken
+    got, _bits = check.program_logits(engine, ids, lengths, 2, 4, 256, 16, 1024)
+    assert check.logit_error(got, want)["worst_row"] > LIMIT
+
+
+def test_weights_follow_the_seed_and_the_served_types():
+    cfg = dataclasses.replace(TINY, quantize_weights=True, quant_bits=8)
+    a = weights.make_decoder_params(cfg, 1)
+    b = weights.make_decoder_params(cfg, 1)
+    c = weights.make_decoder_params(cfg, 2)
+    assert a["l0_wq"].dtype == jnp.int8 and a["l0_wq" + weights.SCALE].dtype == jnp.float32
+    assert a["tok_emb"].dtype == jnp.bfloat16 and a["lm_head"].dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(a["l1_w_up"]), np.asarray(b["l1_w_up"]))
+    assert not np.array_equal(np.asarray(a["l1_w_up"]), np.asarray(c["l1_w_up"]))
+    from docqa_tpu.models.decoder import decoder_param_schema
+
+    names = {n for n, *_ in decoder_param_schema(cfg)}
+    assert names <= set(a) and all(
+        k in names or k.endswith(weights.SCALE) for k in a
+    )
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_retrieval_comparison_passes_and_int8_rows_fail(seed):
+    from docqa_tpu.config import StoreConfig
+    from docqa_tpu.index.store import VectorStore
+
+    rng = np.random.default_rng(seed)
+    rows = rng.standard_normal((2048, 64)).astype(np.float32)
+    store = VectorStore(StoreConfig(dim=64, shard_capacity=2048))
+    store.add(rows, [{"doc_id": "x"}] * len(rows))
+    stored = check.to_bf16(check.unit_rows(rows))
+    q = check.retrieval_queries(stored, seed)
+    search = check.store_search(store)
+
+    limit = 2e-5
+    assert check.retrieval_error(stored, q, 3, search) < limit
+    assert check.retrieval_error(stored, q, 3, check.control_search(stored)) > limit
+
+    def wrong(queries, k):  # a plausible row that is not among the best
+        scores, ids = search(queries, k)
+        return scores, (ids + 1) % len(rows)
+
+    assert check.retrieval_error(stored, q, 3, wrong) > limit
