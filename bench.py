@@ -822,24 +822,20 @@ def main() -> int:
 
     # ---- load harnesses ------------------------------------------------------
     def trace_stats(traces):
-        """Fold completed request traces (docqa_tpu/obs) into the
-        per-stage attribution record the load sections report: stage
-        table, device/host split, and span coverage of request wall time
-        (the ≥95% acceptance figure — an unattributed gap means a stage
-        nobody instrumented ate latency)."""
+        """Fold completed request traces (docqa_tpu/obs) into the span
+        coverage of request wall time the load sections report (the ≥95%
+        acceptance figure — an uncovered gap means a stage nobody
+        instrumented ate latency)."""
         from docqa_tpu import obs
 
         done = [t for t in traces if t is not None and t.finished]
         if not done:
             return None
-        rows = obs.attribution(done)
         covs = [obs.coverage(t) for t in done]
         return {
             "n_traces": len(done),
             "trace_coverage_mean": round(float(np.mean(covs)), 4),
             "trace_coverage_min": round(float(min(covs)), 4),
-            "device_host_split": obs.device_host_split(done),
-            "stage_attribution": rows,
         }
 
     def dispatch_window(stage_prefixes=("serve_",)):
@@ -1159,12 +1155,6 @@ def main() -> int:
         stats = trace_stats(traces)
         if stats is not None:
             out.update(stats)
-            from docqa_tpu import obs
-
-            log(
-                "rag_load per-stage attribution (winner config):\n"
-                + obs.format_table(stats["stage_attribution"])
-            )
         return out
 
     def run_open_loop(engine, n_slots, chunk, cache_len, qps_target, n_req):
@@ -1262,13 +1252,6 @@ def main() -> int:
         good = [l for l, k in zip(lat_ms, ok) if k]
         errors = n_req - len(good)
         stats = trace_stats(req_traces)
-        if stats is not None:
-            from docqa_tpu import obs as _obs
-
-            log(
-                f"open@{qps_target} per-stage attribution:\n"
-                + _obs.format_table(stats["stage_attribution"])
-            )
         return {
             "arrival": f"open@{qps_target}",
             "requests": n_req,

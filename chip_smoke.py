@@ -6,7 +6,10 @@ Mistral-7B widths with int8 weights (seeded random weights, hash
 tokenizer), ingests three notes, requests one patient synthesis, asks
 seven questions (sequential, then four at once), and checks — from what the
 SERVING PROCESS reports about itself, never from HTTP status alone —
-that the decoder really generated on a TPU.  The last line of stdout is
+that the decoder really generated on a TPU.  The four concurrent asks run
+inside the program's own profiler window (``POST /api/profiler/start``):
+the trace it leaves must name the batcher's host phases.  The last line
+of stdout is
 
     {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}
 
@@ -194,7 +197,9 @@ def say(msg: str) -> None:
     print(msg, flush=True)
 
 
-def drive(base: str, deadline: float, alive=lambda: True) -> dict:
+def drive(
+    base: str, deadline: float, alive=lambda: True, profile_dir: str = ""
+) -> dict:
     """Run the whole request script against a booted server and return
     what the serving process reported, for :func:`verdict` to judge."""
 
@@ -287,6 +292,11 @@ def drive(base: str, deadline: float, alive=lambda: True) -> dict:
 
     poller = threading.Thread(target=poll_pool, daemon=True)
     poller.start()
+    # the operator's own window, around the asks that share admission
+    # rounds: main() reads the trace once the server has let go of the chip
+    profiling = profile_dir and http(
+        "POST", base + "/api/profiler/start", {"logdir": profile_dir}
+    )[0] == 200
     results = [None] * len(CONCURRENT_ASKS)
 
     def worker(i: int):
@@ -300,6 +310,10 @@ def drive(base: str, deadline: float, alive=lambda: True) -> dict:
         t.start()
     for t in threads:
         t.join(timeout=90)
+    if profiling:
+        obs["profiled"] = http(
+            "POST", base + "/api/profiler/stop", {}, 300.0
+        )[0] == 200
     polling.set()
     poller.join(timeout=5)
     obs["responses"].extend(
@@ -462,6 +476,63 @@ def verdict(obs: dict, rehearsal: bool) -> list:
     return bad
 
 
+# the batcher phases every admission passes through (engines/serve.py):
+# spans of the program, so annotations of its profiler window
+PROFILED_PHASES = ("serve_admit_round", "serve_prefill",
+                   "serve_first_token_fetch", "serve_decode_chunk")
+_READ_PROFILE = """
+import json, sys
+from jax.profiler import ProfileData
+from harness import xplane
+profile = ProfileData.from_file(xplane.find_xplane(sys.argv[1]))
+spans = {}
+for plane in profile.planes:
+    if plane.name.startswith("/host:"):
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith(("serve_", "qa_", "dispatch:")):
+                    spans[event.name] = spans.get(event.name, 0) + 1
+reduced = xplane.reduce_profile(profile)
+print(json.dumps({"host_spans": spans, "idle_gaps": reduced["idle_gaps"],
+                  "programs": sorted(reduced["programs"])}))
+"""
+
+
+def read_profile(profile_dir: str) -> dict:
+    """The host spans and the device's idle gaps of the window's trace,
+    read in a process of its own (the reduction imports JAX; this parent
+    does not).  Run after the server has exited."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _READ_PROFILE, profile_dir],
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 PYTHONPATH=os.path.join(HERE, "benchmark")),
+        capture_output=True, text=True, timeout=300,
+    )
+    if proc.returncode != 0:
+        raise Failed(f"the profiler window's trace was not readable: "
+                     f"{proc.stderr[-600:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def profile_verdict(obs: dict, profile_dir: str) -> list:
+    """What the program's profiler window fell short of; empty means the
+    trace names the batcher's phases."""
+    if not obs.get("profiled"):
+        return ["the program's profiler window did not open and close "
+                "around the concurrent asks"]
+    try:
+        trace = read_profile(profile_dir)
+    except Failed as e:
+        return [str(e)]
+    say(f"profiler window: host spans {trace['host_spans']}; programs "
+        f"{trace['programs']}; device idle gaps by host span "
+        f"{trace['idle_gaps']}")
+    missing = [p for p in PROFILED_PHASES if p not in trace["host_spans"]]
+    if missing:
+        return [f"the window's trace lacks the host spans {missing}"]
+    return []
+
+
 def report(obs: dict) -> None:
     status = obs["status"]
     dev, warm = status["device"], status["warmup"]
@@ -546,9 +617,10 @@ def main() -> int:
             start_new_session=True,
         )
     try:
+        profile_dir = os.path.join(work_dir, "profile")
         obs = drive(
             f"http://127.0.0.1:{port}", deadline,
-            alive=lambda: child.poll() is None,
+            alive=lambda: child.poll() is None, profile_dir=profile_dir,
         )
         with open(os.path.join(run_dir, "report.json"), "w") as f:
             json.dump(obs, f, indent=1)
@@ -566,6 +638,8 @@ def main() -> int:
                 child.wait(timeout=wait)
             except (ProcessLookupError, subprocess.TimeoutExpired):
                 pass
+    if not bad:  # the server has exited: the trace can be read
+        bad = profile_verdict(obs, profile_dir)
     if bad:
         for line in bad:
             print(f"FAIL: {line}", file=sys.stderr)

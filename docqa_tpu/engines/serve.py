@@ -138,6 +138,10 @@ class _Request:
     # the same wait twice.  t_submit stays the original submission time
     # (the trace span's anchor).
     t_queue: float = 0.0
+    # when ``_pop_free_slots`` last popped the request (0 = never): the
+    # end of ``serve_queue_wait`` and the start of ``serve_admit_hold``,
+    # so the four serve spans tile submit -> first token end to start
+    t_pop: float = 0.0
     # pool failover budget (engines/pool.py): how many replica hops this
     # request has already made.  A request is requeued at most
     # ``requeue_max_hops`` times — unbounded hopping would let one poison
@@ -920,6 +924,29 @@ class ContinuousBatcher:
         )  # [S, width + 2] — one D2H fetch for the worker
         return pools, table, tok, lengths, active, packed
 
+    # The three adapters below bind ``_prefill_program``'s keyword
+    # arguments for the speculative table and the prefix-cache (warm)
+    # inputs.  Named methods, not lambdas: a jitted function's name is the
+    # program's name in a device trace and in the compile log, and
+    # ``jit__lambda`` says nothing there.
+
+    def _prefill_spec_program(self, p, c, t, i, sg, po, d, lr, sl, r):
+        return self._prefill_program(p, c, i, sg, po, d, lr, sl, r, table=t)
+
+    def _prefill_warm_spec_program(
+        self, p, c, t, i, sg, po, d, lr, sl, bt, pl, r
+    ):
+        return self._prefill_program(
+            p, c, i, sg, po, d, lr, sl, r, table=t,
+            block_tables=bt, prefix_lens=pl,
+        )
+
+    def _prefill_warm_program(self, p, c, i, sg, po, d, lr, sl, bt, pl, r):
+        return self._prefill_program(
+            p, c, i, sg, po, d, lr, sl, r,
+            block_tables=bt, prefix_lens=pl,
+        )
+
     def _pinned(self, n_state_out: int) -> dict:
         """``jax.jit`` kwargs pinning a serve program's output shardings
         on a mesh — ``(pools, *n_state_out slot-state arrays)`` — see
@@ -944,10 +971,7 @@ class ContinuousBatcher:
         if self._prefill_fn is None:
             if self.spec_k:
                 self._prefill_fn = jax.jit(
-                    lambda p, c, t, i, sg, po, d, lr, sl, r:
-                    self._prefill_program(
-                        p, c, i, sg, po, d, lr, sl, r, table=t
-                    ),
+                    self._prefill_spec_program,
                     donate_argnums=(1, 2), **self._pinned(2),
                 )
             else:
@@ -968,20 +992,12 @@ class ContinuousBatcher:
         if self._prefill_warm_fn is None:
             if self.spec_k:
                 self._prefill_warm_fn = jax.jit(
-                    lambda p, c, t, i, sg, po, d, lr, sl, bt, pl, r:
-                    self._prefill_program(
-                        p, c, i, sg, po, d, lr, sl, r, table=t,
-                        block_tables=bt, prefix_lens=pl,
-                    ),
+                    self._prefill_warm_spec_program,
                     donate_argnums=(1, 2), **self._pinned(2),
                 )
             else:
                 self._prefill_warm_fn = jax.jit(
-                    lambda p, c, i, sg, po, d, lr, sl, bt, pl, r:
-                    self._prefill_program(
-                        p, c, i, sg, po, d, lr, sl, r,
-                        block_tables=bt, prefix_lens=pl,
-                    ),
+                    self._prefill_warm_program,
                     donate_argnums=(1,), **self._pinned(1),
                 )
         return self._prefill_warm_fn
@@ -1991,7 +2007,11 @@ class ContinuousBatcher:
 
     # ---- worker loop ---------------------------------------------------------
 
-    def _admit_round(self, pairs: List[Tuple[int, "_Request"]]):
+    def _admit_round(
+        self,
+        pairs: List[Tuple[int, "_Request"]],
+        drained_at: Optional[float] = None,
+    ):
         """Prefill every (slot, request) pair of this round through the
         ragged packed program (async — no device sync; the round is
         finalized with one host fetch per dispatch group in
@@ -2008,7 +2028,12 @@ class ContinuousBatcher:
         enforced there) instead of failing — ``_pop_free_slots``
         pre-checks capacity, so that path is a rare race, not the norm.
         A request whose prompt cannot be marshalled fails alone, before
-        the dispatch — not with the whole round."""
+        the dispatch — not with the whole round.
+
+        ``drained_at``: when the worker finished processing a pending
+        chunk before this round (None: it had none) — a request popped
+        before then sat that drain out, and its ``serve_admit_hold``
+        span says so."""
         # Truncation limit mirrors the budget formula in
         # _finalize_admissions (cache_len - n_ids - 1 - spec_k) with one
         # extra row reserved, so a maximally-long prompt still gets
@@ -2129,6 +2154,10 @@ class ContinuousBatcher:
             with self._cv:
                 for req in reversed(send_back):
                     req.t_queue = _now()  # fresh queue-wait interval
+                    _req_span(
+                        req, "serve_admit_hold", req.t_pop, req.t_queue,
+                        bounced=True,
+                    )
                     self._queue.appendleft(req)
                 # queue-resident again: drop them from the admission
                 # window NOW, not at the round's end — a worker death in
@@ -2141,7 +2170,7 @@ class ContinuousBatcher:
                 self._admitting = len(self._admitting_reqs)
                 self._cv.notify_all()
         if not good:
-            return [], None, []
+            return [], None, [], 0.0
 
         # Register slot state BEFORE the dispatch: if the dispatch dies,
         # _fail_active sweeps these slots and releases their fresh block
@@ -2205,9 +2234,16 @@ class ContinuousBatcher:
         # everything that touches the device happens inside the spine
         # work item below
         group_inputs = []
+        # per group: (token budget T the dispatch runs, rows its prompts
+        # take at their RAGGED_ALIGN starts, novel tokens among them) —
+        # what the prefill spans and the padding counters report
+        group_rows: List[Tuple[int, int, int]] = []
         for warm_flag, group in groups:
             total = sum(_packed_len(e) for e in group)
             T = self._pick_token_bucket(total)
+            group_rows.append(
+                (T, total, sum(len(e[2]) - e[4] for e in group))
+            )
             ids_flat = np.full((T,), self.gen.pad_id, np.int32)
             seg = np.full((T,), -1, np.int32)
             pos = np.zeros((T,), np.int32)
@@ -2318,25 +2354,46 @@ class ContinuousBatcher:
                 "serve_prefill", _prefill_on_lane, stream="prefill"
             )[0]
         t_prefill1 = _now()
+        DEFAULT_REGISTRY.counter("serve_admit_rounds").inc()
+        DEFAULT_REGISTRY.counter("serve_admitted").inc(len(ordered))
+        DEFAULT_REGISTRY.counter("serve_prefill_budget_tokens").inc(
+            sum(T for T, _rows, _novel in group_rows)
+        )
+        DEFAULT_REGISTRY.counter("serve_prefill_tokens").inc(
+            sum(novel for _T, _rows, novel in group_rows)
+        )
+        # group-major, like ``ordered``: (slot, req, prompt tokens,
+        # shared tokens, what the dispatch that carried it ran)
+        meta = []
         for gi, (warm_flag, group) in enumerate(groups):
+            T, rows, _novel = group_rows[gi]
             for slot, req, ids, table, shared in group:
+                _req_span(
+                    req, "serve_admit_hold", req.t_pop, t_prefill0,
+                    drained=(
+                        drained_at is not None and req.t_pop < drained_at
+                    ),
+                    round=len(good),
+                )
                 _req_span(
                     req, "serve_prefill", t_prefill0, t_prefill1,
                     batch=len(good), dispatch=gi, slot=slot,
                     prompt_tokens=len(ids), blocks=len(table.blocks),
-                    shared_tokens=shared,
+                    shared_tokens=shared, budget_tokens=T,
+                    packed_tokens=rows,
                 )
-        meta = [
-            (slot, req, len(ids), shared)
-            for slot, req, ids, _t, shared in ordered
-        ]
+                meta.append((
+                    slot, req, len(ids), shared,
+                    {"budget_tokens": T, "packed_tokens": rows,
+                     "batch": len(good)},
+                ))
         # the groups' token budgets ride along as the admission fetch's
         # cost keys (observatory MFU accounting; warm groups accrue
         # under their own ("warm", T) cost models)
         cost_keys = [
             ("warm", g[0]) if g[8] else g[0] for g in group_inputs
         ]
-        return meta, first_toks, cost_keys
+        return meta, first_toks, cost_keys, t_prefill1
 
     def _finalize_admissions(self, admitted) -> bool:
         """Host-side bookkeeping for an admission round: ONE device fetch
@@ -2353,19 +2410,20 @@ class ContinuousBatcher:
 
         Returns False when the fetch itself failed (prefill died on
         device) — the caller must treat the whole pipeline as poisoned."""
-        meta, round_toks, cost_keys = admitted
+        meta, round_toks, cost_keys, t_dispatched = admitted
         try:
             # ONE device fetch, on a spine lane: its duration is the
             # round's device time at the one-fetch boundary, and the
             # group token budgets are the cost keys MFU accrues under.
             # Submitted (not run) so the ticket's measured
             # queue-wait/device split survives for cost attribution.
-            ticket = spine_submit(
-                "serve_prefill_fetch",
-                lambda: np.asarray(round_toks),
-                cost_key=cost_keys,
-            )
-            firsts = ticket.result()[: len(meta)]
+            with span("serve_first_token_fetch", DEFAULT_REGISTRY):
+                ticket = spine_submit(
+                    "serve_prefill_fetch",
+                    lambda: np.asarray(round_toks),
+                    cost_key=cost_keys,
+                )
+                firsts = ticket.result()[: len(meta)]
         except Exception as e:
             log.exception("admission fetch failed; resetting")
             self._fail_active(e)
@@ -2376,7 +2434,9 @@ class ContinuousBatcher:
         # under the warm field with their avoided tokens recorded, so
         # the per-class sums reconcile against the serve_prefill_fetch
         # dispatch series exactly (same measured value, partitioned).
-        sfx = [max(n_ids - shared, 1) for _s, _r, n_ids, shared in meta]
+        sfx = [
+            max(n_ids - shared, 1) for _s, _r, n_ids, shared, _d in meta
+        ]
         total_sfx = float(sum(sfx)) or 1.0
         flops_total = 0.0
         for key in cost_keys:
@@ -2385,7 +2445,7 @@ class ContinuousBatcher:
                 flops_total += c["flops"]
         dev_ms = ticket.device_s * 1e3
         qw_ms = ticket.queue_wait_s * 1e3
-        for (slot, req, n_ids, shared), n_sfx in zip(meta, sfx):
+        for (slot, req, n_ids, shared, _d), n_sfx in zip(meta, sfx):
             share = n_sfx / total_sfx
             field = (
                 "prefill_device_ms_warm" if shared
@@ -2397,9 +2457,17 @@ class ContinuousBatcher:
             _cost_add(req, "prefill_tokens_avoided", shared)
             if flops_total:
                 _cost_add(req, "flops_est", flops_total * share)
-        for (slot, req, _n_ids, _shared), first in zip(meta, firsts):
+        for (slot, req, _n_ids, _shared, dispatch), first in zip(
+            meta, firsts
+        ):
             first = int(first)
             budget = self._slot_budget[slot]
+            # dispatched -> here: the device ran what was queued ahead
+            # (a decode chunk, by the order of dispatch) and the prefill,
+            # and the worker fetched the round's first tokens
+            _req_span(
+                req, "serve_first_token", t_dispatched, _now(), **dispatch
+            )
             if first == self.gen.eos_id or budget <= 0:
                 self._retire(slot)
             else:
@@ -2587,10 +2655,12 @@ class ContinuousBatcher:
             n_cols = self.chunk
         deactivate = []
         n_appended = 0
+        n_held = 0  # snapshot slots whose request is still the occupant
         for slot in range(self.n_slots):
             req = snap[slot]
             if req is None or self._slot_req[slot] is not req:
                 continue
+            n_held += 1
             before = len(req.tokens)
             for t in range(n_cols):
                 if not valid_h[slot, t]:
@@ -2655,6 +2725,12 @@ class ContinuousBatcher:
         DEFAULT_REGISTRY.histogram("serve_tokens_per_chunk").observe(
             float(n_appended)
         )
+        DEFAULT_REGISTRY.counter("serve_decode_chunks").inc()
+        if not n_held:
+            # every lane the chunk advanced had retired by the time it
+            # was fetched (the overshoot chunk dispatched ahead): device
+            # time that delivered nothing
+            DEFAULT_REGISTRY.counter("serve_decode_chunks_stale").inc()
         if deactivate:
             # queued for the next device work item (_apply_deact_on_lane)
             # — the worker never issues device ops from its own thread
@@ -2782,14 +2858,22 @@ class ContinuousBatcher:
                     # object's address still gets its own mark/count
                     self._block_wait_marked = None
                 drained = True
-                # queue-wait is over either way (admitted or shed)
-                _req_span(req, "serve_queue_wait", req.t_submit, _now())
+                # queue-wait is over either way (admitted or shed).  A
+                # request popped before (bounced off the block pool,
+                # preempted) waited from its re-entry, not from submit:
+                # each entry records one set of spans, end to start
+                t_pop = _now()
+                _req_span(
+                    req, "serve_queue_wait",
+                    req.t_queue if req.t_pop else req.t_submit, t_pop,
+                )
+                req.t_pop = t_pop
                 # cost wait = THIS queue entry's interval only (t_queue
                 # resets on every requeue, so bounced/rescued requests
                 # sum disjoint intervals instead of re-counting)
                 _cost_add(
                     req, "queue_wait_ms",
-                    (_now() - (req.t_queue or req.t_submit)) * 1e3,
+                    (t_pop - (req.t_queue or req.t_submit)) * 1e3,
                 )
                 if req.cancelled:
                     # hedged-dispatch loser (or abandoned client) still
@@ -2949,7 +3033,8 @@ class ContinuousBatcher:
                     and not any(self._slot_req)
                 ):
                     self._beat = time_monotonic()
-                    self._cv.wait(0.5)
+                    with span("serve_idle_wait", DEFAULT_REGISTRY):
+                        self._cv.wait(0.5)
                 if self._stopped:
                     return
                 # admission: fill every free slot from the queue; the whole
@@ -2965,12 +3050,16 @@ class ContinuousBatcher:
                     # or a teardown window): bounded wait instead of a
                     # hot spin; retirements notify this cv
                     self._beat = time_monotonic()
-                    self._cv.wait(0.05)
+                    with span("serve_idle_wait", DEFAULT_REGISTRY):
+                        self._cv.wait(0.05)
                     self._pop_free_slots(pairs)
+            drained_at = None
             if pairs and pending is not None:
                 # drain the pipeline before admitting: the invariant above,
                 # plus processing may retire slots this round can refill
-                drained_ok = self._process_chunk(*pending)
+                with span("serve_admit_drain", DEFAULT_REGISTRY):
+                    drained_ok = self._process_chunk(*pending)
+                drained_at = _now()
                 pending = None
                 if drained_ok:
                     with self._cv:  # top-up from slots freed by the drain
@@ -3138,7 +3227,8 @@ class ContinuousBatcher:
             admitted = None
             if pairs:
                 try:
-                    admitted = self._admit_round(pairs)
+                    with span("serve_admit_round", DEFAULT_REGISTRY):
+                        admitted = self._admit_round(pairs, drained_at)
                     if not admitted[0]:
                         admitted = None
                 except Exception as e:

@@ -11,8 +11,8 @@ for the rest of the framework:
   :func:`finish` / :func:`finish_id`;
 * export: :func:`timeline_dict`, :func:`to_chrome_trace`,
   :func:`coverage`;
-* analysis: :func:`attribution`, :func:`format_table`,
-  :data:`DEFAULT_PROFILER` (on-demand ``jax.profiler`` window);
+* profiling: :data:`DEFAULT_PROFILER` (on-demand ``jax.profiler``
+  window; while open, every ``metrics.span`` site annotates the trace);
 * telemetry (ISSUE 7): :class:`TelemetryStore` / :class:`WindowedDigest`
   / :class:`TelemetrySampler` (time-series rollups of the serving
   plane), :class:`BurnRateEvaluator` + :func:`default_ask_slos` (SLO
@@ -60,12 +60,7 @@ from docqa_tpu.obs.export import (  # noqa: F401
 )
 from docqa_tpu.obs.profiler import (  # noqa: F401
     DEFAULT_PROFILER,
-    DEVICE_STAGES,
     ProfilerWindow,
-    attribution,
-    device_host_split,
-    format_table,
-    stage_kind,
 )
 from docqa_tpu.obs.expo import (  # noqa: F401
     lint_prometheus_text,

@@ -526,7 +526,7 @@ def flash_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="_flash_kernel",
     )(lengths.astype(jnp.int32), q_offset.astype(jnp.int32), qr, kr, vr)
 
     out = out.reshape(b, hq, sq_p, d).transpose(0, 2, 1, 3)

@@ -20,6 +20,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 # stdlib-only subsystem (jax lazy inside its profiler) — no import cycle
 from docqa_tpu.obs.context import current_trace_id
+from docqa_tpu.obs.profiler import DEFAULT_PROFILER
 from docqa_tpu.obs.spans import start_span as _trace_span
 from docqa_tpu.obs.telemetry import WindowedDigest
 
@@ -294,8 +295,10 @@ def span(
     registry: Optional[MetricsRegistry] = None,
     profile: bool = False,
 ) -> Iterator[None]:
-    """Wall-clock span recorded as ``<name>_ms`` histogram; optionally wraps a
-    ``jax.profiler.TraceAnnotation`` so the stage shows up in TPU traces.
+    """Wall-clock span recorded as ``<name>_ms`` histogram; while the
+    program's profiler window is open (``POST /api/profiler/start``), or
+    when ``profile`` is true, also a ``jax.profiler.TraceAnnotation``, so
+    the stage sits on the device trace's clock.
 
     When a TraceContext is active (docqa_tpu/obs), the same interval is
     ALSO recorded as a trace span and the histogram sample carries the
@@ -304,7 +307,7 @@ def span(
     read."""
     registry = registry or DEFAULT_REGISTRY
     start = time.perf_counter()
-    if profile:
+    if profile or DEFAULT_PROFILER.annotate:
         import jax.profiler
 
         ctx: contextlib.AbstractContextManager = jax.profiler.TraceAnnotation(name)

@@ -1483,11 +1483,22 @@ def make_app(rt: DocQARuntime):
             )
         budget = rt.cfg.resilience.request_deadline_s
         deadline = Deadline.after(budget) if budget > 0 else None
+        t_lane = time.perf_counter()
+
+        def submit_on_lane():
+            # the device lane is ONE thread: until it is free the request
+            # has not begun (a retrieval ahead of it may itself be waiting
+            # on the device behind a decode chunk) — on the record, so the
+            # spans of a timeline add up to the client's time to first token
+            if ctx is not None:
+                ctx.trace.record_span(
+                    "ask_lane_wait", t_lane, time.perf_counter(),
+                    parent_id=ctx.span_id,
+                )
+            return rt.qa.ask_submit(q.question, deadline=deadline)
+
         try:
-            pending = await on_device(
-                obs.call_in, ctx, rt.qa.ask_submit, q.question,
-                deadline=deadline,
-            )
+            pending = await on_device(obs.call_in, ctx, submit_on_lane)
         except QueueFull as e:
             return None, json_error(503, str(e), ctx)
         except DeadlineExceeded as e:

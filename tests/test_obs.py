@@ -205,23 +205,6 @@ class TestExport:
         assert all("ts" in e and "dur" in e and e["pid"] == 1 for e in x)
         assert any(e["args"].get("trace_id") == ctx.trace_id for e in x)
 
-    def test_attribution_table(self):
-        tr = Trace("t-a", "req")
-        t0 = tr.t0
-        tr.record_span("serve_decode_chunk", t0, t0 + 0.08)
-        tr.record_span("qa_retrieve", t0 + 0.08, t0 + 0.09)
-        tr.root.t_end = t0 + 0.1
-        rows = obs.attribution([tr])
-        by_stage = {r["stage"]: r for r in rows}
-        assert by_stage["serve_decode_chunk"]["kind"] == "device"
-        assert by_stage["qa_retrieve"]["kind"] == "host"
-        assert "(unattributed)" in by_stage
-        split = obs.device_host_split([tr])
-        assert split["device_ms"] == pytest.approx(80.0, abs=1.0)
-        # the text table renders every row
-        table = obs.format_table(rows)
-        assert "serve_decode_chunk" in table and "share%" in table
-
 
 # ---------------------------------------------------------------------------
 # metrics integration: span() -> trace span + exemplar; log filter

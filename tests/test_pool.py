@@ -341,7 +341,7 @@ class TestPoolFailover:
             b = pool._replicas[0].batcher
             release = threading.Event()
 
-            def hung_admit(pairs):
+            def hung_admit(pairs, drained_at=None):
                 # popped, never slot-resident; released only at teardown
                 release.wait(30)
                 raise WorkerDied("test wedge released")
