@@ -39,16 +39,18 @@ Attention arXiv 2604.15464):
   overlap the next chunk's device execution.
   Correctness rests on the dispatch-time snapshot: every chunk carries
   the slot→request mapping of its own dispatch, and tokens are
-  delivered only to slots whose occupant is still that request — so the
-  disaggregated order below (admission prefill AFTER the chunk
-  dispatch) can never misdeliver.  Slots that retire on budget mid-pipeline decode one extra chunk
+  delivered only to slots whose occupant is still that request — so a
+  lane retired between a chunk's dispatch and its fetch (its first token
+  was EOS, its budget ran out) can never be misdelivered to.  Slots that
+  retire on budget mid-pipeline decode one extra chunk
   whose tokens are discarded — wasted compute, never wrong output — and an
   in-program capacity guard deactivates any lane before a K/V write could
   land past its allocated blocks (such writes are additionally dropped,
   never clamped, by the paged scatter).  Freed blocks can be re-used by
-  the very next admission because the pool is DONATED through every
-  dispatch: an in-flight overshoot chunk's stale writes are sequenced
-  before the prefill that re-populates those rows.
+  the very next admission because the worker fetches the chunk in flight
+  before it admits, and the pool is DONATED through every dispatch: an
+  overshoot chunk's stale writes have landed before the prefill that
+  re-populates those rows is dispatched.
 
 Prefix reuse (docqa-prefix, ROADMAP item 1 follow-through): a refcounted
 copy-on-write prefix cache (``engines/paged.PrefixCache``) keyed by the
@@ -60,9 +62,13 @@ the novel suffix.  Shared runs are full blocks and 128-aligned, so warm
 output is bitwise-identical to a cold prefill; ``release`` decrements
 instead of freeing, double frees still raise, and the cache gives its
 HBM back (LRU) under :class:`BlockPoolExhausted` pressure before any
-live work is shed.  The worker loop is DISAGGREGATED: decode chunks
-dispatch ahead of the admission prefill (which rides its own spine
-stream), so a long prefill never stalls live lanes' token cadence.
+live work is shed.  The worker loop admits PREFILL FIRST: a round's
+prefill is the next device program and the iteration's one decode chunk
+follows it, carrying the live lanes and the lanes just admitted — one
+device runs the two back to back either way, so new requests get their
+first token a chunk sooner and live lanes finish when they did.  The
+prefill rides its own spine stream, ranked below decode-class items, so
+one replica's long prefill never holds another replica's chunks.
 
 TP shardings come from ``parallel/sharding.py`` (block pool: kv-heads over
 the model axis, block rows replicated); slots ride the batch axis.
@@ -2299,10 +2305,10 @@ class ContinuousBatcher:
             deactivations, one packed dispatch per group, then the slot
             -state scatter.  Slot state updates ride the device (the
             sampled first tokens are already there) — alive = (first !=
-            eos) & (budget >= 2) needs no host fetch, so the decode
-            chunk that follows this admission can dispatch immediately;
-            the host-side fetch of first tokens (_finalize_admissions)
-            then overlaps that chunk's execution."""
+            eos) & (budget >= 2) needs no host fetch, so the worker
+            dispatches the decode chunk for the live lanes and these
+            right behind it; the host-side fetch of first tokens
+            (_finalize_admissions) then overlaps that chunk."""
             self._apply_deact_on_lane()
             parts = []
             for (T, ids_flat, seg, pos, dest, last_rows, slots_arr,
@@ -2401,8 +2407,12 @@ class ContinuousBatcher:
 
         Device-side slot state (tok/lengths/active + budgets) was already
         written by ``_admit_round`` without a fetch, so the worker calls
-        this AFTER dispatching the next decode chunk — the fetch round-trip
-        overlaps that chunk's execution.  The budget math mirrors
+        this AFTER dispatching the decode chunk that carries the round's
+        lanes — the fetch waits for the prefill alone and its round-trip
+        overlaps that chunk's execution.  A lane retired here (first
+        token EOS, budget spent) is in that chunk's snapshot: the device
+        ``active`` flag kept the chunk off it and ``_process_chunk``
+        drops it.  The budget math mirrors
         ``_admit_round``: the prefill token counts as one, and speculation
         reserves ``spec_k`` rows of K/V headroom (a verify writes K rows
         from the current length, and dynamic_update_slice CLAMPS an
@@ -2462,9 +2472,9 @@ class ContinuousBatcher:
         ):
             first = int(first)
             budget = self._slot_budget[slot]
-            # dispatched -> here: the device ran what was queued ahead
-            # (a decode chunk, by the order of dispatch) and the prefill,
-            # and the worker fetched the round's first tokens
+            # dispatched -> here: the device ran the prefill (the
+            # round's chunk is queued behind it) and the worker fetched
+            # the round's first tokens
             _req_span(
                 req, "serve_first_token", t_dispatched, _now(), **dispatch
             )
@@ -3011,11 +3021,9 @@ class ContinuousBatcher:
     def _run_loop(self) -> None:
         # The one dispatched-but-unprocessed decode chunk: (packed device
         # array, dispatch-time slot→request snapshot).  The snapshot is
-        # taken at DISPATCH time, so a prefill admitted between the
-        # chunk's dispatch and its processing (the disaggregated order)
-        # maps to slots the snapshot holds as None — the guard in
-        # _process_chunk delivers tokens only where the occupant is
-        # still the snapshot's request.
+        # taken at DISPATCH time, and the guard in _process_chunk
+        # delivers tokens only where the occupant is still the
+        # snapshot's request.
         pending: Optional[Tuple[jax.Array, List[Optional[_Request]]]] = None
         while True:
             self._beat = time_monotonic()
@@ -3055,8 +3063,11 @@ class ContinuousBatcher:
                     self._pop_free_slots(pairs)
             drained_at = None
             if pairs and pending is not None:
-                # drain the pipeline before admitting: the invariant above,
-                # plus processing may retire slots this round can refill
+                # drain the pipeline before admitting: processing may
+                # retire slots this round can refill, and the fetch is the
+                # PR-9 re-use guarantee — the only chunk in flight, stale
+                # writes to retired lanes' rows included, has LANDED
+                # before the prefill that re-populates them is dispatched
                 with span("serve_admit_drain", DEFAULT_REGISTRY):
                     drained_ok = self._process_chunk(*pending)
                 drained_at = _now()
@@ -3067,21 +3078,11 @@ class ContinuousBatcher:
                 # on drain failure the device state was reset; the popped
                 # requests were never slot-resident, so admit them into
                 # the fresh state below
-            # ---- disaggregated prefill/decode (docqa-prefix): the
-            # decode chunk for ALREADY-LIVE lanes is dispatched BEFORE
-            # this round's admission prefill, so a long prefill no
-            # longer sits between two decode chunks — live lanes keep
-            # their chunk cadence and the prefill (its own spine
-            # stream, scheduled below decode-class items) only delays
-            # the NEW requests' second chunk by one iteration.  On
-            # device the chunk is sequenced first through the donated
-            # pools, so an in-flight overshoot chunk's stale writes
-            # still land before any prefill that re-populates freed
-            # rows (the PR-9 re-use guarantee, order now explicit).
-            #
             # grow-at-decode: top up every live lane's block table to the
-            # margin BEFORE dispatching (the in-program capacity guard
-            # must never be what stops a live lane).  A lane the pool
+            # margin BEFORE dispatching, and ahead of the round's own
+            # allocation (the in-program capacity guard must never be
+            # what stops a live lane; a live lane outranks a popped
+            # request for the last blocks).  A lane the pool
             # cannot grow sheds TYPED here — in an overcommitted pool
             # (gen.kv_pool_tokens < worst case) that is the designed
             # failure mode, and it frees the lane's blocks for the rest.
@@ -3154,9 +3155,57 @@ class ContinuousBatcher:
                 # queued for the next device closure (the worker never
                 # issues device ops from its own thread)
                 self._deact_pending.extend(shed_slots)
+            # ---- prefill first: a round's prefill is the next device
+            # program, and the iteration's ONE decode chunk follows it
+            # with the live lanes AND the lanes just admitted in its
+            # snapshot.  One device runs the two back to back in either
+            # order, so the order only decides who waits: the new
+            # requests get their first token a chunk sooner, and a live
+            # lane's chunk lands one prefill later — where its next
+            # chunk would have landed behind that prefill anyway.  The
+            # spine's "prefill" stream still ranks below decode-class
+            # items: that protects OTHER replicas' chunks on a shared
+            # lane, not this worker's order.
+            admitted = None
+            if pairs:
+                # lanes live now are due a chunk: the round goes ahead
+                # of it (a round into an idle batcher has nothing to pass)
+                ahead = any(self._slot_req)
+                try:
+                    with span("serve_admit_round", DEFAULT_REGISTRY):
+                        admitted = self._admit_round(pairs, drained_at)
+                    if not admitted[0]:
+                        admitted = None
+                    elif ahead:
+                        DEFAULT_REGISTRY.counter("serve_prefill_ahead").inc()
+                except Exception as e:
+                    # the round's dispatch died; the pool was donated
+                    # through it — fail in-flight and reset.  Requests
+                    # _admit_round already sent BACK to the queue
+                    # (block-starved) were never in the dispatch: they
+                    # stay queued for the next round, not failed here.
+                    log.exception("admission round failed; resetting")
+                    with self._cv:
+                        requeued = {id(r) for r in self._queue}
+                    for _slot, req in pairs:
+                        if id(req) in requeued:
+                            continue
+                        if not req.done.is_set():
+                            req.error = RuntimeError(f"prefill failed: {e!r}")
+                            _finish(req)
+                    self._fail_active(e)
+                    pending = None
+                    continue
+                finally:
+                    # every pair is slot-resident or finished by now —
+                    # drain() may judge quiescence again
+                    with self._cv:
+                        self._admitting = 0
+                        self._admitting_reqs = []
+                        self._cv.notify_all()
             # one decode chunk for every live slot, dispatched BEFORE the
-            # previous chunk's results are fetched — fetch + host work
-            # below overlap this chunk's device execution
+            # round's first tokens and the previous chunk's results are
+            # fetched — the fetches and host work below overlap it
             fn = self._get_decode_fn()
 
             def _decode_on_lane():
@@ -3208,11 +3257,11 @@ class ContinuousBatcher:
 
             packed = snap = None
             if any(self._slot_req):
-                # snapshot at DISPATCH time: slots this chunk advances.
-                # Lanes admitted by the prefill BELOW were free here —
-                # the chunk carries nothing for them, and the snapshot
-                # guard in _process_chunk drops any slot whose occupant
-                # changed (retired during finalize) either way.
+                # snapshot at DISPATCH time: slots this chunk advances,
+                # this round's lanes among them.  One whose first token
+                # retires it in _finalize_admissions below (EOS, budget
+                # < 2) is inactive on the device, and the guard in
+                # _process_chunk drops any slot whose occupant changed.
                 snap = list(self._slot_req)
                 try:
                     with span("serve_decode_dispatch", DEFAULT_REGISTRY):
@@ -3224,65 +3273,12 @@ class ContinuousBatcher:
                     self._fail_active(e)
                     pending = None
                     continue
-            admitted = None
-            if pairs:
-                try:
-                    with span("serve_admit_round", DEFAULT_REGISTRY):
-                        admitted = self._admit_round(pairs, drained_at)
-                    if not admitted[0]:
-                        admitted = None
-                except Exception as e:
-                    # the round's dispatch died; the pool was donated
-                    # through it — fail in-flight and reset.  Requests
-                    # _admit_round already sent BACK to the queue
-                    # (block-starved) were never in the dispatch: they
-                    # stay queued for the next round, not failed here.
-                    # The chunk dispatched above chains into the same
-                    # poisoned pool lineage: drop it (its requests were
-                    # failed by the reset).
-                    log.exception("admission round failed; resetting")
-                    with self._cv:
-                        requeued = {id(r) for r in self._queue}
-                    for _slot, req in pairs:
-                        if id(req) in requeued:
-                            continue
-                        if not req.done.is_set():
-                            req.error = RuntimeError(f"prefill failed: {e!r}")
-                            _finish(req)
-                    self._fail_active(e)
-                    pending = None
-                    continue
-                finally:
-                    # every pair is slot-resident or finished by now —
-                    # drain() may judge quiescence again
-                    with self._cv:
-                        self._admitting = 0
-                        self._admitting_reqs = []
-                        self._cv.notify_all()
             ok = True
             if admitted is not None:
-                # the first-token fetch blocks on the prefill, which the
-                # device sequences after the chunk above — host-side
-                # token bookkeeping for BOTH lands while the next
-                # iteration's work queues up
+                # blocks on the prefill alone (the chunk above runs
+                # behind it): a request's first token is delivered
+                # before any token of that chunk
                 ok = self._finalize_admissions(admitted)
             if ok and pending is not None:
                 ok = self._process_chunk(*pending)
-            if ok and packed is None and any(self._slot_req):
-                # admission-only iteration (no lane was decoding when
-                # the chunk slot came up, so there was no cadence to
-                # protect): give the fresh lanes their first chunk NOW
-                # instead of one loop later — burst starts and
-                # idle-arrival requests keep the pre-split latency
-                snap = list(self._slot_req)
-                try:
-                    with span("serve_decode_dispatch", DEFAULT_REGISTRY):
-                        packed = spine_run("serve_decode", _decode_on_lane)
-                except Exception as e:
-                    log.exception(
-                        "decode dispatch failed; resetting slot state"
-                    )
-                    self._fail_active(e)
-                    pending = None
-                    continue
             pending = (packed, snap) if ok and packed is not None else None
