@@ -5,7 +5,8 @@ chip, at the configuration's own size, many seeds in one process:
 * the largest error sound runs of the program give, and
 * the smallest error the lower-precision controls give (the plain
   reference put in the program's place, one step below each thing the
-  configuration states — ``weights.controls_for``: int4 weights below
+  configuration states — the ``weights.controls_for`` of its architecture
+  package; for the Mistral block: int4 weights below
   int8, int8 and float8 activations with the cache below bfloat16; int8
   rows below bfloat16 rows for the store), and beside them what the
   cache alone in 8 bits reads (``weights.kv_only_controls``).
@@ -48,13 +49,14 @@ def main() -> int:
 
     from docqa_tpu.config import load_config
     from docqa_tpu.runtime.compile_cache import configure_compile_cache
-    from harness import check, weights
-    from harness.child import load_cell_config, program_overrides
+    from harness import arch, check
+    from harness.child import program_overrides
 
     configure_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    conf = load_cell_config(args.config, args.overlay)
+    conf = arch.load_cell_config(args.config, args.overlay)
     cfg = load_config(env={}, overrides=program_overrides(conf))
+    package = arch.load(conf)
     devices = jax.devices()
     print(f"device: {devices[0].platform} {devices[0].device_kind} x{len(devices)}")
     mesh = None
@@ -73,12 +75,15 @@ def main() -> int:
     out = {"decoder": [], "retrieval": []}
     for seed in [int(s) for s in args.seeds.split(",") if s]:
         t = time.monotonic()
-        params = weights.make_decoder_params(cfg.decoder, seed % (2**31), mesh)
+        params = package.weights.make_decoder_params(
+            cfg.decoder, seed % (2**31), mesh
+        )
         engine = types.SimpleNamespace(
             cfg=cfg.decoder, params=params, use_flash=use_flash
         )
         row = check.decoder_check(
-            engine, seed, n_blocks=n_blocks, block_size=block,
+            package, conf["check"], engine, seed, n_blocks=n_blocks,
+            block_size=block,
             seq_capacity=capacity, n_lanes=int(gen.max_concurrent),
             step_width=max(1, int(gen.speculative_k)), mesh=mesh, control=True,
         )

@@ -3,11 +3,16 @@ outside the timed window.  Nothing a request's status, route or timing
 could change enters here.
 
 Decoder: a seeded batch of prompts goes through the program's
-``ragged_prefill_forward`` and then two ``paged_decode_forward`` steps (the
+``ragged_prefill_forward`` and then ``paged_decode_forward`` steps (the
 speculative verify width) through a paged pool of the served shape, with
 the flash kernel where the engine uses it.  The LOGITS at the last prompt
-position and at every decoded position are compared with the reference's
-one full forward pass over prompt + forced tokens.  The number compared is
+position and at every decoded position are compared with the one full
+forward pass over prompt + forced tokens of the plain reference in the
+configuration's architecture package (``arch.load``).  The compared sizes
+are the configuration's ``check`` block: ``prompt_lengths`` (base lengths,
+taken in turn by the lanes, each plus a seeded 0..``LENGTH_JITTER``-1) and
+``lane_rows`` (packed rows reserved per prompt); ``DECODE_STEPS`` decode
+steps follow the prefill.  The number compared is
 the worst row's relative error ``|l_prog - l_ref| / |l_ref - mean(l_ref)|``
 (2-norms over the vocabulary).
 
@@ -28,19 +33,24 @@ from typing import Callable, Dict
 import numpy as np
 
 RAGGED_ALIGN = 128  # ops/attention.py packs each prompt from a 128-row start
-LANE_ROWS = 512  # packed rows reserved per checked prompt (a multiple of 128)
+LENGTH_JITTER = 40  # a seeded 0..39 on top of each base length
+DECODE_STEPS = 2  # decode steps compared after the prefill
 
 
-def sample_prompts(seed: int, vocab: int, n_lanes: int, n_decode: int):
-    """Seeded token ids: (ids [n_lanes, LANE_ROWS], lengths).  Lengths
-    spread over the range the cells' prompts fall in; the array's shape is
-    the same for every seed, so every seed runs the same programs."""
+def sample_prompts(seed: int, vocab: int, n_lanes: int, n_decode: int,
+                   spec: dict):
+    """Seeded token ids: (ids [n_lanes, lane_rows], lengths), at the sizes
+    of ``spec`` (the configuration's ``check`` block).  The array's shape
+    is the same for every seed, so every seed runs the same programs."""
     rng = np.random.default_rng([seed % (2**31), 7])
-    base = (150, 290, 380, 450)
-    lengths = [base[i % 4] + int(rng.integers(0, 40)) for i in range(n_lanes)]
-    total = LANE_ROWS
+    base = spec["prompt_lengths"]
+    lengths = [base[i % len(base)] + int(rng.integers(0, LENGTH_JITTER))
+               for i in range(n_lanes)]
+    total = int(spec["lane_rows"])
+    if total % RAGGED_ALIGN:
+        raise ValueError(f"check.lane_rows is no multiple of {RAGGED_ALIGN}")
     if max(lengths) + n_decode > total:
-        raise ValueError("a checked prompt does not fit LANE_ROWS")
+        raise ValueError("a checked prompt does not fit check.lane_rows")
     ids = rng.integers(5, vocab, size=(n_lanes, total), dtype=np.int32)
     return ids, np.asarray(lengths, np.int32)
 
@@ -91,7 +101,7 @@ def program_logits(engine, ids, lengths, n_steps: int, step_width: int,
     from docqa_tpu.engines.paged import init_paged_pools
 
     cfg, params = engine.cfg, engine.params
-    lanes = ids.shape[0]
+    lanes, lane_rows = ids.shape
     blocks_per_seq = seq_capacity // block_size
     if lanes * blocks_per_seq > n_blocks:
         raise ValueError("the check's lanes do not fit the pool")
@@ -104,8 +114,8 @@ def program_logits(engine, ids, lengths, n_steps: int, step_width: int,
     n_rows = n_blocks * block_size
 
     # pack: lane b occupies rows [start_b, start_b + L_b), starts aligned
-    starts = [b * LANE_ROWS for b in range(lanes)]
-    budget = max(lanes * LANE_ROWS, 2 * RAGGED_ALIGN)
+    starts = [b * lane_rows for b in range(lanes)]
+    budget = max(lanes * lane_rows, 2 * RAGGED_ALIGN)
     packed = np.zeros((budget,), np.int32)
     seg = np.full((budget,), -1, np.int32)
     pos = np.zeros((budget,), np.int32)
@@ -158,15 +168,14 @@ def kv_bits_missing(stated_bits: int, found_bits: int) -> int:
     return max(0, int(stated_bits) - int(found_bits))
 
 
-def reference_logits(params, cfg, ids, lengths, n_rows: int, control=None):
-    """The reference's logits at the same positions: rows L-1 .. L-1+n_rows-1
-    of one full forward pass per lane."""
+def reference_logits(arch, params, cfg, ids, lengths, n_rows: int,
+                     control=None):
+    """The logits of ``arch``'s plain reference at the same positions: rows
+    L-1 .. L-1+n_rows-1 of one full forward pass per lane."""
     import jax.numpy as jnp
 
-    from . import reference
-
     rows = np.asarray(lengths)[:, None] - 1 + np.arange(n_rows)[None, :]
-    out = reference.forward_logits(
+    out = arch.reference.forward_logits(
         params, cfg, jnp.asarray(ids), jnp.asarray(rows.astype(np.int32)),
         control=control,
     )
@@ -185,19 +194,22 @@ def logit_error(got: np.ndarray, want: np.ndarray) -> Dict[str, float]:
     }
 
 
-def decoder_check(engine, seed: int, n_blocks: int, block_size: int,
-                  seq_capacity: int, n_lanes: int, step_width: int,
-                  mesh=None, control: bool = False) -> Dict[str, float]:
-    """The decoder comparison of one seed.  ``control``: ALSO put the
-    lower-precision reference in the program's place."""
-    from . import weights
-
-    n_steps = 2
+def decoder_check(arch, spec: dict, engine, seed: int, n_blocks: int,
+                  block_size: int, seq_capacity: int, n_lanes: int,
+                  step_width: int, mesh=None,
+                  control: bool = False) -> Dict[str, float]:
+    """The decoder comparison of one seed, against the reference of the
+    architecture package ``arch`` at the sizes of ``spec`` (the
+    configuration's ``check`` block).  ``control``: ALSO put the package's
+    lower-precision references in the program's place."""
+    n_steps = DECODE_STEPS
     ids, lengths = sample_prompts(
-        seed, engine.cfg.vocab_size, n_lanes, n_steps * step_width
+        seed, engine.cfg.vocab_size, n_lanes, n_steps * step_width, spec
     )
     n_rows = 1 + n_steps * step_width
-    want = reference_logits(engine.params, engine.cfg, ids, lengths, n_rows)
+    want = reference_logits(
+        arch, engine.params, engine.cfg, ids, lengths, n_rows
+    )
     got, kv_bits = program_logits(
         engine, ids, lengths, n_steps, step_width, n_blocks, block_size,
         seq_capacity, mesh=mesh,
@@ -206,8 +218,8 @@ def decoder_check(engine, seed: int, n_blocks: int, block_size: int,
     if control:
         def reading(c):
             return logit_error(
-                reference_logits(engine.params, engine.cfg, ids, lengths,
-                                 n_rows, control=c),
+                reference_logits(arch, engine.params, engine.cfg, ids,
+                                 lengths, n_rows, control=c),
                 want,
             )
 
@@ -215,13 +227,14 @@ def decoder_check(engine, seed: int, n_blocks: int, block_size: int,
         # reads smallest, the upper end of any limit
         out["controls"] = {
             name: reading(c)
-            for name, c in weights.controls_for(engine.cfg).items()
+            for name, c in arch.weights.controls_for(engine.cfg).items()
         }
         out["control"] = min(
             out["controls"].values(), key=lambda e: e["worst_row"]
         )
         out["kv_only"] = {
-            name: reading(c) for name, c in weights.kv_only_controls().items()
+            name: reading(c)
+            for name, c in arch.weights.kv_only_controls().items()
         }
     return out
 

@@ -4,8 +4,9 @@ HTTP surface, booted for a benchmark cell.
 What differs from ``scripts/start_all.py`` is set-up only, and none of it
 is on a request's path:
 
-* decoder weights are made on the device from the seed by
-  ``weights.make_decoder_params`` and handed to the program's
+* decoder weights are made on the device from the seed by the
+  ``weights.make_decoder_params`` of the configuration's architecture
+  package (``arch.load``) and handed to the program's
   ``GenerateEngine(params=...)`` (the program's own default draws 7e9
   normals on one host thread, 280 s);
 * the encoder keeps the program's initialiser but takes the run's seed;
@@ -25,7 +26,6 @@ Usage (the parent, ``run.py``, is the only caller):
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 import threading
@@ -38,42 +38,14 @@ for p in (ROOT, BENCH_DIR):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-HF_TO_DECODER = {
-    "hidden_size": "hidden_dim",
-    "intermediate_size": "mlp_dim",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "vocab_size": "vocab_size",
-    "max_position_embeddings": "max_seq_len",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "sliding_window": "sliding_window",
-}
-
-
-def load_cell_config(path: str, overlay: str = ""):
-    """The configuration file, with a test's overlay (tiny widths) merged
-    over it when given."""
-    with open(path, encoding="utf-8") as f:
-        conf = json.load(f)
-    if overlay:
-        with open(overlay, encoding="utf-8") as f:
-            over = json.load(f)
-        for key, value in over.items():
-            if isinstance(value, dict) and isinstance(conf.get(key), dict):
-                conf[key] = {**conf[key], **value}
-            else:
-                conf[key] = value
-    return conf
+from harness import arch  # noqa: E402  (standard library)
 
 
 def program_overrides(conf: dict) -> dict:
-    """Dotted-path overrides for the program's ``load_config``."""
-    out = {f"decoder.{HF_TO_DECODER[k]}": conf[k]
-           for k in HF_TO_DECODER if k in conf}
-    out["decoder.dtype"] = conf.get("torch_dtype", "bfloat16")
+    """Dotted-path overrides for the program's ``load_config``: the
+    published keys as the architecture's package maps them, the file's
+    ``serving`` block over them, then what every benchmark child fixes."""
+    out = dict(arch.load_shapes(conf).keys.program_overrides(conf))
     out.update(conf.get("serving", {}))
     out.update({
         "data.work_dir": None,  # nothing persisted, nothing restored
@@ -182,7 +154,8 @@ def build_corpus(rt, conf: dict, seed: int, state: State):
     return np.concatenate(stored, axis=0)
 
 
-def make_routes(rt, state: State, conf: dict, seed: int, stored, work: str):
+def make_routes(rt, state: State, conf: dict, package, seed: int, stored,
+                work: str):
     import jax
     from aiohttp import web
 
@@ -234,7 +207,7 @@ def make_routes(rt, state: State, conf: dict, seed: int, stored, work: str):
         gen = rt.generator
         batcher = rt.batcher._replicas[0].batcher
         dec = check.decoder_check(
-            gen, seed,
+            package, conf["check"], gen, seed,
             n_blocks=batcher.n_blocks, block_size=batcher.block_size,
             seq_capacity=batcher.seq_capacity, n_lanes=batcher.n_slots,
             step_width=max(1, int(rt.cfg.generate.speculative_k)),
@@ -334,7 +307,7 @@ def main() -> int:
     args = ap.parse_args()
     state = State()
     t0 = state.t_start
-    conf = load_cell_config(args.config, args.overlay)
+    conf = arch.load_cell_config(args.config, args.overlay)
 
     if args.trace:
         profile_program_spans()
@@ -350,7 +323,7 @@ def main() -> int:
     jax.config.update("jax_log_compiles", True)  # names, in the child's log
     count_compiles(state)
 
-    from harness import peaks, weights
+    from harness import peaks
 
     devices = jax.devices()  # no accelerator under JAX_PLATFORMS=tpu: raises
     if not args.overlay:
@@ -368,6 +341,7 @@ def main() -> int:
     from docqa_tpu.engines import generate as generate_mod
 
     cfg = load_config(env={}, overrides=program_overrides(conf))
+    package = arch.load(conf)
     seed31 = args.seed % (2**31)
 
     class SeededGenerateEngine(generate_mod.GenerateEngine):
@@ -376,7 +350,9 @@ def main() -> int:
         def __init__(self, dec_cfg, gen=None, mesh=None, params=None, **kw):
             if params is None:
                 t = time.monotonic()
-                params = weights.make_decoder_params(dec_cfg, seed31, mesh)
+                params = package.weights.make_decoder_params(
+                    dec_cfg, seed31, mesh
+                )
                 jax.block_until_ready(params)
                 state.mark("decoder_weights", t)
             kw.setdefault("seed", seed31)
@@ -415,7 +391,7 @@ def main() -> int:
 
     threading.Thread(target=watch_warmup, daemon=True).start()
     app = make_app(rt)
-    app.add_routes(make_routes(rt, state, conf, args.seed, stored, args.work))
+    app.add_routes(make_routes(rt, state, conf, package, args.seed, stored, args.work))
     try:
         web.run_app(app, host="127.0.0.1", port=args.port, print=None)
     finally:

@@ -113,7 +113,7 @@ class Connection:
                     event = line[6:].strip().decode()
                 elif line.startswith(b"data:"):
                     if event == "error":
-                        sample.failed = "sse_error:" + line[5:45].decode(
+                        sample.failed = "sse_error:" + line[5:165].decode(
                             errors="replace").strip()
                     elif event == "done":
                         ended = True

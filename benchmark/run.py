@@ -37,8 +37,7 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, BENCH_DIR)
 
-from harness import client, stats, traffic  # noqa: E402
-from harness.child import load_cell_config  # noqa: E402  (stdlib at import)
+from harness import arch, client, stats, traffic  # noqa: E402
 
 FIRST_RUN_BUDGET_S = 1150.0  # a run that compiles may take 1200 s
 
@@ -313,7 +312,12 @@ def main() -> int:
     )
     args = ap.parse_args()
     bench, cell, config = load_spec(args.workload)
-    conf = load_cell_config(os.path.join(ROOT, config["file"]), args.rehearsal)
+    try:
+        conf = arch.load_cell_config(
+            os.path.join(ROOT, config["file"]), args.rehearsal
+        )
+    except arch.ConfigError as e:
+        raise SystemExit(f"FAIL: {e}")
     mix = traffic.load(
         os.path.join(BENCH_DIR, "traffic", cell["traffic"] + ".json")
     )
@@ -360,11 +364,16 @@ def main() -> int:
     finally:
         child.stop()
 
-    for n in verdict.get("numbers", []):
-        say(f"compared {n['name']}: {n['value']:.6g} (limit {n['limit']})")
+    compared = [f"compared {n['name']}: {n['value']:.6g} (limit {n['limit']})"
+                for n in verdict.get("numbers", [])]
     if verdict.get("error"):
-        say(f"comparison error: {verdict['error']}")
+        compared.append(f"comparison error: {verdict['error']}")
+    for line in compared:
+        say(line)
     failed = [s for s in window if s.failed]
+    if failed:  # the program's own account of what it cut or refused
+        print(f"---- end of {child.log_path} ----\n{child.log_tail()}",
+              file=sys.stderr)
     reasons = {}
     for s in failed:
         reasons[s.failed] = reasons.get(s.failed, 0) + 1
@@ -432,6 +441,8 @@ def main() -> int:
             "idle_gaps": ctx["trace"]["idle_gaps"],
         }
     say(json.dumps(result))
+    # the driver's record of a run that is not correct keeps the end of this
+    print("\n".join(compared), file=sys.stderr, flush=True)
     return 0
 
 

@@ -18,7 +18,7 @@ sys.path.insert(0, BENCH_DIR)
 from readers import counter_ratio  # noqa: E402
 
 OVERLAY = os.path.join(HERE, "data", "tiny_overlay.json")
-NEW = {"admit_hold_p50_ms", "first_token_wait_p50_ms", "admit_drain_mean_ms",
+NEW = {"admit_wait_p50_ms", "first_token_wait_p50_ms", "admit_drain_mean_ms",
        "admit_batch_mean", "prefill_pad_share", "decode_tokens_per_chunk",
        "decode_stale_chunk_share"}
 
@@ -109,7 +109,7 @@ def test_rehearsal_values_are_what_the_counters_allow(traced):
     assert 0 <= m["decode_stale_chunk_share"] <= 100
     assert 1 <= m["admit_batch_mean"] <= 4  # four clients on four slots
     assert m["decode_tokens_per_chunk"] >= 0
-    for name in ("admit_hold_p50_ms", "first_token_wait_p50_ms",
+    for name in ("admit_wait_p50_ms", "first_token_wait_p50_ms",
                  "admit_drain_mean_ms"):
         assert m[name] >= 0, name
 

@@ -31,6 +31,9 @@ def child_env():
     return env
 
 
+STDERR = {}  # (workload, trace) -> what that rehearsal wrote there
+
+
 def run_cell(workload, trace, seconds="2", seed="4294967301"):
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH_DIR, "run.py"),
@@ -39,6 +42,7 @@ def run_cell(workload, trace, seconds="2", seed="4294967301"):
         cwd=ROOT, env=child_env(), capture_output=True, text=True, timeout=420,
     )
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    STDERR[workload, trace] = proc.stderr
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
 
 
@@ -63,6 +67,13 @@ def test_last_line_has_the_keys_the_driver_reads(rag_untraced):
     assert "compared retrieval_score_err" in out
 
 
+def test_the_compared_numbers_are_the_last_lines_of_standard_error(rag_untraced):
+    _result, out = rag_untraced
+    compared = [ln for ln in out.splitlines() if ln.startswith("compared ")]
+    assert len(compared) == 3
+    assert STDERR["rag_closed", "0"].strip().splitlines()[-3:] == compared
+
+
 def test_untraced_run_reports_the_cells_end_to_end_metrics(rag_untraced):
     result, _ = rag_untraced
     assert set(result["metrics"]) == {"ttft_p50_ms", "tpot_p50_ms", "setup_s"}
@@ -74,7 +85,7 @@ def test_traced_run_reports_per_layer_metrics_but_no_device_number_on_a_cpu(rag_
     result, out = rag_traced
     assert set(result) == RESULT_KEYS  # no breakdown either
     names = set(result["metrics"])
-    assert {"window_tok_s", "retrieve_mean_ms.gen", "queue_wait_p50_ms",
+    assert {"window_tok_s", "retrieve_mean_ms.gen", "admit_wait_p50_ms",
             "decode_batch_mean", "kv_pool_used_share",
             "spine_wait_mean_ms"} <= names
     # what only a device trace can say is never printed from a CPU run

@@ -2,6 +2,7 @@
 with the lower-precision control failing the same comparison."""
 
 import dataclasses
+import json
 import os
 import sys
 
@@ -9,13 +10,22 @@ import numpy as np
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)), "benchmark"))
+BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(HERE)), "benchmark")
+sys.path.insert(0, BENCH_DIR)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from docqa_tpu.config import DecoderConfig  # noqa: E402
-from harness import check, reference, weights  # noqa: E402
+from harness import arch, check  # noqa: E402
+from harness.weights import SCALE, act_int8  # noqa: E402
+
+with open(os.path.join(BENCH_DIR, "configs", "mistral-7b-int8.json"),
+          encoding="utf-8") as _f:
+    CONF = json.load(_f)
+MISTRAL = arch.load(CONF)  # the package the configuration's file names
+weights, reference = MISTRAL.weights, MISTRAL.reference
+CHECK = CONF["check"]  # the compared sizes PR 24's limits were read at
 
 TINY = DecoderConfig(
     vocab_size=512, hidden_dim=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -77,7 +87,7 @@ def test_the_sliding_window_masks_old_keys():
 def test_paged_path_passes_and_the_lower_precision_control_fails(seed, quantized):
     cfg = dataclasses.replace(TINY, quantize_weights=quantized, quant_bits=8)
     out = check.decoder_check(
-        engine_for(cfg, seed), seed, n_blocks=256, block_size=16,
+        MISTRAL, CHECK, engine_for(cfg, seed), seed, n_blocks=256, block_size=16,
         seq_capacity=1024, n_lanes=4, step_width=4, control=True,
     )
     assert out["program"]["worst_row"] < LIMIT, out
@@ -108,7 +118,7 @@ def test_an_activation_control_rounds_matmul_inputs_and_the_cache(name):
         np.testing.assert_allclose(np.abs(y).max(-1), np.abs(x).max(-1),
                                    rtol=1e-6)
     levels = np.unique(np.round(
-        np.asarray(weights.act_int8(x))[0, 0] / (np.abs(x[0, 0]).max() / 127)
+        np.asarray(act_int8(x))[0, 0] / (np.abs(x[0, 0]).max() / 127)
     ))
     assert len(levels) <= 255 and np.abs(levels).max() <= 127
 
@@ -118,8 +128,8 @@ def test_a_wrong_program_fails():
     program's tree (the reference keeps the right one) is caught."""
     cfg = dataclasses.replace(TINY, quantize_weights=False)
     engine = engine_for(cfg, 9)
-    ids, lengths = check.sample_prompts(9, cfg.vocab_size, 4, 8)
-    want = check.reference_logits(engine.params, cfg, ids, lengths, 9)
+    ids, lengths = check.sample_prompts(9, cfg.vocab_size, 4, 8, CHECK)
+    want = check.reference_logits(MISTRAL, engine.params, cfg, ids, lengths, 9)
     broken = dict(engine.params)
     broken["l1_wo"] = broken["l1_wo"] * 0.5
     engine.params = broken
@@ -132,7 +142,7 @@ def test_weights_follow_the_seed_and_the_served_types():
     a = weights.make_decoder_params(cfg, 1)
     b = weights.make_decoder_params(cfg, 1)
     c = weights.make_decoder_params(cfg, 2)
-    assert a["l0_wq"].dtype == jnp.int8 and a["l0_wq" + weights.SCALE].dtype == jnp.float32
+    assert a["l0_wq"].dtype == jnp.int8 and a["l0_wq" + SCALE].dtype == jnp.float32
     assert a["tok_emb"].dtype == jnp.bfloat16 and a["lm_head"].dtype == jnp.int8
     np.testing.assert_array_equal(np.asarray(a["l1_w_up"]), np.asarray(b["l1_w_up"]))
     assert not np.array_equal(np.asarray(a["l1_w_up"]), np.asarray(c["l1_w_up"]))
@@ -140,7 +150,7 @@ def test_weights_follow_the_seed_and_the_served_types():
 
     names = {n for n, *_ in decoder_param_schema(cfg)}
     assert names <= set(a) and all(
-        k in names or k.endswith(weights.SCALE) for k in a
+        k in names or k.endswith(SCALE) for k in a
     )
 
 

@@ -16,7 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH_DIR)
 
-from harness import traffic  # noqa: E402
+from harness import arch, traffic  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
     BENCH = json.load(_f)
@@ -28,6 +28,14 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = {w["name"]: w for w in BENCH["workloads"]}
 E2E = {m["name"]: m for m in BENCH["end_to_end"]}
 ALL_METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+PUBLISHED = {
+    "https://huggingface.co/mistralai/Mistral-7B-v0.1/blob/main/config.json": {
+        "hidden_size": 4096, "intermediate_size": 14336,
+        "num_attention_heads": 32, "num_key_value_heads": 8,
+        "head_dim": 128, "num_hidden_layers": 32, "vocab_size": 32000,
+        "sliding_window": 4096, "rope_theta": 10000.0,
+        "rms_norm_eps": 1e-05},
+}
 
 
 def cells_of(metric):
@@ -64,19 +72,25 @@ def test_configuration(config):
     assert any(w["config"] == config["name"] for w in BENCH["workloads"])
     with open(os.path.join(ROOT, config["file"]), encoding="utf-8") as f:
         conf = json.load(f)
-    # the published widths of Mistral-7B-v0.1; none may ever be reduced
-    widths = {"hidden_size": 4096, "intermediate_size": 14336,
-              "num_attention_heads": 32, "num_key_value_heads": 8,
-              "head_dim": 128, "num_hidden_layers": 32, "vocab_size": 32000,
-              "sliding_window": 4096, "rope_theta": 10000.0,
-              "rms_norm_eps": 1e-05}
+    # the published widths, by the source the entry claims: every file of
+    # Mistral-7B-v0.1 is held to them, and none may ever be reduced (a
+    # configuration of another source brings a test of its widths with it)
+    widths = PUBLISHED.get(config["source"], {})
     for key, value in widths.items():
         assert conf[key] == value, key
         assert key not in config["reduced"]
     for key in config["reduced"]:
         assert NAME.match(key) and not re.search(r"(_dim|_rank|_size)$", key)
-    for key in ("serving", "corpus", "assumed", "deployment", "correct", "chips"):
+    for key in ("architecture", "serving", "corpus", "assumed", "deployment",
+                "check", "correct", "chips"):
         assert key in conf, key
+    # the package the file names has the whole surface, and knows every
+    # published key of the file
+    block = arch.load(conf)
+    assert set(block.keys.program_overrides(conf)) >= {"decoder.dtype"}
+    assert set(conf["check"]) == {"prompt_lengths", "lane_rows"}
+    assert conf["check"]["lane_rows"] % 128 == 0
+    assert max(conf["check"]["prompt_lengths"]) < conf["check"]["lane_rows"]
     assert set(conf["correct"]) == {"decoder_logit_rel_err", "kv_cache_bits_missing",
                                     "retrieval_score_err"}
     assert conf["kv_cache_bits"] == 16 and conf["correct"]["kv_cache_bits_missing"] == 0

@@ -123,8 +123,8 @@ def test_polled_span_and_spine_readers():
     traces = {"t1": {"spans": [{"name": "serve_queue_wait", "duration_ms": 2.0},
                                {"name": "other", "duration_ms": 99.0}]},
               "t2": {"spans": [{"name": "serve_queue_wait", "duration_ms": 4.0}]}}
-    assert request_span.read({"request_traces": traces}, "serve_queue_wait", 50) == 3.0
-    assert request_span.read({}, "serve_queue_wait", 50) is None
+    assert request_span.read({"request_traces": traces}, ["serve_queue_wait"], 50) == 3.0
+    assert request_span.read({}, ["serve_queue_wait"], 50) is None
 
     def status(wait, count):
         return {"dispatch": {"spine": {"stages": {
@@ -134,6 +134,31 @@ def test_polled_span_and_spine_readers():
            "after": {"status": status(1.5, 300)}}
     assert spine_wait.read(ctx, ["serve_decode_chunk"]) == pytest.approx(2.5)
     assert spine_wait.read(ctx, ["absent"]) is None
+
+
+def _timeline(queue, hold, other=99.0):
+    spans = [{"name": "serve_first_token", "duration_ms": other}]
+    spans += [{"name": "serve_queue_wait", "duration_ms": q} for q in queue]
+    spans += [{"name": "serve_admit_hold", "duration_ms": h} for h in hold]
+    return {"spans": spans}
+
+
+@pytest.mark.parametrize("timelines, expected", [
+    # rag_closed since PR 26 (PERF.md): the request admitted ahead, two
+    # popped before the drain (hold ~670), one after it (queue ~667): each
+    # span's own median falls on either population, the sum does not
+    ([_timeline([0.3], [3.0]), _timeline([60.0], [670.0]),
+      _timeline([55.0], [671.0]), _timeline([667.0], [2.0])], 697.5),
+    # a bounced request holds twice: both count towards its wait
+    ([_timeline([5.0], [100.0, 20.0])], 125.0),
+    # a request that lacks one of the spans is left out, not half counted
+    ([_timeline([5.0], [10.0]), _timeline([700.0], [])], 15.0),
+    ([_timeline([], [])], None),
+], ids=["two_populations", "bounced", "a_span_missing", "nothing_to_read"])
+def test_request_span_sums_the_named_spans_per_request(timelines, expected):
+    ctx = {"request_traces": {f"t{i}": t for i, t in enumerate(timelines)}}
+    got = request_span.read(ctx, ["serve_queue_wait", "serve_admit_hold"], 50)
+    assert got == (None if expected is None else pytest.approx(expected))
 
 
 def test_every_seed_asks_the_same_templates_in_another_order():
