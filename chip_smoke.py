@@ -57,13 +57,11 @@ MAX_NEW_TOKENS = 64
 # 16384 tokens = 4 slots x 4096 positions: worst-case provisioning, no
 # request mix can exhaust it.
 KV_POOL_TOKENS = 16384
-# Decode slots.  The paged decode path gathers EVERY slot's whole
-# 4096-position cache per layer per step (XLA reference gather, ROADMAP
-# A4), so a step's cost grows with slots x positions whatever is live: at
-# the default 16 slots one 64-token ask took 5.1 s on a v5e and three of
-# four concurrent asks ran into the 8 s request deadline (chip run, PR 21;
-# PERF.md).  Four slots hold the four concurrent asks and keep a step's
-# attention traffic a quarter of that.
+# Decode slots.  Four hold the four concurrent asks, and the pool above is
+# provisioned for four: 16 slots x 4096 positions would be an 8.6 GB pool
+# beside 7.4 GB of weights, more than one 16 GB chip holds.  (Speed no
+# longer decides it: the paged decode kernel reads live pages only, so a
+# step's attention does not grow with slots that are empty — PERF.md.)
 DECODE_SLOTS = 4
 
 DOCUMENTS = [

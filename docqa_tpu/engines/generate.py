@@ -569,18 +569,28 @@ class GenerateEngine:
             return None
 
     def kernel_selfcheck(self) -> dict:
-        """The Pallas flash kernel against ``attention_reference`` ON THE
-        ATTACHED DEVICE, at the decode shapes the batcher dispatches
-        (q_len 1 and the speculative verify width) with this model's
-        head geometry, window and mesh — a seeded small input, lengths
-        and offsets included.  Raises when they disagree: a kernel that
-        compiles but computes something else must fail the warm-up, not
-        serve.  Tolerance: both sides round an O(1) output to bf16
-        (ulp 2^-7 below 2.0), so two roundings apart is the most an
-        agreeing pair can differ."""
+        """The Pallas kernels against their XLA references ON THE ATTACHED
+        DEVICE, at the decode shapes the engines dispatch (q_len 1 and the
+        speculative verify width) with this model's head geometry, window
+        and mesh — seeded small inputs, lengths and offsets included:
+
+        * ``flash_attention`` (the dense cache of the solo engine)
+          against ``attention_reference``;
+        * ``paged_flash_decode`` (what the batcher's decode program
+          serves) through a SCATTERED block table — ragged lengths, a
+          page boundary, a free lane, hole entries past each length —
+          against the gather reference, when the kernel reads this
+          geometry (``paged_kernel_supported``).
+
+        Raises when a pair disagrees: a kernel that compiles but computes
+        something else must fail the warm-up, not serve.  Tolerance: both
+        sides round an O(1) output to bf16 (ulp 2^-7 below 2.0), so two
+        roundings apart is the most an agreeing pair can differ."""
         from docqa_tpu.ops.attention import (
             attention_reference,
             flash_attention,
+            paged_decode_attention,
+            paged_kernel_supported,
         )
 
         cfg, tol = self.cfg, 2.0 ** -6
@@ -592,35 +602,81 @@ class GenerateEngine:
         )
         q_lens = sorted({1, max(self.gen.speculative_k, 1)})
 
-        def _check_on_lane() -> float:
-            rng = np.random.default_rng(0)
-            worst = 0.0
-            for sq in q_lens:
-                q, k, v = (
-                    jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
-                    for shape in (
-                        (b, sq, cfg.num_heads, cfg.head_dim),
-                        (b, skv, cfg.num_kv_heads, cfg.head_dim),
-                        (b, skv, cfg.num_kv_heads, cfg.head_dim),
-                    )
-                )
-                lengths = jnp.full((b,), skv - 83, jnp.int32)
-                got = kernel(q, k, v, lengths=lengths, q_offset=lengths - sq)
-                with jax.default_matmul_precision("highest"):
-                    want = attention_reference(
-                        q, k, v, lengths=lengths, q_offset=lengths - sq, **kw
-                    )
-                err = jnp.max(jnp.abs(
-                    got.astype(jnp.float32) - want.astype(jnp.float32)
-                ))
-                worst = max(worst, float(err))
-            return worst
+        # the paged pair: 4 lanes per data shard over a 64-page pool, 16
+        # table entries a lane; lengths end inside a page, on a page
+        # boundary, at 0 (free lane: all holes) and at the table's span
+        block_size, n_pages, per_lane = self.gen.kv_block_size, 64, 16
+        paged = paged_kernel_supported(
+            dtype, cfg.num_kv_heads, cfg.head_dim, self.mesh
+        )
+        lane_lens = np.tile(
+            np.array([block_size * 5 - 3, block_size * 4, 0,
+                      block_size * per_lane], np.int32), b,
+        )
+        paged_kw = dict(
+            block_size=block_size, sliding_window=cfg.sliding_window,
+        )
+        paged_kernel = jax.jit(functools.partial(
+            paged_decode_attention, use_flash=True, mesh=self.mesh, **paged_kw
+        ))
+        def draw(rng, *shape):
+            return jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
 
-        err = spine_run("kernel_check", _check_on_lane, stream="probe")
+        def err_of(got, want) -> float:
+            return float(jnp.max(jnp.abs(
+                got.astype(jnp.float32) - want.astype(jnp.float32)
+            )))
+
+        def dense_err(rng, sq: int) -> float:
+            q = draw(rng, b, sq, cfg.num_heads, cfg.head_dim)
+            k = draw(rng, b, skv, cfg.num_kv_heads, cfg.head_dim)
+            v = draw(rng, b, skv, cfg.num_kv_heads, cfg.head_dim)
+            lengths = jnp.full((b,), skv - 83, jnp.int32)
+            got = kernel(q, k, v, lengths=lengths, q_offset=lengths - sq)
+            with jax.default_matmul_precision("highest"):
+                want = attention_reference(
+                    q, k, v, lengths=lengths, q_offset=lengths - sq, **kw
+                )
+            return err_of(got, want)
+
+        def paged_err(rng, sq: int) -> float:
+            # pages handed out in a shuffled order, so every lane's table
+            # is scattered and out of order; the rest are holes
+            tables = np.full((len(lane_lens), per_lane), n_pages, np.int32)
+            pages = iter(rng.permutation(n_pages))
+            for lane, n in enumerate(lane_lens):
+                for i in range(-(-int(n) // block_size)):
+                    tables[lane, i] = next(pages)
+            pool = (n_pages * block_size, cfg.num_kv_heads, cfg.head_dim)
+            args = (
+                draw(rng, len(lane_lens), sq, cfg.num_heads, cfg.head_dim),
+                draw(rng, *pool), draw(rng, *pool),
+                jnp.asarray(tables), jnp.asarray(lane_lens),
+            )
+            q_offset = jnp.maximum(jnp.asarray(lane_lens) - sq, 0)
+            got = paged_kernel(*args, q_offset=q_offset)
+            with jax.default_matmul_precision("highest"):
+                want = paged_decode_attention(
+                    *args, q_offset=q_offset, **paged_kw
+                )
+            return err_of(got, want)
+
+        def _check_on_lane() -> Tuple[float, float]:
+            rng = np.random.default_rng(0)
+            return (
+                max(dense_err(rng, sq) for sq in q_lens),
+                max(paged_err(rng, sq) for sq in q_lens) if paged else 0.0,
+            )
+
+        err, err_paged = spine_run(
+            "kernel_check", _check_on_lane, stream="probe"
+        )
         report = {"max_abs_err": err, "tolerance": tol, "q_lens": q_lens}
-        if not err <= tol:  # NaN fails too
+        if paged:
+            report["paged_max_abs_err"] = err_paged
+        if not (err <= tol and err_paged <= tol):  # NaN fails too
             raise AssertionError(
-                f"flash kernel disagrees with reference: {report}"
+                f"a Pallas kernel disagrees with its reference: {report}"
             )
         return report
 

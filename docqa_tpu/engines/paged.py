@@ -589,8 +589,10 @@ def init_paged_pools(
 ) -> PagedPools:
     """Flat per-layer K/V block pools: [n_blocks * block_size, kv_heads,
     head_dim].  Row ``b * block_size + o`` is offset ``o`` of block ``b``
-    — the one flat axis both the prefill scatter and the decode gather
-    index, so a block id IS a row range.
+    — the one flat axis the prefill scatter, the decode write and the
+    decode read index, so a block id IS a row range: ``block_size``
+    consecutive rows, one contiguous page the TPU decode kernel DMAs as a
+    whole (``ops/attention.paged_flash_decode``).
 
     ``sharding`` (``parallel.sharding.paged_pool_sharding`` on a mesh):
     each pool is CREATED under it — every device zero-fills only its own
@@ -696,10 +698,12 @@ def paged_decode_forward(
     block_size: int,
     rope_len: int,
     use_flash: bool = False,
-    mesh=None,  # MeshContext: the flash kernel shards over it (ops/attention)
+    mesh=None,  # MeshContext: the paged kernel shards over it (ops/attention)
 ):
     """Advance every lane ``s`` tokens against the block pool: write each
-    new token's K/V at its table-mapped row, attend through the table.
+    new token's K/V at its table-mapped row (in place: a scatter of
+    ``S x s`` rows into the donated pool), attend through the table —
+    with ``use_flash`` by a kernel that reads only each lane's live pages.
 
     Writes whose position falls past a lane's allocated blocks (hole
     entries / retired lanes whose table row went sentinel) are DROPPED —

@@ -108,7 +108,7 @@ from docqa_tpu.engines.spine import spine_run, spine_submit
 from docqa_tpu.models.decoder import (
     init_decoder_params,  # noqa: F401  (re-export convenience for tests)
 )
-from docqa_tpu.ops.attention import RAGGED_ALIGN
+from docqa_tpu.ops.attention import RAGGED_ALIGN, paged_kernel_supported
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.resilience import faults
 from docqa_tpu.resilience.deadline import Deadline, DeadlineExceeded
@@ -165,6 +165,13 @@ class _Request:
     # the session-affinity routing key in engines/pool.py.  None =
     # always-cold (canaries, bulk tools, foreign prompts).
     prefix_key: Optional[str] = None
+    # prompt tokens the lane was admitted with (worker-written at
+    # admission; it stays true after the slot is handed on): with the
+    # delivered-token count this is the worker's host-side KV length of
+    # the lane — it drives grow-at-decode and the block-occupancy
+    # gauges, and says what a chunk's steps had to read
+    # (``_chunk_kv_rows``)
+    kv_prompt: int = 0
     # per-class cost attribution (docqa-costscope; obs/costs.py): the
     # request's CostRecord — queue wait, prefill/decode device-ms, KV
     # block-seconds all land here; retired exactly once at _finish.
@@ -615,12 +622,6 @@ class ContinuousBatcher:
         # host-side slot bookkeeping
         self._slot_req: List[Optional[_Request]] = [None] * self.n_slots
         self._slot_budget = np.zeros((self.n_slots,), np.int64)
-        # prompt tokens each occupied slot was admitted with: with the
-        # delivered-token count this is the worker's host-side length
-        # estimate, driving grow-at-decode and the block-occupancy
-        # gauges.  Read only where _slot_req is non-None (freed slots
-        # keep stale values), worker-written like _slot_budget.
-        self._slot_prompt = [0] * self.n_slots
         # per-request block tables + their device mirror.  _block_rows
         # holds the flat [n_slots, blocks_per_seq] int32 table the decode
         # program indexes (sentinel n_blocks = hole); it re-uploads only
@@ -712,6 +713,14 @@ class ContinuousBatcher:
         # Mosaic custom calls in the lowered decode program, counted by
         # annotate_costs (None until then; 0 = XLA reference attention)
         self.decode_kernel_calls: Optional[int] = None
+        # which attention the decode program was built with: the kernel
+        # reads live pages in place, the reference gathers every table
+        self._pages_read_in_place = self.engine.use_flash and (
+            paged_kernel_supported(
+                self.cfg.dtype, self.cfg.num_kv_heads, self.cfg.head_dim,
+                self.mesh,
+            )
+        )
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="continuous-batcher"
         )
@@ -1691,7 +1700,7 @@ class ContinuousBatcher:
         for slot in range(self.n_slots):
             req = self._slot_req[slot]
             if req is not None:
-                tokens += self._slot_prompt[slot] + len(req.tokens)
+                tokens += req.kv_prompt + len(req.tokens)
         out = {
             "blocks_total": self.n_blocks,
             "blocks_used": used,
@@ -2195,10 +2204,10 @@ class ContinuousBatcher:
             )
             self._slot_req[slot] = req
             self._slot_budget[slot] = budget
-            # subtract resumed tokens so _slot_prompt + len(req.tokens)
+            # subtract resumed tokens so kv_prompt + len(req.tokens)
             # stays the lane's exact KV length (grow estimates and the
             # occupancy gauges depend on that identity)
-            self._slot_prompt[slot] = n_ids - resumed
+            req.kv_prompt = n_ids - resumed
             self._slot_table[slot] = table
             row = self._block_rows[slot]
             row[:] = self.n_blocks
@@ -2666,9 +2675,20 @@ class ContinuousBatcher:
         deactivate = []
         n_appended = 0
         n_held = 0  # snapshot slots whose request is still the occupant
+        # (KV length at dispatch, positions advanced) per snapshot lane:
+        # what the chunk's steps read (serve_decode_kv_rows_*)
+        lanes = []
         for slot in range(self.n_slots):
             req = snap[slot]
-            if req is None or self._slot_req[slot] is not req:
+            if req is None:
+                continue
+            # every token delivered so far but the newest has its K/V
+            # written; a lane retired in flight advanced nothing it kept
+            lanes.append((
+                req.kv_prompt + max(len(req.tokens) - 1, 0),
+                int(valid_h[slot].sum()),
+            ))
+            if self._slot_req[slot] is not req:
                 continue
             n_held += 1
             before = len(req.tokens)
@@ -2736,6 +2756,9 @@ class ContinuousBatcher:
             float(n_appended)
         )
         DEFAULT_REGISTRY.counter("serve_decode_chunks").inc()
+        rows_read, rows_live = self._chunk_kv_rows(lanes)
+        DEFAULT_REGISTRY.counter("serve_decode_kv_rows_read").inc(rows_read)
+        DEFAULT_REGISTRY.counter("serve_decode_kv_rows_live").inc(rows_live)
         if not n_held:
             # every lane the chunk advanced had retired by the time it
             # was fetched (the overshoot chunk dispatched ahead): device
@@ -2746,6 +2769,38 @@ class ContinuousBatcher:
             # — the worker never issues device ops from its own thread
             self._deact_pending.extend(deactivate)
         return True
+
+    def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
+        """(KV rows fetched, KV rows live) PER LAYER over one chunk's
+        steps, as the decode program is built — host arithmetic on what
+        ``_process_chunk`` holds, no device fetch.
+
+        ``lanes``: per lane of the chunk's snapshot, (KV length at
+        dispatch, positions it advanced).  A step attends ``width`` new
+        positions (1, or ``speculative_k``) past what the lane has
+        advanced so far; a lane that stopped keeps attending where it
+        stood.  Live rows are those lengths summed over lanes and steps.
+        Fetched rows: under the paged kernel the live PAGES of each lane
+        (``ceil(len / block_size)`` of them); under the gather reference
+        every slot's whole block table, live or not, each step.  The
+        plain program runs ``chunk`` steps; the speculative one loops on
+        the device until every lane has emitted a chunk, which the host
+        does not see — counted as its fewest possible forwards."""
+        width = max(self.spec_k, 1)
+        steps = self.chunk
+        if self.spec_k:
+            most = max((adv for _, adv in lanes), default=0)
+            steps = max(-(-most // width), 1)
+        read = live = 0
+        for length, adv in lanes:
+            lens = length + width + np.minimum(
+                np.arange(steps) * width, adv
+            )
+            live += int(lens.sum())
+            read += int((-(-lens // self.block_size)).sum()) * self.block_size
+        if not self._pages_read_in_place:
+            read = steps * self.n_slots * self.seq_capacity
+        return read, live
 
     def _blocks_for_admission(self, req: "_Request") -> int:
         """FRESH blocks an admission would allocate for ``req`` (prompt
@@ -3092,7 +3147,7 @@ class ContinuousBatcher:
                 table = self._slot_table[slot]
                 if req is None or table is None:
                     continue
-                est = self._slot_prompt[slot] + len(req.tokens)
+                est = req.kv_prompt + len(req.tokens)
                 target = min(est + self._grow_margin, self.seq_capacity)
                 if table.capacity >= target:
                     continue

@@ -14,6 +14,12 @@ Layouts:
 
 The dispatcher :func:`attention` picks the Pallas kernel on TPU and the pure
 XLA path elsewhere (CPU tests run the kernel in interpret mode explicitly).
+
+Paged KV (``engines/paged.py``): K and V live in flat block pools
+``[n_blocks * block_size, num_kv_heads, head_dim]`` addressed through block
+tables.  :func:`paged_decode_attention` is the decode step's attention over
+them: on a TPU the Pallas kernel :func:`paged_flash_decode`, which DMAs each
+lane's live pages out of the pool; elsewhere the gather reference.
 """
 
 from __future__ import annotations
@@ -274,6 +280,12 @@ def gather_paged_kv(pool, block_tables, block_size):
     that sequence's token-position p, exactly the layout a dense
     per-lane cache would have — so downstream attention reductions are
     bitwise identical to the contiguous-cache path.
+
+    This is the REFERENCE, not the served path: it copies every slot's
+    whole block table, allocated or not, so its cost is the table's span
+    (S x NB x block_size rows per pool), whatever is live.  On a TPU the
+    decode step reads pages in place (:func:`paged_flash_decode`); this
+    backs the CPU path, the tests and the warm-up self-check.
     """
     S, nb = block_tables.shape
     L = nb * block_size
@@ -288,25 +300,34 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            block_size, q_offset=None, sliding_window=None,
                            scale=None, use_flash=False, mesh=None):
     """Decode-side attention through a block table (the decode half of
-    Ragged Paged Attention).  XLA reference path: gather the pages into a
-    per-sequence contiguous view, then run the standard masked kernel —
-    a TPU Pallas kernel would stream pages without materializing the
-    gather; this backs it the same way :func:`attention_reference` backs
-    :func:`flash_attention`.
+    Ragged Paged Attention).
+
+    ``use_flash`` (derived from the backend, ``engines/generate.py``):
+    the Pallas kernel :func:`paged_flash_decode` reads each lane's live
+    pages straight out of the pool — nothing of the table's span or the
+    pool's size is gathered, transposed or fetched.  Otherwise (every CPU
+    run, and a head geometry the kernel does not read —
+    :func:`paged_kernel_supported`) the XLA reference: gather the pages into a per-sequence
+    contiguous view, then the standard masked attention — the plain
+    reference the kernel is tested against, the same way
+    :func:`attention_reference` backs :func:`flash_attention`.
 
     q            [S, s, q_heads, d] (s = 1 plain step, K spec verify)
     k/v_pool     [P, kv_heads, d] flat block pool
     block_tables [S, NB] int32
     lengths      [S] valid kv length per sequence AFTER this step
     """
+    if use_flash and paged_kernel_supported(
+        k_pool.dtype, k_pool.shape[1], k_pool.shape[2], mesh
+    ):
+        return paged_flash_decode(
+            q, k_pool, v_pool, block_tables, lengths, q_offset,
+            block_size=block_size, sliding_window=sliding_window,
+            scale=scale, mesh=mesh,
+        )
     k = gather_paged_kv(k_pool, block_tables, block_size)
     v = gather_paged_kv(v_pool, block_tables, block_size)
-    attn_fn = (
-        functools.partial(flash_attention, mesh=mesh)
-        if use_flash
-        else attention_reference
-    )
-    return attn_fn(
+    return attention_reference(
         q, k, v, causal=True, lengths=lengths, q_offset=q_offset,
         sliding_window=sliding_window, scale=scale,
     )
@@ -531,6 +552,383 @@ def flash_attention(
 
     out = out.reshape(b, hq, sq_p, d).transpose(0, 2, 1, 3)
     return out[:, :sq]
+
+
+# --------------------------------------------------------------------------
+# Pallas paged decode kernel
+# --------------------------------------------------------------------------
+
+# KV rows one compute block of the paged kernel covers (a whole number of
+# pages): the double-buffered VMEM window a lane's live pages stream
+# through; 512 rows x 8 kv heads x 128 x bf16 = 1 MiB per buffer, 4 MiB
+# for K and V twice.  Of 128 / 256 / 512 the largest was the fastest at
+# every length on a v5e (24.0 us a layer at 4 lanes x ~360 rows, 133.9 at
+# 4 x 4096, 84.5 at 16 x ~360 — chip run, PR 29; PERF.md): a lane of a few
+# hundred rows is one block, and a long one pays fewer loop turns.
+PAGED_BLOCK_ROWS = 512
+
+
+def paged_kernel_supported(pool_dtype, kv_heads, head_dim, mesh=None) -> bool:
+    """Whether :func:`paged_flash_decode` reads a pool of this geometry:
+    Mosaic's strided sublane read (one kv head out of a page's
+    token-major rows) needs a 128-wide minor axis and 32-bit words, so a
+    16-bit pool is read as packed head PAIRS — 1 or an even number of kv
+    heads per device.  Anything else stays on the XLA reference."""
+    if mesh is not None and mesh.n_devices > 1:
+        if kv_heads % mesh.n_model:
+            return False
+        kv_heads //= mesh.n_model
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    return head_dim == 128 and (
+        itemsize == 4 or (itemsize == 2 and (kv_heads == 1 or kv_heads % 2 == 0))
+    )
+
+
+def _paged_decode_kernel(
+    # scalar prefetch
+    tables_ref,  # [S, NB] int32 block ids; entries >= n_blocks are holes
+    lengths_ref,  # [S] int32 valid (and allocated) kv length
+    qoff_ref,  # [S] int32 absolute position of q row 0
+    # blocks
+    q_ref,  # [1, hkv, s * groups, d]: row r of a kv head is q position r // groups
+    k_hbm,  # [n_blocks, block_size * hkv, d] — the layer's pool, left in HBM
+    v_hbm,
+    o_ref,  # [1, hkv, s * groups, d]
+    # scratch
+    k_buf,  # [2, ppb * block_size * hkv, d] double-buffered pages (token-major)
+    v_buf,
+    sems,  # DMA semaphores [k | v, buffer]
+    first_buf_ref,  # [1] int32 (SMEM): the buffer this lane's first block is in
+    m_ref,  # [hkv, s * groups, 128] f32 running max (lane-replicated)
+    l_ref,  # [hkv, s * groups, 128] f32 running denom
+    acc_ref,  # [hkv, s * groups, d] f32
+    *,
+    block_size: int,
+    groups: int,
+    sliding_window: Optional[int],
+    scale: float,
+):
+    """One grid step = one lane: stream its LIVE pages (from the first one
+    the sliding window can still see) through VMEM a compute block at a
+    time, every kv head of a page in one contiguous DMA, and run
+    ``_flash_kernel``'s online softmax per kv head on them — the q heads
+    of a group share the head's rows.  While a block is computed the next
+    one is in flight: the lane's next block, or, from its last block, the
+    NEXT lane's first (the buffers and semaphores outlive a grid step).
+    A lane of length 0 issues no DMA and writes zeros."""
+    lane = pl.program_id(0)
+    n_lanes = pl.num_programs(0)
+    n_blocks, page_rows, d = k_hbm.shape
+    hkv = page_rows // block_size
+    ppb = k_buf.shape[1] // page_rows
+    gq = q_ref.shape[2]
+    rows = ppb * block_size  # kv positions a compute block covers
+
+    def blocks_of(ln):
+        """(first block, end block, live pages) of lane ``ln``."""
+        n_pages = pl.cdiv(lengths_ref[ln], block_size)
+        first = 0
+        if sliding_window is not None:
+            first = jnp.maximum(qoff_ref[ln] - sliding_window + 1, 0) // rows
+        return first, pl.cdiv(n_pages, ppb), n_pages
+
+    def for_live_pages(ln, j, buf, act):
+        n_pages = blocks_of(ln)[2]
+
+        def one(i, carry):
+            @pl.when(j * ppb + i < n_pages)
+            def _():
+                # a hole can only lie past the length (the wrapper clamps
+                # it to the allocated pages); the clamp keeps the DMA in
+                # bounds even if a caller breaks that
+                page = jnp.minimum(tables_ref[ln, j * ppb + i], n_blocks - 1)
+                dst = pl.ds(i * page_rows, page_rows)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[buf, dst], sems.at[0, buf]
+                ))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[page], v_buf.at[buf, dst], sems.at[1, buf]
+                ))
+
+            return carry
+
+        jax.lax.fori_loop(0, ppb, one, 0)
+
+    def fetch(ln, j, buf):
+        for_live_pages(ln, j, buf, lambda copy: copy.start())
+
+    def fetch_first_of_next_lane(buf):
+        @pl.when(lane + 1 < n_lanes)
+        def _():
+            first, end, _ = blocks_of(lane + 1)
+
+            @pl.when(first < end)
+            def _():
+                fetch(lane + 1, first, buf)
+
+    kv_len = lengths_ref[lane]
+    q_off = qoff_ref[lane]
+    j0, j1, _ = blocks_of(lane)
+
+    @pl.when(lane == 0)
+    def _first_lane():
+        # a block's tail pages past the lane's length are never fetched.
+        # What K holds there is masked out of the scores, but in V a
+        # probability of exactly 0 times a NaN bit pattern would still
+        # poison the accumulator — so nothing but zeros and fetched
+        # (finite) rows is ever in the V buffers
+        v_buf[...] = jnp.zeros_like(v_buf)
+        first_buf_ref[0] = 0
+
+        @pl.when(j0 < j1)
+        def _():
+            fetch(lane, j0, 0)
+
+    first_buf = first_buf_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q_rows = jax.lax.broadcasted_iota(jnp.int32, (gq, rows), 0)
+    kv_cols = jax.lax.broadcasted_iota(jnp.int32, (gq, rows), 1)
+    q_abs = q_off + q_rows // groups
+
+    def head_rows(buf_ref, buf, h):
+        """[rows, d] f32: kv head ``h`` of every token in the buffer.  A
+        token's heads are consecutive buffer rows, so a head is a strided
+        sublane read — which Mosaic has for 32-bit data only.  bf16 rows
+        are read as packed pairs (row 2i in the low half of a word, 2i+1
+        in the high half) and widened by a shift or a mask: exactly the
+        bf16 value as float32."""
+        ref = buf_ref.at[buf]
+        if hkv == 1:
+            return ref[...].astype(jnp.float32)
+        if jnp.dtype(ref.dtype).itemsize == 4:
+            return ref[pl.ds(h, rows, stride=hkv), :].astype(jnp.float32)
+        words = ref.bitcast(jnp.uint32)[pl.ds(h // 2, rows, stride=hkv // 2), :]
+        if h % 2:
+            words = words & jnp.uint32(0xFFFF0000)
+        else:
+            words = words << 16
+        return pltpu.bitcast(words, jnp.float32)
+
+    def compute_block(j, carry):
+        buf = (first_buf + j - j0) % 2
+
+        @pl.when(j + 1 < j1)
+        def _():
+            fetch(lane, j + 1, 1 - buf)
+
+        @pl.when(j + 1 == j1)
+        def _():
+            fetch_first_of_next_lane(1 - buf)
+
+        for_live_pages(lane, j, buf, lambda copy: copy.wait())
+
+        kv_pos = j * rows + kv_cols
+        mask = (kv_pos < kv_len) & (kv_pos <= q_abs)
+        if sliding_window is not None:
+            mask &= kv_pos > q_abs - sliding_window
+
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # [gq, d]
+            k = head_rows(k_buf, buf, h)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [gq, rows]
+            s = jnp.where(mask, s, NEG_INF)
+
+            m_prev = m_ref[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # explicit re-mask, as in _flash_kernel: in a fully-masked
+            # block m_new == NEG_INF and exp(s - m_new) would be 1
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_ref[h, :, :1] + jnp.sum(
+                p, axis=-1, keepdims=True
+            )
+            pv = jax.lax.dot_general(
+                p, head_rows(v_buf, buf, h), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [gq, d]
+            acc_ref[h] = acc_ref[h] * alpha + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        return carry
+
+    jax.lax.fori_loop(j0, j1, compute_block, 0)
+
+    # where the next lane's first block is (being) fetched: the buffer
+    # after this lane's last block — by this lane if it had none
+    n_computed = jnp.maximum(j1 - j0, 0)
+    next_first_buf = (first_buf + n_computed) % 2
+
+    @pl.when(n_computed == 0)
+    def _():
+        fetch_first_of_next_lane(next_first_buf)
+
+    first_buf_ref[0] = next_first_buf
+
+    denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
+    o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def paged_flash_decode(
+    q,
+    k_pool,
+    v_pool,
+    block_tables,
+    lengths,
+    q_offset=None,
+    *,
+    block_size: int,
+    sliding_window: Optional[int] = None,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+    mesh=None,
+):
+    """Causal decode attention that reads a lane's live pages IN PLACE.
+
+    q            [S, s, q_heads, d] (s = 1 plain step, K spec verify —
+                 read from the shape)
+    k/v_pool     [P, kv_heads, d] flat block pool; stays in HBM, viewed as
+                 [n_blocks, block_size * kv_heads, d] (a free reshape: a
+                 page is ``block_size`` consecutive rows)
+    block_tables [S, NB] int32; entries >= n_blocks are holes
+    lengths      [S] valid kv length per lane AFTER this step
+    q_offset     [S] absolute position of q[:, 0] (default lengths - s)
+
+    Grid: one step per lane.  Per lane the kernel loops over
+    ``ceil(len / block_size)`` pages only, ``PAGED_BLOCK_ROWS`` kv
+    positions at a time, double-buffered with one DMA per page that
+    carries every kv head of its 16 tokens (the way
+    ``jax.experimental.pallas.ops.tpu.ragged_paged_attention`` does);
+    the arithmetic is ``_flash_kernel``'s (f32 scores, running max,
+    denominator and accumulator; bf16 in and out; the same masks).  A
+    length is clamped to the lane's ALLOCATED pages, so a hole is never
+    dereferenced: a free slot or a retired lane (all-hole table row)
+    issues no DMA and outputs zeros, where the gather reference would
+    attend to a clamped garbage row — only ever for lanes nobody reads.
+
+    ``mesh``: under ``shard_map`` exactly as :func:`flash_attention` —
+    kv heads over the model axis (the pool's own sharding), lanes over
+    the data axis."""
+    S, s, hq, d = q.shape
+    n_rows, hkv, _ = k_pool.shape
+    if hq % hkv:
+        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
+    scale = scale if scale is not None else d ** -0.5
+    if q_offset is None:
+        q_offset = lengths - s
+    if mesh is not None and mesh.n_devices > 1:
+        if hkv % mesh.n_model or S % mesh.n_data:
+            raise ValueError(
+                f"paged_flash_decode on a {mesh.n_data}x{mesh.n_model} mesh "
+                f"needs kv heads ({hkv}) divisible by the model axis and "
+                f"lanes ({S}) by the data axis"
+            )
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        local = functools.partial(
+            paged_flash_decode, block_size=block_size,
+            sliding_window=sliding_window, scale=scale, interpret=interpret,
+        )
+        heads = P(mesh.data_axis, None, mesh.model_axis, None)
+        pool = P(None, mesh.model_axis, None)
+        lanes = P(mesh.data_axis)
+        return shard_map(
+            local,
+            mesh=mesh.mesh,
+            in_specs=(heads, pool, pool, P(mesh.data_axis, None), lanes, lanes),
+            out_specs=heads,
+            check_vma=False,
+        )(q, k_pool, v_pool, block_tables, lengths, q_offset)
+    if not paged_kernel_supported(k_pool.dtype, hkv, d):
+        raise NotImplementedError(
+            f"paged_flash_decode does not read a {k_pool.dtype} pool of "
+            f"{hkv} kv heads x {d} (paged_kernel_supported)"
+        )
+    return _paged_attend_local(
+        q, k_pool, v_pool, block_tables, lengths, q_offset,
+        block_size=block_size, sliding_window=sliding_window, scale=scale,
+        block_rows=PAGED_BLOCK_ROWS, interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "block_size", "sliding_window", "scale", "block_rows", "interpret",
+    ),
+)
+def _paged_attend_local(q, k_pool, v_pool, block_tables, lengths, q_offset,
+                        *, block_size, sliding_window, scale, block_rows,
+                        interpret):
+    """One device's share of :func:`paged_flash_decode`.  Jitted so that a
+    decode program traces and lowers the kernel ONCE and calls it from
+    each of its layers — inlined by XLA, no program of its own (a
+    32-layer program otherwise traces and serializes the Mosaic body 32
+    times at every warm-up)."""
+    S, s, hq, d = q.shape
+    n_rows, hkv, _ = k_pool.shape
+    groups = hq // hkv
+    n_blocks = n_rows // block_size
+    nb = block_tables.shape[1]
+    ppb = max(1, min(block_rows // block_size, nb))
+    buf_rows = ppb * block_size * hkv
+
+    # never past the allocated pages (holes fill a table row's tail)
+    allocated = jnp.sum(
+        (block_tables < n_blocks).astype(jnp.int32), axis=1
+    ) * block_size
+    kv_len = jnp.minimum(lengths.astype(jnp.int32), allocated)
+
+    # [S, s, hq, d] -> [S, hkv, s * groups, d]; a reshape when s == 1
+    qr = q.reshape(S, s, hkv, groups, d).transpose(0, 2, 1, 3, 4)
+    qr = qr.reshape(S, hkv, s * groups, d)
+    page_shape = (n_blocks, block_size * hkv, d)
+
+    kernel = functools.partial(
+        _paged_decode_kernel,
+        block_size=block_size,
+        groups=groups,
+        sliding_window=sliding_window,
+        scale=scale,
+    )
+    lane_block = pl.BlockSpec(
+        (1, hkv, s * groups, d), lambda i, *_: (i, 0, 0, 0)
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[
+                lane_block,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=lane_block,
+            scratch_shapes=[
+                pltpu.VMEM((2, buf_rows, d), k_pool.dtype),
+                pltpu.VMEM((2, buf_rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((hkv, s * groups, 128), jnp.float32),
+                pltpu.VMEM((hkv, s * groups, 128), jnp.float32),
+                pltpu.VMEM((hkv, s * groups, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, hkv, s * groups, d), q.dtype),
+        interpret=interpret, name="_paged_decode_kernel",
+    )(
+        block_tables.astype(jnp.int32), kv_len, q_offset.astype(jnp.int32),
+        qr, k_pool.reshape(page_shape), v_pool.reshape(page_shape),
+    )
+    out = out.reshape(S, hkv, s, groups, d).transpose(0, 2, 1, 3, 4)
+    return out.reshape(S, s, hq, d)
 
 
 # --------------------------------------------------------------------------

@@ -1,0 +1,384 @@
+"""The paged decode kernel (ISSUE 29): ``paged_flash_decode`` reads a lane's
+live pages out of the pool in place.
+
+* in interpret mode against the plain reference (``gather_paged_kv`` +
+  ``attention_reference``): lengths around a page boundary, scattered and
+  shared pages, holes, both step widths, a sliding window, GQA and MHA;
+* structurally: the traced decode attention holds no value with the block
+  tables' span of rows (no gather, no transpose of the span);
+* the counters ``serve_decode_kv_rows_read`` / ``_live`` as defined, on
+  both paths, through ``_process_chunk``.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+# ``docqa_tpu.ops`` re-exports a FUNCTION named ``attention``
+A = importlib.import_module("docqa_tpu.ops.attention")
+
+BS = 16  # block_size
+N_PAGES = 96
+NB = 32  # table entries per lane: a full table is 512 positions
+TOL = 2.0 ** -6  # two bf16 roundings of an O(1) output, as kernel_selfcheck
+
+
+def _pool(rng, hkv, d, dtype):
+    return jnp.asarray(
+        rng.standard_normal((N_PAGES * BS, hkv, d), np.float32), dtype
+    )
+
+
+def _tables(rng, lengths, shared_pages=0):
+    """Scattered, out-of-order pages per lane; the tail of every row is
+    holes (``>= N_PAGES``, a different sentinel per entry so a dereference
+    could not go unnoticed).  ``shared_pages``: lanes 0 and 1 share their
+    first pages (a prefix hit)."""
+    tables = N_PAGES + rng.integers(0, 1000, (len(lengths), NB)).astype(np.int32)
+    pages = iter(rng.permutation(N_PAGES))
+    for lane, n in enumerate(lengths):
+        for i in range(-(-int(n) // BS)):
+            tables[lane, i] = next(pages)
+    if shared_pages:
+        tables[1, :shared_pages] = tables[0, :shared_pages]
+    return tables
+
+
+def _compare(lengths, *, s=1, hq=32, hkv=8, d=128, window=None,
+             dtype=jnp.bfloat16, shared_pages=0, seed=0):
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths, np.int32)
+    k_pool, v_pool = _pool(rng, hkv, d, dtype), _pool(rng, hkv, d, dtype)
+    tables = jnp.asarray(_tables(rng, lengths, shared_pages))
+    q = jnp.asarray(
+        rng.standard_normal((len(lengths), s, hq, d), np.float32), dtype
+    )
+    q_offset = jnp.asarray(np.maximum(lengths - s, 0))
+    args = (q, k_pool, v_pool, tables, jnp.asarray(lengths))
+    want = A.paged_decode_attention(
+        *args, block_size=BS, q_offset=q_offset, sliding_window=window
+    )
+    got = A.paged_flash_decode(
+        *args, q_offset, block_size=BS, sliding_window=window, interpret=True
+    )
+    assert got.shape == want.shape and got.dtype == want.dtype
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
+    return err.max(axis=(1, 2, 3)), np.asarray(got, np.float32)
+
+
+@pytest.mark.parametrize("case", [
+    dict(lengths=[0, 1, 15, 16]),
+    dict(lengths=[17, 389, 0, NB * BS]),  # a full table
+    dict(lengths=[389, 260, 17, 1], s=4),  # the verify width, with q_offset
+    dict(lengths=[NB * BS, 16, 5, 0], s=4),
+    dict(lengths=[389, 300, 140, 33], window=100),  # window < length
+    dict(lengths=[389, 300, 500, 33], s=4, window=270),
+    dict(lengths=[200, 260, 40, 0], shared_pages=8),  # a prefix hit
+    dict(lengths=[1, 15, 17, 389], hq=8, hkv=8),  # MHA
+    dict(lengths=[16, 389, 0, 100], hq=8, hkv=2, s=4),
+    dict(lengths=[15, 389, 17, 0], hq=4, hkv=1),  # one kv head a device
+    dict(lengths=[15, 389, 17, 0], hq=8, hkv=4, dtype=jnp.float32),
+], ids=["page_edge", "full_table", "verify", "verify_full", "window",
+        "verify_window", "shared_pages", "mha", "gqa_pair", "one_kv_head",
+        "float32_pool"])
+@pytest.mark.parametrize("block_rows", [64, 512], ids=["rows64", "rows512"])
+def test_kernel_matches_the_gather_reference(case, block_rows, monkeypatch):
+    # 64 rows: a lane of 389 streams through 7 compute blocks, the window
+    # starts past the first; 512 (the served size): a lane is one block
+    monkeypatch.setattr(A, "PAGED_BLOCK_ROWS", block_rows)
+    err, got = _compare(**case)
+    assert (err <= TOL).all(), err
+    # a lane of length 0 writes zeros, and fetched nothing to do so
+    for lane, n in enumerate(case["lengths"]):
+        if n == 0:
+            assert not got[lane].any()
+
+
+def test_a_hole_inside_the_length_is_never_dereferenced():
+    """A lane whose length runs past its allocated pages (a retired lane:
+    every entry a hole) attends only to what is allocated."""
+    rng = np.random.default_rng(3)
+    k_pool, v_pool = (_pool(rng, 8, 128, jnp.bfloat16) for _ in range(2))
+    lengths = np.array([200, 77], np.int32)
+    tables = _tables(rng, [200, 32])
+    tables[0] = N_PAGES + 7  # retired: all holes, length left standing
+    q = jnp.asarray(rng.standard_normal((2, 1, 32, 128), np.float32),
+                    jnp.bfloat16)
+    got = A.paged_flash_decode(
+        q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths),
+        jnp.asarray(lengths - 1), block_size=BS, interpret=True,
+    )
+    assert not np.asarray(got[0], np.float32).any()
+    # lane 1 holds two pages: exactly the reference at length 32
+    want = A.paged_decode_attention(
+        q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray([200, 32]),
+        block_size=BS, q_offset=jnp.asarray(lengths - 1),
+    )
+    np.testing.assert_allclose(
+        np.asarray(got[1], np.float32), np.asarray(want[1], np.float32),
+        atol=TOL,
+    )
+
+
+def test_on_a_virtual_mesh_kv_heads_are_sharded():
+    """The 1x4 path end to end on virtual devices: ``shard_map`` over kv
+    heads (2 a device), tables and lengths as the batcher holds them."""
+    from docqa_tpu.runtime.mesh import host_cpu_mesh
+
+    mesh = host_cpu_mesh(4)
+    rng = np.random.default_rng(11)
+    lengths = np.array([389, 0, 17, 512], np.int32)
+    k_pool, v_pool = (_pool(rng, 8, 128, jnp.bfloat16) for _ in range(2))
+    tables = jnp.asarray(_tables(rng, lengths))
+    for s in (1, 4):
+        q = jnp.asarray(rng.standard_normal((4, s, 32, 128), np.float32),
+                        jnp.bfloat16)
+        q_offset = jnp.asarray(np.maximum(lengths - s, 0))
+        args = (q, k_pool, v_pool, tables, jnp.asarray(lengths))
+        want = A.paged_decode_attention(
+            *args, block_size=BS, q_offset=q_offset, sliding_window=300
+        )
+        got = A.paged_flash_decode(
+            *args, q_offset, block_size=BS, sliding_window=300,
+            interpret=True, mesh=mesh,
+        )
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=TOL,
+        )
+
+
+@pytest.mark.parametrize("dtype, hkv, d, reads", [
+    ("bfloat16", 8, 128, True), ("bfloat16", 1, 128, True),
+    ("float32", 3, 128, True), ("bfloat16", 3, 128, False),
+    ("bfloat16", 8, 64, False), ("int8", 8, 128, False),
+])
+def test_geometries_the_kernel_reads(dtype, hkv, d, reads):
+    assert A.paged_kernel_supported(dtype, hkv, d) is reads
+
+
+def _values(jaxpr):
+    """Every value of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield from eqn.outvars
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple)) else [param]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _values(inner)
+
+
+@pytest.mark.parametrize("use_flash", [True, False], ids=["kernel", "gather"])
+def test_no_value_spans_the_block_tables(use_flash):
+    """At [4 x 256] tables the gather reference makes [4, 4096, 8, 128]
+    copies of the span; the kernel's trace holds nothing with that many
+    rows (the parent's gather + flash pairing did, four times a layer)."""
+    S, nb, hq, hkv, d = 4, 256, 32, 8, 128
+    span = S * nb * BS
+
+    def attend(q, k_pool, v_pool, tables, lengths):
+        return A.paged_decode_attention(
+            q, k_pool, v_pool, tables, lengths, block_size=BS,
+            q_offset=lengths - 1, sliding_window=4096, use_flash=use_flash,
+        )
+
+    pool = jax.ShapeDtypeStruct((span, hkv, d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(attend)(
+        jax.ShapeDtypeStruct((S, 1, hq, d), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((S, nb), jnp.int32),
+        jax.ShapeDtypeStruct((S,), jnp.int32),
+    )
+    spanning = [
+        v.aval.shape for v in _values(jaxpr.jaxpr)
+        if hasattr(v.aval, "shape") and len(v.aval.shape) >= 3
+        and int(np.prod(v.aval.shape[:-2])) >= span
+    ]
+    if use_flash:
+        assert not spanning, spanning
+        assert "_paged_decode_kernel" in str(jaxpr)
+    else:
+        assert (S, nb * BS, hkv, d) in spanning
+
+
+class TestKvRowCounters:
+    """``serve_decode_kv_rows_read`` / ``_live`` per fetched chunk: a fake
+    chunk through ``_process_chunk``, under the plain and the speculative
+    program, on both attention paths."""
+
+    @pytest.fixture(params=[0, 4], ids=["plain", "spec4"])
+    def batcher(self, request):
+        from docqa_tpu.config import DecoderConfig, GenerateConfig
+        from docqa_tpu.engines.generate import GenerateEngine
+        from docqa_tpu.engines.serve import ContinuousBatcher
+
+        cfg = DecoderConfig(
+            vocab_size=64, hidden_dim=32, num_layers=1, num_heads=2,
+            num_kv_heads=1, head_dim=16, mlp_dim=64, max_seq_len=128,
+            dtype="float32",
+        )
+        gen = GenerateConfig(
+            temperature=0.0, prefill_buckets=(16,), eos_id=2,
+            speculative_k=request.param,
+        )
+        b = ContinuousBatcher(
+            GenerateEngine(cfg, gen, seed=3), n_slots=4, chunk=4,
+            cache_len=128,
+        )
+        yield b
+        b.stop()
+
+    @staticmethod
+    def _fake_chunk(batcher, in_place):
+        """One chunk fetched for two lanes that have since retired: 37
+        and 16 positions of K/V at dispatch; the first emitted 4 tokens,
+        the second stopped after 1."""
+        from docqa_tpu.engines.serve import make_request
+        from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
+
+        assert batcher.block_size == 16
+        batcher._pages_read_in_place = in_place
+        lanes = []
+        for kv_prompt, delivered in ((30, 8), (14, 3)):
+            req = make_request([3] * kv_prompt, 16)
+            req.kv_prompt = kv_prompt
+            req.tokens.extend([5] * delivered)
+            lanes.append(req)
+        snap = lanes + [None] * (batcher.n_slots - 2)
+        chunk, k = batcher.chunk, batcher.spec_k
+        if k:  # [tokens | emitted count | active]
+            packed = np.zeros((batcher.n_slots, chunk + 2 * k + 2), np.int32)
+            packed[:2, chunk + 2 * k] = (4, 1)
+        else:  # [tokens | valid | active]
+            packed = np.zeros((batcher.n_slots, 2 * chunk + 1), np.int32)
+            packed[0, chunk: 2 * chunk] = 1
+            packed[1, chunk] = 1
+        names = ("serve_decode_kv_rows_read", "serve_decode_kv_rows_live")
+        before = [DEFAULT_REGISTRY.counter(n).value for n in names]
+        assert batcher._process_chunk(packed, snap)
+        return [DEFAULT_REGISTRY.counter(n).value - b
+                for n, b in zip(names, before)]
+
+    @staticmethod
+    def _expected(batcher):
+        """(steps, live rows, live pages) of the fake chunk."""
+        if batcher.spec_k:
+            # one verify forward of 4 positions: 41 and 20 rows attended
+            return 1, 41 + 20, 3 + 2
+        # 4 steps: lane 0 attends 38, 39, 40, 41 rows (3 pages each),
+        # lane 1 attends 17, then 18 where it stopped (2 pages each)
+        return 4, 38 + 39 + 40 + 41 + 17 + 18 + 18 + 18, 4 * 3 + 4 * 2
+
+    def test_under_the_kernel_live_pages_are_read(self, batcher):
+        _steps, rows, pages = self._expected(batcher)
+        read, live = self._fake_chunk(batcher, in_place=True)
+        assert (read, live) == (pages * 16, rows)
+
+    def test_under_the_gather_every_table_is_read(self, batcher):
+        steps, rows, _pages = self._expected(batcher)
+        read, live = self._fake_chunk(batcher, in_place=False)
+        # every slot's whole table, each of the chunk's steps
+        assert (read, live) == (
+            steps * batcher.n_slots * batcher.seq_capacity, rows
+        )
+
+    def test_the_path_is_observed_not_set(self, batcher):
+        # a CPU run serves the gather reference
+        assert batcher._pages_read_in_place is False
+        assert batcher.engine.use_flash is False
+
+
+class TestCompilesForTheChip:
+    """What interpret mode cannot see: whether Mosaic accepts the kernel
+    (strided sublane reads of packed bf16 pairs, page-sized DMAs, VMEM)
+    at the widths the deployment runs — compiled for a DESCRIBED v5e, no
+    chip attached, nothing executed."""
+
+    @pytest.fixture(scope="class")
+    def topo(self):
+        from jax.experimental import topologies
+
+        try:
+            return topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+    @staticmethod
+    def _decode_args(sharding, S=4, s=1, n_rows=16384):
+        """Mistral-7B's decode attention: 32/8 heads x 128, 4 lanes of 256
+        table entries over a 16384-row pool."""
+
+        def arg(shape, dtype, *spec):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding(*spec))
+
+        return (
+            arg((S, s, 32, 128), jnp.bfloat16, None, None, "model", None),
+            arg((n_rows, 8, 128), jnp.bfloat16, None, "model", None),
+            arg((n_rows, 8, 128), jnp.bfloat16, None, "model", None),
+            arg((S, 256), jnp.int32), arg((S,), jnp.int32),
+        )
+
+    @staticmethod
+    def _attend(mesh=None):
+        def attend(q, k_pool, v_pool, tables, lengths):
+            return A.paged_decode_attention(
+                q, k_pool, v_pool, tables, lengths, block_size=BS,
+                q_offset=lengths - q.shape[1], sliding_window=4096,
+                use_flash=True, mesh=mesh,
+            )
+
+        return jax.jit(attend)
+
+    @pytest.mark.parametrize("s", [1, 4], ids=["step", "verify"])
+    def test_one_chip(self, topo, s):
+        from jax.sharding import SingleDeviceSharding
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        args = self._decode_args(lambda *spec: one_chip, s=s)
+        hlo = self._attend().lower(*args).compile().as_text()
+        assert "_paged_decode_kernel" in hlo
+        # the pool reaches the kernel as it lies: no copy, no gather of it
+        assert not [
+            line for line in hlo.splitlines()
+            if " = bf16[16384,8,128]" in line and " parameter(" not in line
+        ]
+
+    def test_a_1x4_mesh_shards_kv_heads(self, topo):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from docqa_tpu.runtime.mesh import MeshContext
+
+        mesh = MeshContext(
+            mesh=Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model")),
+            data_axis="data", model_axis="model",
+        )
+        args = self._decode_args(
+            lambda *spec: NamedSharding(mesh.mesh, P(*spec))
+        )
+        hlo = self._attend(mesh).lower(*args).compile().as_text()
+        assert "_paged_decode_kernel" in hlo
+        # kv heads are independent: no collective, and each chip's quarter
+        # of the pool is read in place
+        assert "all-gather" not in hlo and "all-reduce" not in hlo
+        assert "bf16[1024,32,128]" in hlo
+
+    def test_on_a_mesh_it_is_shard_mapped(self):
+        """GSPMD cannot partition a Mosaic call: cross-lowered for TPU on
+        the virtual 1x4 mesh, as ``test_flash_on_a_mesh_lowers_for_tpu``."""
+        from jax import export
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from docqa_tpu.runtime.mesh import host_cpu_mesh
+
+        mesh = host_cpu_mesh(4)
+        args = self._decode_args(
+            lambda *spec: NamedSharding(mesh.mesh, P(*spec))
+        )
+        exported = export.export(self._attend(mesh), platforms=["tpu"])(*args)
+        assert "tpu_custom_call" in exported.mlir_module()
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            export.export(self._attend(None), platforms=["tpu"])(*args)
