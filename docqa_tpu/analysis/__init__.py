@@ -53,7 +53,7 @@ Twenty-four project-specific checkers (docs/STATIC_ANALYSIS.md):
 * ``thread-lifecycle``— every thread has a reachable join on its owner's
   stop/close path (daemon threads that can reach jax especially).
 * ``wire-consumer``   — every subscript/``.get`` read of an HTTP
-  response, broker body, journal record, or bench dotted path resolves
+  response, broker body, journal record, or dotted metric path resolves
   to a declared producer key; orphaned producer keys also flag.
 * ``wire-safety``     — device arrays, numpy scalars, locks, Trace/Span
   objects, and non-finite floats at serialization boundaries

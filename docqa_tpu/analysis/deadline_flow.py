@@ -49,7 +49,6 @@ REQUEST_PATH_MODULES = frozenset(
         "docqa_tpu.service.qa",
         "docqa_tpu.engines.dispatch",
         "docqa_tpu.engines.retrieve",
-        "docqa_tpu.engines.rag_fused",
         "docqa_tpu.engines.serve",
         # the pool fronts the batcher on every /ask since PR 6 — its
         # waits are request waits (cv-protocol holds them to a Deadline)

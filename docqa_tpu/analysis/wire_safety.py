@@ -5,7 +5,7 @@ miscarries the subtle cases: a JAX device array blocks the event loop
 on implicit device-to-host transfer before TypeError-ing, a numpy
 scalar serializes fine on one numpy version and raises on another, and
 ``float("nan")`` produces ``NaN`` — a token that is NOT JSON and that
-strict parsers (and the perf-gate's ``json.load``) reject.  This rule
+strict parsers reject.  This rule
 flows coarse type facts to the three serialization boundaries —
 ``json_response(...)``, ``publish(queue, body)`` / ``_publish``, and
 ``_journal_write(queue, record)`` — and flags:

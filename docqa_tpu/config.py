@@ -287,11 +287,9 @@ class StoreConfig:
     # index/tiered.py — the beyond-1M path).
     serving_index: str = "exact"
     # Serving nprobe: frontier-tuned against the measured recall target
-    # (>= 0.95, not 1.0) — the decision trail (per-scale frontier
-    # snapshot + rationale) lives in bench_details.json["shard_scale"]
-    # ["nprobe_decision"]: recall CI lower bound >= 0.961 at nprobe=8
-    # from 1M to 10M chunks on the int8 sharded tier, and PR 13's
-    # online frontier on the d=384 bench corpus recommended the same 8.
+    # (>= 0.95, not 1.0): recall CI lower bound >= 0.961 at nprobe=8
+    # from 1M to 10M chunks on the int8 sharded tier (PR 15's sweep), and
+    # PR 13's online frontier on a d=384 corpus recommended the same 8.
     # The old blind 48 probed ~6x the cells the target needs.  Re-tune
     # live via /api/retrieval's measured frontier +
     # TieredIndex.set_nprobe.
@@ -309,13 +307,6 @@ class StoreConfig:
     # deletions trigger a compaction (tombstones cost a mask upload per
     # search and dilute IVF cells); 0 disables
     compact_threshold: float = 0.25
-    # Token sidecar: per-row generator-token ids kept in HBM alongside the
-    # vectors (shape [capacity, token_width] int32 + a length column).
-    # Enables the single-sync fused RAG path (engines/rag_fused.py): top-k
-    # -> gather chunk tokens -> assemble the prompt -> decode, all chained
-    # on device with no host round-trip between retrieval and generation.
-    # 0 disables (no HBM cost).  At 1M rows x 128 tokens: 512 MB.
-    token_width: int = 0
 
 
 @dataclass(frozen=True)
@@ -502,7 +493,7 @@ class DispatchConfig:
     # producer and fails typed (SpineSaturated)
     max_depth: int = 256
     # inline mode runs work items on the submitting thread (no lanes) —
-    # the bench dispatch-overhead A/B's OFF arm; never serve with it
+    # the OFF arm of a dispatch-overhead A/B; never serve with it
     inline: bool = False
     # strict mode FULLY SERIALIZES device work: one lane runs at a time
     # and every item block_until_ready()s on it, so exactly one device
@@ -516,7 +507,7 @@ class DispatchConfig:
     # thread dispatching sharded programs at once: chip_smoke.py, PR 21).
     strict_sync: Optional[bool] = None
     # register compiled-program cost_analysis() FLOPs/bytes at boot so
-    # /api/status and bench report per-stage MFU (a few background
+    # /api/status reports per-stage MFU (a few background
     # lowerings; disable on hosts where tracing at boot is too dear)
     annotate_costs: bool = True
 
@@ -575,8 +566,7 @@ class RetrievalQualityConfig:
     enabled: bool = True
     # 1-in-N shadow sampling of tiered retrievals (deterministic seeded
     # hash — replayed workloads sample identical request indices).  The
-    # measured overhead budget (bench retrieval_quality section) is 2%
-    # of qa_e2e p50 at this default.
+    # overhead budget is 2% of qa_e2e p50 at this default.
     sample_every: int = 32
     seed: int = 0
     # per-QUERY comparisons retained per (tier, nprobe) estimate window
@@ -655,10 +645,9 @@ class GenerateConfig:
     # provisioning (n_slots x cache capacity — no request mix can ever
     # exhaust the pool, matching the old per-slot reservation byte for
     # byte).  Set BELOW that to overcommit: mixed real-world lengths
-    # rarely sum to worst case, so the same HBM sustains more slots
-    # (bench.py kv_paging sweep measures the frontier); exhaustion then
-    # sheds typed (serve.BlockPoolExhausted) instead of admitting work
-    # the pool cannot hold.
+    # rarely sum to worst case, so the same HBM sustains more slots;
+    # exhaustion then sheds typed (serve.BlockPoolExhausted) instead of
+    # admitting work the pool cannot hold.
     kv_pool_tokens: Optional[int] = None
     # copy-on-write KV prefix cache (engines/paged.PrefixCache;
     # docs/OPERATIONS.md "Prefix cache"): admission maps a cached,
@@ -695,7 +684,7 @@ class QoSConfig:
 
     # master switch: False reverts every batcher to plain FIFO admission
     # with no preemption and no deferral (the pre-QoS behavior, bit for
-    # bit — the bench qos_overload section A/Bs exactly this flag)
+    # bit)
     enabled: bool = True
     # admission weights: over a contended drain, classes are served in
     # this ratio (deficit WFQ in engines/qos.ClassQueue).  Weights shape
@@ -750,7 +739,7 @@ class LexicalConfig:
     # serving retrieve mode: "dense" | "lexical" | "hybrid".  Dense stays
     # the default per the advisory-first rule (PR 13): hybrid is promoted
     # only when the measured recall CI-low on the labeled mix beats
-    # dense-only (bench answer_routing reports both).
+    # dense-only.
     serving_mode: str = "dense"
 
 

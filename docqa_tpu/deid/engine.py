@@ -241,7 +241,7 @@ def _is_etiology_fr(word: str) -> bool:
 _MIN_PHONE_DIGITS = 7
 
 # Served acceptance threshold for model spans, set from the measured
-# operating curve on the disjoint evalset (bench threshold_sweep) — one
+# operating curve on the disjoint evalset — one
 # constant so serving and the training-recipe gate (training/ner.py
 # evaluate_ner) score the SAME operating point.
 #
@@ -423,13 +423,11 @@ class DeidEngine:
         seed: int = 0,
         use_ner_model: bool = True,
         # Default set from the measured operating curve on the disjoint
-        # evalset (bench threshold_sweep): at 0.8 both typed-span F1
+        # evalset: at 0.8 both typed-span F1
         # (0.989) and char F1 (0.981) beat the 0.5 point (0.966/0.980),
         # and span_recall_any stays 1.0 across the whole 0.3–0.9 sweep —
         # on this tagger a higher bar only sheds false positives, it does
-        # not trade leak risk.  The bench re-sweeps every run, so a
-        # regression shows up as this default no longer sitting on the
-        # curve's knee.
+        # not trade leak risk.
         ner_threshold: float = DEFAULT_NER_THRESHOLD,
         # evaluate_ner turns the deny-list veto OFF: the recipe gate must
         # score the tagger alone, not the tagger hidden behind a list

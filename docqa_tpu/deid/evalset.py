@@ -6,8 +6,7 @@ it measured memorization as much as generalization.  This module is the
 disjoint check: the sentences below were written by hand in registers the
 generator does not produce (narrative discharge prose, referral letters,
 nursing shorthand, French clinical snippets mirroring the service's
-prompt language, intake forms), and the metric code is shared by the test
-suite and bench config 2 (``deid.f1`` in ``bench_details.json``).
+prompt language, intake forms); the test suite scores against it.
 
 Reference capability being measured: Presidio's pretrained 6-entity
 detection (``deid-service/anonymizer.py:41-48``).
@@ -132,7 +131,7 @@ _MARKED: Sequence[str] = (
 # Written AFTER the served threshold (0.8) was frozen from the dev curve —
 # but round 5 then tuned the deny-word list and person-position cues
 # (deid/engine.py) directly against THESE spans, so they are a second dev
-# set, not a held-out test: the bench's reported ``deid.f1`` carries
+# set, not a held-out test: an F1 scored on them carries
 # tuning optimism from that step and must be labeled accordingly wherever
 # it is quoted.  A genuinely held-out split would have to be written
 # fresh and never scored until a release gate.  Registers avoid datagen's
@@ -318,7 +317,7 @@ _MARKED_HELDOUT: Sequence[str] = (
 )
 
 EXAMPLES: List[Tuple[str, List[GoldSpan]]] = [_parse(m) for m in _MARKED]
-DEV_EXAMPLES = EXAMPLES  # threshold-selection split (bench threshold_sweep)
+DEV_EXAMPLES = EXAMPLES  # threshold-selection split
 TEST_EXAMPLES: List[Tuple[str, List[GoldSpan]]] = [
     _parse(m) for m in _MARKED_TEST
 ]
@@ -465,9 +464,8 @@ def evaluate_deid_split(
       second dev number, never as held-out.
     * ``heldout`` — written fresh for PR 7 and never used in any tuning
       decision; THIS is the number to quote as generalization.  Both
-      are reported side by side (bench ``deid.f1`` = second-dev,
-      ``deid.f1_heldout`` = held-out) so the tuning-optimism gap is
-      itself measured instead of hidden.
+      are reported side by side so the tuning-optimism gap is itself
+      measured instead of hidden.
     """
     dev_preds = _predict(engine, DEV_EXAMPLES)
     test_preds = _predict(engine, TEST_EXAMPLES)

@@ -5,10 +5,9 @@ that reads them must either dispatch under ``store._lock`` or hold a
 consistent snapshot and handle the (rare) donation race.  Dispatching
 under the lock is wrong for FIRST calls: XLA tracing+compile of a fused
 program (which embeds the encoder forward) takes seconds and would stall
-every concurrent index/search (ADVICE r4).  This module holds the ONE
-copy of the snapshot-outside/retry-under-lock discipline used by
-``FusedRetriever.search_texts`` and ``FusedRAG.ask_submit`` — the two
-must never drift apart.
+every concurrent index/search (ADVICE r4).  This module holds the
+snapshot-outside/retry-under-lock discipline the fused retrievers
+(``engines/retrieve.py``) dispatch through.
 """
 
 from __future__ import annotations
@@ -88,9 +87,8 @@ def dispatch_with_donation_retry(
         try:
             # spine work item, ASYNC like the pre-spine call: the lane
             # covers the hazard window (trace/compile + enqueue) and
-            # returns device arrays immediately, so FusedRAG's
-            # pack→generate device chaining keeps its no-sync contract
-            # and a lane is never held for the program's device time.
+            # returns device arrays immediately, so a lane is never
+            # held for the program's device time.
             # A donation race surfaces at dispatch (tracing re-reads the
             # donated buffers) exactly as it did pre-spine.
             return spine_run(

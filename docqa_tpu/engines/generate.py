@@ -19,7 +19,6 @@ ICI collectives.
 from __future__ import annotations
 
 import functools
-import itertools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -87,17 +86,6 @@ def accept_drafts(logits, drafts, eos_id):
     is_eos = (g == eos_id) & cand
     eos_pos = jnp.where(jnp.any(is_eos, 1), jnp.argmax(is_eos, 1), K)
     return g, m, cand, is_eos, eos_pos
-
-
-def greedy_dummy_key() -> jax.Array:
-    """The one sanctioned constant key: a placeholder for greedy decode
-    paths whose device program takes the argmax branch and never consumes
-    the sampling key.  The rng-discipline checker exempts THIS body
-    structurally — every other fixed ``PRNGKey(<literal>)`` reachable
-    from the request path flags.  Never thread the result into a
-    temperature>0 path; mint with :meth:`GenerateEngine.next_request_key`
-    there instead."""
-    return jax.random.PRNGKey(0)
 
 
 class GenerateEngine:
@@ -215,20 +203,6 @@ class GenerateEngine:
             use_flash = jax.default_backend() == "tpu" and cfg.head_dim % 64 == 0
         self.use_flash = use_flash
         self._fns = {}
-        self._seed = seed
-        # per-request sampling keys for paths that bypass the batcher
-        # (the fused RAG lane): same counter-minted scheme as
-        # serve._next_rng — unique per request, deterministic per
-        # (seed, admission index), so replay re-mints the same keys
-        self._request_rng_counter = itertools.count(1)
-
-    def next_request_key(self) -> jax.Array:
-        """Counter-minted per-request sampling key: ``PRNGKey(seed *
-        100_003 + counter)``.  next() on itertools.count is atomic, so
-        concurrent submitters get distinct keys without a lock."""
-        return jax.random.PRNGKey(
-            self._seed * 100_003 + next(self._request_rng_counter)
-        )
 
     # ---- device program ------------------------------------------------------
 
@@ -501,10 +475,9 @@ class GenerateEngine:
         accounting, or None when it provides none.
 
         Shared by the compile audit (``analysis/compile_audit.py`` gates
-        per-root ``peak_bytes`` against ``compile_budget.json``) and
-        ``bench.py`` (which feeds ``argument_bytes`` into the
-        ``hbm_utilization`` it reports) — one measurement path
-        (``utils.compiled_memory_stats``), no drift."""
+        per-root ``peak_bytes`` against ``compile_budget.json``) and the
+        telemetry sampler's HBM working-set probe — one measurement
+        path (``utils.compiled_memory_stats``), no drift."""
         from docqa_tpu.utils import compiled_memory_stats
 
         max_new = (
@@ -552,8 +525,8 @@ class GenerateEngine:
             stats = compiled_memory_stats(compiled)
             cost = DEFAULT_OBSERVATORY.cost_of("generate", key)
             if stats is not None and cost is not None:
-                # cost columns ride the same probe (compile_audit /
-                # bench rows then carry flops next to bytes)
+                # cost columns ride the same probe (compile_audit rows
+                # then carry flops next to bytes)
                 stats = dict(stats)
                 stats["flops"] = cost["flops"]
                 stats["bytes_accessed"] = cost["bytes"]
@@ -562,7 +535,7 @@ class GenerateEngine:
         try:
             return spine_run("hbm_probe", _probe_on_lane, stream="probe")
         except Exception:
-            # a lowering failure must not take the bench/audit caller
+            # a lowering failure must not take the audit caller
             # down, but it must be VISIBLE — a silent None here would
             # quietly reintroduce the unmeasured-HBM state
             log.exception("decode AOT memory analysis failed")

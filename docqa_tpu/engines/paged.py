@@ -608,7 +608,7 @@ def init_paged_pools(
 
 def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     """HBM bytes one token of KV occupies across every layer — the
-    block-granular accounting unit the bench and telemetry report
+    block-granular accounting unit telemetry reports
     (ROADMAP item 1: per-token bytes instead of per-bucket)."""
     return (
         2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim

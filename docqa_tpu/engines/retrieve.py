@@ -438,7 +438,8 @@ class FusedTieredRetriever:
     enters the sharded merge kernel inside the SAME single dispatch —
     the former three-dispatch off-mesh fallback (and its loud
     ``retrieve_offmesh_fallback_total`` counter) is structurally gone;
-    the perf gate holds that counter to zero on the multi-device path.
+    ``tests/test_ivf_sharded.py`` holds that counter to zero on the
+    multi-device path.
     """
 
     # docqa-lexroute: search_texts accepts mode= — the QA service's

@@ -16,15 +16,14 @@ Two serving decisions live here, both pure host logic (no device code):
   shapes in EN/FR) vs *generative* (why/how/explain/summarize needs the
   decoder).  Routed-extractive requests are served straight from
   retrieval via :func:`extractive_answer` — the decoder is never
-  touched, no KV slot is allocated, and the ~600 ms generative p50
-  collapses to the ~50 ms retrieval p50 (bench ``answer_routing``).
+  touched and no KV slot is allocated.
   The gate is two-stage and conservative by design: a query-text
   decision first, then an evidence check
   (:func:`extractive_confidence`) after retrieval — low confidence at
   EITHER stage falls through to the generative path, so a wrong route
-  can cost latency, never correctness (the routing-precision floor in
-  perf_gate holds the text stage to >=0.95 on the checked-in labeled
-  mix, authored like the deid HELDOUT split and never tuned against).
+  can cost latency, never correctness (``tests/test_router.py`` holds
+  the text stage's precision to >=0.95 on the checked-in labeled mix,
+  authored like the deid HELDOUT split and never tuned against).
 
 :func:`extractive_answer` is PR 1's degraded-mode answerer *promoted*:
 one implementation, two call sites (degraded fallback in

@@ -608,7 +608,7 @@ class ContinuousBatcher:
         want_cache = (
             bool(getattr(self.gen, "prefix_cache", True))
             if prefix_cache is None
-            else bool(prefix_cache)  # bench A/B + test override
+            else bool(prefix_cache)  # A/B + test override
         )
         if want_cache and self._share_align < self.seq_capacity:
             self._prefix_cache = PrefixCache(
@@ -650,7 +650,7 @@ class ContinuousBatcher:
         # With a policy, the admission queue is per-class weighted-fair
         # (same deque surface, so every sweep below is policy-blind);
         # without one (qos=None) it is the plain FIFO deque — bit-for-
-        # bit the pre-QoS batcher, which the bench A/Bs against.
+        # bit the pre-QoS batcher.
         self._qos: Optional[QoSPolicy] = QoSPolicy.coerce(qos)
         if self._qos is not None:
             self._queue: Any = self._qos.make_queue(now_fn=_now)

@@ -495,7 +495,7 @@ class DispatchSpine:
                 self._ensure_lanes_locked()
                 self._cv.notify_all()
         if run_inline:
-            # inline mode (the bench overhead A/B's OFF arm, tiny
+            # inline mode (a dispatch-overhead A/B's OFF arm, tiny
             # tools): the work item runs on the submitting thread
             self._execute(item)
         return SpineTicket(self, item)
@@ -594,7 +594,7 @@ class DispatchSpine:
             return self._busy / self.n_lanes
 
     def stats(self) -> Dict[str, Any]:
-        """Aggregate + per-stage snapshot (bench / ``/api/status``)."""
+        """Aggregate + per-stage snapshot (``/api/status``)."""
         with self._stats_lock:
             stages = {
                 name: dict(row) for name, row in self._stage_stats.items()
@@ -664,7 +664,7 @@ class DispatchSpine:
         return out
 
     def reset_stats(self) -> None:
-        """Zero the per-stage aggregates (bench A/B windows)."""
+        """Zero the per-stage aggregates (A/B windows)."""
         with self._stats_lock:
             self._stage_stats.clear()
 

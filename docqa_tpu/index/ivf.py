@@ -628,9 +628,8 @@ class IVFIndex:
         return cls(vectors, meta, **kw)
 
     def index_bytes(self) -> Dict[str, Any]:
-        """Device-resident byte accounting for the tier — the perf-gate
-        ``index_bytes_per_chunk`` structural ceiling and the
-        ``/api/retrieval`` capacity surface read this.  ``per_shard`` is
+        """Device-resident byte accounting for the tier — the
+        ``/api/retrieval`` capacity surface reads this.  ``per_shard`` is
         what ONE device holds (sharded tensors split n_shards ways;
         centroids/spill replicate)."""
         sharded_b = sum(

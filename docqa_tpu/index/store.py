@@ -102,11 +102,6 @@ def _search_single(vectors, queries, count, filter_mask, k: int):
     return jax.lax.top_k(scores, k)
 
 
-def _append1_kernel(buf, vals, offset):
-    """1-D variant of ``_append_kernel`` for the token-length column."""
-    return jax.lax.dynamic_update_slice(buf, vals, (offset,))
-
-
 def _append_kernel(buf, rows, offset):
     return jax.lax.dynamic_update_slice_in_dim(buf, rows, offset, 0)
 
@@ -183,29 +178,6 @@ class VectorStore:
         # snapshot restore, which re-drive add() — so a crash-replayed
         # ingest converges every tier, not just the dense one.
         self._index_sinks: List[Any] = []
-        # Token sidecar (cfg.token_width > 0): per-row generator-token ids
-        # + true lengths, row-aligned with the vector buffer through every
-        # add/grow/compact/snapshot — the device-side prompt source for
-        # the fused RAG path (engines/rag_fused.py).  Row-sharded over the
-        # model axis exactly like the vector buffer, so the fused
-        # single-sync ask composes with a sharded mesh (the per-shard
-        # token gather + psum merge lives in engines/rag_fused.py).
-        W = cfg.token_width
-        if W:
-            self._tok_host = np.zeros((0, W), np.int32)
-            self._tok_len_host = np.zeros((0,), np.int32)
-            self._tok_dev = self._place_rows(
-                jnp.zeros((self._capacity, W), jnp.int32)
-            )
-            self._tok_len_dev = self._place_rows(
-                jnp.zeros((self._capacity,), jnp.int32)
-            )
-            self._tok_append_jit = jax.jit(
-                _append_kernel, donate_argnums=(0,)
-            )
-            self._tok_len_append_jit = jax.jit(
-                _append1_kernel, donate_argnums=(0,)
-            )
 
     def _intern(self, column: str, value: Optional[str]) -> int:
         if value is None:
@@ -257,8 +229,7 @@ class VectorStore:
 
     def _place_rows(self, arr: jax.Array) -> jax.Array:
         """Shard a [capacity, ...] array's rows over the model axis (no-op
-        without a mesh) — the one placement rule for the vector buffer and
-        its token sidecar, so the two can never drift apart."""
+        without a mesh)."""
         if self.mesh is None:
             return arr
         return jax.device_put(arr, self.mesh.row_sharded)
@@ -277,19 +248,6 @@ class VectorStore:
         buf = np.zeros((new_cap, self.cfg.dim), np.float32)
         buf[: self._count] = self._host[: self._count]
         self._dev = self._place_rows(jnp.asarray(buf, self._dtype))
-        if self.cfg.token_width:
-            self._upload_tok_locked()
-
-    def _upload_tok_locked(self) -> None:
-        """Re-upload the sidecar device arrays at the current capacity from
-        the host master copy (capacity change or compaction)."""
-        W = self.cfg.token_width
-        tok = np.zeros((self._capacity, W), np.int32)
-        tok[: self._count] = self._tok_host[: self._count]
-        tl = np.zeros((self._capacity,), np.int32)
-        tl[: self._count] = self._tok_len_host[: self._count]
-        self._tok_dev = self._place_rows(jnp.asarray(tok))
-        self._tok_len_dev = self._place_rows(jnp.asarray(tl))
 
     # ---- public API ----------------------------------------------------------
 
@@ -365,18 +323,11 @@ class VectorStore:
         self,
         vectors: np.ndarray,
         metadata: Sequence[Dict[str, Any]],
-        token_rows: Optional[np.ndarray] = None,
-        token_lens: Optional[np.ndarray] = None,
     ) -> List[int]:
         """Append normalized vectors + metadata rows; returns global row ids.
 
         Visible to searches immediately (device-side append — the reference
         required a service restart, ``llm-qa/main.py:35``).
-
-        ``token_rows``/``token_lens``: per-row generator-token ids for the
-        sidecar (``cfg.token_width``); rows longer than the width are
-        truncated, absent rows stay empty (the fused RAG path then renders
-        that chunk as zero tokens).
         """
         vectors = np.asarray(vectors, np.float32)
         if vectors.ndim != 2 or vectors.shape[1] != self.cfg.dim:
@@ -404,21 +355,16 @@ class VectorStore:
             def _append_on_lane():
                 """Device phase (spine work item; submitter holds the
                 store lock while blocked — the closure acquires
-                nothing): capacity growth, the donated buffer append,
-                and the token-sidecar append.  Returns the written
-                device arrays so strict mode syncs every program this
-                item issued before the lane frees."""
+                nothing): capacity growth and the donated buffer
+                append.  Returns the written device array so strict mode
+                syncs the program this item issued before the lane
+                frees."""
                 self._grow_to(start + n_pad)
                 rows = np.zeros((n_pad, self.cfg.dim), np.float32)
                 rows[:n] = vectors
                 self._dev = self._append_jit(
                     self._dev, jnp.asarray(rows, self._dtype), start
                 )
-                if self.cfg.token_width:
-                    self._append_tokens_locked(
-                        start, n, n_pad, token_rows, token_lens
-                    )
-                    return self._dev, self._tok_dev, self._tok_len_dev
                 return self._dev
 
             spine_run("store_add", _append_on_lane)
@@ -429,48 +375,6 @@ class VectorStore:
             row_ids = list(range(start, start + n))
             self._notify_sinks("on_add", row_ids, metadata)
             return row_ids
-
-    def _append_tokens_locked(
-        self, start, n, n_pad, token_rows, token_lens
-    ) -> None:
-        W = self.cfg.token_width
-        block = np.zeros((n_pad, W), np.int32)
-        lens = np.zeros((n_pad,), np.int32)
-        if token_rows is not None:
-            token_rows = np.asarray(token_rows, np.int32)
-            w = min(W, token_rows.shape[1])
-            block[:n, :w] = token_rows[:, :w]
-            if token_lens is None:
-                token_lens = (token_rows != 0).sum(axis=1)
-            lens[:n] = np.minimum(np.asarray(token_lens, np.int32), W)
-        if self._tok_host.shape[0] < start + n:
-            grow = max(start + n, 2 * max(1, self._tok_host.shape[0]))
-            th = np.zeros((grow, W), np.int32)
-            th[: self._tok_host.shape[0]] = self._tok_host
-            tl = np.zeros((grow,), np.int32)
-            tl[: self._tok_len_host.shape[0]] = self._tok_len_host
-            self._tok_host, self._tok_len_host = th, tl
-        self._tok_host[start : start + n] = block[:n]
-        self._tok_len_host[start : start + n] = lens[:n]
-        self._tok_dev = self._tok_append_jit(
-            self._tok_dev, jnp.asarray(block), start
-        )
-        self._tok_len_dev = self._tok_len_append_jit(
-            self._tok_len_dev, jnp.asarray(lens), start
-        )
-
-    def token_sidecar(self):
-        """(tokens [capacity, W] int32, lengths [capacity] int32) device
-        arrays, or None when the sidecar is disabled.  The PAIR is
-        snapshotted under the store lock: each reference store is atomic
-        under the GIL, but reading them back-to-back lock-free could
-        pair a post-append token table with a pre-append length vector
-        (guarded-state, PR 8) — the fused program would then score one
-        phantom row."""
-        if not self.cfg.token_width:
-            return None
-        with self._lock:
-            return self._tok_dev, self._tok_len_dev
 
     def _get_search_fn(self, q: int, k: int, masked: bool) -> Callable:
         key = (self._capacity, q, k, masked)
@@ -617,9 +521,6 @@ class VectorStore:
             keep = ~self._deleted[:count]
             removed = count - int(keep.sum())
             self._host = self._host[:count][keep].copy()
-            if self.cfg.token_width:
-                self._tok_host = self._tok_host[:count][keep].copy()
-                self._tok_len_host = self._tok_len_host[:count][keep].copy()
             self._meta = [
                 md for md, k in zip(self._meta, keep) if k
             ]
@@ -647,9 +548,6 @@ class VectorStore:
                 buf = np.zeros((self._capacity, self.cfg.dim), np.float32)
                 buf[: self._count] = self._host[: self._count]
                 self._dev = self._place_rows(jnp.asarray(buf, self._dtype))
-                if self.cfg.token_width:
-                    self._upload_tok_locked()
-                    return self._dev, self._tok_dev, self._tok_len_dev
                 return self._dev
 
             spine_run("store_add", _reupload_on_lane)
@@ -865,10 +763,6 @@ class VectorStore:
             count, version = self._count, self._version
             vectors = self._host[:count].copy()
             meta = list(self._meta)
-            tokens = token_lens = None
-            if self.cfg.token_width:
-                tokens = self._tok_host[:count].copy()
-                token_lens = self._tok_len_host[:count].copy()
         base = os.path.join(directory, f"index_v{version}")
         tmp = tempfile.mkdtemp(dir=directory)
         # checksummed native codec (C++ DNS1 shard, crc32-verified mmap read)
@@ -882,11 +776,6 @@ class VectorStore:
             "dim": self.cfg.dim,
             "vectors": os.path.basename(vec_path),
         }
-        if tokens is not None:
-            np.save(os.path.join(tmp, "tokens.npy"), tokens)
-            np.save(os.path.join(tmp, "token_lens.npy"), token_lens)
-            manifest["tokens"] = "tokens.npy"
-            manifest["token_width"] = self.cfg.token_width
         with open(os.path.join(tmp, "manifest.json"), "w") as f:
             json.dump(manifest, f)
         import shutil
@@ -931,17 +820,16 @@ class VectorStore:
             base = os.path.join(directory, f.read().strip())
         with open(os.path.join(base, "manifest.json")) as f:
             manifest = json.load(f)
+        # Only the entries read here are trusted to exist; anything else a
+        # snapshot carries (an older writer's ``tokens`` / ``token_width``
+        # sidecar arrays) is left on disk unread.
         vectors = native.read_vectors(
             os.path.join(base, manifest.get("vectors", "vectors.npy"))
         )
         with open(os.path.join(base, "metadata.json")) as f:
             meta = json.load(f)
         store = cls(cfg, mesh=mesh)
-        tokens = token_lens = None
-        if cfg.token_width and manifest.get("tokens"):
-            tokens = np.load(os.path.join(base, manifest["tokens"]))
-            token_lens = np.load(os.path.join(base, "token_lens.npy"))
         if len(vectors):
-            store.add(vectors, meta, token_rows=tokens, token_lens=token_lens)
+            store.add(vectors, meta)
         store._version = manifest["version"]
         return store

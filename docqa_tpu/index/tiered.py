@@ -87,7 +87,7 @@ class TieredIndex:
         # docqa-lexroute: optional lexical tier + fusion knobs.  The
         # serving default stays "dense" unless the measured hybrid
         # recall CI-low beats dense-only on the labeled exact-token mix
-        # (bench ``answer_routing``) — the PR 13 advisory-first rule;
+        # — the PR 13 advisory-first rule;
         # hybrid/lexical modes are always available per request.
         self.lexical = lexical
         self.hybrid_alpha = float(hybrid_alpha)
@@ -791,8 +791,7 @@ class TieredIndex:
         return cache
 
     def index_stats(self) -> dict:
-        """Tier layout + byte accounting for ``/api/retrieval`` and the
-        perf gate's ``index_bytes_per_chunk`` structural ceiling."""
+        """Tier layout + byte accounting for ``/api/retrieval``."""
         with self._rebuild_lock:
             tier = self._tier
         if tier is None:

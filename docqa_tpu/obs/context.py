@@ -56,7 +56,7 @@ _id_counter = itertools.count(1)
 
 
 def reset_ids(prefix: str = "t", start: int = 1) -> None:
-    """Restart the id sequence (tests / bench determinism)."""
+    """Restart the id sequence (tests, replay determinism)."""
     global _id_prefix, _id_counter
     with _id_lock:
         _id_prefix = prefix

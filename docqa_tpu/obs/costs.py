@@ -201,7 +201,7 @@ class CostRecord:
         path covers the submitter-side stages (retrieval, store search,
         solo generate) with no double count."""
         self.add("spine_queue_wait_ms", queue_wait_s * 1e3)
-        if stage.startswith(("retrieve", "store_search", "fused")):
+        if stage.startswith(("retrieve", "store_search")):
             self.add("retrieve_device_ms", device_s * 1e3)
         else:
             self.add("other_device_ms", device_s * 1e3)
@@ -411,7 +411,7 @@ class RequestCostLedger:
         (``/api/costs/sheds``): the shed kind, the shed REQUEST's class,
         and — via the registered probe — which classes held how many KV
         blocks, decode lanes, and queue slots at that instant.  Fenced
-        and cheap; returns the snapshot (tests/bench read it back)."""
+        and cheap; returns the snapshot (tests read it back)."""
         if not self._enabled:
             return None
         snap: Dict[str, Any] = {
@@ -466,8 +466,8 @@ class RequestCostLedger:
     # ---- surfaces ------------------------------------------------------------
 
     def class_totals(self) -> Dict[str, Dict[str, float]]:
-        """Deep-copied per-class cumulative sums (bench A/B windows
-        difference two of these)."""
+        """Deep-copied per-class cumulative sums (an A/B window is the
+        difference of two of these: scripts/qos_smoke.py)."""
         with self._lock:
             return {c: dict(row) for c, row in self._classes.items()}
 
@@ -489,7 +489,7 @@ class RequestCostLedger:
     ) -> Dict[str, Any]:
         """The ``GET /api/costs`` payload: per-class breakdown, top
         spenders, and each class's share of measured device time
-        (vs the spine's total — the cross-check the bench asserts) and
+        (vs the spine's total) and
         of the KV pool's block-seconds."""
         with self._lock:
             classes = {c: dict(row) for c, row in self._classes.items()}
@@ -564,7 +564,7 @@ class RequestCostLedger:
         }
 
     def reset(self) -> None:
-        """Zero the aggregates (bench measurement windows).  Open
+        """Zero the aggregates (measurement windows).  Open
         records keep working — their retire/late-adds fold into the
         fresh sums."""
         with self._lock:

@@ -16,7 +16,7 @@ flight-recorder timeline by trace id (``/api/trace/<id>``).
 ``GET /api/telemetry`` serves :func:`telemetry_json` — the rollup ring
 as JSON, one series per name, consumed by ``scripts/soak.py`` /
 ``scripts/chaos_smoke.py`` (violation dumps carry the series next to
-the trace timelines) and by the bench's telemetry snapshot.
+the trace timelines).
 
 The format contract is pinned by a strict line-lint in
 ``tests/test_telemetry.py`` (CI has no promtool): every non-comment,

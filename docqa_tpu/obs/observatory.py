@@ -15,8 +15,8 @@ next to *measured device time* and
     MFU = flops / device_seconds / peak_flops
 
 is an attribution, not a wall-clock guess.  Peaks come from ONE table
-keyed by jax's ``device_kind`` (:data:`DEVICE_PEAKS`, shared with
-``bench.py``); a device that is not in the table has no peak, so every
+keyed by jax's ``device_kind`` (:data:`DEVICE_PEAKS`); a device that is
+not in the table has no peak, so every
 ``mfu`` / ``roofline_bound`` stays null there — a CPU run never reports
 utilization against somebody else's chip.
 
@@ -161,7 +161,7 @@ class Observatory:
                 row["uncosted"] += 1
 
     def reset(self) -> None:
-        """Zero the aggregates (bench measurement windows); registered
+        """Zero the aggregates (measurement windows); registered
         cost models survive — they describe programs, not traffic."""
         with self._lock:
             self._stages.clear()

@@ -23,8 +23,7 @@ Retention policy:
   crashed consumer, an abandoned stream) is evicted oldest-first with an
   ``abandoned`` flag instead of leaking.
 
-Everything no-ops when disabled (``set_enabled(False)``) — the bench's
-tracing-overhead A/B flips exactly this switch.
+Everything no-ops when disabled (``set_enabled(False)``).
 """
 
 from __future__ import annotations

@@ -217,7 +217,7 @@ class RetrievalObservatory:
         self.window = int(window)
         self.max_pending = max(1, int(max_pending))
         # every Nth sampled shadow also probes the frontier; 0 disables
-        # frontier probing entirely (bench overhead arms)
+        # frontier probing entirely
         self.frontier_every = max(0, int(frontier_every))
         self.frontier_factors = tuple(frontier_factors)
         self.min_frontier_n = int(min_frontier_n)
@@ -290,7 +290,7 @@ class RetrievalObservatory:
         and the hashed slot keeps the cadence from phase-locking onto a
         periodic workload the way a bare ``seq % N == 0`` would.  (A
         residue of the raw hash is only window-exact for power-of-two
-        rates; the per-window slot holds the bench A/B's '2x the rate
+        rates; the per-window slot holds the '2x the rate
         contains real shadows' sizing for every operator-tuned N.)"""
         win, offset = divmod(seq, self.sample_every)
         h = ((win + 1) * _HASH_MULT + self.seed * _SEED_MULT) & 0xFFFFFFFF
@@ -356,7 +356,7 @@ class RetrievalObservatory:
 
     def drain(self, timeout: float = 10.0) -> bool:
         """Block until every queued job has been processed AND the
-        worker is idle (tests, the bench's A/B windows).  True on
+        worker is idle (tests, measurement windows).  True on
         success; False when the timeout expired first."""
         import time as _time
 

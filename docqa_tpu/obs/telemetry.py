@@ -541,9 +541,9 @@ class TelemetrySampler:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.ticks = 0
-        # cumulative wall seconds spent inside tick() — bench divides
-        # this by the measured window to report the sampler's CPU share
-        # against the 2% observability budget
+        # cumulative wall seconds spent inside tick(): over a measured
+        # window, the sampler's CPU share against the 2% observability
+        # budget
         self.tick_seconds = 0.0
         self._probe_errors: Dict[str, int] = {}
 

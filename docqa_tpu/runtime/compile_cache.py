@@ -3,9 +3,9 @@
 The directory is part of the cache key, so it must not move between
 runs: ``JAX_COMPILATION_CACHE_DIR`` decides it when set (JAX reads that
 variable itself — the program then sets nothing), otherwise it is ONE
-fixed directory inside the checkout.  Called at process entry points
-only (``service.app.serve``, ``bench.py``), never at import, so a test
-that counts compiles still sees real ones.
+fixed directory inside the checkout.  Called at the process entry point
+only (``service.app.serve``), never at import, so a test that counts
+compiles still sees real ones.
 """
 
 from __future__ import annotations

@@ -428,13 +428,6 @@ class DocQARuntime:
             http_extractor=http_extractor,
             on_indexed=self._on_indexed,
             breakers=self.breakers,
-            # generator tokens at index time feed the single-sync fused
-            # RAG path when the sidecar is enabled (engines/rag_fused.py)
-            prompt_tokenizer=(
-                self.generator.tokenizer
-                if self.cfg.store.token_width and self.generator is not None
-                else None
-            ),
         )
 
         # ---- registry ↔ index reconciliation: a crash between periodic
@@ -473,14 +466,7 @@ class DocQARuntime:
             from docqa_tpu.service.bootstrap import bootstrap_csv_dir
 
             n = bootstrap_csv_dir(
-                self.cfg.data.bootstrap_dir,
-                self.encoder,
-                self.store,
-                prompt_tokenizer=(
-                    self.generator.tokenizer
-                    if self.cfg.store.token_width and self.generator is not None
-                    else None
-                ),
+                self.cfg.data.bootstrap_dir, self.encoder, self.store
             )
             if n and self._index_dir:
                 self._snapshot()
@@ -502,27 +488,6 @@ class DocQARuntime:
                 retriever = FusedTieredRetriever(
                     self.encoder, self.search_index
                 )
-        fused_rag = None
-        if (
-            self.cfg.store.token_width
-            and not self.cfg.flags.use_fake_llm
-            and not self.cfg.flags.use_fake_encoder  # HashEncoder has no
-            # device params for the fused program
-            and self.cfg.store.serving_index == "exact"
-            and (self.mesh is None or self.mesh.n_devices == 1)
-        ):
-            # single-sync ask (engines/rag_fused.py): exact-serving,
-            # single-device only — a tiered policy or sharded store keeps
-            # the classic path, which respects both
-            from docqa_tpu.engines.rag_fused import FusedRAG
-
-            fused_rag = FusedRAG(
-                self.encoder,
-                self.store,
-                self.generator,
-                QA_TEMPLATE,
-                k=self.cfg.store.default_k,
-            )
         # answer router (docqa-lexroute): extractive/lookup questions are
         # served straight from retrieval — zero decode dispatches, no KV
         # slot.  Disabled = the pre-lexroute generative-only path.
@@ -543,7 +508,6 @@ class DocQARuntime:
             use_fake_llm=self.cfg.flags.use_fake_llm,
             batcher=self.batcher,
             retriever=retriever,
-            fused_rag=fused_rag,
             breakers=self.breakers,
             resilience=self.cfg.resilience,
             router=self.router,
@@ -801,8 +765,8 @@ class DocQARuntime:
             if self.store.count:
                 self.qa._retrieve("warm-up", k=self.qa.k)
             # register the warmed programs' cost_analysis() FLOPs with
-            # the observatory (background probe items): /api/status and
-            # bench then report per-stage MFU instead of wall guesses
+            # the observatory (background probe items): /api/status
+            # then reports per-stage MFU instead of wall guesses
             if self.cfg.dispatch.annotate_costs and hasattr(
                 self.batcher, "annotate_costs"
             ):
@@ -1172,8 +1136,8 @@ def make_app(rt: DocQARuntime):
             # reads (storage dtype, shard count, bytes_per_chunk)
             "index": stats_fn() if stats_fn is not None else None,
             # structurally zero since the probe went mesh-native — kept
-            # on the surface (and perf-gate-pinned to 0) so any future
-            # fallback reappearing is loud
+            # on the surface (tests/test_ivf_sharded.py pins it to 0) so
+            # any future fallback reappearing is loud
             "offmesh_fallbacks": DEFAULT_REGISTRY.counter(
                 "retrieve_offmesh_fallback"
             ).value,

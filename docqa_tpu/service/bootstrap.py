@@ -140,9 +140,7 @@ def row_to_sentence(filename: str, row: Dict[str, str]) -> Optional[str]:
     return ". ".join(kv) + "." if kv else None
 
 
-def bootstrap_csv_dir(
-    data_dir: str, encoder, store, prompt_tokenizer=None
-) -> int:
+def bootstrap_csv_dir(data_dir: str, encoder, store) -> int:
     """Index every CSV in ``data_dir``; returns rows indexed.  All sentences
     of all files are encoded in batched device calls (the reference looped
     batch-1 encodes, 649 of them — SURVEY §3.4 hot spot)."""
@@ -164,25 +162,6 @@ def bootstrap_csv_dir(
                         }
                     )
     if sentences:
-        tok_rows = tok_lens = None
-        if prompt_tokenizer is not None and store.cfg.token_width:
-            # sidecar tokens for the fused RAG path: without them a fused
-            # /ask that retrieves KB rows would pack ZERO context while
-            # still citing the chunks as sources
-            import numpy as np
-
-            W = store.cfg.token_width
-            tok_rows = np.zeros((len(sentences), W), np.int32)
-            tok_lens = np.zeros((len(sentences),), np.int32)
-            for i, sent in enumerate(sentences):
-                ids = prompt_tokenizer.encode(sent, add_specials=False)[:W]
-                tok_rows[i, : len(ids)] = ids
-                tok_lens[i] = len(ids)
-        store.add(
-            encoder.encode_texts(sentences),
-            metas,
-            token_rows=tok_rows,
-            token_lens=tok_lens,
-        )
+        store.add(encoder.encode_texts(sentences), metas)
         log.info("bootstrapped %d knowledge rows from %s", len(sentences), data_dir)
     return len(sentences)

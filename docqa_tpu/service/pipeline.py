@@ -53,9 +53,6 @@ class DocumentPipeline:
         store,  # VectorStore
         http_extractor=None,
         on_indexed=None,  # Callable[[int], None]: docs indexed per batch
-        prompt_tokenizer=None,  # generator tokenizer: fills the token
-        # sidecar (store.cfg.token_width) at index time for the
-        # single-sync fused RAG path (engines/rag_fused.py)
         breakers=None,  # resilience.BreakerBoard: broker/deid/index circuits
     ) -> None:
         self.cfg = cfg
@@ -66,7 +63,6 @@ class DocumentPipeline:
         self.store = store
         self.http_extractor = http_extractor
         self.on_indexed = on_indexed
-        self.prompt_tokenizer = prompt_tokenizer
         self.breakers = breakers
         res = cfg.resilience
         # in-place publish retries: a transient broker hiccup must not
@@ -487,20 +483,6 @@ class DocumentPipeline:
                 # append is all-or-nothing) leaves no partial state, so the
                 # Consumer's individual retry cannot duplicate vectors
                 embeddings = self.encoder.encode_texts(all_chunks)
-                tok_rows = tok_lens = None
-                if (
-                    self.prompt_tokenizer is not None
-                    and self.store.cfg.token_width
-                ):
-                    W = self.store.cfg.token_width
-                    tok_rows = np.zeros((len(all_chunks), W), np.int32)
-                    tok_lens = np.zeros((len(all_chunks),), np.int32)
-                    for i, ch_text in enumerate(all_chunks):
-                        ids = self.prompt_tokenizer.encode(
-                            ch_text, add_specials=False
-                        )[:W]
-                        tok_rows[i, : len(ids)] = ids
-                        tok_lens[i] = len(ids)
                 with self._suppress_lock:
                     # a DELETE may have landed during the (seconds-long)
                     # encode; drop those docs' rows now, while suppress_doc
@@ -517,9 +499,6 @@ class DocumentPipeline:
                         ]
                         embeddings = np.asarray(embeddings)[keep]
                         all_meta = [all_meta[i] for i in keep]
-                        if tok_rows is not None:
-                            tok_rows = tok_rows[keep]
-                            tok_lens = tok_lens[keep]
                         per_doc = [
                             (d, n) for d, n in per_doc if d not in late
                         ]
@@ -529,12 +508,7 @@ class DocumentPipeline:
                         for d in sorted(late):
                             obs.finish(ctx_by_doc.get(d), status="dropped")
                     if all_meta:
-                        self.store.add(
-                            embeddings,
-                            all_meta,
-                            token_rows=tok_rows,
-                            token_lens=tok_lens,
-                        )
+                        self.store.add(embeddings, all_meta)
                     self._indexed_doc_ids.update(d for d, _n in per_doc)
             t_batch1 = time.perf_counter()
             for doc_id, n in per_doc:

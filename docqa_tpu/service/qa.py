@@ -215,7 +215,6 @@ class QAService:
         use_fake_llm: bool = False,
         batcher=None,  # ContinuousBatcher: concurrent /ask share decode slots
         retriever=None,  # FusedRetriever: encode+search in one dispatch
-        fused_rag=None,  # FusedRAG: single-sync retrieval->prompt->decode
         breakers=None,  # resilience.BreakerBoard: "decoder" gates generation
         resilience=None,  # ResilienceConfig: degrade thresholds
         router=None,  # engines.router.AnswerRouter: decoder-skip routing
@@ -228,7 +227,6 @@ class QAService:
         self.use_fake_llm = use_fake_llm
         self.batcher = batcher
         self.retriever = retriever
-        self.fused_rag = fused_rag
         self.decoder_breaker = (
             breakers.get("decoder") if breakers is not None else None
         )
@@ -461,41 +459,10 @@ class QAService:
         deadline: Optional[Deadline] = None,
     ) -> Dict[str, Any]:
         """Returns the reference's response contract
-        ``{"answer": ..., "sources": [...]}`` (``llm-qa/main.py:119-122``).
-
-        When the single-sync fused path is wired (``engines/rag_fused.py``)
-        and the batcher is idle, the whole request runs as one device
-        chain — interactive latency drops by a full sync round-trip.
-        Under load (busy batcher) requests keep riding the shared decode
-        slots, where throughput beats solo latency; streaming always uses
-        the batcher (the fused chain has no incremental fetch)."""
+        ``{"answer": ..., "sources": [...]}`` (``llm-qa/main.py:119-122``):
+        ``ask_submit`` resolved in place, the path ``/ask/stream`` takes."""
         if deadline is not None:
             deadline.check("qa_admission")
-        if (
-            self.fused_rag is not None
-            and (k is None or k == self.k)
-            and (
-                self.batcher is None
-                or (self.batcher.n_active == 0 and self.batcher.n_queued == 0)
-            )
-        ):
-            from docqa_tpu.engines.rag_fused import EmptyStoreError
-
-            try:
-                with span("qa_e2e", DEFAULT_REGISTRY):
-                    return self.fused_rag.ask(question)
-            except EmptyStoreError:
-                pass  # classic path answers the empty-index case uniformly
-            except Exception:
-                # a broken fused program (OOM, compile failure) must not
-                # tax EVERY request with a failed attempt, nor fail
-                # silently — disable it loudly and serve classic
-                import logging
-
-                logging.getLogger("docqa.qa").exception(
-                    "fused ask failed; disabling the fused path"
-                )
-                self.fused_rag = None
         with span("qa_e2e", DEFAULT_REGISTRY):
             return self.ask_submit(question, k, deadline=deadline).resolve()
 

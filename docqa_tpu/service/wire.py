@@ -4,8 +4,7 @@
 plane, and it has two failure modes worth engineering around: values
 that raise (numpy scalars on some versions, device arrays, arbitrary
 objects) and values that serialize to NON-JSON (``float("nan")`` →
-``NaN``, which strict parsers — including the perf gate's
-``json.load`` consumers — reject).  ``to_wire`` normalizes both:
+``NaN``, which strict parsers reject).  ``to_wire`` normalizes both:
 
 * numpy scalars → native python via ``.item()``; numpy arrays →
   nested lists via ``.tolist()`` (then re-coerced, so an array of NaN

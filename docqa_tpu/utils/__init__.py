@@ -37,8 +37,8 @@ def compiled_memory_stats(lowered_compiled) -> Optional[dict]:
     or None when the backend provides no analysis.
 
     Lives here (not in ``analysis/``) because both the SERVING layer
-    (``GenerateEngine.decode_memory_analysis`` feeds bench's
-    ``hbm_utilization``) and the audit tooling
+    (``GenerateEngine.decode_memory_analysis``, the telemetry sampler's
+    HBM probe) and the audit tooling
     (``analysis/compile_audit.py`` gates ``compile_budget.json``) read
     the same accounting — engines must never import the lint tree.
 

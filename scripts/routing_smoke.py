@@ -50,7 +50,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MIX_PATH = os.path.join(REPO, "data", "routing_mix.jsonl")
 
-# tiny REAL decoder (the perf-gate/qos smoke shape): the generative arm
+# tiny REAL decoder (the qos smoke's shape): the generative arm
 # must pay genuine prefill+decode dispatches or the split proves nothing
 OVERRIDES = {
     "encoder.hidden_dim": 64,

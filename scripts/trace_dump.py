@@ -163,8 +163,7 @@ def smoke(out: str) -> int:
         f"{len(listing)} trace(s) in the recorder -> {out}"
     )
     # structural gates only: the fake-llm path is sub-millisecond, so a
-    # coverage threshold would gate on scheduler noise — bench gates the
-    # real ≥95% figure on real decode timelines
+    # coverage threshold would gate on scheduler noise
     if n_events == 0 or n_spans < 2:
         print("smoke trace is structurally empty", file=sys.stderr)
         return 1

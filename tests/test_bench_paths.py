@@ -1,9 +1,9 @@
-"""Tiny-scale rehearsal of the bench's TPU-only call shapes.
+"""Tiny-scale rehearsal of call shapes only a chip-sized run takes.
 
-The `small` (CPU smoke) bench run never executes the 7B sections, the
-knob sweeps, or the speculation arms — so a signature typo there would
-only surface on the real chip, wasting a hardware window.  These tests
-execute the exact same API sequences at toy sizes on CPU.
+The 7B-width weight paths, the wide slot counts, the overcommitted pool
+and the speculation arms run at real size only on the chip — a signature
+typo there would surface in a chip window.  These tests execute the same
+API sequences at toy sizes on CPU.
 """
 
 import jax
@@ -19,9 +19,9 @@ TINY = DecoderConfig(
 
 class TestBenchSevenBShapes:
     def test_quantized_host_init_engine_path(self):
-        """bench config 3c: init_quantized_decoder_params(host_init=True)
-        -> GenerateEngine(cfg, GenerateConfig, params=...) ->
-        generate_ids, exactly the bench's call sequence."""
+        """int8 weights drawn on the host:
+        init_quantized_decoder_params(host_init=True) ->
+        GenerateEngine(cfg, GenerateConfig, params=...) -> generate_ids."""
         from docqa_tpu.engines.generate import GenerateEngine
         from docqa_tpu.models.quant import init_quantized_decoder_params
 
@@ -37,7 +37,7 @@ class TestBenchSevenBShapes:
         assert len(out[0]) <= 8
 
     def test_speculation_sweep_engine_variants(self):
-        """bench headline sweep: engines sharing one params tree with
+        """Engines sharing one params tree with
         speculative_k in {0, 4, 8} must produce identical greedy output
         (speculation is output-exact by construction)."""
         from docqa_tpu.engines.generate import GenerateEngine
@@ -62,7 +62,7 @@ class TestBenchSevenBShapes:
         assert outs[0] == outs[1] == outs[2]
 
     def test_bf16_device_init_engine_path(self):
-        """bench config 3b: init_decoder_params(param_dtype=bf16) ->
+        """bf16 weights: init_decoder_params(param_dtype=bf16) ->
         engine -> generate_ids."""
         import jax.numpy as jnp
 
@@ -82,8 +82,8 @@ class TestBenchSevenBShapes:
 
 class TestBenchLoadSweepShapes:
     def test_batcher_32_slots_and_spec(self):
-        """bench sweep combos use n_slots up to 32 and a speculative
-        engine through the same ContinuousBatcher kwargs."""
+        """n_slots up to 32 and a speculative engine through the same
+        ContinuousBatcher kwargs."""
         from docqa_tpu.engines.generate import GenerateEngine
         from docqa_tpu.engines.serve import ContinuousBatcher
 
@@ -104,10 +104,9 @@ class TestBenchLoadSweepShapes:
             b.stop()
 
     def test_kv_paging_sweep_call_shape(self):
-        """bench kv_paging sweep: a ContinuousBatcher with a FIXED
-        kv_pool_tokens overcommit, a live sampler, and the
-        serve_kv_blocks_used series the sweep summarizes into peak
-        occupancy — the exact API sequence at toy size."""
+        """A ContinuousBatcher with a FIXED kv_pool_tokens overcommit, a
+        live sampler, and the serve_kv_blocks_used series whose peak is
+        the pool's occupancy."""
         from docqa_tpu import obs
         from docqa_tpu.engines.generate import GenerateEngine
         from docqa_tpu.engines.serve import ContinuousBatcher
@@ -142,10 +141,9 @@ class TestBenchLoadSweepShapes:
         assert max(vals) <= occ["blocks_total"]
 
     def test_prefix_reuse_ab_call_shape(self):
-        """bench prefix_reuse section: the SAME repeat-heavy session mix
-        through two batchers (sharing disabled, then enabled) with the
-        serve_prefix_* counter deltas the section reports — the exact
-        API sequence at toy size.  The enabled arm must record warm
+        """The SAME repeat-heavy session mix through two batchers
+        (sharing disabled, then enabled) and the serve_prefix_* counter
+        deltas.  The enabled arm must record warm
         hits; the disabled arm must record none."""
         from docqa_tpu.engines.generate import GenerateEngine
         from docqa_tpu.engines.serve import ContinuousBatcher
@@ -155,7 +153,7 @@ class TestBenchLoadSweepShapes:
             TINY, GenerateConfig(max_new_tokens=8, prefill_buckets=(16,))
         )
         ctx = [(3 + i * 7) % 60 + 1 for i in range(140)]
-        mix = [(ctx + [5 + q], "bench-patient-0") for q in range(4)]
+        mix = [(ctx + [5 + q], "patient-0") for q in range(4)]
         hits = {}
         for label, enabled in (("off", False), ("on", True)):
             b = ContinuousBatcher(
@@ -181,7 +179,8 @@ class TestBenchLoadSweepShapes:
         assert hits["on"] >= len(mix) - 1
 
     def test_delta_windowed_histogram_math(self):
-        """bench 5b's serve_tokens_per_chunk delta-mean formula."""
+        """The serve_tokens_per_chunk delta-mean formula
+        (``decode_tokens_per_chunk`` in the benchmark)."""
         from docqa_tpu.runtime.metrics import Histogram
 
         h = Histogram("x")

@@ -177,6 +177,34 @@ class TestMetaspace:
         assert ids[-1] != mine.eos_id  # no eos appended by default
 
 
+    def test_untemplated_prompt_specials_match_the_vocab(self, metaspace_json):
+        """What a prompt built as plain text (no chat template) gets at
+        its ends: BOS at the head, nothing at the tail — and a vocabulary
+        without a BOS/EOS piece gets neither, whatever the flags say."""
+        from docqa_tpu.service.qa import QA_TEMPLATE
+
+        mine = BPETokenizer.from_tokenizer_json(metaspace_json)
+        assert mine.add_eos is False  # sentencepiece lineage
+        prompt = QA_TEMPLATE.format(
+            context="metformin 500 mg twice daily", question="which dose?"
+        )
+        ids = mine.encode(prompt, add_specials=True)
+        assert ids[0] == mine.bos_id and ids[-1] != mine.eos_id
+        assert ids[1:] == mine.encode(prompt, add_specials=False)
+
+        bare = BPETokenizer(
+            {c: i for i, c in enumerate("abcdefgh?▁")},
+            [],
+            mode="metaspace",
+            add_bos=False,
+            add_eos=True,
+        )
+        assert bare.eos_id is None
+        assert bare.encode("abc def?", add_specials=True) == bare.encode(
+            "abc def?", add_specials=False
+        )
+
+
 def _sp_varint(n: int) -> bytes:
     out = b""
     while True:

@@ -70,6 +70,52 @@ class TestStoreTombstones:
         rows = retr.search_texts(["metformin note"], k=3)[0]
         assert all(r.metadata["doc_id"] != "d1" for r in rows)
 
+    def test_tombstoned_chunks_never_reach_the_prompt(self):
+        """Under-fill: with fewer live rows than k, top-k pads with masked
+        ties whose indices point at tombstoned rows — erased clinical text
+        must not be packed into the prompt ``ask_submit`` hands the
+        batcher, nor cited as a source."""
+        from docqa_tpu.engines.encoder import EncoderEngine
+        from docqa_tpu.engines.retrieve import FusedRetriever
+        from docqa_tpu.service.qa import QAService
+
+        cfg = EncoderConfig(
+            vocab_size=512, hidden_dim=32, num_layers=1, num_heads=4,
+            mlp_dim=64, max_seq_len=32, embed_dim=32, dtype="float32",
+        )
+        enc = EncoderEngine(cfg)
+        store = VectorStore(StoreConfig(dim=32, shard_capacity=64))
+        texts = [f"secret{i} note about drug{i}" for i in range(4)]
+        store.add(
+            enc.encode_texts(texts),
+            [
+                {"doc_id": f"d{i}", "source": f"chunk {i}", "text_content": t}
+                for i, t in enumerate(texts)
+            ],
+        )
+        store.delete_docs(["d1", "d2", "d3"])  # one live row, k=3
+
+        prompts = []
+
+        class _Batcher:
+            class engine:
+                tokenizer = None
+
+            def submit_text(self, prompt, **kw):
+                prompts.append(prompt)
+
+        for retriever in (None, FusedRetriever(enc, store)):
+            qa = QAService(
+                enc, store, None, None, k=3, batcher=_Batcher(),
+                retriever=retriever,
+            )
+            pending = qa.ask_submit("what about drug2?")
+            assert pending.sources == ["chunk 0"]
+        assert len(prompts) == 2
+        for prompt in prompts:
+            assert "secret0" in prompt  # the live row's text IS there
+            assert not any(f"secret{i}" in prompt for i in (1, 2, 3))
+
     def test_compaction_erases_and_renumbers(self):
         store, vecs = _mk_store()
         store.delete_docs(["doc0"])

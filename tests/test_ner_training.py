@@ -233,8 +233,7 @@ class TestContextualPHI:
         with a bootstrap CI; floors here are calibrated on the in-test
         550-step tagger + pattern/cue recognizers (measured: test
         span_recall 0.97, char F1 0.90, entity F1 0.93) with slack for
-        training drift — the bench's fully-trained tagger reports its
-        own numbers."""
+        training drift."""
         from docqa_tpu.deid.evalset import evaluate_deid_split
 
         ev = evaluate_deid_split(engine, n_boot=100)

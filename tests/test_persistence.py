@@ -182,6 +182,41 @@ class TestSnapshotVersioning:
         assert s3.count == 3
         assert all(m["tag"] == "new" for m in s3.metadata_rows())
 
+    def test_restore_ignores_a_token_sidecar(self, tmp_path):
+        """A snapshot written while the store kept a per-row token sidecar
+        (manifest ``token_width`` / ``tokens`` + two arrays) still loads:
+        what this version does not read stays on disk unread, and the
+        restored store searches like the one that wrote the vectors."""
+        import json
+
+        import numpy as np
+
+        from docqa_tpu.index.store import VectorStore
+
+        d = str(tmp_path / "index")
+        cfg, s1 = self._store(5, "kept")
+        base = s1.snapshot(d)
+        np.save(os.path.join(base, "tokens.npy"), np.ones((5, 16), np.int32))
+        np.save(os.path.join(base, "token_lens.npy"), np.full((5,), 16, np.int32))
+        with open(os.path.join(base, "manifest.json")) as f:
+            manifest = json.load(f)
+        manifest.update(tokens="tokens.npy", token_width=16)
+        with open(os.path.join(base, "manifest.json"), "w") as f:
+            json.dump(manifest, f)
+
+        s2 = VectorStore.restore(d, cfg)
+        assert s2.count == 5 and s2.version == s1.version
+        assert s2.metadata_rows() == s1.metadata_rows()
+        q = np.eye(8, dtype=np.float32)[[3, 0]] + 0.01
+        want = [[(r.row_id, r.score) for r in row] for row in s1.search(q, k=3)]
+        got = [[(r.row_id, r.score) for r in row] for row in s2.search(q, k=3)]
+        assert got == want and got[0][0][0] == 3
+        # and the next snapshot of the restored store carries no sidecar
+        base2 = s2.snapshot(str(tmp_path / "index2"))
+        assert sorted(os.listdir(base2)) == sorted(
+            f for f in os.listdir(base) if not f.startswith("token")
+        )
+
     def test_old_snapshots_pruned(self, tmp_path):
         import os
 

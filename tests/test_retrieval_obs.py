@@ -14,6 +14,7 @@ new series.
 import time
 from types import SimpleNamespace
 
+import jax
 import numpy as np
 import pytest
 
@@ -492,7 +493,11 @@ class TestFusedTieredShadow:
             if isinstance(o, str):
                 strings.append(o)
                 return
-            if isinstance(o, (bytes, np.ndarray, int, float, bool)):
+            # arrays are leaves: they hold numbers, and a device array's
+            # __slots__ name descriptors (__weakref__) getattr cannot read
+            if isinstance(
+                o, (bytes, np.ndarray, jax.Array, int, float, bool)
+            ):
                 return
             if isinstance(o, dict):
                 for k, v in o.items():
@@ -532,9 +537,9 @@ class TestFusedTieredShadow:
         """docqa-meshindex: the fused tiered probe is MESH-NATIVE — the
         PR-13 loud fallback (and its two extra host<->device
         round-trips) is structurally gone.  The counter stays on the
-        /api/retrieval surface pinned to zero by the perf gate; the
-        sharded-path equivalence itself is covered by
-        tests/test_ivf_sharded.py on the 8-device mesh."""
+        /api/retrieval surface; the sharded-path equivalence (and the
+        same zero) is covered by tests/test_ivf_sharded.py on the
+        8-device mesh."""
         enc, store, tiered, retr = fused_setup
         fallback0 = _counter("retrieve_offmesh_fallback")
         ctx = obs.new_trace("ask")

@@ -96,6 +96,101 @@ class TestBatcherWiring:
         assert set(out) == {"answer", "sources"} and out["answer"]
 
 
+class TestAskOnePath:
+    """``QAService.ask`` is ``ask_submit(...).resolve()`` whatever the
+    batcher's occupancy: an idle batcher gets the request like a busy
+    one, and the deadline, the decoder breaker, the answer router and
+    the cost record apply to it as they do to ``/ask/stream``."""
+
+    @pytest.fixture()
+    def submits(self, rt, monkeypatch):
+        """Every ``EnginePool.submit_text`` call made during the test,
+        which starts on an IDLE batcher (the boot warm-up has drained)."""
+        t_end = time.monotonic() + 300
+        while rt.batcher.n_active or rt.batcher.n_queued:
+            assert time.monotonic() < t_end, "batcher never went idle"
+            time.sleep(0.05)
+        calls = []
+        real = rt.batcher.submit_text
+
+        def spy(prompt, **kw):
+            calls.append((prompt, kw))
+            return real(prompt, **kw)
+
+        monkeypatch.setattr(rt.batcher, "submit_text", spy)
+        return calls
+
+    @pytest.mark.parametrize("surface", ["ask", "stream"])
+    def test_idle_batcher_gets_the_request(self, rt, submits, surface):
+        from docqa_tpu.resilience.deadline import Deadline
+
+        q = "what is the aspirin dose?"
+        deadline = Deadline.after(60.0)
+        if surface == "ask":
+            answer = rt.qa.ask(q, deadline=deadline)["answer"]
+        else:  # what the SSE handler does with its PendingAnswer
+            answer = "".join(
+                rt.qa.ask_submit(q, deadline=deadline).iter_text()
+            )
+        assert answer
+        assert len(submits) == 1
+        prompt, kw = submits[0]
+        assert q in prompt and kw["deadline"] is deadline
+        assert "prefix_key" in kw  # the pool's session-affinity key
+
+    def test_ask_and_stream_answer_alike(self, rt):
+        q = "metformin dosage?"
+        streamed = "".join(rt.qa.ask_submit(q).iter_text())
+        assert rt.qa.ask(q)["answer"] == streamed
+
+    def test_expired_deadline_sheds_before_any_work(self, rt, submits):
+        from docqa_tpu.resilience.deadline import Deadline, DeadlineExceeded
+
+        for call in (rt.qa.ask, rt.qa.ask_submit):
+            with pytest.raises(DeadlineExceeded):
+                call("aspirin dose?", deadline=Deadline.after(-1.0))
+        assert submits == []
+
+    def test_open_breaker_degrades_on_an_idle_batcher(self, rt, submits):
+        from docqa_tpu.resilience.breaker import BreakerBoard
+        from docqa_tpu.service.qa import QAService
+
+        board = BreakerBoard(failure_threshold=1, reset_timeout_s=60.0)
+        board.get("decoder").record_failure()
+        assert board.states()["decoder"] == "open"
+        qa = QAService(
+            rt.encoder, rt.store, rt.generator, rt.summarizer,
+            k=rt.cfg.store.default_k, batcher=rt.batcher,
+            breakers=board, resilience=rt.cfg.resilience,
+        )
+        out = qa.ask("aspirin dose?")
+        assert out["degraded"] is True
+        assert out["degrade_reason"] == "decoder_breaker_open"
+        assert out["answer"] and out["sources"]
+        assert submits == []
+
+    def test_routed_lookup_skips_the_decoder(self, rt, submits):
+        assert rt.qa.router is not None
+        out = rt.qa.ask(
+            "What is the dose of aspirin after the cardiac event?", k=1
+        )
+        assert out["route"] == "extractive"
+        assert "Aspirin 100 mg" in out["answer"]
+        assert submits == []
+
+    def test_cost_record_is_opened_and_retired(self, rt, submits):
+        from docqa_tpu import obs
+
+        ctx = obs.new_trace("ask")
+        out = obs.call_in(ctx, rt.qa.ask, "why was lisinopril started?")
+        obs.finish(ctx)
+        assert out["answer"] and len(submits) == 1
+        cost = obs.timeline_dict(ctx.trace)["cost"]
+        assert cost["class"] == "interactive"
+        assert cost["outcome"] == "ok"
+        assert cost["decode_tokens"] >= 1
+
+
 class TestConcurrentAsk:
     def test_concurrent_matches_solo_and_is_not_serialized(self, rt):
         """VERDICT round-1 item 3 acceptance: N simultaneous /ask complete
@@ -304,8 +399,7 @@ _TAXONOMY = _load_taxonomy()
 # injection recipe per declared shed class: where a request path can
 # surface it.  SUBMIT classes raise out of ask_submit (the admission
 # catch in app._ask_preamble owns the status); RESOLVE classes raise
-# out of the result wait (PendingAnswer.resolve owns the degrade);
-# EMPTY_INDEX is the app's own empty-store refusal.
+# out of the result wait (PendingAnswer.resolve owns the degrade).
 _SUBMIT_RAISE = {
     "QueueFull", "Draining", "BlockPoolExhausted", "DeferredByPolicy",
     "DeadlineExceeded",
@@ -315,7 +409,6 @@ _RESOLVE_RAISE = {
     "RequestCancelled", "SpineCancelled", "SpineClosed",
     "SpineSaturated", "OutOfBlocks",
 }
-_EMPTY_INDEX = {"EmptyStoreError"}
 
 
 def _make_exc(name, entry):
@@ -338,42 +431,13 @@ class TestShedTaxonomyHTTP:
     def test_every_entry_has_an_injection_recipe(self):
         # a NEW taxonomy entry must come with a recipe below — this is
         # the completeness gate that keeps the parametrization honest
-        assert set(_TAXONOMY) == (
-            _SUBMIT_RAISE | _RESOLVE_RAISE | _EMPTY_INDEX
-        )
+        assert set(_TAXONOMY) == _SUBMIT_RAISE | _RESOLVE_RAISE
 
     @pytest.mark.parametrize("name", sorted(_TAXONOMY))
     def test_declared_http_status(self, rt, monkeypatch, name):
         from aiohttp.test_utils import TestClient, TestServer
 
         entry = _TAXONOMY[name]
-
-        if name in _EMPTY_INDEX:
-            # the EmptyStoreError surface is the app's own empty-index
-            # check (the fused path's internal raise falls back to
-            # classic): a runtime with nothing ingested answers 503
-            cfg = load_config(
-                env={}, overrides={**TINY, "flags.use_fake_llm": True}
-            )
-            empty_rt = DocQARuntime(cfg).start()
-
-            async def drive_empty():
-                client = TestClient(TestServer(make_app(empty_rt)))
-                await client.start_server()
-                try:
-                    resp = await client.post(
-                        "/ask/", json={"question": "anything?"}
-                    )
-                    assert resp.status == entry["http_status"] == 503
-                finally:
-                    await client.close()
-
-            try:
-                asyncio.run(drive_empty())
-            finally:
-                empty_rt.stop()
-            return
-
         exc = _make_exc(name, entry)
         if name in _SUBMIT_RAISE:
 
@@ -417,3 +481,29 @@ class TestShedTaxonomyHTTP:
                 await client.close()
 
         asyncio.run(drive())
+
+    def test_empty_index_answers_503(self):
+        """The app's own refusal, no shed class behind it: a runtime with
+        nothing ingested answers /ask/ with 503 before any submit."""
+        from aiohttp.test_utils import TestClient, TestServer
+
+        cfg = load_config(
+            env={}, overrides={**TINY, "flags.use_fake_llm": True}
+        )
+        empty_rt = DocQARuntime(cfg).start()
+
+        async def drive_empty():
+            client = TestClient(TestServer(make_app(empty_rt)))
+            await client.start_server()
+            try:
+                resp = await client.post(
+                    "/ask/", json={"question": "anything?"}
+                )
+                assert resp.status == 503
+            finally:
+                await client.close()
+
+        try:
+            asyncio.run(drive_empty())
+        finally:
+            empty_rt.stop()

@@ -165,12 +165,16 @@ class TestSpineCore:
         s.submit("hold", fn, 0, [])
         time.sleep(0.05)
         t = s.submit("doomed", lambda: 1)
-        ev.set()
+        # close() while the one lane is still held: "doomed" is failed
+        # in the queue before close() starts joining lanes — released
+        # first, the lane could pick it up and run it
         closer = threading.Thread(target=s.close)
         closer.start()
         with pytest.raises((SpineClosed, RuntimeError)):
             t.result(timeout=5)
+        ev.set()
         closer.join(10)
+        assert not closer.is_alive()
         with pytest.raises(SpineClosed):
             s.submit("after", lambda: 1)
 
