@@ -613,8 +613,13 @@ class GenerateConfig:
     prefill_buckets: Tuple[int, ...] = (128, 256, 512, 1024, 2048, 4096)
     # Ragged-prefill token budgets for the continuous batcher (engines/
     # serve.py + engines/paged.py): an admission round packs its prompts
-    # (starts 128-aligned) into the smallest budget that fits, splitting
-    # across dispatches past the largest.  The batcher always ADDS the
+    # (starts 128-aligned) into dispatch groups, each run at the
+    # smallest budget that holds its LARGEST prompt and filled only up
+    # to that budget (serve.partition_prefill_round) — a program's cost
+    # grows faster than its rows, so several short prompts go as several
+    # small dispatches and never sum their way into the full-capacity
+    # program; short prompts ride along with a long one that needs the
+    # large budget anyway.  The batcher always ADDS the
     # full packed cache capacity to this set (a maximal prompt must fit
     # one dispatch), so the WHOLE batcher prefill compile surface is
     # this-set-plus-full — one program per budget, regardless of how

@@ -15,15 +15,22 @@ Attention arXiv 2604.15464):
   gauges PR 7 added existed to show exactly that waste).  Pool
   exhaustion is a typed, deadline-aware admission signal
   (:class:`BlockPoolExhausted`), not an OOM.
-* admission: every free slot is filled from the queue in ONE ragged
-  prefill dispatch per round — mixed-length prompts PACK into a flat
+* admission: every free slot is filled from the queue in one round of
+  ragged prefill dispatches — mixed-length prompts PACK into a flat
   token axis (starts 128-aligned, see ``ops/attention.RAGGED_ALIGN``)
   and scatter straight into their block tables.  No shape families, no
   per-bucket padding: the compile key is the packed token budget alone
   (``gen.prefill_token_buckets``, <= 2 programs), versus the old
   (2 families x buckets) matrix — ``compile_budget.json`` gates the
-  collapse.  Rounds whose prompts exceed the largest budget split
-  across dispatches of the same shape (zero retraces either way);
+  collapse.  A round's prompts are partitioned so that NO dispatch runs
+  a budget larger than its largest prompt needs alone
+  (:func:`partition_prefill_round`): a program's cost grows faster
+  than its rows (the attention is quadratic in them), so three prompts
+  of 384 rows go as three dispatches of the 512-row program, not as
+  one of the full-capacity program; short prompts still ride along in
+  the free rows of a dispatch that a long prompt needs anyway.  Every
+  dispatch is one of the warmed shapes (zero retraces) and the
+  round's first tokens are fetched together;
 * decode: ONE program advances all slots a chunk of tokens per dispatch
   (``lax.fori_loop`` inside jit — no host round-trip per token, SURVEY §7
   hard part (b)), gathering K/V through the block tables; finished lanes
@@ -498,6 +505,59 @@ class DeferredByPolicy(QueueFull):
     it that way for interactive traffic, and relaxes automatically (the
     SLO probe is consulted per submission, so no un-defer edge exists
     to miss).  Ledger outcome ``shed_deferred``, never ``shed_queue``."""
+
+
+def _pick_budget(budgets: Sequence[int], n_rows: int) -> int:
+    """Smallest of the ascending ``budgets`` covering ``n_rows`` (the
+    largest for anything bigger)."""
+    for t in budgets:
+        if n_rows <= t:
+            return t
+    return budgets[-1]
+
+
+def partition_prefill_round(
+    rows: Sequence[int], warm: Sequence[bool], budgets: Sequence[int]
+) -> List[Tuple[bool, int, List[int]]]:
+    """Partition one admission round into prefill dispatch groups.
+
+    ``rows[i]``: packed rows entry ``i``'s novel part takes at its
+    ``RAGGED_ALIGN`` start; ``warm[i]``: it maps a cached prefix (the
+    warm program); ``budgets``: the compiled token budgets, ascending.
+    Returns ``(warm, budget, members)`` per dispatch, in dispatch order.
+
+    1. A group never runs a budget larger than its largest member needs
+       alone — ``budget = _pick_budget(max member rows)`` — and its rows
+       sum to at most that budget.  A program's time grows faster than
+       its rows (the float32 attention covers all T x T of them; on a
+       v5e the 4096-row Mistral-7B program takes 13x the 512-row one),
+       so one large dispatch for several small prompts loses to one
+       small dispatch each; a prompt that needs the large budget alone
+       has paid for it, and smaller ones ride along in its free rows.
+    2. Among such partitions, few groups: first-fit over the members in
+       decreasing order of rows (the first member of a group is its
+       largest and fixes its budget).
+    3. Cold and warm members never share a group (two programs), and
+       every cold group comes before every warm one: a warm lane may
+       read rows that a cold lane of this very round writes.
+
+    A pure function of shapes: no threshold, no timing, no model name.
+    With one budget it is plain first-fit-decreasing bin packing."""
+    groups: List[list] = []  # [warm, budget, free rows, members]
+    for flag in (False, True):
+        opened = len(groups)
+        members = [i for i, w in enumerate(warm) if bool(w) == flag]
+        # sorted() is stable: members of equal rows keep arrival order
+        for i in sorted(members, key=lambda i: -rows[i]):
+            for g in groups[opened:]:
+                if rows[i] <= g[2]:
+                    g[2] -= rows[i]
+                    g[3].append(i)
+                    break
+            else:
+                budget = _pick_budget(budgets, rows[i])
+                groups.append([flag, budget, budget - rows[i], [i]])
+    return [(flag, budget, members) for flag, budget, _free, members in groups]
 
 
 class ContinuousBatcher:
@@ -975,9 +1035,9 @@ class ContinuousBatcher:
 
     def _get_prefill_fn(self):
         """One jit object; XLA re-specializes per packed-token-budget
-        shape T alone.  ``_admit_round`` packs a round's prompts into the
-        smallest budget in ``self._token_buckets`` that fits (splitting
-        past the largest), so the WHOLE prefill compile surface is
+        shape T alone.  ``_admit_round`` runs each of a round's dispatch
+        groups at the smallest budget in ``self._token_buckets`` that
+        holds its largest prompt, so the WHOLE prefill compile surface is
         ``len(self._token_buckets)`` programs (<= 2) — the old policy of
         two batch families x every prompt bucket is gone, and
         :meth:`warmup` pre-compiles the full set before traffic (the
@@ -1276,12 +1336,11 @@ class ContinuousBatcher:
 
     def _pick_token_bucket(self, n_tokens: int) -> int:
         """Smallest packed token budget covering ``n_tokens`` (the
-        largest budget for anything bigger — callers split into multiple
-        dispatches of that same shape)."""
-        for t in self._token_buckets:
-            if n_tokens <= t:
-                return t
-        return self._token_buckets[-1]
+        largest budget for anything bigger — which admission never
+        packs: a prompt is truncated to fit the largest budget alone,
+        and :func:`partition_prefill_round` fills a group only up to
+        the budget its largest member picked)."""
+        return _pick_budget(self._token_buckets, n_tokens)
 
     # ---- public API ----------------------------------------------------------
 
@@ -2029,19 +2088,21 @@ class ContinuousBatcher:
     ):
         """Prefill every (slot, request) pair of this round through the
         ragged packed program (async — no device sync; the round is
-        finalized with one host fetch per dispatch group in
+        finalized with ONE host fetch of every group's first tokens in
         ``_finalize_admissions``).
 
-        Prompts pack into a flat token stream (starts RAGGED_ALIGN-
-        aligned) and the stream pads to the smallest configured token
-        budget that fits — mixed lengths share one dispatch with no
-        shape family and no per-bucket padding; a round whose prompts
-        exceed the largest budget splits into several dispatches of that
-        same shape (no retrace).  Each request's KV blocks are allocated
-        here (prompt + grow margin); a request the pool cannot currently
-        hold goes BACK to the queue head (traced, deadline still
-        enforced there) instead of failing — ``_pop_free_slots``
-        pre-checks capacity, so that path is a rare race, not the norm.
+        Prompts pack into flat token streams (starts RAGGED_ALIGN-
+        aligned), one per dispatch group, and :func:`partition_prefill_round`
+        decides the groups: each runs the smallest configured token
+        budget that holds its LARGEST prompt, never a larger one because
+        several prompts were summed — mixed lengths still share a
+        dispatch with no shape family and no per-bucket padding, and
+        every dispatch is one of the warmed shapes (no retrace).  Each
+        request's KV blocks are allocated here (prompt + grow margin);
+        a request the pool cannot currently hold goes BACK to the queue
+        head (traced, deadline still enforced there) instead of failing
+        — ``_pop_free_slots`` pre-checks capacity, so that path is a
+        rare race, not the norm.
         A request whose prompt cannot be marshalled fails alone, before
         the dispatch — not with the whole round.
 
@@ -2140,18 +2201,22 @@ class ContinuousBatcher:
                         req, "prefix_hit", anomalous=False,
                         shared_tokens=shared, prompt_tokens=len(ids),
                     )
-                if self._prefix_cache is not None:
-                    # insert IN the allocation loop, not after it: a
-                    # later request of the SAME key in this very round
-                    # then acquires this entry and shares in-round
-                    # (consecutive questions of one session routinely
-                    # land in one admission round under load).  Device
-                    # ordering makes it exact: cold groups dispatch
-                    # before warm ones, and within a dispatch the layer
-                    # scatter precedes the prefix gather — the shared
-                    # rows are always written before any sharer reads
-                    # them.  Abort paths stay leak-free: a failed round
-                    # clears the whole cache.
+                if self._prefix_cache is not None and not shared:
+                    # a COLD lane inserts IN the allocation loop, not
+                    # after it: a later request of the SAME key in this
+                    # very round then acquires this entry and shares
+                    # in-round (consecutive questions of one session
+                    # routinely land in one admission round under load).
+                    # Device ordering makes it exact: cold groups
+                    # dispatch before warm ones — the shared rows are
+                    # always written before any sharer reads them.  A
+                    # WARM lane (it may lengthen its key's entry by
+                    # rows of its own suffix) inserts after the loop,
+                    # below: warm groups dispatch in the packer's order,
+                    # not in arrival order, so no lane of this round may
+                    # read what a warm lane of this round writes.  Abort
+                    # paths stay leak-free: a failed round clears the
+                    # whole cache.
                     self._prefix_cache.insert(req.prefix_key, ids, table)
             except BaseException:
                 # between ensure() and the good-list handoff the table
@@ -2214,33 +2279,27 @@ class ContinuousBatcher:
             row[: len(table.blocks)] = table.blocks
             self._caps_np[slot] = table.capacity
         self._tables_dirty = True
+        for _slot, req, ids, table, shared in good:
+            if shared and self._prefix_cache is not None:
+                # see the allocation loop: visible from the next round on
+                self._prefix_cache.insert(req.prefix_key, ids, table)
 
         # pack into dispatch groups: each prompt's NOVEL portion starts
         # on a RAGGED_ALIGN boundary (the exactness contract in
-        # ops/attention.py) and a group never exceeds the largest
-        # budget.  Warm lanes (shared > 0) pack only their suffix and
-        # group separately from cold ones: cold rounds keep dispatching
-        # the exact pre-prefix program (numerics untouched by
-        # construction), warm rounds pay the prefix-gather program.
-        def _packed_len(entry) -> int:
-            return round_up(len(entry[2]) - entry[4], RAGGED_ALIGN)
-
-        groups: List[List[tuple]] = []
-        for warm_flag in (False, True):
-            cur: List[tuple] = []
-            cur_tokens = 0
-            max_t = self._token_buckets[-1]
-            for entry in good:
-                if bool(entry[4]) != warm_flag:
-                    continue
-                n_aligned = _packed_len(entry)
-                if cur and cur_tokens + n_aligned > max_t:
-                    groups.append((warm_flag, cur))
-                    cur, cur_tokens = [], 0
-                cur.append(entry)
-                cur_tokens += n_aligned
-            if cur:
-                groups.append((warm_flag, cur))
+        # ops/attention.py) and a group runs the budget its largest
+        # member needs alone (partition_prefill_round).  Warm lanes
+        # (shared > 0) pack only their suffix and group separately from
+        # cold ones: cold rounds keep dispatching the exact pre-prefix
+        # program (numerics untouched by construction), warm rounds pay
+        # the prefix-gather program.
+        packed_rows = [
+            round_up(len(ids) - shared, RAGGED_ALIGN)
+            for _slot, _req, ids, _table, shared in good
+        ]
+        is_warm = [bool(shared) for *_entry, shared in good]
+        plan = partition_prefill_round(
+            packed_rows, is_warm, self._token_buckets
+        )
 
         fn = self._get_prefill_fn()
         S = self.n_slots
@@ -2253,9 +2312,11 @@ class ContinuousBatcher:
         # take at their RAGGED_ALIGN starts, novel tokens among them) —
         # what the prefill spans and the padding counters report
         group_rows: List[Tuple[int, int, int]] = []
-        for warm_flag, group in groups:
-            total = sum(_packed_len(e) for e in group)
-            T = self._pick_token_bucket(total)
+        groups: List[Tuple[bool, List[tuple]]] = []
+        for warm_flag, T, members in plan:
+            group = [good[i] for i in members]
+            groups.append((warm_flag, group))
+            total = sum(packed_rows[i] for i in members)
             group_rows.append(
                 (T, total, sum(len(e[2]) - e[4] for e in group))
             )
@@ -2371,6 +2432,14 @@ class ContinuousBatcher:
         t_prefill1 = _now()
         DEFAULT_REGISTRY.counter("serve_admit_rounds").inc()
         DEFAULT_REGISTRY.counter("serve_admitted").inc(len(ordered))
+        DEFAULT_REGISTRY.counter("serve_prefill_dispatches").inc(len(groups))
+        # split: the round ran more groups than it would have had every
+        # group been allowed the largest budget — how often rule 1 of
+        # partition_prefill_round engages
+        if len(groups) > len(partition_prefill_round(
+            packed_rows, is_warm, self._token_buckets[-1:]
+        )):
+            DEFAULT_REGISTRY.counter("serve_prefill_rounds_split").inc()
         DEFAULT_REGISTRY.counter("serve_prefill_budget_tokens").inc(
             sum(T for T, _rows, _novel in group_rows)
         )
@@ -2392,7 +2461,8 @@ class ContinuousBatcher:
                 )
                 _req_span(
                     req, "serve_prefill", t_prefill0, t_prefill1,
-                    batch=len(good), dispatch=gi, slot=slot,
+                    batch=len(good), dispatch=gi,
+                    dispatches=len(groups), slot=slot,
                     prompt_tokens=len(ids), blocks=len(table.blocks),
                     shared_tokens=shared, budget_tokens=T,
                     packed_tokens=rows,

@@ -35,7 +35,8 @@ TILE = ("serve_queue_wait", "serve_admit_hold", "serve_prefill",
         "serve_first_token")
 COUNTERS = ("serve_admit_rounds", "serve_admitted", "serve_prefill_tokens",
             "serve_prefill_budget_tokens", "serve_decode_chunks",
-            "serve_decode_chunks_stale")
+            "serve_decode_chunks_stale", "serve_prefill_dispatches",
+            "serve_prefill_rounds_split")
 GAP_S = 5e-3
 
 
@@ -131,6 +132,10 @@ class TestServeSpansTile:
                 assert len(req.prompt_ids) <= attrs["packed_tokens"]
                 assert attrs["packed_tokens"] <= attrs["budget_tokens"]
                 assert attrs["batch"] == hold["round"]
+            # its dispatch group is one of the groups its round ran
+            prefill = spans["serve_prefill"].attrs
+            assert 0 <= prefill["dispatch"] < prefill["dispatches"]
+            assert prefill["dispatches"] <= prefill["batch"]
 
 
 class TestDispatchCounters:
@@ -143,6 +148,14 @@ class TestDispatchCounters:
         )
         assert (gained["serve_prefill_tokens"]
                 <= gained["serve_prefill_budget_tokens"])
+        # a round is at least one dispatch group and a group at least one
+        # request; only a round of several groups can count as split
+        assert (gained["serve_admit_rounds"]
+                <= gained["serve_prefill_dispatches"]
+                <= gained["serve_admitted"])
+        assert (gained["serve_prefill_rounds_split"]
+                <= gained["serve_prefill_dispatches"]
+                - gained["serve_admit_rounds"])
         rounds = {s.attrs["round"] for t, _r in traces
                   for s in t.snapshot_spans()
                   if s.name == "serve_admit_hold"}
