@@ -125,13 +125,27 @@ def patient(seed: int, index: int) -> Dict[str, object]:
 
 
 def identity_chunk(p: Dict[str, object]) -> str:
-    """The chunk the lookup facts are planted in: 20 distinct tokens, in
-    the words an MRN / phone / dosage question uses, EN and FR."""
+    """The chunk the lookup facts are planted in: 24 distinct tokens, in
+    the words an MRN / phone / dosage question uses, EN and FR.
+
+    The facts are stated twice, so that the chunk is as long as a note (84
+    tokens of the program's tokenizer; the notes have 77 to 87).  A prompt
+    is the template, the question and three retrieved chunks, and WHICH
+    three is drawn with the seed (the encoder's weights are): with a
+    43-token identity chunk a prompt that retrieved two of them had under
+    257 tokens, took 256 packed rows and not 384, and shared a 512-row
+    prefill dispatch with another such prompt — so the seed set how many
+    dispatches a round ran (2 or 3, ~53 ms apart) and which small programs
+    the warm phase built (PERF.md section 6, PR 33).  With chunks of one
+    size every prompt takes 384 rows whatever was retrieved."""
     return (
         f"Registration: patient {p['name']}, MRN {p['mrn']}, numéro de "
         f"dossier {p['mrn']}. Phone number on file {p['phone']}, numéro de "
         f"téléphone {p['phone']}. Dosage / posologie: {p['drug']} "
-        f"{p['dose']} mg."
+        f"{p['dose']} mg. Fiche d'identité du patient {p['name']} : numéro "
+        f"de dossier {p['mrn']} (MRN {p['mrn']}), téléphone {p['phone']} "
+        f"(phone {p['phone']}), posologie {p['drug']} {p['dose']} mg, dosage "
+        f"{p['drug']} {p['dose']} mg."
     )
 
 

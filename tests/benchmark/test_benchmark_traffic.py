@@ -73,3 +73,30 @@ def test_every_generative_question_reaches_the_decoder(template):
     for index in range(0, N_PATIENTS, 41):
         q = corpus.question(9, template, index)
         assert router.decide(q).route == ROUTE_GENERATIVE, q
+
+
+def test_every_prompt_of_the_generative_mix_takes_the_same_packed_rows():
+    """ISSUE 33: which three chunks a question retrieves is drawn with the
+    seed (the encoder's weights are), so the chunks are of one size: the
+    template, any question and ANY three chunks of the corpus make a prompt
+    that packs into the same number of ``RAGGED_ALIGN`` rows — three such
+    prompts are three 512-row prefill dispatches on every seed, never two."""
+    from docqa_tpu.ops.attention import RAGGED_ALIGN
+    from docqa_tpu.service.qa import QA_TEMPLATE
+    from docqa_tpu.text.tokenizer import default_tokenizer
+
+    tok = default_tokenizer(32000, vocab_path=None)  # the cells' tokenizer
+    chunks = [row["text_content"] for index in range(0, N_PATIENTS, 13)
+              for row in corpus.patient_chunks(9, index)]
+    def size(text):
+        return len(tok.encode(text, add_specials=False))
+
+    rows = set()
+    for three in ([min(chunks, key=size)] * 3, [max(chunks, key=size)] * 3):
+        for template in GENERATIVE:
+            for index in (0, 777, 2047):
+                prompt = QA_TEMPLATE.format(
+                    context="\n\n".join(three),
+                    question=corpus.question(9, template, index))
+                rows.add(-(-len(tok.encode(prompt)) // RAGGED_ALIGN))
+    assert rows == {3}  # 257..384 tokens: 384 rows, two never share 512
