@@ -51,6 +51,7 @@ the budget format and amendment workflow.
 
 from __future__ import annotations
 
+import functools
 import json
 import math
 import os
@@ -61,7 +62,10 @@ from typing import Any, Dict, List, Optional, Sequence
 # engines must never import the lint tree
 from docqa_tpu.utils import compiled_memory_stats as memory_of
 
-WORKLOADS = ("serve", "generate", "retrieve_fused", "seq2seq", "encoder")
+WORKLOADS = (
+    "serve", "serve_latent", "generate", "retrieve_fused", "seq2seq",
+    "encoder",
+)
 
 # headroom factor applied when a ceiling must grow (or is first written):
 # measured bytes wobble a few percent across jaxlib versions; a regression
@@ -135,6 +139,25 @@ def _audit_decoder_cfg():
     )
 
 
+def _audit_latent_cfg():
+    """The latent-attention / routed-expert block (models/latent.py) at
+    audit widths: one dense layer, one routed layer of which a quarter of
+    the experts is held."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=64, hidden_dim=32, num_layers=2, num_heads=2,
+        num_kv_heads=1, head_dim=24, mlp_dim=64, max_seq_len=128,
+        block="mla_moe", q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_scaling_factor=40.0, rope_original_max_len=64,
+        rope_mscale=0.707, rope_mscale_all_dim=0.707, first_dense_layers=1,
+        num_experts=16, experts_per_token=2, expert_dim=16,
+        num_shared_experts=1, expert_groups=4, expert_groups_per_token=2,
+        routed_scale=4.0, experts_held_start=0, experts_held=4,
+    )
+
+
 def _audit_gen_cfg():
     from docqa_tpu.config import GenerateConfig
 
@@ -166,14 +189,21 @@ def _audit_encoder_cfg():
 # ---------------------------------------------------------------------------
 
 
-def _audit_serve() -> Dict[str, Any]:
+def _audit_serve(latent: bool = False) -> Dict[str, Any]:
     """The PAGED batcher's whole compile surface: one ragged prefill
     program per packed token budget (<= 2) plus the one block-table
     decode chunk — the collapse from the pre-paged (2 shape families x
     prompt buckets) matrix that ROADMAP item 1 demanded.  Steady state =
     a trickle round (1 request) and a full round (n_slots requests) of
     MIXED prompt lengths AFTER warmup; both must hit warm programs (mixed
-    lengths sharing one program is the point of ragged prefill)."""
+    lengths sharing one program is the point of ragged prefill).
+
+    ``latent``: the same batcher over the latent block (workload
+    ``serve_latent``): its cold prefill budgets and its decode chunk,
+    which carries the expert-choice sums.  That block prefills cold only
+    (prefix cache off, no speculation): there is no warm family."""
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
 
@@ -182,6 +212,9 @@ def _audit_serve() -> Dict[str, Any]:
     from docqa_tpu.engines.serve import ContinuousBatcher
 
     cfg, gen = _audit_decoder_cfg(), _audit_gen_cfg()
+    if latent:
+        cfg = _audit_latent_cfg()
+        gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
     engine = GenerateEngine(cfg, gen)
     # cache_len 256: large enough that the 128-aligned prefix cache is
     # ENABLED (share_alignment < seq_capacity), so the warm prefill
@@ -210,12 +243,10 @@ def _audit_serve() -> Dict[str, Any]:
         # second admission maps the cached prefix in and dispatches the
         # WARM program, which warmup must already have compiled
         warm_prompt = [1 + i % 60 for i in range(140)]
-        batcher.submit_ids(
-            warm_prompt + [3, 5], max_new_tokens=3, prefix_key="audit"
-        ).result(timeout=120)
-        batcher.submit_ids(
-            warm_prompt + [7, 9], max_new_tokens=3, prefix_key="audit"
-        ).result(timeout=120)
+        for tail in ([3, 5], [7, 9]):
+            batcher.submit_ids(
+                warm_prompt + tail, max_new_tokens=3, prefix_key="audit"
+            ).result(timeout=120)
         retrace_prefill = jit_cache_size(prefill_fn) - warm_prefill
         retrace_prefill_w = (
             jit_cache_size(prefill_warm_fn) - warm_prefill_w
@@ -262,6 +293,7 @@ def _audit_serve() -> Dict[str, Any]:
         per_shape_warm = {
             f"tokens_{T}": prefill_mem(T, warm=True)
             for T in batcher._token_buckets
+            if batcher.prefix_cache_enabled
         }
         tables = jax.ShapeDtypeStruct(
             (S, batcher.blocks_per_seq), jnp.int32
@@ -280,7 +312,7 @@ def _audit_serve() -> Dict[str, Any]:
                 decode_fn, engine.params, pool_struct, tables, caps,
                 tok, lens, active, rng,
             )
-        return {
+        report = {
             "meta": {
                 "n_slots": S,
                 "paged": True,
@@ -320,16 +352,16 @@ def _audit_serve() -> Dict[str, Any]:
                     "steady_state_retraces": retrace_prefill_w,
                     "per_shape": per_shape_warm,
                     "peak_bytes": max(
-                        (m or {}).get("peak_bytes", 0)
-                        for m in per_shape_warm.values()
+                        ((m or {}).get("peak_bytes", 0)
+                         for m in per_shape_warm.values()), default=0,
                     ),
                     "flops": max(
-                        (m or {}).get("flops", 0)
-                        for m in per_shape_warm.values()
+                        ((m or {}).get("flops", 0)
+                         for m in per_shape_warm.values()), default=0,
                     ),
                     "bytes_accessed": max(
-                        (m or {}).get("bytes_accessed", 0)
-                        for m in per_shape_warm.values()
+                        ((m or {}).get("bytes_accessed", 0)
+                         for m in per_shape_warm.values()), default=0,
                     ),
                 },
                 "serve_decode": {
@@ -345,6 +377,14 @@ def _audit_serve() -> Dict[str, Any]:
                 },
             },
         }
+        if not batcher.prefix_cache_enabled:
+            del report["roots"]["serve_prefill_warm"]
+        if latent:
+            report["roots"] = {
+                name.replace("serve_", "serve_latent_"): root
+                for name, root in report["roots"].items()
+            }
+        return report
     finally:
         batcher.stop()
 
@@ -507,6 +547,7 @@ def _audit_encoder() -> Dict[str, Any]:
 
 _AUDITS = {
     "serve": _audit_serve,
+    "serve_latent": functools.partial(_audit_serve, latent=True),
     "generate": _audit_generate,
     "retrieve_fused": _audit_retrieve,
     "seq2seq": _audit_seq2seq,

@@ -65,6 +65,8 @@ AUDIT_PROGRAMS = (
     "decoder_prefill",
     "decoder_paged_decode",
     "decoder_ragged_prefill",
+    "latent_paged_decode",
+    "latent_ragged_prefill",
     "ring_attention",
     "ulysses_attention",
     "retrieve_fused",
@@ -161,6 +163,25 @@ def _audit_decoder_cfg():
         head_dim=8,
         mlp_dim=128,
         max_seq_len=64,
+    )
+
+
+def _audit_latent_cfg():
+    """The latent-attention / routed-expert block (models/latent.py):
+    heads, the dense and shared MLP widths and the experts HELD all
+    divisible by 8 — the held range is what the ``model`` axis divides."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=128, hidden_dim=64, num_layers=2, num_heads=8,
+        num_kv_heads=1, head_dim=24, mlp_dim=128, max_seq_len=64,
+        block="mla_moe", q_lora_rank=32, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_scaling_factor=40.0, rope_original_max_len=32,
+        rope_mscale=0.707, rope_mscale_all_dim=0.707, first_dense_layers=1,
+        num_experts=32, experts_per_token=2, expert_dim=16,
+        num_shared_experts=1, expert_groups=4, expert_groups_per_token=2,
+        routed_scale=4.0, experts_held_start=0, experts_held=8,
     )
 
 
@@ -267,14 +288,22 @@ def _audit_decoder(mesh_name: str, prefill: bool, pspec_fn=None):
     return counts, meta
 
 
-def _audit_paged(mesh_name: str, prefill: bool):
+def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False):
     """Lower the PAGED serving programs (engines/paged.py) under the
     same Megatron layout: the block-pool gather/scatter must not change
     the collective story — still exactly one all-reduce per Megatron
     block, zero all-gathers (the pool shards kv-heads over ``model``,
     its flat block-row axis is replicated, and every table index rides
     that unsharded axis).  This is the ISSUE's "unchanged collective
-    budget" evidence for the paged KV tentpole."""
+    budget" evidence for the paged KV tentpole.
+
+    ``latent``: the same two programs of the latent block, whose row pool
+    is replicated and whose routed experts ride the ``model`` axis on
+    their expert axis (parallel/sharding._latent_param_pspecs).  They have
+    to LOWER on every mesh; their collectives are recorded, not held to
+    the Megatron count — GSPMD's handling of a per-expert loop over a
+    sharded expert axis is not the exchange a deployment would run, and no
+    cell runs this block across chips yet."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -283,23 +312,23 @@ def _audit_paged(mesh_name: str, prefill: bool):
         paged_decode_forward,
         ragged_prefill_forward,
     )
+    from docqa_tpu.models.decoder import kv_row_shapes
     from docqa_tpu.parallel.sharding import (
         decoder_param_pspecs,
         paged_pool_pspecs,
     )
 
-    cfg = _audit_decoder_cfg()
+    cfg = _audit_latent_cfg() if latent else _audit_decoder_cfg()
     mesh = _mesh(mesh_name)
     slots, block_size, n_blocks = 4, 8, 16
     rope_len = 32
     params, _cache, _ids, _lengths = _decoder_abstract_args(cfg, slots, 1, 8)
     pools = {
         f"{kv}{i}": jax.ShapeDtypeStruct(
-            (n_blocks * block_size, cfg.num_kv_heads, cfg.head_dim),
-            jnp.bfloat16,
+            (n_blocks * block_size, heads, width), jnp.bfloat16,
         )
         for i in range(cfg.num_layers)
-        for kv in ("k", "v")
+        for kv, (heads, width) in kv_row_shapes(cfg).items()
     }
     pspecs = decoder_param_pspecs(cfg, mesh.model_axis)
     pool_specs = paged_pool_pspecs(cfg, mesh)
@@ -362,6 +391,9 @@ def _audit_paged(mesh_name: str, prefill: bool):
         "block_size": block_size,
         "model_parallel": mesh.n_model,
     }
+    if latent:
+        del meta["megatron_blocks"]
+        meta["experts_held"] = cfg.experts_held
     return counts, meta
 
 
@@ -665,6 +697,12 @@ _AUDITS: Dict[str, Callable[[str], Tuple[Dict[str, int], Dict[str, Any]]]] = {
     "decoder_prefill": functools.partial(_audit_decoder, prefill=True),
     "decoder_paged_decode": functools.partial(_audit_paged, prefill=False),
     "decoder_ragged_prefill": functools.partial(_audit_paged, prefill=True),
+    "latent_paged_decode": functools.partial(
+        _audit_paged, prefill=False, latent=True
+    ),
+    "latent_ragged_prefill": functools.partial(
+        _audit_paged, prefill=True, latent=True
+    ),
     "ring_attention": _audit_ring,
     "ulysses_attention": _audit_ulysses,
     "retrieve_fused": _audit_retrieve,

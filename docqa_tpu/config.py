@@ -156,6 +156,42 @@ class DecoderConfig:
     # every TEXT entry point (generate_texts, batcher submit_text) — id
     # entry points are never wrapped.
     chat_template: Optional[str] = None
+    # ---- the block (models/latent.py) -----------------------------------
+    # "gqa_swiglu": the Mistral / Llama block above.  "mla_moe": the
+    # DeepSeek-V2 block — multi-head LATENT attention (the cache holds one
+    # normed latent + one rotated key per token and layer, shared by every
+    # head) and, past ``first_dense_layers``, a group-limited routed expert
+    # MLP with shared experts.  Chosen at trace time; every field below is
+    # read by that block alone.  There ``head_dim`` is the query/key width
+    # (``qk_nope_head_dim + qk_rope_head_dim``) and ``num_kv_heads`` is 1.
+    block: str = "gqa_swiglu"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN RoPE scaling (ops/rope.yarn_inv_freq); factor 1 = plain RoPE
+    rope_scaling_factor: float = 1.0
+    rope_original_max_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # routed experts: the router scores all ``num_experts``; this process
+    # HOLDS the contiguous range [experts_held_start, + experts_held)
+    # (expert parallelism: its chip's share) and computes their part of
+    # the sum plus the shared experts; what absent experts would add is
+    # left out.  experts_held 0 = all of them.
+    first_dense_layers: int = 0
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    num_shared_experts: int = 0
+    expert_groups: int = 1
+    expert_groups_per_token: int = 1
+    routed_scale: float = 1.0
+    experts_held_start: int = 0
+    experts_held: int = 0
 
     @staticmethod
     def mistral_7b() -> "DecoderConfig":
@@ -641,6 +677,18 @@ class GenerateConfig:
     # per-dispatch host round-trip at the cost of coarser slot-retirement
     # granularity
     decode_chunk: int = 16
+    # coalesced admission (ROADMAP A1 (a)): once a round has popped its
+    # first request and slots are still free, the batcher's worker waits
+    # this long for the next arrival before it dispatches the round's
+    # prefill; every arrival restarts the wait, and it ends at once when
+    # the slots are full.  Requests that reach the queue milliseconds
+    # apart (a ward's clients asking together: their retrievals leave the
+    # HTTP layer's one device-lane thread one by one) then prefill as ONE
+    # round instead of one round per decode chunk.  The price: a request
+    # that arrives alone starts this much later, and live lanes decode
+    # this much later.  0 = off: a round is whatever is queued when the
+    # worker looks, bit for bit the behaviour before the option existed.
+    admit_hold_ms: float = 0.0
     # paged KV cache (engines/paged.py; docs/OPERATIONS.md "Paged KV
     # cache"): tokens per KV block.  Smaller blocks waste less on the
     # last partial block per request but grow the block-table/alloc

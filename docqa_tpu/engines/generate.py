@@ -33,6 +33,11 @@ from docqa_tpu.models.decoder import (
     init_decoder_params,
     init_kv_cache,
 )
+from docqa_tpu.models.latent import (
+    LATENT_BLOCK,
+    check_latent_config,
+    is_latent,
+)
 from docqa_tpu.engines.spine import spine_run
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.parallel.sharding import cache_pspecs, shard_decoder_params
@@ -201,6 +206,11 @@ class GenerateEngine:
         self.params = params
         if use_flash is None:
             use_flash = jax.default_backend() == "tpu" and cfg.head_dim % 64 == 0
+        if is_latent(cfg):
+            # served by the batcher over the paged latent pool only; no
+            # Pallas kernel reads that pool yet (ops/attention.py)
+            check_latent_config(cfg)
+            use_flash = False
         self.use_flash = use_flash
         self._fns = {}
 
@@ -433,6 +443,13 @@ class GenerateEngine:
         return out, n_emit
 
     def _get_fn(self, b: int, bucket: int, max_new: int, greedy: bool):
+        if is_latent(self.cfg):
+            raise NotImplementedError(
+                f'the solo dense-cache engine has no "{LATENT_BLOCK}" block '
+                "(model_type deepseek_v2): generate through the batcher "
+                "(engines/serve.ContinuousBatcher), which serves it over the "
+                "paged latent cache"
+            )
         spec_k = self.gen.speculative_k
         if greedy and spec_k >= 2:
             key = (b, bucket, max_new, "spec", spec_k)

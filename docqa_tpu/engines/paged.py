@@ -46,13 +46,24 @@ from docqa_tpu.models.decoder import (
     Params,
     decoder_head,
     decoder_layer_stack,
+    kv_row_shapes,
+)
+from docqa_tpu.models.latent import (
+    absorb_query,
+    expand_output,
+    is_latent,
+    latent_layer_stack,
+    softmax_scale,
+    up_projected,
 )
 from docqa_tpu.ops.attention import (
     paged_decode_attention,
+    paged_latent_decode_attention,
     ragged_prefill_attention,
 )
 
-PagedPools = Dict[str, "jnp.ndarray"]  # "k0".."k{L-1}", "v0".."v{L-1}"
+# "k0".."k{L-1}", "v0".."v{L-1}"; the latent block: "c0".."c{L-1}"
+PagedPools = Dict[str, "jnp.ndarray"]
 
 
 class OutOfBlocks(RuntimeError):
@@ -587,8 +598,11 @@ def init_paged_pools(
     dtype: Optional["jnp.dtype"] = None,
     sharding=None,
 ) -> PagedPools:
-    """Flat per-layer K/V block pools: [n_blocks * block_size, kv_heads,
-    head_dim].  Row ``b * block_size + o`` is offset ``o`` of block ``b``
+    """Flat per-layer block pools, one per kind of row the block caches
+    (``models/decoder.kv_row_shapes``): K and V pools of [n_blocks *
+    block_size, kv_heads, head_dim] for the GQA block, ONE pool of
+    [n_blocks * block_size, 1, latent + rope] for the latent block.
+    Row ``b * block_size + o`` is offset ``o`` of block ``b``
     — the one flat axis the prefill scatter, the decode write and the
     decode read index, so a block id IS a row range: ``block_size``
     consecutive rows, one contiguous page the TPU decode kernel DMAs as a
@@ -596,13 +610,19 @@ def init_paged_pools(
 
     ``sharding`` (``parallel.sharding.paged_pool_sharding`` on a mesh):
     each pool is CREATED under it — every device zero-fills only its own
-    kv-head slice, nothing pool-sized is staged on one device first."""
+    kv-head slice, nothing pool-sized is staged on one device first.  The
+    latent row has no head axis to divide: its pool is replicated."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (n_blocks * block_size, cfg.num_kv_heads, cfg.head_dim)
+    if sharding is not None and is_latent(cfg):
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        sharding = NamedSharding(sharding.mesh, PartitionSpec())
     pools: PagedPools = {}
     for i in range(cfg.num_layers):
-        pools[f"k{i}"] = jnp.zeros(shape, dtype, device=sharding)
-        pools[f"v{i}"] = jnp.zeros(shape, dtype, device=sharding)
+        for prefix, (heads, width) in kv_row_shapes(cfg).items():
+            pools[f"{prefix}{i}"] = jnp.zeros(
+                (n_blocks * block_size, heads, width), dtype, device=sharding
+            )
     return pools
 
 
@@ -610,10 +630,8 @@ def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     """HBM bytes one token of KV occupies across every layer — the
     block-granular accounting unit telemetry reports
     (ROADMAP item 1: per-token bytes instead of per-bucket)."""
-    return (
-        2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim
-        * jnp.dtype(cfg.dtype).itemsize
-    )
+    per_layer = sum(h * w for h, w in kv_row_shapes(cfg).values())
+    return cfg.num_layers * per_layer * jnp.dtype(cfg.dtype).itemsize
 
 
 def ragged_prefill_forward(
@@ -651,7 +669,21 @@ def ragged_prefill_forward(
     same bf16 K/V a cold prefill computes in flight, so warm output is
     bitwise-identical to cold (the token-equality gate in
     tests/test_prefix.py).
+
+    The latent block (``cfg.block``) returns a THIRD value, its routing
+    record (:func:`_latent_prefill_forward`).
     """
+    if is_latent(cfg):
+        if n_prefix_rows:
+            raise NotImplementedError(
+                "the latent block prefills cold only: set "
+                "generate.prefix_cache false (a warm prefill would "
+                "up-project cached rows, which no path here does)"
+            )
+        return _latent_prefill_forward(
+            params, cfg, pools, ids, seg_ids, positions, dest_rows,
+            last_rows, rope_len,
+        )
     warm = n_prefix_rows > 0  # static host int, never a tracer
 
     def attend(i, q, k, v):
@@ -711,7 +743,16 @@ def paged_decode_forward(
     live lanes before that can happen, so a dropped write only ever
     belongs to an inactive lane re-writing its scratch row.
 
-    Returns (logits [S, s, vocab] f32, pools)."""
+    Returns (logits [S, s, vocab] f32, pools) — and, from the latent
+    block, its routing record (:func:`_latent_decode_forward`).  For that
+    block ``use_flash`` and ``mesh`` choose nothing: no Pallas kernel
+    reads a latent row, so the step is the XLA gather whatever they say
+    (GSPMD places it on a mesh)."""
+    if is_latent(cfg):
+        return _latent_decode_forward(
+            params, cfg, pools, block_tables, tok, lengths, block_size,
+            rope_len,
+        )
     S, s = tok.shape
     nb = block_tables.shape[1]
     P = pools["k0"].shape[0]
@@ -745,3 +786,93 @@ def paged_decode_forward(
     x = decoder_layer_stack(params, cfg, tok, rope_pos, rope_len, attend)
     logits = decoder_head(params, cfg, x)
     return logits, pools
+
+
+# ---- the latent block (models/latent.py) over the same pool and tables ----
+
+
+def _with_record(logits, pools, record):
+    """A block that routes hands back its expert ids as a third value; one
+    that does not hands back two, as the GQA block does."""
+    if record is None:
+        return logits, pools
+    return logits, pools, record
+
+
+def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
+                            dest_rows, last_rows, rope_len):
+    """The packed prefill of the latent block: each token's ONE cache row
+    is scattered to its table-mapped pool row, and attention runs over
+    the rows in flight in the non-absorbed form — keys and values
+    up-projected per head, the packed ragged attention the GQA block
+    uses (wider keys than values).
+
+    Returns (last_logits [B, vocab] f32, pools, routing record int32
+    [routed_layers, T, experts_per_token] of the packed rows, padding
+    included)."""
+    scale = softmax_scale(cfg)
+
+    def attend(i, q_nope, q_rope, row):
+        pool = pools[f"c{i}"]
+        pools[f"c{i}"] = pool.at[dest_rows].set(
+            row[0][:, None, :].astype(pool.dtype), mode="drop"
+        )
+        k, v = up_projected(params, cfg, i, row[0])
+        q = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
+        return ragged_prefill_attention(
+            q, k, v, seg_ids, positions, scale=scale
+        )[None]
+
+    x, record = latent_layer_stack(
+        params, cfg, ids[None, :], positions[None, :], rope_len, attend
+    )
+    logits = decoder_head(params, cfg, x[0][last_rows][:, None, :])
+    return _with_record(
+        logits[:, 0], pools, None if record is None else record[:, 0]
+    )
+
+
+def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
+                           block_size, rope_len):
+    """A decode step of the latent block in the ABSORBED form: the new
+    rows are written at their table-mapped pool rows, each head's query is
+    carried into latent space, scores and the weighted sum are taken
+    against the pool rows as stored (one read serves key and value), and
+    the result goes back through the value half of the up-projection.  No
+    per-head key or value of a cached row exists at any point.
+
+    Returns (logits [S, s, vocab] f32, pools, routing record int32
+    [routed_layers, S, s, experts_per_token])."""
+    S, s = tok.shape
+    nb = block_tables.shape[1]
+    P = pools["c0"].shape[0]
+    n_blocks = P // block_size
+    pos = lengths[:, None] + jnp.arange(s)[None, :]
+    blk_idx = pos // block_size
+    blk = jnp.take_along_axis(
+        block_tables, jnp.minimum(blk_idx, nb - 1), axis=1
+    )
+    dest = jnp.where(
+        (blk_idx < nb) & (blk < n_blocks),
+        blk * block_size + pos % block_size,
+        P,  # out of bounds -> dropped write
+    )
+    rope_pos = jnp.minimum(pos, rope_len - 1)
+    scale = softmax_scale(cfg)
+
+    def attend(i, q_nope, q_rope, row):
+        pool = pools[f"c{i}"]
+        pools[f"c{i}"] = pool.at[dest].set(
+            row[:, :, None, :].astype(pool.dtype), mode="drop"
+        )
+        o_lat = paged_latent_decode_attention(
+            absorb_query(params, cfg, i, q_nope), q_rope, pools[f"c{i}"],
+            block_tables, lengths + s, block_size=block_size,
+            q_offset=lengths, scale=scale,
+        )
+        return expand_output(params, cfg, i, o_lat)
+
+    x, record = latent_layer_stack(
+        params, cfg, tok, rope_pos, rope_len, attend
+    )
+    return _with_record(decoder_head(params, cfg, x), pools, record)

@@ -114,7 +114,9 @@ from docqa_tpu.engines.qos import QoSPolicy, request_class
 from docqa_tpu.engines.spine import spine_run, spine_submit
 from docqa_tpu.models.decoder import (
     init_decoder_params,  # noqa: F401  (re-export convenience for tests)
+    kv_row_shapes,
 )
+from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
 from docqa_tpu.ops.attention import RAGGED_ALIGN, paged_kernel_supported
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.resilience import faults
@@ -123,6 +125,16 @@ from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY, get_logger, span
 from docqa_tpu.utils import round_up
 
 log = get_logger("docqa.serve")
+
+# counters of a routing block's decode chunks, in the order the decode
+# program sums them on the device (``_moe_step_sums``) and the worker adds
+# them (``_moe_chunk_sums``): expert picks of the live lanes; those that
+# fell on an expert held here; distinct held experts touched, summed over
+# (routed layer, step); and the (routed layer, step)s with a live lane
+MOE_SUMS = (
+    "serve_moe_picks", "serve_moe_picks_local", "serve_moe_experts_touched",
+    "serve_moe_layer_steps",
+)
 
 
 @dataclass
@@ -598,6 +610,9 @@ class ContinuousBatcher:
             self._pool_sharding = paged_pool_sharding(self.mesh)
             self._state_sharding = self.mesh.replicated
         self.chunk = chunk or getattr(self.gen, "decode_chunk", 8)
+        self._admit_hold_s = max(
+            0.0, float(getattr(self.gen, "admit_hold_ms", 0.0))
+        ) / 1e3
         self.cache_len = round_up(cache_len or self.cfg.max_seq_len, 128)
         self._seed = seed
         self._rng_counter = itertools.count(1)
@@ -670,6 +685,24 @@ class ContinuousBatcher:
             if prefix_cache is None
             else bool(prefix_cache)  # A/B + test override
         )
+        if is_latent(self.cfg):
+            # refused here, at construction, not when a request's warm
+            # prefill or verify step is traced on the worker thread: the
+            # latent block prefills cold only (a warm prefill would
+            # up-project cached rows, which no path does) and its
+            # speculative chunk would drop the routing record
+            unserved = [
+                name for name, on in (
+                    ("generate.prefix_cache", want_cache),
+                    ("generate.speculative_k", self.spec_k),
+                ) if on
+            ]
+            if unserved:
+                raise ValueError(
+                    f'DecoderConfig(block="{self.cfg.block}") is served '
+                    "without " + " and ".join(unserved)
+                    + ": set prefix_cache false and speculative_k 0"
+                )
         if want_cache and self._share_align < self.seq_capacity:
             self._prefix_cache = PrefixCache(
                 self._alloc, self._share_align,
@@ -777,10 +810,16 @@ class ContinuousBatcher:
         # reads live pages in place, the reference gathers every table
         self._pages_read_in_place = self.engine.use_flash and (
             paged_kernel_supported(
-                self.cfg.dtype, self.cfg.num_kv_heads, self.cfg.head_dim,
+                self.cfg.dtype, *next(iter(kv_row_shapes(self.cfg).values())),
                 self.mesh,
             )
         )
+        # layers of the block that route (0: the GQA block).  A routing
+        # block's decode chunk carries its expert-choice sums to the host
+        # in one more row of the array the worker fetches anyway
+        # (``_moe_chunk_sums``); a block that does not route adds nothing
+        # to its programs or to the worker's work per chunk.
+        self._routed_layers = routed_layers(self.cfg)
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="continuous-batcher"
         )
@@ -832,7 +871,9 @@ class ContinuousBatcher:
                 n_prefix_rows=self.seq_capacity,
                 block_size=self.block_size,
             )
-        logits, pools = ragged_prefill_forward(
+        # a block that routes hands back its record too; the decode
+        # chunks count choices, a prefill's are not counted
+        logits, pools, *_ = ragged_prefill_forward(
             params, self.cfg, pools, ids, seg, pos, dest, last_rows,
             rope_len=self.seq_capacity, **warm_kw,
         )
@@ -868,14 +909,22 @@ class ContinuousBatcher:
         S = self.n_slots
         out0 = jnp.full((S, self.chunk), self.gen.pad_id, jnp.int32)
         valid0 = jnp.zeros((S, self.chunk), bool)
+        # the chunk's expert-choice sums; an empty pytree (nothing in the
+        # program) for a block that does not route
+        moe0 = (
+            (jnp.zeros((len(MOE_SUMS),), jnp.int32),)
+            if self._routed_layers else ()
+        )
 
         def body(t, carry):
-            pools, tok, lengths, active, out, valid, rng = carry
-            logits, pools = paged_decode_forward(
+            pools, tok, lengths, active, out, valid, rng, moe = carry
+            logits, pools, *routed = paged_decode_forward(
                 params, self.cfg, pools, tables, tok[:, None], lengths,
                 block_size=self.block_size, rope_len=self.seq_capacity,
                 use_flash=self.engine.use_flash, mesh=self.mesh,
             )
+            if routed:
+                moe = (moe[0] + self._moe_step_sums(routed[0], active),)
             rng, sub = jax.random.split(rng)
             nxt = sample(
                 logits[:, 0], sub, self.gen.temperature, self.gen.top_k,
@@ -896,19 +945,44 @@ class ContinuousBatcher:
             # position attention could later read as garbage.
             active = active & (lengths < caps) & (lengths < self.cache_len)
             tok = jnp.where(active, nxt, tok)
-            return pools, tok, lengths, active, out, valid, rng
+            return pools, tok, lengths, active, out, valid, rng, moe
 
-        pools, tok, lengths, active, out, valid, _ = jax.lax.fori_loop(
+        pools, tok, lengths, active, out, valid, _, moe = jax.lax.fori_loop(
             0,
             self.chunk,
             body,
-            (pools, tok, lengths, active, out0, valid0, rng),
+            (pools, tok, lengths, active, out0, valid0, rng, moe0),
         )
         packed = jnp.concatenate(
             [out, valid.astype(jnp.int32), active.astype(jnp.int32)[:, None]],
             axis=1,
         )  # [S, 2*chunk + 1] — one D2H fetch for the worker
+        if moe:  # one more row of the same fetch: the sums, then zeros
+            packed = jnp.concatenate(
+                [packed, jnp.pad(
+                    moe[0], (0, packed.shape[1] - len(MOE_SUMS))
+                )[None, :]], axis=0,
+            )
         return pools, tok, lengths, active, packed
+
+    def _moe_step_sums(self, record, active):
+        """``MOE_SUMS`` of one decode step, int32, from its routing record
+        [routed_layers, S, 1, k] and the lanes live in it — summed on the
+        device, so that the host reads a handful of numbers a chunk and
+        nothing waits on them."""
+        lo, held = experts_held(self.cfg)
+        taken = record[:, :, 0, :]  # [layers, S, k]
+        live = active[None, :, None]
+        per_expert = jnp.sum(
+            live[..., None] & (taken[..., None] - lo == jnp.arange(held)),
+            axis=(1, 2),
+        )  # [layers, held] live picks of each held expert
+        return jnp.stack([
+            jnp.sum(live & (taken >= 0)),
+            jnp.sum(per_expert),
+            jnp.sum(per_expert > 0),
+            jnp.any(active) * record.shape[0],
+        ]).astype(jnp.int32)
 
     def _decode_spec_program(self, params, pools, tables, caps, table, tok,
                              lengths, active):
@@ -947,7 +1021,8 @@ class ContinuousBatcher:
             pools, table, tok, lengths, active, out, n_out = st
             drafts = draft_tokens(table, tok, K)
             verify_in = jnp.concatenate([tok[:, None], drafts], axis=1)
-            logits, pools = paged_decode_forward(
+            # (a routing block's record is not counted under speculation)
+            logits, pools, *_ = paged_decode_forward(
                 params, self.cfg, pools, tables, verify_in, lengths,
                 block_size=self.block_size, rope_len=self.seq_capacity,
                 use_flash=self.engine.use_flash, mesh=self.mesh,
@@ -2729,6 +2804,9 @@ class ContinuousBatcher:
                 _cost_add(req, "spine_queue_wait_ms", qw_ms)
                 if fl:
                     _cost_add(req, "flops_est", fl)
+        if self._routed_layers and not self.spec_k:
+            self._moe_chunk_sums(packed_h[self.n_slots])
+            packed_h = packed_h[: self.n_slots]
         if self.spec_k:
             width = self.chunk + 2 * self.spec_k
             out_h = packed_h[:, :width]
@@ -2839,6 +2917,21 @@ class ContinuousBatcher:
             # — the worker never issues device ops from its own thread
             self._deact_pending.extend(deactivate)
         return True
+
+    def _moe_chunk_sums(self, row) -> None:
+        """One fetched chunk's expert-choice sums (``MOE_SUMS``, summed on
+        the device over its steps and live lanes) into the counters the
+        routed layer's metrics read: picks made, picks that fell on an
+        expert held here, distinct held experts a (layer, step) touched —
+        the weights a step had to read — and the (layer, step)s counted."""
+        sums = dict(zip(MOE_SUMS, (int(v) for v in row[: len(MOE_SUMS)])))
+        for name, value in sums.items():
+            DEFAULT_REGISTRY.counter(name).inc(value)
+        if sums["serve_moe_experts_touched"]:
+            DEFAULT_REGISTRY.histogram("serve_moe_tokens_per_expert").observe(
+                sums["serve_moe_picks_local"]
+                / sums["serve_moe_experts_touched"]
+            )
 
     def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
         """(KV rows fetched, KV rows live) PER LAYER over one chunk's
@@ -3173,6 +3266,34 @@ class ContinuousBatcher:
                 # admission: fill every free slot from the queue; the whole
                 # round prefills in one batched dispatch below
                 self._pop_free_slots(pairs)
+                if self._admit_hold_s and pairs:
+                    # generate.admit_hold_ms: with a round's first
+                    # requests popped and slots still free, wait for the
+                    # next arrival and pop it into the same round.  Every
+                    # arrival restarts the wait; it ends when the slots
+                    # are full, when nothing arrived for the hold, when a
+                    # popped request's budget runs out, or when the head
+                    # is block-starved (the fill stopped: waiting would
+                    # not admit it).  FIFO order is _pop_free_slots's.
+                    free = sum(1 for r in self._slot_req if r is None)
+                    until = _now() + self._admit_hold_s
+                    with span("serve_admit_gather", DEFAULT_REGISTRY):
+                        while len(pairs) < free and not self._stopped:
+                            left = until - _now()
+                            for _, held in pairs:
+                                if held.deadline is not None:
+                                    left = held.deadline.bound(left)
+                            if left <= 0:
+                                break
+                            self._beat = time_monotonic()
+                            if not self._queue:
+                                self._cv.wait(left)
+                            n = len(pairs)
+                            self._pop_free_slots(pairs)
+                            if len(pairs) > n:
+                                until = _now() + self._admit_hold_s
+                            elif self._queue:
+                                break
                 if (
                     not pairs
                     and self._queue

@@ -26,6 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.latent import (
+    LATENT_BLOCK,
+    is_latent,
+    latent_param_schema,
+    latent_row_width,
+)
 from docqa_tpu.ops.attention import attention_reference, flash_attention
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
@@ -40,7 +46,10 @@ def decoder_param_schema(cfg: DecoderConfig):
     Both ``init_decoder_params`` and the int8 incremental init
     (``models/quant.py``) consume this — the RNG stream order is defined
     by the order of "normal" entries here, so the two inits can never
-    desynchronize."""
+    desynchronize.  The latent block's tree is ``models/latent.py``'s."""
+    if is_latent(cfg):
+        yield from latent_param_schema(cfg)
+        return
     h = cfg.hidden_dim
     qd = cfg.num_heads * cfg.head_dim
     kvd = cfg.num_kv_heads * cfg.head_dim
@@ -57,6 +66,18 @@ def decoder_param_schema(cfg: DecoderConfig):
         yield (f"l{i}_w_gate", "normal", (h, cfg.mlp_dim), h)
         yield (f"l{i}_w_up", "normal", (h, cfg.mlp_dim), h)
         yield (f"l{i}_w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
+
+
+def kv_row_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, int]]:
+    """What one token leaves in the cache, per layer: ``{pool prefix:
+    (heads, width)}``.  The GQA block keeps a key and a value per kv
+    head; the latent block ONE row (normed latent ‖ rotated key) that
+    every head reads as key and as value.  The paged pools, their bytes
+    per token and the choice of decode kernel are all asked of this."""
+    if is_latent(cfg):
+        return {"c": (1, latent_row_width(cfg))}
+    return {"k": (cfg.num_kv_heads, cfg.head_dim),
+            "v": (cfg.num_kv_heads, cfg.head_dim)}
 
 
 def param_putter(cfg: DecoderConfig, mesh=None):
@@ -259,6 +280,12 @@ def decoder_forward(
 
     Returns (logits [b, s, vocab] f32, updated cache).
     """
+    if is_latent(cfg):
+        raise NotImplementedError(
+            f'the dense-cache solo forward has no "{LATENT_BLOCK}" block '
+            "(model_type deepseek_v2): that block serves through the paged "
+            "cache (engines/paged.py, the batcher) only"
+        )
     b, s = ids.shape
     max_len = cache["k0"].shape[1]
 

@@ -264,7 +264,7 @@ def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
         out = attend_rows(jnp.arange(t))
     else:
         blocks = jnp.arange(t).reshape(t // RAGGED_ALIGN, RAGGED_ALIGN)
-        out = jax.lax.map(attend_rows, blocks).reshape(t, hq, d)
+        out = jax.lax.map(attend_rows, blocks).reshape(t, hq, v.shape[-1])
     return out.astype(q.dtype)
 
 
@@ -331,6 +331,43 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         q, k, v, causal=True, lengths=lengths, q_offset=q_offset,
         sliding_window=sliding_window, scale=scale,
     )
+
+
+def paged_latent_decode_attention(q_lat, q_rope, pool, block_tables, lengths,
+                                  *, block_size, q_offset, scale):
+    """Decode-side attention of the LATENT block (models/latent.py)
+    through a block table, in its absorbed form: every head's key is the
+    pool row itself — the normed latent ‖ the one rotated key all heads
+    share — and its value the row's latent part, so one gather of a
+    lane's pages serves scores and output, and no per-head key or value
+    of a cached row is ever formed.  XLA reference (gather the table's
+    span, mask by ``lengths``), as :func:`gather_paged_kv` backs the GQA
+    block; f32 softmax, the same dtype contract.
+
+    q_lat        [S, s, heads, r]  queries carried into latent space
+    q_rope       [S, s, heads, dr] rotated query parts
+    pool         [P, 1, r + dr] flat block pool (one shared row a token)
+    block_tables [S, NB] int32;  lengths [S] valid rows AFTER this step
+    q_offset     [S] absolute position of q row 0;  scale: softmax scale
+
+    Returns [S, s, heads, r] (float32 sums cast to q's type): the
+    attention-weighted latent, which the caller carries back through the
+    value half of the up-projection."""
+    r = q_lat.shape[-1]
+    rows = gather_paged_kv(pool, block_tables, block_size)[:, :, 0, :]
+    rows = rows.astype(jnp.float32)  # [S, L, r + dr]
+    qf = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqhd,bkd->bhqk", qf, rows)
+    kv_pos = jnp.arange(rows.shape[1])[None, None, None, :]
+    q_abs = (jnp.arange(q_lat.shape[1])[None, :] + q_offset[:, None])
+    mask = (kv_pos < lengths[:, None, None, None]) & (
+        kv_pos <= q_abs[:, None, :, None]
+    )
+    scores = jnp.where(mask, scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    probs = jnp.where(jnp.any(mask, axis=-1, keepdims=True), probs, 0.0)
+    out = jnp.einsum("bhqk,bkd->bqhd", probs, rows[..., :r])
+    return out.astype(q_lat.dtype)
 
 
 # --------------------------------------------------------------------------

@@ -27,6 +27,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.latent import is_latent
 from docqa_tpu.runtime.mesh import MeshContext
 
 
@@ -37,6 +38,9 @@ def decoder_param_pspecs(cfg: DecoderConfig, model_axis: str) -> Dict[str, P]:
         "final_norm_g": P(None),
         "lm_head": P(None, m),  # vocab-sharded logits
     }
+    if is_latent(cfg):
+        specs.update(_latent_param_pspecs(cfg, m))
+        return specs
     for i in range(cfg.num_layers):
         specs.update(
             {
@@ -51,6 +55,40 @@ def decoder_param_pspecs(cfg: DecoderConfig, model_axis: str) -> Dict[str, P]:
                 f"l{i}_w_down": P(m, None),
             }
         )
+    return specs
+
+
+def _latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
+    """The latent block (models/latent.py).  Attention: the low-rank
+    down-projections and their norms replicated (every device forms the
+    same latent row, and the row pool is replicated); the per-head
+    up-projections column-parallel over heads, ``wo`` row-parallel — one
+    psum, as for the GQA block.  Dense and shared MLPs: Megatron.  Routed
+    experts: the EXPERT axis over ``model`` — expert parallelism; the
+    range a process holds (``experts_held``) is one device's shard of a
+    layer's experts, and the router is replicated."""
+    specs: Dict[str, P] = {}
+    for i in range(cfg.num_layers):
+        p = f"l{i}_"
+        specs.update({
+            p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
+            p + "wq_a": P(None, None), p + "q_norm_g": P(None),
+            p + "wq_b": P(None, m),
+            p + "wkv_a": P(None, None), p + "kv_norm_g": P(None),
+            p + "wk_b": P(None, m), p + "wv_b": P(None, m),
+            p + "wo": P(m, None),
+        })
+        if i < cfg.first_dense_layers:
+            specs.update({p + "w_gate": P(None, m), p + "w_up": P(None, m),
+                          p + "w_down": P(m, None)})
+            continue
+        specs.update({
+            p + "router": P(None, None),
+            p + "e_gate": P(m, None, None), p + "e_up": P(m, None, None),
+            p + "e_down": P(m, None, None),
+            p + "s_gate": P(None, m), p + "s_up": P(None, m),
+            p + "s_down": P(m, None),
+        })
     return specs
 
 
@@ -129,6 +167,8 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     gather ride the unsharded row axis and insert no collective (the
     shard audit's decoder_paged_decode program holds that to the same
     one-all-reduce-per-Megatron-block budget as the dense programs)."""
+    if is_latent(cfg):  # one row a token, no head axis: replicated
+        return {f"c{i}": P() for i in range(cfg.num_layers)}
     spec = paged_pool_sharding(mesh).spec
     out: Dict[str, P] = {}
     for i in range(cfg.num_layers):
