@@ -48,16 +48,24 @@ Attention arXiv 2604.15464):
   the slot→request mapping of its own dispatch, and tokens are
   delivered only to slots whose occupant is still that request — so a
   lane retired between a chunk's dispatch and its fetch (its first token
-  was EOS, its budget ran out) can never be misdelivered to.  Slots that
-  retire on budget mid-pipeline decode one extra chunk
-  whose tokens are discarded — wasted compute, never wrong output — and an
-  in-program capacity guard deactivates any lane before a K/V write could
-  land past its allocated blocks (such writes are additionally dropped,
-  never clamped, by the paged scatter).  Freed blocks can be re-used by
-  the very next admission because the worker fetches the chunk in flight
-  before it admits, and the pool is DONATED through every dispatch: an
-  overshoot chunk's stale writes have landed before the prefill that
-  re-populates those rows is dispatched.
+  was EOS, its budget ran out) can never be misdelivered to.  A chunk is
+  dispatched only while some occupied slot can still be owed a token
+  after the chunk in flight (``_any_lane_owed``): the budget is the
+  host's to enforce, and the host can count — a lane that stays active
+  emits at least ``chunk`` tokens a chunk — so a lane that retires on its
+  BUDGET decodes no extra chunk, and a batcher whose lanes all end so
+  idles with nothing in flight (``serve_decode_chunks_skipped``).  What
+  the host cannot foresee still costs one: a lane that retires on EOS, a
+  deadline or a cancellation decodes one extra chunk whose tokens are
+  discarded (``serve_decode_chunks_stale``) — wasted compute, never wrong
+  output — and an in-program capacity guard deactivates any lane before a
+  K/V write could land past its allocated blocks (such writes are
+  additionally dropped, never clamped, by the paged scatter).  Freed
+  blocks can be re-used by the very next admission because the worker
+  fetches the chunk in flight, if there is one, before it admits, and
+  the pool is DONATED through every dispatch: an overshoot chunk's stale
+  writes have landed before the prefill that re-populates those rows is
+  dispatched.
 
 Prefix reuse (docqa-prefix, ROADMAP item 1 follow-through): a refcounted
 copy-on-write prefix cache (``engines/paged.PrefixCache``) keyed by the
@@ -3236,6 +3244,35 @@ class ContinuousBatcher:
         if self._prefix_cache is not None:
             self._prefix_cache.clear()
 
+    def _any_lane_owed(self, in_flight) -> bool:
+        """Whether some occupied slot can still be owed a token once the
+        chunk in flight (``in_flight``: its dispatch-time snapshot, None
+        with nothing pending) has been fetched — the condition for
+        dispatching another chunk.
+
+        The budget is the host's alone to enforce, but the host can
+        count: a lane that stays active through a chunk emits at least
+        ``self.chunk`` tokens (exactly that many in the plain program;
+        the speculative one loops until every live lane has), and a lane
+        that emits fewer has ended (EOS, capacity).  So a lane the chunk
+        in flight advances, with ``len(tokens) + chunk >= budget``, is
+        finished when that chunk is fetched, whatever it samples.  A lane
+        that chunk does not hold (nothing pending, or admitted since) is
+        owed while it is short of its budget — every lane
+        ``_finalize_admissions`` has not retired.  Resumed tokens count
+        on both sides, as in ``_process_chunk``'s own comparison.  A lane
+        judged not owed that is live after the fetch all the same is owed
+        in the next iteration, where nothing is pending."""
+        for slot, req in enumerate(self._slot_req):
+            if req is None:
+                continue
+            due = len(req.tokens)
+            if in_flight is not None and in_flight[slot] is req:
+                due += self.chunk
+            if due < self._slot_budget[slot]:
+                return True
+        return False
+
     def _run_loop(self) -> None:
         # The one dispatched-but-unprocessed decode chunk: (packed device
         # array, dispatch-time slot→request snapshot).  The snapshot is
@@ -3308,16 +3345,24 @@ class ContinuousBatcher:
                         self._cv.wait(0.05)
                     self._pop_free_slots(pairs)
             drained_at = None
-            if pairs and pending is not None:
+            if pairs:
                 # drain the pipeline before admitting: processing may
                 # retire slots this round can refill, and the fetch is the
                 # PR-9 re-use guarantee — the only chunk in flight, stale
                 # writes to retired lanes' rows included, has LANDED
-                # before the prefill that re-populates them is dispatched
+                # before the prefill that re-populates them is dispatched.
+                # The span is every admission round's: with nothing in
+                # flight (the lanes before it retired on their budgets
+                # and no chunk was sent past them) the round waited 0 ms
+                # for the pipeline, and the last real chunk was fetched
+                # before its lanes retired — the same guarantee.
                 with span("serve_admit_drain", DEFAULT_REGISTRY):
-                    drained_ok = self._process_chunk(*pending)
-                drained_at = _now()
-                pending = None
+                    drained_ok = (
+                        pending is not None and self._process_chunk(*pending)
+                    )
+                if pending is not None:
+                    drained_at = _now()
+                    pending = None
                 if drained_ok:
                     with self._cv:  # top-up from slots freed by the drain
                         self._pop_free_slots(pairs)
@@ -3502,7 +3547,16 @@ class ContinuousBatcher:
                 return out
 
             packed = snap = None
-            if any(self._slot_req):
+            occupied = any(self._slot_req)
+            if occupied and not self._any_lane_owed(
+                pending[1] if pending is not None else None
+            ):
+                # every occupied lane reaches its budget inside the chunk
+                # in flight: the fetch below retires them all, nothing is
+                # sent past them, and the loop idles with an empty
+                # pipeline (the next round has nothing to drain)
+                DEFAULT_REGISTRY.counter("serve_decode_chunks_skipped").inc()
+            elif occupied:
                 # snapshot at DISPATCH time: slots this chunk advances,
                 # this round's lanes among them.  One whose first token
                 # retires it in _finalize_admissions below (EOS, budget

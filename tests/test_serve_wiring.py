@@ -253,7 +253,11 @@ class TestConcurrentAsk:
         # mechanism behind ≈-solo latency, asserted load-independently —
         # wall-clock comparisons flake on busy CI hosts)
         assert c_seq >= n  # sanity: sequential paid ≥ one chunk per request
-        assert c_conc <= c_seq * 0.6, (c_conc, c_seq)
+        # (a request that ends on its budget leaves no overshoot chunk for
+        # the next admission to fetch, so the sequential count is the real
+        # chunks alone, 2 a request: four requests admitted a chunk apart
+        # from each other still overlap in 5 of those 8)
+        assert c_conc <= c_seq * 0.75, (c_conc, c_seq)
 
     def test_batcher_counters_track_requests(self, rt):
         from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
