@@ -63,7 +63,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from docqa_tpu.utils import compiled_memory_stats as memory_of
 
 WORKLOADS = (
-    "serve", "serve_latent", "generate", "retrieve_fused", "seq2seq",
+    "serve", "serve_latent", "serve_hybrid", "generate", "retrieve_fused", "seq2seq",
     "encoder",
 )
 
@@ -158,6 +158,22 @@ def _audit_latent_cfg():
     )
 
 
+def _audit_hybrid_cfg():
+    """The two-mixer block (models/hybrid.py) at audit widths: a sparse
+    and a linear layer."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=64, hidden_dim=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=8, mlp_dim=64, max_seq_len=128,
+        block="sparse_linear", mixer_types=("sparse", "linear"),
+        linear_heads=4, linear_head_dim=8, scale_emb=12.0, scale_depth=1.4,
+        dim_model_base=16, sparse_kernel_size=8, sparse_kernel_stride=4,
+        sparse_block_size=8, sparse_topk=4, sparse_init_blocks=1,
+        sparse_window_size=16, sparse_dense_len=48,
+    )
+
+
 def _audit_gen_cfg():
     from docqa_tpu.config import GenerateConfig
 
@@ -189,7 +205,7 @@ def _audit_encoder_cfg():
 # ---------------------------------------------------------------------------
 
 
-def _audit_serve(latent: bool = False) -> Dict[str, Any]:
+def _audit_serve(latent: bool = False, hybrid: bool = False) -> Dict[str, Any]:
     """The PAGED batcher's whole compile surface: one ragged prefill
     program per packed token budget (<= 2) plus the one block-table
     decode chunk — the collapse from the pre-paged (2 shape families x
@@ -201,7 +217,11 @@ def _audit_serve(latent: bool = False) -> Dict[str, Any]:
     ``latent``: the same batcher over the latent block (workload
     ``serve_latent``): its cold prefill budgets and its decode chunk,
     which carries the expert-choice sums.  That block prefills cold only
-    (prefix cache off, no speculation): there is no warm family."""
+    (prefix cache off, no speculation): there is no warm family.
+
+    ``hybrid``: the same over the two-mixer block (workload
+    ``serve_hybrid``): cold prefill budgets, and a decode chunk that
+    advances lane states and carries the selection sums."""
     import dataclasses
 
     import jax
@@ -214,6 +234,9 @@ def _audit_serve(latent: bool = False) -> Dict[str, Any]:
     cfg, gen = _audit_decoder_cfg(), _audit_gen_cfg()
     if latent:
         cfg = _audit_latent_cfg()
+        gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
+    if hybrid:
+        cfg = _audit_hybrid_cfg()
         gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
     engine = GenerateEngine(cfg, gen)
     # cache_len 256: large enough that the 128-aligned prefix cache is
@@ -379,9 +402,10 @@ def _audit_serve(latent: bool = False) -> Dict[str, Any]:
         }
         if not batcher.prefix_cache_enabled:
             del report["roots"]["serve_prefill_warm"]
-        if latent:
+        if latent or hybrid:
+            prefix = "serve_latent_" if latent else "serve_hybrid_"
             report["roots"] = {
-                name.replace("serve_", "serve_latent_"): root
+                name.replace("serve_", prefix): root
                 for name, root in report["roots"].items()
             }
         return report
@@ -548,6 +572,7 @@ def _audit_encoder() -> Dict[str, Any]:
 _AUDITS = {
     "serve": _audit_serve,
     "serve_latent": functools.partial(_audit_serve, latent=True),
+    "serve_hybrid": functools.partial(_audit_serve, hybrid=True),
     "generate": _audit_generate,
     "retrieve_fused": _audit_retrieve,
     "seq2seq": _audit_seq2seq,

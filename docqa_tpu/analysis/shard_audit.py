@@ -67,6 +67,8 @@ AUDIT_PROGRAMS = (
     "decoder_ragged_prefill",
     "latent_paged_decode",
     "latent_ragged_prefill",
+    "hybrid_paged_decode",
+    "hybrid_ragged_prefill",
     "ring_attention",
     "ulysses_attention",
     "retrieve_fused",
@@ -185,6 +187,23 @@ def _audit_latent_cfg():
     )
 
 
+def _audit_hybrid_cfg():
+    """The two-mixer block (models/hybrid.py): a sparse and a linear
+    layer; query heads, the linear heads and the MLP width divisible by 8,
+    the sparse layer's 2 kv heads replicated."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=128, hidden_dim=64, num_layers=2, num_heads=8,
+        num_kv_heads=2, head_dim=8, mlp_dim=128, max_seq_len=32,
+        block="sparse_linear", mixer_types=("sparse", "linear"),
+        linear_heads=8, linear_head_dim=8, scale_emb=12.0, scale_depth=1.4,
+        dim_model_base=16, sparse_kernel_size=8, sparse_kernel_stride=4,
+        sparse_block_size=8, sparse_topk=2, sparse_init_blocks=1,
+        sparse_window_size=8, sparse_dense_len=16,
+    )
+
+
 def _audit_encoder_cfg():
     from docqa_tpu.config import EncoderConfig
 
@@ -288,7 +307,8 @@ def _audit_decoder(mesh_name: str, prefill: bool, pspec_fn=None):
     return counts, meta
 
 
-def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False):
+def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
+                 hybrid: bool = False):
     """Lower the PAGED serving programs (engines/paged.py) under the
     same Megatron layout: the block-pool gather/scatter must not change
     the collective story — still exactly one all-reduce per Megatron
@@ -303,7 +323,11 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False):
     to LOWER on every mesh; their collectives are recorded, not held to
     the Megatron count — GSPMD's handling of a per-expert loop over a
     sharded expert axis is not the exchange a deployment would run, and no
-    cell runs this block across chips yet."""
+    cell runs this block across chips yet.
+
+    ``hybrid``: the same two programs of the two-mixer block
+    (models/hybrid.py; its pools — rows, compressed keys, lane states, the
+    slot map — replicated): lowered on every mesh, collectives recorded."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -319,6 +343,8 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False):
     )
 
     cfg = _audit_latent_cfg() if latent else _audit_decoder_cfg()
+    if hybrid:
+        cfg = _audit_hybrid_cfg()
     mesh = _mesh(mesh_name)
     slots, block_size, n_blocks = 4, 8, 16
     rope_len = 32
@@ -330,6 +356,11 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False):
         for i in range(cfg.num_layers)
         for kv, (heads, width) in kv_row_shapes(cfg).items()
     }
+    if hybrid:
+        from docqa_tpu.engines.paged import init_paged_pools
+
+        pools = jax.eval_shape(
+            lambda: init_paged_pools(cfg, n_blocks, block_size))
     pspecs = decoder_param_pspecs(cfg, mesh.model_axis)
     pool_specs = paged_pool_pspecs(cfg, mesh)
     replicated = NamedSharding(mesh.mesh, P())
@@ -394,6 +425,9 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False):
     if latent:
         del meta["megatron_blocks"]
         meta["experts_held"] = cfg.experts_held
+    if hybrid:
+        del meta["megatron_blocks"]
+        meta["mixer_types"] = list(cfg.mixer_types)
     return counts, meta
 
 
@@ -702,6 +736,12 @@ _AUDITS: Dict[str, Callable[[str], Tuple[Dict[str, int], Dict[str, Any]]]] = {
     ),
     "latent_ragged_prefill": functools.partial(
         _audit_paged, prefill=True, latent=True
+    ),
+    "hybrid_paged_decode": functools.partial(
+        _audit_paged, prefill=False, hybrid=True
+    ),
+    "hybrid_ragged_prefill": functools.partial(
+        _audit_paged, prefill=True, hybrid=True
     ),
     "ring_attention": _audit_ring,
     "ulysses_attention": _audit_ulysses,

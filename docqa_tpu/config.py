@@ -192,6 +192,35 @@ class DecoderConfig:
     routed_scale: float = 1.0
     experts_held_start: int = 0
     experts_held: int = 0
+    # ---- "sparse_linear" (models/hybrid.py) ------------------------------
+    # A stack of TWO mixer kinds, one name per layer in ``mixer_types``
+    # (``len == num_layers``): "linear" — decayed linear attention whose
+    # whole past is one [heads, d, d] float32 state a LANE (no row a
+    # token), RoPE, per-head output norm — and "sparse" — GQA softmax
+    # attention (``num_heads`` / ``num_kv_heads`` / ``head_dim`` above, no
+    # RoPE) that, once a sequence holds ``sparse_dense_len`` tokens, reads
+    # only the ``sparse_topk`` blocks of ``sparse_block_size`` tokens a row
+    # selects by its scores against mean-pooled keys (windows of
+    # ``sparse_kernel_size`` every ``sparse_kernel_stride`` tokens); the
+    # first ``sparse_init_blocks`` blocks and those over the last
+    # ``sparse_window_size`` tokens are always taken.  Both mixers norm
+    # q and k per head and gate their output (sigmoid).  muP scalings:
+    # the embedding x ``scale_emb``, every residual add x ``scale_depth /
+    # sqrt(num_layers)`` (0: plain adds), logits / (hidden_dim /
+    # ``dim_model_base``) (0: unscaled).  Read by that block alone.
+    mixer_types: Tuple[str, ...] = ()
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
 
     @staticmethod
     def mistral_7b() -> "DecoderConfig":

@@ -33,6 +33,11 @@ from docqa_tpu.models.decoder import (
     init_decoder_params,
     init_kv_cache,
 )
+from docqa_tpu.models.hybrid import (
+    HYBRID_BLOCK,
+    check_hybrid_config,
+    is_hybrid,
+)
 from docqa_tpu.models.latent import (
     LATENT_BLOCK,
     check_latent_config,
@@ -210,6 +215,11 @@ class GenerateEngine:
             # served by the batcher over the paged latent pool only; no
             # Pallas kernel reads that pool yet (ops/attention.py)
             check_latent_config(cfg)
+            use_flash = False
+        if is_hybrid(cfg):
+            # the batcher over the paged rows and the lane state only; the
+            # scan and the selection are XLA (no Pallas kernel yet)
+            check_hybrid_config(cfg)
             use_flash = False
         self.use_flash = use_flash
         self._fns = {}
@@ -449,6 +459,13 @@ class GenerateEngine:
                 "(model_type deepseek_v2): generate through the batcher "
                 "(engines/serve.ContinuousBatcher), which serves it over the "
                 "paged latent cache"
+            )
+        if is_hybrid(self.cfg):
+            raise NotImplementedError(
+                f'the solo dense-cache engine has no "{HYBRID_BLOCK}" block: '
+                "generate through the batcher "
+                "(engines/serve.ContinuousBatcher), which serves it over the "
+                "paged rows and the lane state"
             )
         spec_k = self.gen.speculative_k
         if greedy and spec_k >= 2:

@@ -47,6 +47,16 @@ from docqa_tpu.models.decoder import (
     decoder_head,
     decoder_layer_stack,
     kv_row_shapes,
+    lane_state_shapes,
+)
+from docqa_tpu.models.hybrid import (
+    LINEAR,
+    decay_slopes,
+    hybrid_head,
+    hybrid_layer_stack,
+    is_hybrid,
+    linear_layers,
+    sparse_layers,
 )
 from docqa_tpu.models.latent import (
     absorb_query,
@@ -57,13 +67,22 @@ from docqa_tpu.models.latent import (
     up_projected,
 )
 from docqa_tpu.ops.attention import (
+    RAGGED_ALIGN,
+    compressed_keys,
+    linear_attention_prefill,
+    linear_attention_step,
     paged_decode_attention,
     paged_latent_decode_attention,
     ragged_prefill_attention,
+    sparse_decode_attention,
+    sparse_prefill_attention,
 )
 
-# "k0".."k{L-1}", "v0".."v{L-1}"; the latent block: "c0".."c{L-1}"
+# "k0".."k{L-1}", "v0".."v{L-1}"; the latent block: "c0".."c{L-1}"; the
+# two-mixer block: "k{i}" / "v{i}" / "ck{i}" of its sparse layers, "s{i}" of
+# its linear layers and STATE_SLOT (``_init_hybrid_pools``)
 PagedPools = Dict[str, "jnp.ndarray"]
+STATE_SLOT = "state_slot"
 
 
 class OutOfBlocks(RuntimeError):
@@ -597,6 +616,7 @@ def init_paged_pools(
     cfg: DecoderConfig, n_blocks: int, block_size: int,
     dtype: Optional["jnp.dtype"] = None,
     sharding=None,
+    n_lanes: Optional[int] = None,
 ) -> PagedPools:
     """Flat per-layer block pools, one per kind of row the block caches
     (``models/decoder.kv_row_shapes``): K and V pools of [n_blocks *
@@ -613,10 +633,13 @@ def init_paged_pools(
     kv-head slice, nothing pool-sized is staged on one device first.  The
     latent row has no head axis to divide: its pool is replicated."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    if sharding is not None and is_latent(cfg):
+    if sharding is not None and (is_latent(cfg) or is_hybrid(cfg)):
         from jax.sharding import NamedSharding, PartitionSpec
 
         sharding = NamedSharding(sharding.mesh, PartitionSpec())
+    if is_hybrid(cfg):
+        return _init_hybrid_pools(
+            cfg, n_blocks, block_size, dtype, sharding, n_lanes)
     pools: PagedPools = {}
     for i in range(cfg.num_layers):
         for prefix, (heads, width) in kv_row_shapes(cfg).items():
@@ -629,9 +652,18 @@ def init_paged_pools(
 def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     """HBM bytes one token of KV occupies across every layer — the
     block-granular accounting unit telemetry reports
-    (ROADMAP item 1: per-token bytes instead of per-bucket)."""
+    (ROADMAP item 1: per-token bytes instead of per-bucket).  The
+    two-mixer block: its sparse layers' K and V rows plus their share of
+    a compressed key (one per ``sparse_kernel_stride`` tokens); its linear
+    layers keep nothing a token (``models/hybrid.lane_state_bytes``)."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    if is_hybrid(cfg):
+        per_layer = sum(h * w for h, w in kv_row_shapes(cfg).values())
+        key = cfg.num_kv_heads * cfg.head_dim
+        return len(sparse_layers(cfg)) * item * (
+            per_layer + key // cfg.sparse_kernel_stride)
     per_layer = sum(h * w for h, w in kv_row_shapes(cfg).values())
-    return cfg.num_layers * per_layer * jnp.dtype(cfg.dtype).itemsize
+    return cfg.num_layers * per_layer * item
 
 
 def ragged_prefill_forward(
@@ -681,6 +713,17 @@ def ragged_prefill_forward(
                 "up-project cached rows, which no path here does)"
             )
         return _latent_prefill_forward(
+            params, cfg, pools, ids, seg_ids, positions, dest_rows,
+            last_rows, rope_len,
+        )
+    if is_hybrid(cfg):
+        if n_prefix_rows:
+            raise NotImplementedError(
+                "the two-mixer block prefills cold only: set "
+                "generate.prefix_cache false (a shared prefix is a run of "
+                "pages, and a lane's state at the share boundary is in none)"
+            )
+        return _hybrid_prefill_forward(
             params, cfg, pools, ids, seg_ids, positions, dest_rows,
             last_rows, rope_len,
         )
@@ -750,6 +793,11 @@ def paged_decode_forward(
     (GSPMD places it on a mesh)."""
     if is_latent(cfg):
         return _latent_decode_forward(
+            params, cfg, pools, block_tables, tok, lengths, block_size,
+            rope_len,
+        )
+    if is_hybrid(cfg):
+        return _hybrid_decode_forward(
             params, cfg, pools, block_tables, tok, lengths, block_size,
             rope_len,
         )
@@ -876,3 +924,209 @@ def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
         params, cfg, tok, rope_pos, rope_len, attend
     )
     return _with_record(decoder_head(params, cfg, x), pools, record)
+
+
+# ---- the two-mixer block (models/hybrid.py): rows AND a state a lane -------
+
+
+def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
+    """The pools of the two-mixer block:
+
+    * ``k{i}`` / ``v{i}`` [n_blocks * block_size, kv heads, d] of each
+      SPARSE layer, and ``ck{i}`` [rows / sparse_kernel_stride, kv heads,
+      d]: the mean-pooled key of the window that STARTS at that stride of
+      that page (written when the window completes, by the prefill and by
+      the decode step that completes it);
+    * ``s{i}`` [n_lanes, heads, d, d] float32 of each LINEAR layer: a
+      lane's state.  ``n_lanes`` defaults to the lanes of ``cfg.max_seq_len``
+      positions the pool holds;
+    * ``state_slot`` [n_blocks * block_size] int32: the state entry of
+      the lane whose FIRST token lives at that pool row (only rows that
+      start a block are ever looked up).  Both forwards find a lane's
+      state through it — the prefill from a segment's first destination
+      row, the decode step from the first entry of the lane's table — so
+      whatever addresses a lane travels in ``pools``.  It starts as
+      ``block // blocks of a lane``: right for tables laid out lane after
+      lane (the benchmark's comparison); an allocator's owner writes
+      ``state_slot[first block * block_size] = lane`` at admission
+      (``engines/serve.py``).
+    """
+    st = cfg.sparse_kernel_stride
+    if block_size % st:
+        raise ValueError(
+            f"kv_block_size {block_size} is no multiple of "
+            f"sparse_kernel_stride {st}")
+    rows = n_blocks * block_size
+    per_lane = -(-cfg.max_seq_len // block_size)
+    n_lanes = n_lanes or max(1, n_blocks // per_lane)
+    pools: PagedPools = {}
+    for i in sparse_layers(cfg):
+        for prefix, (heads, width) in kv_row_shapes(cfg, i).items():
+            pools[f"{prefix}{i}"] = jnp.zeros(
+                (rows, heads, width), dtype, device=sharding)
+        pools[f"ck{i}"] = jnp.zeros(
+            (rows // st, cfg.num_kv_heads, cfg.head_dim), dtype,
+            device=sharding)
+    for name, shape in lane_state_shapes(cfg).items():
+        pools[name] = jnp.zeros(
+            (n_lanes, *shape), jnp.float32, device=sharding)
+    slot = jnp.minimum(
+        jnp.arange(rows, dtype=jnp.int32) // (per_lane * block_size),
+        n_lanes - 1)
+    pools[STATE_SLOT] = (
+        slot if sharding is None else jnp.asarray(slot, device=sharding))
+    return pools
+
+
+def _sparse_sizes(cfg) -> dict:
+    return dict(
+        kernel_size=cfg.sparse_kernel_size, stride=cfg.sparse_kernel_stride,
+        block=cfg.sparse_block_size, topk=cfg.sparse_topk,
+        init_blocks=cfg.sparse_init_blocks, window=cfg.sparse_window_size,
+        dense_len=cfg.sparse_dense_len,
+    )
+
+
+def _state_slots(pools, cfg, first_rows, ok=True):
+    """The state entry of the lanes whose first token lives at the pool
+    rows ``first_rows``; out of bounds (a zero read, a dropped write)
+    where ``ok`` is false or the row is past the pool (a hole)."""
+    slot_of = pools[STATE_SLOT]
+    n_slots = pools[f"s{linear_layers(cfg)[0]}"].shape[0]
+    slot = slot_of[jnp.minimum(first_rows, slot_of.shape[0] - 1)]
+    return jnp.where(ok & (first_rows < slot_of.shape[0]), slot, n_slots)
+
+
+def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
+                            dest_rows, last_rows, rope_len):
+    """One packed COLD prefill dispatch of the two-mixer block.  A sparse
+    layer scatters K and V rows and the compressed keys of the windows
+    that lie whole inside a segment (none straddles two), and every row
+    selects for itself; a linear layer runs the chunked scan from a ZERO state at
+    each segment's first row and leaves the segment's final state in the
+    lane's entry (found through ``state_slot`` from the segment's first
+    destination row).
+
+    Returns (last_logits [B, vocab] f32, pools, selection record int32
+    [sparse layers x kv heads, T, sparse_topk] of the packed rows)."""
+    sizes = _sparse_sizes(cfg)
+    st = sizes["stride"]
+    t = ids.shape[0]
+    # a segment: its rows are one contiguous run from position 0
+    seg_ok = seg_ids[last_rows] == jnp.arange(last_rows.shape[0])
+    seg_len = jnp.where(seg_ok, positions[last_rows] + 1, 0)
+    seg_lens = jnp.where(
+        seg_ids >= 0, seg_len[jnp.maximum(seg_ids, 0)], 0)
+    chunk_slot = None
+    if LINEAR in cfg.mixer_types:
+        first_rows = last_rows - positions[last_rows]
+        slots = _state_slots(pools, cfg, dest_rows[first_rows], seg_ok)
+        # the chunk that holds a segment's last row takes its state
+        chunk_seg = seg_ids[:: RAGGED_ALIGN]
+        at = jnp.maximum(chunk_seg, 0)
+        is_last = (chunk_seg >= 0) & (
+            last_rows[at] // RAGGED_ALIGN == jnp.arange(t // RAGGED_ALIGN))
+        chunk_slot = jnp.where(is_last, slots[at], jnp.iinfo(jnp.int32).max)
+
+    def mix(i, kind, q, k, v):
+        if kind == LINEAR:
+            out, pools[f"s{i}"] = linear_attention_prefill(
+                q[0], k[0], v[0], seg_ids, positions, decay_slopes(cfg, i),
+                pools[f"s{i}"], chunk_slot,
+            )
+            return out[None], None
+        for name, new in (("k", k[0]), ("v", v[0])):
+            pool = pools[f"{name}{i}"]
+            pools[f"{name}{i}"] = pool.at[dest_rows].set(
+                new.astype(pool.dtype), mode="drop")
+        ck, ck_ok, ck_seg, ck_end = compressed_keys(
+            k[0], seg_ids, positions, sizes["kernel_size"], st)
+        cpool = pools[f"ck{i}"]
+        pools[f"ck{i}"] = cpool.at[
+            jnp.where(ck_ok, dest_rows[::st] // st, cpool.shape[0])
+        ].set(ck.astype(cpool.dtype), mode="drop")
+        out, taken = sparse_prefill_attention(
+            q[0], k[0], v[0], seg_ids, positions, seg_lens, ck, ck_ok,
+            ck_seg, ck_end, **sizes,
+        )
+        return out[None], taken[:, None]
+
+    x, record = hybrid_layer_stack(
+        params, cfg, ids[None, :], positions[None, :], rope_len, mix
+    )
+    logits = hybrid_head(params, cfg, x[0][last_rows][:, None, :])
+    return _with_record(
+        logits[:, 0], pools, None if record is None else record[:, 0]
+    )
+
+
+def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
+                           block_size, rope_len):
+    """A decode step of the two-mixer block, one token a lane.  A sparse
+    layer writes the token's K and V at its table-mapped row, writes the
+    compressed key of the window the token COMPLETES (if it does), selects
+    among the lane's compressed keys and reads the rows of the blocks
+    taken; a linear layer advances the lane's state IN PLACE (read, one
+    recurrence step, written back).  A lane whose table starts with a hole
+    (a retired slot) reads a zero state and writes nothing.
+
+    Returns (logits [S, 1, vocab] f32, pools, selection record int32
+    [sparse layers x kv heads, S, 1, sparse_topk])."""
+    S, s = tok.shape
+    if s != 1:
+        raise NotImplementedError(
+            "the two-mixer block decodes one token a lane a step (set "
+            "generate.speculative_k 0): a verify step of several would "
+            "need the state after each of them")
+    sizes = _sparse_sizes(cfg)
+    ks, st = sizes["kernel_size"], sizes["stride"]
+    nb = block_tables.shape[1]
+    P = pools[STATE_SLOT].shape[0]
+    n_blocks = P // block_size
+
+    def pool_rows(pos):
+        """Flat pool rows of the positions ``pos`` [S, n] of each lane;
+        ``P`` (out of bounds) past the table or on a hole."""
+        idx = pos // block_size
+        blk = jnp.take_along_axis(
+            block_tables, jnp.clip(idx, 0, nb - 1), axis=1)
+        ok = (pos >= 0) & (idx < nb) & (blk < n_blocks)
+        return jnp.where(ok, blk * block_size + pos % block_size, P)
+
+    dest = pool_rows(lengths[:, None])[:, 0]
+    # the window this token completes, if any: its first token, its rows
+    w_first = lengths - (ks - 1)
+    w_done = (w_first >= 0) & (w_first % st == 0)
+    w_rows = pool_rows(w_first[:, None] + jnp.arange(ks)[None, :])
+    w_dest = jnp.where(
+        w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
+    slots = None
+    if LINEAR in cfg.mixer_types:
+        slots = _state_slots(pools, cfg, block_tables[:, 0] * block_size)
+    rope_pos = jnp.minimum(lengths, rope_len - 1)[:, None]
+
+    def mix(i, kind, q, k, v):
+        if kind == LINEAR:
+            pool = pools[f"s{i}"]
+            state = pool.at[slots].get(mode="fill", fill_value=0.0)
+            out, state = linear_attention_step(
+                q[:, 0], k[:, 0], v[:, 0], state, decay_slopes(cfg, i))
+            pools[f"s{i}"] = pool.at[slots].set(state, mode="drop")
+            return out[:, None], None
+        for name, new in (("k", k[:, 0]), ("v", v[:, 0])):
+            pool = pools[f"{name}{i}"]
+            pools[f"{name}{i}"] = pool.at[dest].set(
+                new.astype(pool.dtype), mode="drop")
+        kp, cpool = pools[f"k{i}"], pools[f"ck{i}"]
+        mean = kp[jnp.minimum(w_rows, P - 1)].astype(jnp.float32).mean(1)
+        pools[f"ck{i}"] = cpool.at[w_dest].set(
+            mean.astype(cpool.dtype), mode="drop")
+        out, taken = sparse_decode_attention(
+            q[:, 0], pools[f"k{i}"], pools[f"v{i}"], pools[f"ck{i}"],
+            block_tables, lengths + 1, block_size=block_size, **sizes,
+        )
+        return out[:, None], taken[:, :, None]
+
+    x, record = hybrid_layer_stack(
+        params, cfg, tok, rope_pos, rope_len, mix)
+    return _with_record(hybrid_head(params, cfg, x), pools, record)

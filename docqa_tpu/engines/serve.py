@@ -108,6 +108,7 @@ from docqa_tpu import obs
 from docqa_tpu.obs.costs import DEFAULT_COST_LEDGER, cost_record_of
 from docqa_tpu.obs.observatory import DEFAULT_OBSERVATORY
 from docqa_tpu.engines.paged import (
+    STATE_SLOT,
     BlockAllocator,
     OutOfBlocks,
     PrefixCache,
@@ -123,6 +124,11 @@ from docqa_tpu.engines.spine import spine_run, spine_submit
 from docqa_tpu.models.decoder import (
     init_decoder_params,  # noqa: F401  (re-export convenience for tests)
     kv_row_shapes,
+)
+from docqa_tpu.models.hybrid import (
+    is_hybrid,
+    lane_state_bytes,
+    sparse_layers,
 )
 from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
 from docqa_tpu.ops.attention import RAGGED_ALIGN, paged_kernel_supported
@@ -142,6 +148,17 @@ log = get_logger("docqa.serve")
 MOE_SUMS = (
     "serve_moe_picks", "serve_moe_picks_local", "serve_moe_experts_touched",
     "serve_moe_layer_steps",
+)
+
+# the same of the two-mixer block (``_sparse_step_sums`` /
+# ``_sparse_chunk_sums``), over a chunk's steps and live lanes: blocks the
+# sparse layers' queries READ (the blocks taken; every live block on a
+# lane still under ``sparse_dense_len``), blocks live for them, lane-steps
+# that ran dense, and lane-steps in all (each reads and writes the lane's
+# state once: ``serve_state_bytes_rw`` is that times the state's bytes)
+SPARSE_SUMS = (
+    "serve_sparse_blocks_selected", "serve_sparse_blocks_live",
+    "serve_sparse_dense_lane_steps", "serve_state_lane_steps",
 )
 
 
@@ -711,6 +728,27 @@ class ContinuousBatcher:
                     "without " + " and ".join(unserved)
                     + ": set prefix_cache false and speculative_k 0"
                 )
+        if is_hybrid(self.cfg):
+            # refused at construction, by name: a shared prefix is a run of
+            # pages and a lane's state at the share boundary is in none of
+            # them; a verify step of several tokens would need the state
+            # after each; and a preempted lane resumes from pages alone
+            policy = QoSPolicy.coerce(qos)
+            unserved = [
+                name for name, on in (
+                    ("generate.prefix_cache", want_cache),
+                    ("generate.speculative_k", self.spec_k),
+                    ("qos.preemption", policy is not None
+                     and policy.preemption != "off"),
+                ) if on
+            ]
+            if unserved:
+                raise ValueError(
+                    f'DecoderConfig(block="{self.cfg.block}") is served '
+                    "without " + " and ".join(unserved)
+                    + ": set prefix_cache false, speculative_k 0 and "
+                    "qos.preemption off"
+                )
         if want_cache and self._share_align < self.seq_capacity:
             self._prefix_cache = PrefixCache(
                 self._alloc, self._share_align,
@@ -828,6 +866,20 @@ class ContinuousBatcher:
         # (``_moe_chunk_sums``); a block that does not route adds nothing
         # to its programs or to the worker's work per chunk.
         self._routed_layers = routed_layers(self.cfg)
+        # the two-mixer block: which state entry a lane owns travels in
+        # the pools (``paged.STATE_SLOT``: keyed by the pool row of a
+        # lane's first token); this is its host copy, written at admission
+        # and uploaded with the round's prefill.  Its decode chunks carry
+        # SPARSE_SUMS the way a routing block's carry MOE_SUMS.
+        self._hybrid = is_hybrid(self.cfg)
+        self._state_slot_np = (
+            np.zeros((self.n_blocks * self.block_size,), np.int32)
+            if self._hybrid else None
+        )
+        self._chunk_sum_names = (
+            MOE_SUMS if self._routed_layers
+            else SPARSE_SUMS if self._hybrid else ()
+        )
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="continuous-batcher"
         )
@@ -920,8 +972,8 @@ class ContinuousBatcher:
         # the chunk's expert-choice sums; an empty pytree (nothing in the
         # program) for a block that does not route
         moe0 = (
-            (jnp.zeros((len(MOE_SUMS),), jnp.int32),)
-            if self._routed_layers else ()
+            (jnp.zeros((len(self._chunk_sum_names),), jnp.int32),)
+            if self._chunk_sum_names else ()
         )
 
         def body(t, carry):
@@ -931,7 +983,10 @@ class ContinuousBatcher:
                 block_size=self.block_size, rope_len=self.seq_capacity,
                 use_flash=self.engine.use_flash, mesh=self.mesh,
             )
-            if routed:
+            if routed and self._hybrid:
+                moe = (moe[0] + self._sparse_step_sums(
+                    routed[0], lengths, active),)
+            elif routed:
                 moe = (moe[0] + self._moe_step_sums(routed[0], active),)
             rng, sub = jax.random.split(rng)
             nxt = sample(
@@ -968,7 +1023,7 @@ class ContinuousBatcher:
         if moe:  # one more row of the same fetch: the sums, then zeros
             packed = jnp.concatenate(
                 [packed, jnp.pad(
-                    moe[0], (0, packed.shape[1] - len(MOE_SUMS))
+                    moe[0], (0, packed.shape[1] - moe[0].shape[0])
                 )[None, :]], axis=0,
             )
         return pools, tok, lengths, active, packed
@@ -990,6 +1045,24 @@ class ContinuousBatcher:
             jnp.sum(per_expert),
             jnp.sum(per_expert > 0),
             jnp.any(active) * record.shape[0],
+        ]).astype(jnp.int32)
+
+    def _sparse_step_sums(self, record, lengths, active):
+        """``SPARSE_SUMS`` of one decode step, int32, from the selection
+        record [sparse layers x kv heads, S, 1, topk], the lanes' lengths
+        BEFORE the step and the lanes live in it — summed on the device,
+        as ``_moe_step_sums`` is."""
+        took = record[:, :, 0, :] >= 0  # [decisions, S, topk]
+        selected = took[0, :, 0]  # a lane that selected: first id >= 0
+        live_blocks = record.shape[0] * (
+            lengths // self.cfg.sparse_block_size + 1
+        )
+        read = jnp.where(selected, jnp.sum(took, axis=(0, 2)), live_blocks)
+        return jnp.stack([
+            jnp.sum(jnp.where(active, read, 0)),
+            jnp.sum(jnp.where(active, live_blocks, 0)),
+            jnp.sum(active & ~selected),
+            jnp.sum(active),
         ]).astype(jnp.int32)
 
     def _decode_spec_program(self, params, pools, tables, caps, table, tok,
@@ -1184,7 +1257,7 @@ class ContinuousBatcher:
         for ``self._pools``."""
         pools = init_paged_pools(
             self.cfg, self.n_blocks, self.block_size,
-            sharding=self._pool_sharding,
+            sharding=self._pool_sharding, n_lanes=self.n_slots,
         )
         rep = self._state_sharding
         table = (
@@ -1339,7 +1412,8 @@ class ContinuousBatcher:
             try:
                 pools_s = jax.eval_shape(
                     lambda: init_paged_pools(
-                        self.cfg, self.n_blocks, self.block_size
+                        self.cfg, self.n_blocks, self.block_size,
+                        n_lanes=self.n_slots,
                     )
                 )
                 params_s = jax.tree_util.tree_map(
@@ -1843,7 +1917,12 @@ class ContinuousBatcher:
             req = self._slot_req[slot]
             if req is not None:
                 tokens += req.kv_prompt + len(req.tokens)
+        state = (
+            {"state_bytes_per_lane": lane_state_bytes(self.cfg)}
+            if self._hybrid else {}
+        )
         out = {
+            **state,
             "blocks_total": self.n_blocks,
             "blocks_used": used,
             "block_size": self.block_size,
@@ -2361,6 +2440,13 @@ class ContinuousBatcher:
             row[:] = self.n_blocks
             row[: len(table.blocks)] = table.blocks
             self._caps_np[slot] = table.capacity
+            if self._hybrid:
+                # the slot owns state entry ``slot`` until it retires; the
+                # round's prefill starts it from zeros (a reused slot
+                # inherits nothing) and the map goes up with that dispatch
+                self._state_slot_np[
+                    table.blocks[0] * self.block_size
+                ] = slot
         self._tables_dirty = True
         for _slot, req, ids, table, shared in good:
             if shared and self._prefix_cache is not None:
@@ -2463,6 +2549,10 @@ class ContinuousBatcher:
             right behind it; the host-side fetch of first tokens
             (_finalize_admissions) then overlaps that chunk."""
             self._apply_deact_on_lane()
+            if self._hybrid:
+                self._pools[STATE_SLOT] = jax.device_put(
+                    self._state_slot_np, self._state_sharding
+                )
             parts = []
             for (T, ids_flat, seg, pos, dest, last_rows, slots_arr,
                  n_lanes, warm_flag, tables_np, plens_np) in group_inputs:
@@ -2516,6 +2606,10 @@ class ContinuousBatcher:
         DEFAULT_REGISTRY.counter("serve_admit_rounds").inc()
         DEFAULT_REGISTRY.counter("serve_admitted").inc(len(ordered))
         DEFAULT_REGISTRY.counter("serve_prefill_dispatches").inc(len(groups))
+        if self._hybrid:
+            DEFAULT_REGISTRY.counter("serve_lane_state_resets").inc(
+                len(ordered)
+            )
         # split: the round ran more groups than it would have had every
         # group been allowed the largest budget — how often rule 1 of
         # partition_prefill_round engages
@@ -2549,6 +2643,7 @@ class ContinuousBatcher:
                     prompt_tokens=len(ids), blocks=len(table.blocks),
                     shared_tokens=shared, budget_tokens=T,
                     packed_tokens=rows,
+                    **self._hybrid_prefill_attrs(len(ids), len(good)),
                 )
                 meta.append((
                     slot, req, len(ids), shared,
@@ -2562,6 +2657,18 @@ class ContinuousBatcher:
             ("warm", g[0]) if g[8] else g[0] for g in group_inputs
         ]
         return meta, first_toks, cost_keys, t_prefill1
+
+    def _hybrid_prefill_attrs(self, n_ids: int, n_lanes: int) -> dict:
+        """What the two-mixer block adds to a request's ``serve_prefill``
+        span: the rows of its prompt that SELECTED (all of them once the
+        prompt holds ``sparse_dense_len`` tokens, none under it) and the
+        lanes whose state the round started from zeros.  Nothing for any
+        other block."""
+        if not self._hybrid:
+            return {}
+        selects = n_ids >= self.cfg.sparse_dense_len
+        return {"sparse_rows": n_ids if selects else 0,
+                "state_lanes": n_lanes}
 
     def _finalize_admissions(self, admitted) -> bool:
         """Host-side bookkeeping for an admission round: ONE device fetch
@@ -2812,8 +2919,11 @@ class ContinuousBatcher:
                 _cost_add(req, "spine_queue_wait_ms", qw_ms)
                 if fl:
                     _cost_add(req, "flops_est", fl)
-        if self._routed_layers and not self.spec_k:
-            self._moe_chunk_sums(packed_h[self.n_slots])
+        if self._chunk_sum_names and not self.spec_k:
+            if self._hybrid:
+                self._sparse_chunk_sums(packed_h[self.n_slots])
+            else:
+                self._moe_chunk_sums(packed_h[self.n_slots])
             packed_h = packed_h[: self.n_slots]
         if self.spec_k:
             width = self.chunk + 2 * self.spec_k
@@ -2941,6 +3051,26 @@ class ContinuousBatcher:
                 / sums["serve_moe_experts_touched"]
             )
 
+    def _sparse_chunk_sums(self, row) -> None:
+        """One fetched chunk's ``SPARSE_SUMS`` into the counters the
+        two-mixer block's metrics read, the bytes of lane state its steps
+        read and wrote, and — one sample a chunk — the tokens a selecting
+        query read per sparse layer and kv head."""
+        sums = dict(zip(SPARSE_SUMS, (int(v) for v in row[: len(SPARSE_SUMS)])))
+        for name, value in sums.items():
+            DEFAULT_REGISTRY.counter(name).inc(value)
+        lane_steps = sums["serve_state_lane_steps"]
+        DEFAULT_REGISTRY.counter("serve_state_bytes_rw").inc(
+            2 * lane_state_bytes(self.cfg) * lane_steps
+        )
+        selecting = lane_steps - sums["serve_sparse_dense_lane_steps"]
+        if selecting and not sums["serve_sparse_dense_lane_steps"]:
+            decisions = len(sparse_layers(self.cfg)) * self.cfg.num_kv_heads
+            DEFAULT_REGISTRY.histogram("serve_sparse_selected_tokens").observe(
+                sums["serve_sparse_blocks_selected"]
+                * self.cfg.sparse_block_size / (selecting * decisions)
+            )
+
     def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
         """(KV rows fetched, KV rows live) PER LAYER over one chunk's
         steps, as the decode program is built — host arithmetic on what
@@ -2969,7 +3099,21 @@ class ContinuousBatcher:
             )
             live += int(lens.sum())
             read += int((-(-lens // self.block_size)).sum()) * self.block_size
-        if not self._pages_read_in_place:
+        if self._hybrid:
+            # a sparse layer reads the rows of the blocks taken, every
+            # table once a lane of the step is still under dense_len
+            # (ops/attention.sparse_decode_attention)
+            taken = self.cfg.sparse_topk * self.cfg.sparse_block_size
+            all_lens = np.stack([
+                length + 1 + np.minimum(np.arange(steps), adv)
+                for length, adv in lanes
+            ]) if lanes else np.zeros((0, steps), np.int64)
+            dense = (all_lens < self.cfg.sparse_dense_len).any(axis=0)
+            read = int(np.where(
+                dense, self.n_slots * self.seq_capacity,
+                len(lanes) * taken,
+            ).sum())
+        elif not self._pages_read_in_place:
             read = steps * self.n_slots * self.seq_capacity
         return read, live
 

@@ -26,6 +26,14 @@ import jax
 import jax.numpy as jnp
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.hybrid import (
+    HYBRID_BLOCK,
+    LINEAR,
+    hybrid_param_schema,
+    is_hybrid,
+    lane_state_shape,
+    linear_layers,
+)
 from docqa_tpu.models.latent import (
     LATENT_BLOCK,
     is_latent,
@@ -46,9 +54,13 @@ def decoder_param_schema(cfg: DecoderConfig):
     Both ``init_decoder_params`` and the int8 incremental init
     (``models/quant.py``) consume this — the RNG stream order is defined
     by the order of "normal" entries here, so the two inits can never
-    desynchronize.  The latent block's tree is ``models/latent.py``'s."""
+    desynchronize.  The latent block's tree is ``models/latent.py``'s,
+    the two-mixer block's ``models/hybrid.py``'s."""
     if is_latent(cfg):
         yield from latent_param_schema(cfg)
+        return
+    if is_hybrid(cfg):
+        yield from hybrid_param_schema(cfg)
         return
     h = cfg.hidden_dim
     qd = cfg.num_heads * cfg.head_dim
@@ -68,16 +80,33 @@ def decoder_param_schema(cfg: DecoderConfig):
         yield (f"l{i}_w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
 
 
-def kv_row_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, int]]:
+def kv_row_shapes(
+    cfg: DecoderConfig, layer: Optional[int] = None
+) -> Dict[str, Tuple[int, int]]:
     """What one token leaves in the cache, per layer: ``{pool prefix:
     (heads, width)}``.  The GQA block keeps a key and a value per kv
     head; the latent block ONE row (normed latent ‖ rotated key) that
     every head reads as key and as value.  The paged pools, their bytes
-    per token and the choice of decode kernel are all asked of this."""
+    per token and the choice of decode kernel are all asked of this.
+
+    The two-mixer block answers per LAYER KIND: a sparse layer keeps K and
+    V rows (``layer`` None: a row-keeping layer's answer), a linear layer
+    NO row — what it keeps is a state a lane, :func:`lane_state_shapes`."""
     if is_latent(cfg):
         return {"c": (1, latent_row_width(cfg))}
+    if is_hybrid(cfg) and layer is not None and (
+            cfg.mixer_types[layer] == LINEAR):
+        return {}
     return {"k": (cfg.num_kv_heads, cfg.head_dim),
             "v": (cfg.num_kv_heads, cfg.head_dim)}
+
+
+def lane_state_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
+    """What a LANE holds whatever its length, beside the rows its tokens
+    left: ``{pool name: shape of one lane's entry}``, float32.  Empty for
+    every block but the two-mixer one, whose linear layers each keep one
+    [heads, d, d] state."""
+    return {f"s{i}": lane_state_shape(cfg) for i in linear_layers(cfg)}
 
 
 def param_putter(cfg: DecoderConfig, mesh=None):
@@ -285,6 +314,12 @@ def decoder_forward(
             f'the dense-cache solo forward has no "{LATENT_BLOCK}" block '
             "(model_type deepseek_v2): that block serves through the paged "
             "cache (engines/paged.py, the batcher) only"
+        )
+    if is_hybrid(cfg):
+        raise NotImplementedError(
+            f'the dense-cache solo forward has no "{HYBRID_BLOCK}" block: '
+            "a stack of linear and sparse mixers serves through the paged "
+            "cache and its lane state (engines/paged.py, the batcher) only"
         )
     b, s = ids.shape
     max_len = cache["k0"].shape[1]

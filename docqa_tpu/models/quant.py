@@ -58,7 +58,10 @@ SCALE_SUFFIX = "__scale"
 GROUP_SIZE = 128  # int4 grouping along the `in` axis (llama.cpp/AWQ size)
 
 # 2-D matmul weights that quantize; everything else passes through
-_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# by NAME: the projections of every block's tree that end in one of these
+# (the two-mixer block's output gate, ``w_ogate``, among them); norms,
+# the embedding, routers and expert stacks stay in the activation type
+_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "w_ogate")
 
 
 def _int4_group(in_dim: int, group: Optional[int] = None) -> int:

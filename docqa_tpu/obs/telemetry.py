@@ -662,6 +662,12 @@ class TelemetrySampler:
             ):
                 if key in occ:
                     rec(f"serve_kv_{key}", float(occ[key]), now=now)
+            if "state_bytes_per_lane" in occ:
+                # a block whose lanes hold STATE beside their rows
+                # (models/hybrid.py): what one lane pins, whatever its
+                # length
+                rec("serve_state_bytes_per_lane",
+                    float(occ["state_bytes_per_lane"]), now=now)
         qos_status = getattr(b, "qos_status", None)
         if qos_status is not None:
             # multi-tenant QoS (docqa-qos): live deferral flag + class

@@ -987,3 +987,322 @@ def attention(q, k, v, **kwargs):
     for kw in _FLASH_ONLY_KWARGS:
         kwargs.pop(kw, None)
     return attention_reference(q, k, v, **kwargs)
+
+
+# --------------------------------------------------------------------------
+# The two-mixer block (models/hybrid.py): decayed linear attention whose
+# past is a state a lane, and block-sparse attention that selects inside
+# the paged cache.  XLA throughout; no Pallas kernel reads these yet.
+# --------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+# the selection score of a block that is always taken: above any real one
+# (a block's score is a sum of probabilities over a kv head's query heads)
+FORCED_SCORE = 1e4
+
+
+def linear_attention_prefill(q, k, v, seg_ids, positions, slopes, state_pool,
+                             chunk_slot):
+    """Decayed linear attention over a PACKED batch, in its chunked form:
+    per head ``S_t = lambda S_{t-1} + k_t^T v_t``, ``o_t = (q_t /
+    sqrt(d)) S_t`` with ``lambda = exp(-slope)``, computed
+    ``RAGGED_ALIGN`` rows at a time — inside a chunk the quadratic form
+    ``((q k^T) * lambda^(t-s)) v``, across chunks the carried state.
+
+    q, k, v    [T, heads, d]; segments start on chunk boundaries, so a
+               chunk belongs to one segment (or is padding)
+    seg_ids, positions [T]; a chunk whose first position is 0 starts from
+               a ZERO state (a segment's first: the lane's reset)
+    slopes     [heads] float32
+    state_pool [n_slots, heads, d, d] float32: a lane's state
+    chunk_slot [T / RAGGED_ALIGN] int32: the pool entry that takes the
+               state as it stands after this chunk (>= n_slots: none) —
+               set for a segment's LAST chunk
+
+    Returns (out [T, heads, d] in q's type, state_pool).  State and sums
+    are float32 at full matmul precision."""
+    t, heads, d = q.shape
+    c = RAGGED_ALIGN
+    n = t // c
+    f32 = jnp.float32
+    idx = jnp.arange(c, dtype=f32)
+    rel = idx[:, None] - idx[None, :]  # t - s inside a chunk
+    decay_ts = jnp.where(
+        rel >= 0, jnp.exp(-slopes[:, None, None] * jnp.maximum(rel, 0.0)), 0.0
+    )  # [heads, c, c]
+    decay_q = jnp.exp(-slopes[None, :] * (idx[:, None] + 1.0))  # [c, heads]
+    scale = d ** -0.5
+
+    def step(carry, xs):
+        state, pool = carry
+        qb, kb, vb, ok, pos0, slot = xs
+        state = jnp.where(pos0 == 0, 0.0, state)
+        count = jnp.sum(ok).astype(f32)
+        qf = qb.astype(f32) * scale
+        kf = jnp.where(ok[:, None, None], kb.astype(f32), 0.0)
+        vf = vb.astype(f32)
+        a = jnp.einsum("thd,shd->hts", qf, kf, precision=_HIGHEST) * decay_ts
+        o = jnp.einsum("hts,she->the", a, vf, precision=_HIGHEST)
+        o = o + decay_q[:, :, None] * jnp.einsum(
+            "thd,hde->the", qf, state, precision=_HIGHEST)
+        # what row s still weighs when the chunk's last valid row is done
+        decay_k = jnp.where(
+            ok[:, None],
+            jnp.exp(-slopes[None, :] * jnp.maximum(count - 1.0 - idx, 0.0)[
+                :, None]),
+            0.0,
+        )  # [c, heads]
+        state = jnp.exp(-slopes * count)[:, None, None] * state + jnp.einsum(
+            "shd,she->hde", kf * decay_k[:, :, None], vf, precision=_HIGHEST)
+        pool = pool.at[slot].set(state, mode="drop")
+        return (state, pool), o.astype(q.dtype)
+
+    chunks = lambda x: x.reshape(n, c, *x.shape[1:])  # noqa: E731
+    (_, state_pool), out = jax.lax.scan(
+        step,
+        (jnp.zeros(state_pool.shape[1:], f32), state_pool),
+        (chunks(q), chunks(k), chunks(v), chunks(seg_ids >= 0),
+         chunks(positions)[:, 0], chunk_slot),
+    )
+    return out.reshape(t, heads, d), state_pool
+
+
+def linear_attention_step(q, k, v, state, slopes):
+    """One decode step of the same, the recurrence itself: q, k, v
+    [S, heads, d] (one token a lane), state [S, heads, d, d] float32 ->
+    (out [S, heads, d] in q's type, the advanced state)."""
+    f32 = jnp.float32
+    qf = q.astype(f32) * q.shape[-1] ** -0.5
+    state = jnp.exp(-slopes)[None, :, None, None] * state + (
+        k.astype(f32)[..., :, None] * v.astype(f32)[..., None, :]
+    )
+    out = jnp.einsum("shd,shde->she", qf, state, precision=_HIGHEST)
+    return out.astype(q.dtype), state
+
+
+def _windows_of_blocks(p, m: int, r: int, nb: int):
+    """``p`` [..., nb * r] scores of the compressed-key windows (window j
+    starts ``stride * j`` tokens in; ``m`` strides long; ``r`` window
+    starts a block) -> [..., nb, r + m - 1]: for each block the windows
+    that overlap it, ``r b - (m - 1) .. r b + r - 1`` (0 before the
+    first window)."""
+    pad = jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(m - 1, 0)])
+    return jnp.stack(
+        [pad[..., i:i + r * nb:r] for i in range(r + m - 1)], axis=-1
+    )
+
+
+def _taken_blocks(block_score, exists, forced, topk: int):
+    """The blocks one row takes for one kv head: the ``topk`` best of the
+    blocks that exist for it, the forced ones first.  ``block_score``
+    [..., nb]; ``exists`` / ``forced`` broadcastable to it.  Returns (ids
+    int32 [..., kk], took bool [..., kk]) with kk = min(topk, nb)."""
+    score = jnp.where(
+        exists, jnp.where(forced, FORCED_SCORE, block_score), -1.0)
+    vals, ids = jax.lax.top_k(score, min(topk, score.shape[-1]))
+    return ids.astype(jnp.int32), vals >= 0.0
+
+
+def _pad_record(rec, topk: int):
+    short = topk - rec.shape[-1]
+    if short <= 0:
+        return rec
+    return jnp.pad(rec, [(0, 0)] * (rec.ndim - 1) + [(0, short)],
+                   constant_values=-1)
+
+
+def compressed_keys(k, seg_ids, positions, kernel_size: int, stride: int):
+    """Mean-pooled keys of a packed batch: window j is the rows ``stride
+    j .. stride j + kernel_size - 1``.  Returns (ck [T / stride, kv heads,
+    d] in k's type — as a pool holds them —, ok [W]: the window lies
+    whole inside ONE segment, its segment [W], the position of its last
+    token [W])."""
+    t, g, d = k.shape
+    w, m = t // stride, kernel_size // stride
+    k16 = k.astype(jnp.float32).reshape(w, stride, g, d).sum(1)
+    pad = jnp.pad(k16, ((0, m - 1), (0, 0), (0, 0)))
+    ck = sum(pad[i:i + w] for i in range(m)) / kernel_size
+    first = jnp.arange(w) * stride
+    last = first + kernel_size - 1
+    last_c = jnp.minimum(last, t - 1)
+    ok = (last < t) & (seg_ids[first] >= 0) & (
+        seg_ids[first] == seg_ids[last_c])
+    return ck.astype(k.dtype), ok, seg_ids[first], positions[last_c]
+
+
+def sparse_prefill_attention(q, k, v, seg_ids, positions, seg_lens, ck, ck_ok,
+                             ck_seg, ck_end, *, kernel_size: int, stride: int,
+                             block: int, topk: int, init_blocks: int,
+                             window: int, dense_len: int):
+    """Block-sparse self-attention over a PACKED batch: every row selects
+    for itself, per kv head, and attends causally to the rows of the
+    blocks it took (of its own segment) alone; a row of a segment that
+    holds fewer than ``dense_len`` tokens (``seg_lens`` [T]) attends to
+    all of them.
+
+    Selection (per row at position t, kv head g): softmax over the
+    compressed keys whose window ended at or before t, per query head,
+    summed over g's query heads; a block's score is the best of the
+    windows that overlap it; the first ``init_blocks`` blocks and those
+    over the last ``window`` tokens are always taken; ``topk`` in all.
+
+    q [T, heads, d]; k, v [T, kv heads, d]; ``ck`` ... as
+    :func:`compressed_keys` gives them.  Returns (out [T, heads, d],
+    taken int32 [kv heads, T, topk]: block ids within the row's segment,
+    the forced ones first, -1 where fewer exist and on rows that ran
+    dense or are padding)."""
+    t, hq, d = q.shape
+    g = k.shape[1]
+    per = hq // g
+    if RAGGED_ALIGN % block or t % RAGGED_ALIGN:
+        raise ValueError(
+            f"sparse prefill: blocks of {block} tokens do not tile "
+            f"{RAGGED_ALIGN}-row aligned segments of a {t}-row dispatch")
+    m, r, nb = kernel_size // stride, block // stride, t // block
+    scale = d ** -0.5
+    f32 = jnp.float32
+    ckf = ck.astype(f32)
+    valid = seg_ids >= 0
+    blk_first = jnp.arange(nb) * block
+    blk_seg, blk_pos0 = seg_ids[blk_first], positions[blk_first]
+
+    def attend_rows(rows):
+        qb = q[rows].reshape(-1, g, per, d)
+        seg_q, pos_q = seg_ids[rows], positions[rows]
+        real = seg_q >= 0
+        s_sel = jnp.einsum(
+            "qgpd,wgd->gpqw", qb.astype(f32), ckf, precision=_HIGHEST
+        ) * scale
+        ok_w = (ck_ok[None, :] & (ck_seg[None, :] == seg_q[:, None])
+                & (ck_end[None, :] <= pos_q[:, None]))
+        p = jax.nn.softmax(jnp.where(ok_w, s_sel, NEG_INF), axis=-1)
+        p = jnp.where(ok_w, p, 0.0).sum(axis=1)  # [g, bq, W]
+        score = _windows_of_blocks(p, m, r, nb).max(axis=-1)
+        exists = (real[:, None] & (blk_seg[None, :] == seg_q[:, None])
+                  & (blk_pos0[None, :] <= pos_q[:, None]))
+        forced = (blk_pos0[None, :] < init_blocks * block) | (
+            blk_pos0[None, :] + block - 1 >= pos_q[:, None] - window + 1)
+        ids, took = _taken_blocks(score, exists[None], forced[None], topk)
+        sparse_row = real & (seg_lens[rows] >= dense_len)
+        first_block = (rows - pos_q) // block  # of the row's segment
+        rec = jnp.where(
+            took & sparse_row[None, :, None],
+            ids - first_block[None, :, None], -1)
+        sel = jnp.any(
+            (ids[..., None] == jnp.arange(nb)) & took[..., None], axis=-2)
+        blk_mask = sel | ~sparse_row[None, :, None]  # [g, bq, nb]
+        base = (real[:, None] & valid[None, :]
+                & (seg_q[:, None] == seg_ids[None, :])
+                & (positions[None, :] <= pos_q[:, None]))
+        mask = base[None] & jnp.repeat(blk_mask, block, axis=-1)
+        scores = jnp.einsum(
+            "qgpd,kgd->gpqk", qb, k, preferred_element_type=f32) * scale
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        probs = jnp.where(
+            jnp.any(mask, axis=-1, keepdims=True)[:, None], probs, 0.0)
+        out = jnp.einsum(
+            "gpqk,kgd->qgpd", probs.astype(v.dtype), v,
+            preferred_element_type=f32)
+        return out.reshape(-1, hq, d).astype(q.dtype), rec
+
+    blocks = jnp.arange(t).reshape(t // RAGGED_ALIGN, RAGGED_ALIGN)
+    out, rec = jax.lax.map(attend_rows, blocks)
+    rec = jnp.moveaxis(rec, 1, 0).reshape(g, t, -1)  # [g, T, kk]
+    return out.reshape(t, hq, d), _pad_record(rec, topk)
+
+
+def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
+                            *, block_size: int, kernel_size: int, stride: int,
+                            block: int, topk: int, init_blocks: int,
+                            window: int, dense_len: int):
+    """The decode step of the same THROUGH A BLOCK TABLE: one query a
+    lane selects among the lane's compressed keys (gathered through the
+    table: one row per ``stride`` tokens) and reads the K / V rows of the
+    blocks it took, and only those — ``topk * block`` rows a kv head,
+    whatever the lane's length.  A lane that holds fewer than
+    ``dense_len`` tokens reads every row; while any LIVE lane (first
+    table entry allocated) is such a lane, the step gathers every lane's
+    whole table and masks (``lax.cond``: the other branch is not run).
+
+    q [S, heads, d]; pools flat ([P, kv heads, d]; ``ck_pool`` [P /
+    stride, ...]); ``lengths`` [S] AFTER this step.  Returns (out
+    [S, heads, d], taken int32 [kv heads, S, topk] as the prefill's)."""
+    s_, hq, d = q.shape
+    pool_rows, g, _ = k_pool.shape
+    per = hq // g
+    nbt = block_tables.shape[1]
+    cap = nbt * block_size
+    if block_size % stride or cap % block:
+        raise ValueError(
+            f"sparse decode: pages of {block_size} and a table of {cap} "
+            f"tokens do not tile windows every {stride} / blocks of {block}")
+    m, r, nb = kernel_size // stride, block // stride, cap // block
+    n_win = cap // stride
+    scale = d ** -0.5
+    f32 = jnp.float32
+    t = lengths - 1  # the query's position
+    lane = jnp.arange(s_)
+
+    w_tok = jnp.arange(n_win) * stride
+    page = block_tables[:, w_tok // block_size]  # [S, W]
+    ck_rows = jnp.minimum(
+        page * (block_size // stride) + (w_tok % block_size) // stride,
+        ck_pool.shape[0] - 1)
+    ck = ck_pool[ck_rows].astype(f32)  # [S, W, g, d]
+    ok_w = (w_tok[None, :] + kernel_size - 1 <= t[:, None])[:, None, None]
+    qg = q.reshape(s_, g, per, d)
+    s_sel = jnp.einsum(
+        "sgpd,swgd->sgpw", qg.astype(f32), ck, precision=_HIGHEST) * scale
+    p = jax.nn.softmax(jnp.where(ok_w, s_sel, NEG_INF), axis=-1)
+    p = jnp.where(ok_w, p, 0.0).sum(axis=2)  # [S, g, W]
+    score = _windows_of_blocks(p, m, r, nb).max(axis=-1)
+    blk_pos0 = jnp.arange(nb) * block
+    exists = blk_pos0[None, :] <= t[:, None]
+    forced = (blk_pos0[None, :] < init_blocks * block) | (
+        blk_pos0[None, :] + block - 1 >= t[:, None] - window + 1)
+    ids, took = _taken_blocks(score, exists[:, None], forced[:, None], topk)
+    sparse_lane = lengths >= dense_len
+    live = block_tables[:, 0] < pool_rows // block_size
+    rec = jnp.where(took & sparse_lane[:, None, None], ids, -1)
+
+    def finish(scores, mask, values, spec):
+        scores = jnp.where(mask[:, :, None], scores * scale, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        probs = jnp.where(
+            jnp.any(mask, axis=-1)[:, :, None, None], probs, 0.0)
+        return jnp.einsum(
+            spec, probs.astype(values.dtype), values,
+            preferred_element_type=f32)
+
+    def taken_rows_only(_):
+        tok = (ids[..., None] * block + jnp.arange(block)).reshape(
+            s_, g, -1)  # [S, g, kk * block] positions in the lane
+        pg = block_tables[lane[:, None, None],
+                          jnp.minimum(tok // block_size, nbt - 1)]
+        rows = jnp.minimum(pg * block_size + tok % block_size, pool_rows - 1)
+        head = jnp.arange(g)[None, :, None]
+        mask = jnp.repeat(took, block, axis=-1) & (tok <= t[:, None, None])
+        scores = jnp.einsum(
+            "sgpd,sgnd->sgpn", qg, k_pool[rows, head],
+            preferred_element_type=f32)
+        return finish(scores, mask, v_pool[rows, head], "sgpn,sgnd->sgpd")
+
+    def whole_tables(_):
+        sel = jnp.any(
+            (ids[..., None] == jnp.arange(nb)) & took[..., None], axis=-2)
+        blk_mask = sel | ~sparse_lane[:, None, None]  # [S, g, nb]
+        mask = jnp.repeat(blk_mask, block, axis=-1) & (
+            jnp.arange(cap)[None, None, :] <= t[:, None, None])
+        scores = jnp.einsum(
+            "sgpd,skgd->sgpk", qg,
+            gather_paged_kv(k_pool, block_tables, block_size),
+            preferred_element_type=f32)
+        return finish(
+            scores, mask, gather_paged_kv(v_pool, block_tables, block_size),
+            "sgpk,skgd->sgpd")
+
+    out = jax.lax.cond(
+        jnp.any(live & ~sparse_lane), whole_tables, taken_rows_only, None)
+    rec = jnp.moveaxis(rec, 1, 0)  # [g, S, kk]
+    return out.reshape(s_, hq, d).astype(q.dtype), _pad_record(rec, topk)

@@ -27,6 +27,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.hybrid import LINEAR, is_hybrid
 from docqa_tpu.models.latent import is_latent
 from docqa_tpu.runtime.mesh import MeshContext
 
@@ -40,6 +41,9 @@ def decoder_param_pspecs(cfg: DecoderConfig, model_axis: str) -> Dict[str, P]:
     }
     if is_latent(cfg):
         specs.update(_latent_param_pspecs(cfg, m))
+        return specs
+    if is_hybrid(cfg):
+        specs.update(_hybrid_param_pspecs(cfg, m))
         return specs
     for i in range(cfg.num_layers):
         specs.update(
@@ -89,6 +93,31 @@ def _latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
             p + "s_gate": P(None, m), p + "s_up": P(None, m),
             p + "s_down": P(m, None),
         })
+    return specs
+
+
+def _hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
+    """The two-mixer block (models/hybrid.py): Megatron per layer — q, the
+    output gate and the MLP's gate / up column-parallel, ``wo`` and
+    ``w_down`` row-parallel.  A linear layer's k and v are as wide as its
+    q and go column-parallel with it; a sparse layer's few kv heads are
+    replicated (2 heads do not divide over 4 or 8 devices), as are the
+    per-head norm gains.  The pools — rows, compressed keys, lane states —
+    are replicated (``paged_pool_pspecs``)."""
+    specs: Dict[str, P] = {}
+    for i, kind in enumerate(cfg.mixer_types):
+        p = f"l{i}_"
+        kv = P(None, m) if kind == LINEAR else P(None, None)
+        specs.update({
+            p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
+            p + "q_norm_g": P(None), p + "k_norm_g": P(None),
+            p + "wq": P(None, m), p + "wk": kv, p + "wv": kv,
+            p + "w_ogate": P(None, m), p + "wo": P(m, None),
+            p + "w_gate": P(None, m), p + "w_up": P(None, m),
+            p + "w_down": P(m, None),
+        })
+        if kind == LINEAR:
+            specs[p + "o_norm_g"] = P(None)
     return specs
 
 
@@ -169,6 +198,13 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     one-all-reduce-per-Megatron-block budget as the dense programs)."""
     if is_latent(cfg):  # one row a token, no head axis: replicated
         return {f"c{i}": P() for i in range(cfg.num_layers)}
+    if is_hybrid(cfg):  # rows of 2 kv heads, lane states, the slot map
+        import jax
+
+        from docqa_tpu.engines.paged import init_paged_pools
+
+        names = jax.eval_shape(lambda: init_paged_pools(cfg, 1, 16))
+        return {name: P() for name in names}
     spec = paged_pool_sharding(mesh).spec
     out: Dict[str, P] = {}
     for i in range(cfg.num_layers):
