@@ -706,16 +706,22 @@ class GenerateConfig:
     # per-dispatch host round-trip at the cost of coarser slot-retirement
     # granularity
     decode_chunk: int = 16
-    # coalesced admission (ROADMAP A1 (a)): once a round has popped its
-    # first request and slots are still free, the batcher's worker waits
-    # this long for the next arrival before it dispatches the round's
-    # prefill; every arrival restarts the wait, and it ends at once when
-    # the slots are full.  Requests that reach the queue milliseconds
-    # apart (a ward's clients asking together: their retrievals leave the
-    # HTTP layer's one device-lane thread one by one) then prefill as ONE
-    # round instead of one round per decode chunk.  The price: a request
-    # that arrives alone starts this much later, and live lanes decode
-    # this much later.  0 = off: a round is whatever is queued when the
+    # coalesced admission BY TIMER (ROADMAP A1 (a)), for arrivals the
+    # service cannot see coming: once a round has popped its first request
+    # and slots are still free, the batcher's worker waits this long for
+    # the next arrival before it dispatches the round's prefill; every
+    # arrival restarts the wait, and it ends at once when the slots are
+    # full.  Asks that come through the HTTP layer need none of it: the
+    # service counts them from the moment it takes them in
+    # (app._ask_preamble -> ContinuousBatcher.expect_arrival), and a round
+    # into an idle batcher gathers while that count is above zero — a
+    # ward's clients asking together prefill as ONE round, and a request
+    # with nobody behind it waits for nobody.  What the timer is still
+    # for: callers that submit to the pool directly, or that reach the
+    # service one after the other (each sent when the one before was
+    # acknowledged).  Its price: a request that arrives alone starts this
+    # much later, and live lanes decode this much later.  0 = off: with
+    # nothing expected either, a round is whatever is queued when the
     # worker looks, bit for bit the behaviour before the option existed.
     admit_hold_ms: float = 0.0
     # paged KV cache (engines/paged.py; docs/OPERATIONS.md "Paged KV

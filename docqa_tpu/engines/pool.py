@@ -59,7 +59,7 @@ import threading
 import zlib
 from time import monotonic as time_monotonic
 from time import perf_counter as _now
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -554,6 +554,21 @@ class EnginePool:
             getattr(r.batcher, "prefix_cache_enabled", False)
             for r in self._replicas
         )
+
+    def expect_arrival(self) -> Callable[[], None]:
+        """Pool passthrough of ``ContinuousBatcher.expect_arrival`` (the
+        HTTP layer calls it for every ask it takes in, ``service/app.py``).
+        The count is the POOL's: routing happens at the submit, so every
+        replica is told, and with several replicas one may gather for an
+        ask that lands on its sibling — until the count drops, or at most
+        the batcher's bound an arrival."""
+        arrived = [r.batcher.expect_arrival() for r in self._replicas]
+
+        def arrived_all() -> None:
+            for fn in arrived:
+                fn()
+
+        return arrived_all
 
     def submit_ids(
         self,

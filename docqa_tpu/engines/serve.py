@@ -98,7 +98,7 @@ import threading
 from dataclasses import dataclass, field
 from time import monotonic as time_monotonic
 from time import perf_counter as _now
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -326,6 +326,17 @@ def _req_mark(req: _Request, reason: str, anomalous: bool = True, **attrs):
         if anomalous:
             req.trace.flag(reason)
         req.trace.add_event(reason, span_id=req.span_parent, **attrs)
+
+
+# How long a gathering round waits for ONE arrival it was told to expect
+# (``ContinuousBatcher.expect_arrival``) before it goes without it.  The
+# wait normally ends far sooner, on the arrival or on the count dropping:
+# an ask is a retrieval long (5-7 ms on an idle device; the widest spacing
+# any benchmark cell shows is 45 ms, 9k prompt tokens hashed per ask).  The
+# bound is what a miss costs the other way — a request that misses its
+# round is admitted a decode chunk later (16 steps of 8-23 ms) — so past
+# about one chunk, waiting for it costs the round more than leaving it.
+_EXPECTED_ARRIVAL_BOUND_S = 0.2
 
 
 # One wait policy for every consumer of a Handle (qa /ask, summarize,
@@ -806,6 +817,10 @@ class ContinuousBatcher:
         self.on_preempt = None
         self._cv = threading.Condition()
         self._stopped = False
+        # arrivals the caller can already see (``expect_arrival``): asks
+        # the HTTP layer has taken in and not yet submitted.  Guarded by
+        # ``_cv``; the round that gathers for them is ``_run_loop``'s.
+        self._expected = 0
         # requests popped from the queue but not yet slot-resident (the
         # worker's admission round holds them in a local list).  Guarded
         # by ``_cv``.  drain() must count these as pending work: between
@@ -1705,6 +1720,38 @@ class ContinuousBatcher:
                     with self._cv:
                         self._cv.wait(deadline.bound(0.05))
         return [h.text(self.engine.tokenizer) for h in handles]
+
+    def expect_arrival(self) -> Callable[[], None]:
+        """Say that a request is on its way; call what this returns when
+        it has been submitted, or never will be (any number of times, from
+        any thread).
+
+        A round that pops its first request into an idle batcher keeps
+        gathering while arrivals are expected and slots are free
+        (``_run_loop``), so requests that leave the caller's preamble
+        milliseconds apart are ONE admission — and a request with nobody
+        behind it waits for nobody.  The caller submits BEFORE it calls
+        back: the worker then never sees "none expected, queue empty"
+        with a request in between.  Callers that expect nothing (direct
+        ``submit_*``) are admitted as ever."""
+        with self._cv:
+            self._expected += 1
+        counted = True
+
+        def arrived() -> None:
+            nonlocal counted
+            with self._cv:
+                if not counted:
+                    return
+                counted = False
+                self._expected -= 1
+                if not self._expected:
+                    # the last one may have left without a submit (routed
+                    # away, shed, failed): the gather ends on this, not
+                    # on its bound
+                    self._cv.notify_all()
+
+        return arrived
 
     def stop(self) -> None:
         with self._cv:
@@ -3447,34 +3494,79 @@ class ContinuousBatcher:
                 # admission: fill every free slot from the queue; the whole
                 # round prefills in one batched dispatch below
                 self._pop_free_slots(pairs)
-                if self._admit_hold_s and pairs:
-                    # generate.admit_hold_ms: with a round's first
-                    # requests popped and slots still free, wait for the
-                    # next arrival and pop it into the same round.  Every
-                    # arrival restarts the wait; it ends when the slots
-                    # are full, when nothing arrived for the hold, when a
-                    # popped request's budget runs out, or when the head
-                    # is block-starved (the fill stopped: waiting would
-                    # not admit it).  FIFO order is _pop_free_slots's.
+                # ---- gather: with a round's first requests popped and
+                # slots still free, wait for the next arrival and pop it
+                # into the same round.  Every arrival restarts the wait.
+                # It ends when the slots are full, on stop, when a popped
+                # request's budget runs out, when the head is block-
+                # starved (the fill stopped: waiting would not admit it),
+                # or when NO ARRIVAL IS EXPECTED and nothing arrived for
+                # generate.admit_hold_ms (0 by default: at once).  While
+                # arrivals are expected (expect_arrival) one may take
+                # _EXPECTED_ARRIVAL_BOUND_S; the count dropping to zero
+                # ends the wait, the bound is for an ask that never
+                # leaves.  FIFO order is _pop_free_slots's.
+                #
+                # Expected arrivals count only for a round into an IDLE
+                # batcher.  With lanes live a chunk is in flight, its
+                # fetch below is the wait this round owes anyway and the
+                # top-up after it takes whoever arrived meanwhile (an
+                # expected ask's retrieval sits behind that chunk on the
+                # device); gathering first would add a second wait, which
+                # every live lane's next chunk pays.
+                idle = pending is None and not any(self._slot_req)
+                if pairs and (self._admit_hold_s or (idle and self._expected)):
                     free = sum(1 for r in self._slot_req if r is None)
-                    until = _now() + self._admit_hold_s
+                    t_gather = last = _now()
+                    seen = 0  # most arrivals expected at once, as waited on
                     with span("serve_admit_gather", DEFAULT_REGISTRY):
-                        while len(pairs) < free and not self._stopped:
-                            left = until - _now()
+                        while True:
+                            if len(pairs) >= free:
+                                ended_by = "full"
+                                break
+                            if self._stopped:
+                                ended_by = "stop"
+                                break
+                            expected = self._expected if idle else 0
+                            wait_s = self._admit_hold_s
+                            if expected:
+                                wait_s = max(wait_s, _EXPECTED_ARRIVAL_BOUND_S)
+                            left = last + wait_s - _now()
+                            if left <= 0:
+                                ended_by = "expired" if expected else "quiet"
+                                break
                             for _, held in pairs:
                                 if held.deadline is not None:
                                     left = held.deadline.bound(left)
                             if left <= 0:
+                                ended_by = "deadline"
                                 break
                             self._beat = time_monotonic()
                             if not self._queue:
+                                if expected and not seen:
+                                    DEFAULT_REGISTRY.counter(
+                                        "serve_admit_expected_rounds"
+                                    ).inc()
+                                seen = max(seen, expected)
                                 self._cv.wait(left)
                             n = len(pairs)
                             self._pop_free_slots(pairs)
                             if len(pairs) > n:
-                                until = _now() + self._admit_hold_s
+                                last = _now()
                             elif self._queue:
+                                ended_by = "starved"
                                 break
+                    if ended_by == "expired":
+                        DEFAULT_REGISTRY.counter(
+                            "serve_admit_expected_expired"
+                        ).inc()
+                    t_gathered = _now()
+                    for _, req in pairs:
+                        _req_span(
+                            req, "serve_admit_gather",
+                            max(t_gather, req.t_pop), t_gathered,
+                            expected=seen, ended_by=ended_by,
+                        )
                 if (
                     not pairs
                     and self._queue

@@ -1448,6 +1448,14 @@ def make_app(rt: DocQARuntime):
         budget = rt.cfg.resilience.request_deadline_s
         deadline = Deadline.after(budget) if budget > 0 else None
         t_lane = time.perf_counter()
+        # This is the one point every ask passes, so it is where the
+        # batcher learns how many are on their way: counted from here —
+        # waiting for the lane, then inside ask_submit (routing, retrieval,
+        # prompt assembly) — until ask_submit has put it in the batcher's
+        # queue, answered it without the decoder, or raised.  A round that
+        # has its first request gathers for the rest (serve._run_loop).
+        expect = getattr(rt.batcher, "expect_arrival", None)
+        arrived = expect() if expect is not None else (lambda: None)
 
         def submit_on_lane():
             # the device lane is ONE thread: until it is free the request
@@ -1459,7 +1467,10 @@ def make_app(rt: DocQARuntime):
                     "ask_lane_wait", t_lane, time.perf_counter(),
                     parent_id=ctx.span_id,
                 )
-            return rt.qa.ask_submit(q.question, deadline=deadline)
+            try:
+                return rt.qa.ask_submit(q.question, deadline=deadline)
+            finally:
+                arrived()
 
         try:
             pending = await on_device(obs.call_in, ctx, submit_on_lane)
@@ -1471,6 +1482,10 @@ def make_app(rt: DocQARuntime):
             # QueueFull 503 "out of capacity"
             DEFAULT_REGISTRY.counter("qa_deadline_shed").inc()
             return None, json_error(504, str(e), ctx)
+        finally:
+            # a handler cancelled while it waited for the lane never ran
+            # the closure above; a second call does nothing
+            arrived()
         return pending, None
 
     def _ask_outcome(status: int) -> None:
