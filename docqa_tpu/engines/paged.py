@@ -77,6 +77,7 @@ from docqa_tpu.ops.attention import (
     sparse_decode_attention,
     sparse_prefill_attention,
 )
+from docqa_tpu.ops.scopes import scope
 
 # "k0".."k{L-1}", "v0".."v{L-1}"; the latent block: "c0".."c{L-1}"; the
 # two-mixer block: "k{i}" / "v{i}" / "ck{i}" of its sparse layers, "s{i}" of
@@ -730,14 +731,15 @@ def ragged_prefill_forward(
     warm = n_prefix_rows > 0  # static host int, never a tracer
 
     def attend(i, q, k, v):
-        kp = pools[f"k{i}"]
-        pools[f"k{i}"] = kp.at[dest_rows].set(
-            k[0].astype(kp.dtype), mode="drop"
-        )
-        vp = pools[f"v{i}"]
-        pools[f"v{i}"] = vp.at[dest_rows].set(
-            v[0].astype(vp.dtype), mode="drop"
-        )
+        with scope("cache_write"):
+            kp = pools[f"k{i}"]
+            pools[f"k{i}"] = kp.at[dest_rows].set(
+                k[0].astype(kp.dtype), mode="drop"
+            )
+            vp = pools[f"v{i}"]
+            pools[f"v{i}"] = vp.at[dest_rows].set(
+                v[0].astype(vp.dtype), mode="drop"
+            )
         # attention over the packed batch itself (cold: every KV row a
         # prompt token needs is in-flight in this very dispatch), plus —
         # warm — the cached prefix rows of the post-scatter pool (the
@@ -749,17 +751,19 @@ def ragged_prefill_forward(
                 block_tables=block_tables, prefix_lens=prefix_lens,
                 n_prefix_rows=n_prefix_rows, block_size=block_size,
             )
-        return ragged_prefill_attention(
-            q[0], k[0], v[0], seg_ids, positions,
-            sliding_window=cfg.sliding_window, **kwargs,
-        )[None]
+        with scope("attend"):
+            return ragged_prefill_attention(
+                q[0], k[0], v[0], seg_ids, positions,
+                sliding_window=cfg.sliding_window, **kwargs,
+            )[None]
 
     x = decoder_layer_stack(
         params, cfg, ids[None, :], positions[None, :], rope_len, attend
     )
-    x_last = x[0][last_rows]  # [B, hidden]
-    logits = decoder_head(params, cfg, x_last[:, None, :])
-    return logits[:, 0], pools
+    with scope("head"):
+        x_last = x[0][last_rows]  # [B, hidden]
+        logits = decoder_head(params, cfg, x_last[:, None, :])
+        return logits[:, 0], pools
 
 
 def paged_decode_forward(
@@ -806,30 +810,35 @@ def paged_decode_forward(
     P = pools["k0"].shape[0]
     n_blocks = P // block_size
 
-    pos = lengths[:, None] + jnp.arange(s)[None, :]  # [S, s]
-    blk_idx = pos // block_size
-    blk = jnp.take_along_axis(
-        block_tables, jnp.minimum(blk_idx, nb - 1), axis=1
-    )
-    dest = jnp.where(
-        (blk_idx < nb) & (blk < n_blocks),
-        blk * block_size + pos % block_size,
-        P,  # out of bounds -> dropped write
-    )
+    with scope("cache_write"):
+        pos = lengths[:, None] + jnp.arange(s)[None, :]  # [S, s]
+        blk_idx = pos // block_size
+        blk = jnp.take_along_axis(
+            block_tables, jnp.minimum(blk_idx, nb - 1), axis=1
+        )
+        dest = jnp.where(
+            (blk_idx < nb) & (blk < n_blocks),
+            blk * block_size + pos % block_size,
+            P,  # out of bounds -> dropped write
+        )
     rope_pos = jnp.minimum(pos, rope_len - 1)
     attn_lengths = lengths + s
 
     def attend(i, q, k, v):
-        kp = pools[f"k{i}"]
-        pools[f"k{i}"] = kp.at[dest].set(k.astype(kp.dtype), mode="drop")
-        vp = pools[f"v{i}"]
-        pools[f"v{i}"] = vp.at[dest].set(v.astype(vp.dtype), mode="drop")
-        return paged_decode_attention(
-            q, pools[f"k{i}"], pools[f"v{i}"], block_tables, attn_lengths,
-            block_size=block_size, q_offset=lengths,
-            sliding_window=cfg.sliding_window, use_flash=use_flash,
-            mesh=mesh,
-        )
+        with scope("cache_write"):
+            kp = pools[f"k{i}"]
+            pools[f"k{i}"] = kp.at[dest].set(
+                k.astype(kp.dtype), mode="drop")
+            vp = pools[f"v{i}"]
+            pools[f"v{i}"] = vp.at[dest].set(
+                v.astype(vp.dtype), mode="drop")
+        with scope("attend"):
+            return paged_decode_attention(
+                q, pools[f"k{i}"], pools[f"v{i}"], block_tables,
+                attn_lengths, block_size=block_size, q_offset=lengths,
+                sliding_window=cfg.sliding_window, use_flash=use_flash,
+                mesh=mesh,
+            )
 
     x = decoder_layer_stack(params, cfg, tok, rope_pos, rope_len, attend)
     logits = decoder_head(params, cfg, x)
@@ -861,20 +870,23 @@ def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
     scale = softmax_scale(cfg)
 
     def attend(i, q_nope, q_rope, row):
-        pool = pools[f"c{i}"]
-        pools[f"c{i}"] = pool.at[dest_rows].set(
-            row[0][:, None, :].astype(pool.dtype), mode="drop"
-        )
+        with scope("cache_write"):
+            pool = pools[f"c{i}"]
+            pools[f"c{i}"] = pool.at[dest_rows].set(
+                row[0][:, None, :].astype(pool.dtype), mode="drop"
+            )
         k, v = up_projected(params, cfg, i, row[0])
-        q = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
-        return ragged_prefill_attention(
-            q, k, v, seg_ids, positions, scale=scale
-        )[None]
+        with scope("attend"):
+            q = jnp.concatenate([q_nope[0], q_rope[0]], axis=-1)
+            return ragged_prefill_attention(
+                q, k, v, seg_ids, positions, scale=scale
+            )[None]
 
     x, record = latent_layer_stack(
         params, cfg, ids[None, :], positions[None, :], rope_len, attend
     )
-    logits = decoder_head(params, cfg, x[0][last_rows][:, None, :])
+    with scope("head"):
+        logits = decoder_head(params, cfg, x[0][last_rows][:, None, :])
     return _with_record(
         logits[:, 0], pools, None if record is None else record[:, 0]
     )
@@ -895,29 +907,32 @@ def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
     nb = block_tables.shape[1]
     P = pools["c0"].shape[0]
     n_blocks = P // block_size
-    pos = lengths[:, None] + jnp.arange(s)[None, :]
-    blk_idx = pos // block_size
-    blk = jnp.take_along_axis(
-        block_tables, jnp.minimum(blk_idx, nb - 1), axis=1
-    )
-    dest = jnp.where(
-        (blk_idx < nb) & (blk < n_blocks),
-        blk * block_size + pos % block_size,
-        P,  # out of bounds -> dropped write
-    )
+    with scope("cache_write"):
+        pos = lengths[:, None] + jnp.arange(s)[None, :]
+        blk_idx = pos // block_size
+        blk = jnp.take_along_axis(
+            block_tables, jnp.minimum(blk_idx, nb - 1), axis=1
+        )
+        dest = jnp.where(
+            (blk_idx < nb) & (blk < n_blocks),
+            blk * block_size + pos % block_size,
+            P,  # out of bounds -> dropped write
+        )
     rope_pos = jnp.minimum(pos, rope_len - 1)
     scale = softmax_scale(cfg)
 
     def attend(i, q_nope, q_rope, row):
-        pool = pools[f"c{i}"]
-        pools[f"c{i}"] = pool.at[dest].set(
-            row[:, :, None, :].astype(pool.dtype), mode="drop"
-        )
-        o_lat = paged_latent_decode_attention(
-            absorb_query(params, cfg, i, q_nope), q_rope, pools[f"c{i}"],
-            block_tables, lengths + s, block_size=block_size,
-            q_offset=lengths, scale=scale,
-        )
+        with scope("cache_write"):
+            pool = pools[f"c{i}"]
+            pools[f"c{i}"] = pool.at[dest].set(
+                row[:, :, None, :].astype(pool.dtype), mode="drop"
+            )
+        q_lat = absorb_query(params, cfg, i, q_nope)
+        with scope("attend"):
+            o_lat = paged_latent_decode_attention(
+                q_lat, q_rope, pools[f"c{i}"], block_tables, lengths + s,
+                block_size=block_size, q_offset=lengths, scale=scale,
+            )
         return expand_output(params, cfg, i, o_lat)
 
     x, record = latent_layer_stack(
@@ -1019,42 +1034,50 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
         seg_ids >= 0, seg_len[jnp.maximum(seg_ids, 0)], 0)
     chunk_slot = None
     if LINEAR in cfg.mixer_types:
-        first_rows = last_rows - positions[last_rows]
-        slots = _state_slots(pools, cfg, dest_rows[first_rows], seg_ok)
-        # the chunk that holds a segment's last row takes its state
-        chunk_seg = seg_ids[:: RAGGED_ALIGN]
-        at = jnp.maximum(chunk_seg, 0)
-        is_last = (chunk_seg >= 0) & (
-            last_rows[at] // RAGGED_ALIGN == jnp.arange(t // RAGGED_ALIGN))
-        chunk_slot = jnp.where(is_last, slots[at], jnp.iinfo(jnp.int32).max)
+        with scope("state"):
+            first_rows = last_rows - positions[last_rows]
+            slots = _state_slots(pools, cfg, dest_rows[first_rows], seg_ok)
+            # the chunk that holds a segment's last row takes its state
+            chunk_seg = seg_ids[:: RAGGED_ALIGN]
+            at = jnp.maximum(chunk_seg, 0)
+            is_last = (chunk_seg >= 0) & (
+                last_rows[at] // RAGGED_ALIGN
+                == jnp.arange(t // RAGGED_ALIGN))
+            chunk_slot = jnp.where(
+                is_last, slots[at], jnp.iinfo(jnp.int32).max)
 
     def mix(i, kind, q, k, v):
         if kind == LINEAR:
-            out, pools[f"s{i}"] = linear_attention_prefill(
-                q[0], k[0], v[0], seg_ids, positions, decay_slopes(cfg, i),
-                pools[f"s{i}"], chunk_slot,
+            with scope("state"):
+                out, pools[f"s{i}"] = linear_attention_prefill(
+                    q[0], k[0], v[0], seg_ids, positions,
+                    decay_slopes(cfg, i), pools[f"s{i}"], chunk_slot,
+                )
+                return out[None], None
+        with scope("cache_write"):
+            for name, new in (("k", k[0]), ("v", v[0])):
+                pool = pools[f"{name}{i}"]
+                pools[f"{name}{i}"] = pool.at[dest_rows].set(
+                    new.astype(pool.dtype), mode="drop")
+            ck, ck_ok, ck_seg, ck_end = compressed_keys(
+                k[0], seg_ids, positions, sizes["kernel_size"], st)
+            cpool = pools[f"ck{i}"]
+            pools[f"ck{i}"] = cpool.at[
+                jnp.where(ck_ok, dest_rows[::st] // st, cpool.shape[0])
+            ].set(ck.astype(cpool.dtype), mode="drop")
+        with scope("attend"):  # the selection inside opens ``select``
+            out, taken = sparse_prefill_attention(
+                q[0], k[0], v[0], seg_ids, positions, seg_lens, ck, ck_ok,
+                ck_seg, ck_end, **sizes,
             )
-            return out[None], None
-        for name, new in (("k", k[0]), ("v", v[0])):
-            pool = pools[f"{name}{i}"]
-            pools[f"{name}{i}"] = pool.at[dest_rows].set(
-                new.astype(pool.dtype), mode="drop")
-        ck, ck_ok, ck_seg, ck_end = compressed_keys(
-            k[0], seg_ids, positions, sizes["kernel_size"], st)
-        cpool = pools[f"ck{i}"]
-        pools[f"ck{i}"] = cpool.at[
-            jnp.where(ck_ok, dest_rows[::st] // st, cpool.shape[0])
-        ].set(ck.astype(cpool.dtype), mode="drop")
-        out, taken = sparse_prefill_attention(
-            q[0], k[0], v[0], seg_ids, positions, seg_lens, ck, ck_ok,
-            ck_seg, ck_end, **sizes,
-        )
-        return out[None], taken[:, None]
+            return out[None], taken[:, None]
 
     x, record = hybrid_layer_stack(
         params, cfg, ids[None, :], positions[None, :], rope_len, mix
     )
-    logits = hybrid_head(params, cfg, x[0][last_rows][:, None, :])
+    with scope("head"):
+        x_last = x[0][last_rows][:, None, :]
+    logits = hybrid_head(params, cfg, x_last)
     return _with_record(
         logits[:, 0], pools, None if record is None else record[:, 0]
     )
@@ -1093,39 +1116,47 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
         ok = (pos >= 0) & (idx < nb) & (blk < n_blocks)
         return jnp.where(ok, blk * block_size + pos % block_size, P)
 
-    dest = pool_rows(lengths[:, None])[:, 0]
-    # the window this token completes, if any: its first token, its rows
-    w_first = lengths - (ks - 1)
-    w_done = (w_first >= 0) & (w_first % st == 0)
-    w_rows = pool_rows(w_first[:, None] + jnp.arange(ks)[None, :])
-    w_dest = jnp.where(
-        w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
+    with scope("cache_write"):
+        dest = pool_rows(lengths[:, None])[:, 0]
+        # the window this token completes, if any: its first token, its
+        # rows
+        w_first = lengths - (ks - 1)
+        w_done = (w_first >= 0) & (w_first % st == 0)
+        w_rows = pool_rows(w_first[:, None] + jnp.arange(ks)[None, :])
+        w_dest = jnp.where(
+            w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
     slots = None
     if LINEAR in cfg.mixer_types:
-        slots = _state_slots(pools, cfg, block_tables[:, 0] * block_size)
+        with scope("state"):
+            slots = _state_slots(
+                pools, cfg, block_tables[:, 0] * block_size)
     rope_pos = jnp.minimum(lengths, rope_len - 1)[:, None]
 
     def mix(i, kind, q, k, v):
         if kind == LINEAR:
-            pool = pools[f"s{i}"]
-            state = pool.at[slots].get(mode="fill", fill_value=0.0)
-            out, state = linear_attention_step(
-                q[:, 0], k[:, 0], v[:, 0], state, decay_slopes(cfg, i))
-            pools[f"s{i}"] = pool.at[slots].set(state, mode="drop")
-            return out[:, None], None
-        for name, new in (("k", k[:, 0]), ("v", v[:, 0])):
-            pool = pools[f"{name}{i}"]
-            pools[f"{name}{i}"] = pool.at[dest].set(
-                new.astype(pool.dtype), mode="drop")
-        kp, cpool = pools[f"k{i}"], pools[f"ck{i}"]
-        mean = kp[jnp.minimum(w_rows, P - 1)].astype(jnp.float32).mean(1)
-        pools[f"ck{i}"] = cpool.at[w_dest].set(
-            mean.astype(cpool.dtype), mode="drop")
-        out, taken = sparse_decode_attention(
-            q[:, 0], pools[f"k{i}"], pools[f"v{i}"], pools[f"ck{i}"],
-            block_tables, lengths + 1, block_size=block_size, **sizes,
-        )
-        return out[:, None], taken[:, :, None]
+            with scope("state"):
+                pool = pools[f"s{i}"]
+                state = pool.at[slots].get(mode="fill", fill_value=0.0)
+                out, state = linear_attention_step(
+                    q[:, 0], k[:, 0], v[:, 0], state, decay_slopes(cfg, i))
+                pools[f"s{i}"] = pool.at[slots].set(state, mode="drop")
+                return out[:, None], None
+        with scope("cache_write"):
+            for name, new in (("k", k[:, 0]), ("v", v[:, 0])):
+                pool = pools[f"{name}{i}"]
+                pools[f"{name}{i}"] = pool.at[dest].set(
+                    new.astype(pool.dtype), mode="drop")
+            kp, cpool = pools[f"k{i}"], pools[f"ck{i}"]
+            mean = kp[jnp.minimum(w_rows, P - 1)].astype(
+                jnp.float32).mean(1)
+            pools[f"ck{i}"] = cpool.at[w_dest].set(
+                mean.astype(cpool.dtype), mode="drop")
+        with scope("attend"):  # the selection inside opens ``select``
+            out, taken = sparse_decode_attention(
+                q[:, 0], pools[f"k{i}"], pools[f"v{i}"], pools[f"ck{i}"],
+                block_tables, lengths + 1, block_size=block_size, **sizes,
+            )
+            return out[:, None], taken[:, :, None]
 
     x, record = hybrid_layer_stack(
         params, cfg, tok, rope_pos, rope_len, mix)

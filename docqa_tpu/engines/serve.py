@@ -133,6 +133,7 @@ from docqa_tpu.models.hybrid import (
 from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
 from docqa_tpu.ops.attention import RAGGED_ALIGN, paged_kernel_supported
 from docqa_tpu.ops.sampling import sample
+from docqa_tpu.ops.scopes import scope
 from docqa_tpu.resilience import faults
 from docqa_tpu.resilience.deadline import Deadline, DeadlineExceeded
 from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY, get_logger, span
@@ -952,23 +953,25 @@ class ContinuousBatcher:
             params, self.cfg, pools, ids, seg, pos, dest, last_rows,
             rope_len=self.seq_capacity, **warm_kw,
         )
-        toks = sample(
-            logits, rng, self.gen.temperature, self.gen.top_k,
-            self.gen.top_p,
-        )
-        if table is None:
-            return pools, toks
-        # per-lane bigram rows from the packed stream: a (prev, next)
-        # pair exists wherever two adjacent packed tokens share a segment
-        prev, nxt = ids[:-1], ids[1:]
-        pair_ok = (seg[:-1] == seg[1:]) & (seg[:-1] >= 0)
-        lane = jnp.where(pair_ok, seg[:-1], S)  # OOB -> dropped
-        prev = jnp.where(pair_ok, prev, self.cfg.vocab_size)
-        rows = jnp.full((S, self.cfg.vocab_size), -1, jnp.int32)
-        rows = rows.at[lane, prev].set(nxt, mode="drop")
-        rows = rows.at[jnp.arange(S), ids[last_rows]].set(toks)
-        table = table.at[slots].set(rows, mode="drop")
-        return pools, table, toks
+        with scope("sample"):
+            toks = sample(
+                logits, rng, self.gen.temperature, self.gen.top_k,
+                self.gen.top_p,
+            )
+            if table is None:
+                return pools, toks
+            # per-lane bigram rows from the packed stream: a (prev, next)
+            # pair exists wherever two adjacent packed tokens share a
+            # segment
+            prev, nxt = ids[:-1], ids[1:]
+            pair_ok = (seg[:-1] == seg[1:]) & (seg[:-1] >= 0)
+            lane = jnp.where(pair_ok, seg[:-1], S)  # OOB -> dropped
+            prev = jnp.where(pair_ok, prev, self.cfg.vocab_size)
+            rows = jnp.full((S, self.cfg.vocab_size), -1, jnp.int32)
+            rows = rows.at[lane, prev].set(nxt, mode="drop")
+            rows = rows.at[jnp.arange(S), ids[last_rows]].set(toks)
+            table = table.at[slots].set(rows, mode="drop")
+            return pools, table, toks
 
     def _decode_program(self, params, pools, tables, caps, tok, lengths,
                         active, rng):
@@ -982,14 +985,15 @@ class ContinuousBatcher:
         array so the worker fetches them in a single device→host transfer
         (three separate fetches would be three host round-trips)."""
         S = self.n_slots
-        out0 = jnp.full((S, self.chunk), self.gen.pad_id, jnp.int32)
-        valid0 = jnp.zeros((S, self.chunk), bool)
-        # the chunk's expert-choice sums; an empty pytree (nothing in the
-        # program) for a block that does not route
-        moe0 = (
-            (jnp.zeros((len(self._chunk_sum_names),), jnp.int32),)
-            if self._chunk_sum_names else ()
-        )
+        with scope("sample"):
+            out0 = jnp.full((S, self.chunk), self.gen.pad_id, jnp.int32)
+            valid0 = jnp.zeros((S, self.chunk), bool)
+            # the chunk's expert-choice sums; an empty pytree (nothing in
+            # the program) for a block that does not route
+            moe0 = (
+                (jnp.zeros((len(self._chunk_sum_names),), jnp.int32),)
+                if self._chunk_sum_names else ()
+            )
 
         def body(t, carry):
             pools, tok, lengths, active, out, valid, rng, moe = carry
@@ -998,31 +1002,35 @@ class ContinuousBatcher:
                 block_size=self.block_size, rope_len=self.seq_capacity,
                 use_flash=self.engine.use_flash, mesh=self.mesh,
             )
-            if routed and self._hybrid:
-                moe = (moe[0] + self._sparse_step_sums(
-                    routed[0], lengths, active),)
-            elif routed:
-                moe = (moe[0] + self._moe_step_sums(routed[0], active),)
-            rng, sub = jax.random.split(rng)
-            nxt = sample(
-                logits[:, 0], sub, self.gen.temperature, self.gen.top_k,
-                self.gen.top_p,
-            )
-            nxt = jnp.where(active, nxt, self.gen.pad_id)
-            is_eos = active & (nxt == self.gen.eos_id)
-            out = out.at[:, t].set(nxt)
-            valid = valid.at[:, t].set(active & ~is_eos)
-            lengths = lengths + active.astype(jnp.int32)
-            active = active & ~is_eos
-            # capacity guard: the next step writes row ``lengths``; a
-            # lane at its last ALLOCATED row stops here.  The worker's
-            # grow-at-decode margin keeps live lanes comfortably under
-            # their caps, but a pipelined chunk can run one dispatch past
-            # the host-enforced budget (tokens discarded) — without this
-            # guard that overshoot's K/V write would be dropped at a
-            # position attention could later read as garbage.
-            active = active & (lengths < caps) & (lengths < self.cache_len)
-            tok = jnp.where(active, nxt, tok)
+            with scope("sample"):
+                if routed and self._hybrid:
+                    moe = (moe[0] + self._sparse_step_sums(
+                        routed[0], lengths, active),)
+                elif routed:
+                    moe = (moe[0] + self._moe_step_sums(routed[0], active),)
+                rng, sub = jax.random.split(rng)
+                nxt = sample(
+                    logits[:, 0], sub, self.gen.temperature, self.gen.top_k,
+                    self.gen.top_p,
+                )
+                nxt = jnp.where(active, nxt, self.gen.pad_id)
+                is_eos = active & (nxt == self.gen.eos_id)
+                out = out.at[:, t].set(nxt)
+                valid = valid.at[:, t].set(active & ~is_eos)
+                lengths = lengths + active.astype(jnp.int32)
+                active = active & ~is_eos
+                # capacity guard: the next step writes row ``lengths``; a
+                # lane at its last ALLOCATED row stops here.  The worker's
+                # grow-at-decode margin keeps live lanes comfortably under
+                # their caps, but a pipelined chunk can run one dispatch
+                # past the host-enforced budget (tokens discarded) —
+                # without this guard that overshoot's K/V write would be
+                # dropped at a position attention could later read as
+                # garbage.
+                active = (
+                    active & (lengths < caps) & (lengths < self.cache_len)
+                )
+                tok = jnp.where(active, nxt, tok)
             return pools, tok, lengths, active, out, valid, rng, moe
 
         pools, tok, lengths, active, out, valid, _, moe = jax.lax.fori_loop(
@@ -1031,16 +1039,18 @@ class ContinuousBatcher:
             body,
             (pools, tok, lengths, active, out0, valid0, rng, moe0),
         )
-        packed = jnp.concatenate(
-            [out, valid.astype(jnp.int32), active.astype(jnp.int32)[:, None]],
-            axis=1,
-        )  # [S, 2*chunk + 1] — one D2H fetch for the worker
-        if moe:  # one more row of the same fetch: the sums, then zeros
+        with scope("sample"):
             packed = jnp.concatenate(
-                [packed, jnp.pad(
-                    moe[0], (0, packed.shape[1] - moe[0].shape[0])
-                )[None, :]], axis=0,
-            )
+                [out, valid.astype(jnp.int32),
+                 active.astype(jnp.int32)[:, None]],
+                axis=1,
+            )  # [S, 2*chunk + 1] — one D2H fetch for the worker
+            if moe:  # one more row of the same fetch: the sums, then zeros
+                packed = jnp.concatenate(
+                    [packed, jnp.pad(
+                        moe[0], (0, packed.shape[1] - moe[0].shape[0])
+                    )[None, :]], axis=0,
+                )
         return pools, tok, lengths, active, packed
 
     def _moe_step_sums(self, record, active):
@@ -1115,59 +1125,64 @@ class ContinuousBatcher:
 
         def body(st):
             pools, table, tok, lengths, active, out, n_out = st
-            drafts = draft_tokens(table, tok, K)
-            verify_in = jnp.concatenate([tok[:, None], drafts], axis=1)
+            with scope("sample"):
+                drafts = draft_tokens(table, tok, K)
+                verify_in = jnp.concatenate([tok[:, None], drafts], axis=1)
             # (a routing block's record is not counted under speculation)
             logits, pools, *_ = paged_decode_forward(
                 params, self.cfg, pools, tables, verify_in, lengths,
                 block_size=self.block_size, rope_len=self.seq_capacity,
                 use_flash=self.engine.use_flash, mesh=self.mesh,
             )
-            g, m, cand, is_eos, eos_pos = accept_drafts(
-                logits, drafts, self.gen.eos_id
-            )
-            # freeze slots that already filled their chunk quota: the loop
-            # keeps running for slower slots, and a frozen slot must not
-            # emit, advance, or retire until the next dispatch
-            live = active & (n_out < self.chunk)
-            emit_valid = (
-                cand
-                & (karange < eos_pos[:, None])
-                & live[:, None]
-            )
-            emitted = jnp.where(emit_valid, g, pad)
-            out = jax.vmap(
-                lambda o, v, off: jax.lax.dynamic_update_slice(o, v, (off,))
-            )(out, emitted, n_out)
-            n_valid = jnp.sum(emit_valid.astype(jnp.int32), axis=1)
-            n_out = n_out + n_valid
-            # a frozen slot's un-consumed EOS re-derives next dispatch
-            saw_eos = live & jnp.any(is_eos, 1)
-            last_tok = jnp.take_along_axis(
-                emitted, jnp.maximum(n_valid - 1, 0)[:, None], 1
-            )[:, 0]
-            table = self.engine.confirm_bigrams(table, tok, g, emit_valid)
-            lengths = lengths + jnp.where(active, n_valid, 0)
-            active = active & ~saw_eos
-            # capacity guard (see _decode_program): a verify writes the
-            # K-row window [lengths, lengths+K) — stop the lane while
-            # that window still fits its ALLOCATED blocks, so a pipelined
-            # overshoot chunk can only ever drop writes, never land them
-            # where attention could read them back.
-            active = (
-                active
-                & (lengths <= caps - K)
-                & (lengths < self.cache_len - K)
-            )
-            tok = jnp.where(active & (n_valid > 0), last_tok, tok)
+            with scope("sample"):
+                g, m, cand, is_eos, eos_pos = accept_drafts(
+                    logits, drafts, self.gen.eos_id
+                )
+                # freeze slots that already filled their chunk quota: the loop
+                # keeps running for slower slots, and a frozen slot must not
+                # emit, advance, or retire until the next dispatch
+                live = active & (n_out < self.chunk)
+                emit_valid = (
+                    cand
+                    & (karange < eos_pos[:, None])
+                    & live[:, None]
+                )
+                emitted = jnp.where(emit_valid, g, pad)
+                out = jax.vmap(
+                    lambda o, v, off: jax.lax.dynamic_update_slice(
+                        o, v, (off,))
+                )(out, emitted, n_out)
+                n_valid = jnp.sum(emit_valid.astype(jnp.int32), axis=1)
+                n_out = n_out + n_valid
+                # a frozen slot's un-consumed EOS re-derives next dispatch
+                saw_eos = live & jnp.any(is_eos, 1)
+                last_tok = jnp.take_along_axis(
+                    emitted, jnp.maximum(n_valid - 1, 0)[:, None], 1
+                )[:, 0]
+                table = self.engine.confirm_bigrams(table, tok, g, emit_valid)
+                lengths = lengths + jnp.where(active, n_valid, 0)
+                active = active & ~saw_eos
+                # capacity guard (see _decode_program): a verify writes the
+                # K-row window [lengths, lengths+K) — stop the lane while
+                # that window still fits its ALLOCATED blocks, so a pipelined
+                # overshoot chunk can only ever drop writes, never land them
+                # where attention could read them back.
+                active = (
+                    active
+                    & (lengths <= caps - K)
+                    & (lengths < self.cache_len - K)
+                )
+                tok = jnp.where(active & (n_valid > 0), last_tok, tok)
             return pools, table, tok, lengths, active, out, n_out
 
         pools, table, tok, lengths, active, out, n_out = jax.lax.while_loop(
             cond, body, (pools, table, tok, lengths, active, out0, n0)
         )
-        packed = jnp.concatenate(
-            [out, n_out[:, None], active.astype(jnp.int32)[:, None]], axis=1
-        )  # [S, width + 2] — one D2H fetch for the worker
+        with scope("sample"):
+            packed = jnp.concatenate(
+                [out, n_out[:, None], active.astype(jnp.int32)[:, None]],
+                axis=1,
+            )  # [S, width + 2] — one D2H fetch for the worker
         return pools, table, tok, lengths, active, packed
 
     # The three adapters below bind ``_prefill_program``'s keyword

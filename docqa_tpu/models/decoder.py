@@ -43,6 +43,7 @@ from docqa_tpu.models.latent import (
 from docqa_tpu.ops.attention import attention_reference, flash_attention
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
+from docqa_tpu.ops.scopes import scope
 
 Params = Dict[str, jax.Array]
 KVCache = Dict[str, jax.Array]  # "k0".."k{L-1}", "v0".."v{L-1}"
@@ -243,31 +244,36 @@ def decoder_layer_stack(
     :func:`decoder_head` finishes the stack)."""
     b, s = ids.shape
     dtype = jnp.dtype(cfg.dtype)
-    cos, sin = rope_angles(cfg.head_dim, rope_len, cfg.rope_theta)
-    x = params["tok_emb"][ids].astype(dtype)
+    with scope("proj"):
+        cos, sin = rope_angles(cfg.head_dim, rope_len, cfg.rope_theta)
+    with scope("embed"):
+        x = params["tok_emb"][ids].astype(dtype)
     for i in range(cfg.num_layers):
-        y = rms_norm(x, params[f"l{i}_attn_norm_g"], cfg.norm_eps)
-        q = _qmatmul(y, params, f"l{i}_wq", dtype).reshape(
-            b, s, cfg.num_heads, cfg.head_dim
-        )
-        k = _qmatmul(y, params, f"l{i}_wk", dtype).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim
-        )
-        v = _qmatmul(y, params, f"l{i}_wv", dtype).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim
-        )
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        with scope("proj"):
+            y = rms_norm(x, params[f"l{i}_attn_norm_g"], cfg.norm_eps)
+            q = _qmatmul(y, params, f"l{i}_wq", dtype).reshape(
+                b, s, cfg.num_heads, cfg.head_dim
+            )
+            k = _qmatmul(y, params, f"l{i}_wk", dtype).reshape(
+                b, s, cfg.num_kv_heads, cfg.head_dim
+            )
+            v = _qmatmul(y, params, f"l{i}_wv", dtype).reshape(
+                b, s, cfg.num_kv_heads, cfg.head_dim
+            )
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
 
         attn = attend(i, q, k, v)
-        attn = attn.reshape(b, s, cfg.num_heads * cfg.head_dim)
-        x = x + _qmatmul(attn, params, f"l{i}_wo", dtype)
+        with scope("proj"):
+            attn = attn.reshape(b, s, cfg.num_heads * cfg.head_dim)
+            x = x + _qmatmul(attn, params, f"l{i}_wo", dtype)
 
-        y = rms_norm(x, params[f"l{i}_mlp_norm_g"], cfg.norm_eps)
-        gate = _qmatmul(y, params, f"l{i}_w_gate", dtype)
-        up = _qmatmul(y, params, f"l{i}_w_up", dtype)
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * up
-        x = x + _qmatmul(act, params, f"l{i}_w_down", dtype)
+        with scope("mlp"):
+            y = rms_norm(x, params[f"l{i}_mlp_norm_g"], cfg.norm_eps)
+            gate = _qmatmul(y, params, f"l{i}_w_gate", dtype)
+            up = _qmatmul(y, params, f"l{i}_w_up", dtype)
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * up
+            x = x + _qmatmul(act, params, f"l{i}_w_down", dtype)
     return x
 
 
@@ -280,12 +286,16 @@ def decoder_head(
 ) -> jax.Array:
     """Final norm + lm_head over the trunk's hidden states (f32 logits)."""
     dtype = jnp.dtype(cfg.dtype)
-    if last_token_only and x.shape[1] > 1:
-        # prefill path: only the last valid row per lane feeds sampling —
-        # skip the [s, vocab] lm_head matmul for the rest (~s x fewer FLOPs)
-        x = jnp.take_along_axis(x, (new_lengths - 1)[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm_g"], cfg.norm_eps)
-    return _qmatmul(x, params, "lm_head", dtype).astype(jnp.float32)
+    with scope("head"):
+        if last_token_only and x.shape[1] > 1:
+            # prefill path: only the last valid row per lane feeds
+            # sampling — skip the [s, vocab] lm_head matmul for the rest
+            # (~s x fewer FLOPs)
+            x = jnp.take_along_axis(
+                x, (new_lengths - 1)[:, None, None], axis=1
+            )
+        x = rms_norm(x, params["final_norm_g"], cfg.norm_eps)
+        return _qmatmul(x, params, "lm_head", dtype).astype(jnp.float32)
 
 
 def decoder_forward(
@@ -335,17 +345,19 @@ def decoder_forward(
     )
 
     def attend(i, q, k, v):
-        cache[f"k{i}"] = _write_cache(cache[f"k{i}"], k, cache_lengths)
-        cache[f"v{i}"] = _write_cache(cache[f"v{i}"], v, cache_lengths)
-        return attn_fn(
-            q,
-            cache[f"k{i}"],
-            cache[f"v{i}"],
-            causal=True,
-            lengths=new_lengths,
-            q_offset=cache_lengths,
-            sliding_window=cfg.sliding_window,
-        )
+        with scope("cache_write"):
+            cache[f"k{i}"] = _write_cache(cache[f"k{i}"], k, cache_lengths)
+            cache[f"v{i}"] = _write_cache(cache[f"v{i}"], v, cache_lengths)
+        with scope("attend"):
+            return attn_fn(
+                q,
+                cache[f"k{i}"],
+                cache[f"v{i}"],
+                causal=True,
+                lengths=new_lengths,
+                q_offset=cache_lengths,
+                sliding_window=cfg.sliding_window,
+            )
 
     x = decoder_layer_stack(params, cfg, ids, positions, max_len, attend)
     logits = decoder_head(params, cfg, x, new_lengths, last_token_only)

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from docqa_tpu.config import DecoderConfig
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
+from docqa_tpu.ops.scopes import scope
 
 Params = Dict[str, jax.Array]
 
@@ -219,35 +220,44 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
     eps = cfg.norm_eps
     cos = sin = None
     if LINEAR in cfg.mixer_types:
-        cos, sin = rope_angles(cfg.linear_head_dim, rope_len, cfg.rope_theta)
-    x = (params["tok_emb"][ids].astype(jnp.float32) * cfg.scale_emb).astype(
-        dtype)
+        with scope("proj"):
+            cos, sin = rope_angles(
+                cfg.linear_head_dim, rope_len, cfg.rope_theta)
+    with scope("embed"):
+        x = (params["tok_emb"][ids].astype(jnp.float32)
+             * cfg.scale_emb).astype(dtype)
     record = []
     for i, kind in enumerate(cfg.mixer_types):
         p = f"l{i}_"
         heads, kv_heads, d = mixer_geometry(cfg, kind)
-        y = rms_norm(x, params[p + "attn_norm_g"], eps)
-        q = _qmatmul(y, params, p + "wq", dtype).reshape(b, s, heads, d)
-        k = _qmatmul(y, params, p + "wk", dtype).reshape(b, s, kv_heads, d)
-        v = _qmatmul(y, params, p + "wv", dtype).reshape(b, s, kv_heads, d)
-        q = rms_norm(q, params[p + "q_norm_g"], eps)
-        k = rms_norm(k, params[p + "k_norm_g"], eps)
-        if kind == LINEAR:
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        with scope("proj"):
+            y = rms_norm(x, params[p + "attn_norm_g"], eps)
+            q = _qmatmul(y, params, p + "wq", dtype).reshape(b, s, heads, d)
+            k = _qmatmul(y, params, p + "wk", dtype).reshape(
+                b, s, kv_heads, d)
+            v = _qmatmul(y, params, p + "wv", dtype).reshape(
+                b, s, kv_heads, d)
+            q = rms_norm(q, params[p + "q_norm_g"], eps)
+            k = rms_norm(k, params[p + "k_norm_g"], eps)
+            if kind == LINEAR:
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
         out, taken = mix(i, kind, q, k, v)
-        if kind == LINEAR:
-            out = rms_norm(
-                out, params[p + "o_norm_g"].reshape(heads, d), eps)
-        else:
+        if kind != LINEAR:
             record.append(taken)
-        gate = jax.nn.sigmoid(
-            _qmatmul(y, params, p + "w_ogate", dtype).astype(jnp.float32))
-        a = (out.reshape(b, s, heads * d).astype(jnp.float32) * gate).astype(
-            dtype)
-        x = _residual(x, r, _qmatmul(a, params, p + "wo", dtype))
-        y = rms_norm(x, params[p + "mlp_norm_g"], eps)
-        x = _residual(x, r, _swiglu_tiled(y, params, p, dtype))
+        with scope("proj"):
+            if kind == LINEAR:
+                out = rms_norm(
+                    out, params[p + "o_norm_g"].reshape(heads, d), eps)
+            gate = jax.nn.sigmoid(
+                _qmatmul(y, params, p + "w_ogate", dtype).astype(
+                    jnp.float32))
+            a = (out.reshape(b, s, heads * d).astype(jnp.float32)
+                 * gate).astype(dtype)
+            x = _residual(x, r, _qmatmul(a, params, p + "wo", dtype))
+        with scope("mlp"):
+            y = rms_norm(x, params[p + "mlp_norm_g"], eps)
+            x = _residual(x, r, _swiglu_tiled(y, params, p, dtype))
         # the stream is rounded HERE: without the barrier XLA carries it
         # in excess precision and re-sums every earlier layer's branch
         # where it needs it, which keeps them all alive (3.4 GB at 9.7k
@@ -260,4 +270,5 @@ def hybrid_head(params: Params, cfg: DecoderConfig, x):
     """``decoder_head`` with the block's logit scale."""
     from docqa_tpu.models.decoder import decoder_head
 
-    return decoder_head(params, cfg, x) * logit_scale(cfg)
+    with scope("head"):
+        return decoder_head(params, cfg, x) * logit_scale(cfg)

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from docqa_tpu.config import DecoderConfig
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, yarn_mscale, yarn_rope_angles
+from docqa_tpu.ops.scopes import scope
 
 Params = Dict[str, jax.Array]
 
@@ -161,34 +162,39 @@ def up_projected(params: Params, cfg: DecoderConfig, i: int, rows):
     latent rows ``rows`` [n, r + dr] — the PREFILL form, for rows in
     flight."""
     r, heads = cfg.kv_lora_rank, cfg.num_heads
-    c_kv, k_r = rows[:, :r], rows[:, r:]
-    k_nope = (c_kv @ params[f"l{i}_wk_b"].astype(rows.dtype)).reshape(
-        -1, heads, cfg.qk_nope_head_dim
-    )
-    v = (c_kv @ params[f"l{i}_wv_b"].astype(rows.dtype)).reshape(
-        -1, heads, cfg.v_head_dim
-    )
-    k_r = jnp.broadcast_to(k_r[:, None, :], (*k_nope.shape[:2], k_r.shape[-1]))
-    return jnp.concatenate([k_nope, k_r], axis=-1), v
+    with scope("proj"):
+        c_kv, k_r = rows[:, :r], rows[:, r:]
+        k_nope = (c_kv @ params[f"l{i}_wk_b"].astype(rows.dtype)).reshape(
+            -1, heads, cfg.qk_nope_head_dim
+        )
+        v = (c_kv @ params[f"l{i}_wv_b"].astype(rows.dtype)).reshape(
+            -1, heads, cfg.v_head_dim
+        )
+        k_r = jnp.broadcast_to(
+            k_r[:, None, :], (*k_nope.shape[:2], k_r.shape[-1])
+        )
+        return jnp.concatenate([k_nope, k_r], axis=-1), v
 
 
 def absorb_query(params: Params, cfg: DecoderConfig, i: int, q_nope):
     """``q_nope`` [..., heads, nope] carried into latent space
     [..., heads, r]: ``q_nope . (c Wk_b) == (q_nope Wk_b^T) . c`` — the
     DECODE form, so that scores are taken against cached rows as stored."""
-    w = params[f"l{i}_wk_b"].astype(q_nope.dtype).reshape(
-        cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim
-    )
-    return jnp.einsum("...hd,rhd->...hr", q_nope, w)
+    with scope("proj"):
+        w = params[f"l{i}_wk_b"].astype(q_nope.dtype).reshape(
+            cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim
+        )
+        return jnp.einsum("...hd,rhd->...hr", q_nope, w)
 
 
 def expand_output(params: Params, cfg: DecoderConfig, i: int, o_lat):
     """The attention-weighted latent [..., heads, r] through the value
     half of the up-projection -> [..., heads, v]."""
-    w = params[f"l{i}_wv_b"].astype(o_lat.dtype).reshape(
-        cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim
-    )
-    return jnp.einsum("...hr,rhd->...hd", o_lat, w)
+    with scope("proj"):
+        w = params[f"l{i}_wv_b"].astype(o_lat.dtype).reshape(
+            cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim
+        )
+        return jnp.einsum("...hr,rhd->...hd", o_lat, w)
 
 
 # ---- the MLPs --------------------------------------------------------------
@@ -255,20 +261,24 @@ def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
 
 def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int):
     """(what the routed layer adds [n, hidden], expert ids taken [n, k])."""
-    logits = jnp.dot(
-        y.astype(jnp.float32), params[f"l{i}_router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    scores = jax.nn.softmax(logits, axis=-1)
-    taken, taken_scores = select_experts(scores, cfg)
-    out = held_experts_sum(
-        y, taken, cfg.routed_scale * taken_scores, params, cfg, i
-    )
-    if cfg.num_shared_experts:
-        out = out + _swiglu(
-            y, params, f"l{i}_s_gate", f"l{i}_s_up", f"l{i}_s_down"
-        ).astype(jnp.float32)
-    return out.astype(y.dtype), taken
+    with scope("route"):
+        logits = jnp.dot(
+            y.astype(jnp.float32),
+            params[f"l{i}_router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        scores = jax.nn.softmax(logits, axis=-1)
+        taken, taken_scores = select_experts(scores, cfg)
+    with scope("experts"):
+        out = held_experts_sum(
+            y, taken, cfg.routed_scale * taken_scores, params, cfg, i
+        )
+    with scope("mlp"):
+        if cfg.num_shared_experts:
+            out = out + _swiglu(
+                y, params, f"l{i}_s_gate", f"l{i}_s_up", f"l{i}_s_down"
+            ).astype(jnp.float32)
+        return out.astype(y.dtype), taken
 
 
 # ---- the trunk -------------------------------------------------------------
@@ -287,41 +297,54 @@ def latent_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
     dtype = jnp.dtype(cfg.dtype)
     heads, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     r = cfg.kv_lora_rank
-    cos, sin = yarn_rope_angles(
-        dr, rope_len, cfg.rope_theta, factor=cfg.rope_scaling_factor,
-        original_max_len=cfg.rope_original_max_len,
-        beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
-        mscale=cfg.rope_mscale, mscale_all_dim=cfg.rope_mscale_all_dim,
-    )
-    x = params["tok_emb"][ids].astype(dtype)
+    with scope("proj"):
+        cos, sin = yarn_rope_angles(
+            dr, rope_len, cfg.rope_theta, factor=cfg.rope_scaling_factor,
+            original_max_len=cfg.rope_original_max_len,
+            beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
+            mscale=cfg.rope_mscale, mscale_all_dim=cfg.rope_mscale_all_dim,
+        )
+    with scope("embed"):
+        x = params["tok_emb"][ids].astype(dtype)
     record = []
     for i in range(cfg.num_layers):
         p = f"l{i}_"
-        y = rms_norm(x, params[p + "attn_norm_g"], cfg.norm_eps)
-        c_q = rms_norm(
-            y @ params[p + "wq_a"].astype(dtype), params[p + "q_norm_g"],
-            cfg.norm_eps,
-        )
-        q = (c_q @ params[p + "wq_b"].astype(dtype)).reshape(
-            b, s, heads, dn + dr
-        )
-        q_nope = q[..., :dn]
-        q_rope = apply_rope(q[..., dn:], cos, sin, positions)
-        ckv = y @ params[p + "wkv_a"].astype(dtype)
-        c_kv = rms_norm(ckv[..., :r], params[p + "kv_norm_g"], cfg.norm_eps)
-        k_rope = apply_rope(ckv[..., None, r:], cos, sin, positions)[:, :, 0]
-        row = jnp.concatenate([c_kv, k_rope], axis=-1)
+        with scope("proj"):
+            y = rms_norm(x, params[p + "attn_norm_g"], cfg.norm_eps)
+            c_q = rms_norm(
+                y @ params[p + "wq_a"].astype(dtype), params[p + "q_norm_g"],
+                cfg.norm_eps,
+            )
+            q = (c_q @ params[p + "wq_b"].astype(dtype)).reshape(
+                b, s, heads, dn + dr
+            )
+            q_nope = q[..., :dn]
+            q_rope = apply_rope(q[..., dn:], cos, sin, positions)
+            ckv = y @ params[p + "wkv_a"].astype(dtype)
+            c_kv = rms_norm(
+                ckv[..., :r], params[p + "kv_norm_g"], cfg.norm_eps
+            )
+            k_rope = apply_rope(
+                ckv[..., None, r:], cos, sin, positions
+            )[:, :, 0]
+            row = jnp.concatenate([c_kv, k_rope], axis=-1)
 
         attn = attend(i, q_nope, q_rope, row)
-        x = x + attn.reshape(b, s, heads * cfg.v_head_dim) @ params[
-            p + "wo"
-        ].astype(dtype)
+        with scope("proj"):
+            x = x + attn.reshape(b, s, heads * cfg.v_head_dim) @ params[
+                p + "wo"
+            ].astype(dtype)
 
-        y = rms_norm(x, params[p + "mlp_norm_g"], cfg.norm_eps)
         if i < cfg.first_dense_layers:
-            x = x + _swiglu(y, params, p + "w_gate", p + "w_up", p + "w_down")
+            with scope("mlp"):
+                y = rms_norm(x, params[p + "mlp_norm_g"], cfg.norm_eps)
+                x = x + _swiglu(
+                    y, params, p + "w_gate", p + "w_up", p + "w_down"
+                )
             continue
-        add, taken = routed_mlp(y.reshape(b * s, -1), params, cfg, i)
-        x = x + add.reshape(b, s, -1)
+        with scope("mlp"):
+            y = rms_norm(x, params[p + "mlp_norm_g"], cfg.norm_eps)
+            add, taken = routed_mlp(y.reshape(b * s, -1), params, cfg, i)
+            x = x + add.reshape(b, s, -1)
         record.append(taken.reshape(b, s, -1))
     return x, (jnp.stack(record) if record else None)

@@ -14,7 +14,9 @@ While the window is open every ``runtime.metrics.span`` site also opens a
 / ``serve_idle_wait``, the spine's dispatch spans, ``qa_retrieve`` …) sit
 on the same clock as the device's programs: a gap in the device timeline
 reads as what the host was doing in it.  What ran on the device comes from
-the trace itself, never from a host clock around a blocking fetch.
+the trace itself, never from a host clock around a blocking fetch — and
+what ran INSIDE a program from the device scopes of ``ops/scopes.py``:
+``python3 benchmark/harness/xplane_scopes.py <logdir>`` reduces a window.
 """
 
 from __future__ import annotations

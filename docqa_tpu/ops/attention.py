@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from docqa_tpu.ops.scopes import scope
+
 NEG_INF = -1e30
 
 
@@ -1170,27 +1172,31 @@ def sparse_prefill_attention(q, k, v, seg_ids, positions, seg_lens, ck, ck_ok,
         qb = q[rows].reshape(-1, g, per, d)
         seg_q, pos_q = seg_ids[rows], positions[rows]
         real = seg_q >= 0
-        s_sel = jnp.einsum(
-            "qgpd,wgd->gpqw", qb.astype(f32), ckf, precision=_HIGHEST
-        ) * scale
-        ok_w = (ck_ok[None, :] & (ck_seg[None, :] == seg_q[:, None])
-                & (ck_end[None, :] <= pos_q[:, None]))
-        p = jax.nn.softmax(jnp.where(ok_w, s_sel, NEG_INF), axis=-1)
-        p = jnp.where(ok_w, p, 0.0).sum(axis=1)  # [g, bq, W]
-        score = _windows_of_blocks(p, m, r, nb).max(axis=-1)
-        exists = (real[:, None] & (blk_seg[None, :] == seg_q[:, None])
-                  & (blk_pos0[None, :] <= pos_q[:, None]))
-        forced = (blk_pos0[None, :] < init_blocks * block) | (
-            blk_pos0[None, :] + block - 1 >= pos_q[:, None] - window + 1)
-        ids, took = _taken_blocks(score, exists[None], forced[None], topk)
-        sparse_row = real & (seg_lens[rows] >= dense_len)
-        first_block = (rows - pos_q) // block  # of the row's segment
-        rec = jnp.where(
-            took & sparse_row[None, :, None],
-            ids - first_block[None, :, None], -1)
-        sel = jnp.any(
-            (ids[..., None] == jnp.arange(nb)) & took[..., None], axis=-2)
-        blk_mask = sel | ~sparse_row[None, :, None]  # [g, bq, nb]
+        with scope("select"):
+            s_sel = jnp.einsum(
+                "qgpd,wgd->gpqw", qb.astype(f32), ckf, precision=_HIGHEST
+            ) * scale
+            ok_w = (ck_ok[None, :] & (ck_seg[None, :] == seg_q[:, None])
+                    & (ck_end[None, :] <= pos_q[:, None]))
+            p = jax.nn.softmax(jnp.where(ok_w, s_sel, NEG_INF), axis=-1)
+            p = jnp.where(ok_w, p, 0.0).sum(axis=1)  # [g, bq, W]
+            score = _windows_of_blocks(p, m, r, nb).max(axis=-1)
+            exists = (real[:, None] & (blk_seg[None, :] == seg_q[:, None])
+                      & (blk_pos0[None, :] <= pos_q[:, None]))
+            forced = (blk_pos0[None, :] < init_blocks * block) | (
+                blk_pos0[None, :] + block - 1
+                >= pos_q[:, None] - window + 1)
+            ids, took = _taken_blocks(
+                score, exists[None], forced[None], topk)
+            sparse_row = real & (seg_lens[rows] >= dense_len)
+            first_block = (rows - pos_q) // block  # of the row's segment
+            rec = jnp.where(
+                took & sparse_row[None, :, None],
+                ids - first_block[None, :, None], -1)
+            sel = jnp.any(
+                (ids[..., None] == jnp.arange(nb)) & took[..., None],
+                axis=-2)
+            blk_mask = sel | ~sparse_row[None, :, None]  # [g, bq, nb]
         base = (real[:, None] & valid[None, :]
                 & (seg_q[:, None] == seg_ids[None, :])
                 & (positions[None, :] <= pos_q[:, None]))
@@ -1244,27 +1250,31 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
     t = lengths - 1  # the query's position
     lane = jnp.arange(s_)
 
-    w_tok = jnp.arange(n_win) * stride
-    page = block_tables[:, w_tok // block_size]  # [S, W]
-    ck_rows = jnp.minimum(
-        page * (block_size // stride) + (w_tok % block_size) // stride,
-        ck_pool.shape[0] - 1)
-    ck = ck_pool[ck_rows].astype(f32)  # [S, W, g, d]
-    ok_w = (w_tok[None, :] + kernel_size - 1 <= t[:, None])[:, None, None]
-    qg = q.reshape(s_, g, per, d)
-    s_sel = jnp.einsum(
-        "sgpd,swgd->sgpw", qg.astype(f32), ck, precision=_HIGHEST) * scale
-    p = jax.nn.softmax(jnp.where(ok_w, s_sel, NEG_INF), axis=-1)
-    p = jnp.where(ok_w, p, 0.0).sum(axis=2)  # [S, g, W]
-    score = _windows_of_blocks(p, m, r, nb).max(axis=-1)
-    blk_pos0 = jnp.arange(nb) * block
-    exists = blk_pos0[None, :] <= t[:, None]
-    forced = (blk_pos0[None, :] < init_blocks * block) | (
-        blk_pos0[None, :] + block - 1 >= t[:, None] - window + 1)
-    ids, took = _taken_blocks(score, exists[:, None], forced[:, None], topk)
-    sparse_lane = lengths >= dense_len
-    live = block_tables[:, 0] < pool_rows // block_size
-    rec = jnp.where(took & sparse_lane[:, None, None], ids, -1)
+    with scope("select"):
+        w_tok = jnp.arange(n_win) * stride
+        page = block_tables[:, w_tok // block_size]  # [S, W]
+        ck_rows = jnp.minimum(
+            page * (block_size // stride) + (w_tok % block_size) // stride,
+            ck_pool.shape[0] - 1)
+        ck = ck_pool[ck_rows].astype(f32)  # [S, W, g, d]
+        ok_w = (
+            w_tok[None, :] + kernel_size - 1 <= t[:, None])[:, None, None]
+        qg = q.reshape(s_, g, per, d)
+        s_sel = jnp.einsum(
+            "sgpd,swgd->sgpw", qg.astype(f32), ck, precision=_HIGHEST
+        ) * scale
+        p = jax.nn.softmax(jnp.where(ok_w, s_sel, NEG_INF), axis=-1)
+        p = jnp.where(ok_w, p, 0.0).sum(axis=2)  # [S, g, W]
+        score = _windows_of_blocks(p, m, r, nb).max(axis=-1)
+        blk_pos0 = jnp.arange(nb) * block
+        exists = blk_pos0[None, :] <= t[:, None]
+        forced = (blk_pos0[None, :] < init_blocks * block) | (
+            blk_pos0[None, :] + block - 1 >= t[:, None] - window + 1)
+        ids, took = _taken_blocks(
+            score, exists[:, None], forced[:, None], topk)
+        sparse_lane = lengths >= dense_len
+        live = block_tables[:, 0] < pool_rows // block_size
+        rec = jnp.where(took & sparse_lane[:, None, None], ids, -1)
 
     def finish(scores, mask, values, spec):
         scores = jnp.where(mask[:, :, None], scores * scale, NEG_INF)

@@ -1,0 +1,61 @@
+"""The device scopes: what a device trace calls the work INSIDE a program.
+
+A decode chunk is one ``while`` op and a prefill is hundreds of anonymous
+fusions; a trace says which fusion is attention and which the MLP only
+if the program said so when it was traced.  ``with scope("attend"):``
+opens ``jax.named_scope("dq.attend")`` around the ops built inside it:
+every such op's ``op_name`` in the compiled program (and, in a profiler
+trace of a TPU, the ``tf_op`` stat of the op's event metadata) then
+carries ``…/dq.attend/…``.  ``benchmark/harness/xplane_scopes.py`` sums a
+program's device time by these names.
+
+Metadata only: no op, shape, donation or program name changes
+(``Lowered.as_text()`` is byte-identical with and without), and nothing
+runs when no trace is open.  JAX's persistent compile cache leaves
+metadata out of its key, so an executable cached BEFORE a scope was
+opened is found again without it: empty that cache to see a new scope.
+
+A scope names a PHASE, never a layer (the trunks are unrolled in Python:
+the 32 copies of a phase share its scope).  The vocabulary is closed — a
+new trunk or kernel opens one of these, or adds its own to the tuple,
+to the table in ``docs/OBSERVABILITY.md`` and to PERF.md §3 in the same
+PR:
+
+    embed        token embedding (and its scale)
+    proj         the mixer's input and output projections with their norm,
+                 RoPE / YaRN, qk-norm, output gate and residual add
+    cache_write  what a token leaves in the pools: K / V rows, the latent
+                 row, the compressed key, ``state_slot``
+    attend       softmax attention over cached or in-flight rows
+    select       block scoring and top-k of the sparse mixer
+    state        the linear mixer's scan / step, its state's gather and
+                 scatter included
+    mlp          the dense SwiGLU with its norm and residual add; shared
+                 experts
+    route        router scores and the choice of experts
+    experts      the held experts' turns
+    head         final norm and ``lm_head``
+    sample       sampling, token and length bookkeeping, the chunk's
+                 result array with its MoE / sparse sums
+"""
+
+from __future__ import annotations
+
+import jax
+
+PREFIX = "dq."
+DEVICE_SCOPES = (
+    "embed", "proj", "cache_write", "attend", "select", "state", "mlp",
+    "route", "experts", "head", "sample",
+)
+
+
+def scope(name: str):
+    """``jax.named_scope("dq." + name)``; a name outside
+    ``DEVICE_SCOPES`` is refused."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(
+            f"{name!r} is no device scope: one of {DEVICE_SCOPES} "
+            "(docqa_tpu/ops/scopes.py)"
+        )
+    return jax.named_scope(PREFIX + name)
