@@ -193,16 +193,23 @@ def _write_cache(cache_layer: jax.Array, new: jax.Array, offsets: jax.Array):
 
 
 def _qmatmul(x: jax.Array, params: Params, name: str, dtype) -> jax.Array:
-    """``x [..., in] @ W`` with dequantization fused into the dot.
+    """``x [..., in] @ W`` with no dequantized copy of ``W``.
 
-    int8 (2-D store, scale [out]): broadcast-scale the operand — XLA fuses
-    the convert+multiply into the dot read (proven on hardware: the 7B
-    int8 engine runs in 16 GB and beats bf16 tok/s, impossible with a
-    materialized tree).  int4 (3-D grouped store [groups, g, out], scale
-    [groups, out]): the SAME producer shape — pure broadcast multiply, no
-    reshape between the multiply and the dot — contracted over both group
-    axes via ``dot_general``; the activation-side regroup is a free
-    reshape of the small operand."""
+    int8 (2-D store, scale [out]): the per-output-channel scale commutes
+    with the contraction, ``x @ (W * s[None, :]) == (x @ W) * s[None, :]``,
+    so the dot's weight operand is a bare ``convert`` of the stored int8
+    array (exact in bf16) and the small ``[rows, out]`` result is scaled
+    by the stored float32 scale, in float32, and rounded to ``dtype``.
+    Scaling the WEIGHT asks for an ``[in, out]`` product that the compiler
+    may write out as a bf16 array of the weight's shape (it did at the
+    narrow projection sites; why the dot hands back ``dtype`` and not
+    float32: PERF.md section 6).  One form for every row count.  int4
+    (3-D grouped store [groups, g, out], scale [groups, out]: a scale per
+    128 input rows does NOT commute with the contraction):
+    broadcast-scale the operand — pure broadcast multiply, no reshape
+    between the multiply and the dot — contracted over both group axes
+    via ``dot_general``; the activation-side regroup is a free reshape of
+    the small operand."""
     from docqa_tpu.models.quant import SCALE_SUFFIX
 
     w = params[name]
@@ -210,7 +217,8 @@ def _qmatmul(x: jax.Array, params: Params, name: str, dtype) -> jax.Array:
     if scale is None:
         return x @ w.astype(dtype)
     if w.ndim == 2:  # int8
-        return x @ (w.astype(dtype) * scale.astype(dtype)[None, :])
+        y = x @ w.astype(dtype)
+        return (y.astype(jnp.float32) * scale).astype(dtype)
     groups, g, _out = w.shape  # int4 grouped
     wf = w.astype(dtype) * scale.astype(dtype)[:, None, :]
     x3 = x.reshape(*x.shape[:-1], groups, g)

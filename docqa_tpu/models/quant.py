@@ -19,11 +19,17 @@ Schemes (for each 2-D weight ``w [in, out]``):
   convention): ``scale[in//g, out] = absmax over the group / 7``.  The
   scale overhead is one f32 per 128 int4s (~6%).
 
-The forward pass dequantizes in-kernel — ``q.astype(bf16) * scale`` feeds
-the matmul directly, and XLA fuses the convert+multiply into the dot's
-operand read, so the dequantized tree never materializes in HBM.  The
-int4 weight is STORED grouped-3-D ``[groups, g, out]`` so its dequant is
-the same reshape-free broadcast-multiply producer shape as int8's (see
+The forward pass never holds a dequantized tree (``decoder._qmatmul``).
+int8: the scale is per OUTPUT column, so it commutes with the contraction
+— the dot reads a bare ``convert`` of the stored int8 array and its
+small ``[rows, out]`` result is scaled in float32 by the stored scale and
+rounded to the activation type.  Scaling the WEIGHT instead asks for an
+``[in, out]`` product, which the compiler fuses into the dot's read at
+some sites and writes to HBM as a bf16 copy of the weight at others
+(PERF.md section 6).  int4: a scale per 128 input rows does not commute,
+so ``q.astype(bf16) * scale`` feeds the dot as a broadcast-multiply
+producer.  The int4 weight is STORED grouped-3-D ``[groups, g, out]`` so
+its dequant is a reshape-free broadcast multiply (see
 ``decoder._qmatmul``; a 2-D store would interpose reshapes the compiler
 may refuse to fuse through).  XLA TPU stores int4 packed two-per-byte.
 Activations stay bf16: no calibration data needed.
@@ -151,13 +157,13 @@ def quantize_array_int4(
 
     The quantized weight is STORED 3-D, grouped layout — dequant is then
     a pure broadcast multiply (``q.astype(bf16) * scale[:, None, :]``)
-    feeding a two-axis ``dot_general``, the same producer shape XLA
-    provably fuses into the dot's operand read for the int8 path.  A 2-D
-    store would need reshape(dequant(reshape)) around the multiply, a
-    pattern the compiler may materialize as a full bf16 tree (14.5 GB at
-    7B — un-servable).  Fused under jit like ``quantize_array``: the
-    eager op sequence would materialize several f32 temporaries per
-    tensor on the transient-fit checkpoint-quantization path."""
+    feeding a two-axis ``dot_general``, a producer shape XLA can fuse into
+    the dot's operand read.  A 2-D store would need
+    reshape(dequant(reshape)) around the multiply, a pattern the compiler
+    may materialize as a full bf16 tree (14.5 GB at 7B — un-servable).
+    Fused under jit like ``quantize_array``: the eager op sequence would
+    materialize several f32 temporaries per tensor on the transient-fit
+    checkpoint-quantization path."""
     g = _int4_group(w.shape[0], group)
     return _quantize_int4_jit(w, g)
 

@@ -158,6 +158,7 @@ def test_an_expected_ask_that_never_submits_ends_the_wait(batcher):
     first()
     time.sleep(TRICKLE_S)
     assert not h.started  # the round is waiting for the second ask
+    t0 = time.perf_counter()  # the spans' clock
     never()
     never()  # a second report changes nothing
     h.result(timeout=240)
@@ -169,7 +170,10 @@ def test_an_expected_ask_that_never_submits_ends_the_wait(batcher):
     assert [s.attrs["ended_by"] for s in gather] == ["quiet"]
     # 2 if the worker looked before the first ask reported, else 1
     assert gather[0].attrs["expected"] in (1, 2)
-    assert TRICKLE_S <= gather[0].t_end - gather[0].t_start < BOUND_S
+    # the wait ended because the count dropped: after `never()`, which
+    # found the round not started, and not on the bound
+    assert gather[0].t_end >= t0
+    assert gather[0].t_end - gather[0].t_start < BOUND_S
 
 
 def test_the_bound_ends_a_wait_whose_ask_never_leaves(batcher):

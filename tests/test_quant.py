@@ -5,6 +5,8 @@ The capability this buys: a Mistral-7B-class decoder on ONE 16 GB v5e chip
 both the tree and the bytes read per decode step).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 from docqa_tpu.config import DecoderConfig, GenerateConfig
 from docqa_tpu.engines.generate import GenerateEngine
 from docqa_tpu.models.decoder import (
+    _qmatmul,
     decoder_forward,
     init_decoder_params,
     init_kv_cache,
@@ -114,6 +117,59 @@ class TestQuantizedForward:
         assert eng.params["l0_wq"].dtype == jnp.int8
         assert eng.params["l0_wq__scale"].dtype == jnp.float32
         assert eng.generate_ids([[3, 5]])[0] is not None
+
+
+class TestInt8Branch:
+    """``decoder._qmatmul``'s int8 branch (ISSUE 41): the per-column scale
+    is applied to the dot's OUTPUT, in float32, so the dot's weight operand
+    is a bare ``convert`` of the stored int8 array — nothing of the
+    weight's shape is multiplied, and nothing is rounded per weight
+    element."""
+
+    @pytest.mark.parametrize("shape", [(4096, 1024), (4096, 4096),
+                                       (4096, 14336)])
+    @pytest.mark.parametrize("rows", [1, 4, 512])
+    def test_scale_on_the_output(self, rows, shape):
+        dtype = jnp.bfloat16
+        rng = np.random.default_rng(rows * 31 + shape[1])
+        fan_in, fan_out = shape
+        w = rng.standard_normal(shape, np.float32) * fan_in ** -0.5
+        q, scale = quantize_array(jnp.asarray(w))
+        x = jnp.asarray(rng.standard_normal((1, rows, fan_in), np.float32),
+                        dtype)
+        params = {"w": q, "w__scale": scale}
+        fn = jax.jit(lambda x, p: _qmatmul(x, p, "w", dtype))
+
+        # numerics: against the float32 product, at least as close as the
+        # operand form (scale on the weight, rounded to bf16 per element)
+        want = np.asarray(x, np.float32) @ (
+            np.asarray(q, np.float32) * np.asarray(scale)[None, :])
+        out = fn(x, params)
+        assert out.dtype == dtype and out.shape == (1, rows, fan_out)
+        got = np.asarray(out, np.float32)
+        operand_form = np.asarray(jax.jit(
+            lambda x, q, s: x @ (q.astype(dtype) * s.astype(dtype)[None, :])
+        )(x, q, scale), np.float32)
+        err = np.linalg.norm(got - want) / np.linalg.norm(want)
+        err_operand = np.linalg.norm(operand_form - want) / np.linalg.norm(want)
+        assert err <= err_operand + np.finfo(np.float32).eps, (
+            err, err_operand)
+        assert err < 2.0 ** -8  # the output's two bf16 roundings
+
+        # structure, on the lowered (platform-independent) text
+        text = fn.lower(x, params).as_text()
+        wt = f"tensor<{fan_in}x{fan_out}x"
+        ops = [l for l in text.splitlines() if " = stablehlo." in l]
+        made = [  # every op whose RESULT (its last type) has that shape
+            l for l in ops if re.findall(r"tensor<[^>]*>", l)[-1].startswith(wt)
+        ]
+        assert made and all("stablehlo.convert" in m for m in made), made
+        dots = [l for l in ops if "stablehlo.dot_general" in l]
+        assert len(dots) == 1 and f"{wt}bf16>" in dots[0], dots
+        # the ONE multiply is the scale, in float32, on the dot's result
+        scaled = [l for l in ops if "stablehlo.multiply" in l]
+        assert len(scaled) == 1 and scaled[0].rstrip().endswith(
+            f"tensor<1x{rows}x{fan_out}xf32>"), scaled
 
 
 class TestDirectInt8Init:
