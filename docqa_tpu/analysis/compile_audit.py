@@ -63,7 +63,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from docqa_tpu.utils import compiled_memory_stats as memory_of
 
 WORKLOADS = (
-    "serve", "serve_latent", "serve_hybrid", "generate", "retrieve_fused", "seq2seq",
+    "serve", "serve_latent", "serve_hybrid", "serve_ssm", "generate", "retrieve_fused", "seq2seq",
     "encoder",
 )
 
@@ -158,9 +158,25 @@ def _audit_latent_cfg():
     )
 
 
+def _audit_ssm_cfg():
+    """The same stack (models/hybrid.py) at audit widths with its other
+    two mixer kinds: a state-space and a plain attention layer, tied
+    head."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=64, hidden_dim=32, num_layers=2, num_heads=4,
+        num_kv_heads=1, head_dim=8, mlp_dim=64, max_seq_len=128,
+        block="sparse_linear", mixer_types=("mamba", "attention"),
+        qk_norm=False, use_output_gate=False, use_output_norm=False,
+        tie_embeddings=True, ssm_state_dim=4, ssm_conv_width=4,
+        ssm_dt_rank=4, ssm_expand=2,
+    )
+
+
 def _audit_hybrid_cfg():
-    """The two-mixer block (models/hybrid.py) at audit widths: a sparse
-    and a linear layer."""
+    """The stack of mixer kinds (models/hybrid.py) at audit widths: a
+    sparse and a linear layer."""
     from docqa_tpu.config import DecoderConfig
 
     return DecoderConfig(
@@ -205,7 +221,8 @@ def _audit_encoder_cfg():
 # ---------------------------------------------------------------------------
 
 
-def _audit_serve(latent: bool = False, hybrid: bool = False) -> Dict[str, Any]:
+def _audit_serve(latent: bool = False, hybrid: bool = False,
+                 ssm: bool = False) -> Dict[str, Any]:
     """The PAGED batcher's whole compile surface: one ragged prefill
     program per packed token budget (<= 2) plus the one block-table
     decode chunk — the collapse from the pre-paged (2 shape families x
@@ -219,9 +236,13 @@ def _audit_serve(latent: bool = False, hybrid: bool = False) -> Dict[str, Any]:
     which carries the expert-choice sums.  That block prefills cold only
     (prefix cache off, no speculation): there is no warm family.
 
-    ``hybrid``: the same over the two-mixer block (workload
-    ``serve_hybrid``): cold prefill budgets, and a decode chunk that
-    advances lane states and carries the selection sums."""
+    ``hybrid``: the same over the stack of mixer kinds (workload
+    ``serve_hybrid``, a sparse and a linear layer): cold prefill budgets,
+    and a decode chunk that advances lane states and carries the selection
+    sums.  ``ssm``: the same stack's other two kinds (workload
+    ``serve_ssm``, a state-space and a plain attention layer): a prefill
+    that convolves and scans, a decode chunk that advances windows and
+    states and carries no sums."""
     import dataclasses
 
     import jax
@@ -235,8 +256,8 @@ def _audit_serve(latent: bool = False, hybrid: bool = False) -> Dict[str, Any]:
     if latent:
         cfg = _audit_latent_cfg()
         gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
-    if hybrid:
-        cfg = _audit_hybrid_cfg()
+    if hybrid or ssm:
+        cfg = _audit_ssm_cfg() if ssm else _audit_hybrid_cfg()
         gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
     engine = GenerateEngine(cfg, gen)
     # cache_len 256: large enough that the 128-aligned prefix cache is
@@ -402,8 +423,9 @@ def _audit_serve(latent: bool = False, hybrid: bool = False) -> Dict[str, Any]:
         }
         if not batcher.prefix_cache_enabled:
             del report["roots"]["serve_prefill_warm"]
-        if latent or hybrid:
-            prefix = "serve_latent_" if latent else "serve_hybrid_"
+        if latent or hybrid or ssm:
+            prefix = ("serve_latent_" if latent else
+                      "serve_ssm_" if ssm else "serve_hybrid_")
             report["roots"] = {
                 name.replace("serve_", prefix): root
                 for name, root in report["roots"].items()
@@ -573,6 +595,7 @@ _AUDITS = {
     "serve": _audit_serve,
     "serve_latent": functools.partial(_audit_serve, latent=True),
     "serve_hybrid": functools.partial(_audit_serve, hybrid=True),
+    "serve_ssm": functools.partial(_audit_serve, ssm=True),
     "generate": _audit_generate,
     "retrieve_fused": _audit_retrieve,
     "seq2seq": _audit_seq2seq,

@@ -69,6 +69,8 @@ AUDIT_PROGRAMS = (
     "latent_ragged_prefill",
     "hybrid_paged_decode",
     "hybrid_ragged_prefill",
+    "ssm_paged_decode",
+    "ssm_ragged_prefill",
     "ring_attention",
     "ulysses_attention",
     "retrieve_fused",
@@ -187,8 +189,24 @@ def _audit_latent_cfg():
     )
 
 
+def _audit_ssm_cfg():
+    """The same stack's other two mixer kinds: a state-space layer (its
+    inner channels and the MLP width divisible by 8) and a plain attention
+    layer whose ONE kv head is replicated; tied head."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=128, hidden_dim=64, num_layers=2, num_heads=8,
+        num_kv_heads=1, head_dim=8, mlp_dim=128, max_seq_len=32,
+        block="sparse_linear", mixer_types=("mamba", "attention"),
+        qk_norm=False, use_output_gate=False, use_output_norm=False,
+        tie_embeddings=True, ssm_state_dim=4, ssm_conv_width=4,
+        ssm_dt_rank=8, ssm_expand=2,
+    )
+
+
 def _audit_hybrid_cfg():
-    """The two-mixer block (models/hybrid.py): a sparse and a linear
+    """The stack of mixer kinds (models/hybrid.py): a sparse and a linear
     layer; query heads, the linear heads and the MLP width divisible by 8,
     the sparse layer's 2 kv heads replicated."""
     from docqa_tpu.config import DecoderConfig
@@ -308,7 +326,7 @@ def _audit_decoder(mesh_name: str, prefill: bool, pspec_fn=None):
 
 
 def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
-                 hybrid: bool = False):
+                 hybrid: bool = False, ssm: bool = False):
     """Lower the PAGED serving programs (engines/paged.py) under the
     same Megatron layout: the block-pool gather/scatter must not change
     the collective story — still exactly one all-reduce per Megatron
@@ -325,9 +343,12 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     sharded expert axis is not the exchange a deployment would run, and no
     cell runs this block across chips yet.
 
-    ``hybrid``: the same two programs of the two-mixer block
+    ``hybrid``: the same two programs of the stack of mixer kinds
     (models/hybrid.py; its pools — rows, compressed keys, lane states, the
-    slot map — replicated): lowered on every mesh, collectives recorded."""
+    slot map — replicated): lowered on every mesh, collectives recorded.
+    ``ssm``: the same stack with a state-space and a plain attention layer
+    (the state-space mixer divided along its inner channels, windows and
+    states replicated with the rows of the one kv head)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -343,8 +364,9 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     )
 
     cfg = _audit_latent_cfg() if latent else _audit_decoder_cfg()
+    hybrid = hybrid or ssm
     if hybrid:
-        cfg = _audit_hybrid_cfg()
+        cfg = _audit_ssm_cfg() if ssm else _audit_hybrid_cfg()
     mesh = _mesh(mesh_name)
     slots, block_size, n_blocks = 4, 8, 16
     rope_len = 32
@@ -742,6 +764,12 @@ _AUDITS: Dict[str, Callable[[str], Tuple[Dict[str, int], Dict[str, Any]]]] = {
     ),
     "hybrid_ragged_prefill": functools.partial(
         _audit_paged, prefill=True, hybrid=True
+    ),
+    "ssm_paged_decode": functools.partial(
+        _audit_paged, prefill=False, ssm=True
+    ),
+    "ssm_ragged_prefill": functools.partial(
+        _audit_paged, prefill=True, ssm=True
     ),
     "ring_attention": _audit_ring,
     "ulysses_attention": _audit_ulysses,

@@ -193,7 +193,7 @@ class DecoderConfig:
     experts_held_start: int = 0
     experts_held: int = 0
     # ---- "sparse_linear" (models/hybrid.py) ------------------------------
-    # A stack of TWO mixer kinds, one name per layer in ``mixer_types``
+    # A stack of mixer kinds, one name per layer in ``mixer_types``
     # (``len == num_layers``): "linear" — decayed linear attention whose
     # whole past is one [heads, d, d] float32 state a LANE (no row a
     # token), RoPE, per-head output norm — and "sparse" — GQA softmax
@@ -221,6 +221,30 @@ class DecoderConfig:
     sparse_init_blocks: int = 1
     sparse_window_size: int = 2048
     sparse_dense_len: int = 8192
+    # Two more mixer kinds of the same stack.  "attention": plain causal
+    # GQA / MQA softmax attention over every row (no RoPE, no selection),
+    # read at decode by the paged kernel.  "mamba": a Mamba-1 state-space
+    # mixer (arXiv:2312.00752) with its own projections — in
+    # (``hidden -> 2 x ssm_expand x hidden``), a depthwise causal conv of
+    # ``ssm_conv_width`` taps, ``x`` (``-> ssm_dt_rank + 2 x
+    # ssm_state_dim``, each part RMS-normed), ``dt`` and out — whose past
+    # is, a LANE, the last ``ssm_conv_width - 1`` conv inputs and one
+    # [ssm_state_dim, inner] float32 state (no row a token).  What the
+    # attention kinds do around the softmax is what the file says:
+    # per-head q / k norms (``qk_norm``), a sigmoid output gate
+    # (``use_output_gate``), the linear mixer's per-head output norm
+    # (``use_output_norm``).  ``tie_embeddings``: the tree holds no
+    # ``lm_head``, logits are taken against ``tok_emb``.
+    qk_norm: bool = True
+    use_output_gate: bool = True
+    use_output_norm: bool = True
+    tie_embeddings: bool = False
+    ssm_state_dim: int = 16
+    ssm_conv_width: int = 4
+    ssm_dt_rank: int = 0
+    ssm_expand: int = 2
+    ssm_conv_bias: bool = True
+    ssm_proj_bias: bool = False
 
     @staticmethod
     def mistral_7b() -> "DecoderConfig":

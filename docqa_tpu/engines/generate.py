@@ -34,6 +34,7 @@ from docqa_tpu.models.decoder import (
     init_kv_cache,
 )
 from docqa_tpu.models.hybrid import (
+    ATTENTION,
     HYBRID_BLOCK,
     check_hybrid_config,
     is_hybrid,
@@ -218,9 +219,10 @@ class GenerateEngine:
             use_flash = False
         if is_hybrid(cfg):
             # the batcher over the paged rows and the lane state only; the
-            # scan and the selection are XLA (no Pallas kernel yet)
+            # scans and the selection are XLA (no Pallas kernel yet), and
+            # the paged decode kernel reads the plain attention layers
             check_hybrid_config(cfg)
-            use_flash = False
+            use_flash = bool(use_flash) and ATTENTION in cfg.mixer_types
         self.use_flash = use_flash
         self._fns = {}
 

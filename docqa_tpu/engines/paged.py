@@ -50,13 +50,18 @@ from docqa_tpu.models.decoder import (
     lane_state_shapes,
 )
 from docqa_tpu.models.hybrid import (
+    ATTENTION,
     LINEAR,
+    MAMBA,
+    SPARSE,
     decay_slopes,
     hybrid_head,
     hybrid_layer_stack,
     is_hybrid,
-    linear_layers,
+    lane_state_entries,
+    layers_of,
     sparse_layers,
+    ssm_constants,
 )
 from docqa_tpu.models.latent import (
     absorb_query,
@@ -78,10 +83,18 @@ from docqa_tpu.ops.attention import (
     sparse_prefill_attention,
 )
 from docqa_tpu.ops.scopes import scope
+from docqa_tpu.ops.ssm import (
+    causal_conv_prefill,
+    causal_conv_step,
+    conv_window_of,
+    selective_scan_prefill,
+    selective_scan_step,
+)
 
 # "k0".."k{L-1}", "v0".."v{L-1}"; the latent block: "c0".."c{L-1}"; the
-# two-mixer block: "k{i}" / "v{i}" / "ck{i}" of its sparse layers, "s{i}" of
-# its linear layers and STATE_SLOT (``_init_hybrid_pools``)
+# stack of mixer kinds: "k{i}" / "v{i}" of its row-keeping layers ("ck{i}"
+# where one selects), the lane-state entries of its state-keeping layers
+# and STATE_SLOT (``_init_hybrid_pools``)
 PagedPools = Dict[str, "jnp.ndarray"]
 STATE_SLOT = "state_slot"
 
@@ -653,17 +666,18 @@ def init_paged_pools(
 def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     """HBM bytes one token of KV occupies across every layer — the
     block-granular accounting unit telemetry reports
-    (ROADMAP item 1: per-token bytes instead of per-bucket).  The
-    two-mixer block: its sparse layers' K and V rows plus their share of
-    a compressed key (one per ``sparse_kernel_stride`` tokens); its linear
-    layers keep nothing a token (``models/hybrid.lane_state_bytes``)."""
+    (ROADMAP item 1: per-token bytes instead of per-bucket).  The stack of
+    mixer kinds: the K and V rows of its row-keeping layers (sparse,
+    attention), plus a SPARSE layer's share of a compressed key (one per
+    ``sparse_kernel_stride`` tokens); its state-keeping layers (linear,
+    state-space) keep nothing a token (``models/hybrid.lane_state_bytes``)."""
     item = jnp.dtype(cfg.dtype).itemsize
-    if is_hybrid(cfg):
-        per_layer = sum(h * w for h, w in kv_row_shapes(cfg).values())
-        key = cfg.num_kv_heads * cfg.head_dim
-        return len(sparse_layers(cfg)) * item * (
-            per_layer + key // cfg.sparse_kernel_stride)
     per_layer = sum(h * w for h, w in kv_row_shapes(cfg).values())
+    if is_hybrid(cfg):
+        key = cfg.num_kv_heads * cfg.head_dim
+        return item * (
+            len(layers_of(cfg, SPARSE, ATTENTION)) * per_layer
+            + len(sparse_layers(cfg)) * (key // cfg.sparse_kernel_stride))
     return cfg.num_layers * per_layer * item
 
 
@@ -720,7 +734,7 @@ def ragged_prefill_forward(
     if is_hybrid(cfg):
         if n_prefix_rows:
             raise NotImplementedError(
-                "the two-mixer block prefills cold only: set "
+                "the stack of mixer kinds prefills cold only: set "
                 "generate.prefix_cache false (a shared prefix is a run of "
                 "pages, and a lane's state at the share boundary is in none)"
             )
@@ -803,7 +817,7 @@ def paged_decode_forward(
     if is_hybrid(cfg):
         return _hybrid_decode_forward(
             params, cfg, pools, block_tables, tok, lengths, block_size,
-            rope_len,
+            rope_len, use_flash, mesh,
         )
     S, s = tok.shape
     nb = block_tables.shape[1]
@@ -941,20 +955,25 @@ def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
     return _with_record(decoder_head(params, cfg, x), pools, record)
 
 
-# ---- the two-mixer block (models/hybrid.py): rows AND a state a lane -------
+# ---- the stack of mixer kinds (models/hybrid.py): rows AND a state a lane ---
 
 
 def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
-    """The pools of the two-mixer block:
+    """The pools of the stack of mixer kinds, by what each layer's kind
+    keeps (``models/hybrid.MIXERS``):
 
     * ``k{i}`` / ``v{i}`` [n_blocks * block_size, kv heads, d] of each
-      SPARSE layer, and ``ck{i}`` [rows / sparse_kernel_stride, kv heads,
-      d]: the mean-pooled key of the window that STARTS at that stride of
-      that page (written when the window completes, by the prefill and by
-      the decode step that completes it);
-    * ``s{i}`` [n_lanes, heads, d, d] float32 of each LINEAR layer: a
-      lane's state.  ``n_lanes`` defaults to the lanes of ``cfg.max_seq_len``
-      positions the pool holds;
+      row-keeping layer (sparse, attention), and of a SPARSE layer
+      ``ck{i}`` [rows / sparse_kernel_stride, kv heads, d]: the
+      mean-pooled key of the window that STARTS at that stride of that
+      page (written when the window completes, by the prefill and by the
+      decode step that completes it);
+    * a lane's state, one entry a lane (``lane_state_shapes`` /
+      ``lane_state_dtypes``): ``s{i}`` [n_lanes, heads, d, d] float32 of
+      each LINEAR layer; ``h{i}`` [n_lanes, state, inner] float32 and
+      ``u{i}`` [n_lanes, taps - 1, inner] (the activation type) of each
+      state-space layer.  ``n_lanes`` defaults to the lanes of
+      ``cfg.max_seq_len`` positions the pool holds;
     * ``state_slot`` [n_blocks * block_size] int32: the state entry of
       the lane whose FIRST token lives at that pool row (only rows that
       start a block are ever looked up).  Both forwards find a lane's
@@ -967,7 +986,8 @@ def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
       (``engines/serve.py``).
     """
     st = cfg.sparse_kernel_stride
-    if block_size % st:
+    selecting = sparse_layers(cfg)
+    if selecting and block_size % st:
         raise ValueError(
             f"kv_block_size {block_size} is no multiple of "
             f"sparse_kernel_stride {st}")
@@ -975,16 +995,17 @@ def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
     per_lane = -(-cfg.max_seq_len // block_size)
     n_lanes = n_lanes or max(1, n_blocks // per_lane)
     pools: PagedPools = {}
-    for i in sparse_layers(cfg):
+    for i in range(cfg.num_layers):
         for prefix, (heads, width) in kv_row_shapes(cfg, i).items():
             pools[f"{prefix}{i}"] = jnp.zeros(
                 (rows, heads, width), dtype, device=sharding)
+    for i in selecting:
         pools[f"ck{i}"] = jnp.zeros(
             (rows // st, cfg.num_kv_heads, cfg.head_dim), dtype,
             device=sharding)
-    for name, shape in lane_state_shapes(cfg).items():
+    for name, (shape, kind) in lane_state_entries(cfg).items():
         pools[name] = jnp.zeros(
-            (n_lanes, *shape), jnp.float32, device=sharding)
+            (n_lanes, *shape), jnp.dtype(kind), device=sharding)
     slot = jnp.minimum(
         jnp.arange(rows, dtype=jnp.int32) // (per_lane * block_size),
         n_lanes - 1)
@@ -1007,58 +1028,72 @@ def _state_slots(pools, cfg, first_rows, ok=True):
     rows ``first_rows``; out of bounds (a zero read, a dropped write)
     where ``ok`` is false or the row is past the pool (a hole)."""
     slot_of = pools[STATE_SLOT]
-    n_slots = pools[f"s{linear_layers(cfg)[0]}"].shape[0]
+    n_slots = pools[next(iter(lane_state_shapes(cfg)))].shape[0]
     slot = slot_of[jnp.minimum(first_rows, slot_of.shape[0] - 1)]
     return jnp.where(ok & (first_rows < slot_of.shape[0]), slot, n_slots)
 
 
+def _write_rows(pools, i, dest, k, v):
+    """K and V of one row-keeping layer into their pools at ``dest``."""
+    for name, new in (("k", k), ("v", v)):
+        pool = pools[f"{name}{i}"]
+        pools[f"{name}{i}"] = pool.at[dest].set(
+            new.astype(pool.dtype), mode="drop")
+
+
 def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
                             dest_rows, last_rows, rope_len):
-    """One packed COLD prefill dispatch of the two-mixer block.  A sparse
-    layer scatters K and V rows and the compressed keys of the windows
-    that lie whole inside a segment (none straddles two), and every row
-    selects for itself; a linear layer runs the chunked scan from a ZERO state at
-    each segment's first row and leaves the segment's final state in the
-    lane's entry (found through ``state_slot`` from the segment's first
-    destination row).
+    """One packed COLD prefill dispatch of the stack of mixer kinds, one
+    handler a kind.  A row-keeping layer scatters K and V rows — a SPARSE
+    one also the compressed keys of the windows that lie whole inside a
+    segment (none straddles two), and every row selects for itself; an
+    ATTENTION one attends over the rows in flight.  A state-keeping layer
+    starts from ZERO at each segment's first row and leaves what the
+    segment ends with in the lane's entry (found through ``state_slot``
+    from the segment's first destination row): a LINEAR layer its chunked
+    scan's state, a state-space layer its scan's state and its last conv
+    inputs.
 
     Returns (last_logits [B, vocab] f32, pools, selection record int32
-    [sparse layers x kv heads, T, sparse_topk] of the packed rows)."""
+    [sparse layers x kv heads, T, sparse_topk] of the packed rows) — two
+    values where no layer selects."""
     sizes = _sparse_sizes(cfg)
     st = sizes["stride"]
     t = ids.shape[0]
+    kinds = set(cfg.mixer_types)
     # a segment: its rows are one contiguous run from position 0
     seg_ok = seg_ids[last_rows] == jnp.arange(last_rows.shape[0])
-    seg_len = jnp.where(seg_ok, positions[last_rows] + 1, 0)
-    seg_lens = jnp.where(
-        seg_ids >= 0, seg_len[jnp.maximum(seg_ids, 0)], 0)
-    chunk_slot = None
-    if LINEAR in cfg.mixer_types:
+    seg_lens = None
+    if SPARSE in kinds:
+        seg_len = jnp.where(seg_ok, positions[last_rows] + 1, 0)
+        seg_lens = jnp.where(
+            seg_ids >= 0, seg_len[jnp.maximum(seg_ids, 0)], 0)
+    slots = chunk_slot = None
+    if kinds & {LINEAR, MAMBA}:
         with scope("state"):
             first_rows = last_rows - positions[last_rows]
             slots = _state_slots(pools, cfg, dest_rows[first_rows], seg_ok)
-            # the chunk that holds a segment's last row takes its state
-            chunk_seg = seg_ids[:: RAGGED_ALIGN]
-            at = jnp.maximum(chunk_seg, 0)
-            is_last = (chunk_seg >= 0) & (
-                last_rows[at] // RAGGED_ALIGN
-                == jnp.arange(t // RAGGED_ALIGN))
-            chunk_slot = jnp.where(
-                is_last, slots[at], jnp.iinfo(jnp.int32).max)
+            if LINEAR in kinds:
+                # the chunk that holds a segment's last row takes its state
+                chunk_seg = seg_ids[:: RAGGED_ALIGN]
+                at = jnp.maximum(chunk_seg, 0)
+                is_last = (chunk_seg >= 0) & (
+                    last_rows[at] // RAGGED_ALIGN
+                    == jnp.arange(t // RAGGED_ALIGN))
+                chunk_slot = jnp.where(
+                    is_last, slots[at], jnp.iinfo(jnp.int32).max)
 
-    def mix(i, kind, q, k, v):
-        if kind == LINEAR:
-            with scope("state"):
-                out, pools[f"s{i}"] = linear_attention_prefill(
-                    q[0], k[0], v[0], seg_ids, positions,
-                    decay_slopes(cfg, i), pools[f"s{i}"], chunk_slot,
-                )
-                return out[None], None
+    def linear(i, q, k, v):
+        with scope("state"):
+            out, pools[f"s{i}"] = linear_attention_prefill(
+                q[0], k[0], v[0], seg_ids, positions,
+                decay_slopes(cfg, i), pools[f"s{i}"], chunk_slot,
+            )
+            return out[None], None
+
+    def sparse(i, q, k, v):
         with scope("cache_write"):
-            for name, new in (("k", k[0]), ("v", v[0])):
-                pool = pools[f"{name}{i}"]
-                pools[f"{name}{i}"] = pool.at[dest_rows].set(
-                    new.astype(pool.dtype), mode="drop")
+            _write_rows(pools, i, dest_rows, k[0], v[0])
             ck, ck_ok, ck_seg, ck_end = compressed_keys(
                 k[0], seg_ids, positions, sizes["kernel_size"], st)
             cpool = pools[f"ck{i}"]
@@ -1072,8 +1107,35 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
             )
             return out[None], taken[:, None]
 
+    def attention(i, q, k, v):
+        with scope("cache_write"):
+            _write_rows(pools, i, dest_rows, k[0], v[0])
+        with scope("attend"):
+            return ragged_prefill_attention(
+                q[0], k[0], v[0], seg_ids, positions)[None], None
+
+    def mamba(i, u, project):
+        conv_w, conv_b, a, d_skip = ssm_constants(params, cfg, i)
+        with scope("state"):
+            c = causal_conv_prefill(u[0], conv_w, conv_b, positions)
+            window = pools[f"u{i}"]
+            pools[f"u{i}"] = window.at[slots].set(
+                conv_window_of(
+                    u[0], positions, last_rows, window.shape[1]
+                ).astype(window.dtype), mode="drop")
+        delta, b_in, c_out = project(c[None])
+        with scope("state"):
+            g, h = selective_scan_prefill(
+                c, delta[0], a, b_in[0], c_out[0], d_skip, seg_ids,
+                positions, last_rows)
+            pools[f"h{i}"] = pools[f"h{i}"].at[slots].set(h, mode="drop")
+            return g[None], None
+
+    handlers = {LINEAR: linear, SPARSE: sparse, ATTENTION: attention,
+                MAMBA: mamba}
     x, record = hybrid_layer_stack(
-        params, cfg, ids[None, :], positions[None, :], rope_len, mix
+        params, cfg, ids[None, :], positions[None, :], rope_len,
+        lambda i, kind, *args: handlers[kind](i, *args),
     )
     with scope("head"):
         x_last = x[0][last_rows][:, None, :]
@@ -1084,28 +1146,34 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
 
 
 def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
-                           block_size, rope_len):
-    """A decode step of the two-mixer block, one token a lane.  A sparse
-    layer writes the token's K and V at its table-mapped row, writes the
-    compressed key of the window the token COMPLETES (if it does), selects
-    among the lane's compressed keys and reads the rows of the blocks
-    taken; a linear layer advances the lane's state IN PLACE (read, one
-    recurrence step, written back).  A lane whose table starts with a hole
-    (a retired slot) reads a zero state and writes nothing.
+                           block_size, rope_len, use_flash=False, mesh=None):
+    """A decode step of the stack of mixer kinds, one token a lane, one
+    handler a kind.  A row-keeping layer writes the token's K and V at its
+    table-mapped row; a SPARSE one also writes the compressed key of the
+    window the token COMPLETES (if it does), selects among the lane's
+    compressed keys and reads the rows of the blocks taken; an ATTENTION
+    one reads the lane's live pages (``paged_decode_attention``: the paged
+    kernel under ``use_flash``).  A state-keeping layer advances the
+    lane's entries IN PLACE (read, one step, written back): a LINEAR layer
+    its state, a state-space layer its conv window (shifted by the token)
+    and its state.  A lane whose table starts with a hole (a retired slot)
+    reads zeros and writes nothing.
 
     Returns (logits [S, 1, vocab] f32, pools, selection record int32
-    [sparse layers x kv heads, S, 1, sparse_topk])."""
+    [sparse layers x kv heads, S, 1, sparse_topk]) — two values where no
+    layer selects."""
     S, s = tok.shape
     if s != 1:
         raise NotImplementedError(
-            "the two-mixer block decodes one token a lane a step (set "
-            "generate.speculative_k 0): a verify step of several would "
-            "need the state after each of them")
+            "a stack with a state-keeping mixer decodes one token a lane a "
+            "step (set generate.speculative_k 0): a verify step of several "
+            "would need the state after each of them")
     sizes = _sparse_sizes(cfg)
     ks, st = sizes["kernel_size"], sizes["stride"]
     nb = block_tables.shape[1]
     P = pools[STATE_SLOT].shape[0]
     n_blocks = P // block_size
+    kinds = set(cfg.mixer_types)
 
     def pool_rows(pos):
         """Flat pool rows of the positions ``pos`` [S, n] of each lane;
@@ -1118,34 +1186,33 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
 
     with scope("cache_write"):
         dest = pool_rows(lengths[:, None])[:, 0]
-        # the window this token completes, if any: its first token, its
-        # rows
-        w_first = lengths - (ks - 1)
-        w_done = (w_first >= 0) & (w_first % st == 0)
-        w_rows = pool_rows(w_first[:, None] + jnp.arange(ks)[None, :])
-        w_dest = jnp.where(
-            w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
+        if SPARSE in kinds:
+            # the window this token completes, if any: its first token,
+            # its rows
+            w_first = lengths - (ks - 1)
+            w_done = (w_first >= 0) & (w_first % st == 0)
+            w_rows = pool_rows(w_first[:, None] + jnp.arange(ks)[None, :])
+            w_dest = jnp.where(
+                w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
     slots = None
-    if LINEAR in cfg.mixer_types:
+    if kinds & {LINEAR, MAMBA}:
         with scope("state"):
             slots = _state_slots(
                 pools, cfg, block_tables[:, 0] * block_size)
     rope_pos = jnp.minimum(lengths, rope_len - 1)[:, None]
 
-    def mix(i, kind, q, k, v):
-        if kind == LINEAR:
-            with scope("state"):
-                pool = pools[f"s{i}"]
-                state = pool.at[slots].get(mode="fill", fill_value=0.0)
-                out, state = linear_attention_step(
-                    q[:, 0], k[:, 0], v[:, 0], state, decay_slopes(cfg, i))
-                pools[f"s{i}"] = pool.at[slots].set(state, mode="drop")
-                return out[:, None], None
+    def linear(i, q, k, v):
+        with scope("state"):
+            pool = pools[f"s{i}"]
+            state = pool.at[slots].get(mode="fill", fill_value=0.0)
+            out, state = linear_attention_step(
+                q[:, 0], k[:, 0], v[:, 0], state, decay_slopes(cfg, i))
+            pools[f"s{i}"] = pool.at[slots].set(state, mode="drop")
+            return out[:, None], None
+
+    def sparse(i, q, k, v):
         with scope("cache_write"):
-            for name, new in (("k", k[:, 0]), ("v", v[:, 0])):
-                pool = pools[f"{name}{i}"]
-                pools[f"{name}{i}"] = pool.at[dest].set(
-                    new.astype(pool.dtype), mode="drop")
+            _write_rows(pools, i, dest, k[:, 0], v[:, 0])
             kp, cpool = pools[f"k{i}"], pools[f"ck{i}"]
             mean = kp[jnp.minimum(w_rows, P - 1)].astype(
                 jnp.float32).mean(1)
@@ -1158,6 +1225,35 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             )
             return out[:, None], taken[:, :, None]
 
+    def attention(i, q, k, v):
+        with scope("cache_write"):
+            _write_rows(pools, i, dest, k[:, 0], v[:, 0])
+        with scope("attend"):
+            return paged_decode_attention(
+                q, pools[f"k{i}"], pools[f"v{i}"], block_tables,
+                lengths + 1, block_size=block_size, q_offset=lengths,
+                use_flash=use_flash, mesh=mesh,
+            ), None
+
+    def mamba(i, u, project):
+        conv_w, conv_b, a, d_skip = ssm_constants(params, cfg, i)
+        with scope("state"):
+            wpool = pools[f"u{i}"]
+            window = wpool.at[slots].get(mode="fill", fill_value=0)
+            c, window = causal_conv_step(u[:, 0], window, conv_w, conv_b)
+            pools[f"u{i}"] = wpool.at[slots].set(window, mode="drop")
+        delta, b_in, c_out = project(c[:, None])
+        with scope("state"):
+            hpool = pools[f"h{i}"]
+            h = hpool.at[slots].get(mode="fill", fill_value=0.0)
+            g, h = selective_scan_step(
+                c, delta[:, 0], a, b_in[:, 0], c_out[:, 0], d_skip, h)
+            pools[f"h{i}"] = hpool.at[slots].set(h, mode="drop")
+            return g[:, None], None
+
+    handlers = {LINEAR: linear, SPARSE: sparse, ATTENTION: attention,
+                MAMBA: mamba}
     x, record = hybrid_layer_stack(
-        params, cfg, tok, rope_pos, rope_len, mix)
+        params, cfg, tok, rope_pos, rope_len,
+        lambda i, kind, *args: handlers[kind](i, *args))
     return _with_record(hybrid_head(params, cfg, x), pools, record)

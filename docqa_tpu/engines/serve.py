@@ -128,6 +128,7 @@ from docqa_tpu.models.decoder import (
 from docqa_tpu.models.hybrid import (
     is_hybrid,
     lane_state_bytes,
+    mamba_layers,
     sparse_layers,
 )
 from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
@@ -151,8 +152,10 @@ MOE_SUMS = (
     "serve_moe_layer_steps",
 )
 
-# the same of the two-mixer block (``_sparse_step_sums`` /
-# ``_sparse_chunk_sums``), over a chunk's steps and live lanes: blocks the
+# the same of a stack of mixer kinds in which a layer SELECTS
+# (``_sparse_step_sums`` / ``_sparse_chunk_sums``; a stack in which none
+# does carries no such row and counts its lane-steps on the host,
+# ``_count_state_steps``), over a chunk's steps and live lanes: blocks the
 # sparse layers' queries READ (the blocks taken; every live block on a
 # lane still under ``sparse_dense_len``), blocks live for them, lane-steps
 # that ran dense, and lane-steps in all (each reads and writes the lane's
@@ -882,19 +885,23 @@ class ContinuousBatcher:
         # (``_moe_chunk_sums``); a block that does not route adds nothing
         # to its programs or to the worker's work per chunk.
         self._routed_layers = routed_layers(self.cfg)
-        # the two-mixer block: which state entry a lane owns travels in
-        # the pools (``paged.STATE_SLOT``: keyed by the pool row of a
+        # the stack of mixer kinds: which state entry a lane owns travels
+        # in the pools (``paged.STATE_SLOT``: keyed by the pool row of a
         # lane's first token); this is its host copy, written at admission
-        # and uploaded with the round's prefill.  Its decode chunks carry
-        # SPARSE_SUMS the way a routing block's carry MOE_SUMS.
+        # and uploaded with the round's prefill.  Where a layer SELECTS,
+        # its decode chunks carry SPARSE_SUMS the way a routing block's
+        # carry MOE_SUMS.
         self._hybrid = is_hybrid(self.cfg)
+        self._selects = bool(sparse_layers(self.cfg))
+        self._scan_layers = len(mamba_layers(self.cfg))
+        self._state_bytes = lane_state_bytes(self.cfg)  # one lane's
         self._state_slot_np = (
             np.zeros((self.n_blocks * self.block_size,), np.int32)
             if self._hybrid else None
         )
         self._chunk_sum_names = (
             MOE_SUMS if self._routed_layers
-            else SPARSE_SUMS if self._hybrid else ()
+            else SPARSE_SUMS if self._selects else ()
         )
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="continuous-batcher"
@@ -1980,7 +1987,7 @@ class ContinuousBatcher:
             if req is not None:
                 tokens += req.kv_prompt + len(req.tokens)
         state = (
-            {"state_bytes_per_lane": lane_state_bytes(self.cfg)}
+            {"state_bytes_per_lane": self._state_bytes}
             if self._hybrid else {}
         )
         out = {
@@ -2682,9 +2689,13 @@ class ContinuousBatcher:
         DEFAULT_REGISTRY.counter("serve_prefill_budget_tokens").inc(
             sum(T for T, _rows, _novel in group_rows)
         )
-        DEFAULT_REGISTRY.counter("serve_prefill_tokens").inc(
-            sum(novel for _T, _rows, novel in group_rows)
-        )
+        prefill_tokens = sum(novel for _T, _rows, novel in group_rows)
+        DEFAULT_REGISTRY.counter("serve_prefill_tokens").inc(prefill_tokens)
+        if self._scan_layers:
+            # prompt tokens x the state-space layers that scanned them
+            DEFAULT_REGISTRY.counter("serve_scan_tokens").inc(
+                prefill_tokens * self._scan_layers
+            )
         # group-major, like ``ordered``: (slot, req, prompt tokens,
         # shared tokens, what the dispatch that carried it ran)
         meta = []
@@ -2721,16 +2732,21 @@ class ContinuousBatcher:
         return meta, first_toks, cost_keys, t_prefill1
 
     def _hybrid_prefill_attrs(self, n_ids: int, n_lanes: int) -> dict:
-        """What the two-mixer block adds to a request's ``serve_prefill``
-        span: the rows of its prompt that SELECTED (all of them once the
-        prompt holds ``sparse_dense_len`` tokens, none under it) and the
-        lanes whose state the round started from zeros.  Nothing for any
-        other block."""
+        """What the stack of mixer kinds adds to a request's
+        ``serve_prefill`` span: the lanes whose state the round started
+        from zeros; where a layer SELECTS, the rows of the prompt that
+        selected (all of them once it holds ``sparse_dense_len`` tokens,
+        none under it); where a layer SCANS (state-space), the rows its
+        scan ran over.  Nothing for any other block."""
         if not self._hybrid:
             return {}
-        selects = n_ids >= self.cfg.sparse_dense_len
-        return {"sparse_rows": n_ids if selects else 0,
-                "state_lanes": n_lanes}
+        out = {"state_lanes": n_lanes}
+        if self._selects:
+            selects = n_ids >= self.cfg.sparse_dense_len
+            out["sparse_rows"] = n_ids if selects else 0
+        if self._scan_layers:
+            out["scan_rows"] = n_ids
+        return out
 
     def _finalize_admissions(self, admitted) -> bool:
         """Host-side bookkeeping for an admission round: ONE device fetch
@@ -2982,7 +2998,7 @@ class ContinuousBatcher:
                 if fl:
                     _cost_add(req, "flops_est", fl)
         if self._chunk_sum_names and not self.spec_k:
-            if self._hybrid:
+            if self._selects:
                 self._sparse_chunk_sums(packed_h[self.n_slots])
             else:
                 self._moe_chunk_sums(packed_h[self.n_slots])
@@ -3084,6 +3100,10 @@ class ContinuousBatcher:
             float(n_appended)
         )
         DEFAULT_REGISTRY.counter("serve_decode_chunks").inc()
+        if self._hybrid and not self._selects:
+            # no sums row rides this stack's chunks: a lane-step is a
+            # position a lane advanced, which the host holds
+            self._count_state_steps(sum(adv for _, adv in lanes))
         rows_read, rows_live = self._chunk_kv_rows(lanes)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_read").inc(rows_read)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_live").inc(rows_live)
@@ -3114,17 +3134,15 @@ class ContinuousBatcher:
             )
 
     def _sparse_chunk_sums(self, row) -> None:
-        """One fetched chunk's ``SPARSE_SUMS`` into the counters the
-        two-mixer block's metrics read, the bytes of lane state its steps
+        """One fetched chunk's ``SPARSE_SUMS`` into the counters a
+        selecting stack's metrics read, the bytes of lane state its steps
         read and wrote, and — one sample a chunk — the tokens a selecting
         query read per sparse layer and kv head."""
         sums = dict(zip(SPARSE_SUMS, (int(v) for v in row[: len(SPARSE_SUMS)])))
+        lane_steps = sums.pop("serve_state_lane_steps")
         for name, value in sums.items():
             DEFAULT_REGISTRY.counter(name).inc(value)
-        lane_steps = sums["serve_state_lane_steps"]
-        DEFAULT_REGISTRY.counter("serve_state_bytes_rw").inc(
-            2 * lane_state_bytes(self.cfg) * lane_steps
-        )
+        self._count_state_steps(lane_steps)
         selecting = lane_steps - sums["serve_sparse_dense_lane_steps"]
         if selecting and not sums["serve_sparse_dense_lane_steps"]:
             decisions = len(sparse_layers(self.cfg)) * self.cfg.num_kv_heads
@@ -3132,6 +3150,14 @@ class ContinuousBatcher:
                 sums["serve_sparse_blocks_selected"]
                 * self.cfg.sparse_block_size / (selecting * decisions)
             )
+
+    def _count_state_steps(self, lane_steps: int) -> None:
+        """``lane_steps`` decode steps of a live lane: each read and wrote
+        every entry the lane's state-keeping layers hold, once."""
+        DEFAULT_REGISTRY.counter("serve_state_lane_steps").inc(lane_steps)
+        DEFAULT_REGISTRY.counter("serve_state_bytes_rw").inc(
+            2 * self._state_bytes * lane_steps
+        )
 
     def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
         """(KV rows fetched, KV rows live) PER LAYER over one chunk's
@@ -3161,7 +3187,7 @@ class ContinuousBatcher:
             )
             live += int(lens.sum())
             read += int((-(-lens // self.block_size)).sum()) * self.block_size
-        if self._hybrid:
+        if self._selects:
             # a sparse layer reads the rows of the blocks taken, every
             # table once a lane of the step is still under dense_len
             # (ops/attention.sparse_decode_attention)

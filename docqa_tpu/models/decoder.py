@@ -28,11 +28,10 @@ import jax.numpy as jnp
 from docqa_tpu.config import DecoderConfig
 from docqa_tpu.models.hybrid import (
     HYBRID_BLOCK,
-    LINEAR,
+    MIXERS,
     hybrid_param_schema,
     is_hybrid,
-    lane_state_shape,
-    linear_layers,
+    lane_state_entries,
 )
 from docqa_tpu.models.latent import (
     LATENT_BLOCK,
@@ -56,7 +55,7 @@ def decoder_param_schema(cfg: DecoderConfig):
     (``models/quant.py``) consume this — the RNG stream order is defined
     by the order of "normal" entries here, so the two inits can never
     desynchronize.  The latent block's tree is ``models/latent.py``'s,
-    the two-mixer block's ``models/hybrid.py``'s."""
+    the stack of mixer kinds' ``models/hybrid.py``'s."""
     if is_latent(cfg):
         yield from latent_param_schema(cfg)
         return
@@ -90,24 +89,33 @@ def kv_row_shapes(
     every head reads as key and as value.  The paged pools, their bytes
     per token and the choice of decode kernel are all asked of this.
 
-    The two-mixer block answers per LAYER KIND: a sparse layer keeps K and
-    V rows (``layer`` None: a row-keeping layer's answer), a linear layer
+    The stack of mixer kinds (``models/hybrid.py``) answers per LAYER KIND:
+    a sparse or a plain attention layer keeps K and V rows (``layer``
+    None: a row-keeping layer's answer), a linear or a state-space layer
     NO row — what it keeps is a state a lane, :func:`lane_state_shapes`."""
     if is_latent(cfg):
         return {"c": (1, latent_row_width(cfg))}
-    if is_hybrid(cfg) and layer is not None and (
-            cfg.mixer_types[layer] == LINEAR):
-        return {}
+    if is_hybrid(cfg) and layer is not None:
+        return MIXERS[cfg.mixer_types[layer]].rows(cfg)
     return {"k": (cfg.num_kv_heads, cfg.head_dim),
             "v": (cfg.num_kv_heads, cfg.head_dim)}
 
 
 def lane_state_shapes(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
     """What a LANE holds whatever its length, beside the rows its tokens
-    left: ``{pool name: shape of one lane's entry}``, float32.  Empty for
-    every block but the two-mixer one, whose linear layers each keep one
-    [heads, d, d] state."""
-    return {f"s{i}": lane_state_shape(cfg) for i in linear_layers(cfg)}
+    left: ``{pool name: shape of one lane's entry}``.  Empty for every
+    block but the stack of mixer kinds: a linear layer keeps one
+    [heads, d, d] state (``s{i}``), a state-space layer TWO entries of
+    different shape and type — its state ``h{i}`` [state, inner] and its
+    last conv inputs ``u{i}`` [taps - 1, inner]
+    (:func:`lane_state_dtypes`)."""
+    return {n: shape for n, (shape, _) in lane_state_entries(cfg).items()}
+
+
+def lane_state_dtypes(cfg: DecoderConfig) -> Dict[str, str]:
+    """The element type of each entry of :func:`lane_state_shapes`:
+    float32 for a state, the activation type for a conv window."""
+    return {n: dtype for n, (_, dtype) in lane_state_entries(cfg).items()}
 
 
 def param_putter(cfg: DecoderConfig, mesh=None):
@@ -156,8 +164,12 @@ def init_decoder_params(
                 )
                 p[name] = put(name, w.astype(param_dtype))
         return p
-    keys = iter(jax.random.split(rng, 8 + 8 * cfg.num_layers))
-    for name, kind, shape, fan_in in decoder_param_schema(cfg):
+    schema = list(decoder_param_schema(cfg))
+    # a state-space layer draws more than eight tensors; every other tree
+    # keeps the split (and so the stream) it has always had
+    drawn = sum(kind == "normal" for _, kind, _, _ in schema)
+    keys = iter(jax.random.split(rng, max(8 + 8 * cfg.num_layers, drawn)))
+    for name, kind, shape, fan_in in schema:
         if kind == "ones":
             p[name] = jnp.ones(shape, param_dtype)
         else:
@@ -336,7 +348,7 @@ def decoder_forward(
     if is_hybrid(cfg):
         raise NotImplementedError(
             f'the dense-cache solo forward has no "{HYBRID_BLOCK}" block: '
-            "a stack of linear and sparse mixers serves through the paged "
+            "a stack of mixer kinds serves through the paged "
             "cache and its lane state (engines/paged.py, the batcher) only"
         )
     b, s = ids.shape

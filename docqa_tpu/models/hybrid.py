@@ -1,42 +1,65 @@
-"""A decoder stack of TWO mixer kinds — ``DecoderConfig.block ==
-"sparse_linear"``: decayed linear attention (Lightning Attention-2,
-arXiv:2401.04658) in most layers, block-sparse softmax attention
-(InfLLM-V2, arXiv:2509.24663) in the rest, one name per layer in
-``cfg.mixer_types``, chosen at trace time.
+"""A decoder stack of several MIXER KINDS — ``DecoderConfig.block ==
+"sparse_linear"``: one name per layer in ``cfg.mixer_types``, chosen at
+trace time.  Four kinds, one trunk:
+
+* ``linear`` — decayed linear attention (Lightning Attention-2,
+  arXiv:2401.04658): RoPE, a [d, d] float32 state a head A LANE;
+* ``sparse`` — block-sparse softmax attention (InfLLM-V2,
+  arXiv:2509.24663): no RoPE, K / V rows and compressed keys in the cache;
+* ``attention`` — plain causal GQA / MQA softmax attention over every row:
+  no RoPE, K / V rows in the cache, read at decode by the paged kernel;
+* ``mamba`` — a Mamba-1 state-space mixer (arXiv:2312.00752) with its own
+  projections; a lane keeps its last conv inputs and one state.
 
 Same shape as ``models/decoder.py`` and ``models/latent.py``: a flat
 parameter tree, one pure-functional trunk, and a ``mix`` callback that owns
-what a layer keeps between steps.  Per layer ``l`` of ``L``, ``x`` the
-residual stream, ``r = scale_depth / sqrt(L)``:
+what a layer keeps between steps.  What differs by kind is ONE entry of
+:data:`MIXERS`: the layer's parameter schema, what a token and what a lane
+keep, and the projections around the callback (the step itself is the
+engine's: ``engines/paged.py`` holds one handler a kind and forward).  Per
+layer ``l`` of ``L``, ``x`` the residual stream, ``r = scale_depth /
+sqrt(L)`` (1 where ``scale_depth`` is 0):
 
     y = rmsnorm(x)
-    q, k, v = y Wq, y Wk, y Wv;  q, k = rmsnorm_head(q), rmsnorm_head(k)
-    linear:  q, k = rope(q), rope(k)           32 heads = 32 kv heads
-             S_t = lambda_h S_{t-1} + k_t^T v_t     [d, d] float32 A LANE
-             o_t = (q_t / sqrt(d)) S_t;  o = rmsnorm_head(o)
-    sparse:  no RoPE; GQA softmax attention over K / V rows in the cache —
-             every row while the sequence holds fewer than
-             ``sparse_dense_len`` tokens, else the rows of the
-             ``sparse_topk`` blocks the row selects (ops/attention.py)
-    x = x + r * (o * sigmoid(y Wg)) Wo
+    attention kinds:
+        q, k, v = y Wq, y Wk, y Wv
+        q, k = rmsnorm_head(q), rmsnorm_head(k)          (``qk_norm``)
+        linear:    q, k = rope(q), rope(k)      32 heads = 32 kv heads
+                   S_t = lambda_h S_{t-1} + k_t^T v_t     [d, d] float32
+                   o_t = (q_t / sqrt(d)) S_t
+                   o = rmsnorm_head(o)             (``use_output_norm``)
+        sparse:    softmax attention over K / V rows in the cache — every
+                   row while the sequence holds fewer than
+                   ``sparse_dense_len`` tokens, else the rows of the
+                   ``sparse_topk`` blocks the row selects
+        attention: causal softmax attention over every row
+        branch = (o * sigmoid(y Wg)) Wo   (``use_output_gate``; else o Wo)
+    mamba (``inner = ssm_expand * hidden``):
+        [u, z] = y W_in
+        c_t = silu(b_conv + sum_j w_conv[j] * u_{t-K+1+j})   depthwise
+        [dt, B, C] = c W_x;  each RMS-normed
+        D_t = softplus(dt W_dt + b_dt)
+        h_t = exp(D_t (x) A) h_{t-1} + (D_t c_t) (x) B_t,  A = -exp(A_log)
+        g_t = h_t C_t + D c_t                  [state, inner] float32
+        branch = (g * silu(z)) W_out
+    x = x + r * branch
     x = x + r * swiglu(rmsnorm(x))
 
 and ``h_0 = scale_emb * E[ids]``, logits ``= head(rmsnorm(h)) /
-(hidden_dim / dim_model_base)``.  ``lambda_h = exp(-s_h)`` with the
-family's slopes (:func:`decay_slopes`).
+(hidden_dim / dim_model_base)`` (unscaled where ``dim_model_base`` is 0;
+against ``E`` itself under ``tie_embeddings``).  ``lambda_h = exp(-s_h)``
+with the family's slopes (:func:`decay_slopes`).
 
-What a layer keeps: a sparse layer K and V rows a token and one
-mean-pooled key per ``sparse_kernel_stride`` tokens; a linear layer NO row
-— its whole past is the state, held per lane beside the paged rows
-(``engines/paged.py``).  The trunk hands back the blocks each sparse layer
-and kv head took (the selection record; benchmark/README.md "A block that
-routes").
+The trunk hands back the blocks each sparse layer and kv head took (the
+selection record; benchmark/README.md "A block that routes") — ``None``
+for a stack in which no layer selects.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
-from typing import Dict, Tuple
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +72,7 @@ from docqa_tpu.ops.scopes import scope
 Params = Dict[str, jax.Array]
 
 HYBRID_BLOCK = "sparse_linear"
-SPARSE, LINEAR = "sparse", "linear"
+SPARSE, LINEAR, ATTENTION, MAMBA = "sparse", "linear", "attention", "mamba"
 # prefill rows one MLP tile holds: the gate / up activations of a longer
 # dispatch are never whole (38k rows x 16384 would be 1.2 GB each)
 MLP_TILE_ROWS = 2048
@@ -59,25 +82,33 @@ def is_hybrid(cfg: DecoderConfig) -> bool:
     return cfg.block == HYBRID_BLOCK
 
 
+def layers_of(cfg: DecoderConfig, *kinds: str) -> Tuple[int, ...]:
+    """Indices of the layers of the named mixer kinds, in order."""
+    if not is_hybrid(cfg):
+        return ()
+    return tuple(i for i, m in enumerate(cfg.mixer_types) if m in kinds)
+
+
 def sparse_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
     """Indices of the layers that keep rows in the cache and select."""
-    if not is_hybrid(cfg):
-        return ()
-    return tuple(i for i, m in enumerate(cfg.mixer_types) if m == SPARSE)
+    return layers_of(cfg, SPARSE)
 
 
-def linear_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
-    """Indices of the layers whose past is a state a lane."""
-    if not is_hybrid(cfg):
-        return ()
-    return tuple(i for i, m in enumerate(cfg.mixer_types) if m == LINEAR)
+def mamba_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
+    """Indices of the state-space layers (a conv window and a state)."""
+    return layers_of(cfg, MAMBA)
 
 
 def mixer_geometry(cfg: DecoderConfig, kind: str) -> Tuple[int, int, int]:
-    """(query heads, kv heads, head width) of one mixer kind."""
+    """(query heads, kv heads, head width) of one attention kind."""
     if kind == LINEAR:
         return cfg.linear_heads, cfg.linear_heads, cfg.linear_head_dim
     return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+
+
+def ssm_inner(cfg: DecoderConfig) -> int:
+    """Channels of the state-space mixer: ``ssm_expand * hidden_dim``."""
+    return cfg.ssm_expand * cfg.hidden_dim
 
 
 def lane_state_shape(cfg: DecoderConfig) -> Tuple[int, int, int]:
@@ -85,9 +116,21 @@ def lane_state_shape(cfg: DecoderConfig) -> Tuple[int, int, int]:
     return (cfg.linear_heads, cfg.linear_head_dim, cfg.linear_head_dim)
 
 
+def lane_state_entries(cfg: DecoderConfig) -> Dict[str, Tuple[tuple, str]]:
+    """``{pool name: (shape of one lane's entry, element type)}`` over
+    every state-keeping layer, in layer order."""
+    out: Dict[str, Tuple[tuple, str]] = {}
+    for i, kind in enumerate(cfg.mixer_types if is_hybrid(cfg) else ()):
+        for prefix, entry in MIXERS[kind].lane_state(cfg).items():
+            out[f"{prefix}{i}"] = entry
+    return out
+
+
 def lane_state_bytes(cfg: DecoderConfig) -> int:
-    """Bytes of one lane's state across every linear layer."""
-    return len(linear_layers(cfg)) * 4 * math.prod(lane_state_shape(cfg))
+    """Bytes of one lane's state across every state-keeping layer."""
+    return sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize
+        for shape, dtype in lane_state_entries(cfg).values())
 
 
 def residual_scale(cfg: DecoderConfig) -> float:
@@ -110,12 +153,25 @@ def decay_slopes(cfg: DecoderConfig, layer: int):
     return base * (1.0 - layer / max(cfg.num_layers - 1, 1) + 1e-5)
 
 
+def ssm_constants(params: "Params", cfg: DecoderConfig, layer: int):
+    """What a state-space layer's conv and scan read beside their inputs:
+    (conv weight [taps, inner], conv bias [inner] or None, ``A = -exp(A_log)``
+    [state, inner] float32, ``D`` [inner])."""
+    p = f"l{layer}_"
+    return (
+        params[p + "conv_w"],
+        params[p + "conv_b"] if cfg.ssm_conv_bias else None,
+        -jnp.exp(params[p + "a_log"].astype(jnp.float32)),
+        params[p + "d_skip"],
+    )
+
+
 def check_hybrid_config(cfg: DecoderConfig) -> None:
     """Refuse, by field, a configuration this block cannot run."""
     problems = []
     if len(cfg.mixer_types) != cfg.num_layers:
         problems.append("len(mixer_types) != num_layers")
-    strange = sorted(set(cfg.mixer_types) - {SPARSE, LINEAR})
+    strange = sorted(set(cfg.mixer_types) - set(MIXERS))
     if strange:
         problems.append(f"mixer_types names {strange}")
     if LINEAR in cfg.mixer_types and min(
@@ -127,11 +183,23 @@ def check_hybrid_config(cfg: DecoderConfig) -> None:
         problems.append("sliding_window (the sparse mixer has its own)")
     if cfg.quantize_weights and cfg.quant_bits != 8:
         problems.append("quant_bits (this block serves int8 or float)")
+    if cfg.num_experts > 1:
+        problems.append(
+            "num_experts (every layer's feed-forward is one dense SwiGLU: a "
+            "sparse-MoE feed-forward inside this stack is not brought)")
+    if MAMBA in cfg.mixer_types and min(
+            cfg.ssm_state_dim, cfg.ssm_dt_rank, cfg.ssm_expand) <= 0:
+        problems.append("ssm_state_dim / ssm_dt_rank / ssm_expand unset")
+    if MAMBA in cfg.mixer_types and cfg.ssm_conv_width < 2:
+        problems.append("ssm_conv_width under 2 (a lane keeps width - 1 rows)")
     ks, st, bs = (cfg.sparse_kernel_size, cfg.sparse_kernel_stride,
                   cfg.sparse_block_size)
-    if min(ks, st, bs, cfg.sparse_topk, cfg.sparse_dense_len) <= 0:
+    # where no layer selects the sparse_* sizes are read by nobody
+    selects = SPARSE in cfg.mixer_types
+    if selects and min(
+            ks, st, bs, cfg.sparse_topk, cfg.sparse_dense_len) <= 0:
         problems.append("a sparse_* size is not positive")
-    elif ks % st or bs % st or ks > bs:
+    elif selects and (ks % st or bs % st or ks > bs):
         problems.append(
             "sparse_kernel_size and sparse_block_size are no multiples of "
             "sparse_kernel_stride, or a window is longer than a block")
@@ -141,34 +209,6 @@ def check_hybrid_config(cfg: DecoderConfig) -> None:
         )
 
 
-def hybrid_param_schema(cfg: DecoderConfig):
-    """``(name, kind, shape, fan_in)`` of the block's tree, in the order of
-    ``models/decoder.decoder_param_schema`` (which yields this for the
-    block)."""
-    check_hybrid_config(cfg)
-    h = cfg.hidden_dim
-    yield ("tok_emb", "normal", (cfg.vocab_size, h), h)
-    yield ("final_norm_g", "ones", (h,), None)
-    yield ("lm_head", "normal", (h, cfg.vocab_size), h)
-    for i, kind in enumerate(cfg.mixer_types):
-        p = f"l{i}_"
-        heads, kv_heads, d = mixer_geometry(cfg, kind)
-        yield (p + "attn_norm_g", "ones", (h,), None)
-        yield (p + "wq", "normal", (h, heads * d), h)
-        yield (p + "wk", "normal", (h, kv_heads * d), h)
-        yield (p + "wv", "normal", (h, kv_heads * d), h)
-        yield (p + "q_norm_g", "ones", (d,), None)
-        yield (p + "k_norm_g", "ones", (d,), None)
-        if kind == LINEAR:
-            yield (p + "o_norm_g", "ones", (heads * d,), None)
-        yield (p + "w_ogate", "normal", (h, heads * d), h)
-        yield (p + "wo", "normal", (heads * d, h), heads * d)
-        yield (p + "mlp_norm_g", "ones", (h,), None)
-        yield (p + "w_gate", "normal", (h, cfg.mlp_dim), h)
-        yield (p + "w_up", "normal", (h, cfg.mlp_dim), h)
-        yield (p + "w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
-
-
 def _qmatmul(x, params: Params, name: str, dtype):
     """``models/decoder._qmatmul`` (float, int8 or int4 weights by what the
     tree holds).  Imported at the call: that module imports this one for
@@ -176,6 +216,191 @@ def _qmatmul(x, params: Params, name: str, dtype):
     from docqa_tpu.models.decoder import _qmatmul as matmul
 
     return matmul(x, params, name, dtype)
+
+
+# ---- the mixer kinds: one entry each ----------------------------------------
+
+
+def _attention_schema(kind: str):
+    def schema(cfg: DecoderConfig):
+        h = cfg.hidden_dim
+        heads, kv_heads, d = mixer_geometry(cfg, kind)
+        yield ("wq", "normal", (h, heads * d), h)
+        yield ("wk", "normal", (h, kv_heads * d), h)
+        yield ("wv", "normal", (h, kv_heads * d), h)
+        if cfg.qk_norm:
+            yield ("q_norm_g", "ones", (d,), None)
+            yield ("k_norm_g", "ones", (d,), None)
+        if kind == LINEAR and cfg.use_output_norm:
+            yield ("o_norm_g", "ones", (heads * d,), None)
+        if cfg.use_output_gate:
+            yield ("w_ogate", "normal", (h, heads * d), h)
+        yield ("wo", "normal", (heads * d, h), heads * d)
+
+    return schema
+
+
+def _attention_branch(params: Params, cfg: DecoderConfig, i: int, kind: str,
+                      y, rope, mix):
+    """q, k, v with what the file says of their norms (and RoPE for the
+    linear kind), ``mix(i, kind, q, k, v) -> (out, taken)``, then the
+    output norm, gate and projection."""
+    p = f"l{i}_"
+    b, s, _ = y.shape
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.norm_eps
+    heads, kv_heads, d = mixer_geometry(cfg, kind)
+    with scope("proj"):
+        q = _qmatmul(y, params, p + "wq", dtype).reshape(b, s, heads, d)
+        k = _qmatmul(y, params, p + "wk", dtype).reshape(b, s, kv_heads, d)
+        v = _qmatmul(y, params, p + "wv", dtype).reshape(b, s, kv_heads, d)
+        if cfg.qk_norm:
+            q = rms_norm(q, params[p + "q_norm_g"], eps)
+            k = rms_norm(k, params[p + "k_norm_g"], eps)
+        if kind == LINEAR:
+            cos, sin, positions = rope
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+    out, taken = mix(i, kind, q, k, v)
+    with scope("proj"):
+        if kind == LINEAR and cfg.use_output_norm:
+            out = rms_norm(
+                out, params[p + "o_norm_g"].reshape(heads, d), eps)
+        if cfg.use_output_gate:
+            gate = jax.nn.sigmoid(
+                _qmatmul(y, params, p + "w_ogate", dtype).astype(
+                    jnp.float32))
+            a = (out.reshape(b, s, heads * d).astype(jnp.float32)
+                 * gate).astype(dtype)
+        else:
+            a = out.reshape(b, s, heads * d)
+        return _qmatmul(a, params, p + "wo", dtype), taken
+
+
+def _kv_rows(cfg: DecoderConfig):
+    return {"k": (cfg.num_kv_heads, cfg.head_dim),
+            "v": (cfg.num_kv_heads, cfg.head_dim)}
+
+
+def _mamba_schema(cfg: DecoderConfig):
+    h, inner = cfg.hidden_dim, ssm_inner(cfg)
+    n, rank, taps = cfg.ssm_state_dim, cfg.ssm_dt_rank, cfg.ssm_conv_width
+    yield ("w_in", "normal", (h, 2 * inner), h)
+    if cfg.ssm_proj_bias:
+        yield ("b_in", "normal", (2 * inner,), 1)
+    yield ("conv_w", "normal", (taps, inner), taps)
+    if cfg.ssm_conv_bias:
+        yield ("conv_b", "normal", (inner,), taps)
+    yield ("w_x", "normal", (inner, rank + 2 * n), inner)
+    yield ("dt_norm_g", "ones", (rank,), None)
+    yield ("b_norm_g", "ones", (n,), None)
+    yield ("c_norm_g", "ones", (n,), None)
+    yield ("w_dt", "normal", (rank, inner), rank)
+    yield ("b_dt", "normal", (inner,), 1)
+    yield ("a_log", "ones", (n, inner), None)
+    yield ("d_skip", "ones", (inner,), None)
+    yield ("w_out", "normal", (inner, h), inner)
+    if cfg.ssm_proj_bias:
+        yield ("b_out", "normal", (h,), 1)
+
+
+def _mamba_branch(params: Params, cfg: DecoderConfig, i: int, kind: str,
+                  y, rope, mix):
+    """``[u, z] = y W_in``; ``mix(i, kind, u, project) -> (g, None)`` owns
+    the conv (its window) and the scan (its state) and calls
+    ``project(c) -> (delta float32, B, C)`` between them; then the gate and
+    ``W_out``."""
+    p = f"l{i}_"
+    dtype = jnp.dtype(cfg.dtype)
+    eps, f32 = cfg.norm_eps, jnp.float32
+    inner, n, rank = ssm_inner(cfg), cfg.ssm_state_dim, cfg.ssm_dt_rank
+    with scope("proj"):
+        uz = _qmatmul(y, params, p + "w_in", dtype)
+        if cfg.ssm_proj_bias:
+            uz = uz + params[p + "b_in"].astype(dtype)
+        u, z = uz[..., :inner], uz[..., inner:]
+
+    def project(c):
+        with scope("proj"):
+            dbc = _qmatmul(c, params, p + "w_x", dtype)
+            dt = rms_norm(dbc[..., :rank], params[p + "dt_norm_g"], eps)
+            bm = rms_norm(
+                dbc[..., rank:rank + n], params[p + "b_norm_g"], eps)
+            cm = rms_norm(dbc[..., rank + n:], params[p + "c_norm_g"], eps)
+            delta = jax.nn.softplus(
+                _qmatmul(dt, params, p + "w_dt", dtype).astype(f32)
+                + params[p + "b_dt"].astype(f32))
+            return delta, bm, cm
+
+    g, taken = mix(i, kind, u, project)
+    with scope("proj"):
+        a = (g.astype(f32) * jax.nn.silu(z.astype(f32))).astype(dtype)
+        out = _qmatmul(a, params, p + "w_out", dtype)
+        if cfg.ssm_proj_bias:
+            out = out + params[p + "b_out"].astype(dtype)
+        return out, taken
+
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """What the stack asks of one mixer kind.  ``schema(cfg)`` yields the
+    layer's mixer parameters ``(short name, init kind, shape, fan_in)``;
+    ``rows(cfg)`` is what a TOKEN leaves in the cache, ``{pool prefix:
+    (heads, width)}``; ``lane_state(cfg)`` what a LANE keeps whatever its
+    length, ``{pool prefix: (shape, element type)}``; ``branch(params,
+    cfg, i, kind, y, rope, mix) -> (the residual branch [b, s, hidden],
+    the blocks taken or None)`` the projections around the engine's step."""
+
+    schema: Callable
+    rows: Callable
+    lane_state: Callable
+    branch: Callable
+
+
+def _nothing(cfg: DecoderConfig) -> dict:
+    return {}
+
+
+MIXERS: Dict[str, Mixer] = {
+    SPARSE: Mixer(_attention_schema(SPARSE), _kv_rows, _nothing,
+                  _attention_branch),
+    ATTENTION: Mixer(_attention_schema(ATTENTION), _kv_rows, _nothing,
+                     _attention_branch),
+    LINEAR: Mixer(
+        _attention_schema(LINEAR), _nothing,
+        lambda cfg: {"s": (lane_state_shape(cfg), "float32")},
+        _attention_branch),
+    # the window holds conv INPUTS as the in-projection rounded them (the
+    # activation type: nothing is lost); the state is float32
+    MAMBA: Mixer(
+        _mamba_schema, _nothing,
+        lambda cfg: {
+            "h": ((cfg.ssm_state_dim, ssm_inner(cfg)), "float32"),
+            "u": ((cfg.ssm_conv_width - 1, ssm_inner(cfg)), cfg.dtype),
+        },
+        _mamba_branch),
+}
+
+
+def hybrid_param_schema(cfg: DecoderConfig):
+    """``(name, kind, shape, fan_in)`` of the block's tree, in the order of
+    ``models/decoder.decoder_param_schema`` (which yields this for the
+    block).  No ``lm_head`` under ``tie_embeddings``."""
+    check_hybrid_config(cfg)
+    h = cfg.hidden_dim
+    yield ("tok_emb", "normal", (cfg.vocab_size, h), h)
+    yield ("final_norm_g", "ones", (h,), None)
+    if not cfg.tie_embeddings:
+        yield ("lm_head", "normal", (h, cfg.vocab_size), h)
+    for i, kind in enumerate(cfg.mixer_types):
+        p = f"l{i}_"
+        yield (p + "attn_norm_g", "ones", (h,), None)
+        for name, init, shape, fan_in in MIXERS[kind].schema(cfg):
+            yield (p + name, init, shape, fan_in)
+        yield (p + "mlp_norm_g", "ones", (h,), None)
+        yield (p + "w_gate", "normal", (h, cfg.mlp_dim), h)
+        yield (p + "w_up", "normal", (h, cfg.mlp_dim), h)
+        yield (p + "w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
 
 
 def _swiglu_tiled(y, params: Params, p: str, dtype):
@@ -206,55 +431,41 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
                        rope_len: int, mix):
     """The block's trunk, as ``decoder_layer_stack`` is the GQA block's.
 
-    ``mix(i, kind, q [b, s, heads, d], k, v [b, s, kv heads, d]) ->
-    (out [b, s, heads, d], taken)`` owns the cache and the lane state:
-    a sparse layer writes its rows and attends (``taken``: the blocks it
-    took, int32 [kv heads, b, s, topk]), a linear layer advances its
-    state (``taken`` None).
+    ``mix(i, kind, ...)`` owns the cache and the lane state; what it is
+    handed is the kind's affair (:data:`MIXERS`).  An attention kind:
+    ``mix(i, kind, q [b, s, heads, d], k, v [b, s, kv heads, d]) -> (out
+    [b, s, heads, d], taken)`` — a row-keeping layer writes its rows and
+    attends (``taken``: the blocks a SPARSE layer took, int32 [kv heads,
+    b, s, topk]; None from every other kind), a linear layer advances its
+    state.  The state-space kind: ``mix(i, kind, u [b, s, inner],
+    project) -> (g [b, s, inner], None)``.
 
     Returns (hidden states [b, s, hidden] before the final norm, the
-    selection record int32 [sparse layers x kv heads, b, s, topk])."""
-    b, s = ids.shape
+    selection record int32 [sparse layers x kv heads, b, s, topk]; None
+    where no layer selects)."""
     dtype = jnp.dtype(cfg.dtype)
     r = residual_scale(cfg)
     eps = cfg.norm_eps
-    cos = sin = None
+    rope = None
     if LINEAR in cfg.mixer_types:
         with scope("proj"):
             cos, sin = rope_angles(
                 cfg.linear_head_dim, rope_len, cfg.rope_theta)
+            rope = (cos, sin, positions)
     with scope("embed"):
         x = (params["tok_emb"][ids].astype(jnp.float32)
              * cfg.scale_emb).astype(dtype)
     record = []
     for i, kind in enumerate(cfg.mixer_types):
         p = f"l{i}_"
-        heads, kv_heads, d = mixer_geometry(cfg, kind)
         with scope("proj"):
             y = rms_norm(x, params[p + "attn_norm_g"], eps)
-            q = _qmatmul(y, params, p + "wq", dtype).reshape(b, s, heads, d)
-            k = _qmatmul(y, params, p + "wk", dtype).reshape(
-                b, s, kv_heads, d)
-            v = _qmatmul(y, params, p + "wv", dtype).reshape(
-                b, s, kv_heads, d)
-            q = rms_norm(q, params[p + "q_norm_g"], eps)
-            k = rms_norm(k, params[p + "k_norm_g"], eps)
-            if kind == LINEAR:
-                q = apply_rope(q, cos, sin, positions)
-                k = apply_rope(k, cos, sin, positions)
-        out, taken = mix(i, kind, q, k, v)
-        if kind != LINEAR:
+        branch, taken = MIXERS[kind].branch(
+            params, cfg, i, kind, y, rope, mix)
+        if kind == SPARSE:
             record.append(taken)
         with scope("proj"):
-            if kind == LINEAR:
-                out = rms_norm(
-                    out, params[p + "o_norm_g"].reshape(heads, d), eps)
-            gate = jax.nn.sigmoid(
-                _qmatmul(y, params, p + "w_ogate", dtype).astype(
-                    jnp.float32))
-            a = (out.reshape(b, s, heads * d).astype(jnp.float32)
-                 * gate).astype(dtype)
-            x = _residual(x, r, _qmatmul(a, params, p + "wo", dtype))
+            x = _residual(x, r, branch)
         with scope("mlp"):
             y = rms_norm(x, params[p + "mlp_norm_g"], eps)
             x = _residual(x, r, _swiglu_tiled(y, params, p, dtype))
@@ -267,8 +478,18 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
 
 
 def hybrid_head(params: Params, cfg: DecoderConfig, x):
-    """``decoder_head`` with the block's logit scale."""
+    """``decoder_head`` with the block's logit scale; under
+    ``tie_embeddings`` the logits are taken against ``tok_emb`` itself
+    (contracted over its hidden axis: no transposed copy is kept)."""
     from docqa_tpu.models.decoder import decoder_head
 
     with scope("head"):
-        return decoder_head(params, cfg, x) * logit_scale(cfg)
+        if cfg.tie_embeddings:
+            y = rms_norm(x, params["final_norm_g"], cfg.norm_eps)
+            logits = jnp.einsum(
+                "bsh,vh->bsv", y,
+                params["tok_emb"].astype(jnp.dtype(cfg.dtype)),
+            ).astype(jnp.float32)
+        else:
+            logits = decoder_head(params, cfg, x)
+        return logits * logit_scale(cfg)

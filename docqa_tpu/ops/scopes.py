@@ -23,13 +23,16 @@ PR:
 
     embed        token embedding (and its scale)
     proj         the mixer's input and output projections with their norm,
-                 RoPE / YaRN, qk-norm, output gate and residual add
+                 RoPE / YaRN, qk-norm, output gate and residual add; the
+                 state-space mixer's in / x / dt / out projections, its
+                 three inner norms and its gate
     cache_write  what a token leaves in the pools: K / V rows, the latent
                  row, the compressed key, ``state_slot``
     attend       softmax attention over cached or in-flight rows
     select       block scoring and top-k of the sparse mixer
-    state        the linear mixer's scan / step, its state's gather and
-                 scatter included
+    state        the linear mixer's scan / step and the state-space
+                 mixer's conv and selective scan / step, the state's (and
+                 the conv window's) gather and scatter included
     mlp          the dense SwiGLU with its norm and residual add; shared
                  experts
     route        router scores and the choice of experts

@@ -27,7 +27,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
-from docqa_tpu.models.hybrid import LINEAR, is_hybrid
+from docqa_tpu.models.hybrid import LINEAR, MAMBA, is_hybrid
 from docqa_tpu.models.latent import is_latent
 from docqa_tpu.runtime.mesh import MeshContext
 
@@ -97,24 +97,44 @@ def _latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
 
 
 def _hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
-    """The two-mixer block (models/hybrid.py): Megatron per layer — q, the
-    output gate and the MLP's gate / up column-parallel, ``wo`` and
-    ``w_down`` row-parallel.  A linear layer's k and v are as wide as its
-    q and go column-parallel with it; a sparse layer's few kv heads are
-    replicated (2 heads do not divide over 4 or 8 devices), as are the
-    per-head norm gains.  The pools — rows, compressed keys, lane states —
-    are replicated (``paged_pool_pspecs``)."""
+    """The stack of mixer kinds (models/hybrid.py): Megatron per layer.
+    An attention kind: q, the output gate and the MLP's gate / up
+    column-parallel, ``wo`` and ``w_down`` row-parallel; a linear layer's
+    k and v are as wide as its q and go column-parallel with it; the few
+    kv heads of a sparse or a plain attention layer are replicated (1 or
+    2 heads do not divide over 4 or 8 devices), as are the per-head norm
+    gains.  The state-space kind along its INNER channels: ``w_in``
+    column-parallel over its ``2 x inner`` columns (GSPMD re-lays the
+    ``u`` and the ``z`` half along ``inner``), the conv's taps and bias,
+    ``w_x``'s input, ``w_dt``'s output, ``b_dt``, ``A_log`` and ``D``
+    along that axis, ``w_out`` row-parallel; the three inner norms
+    replicated.  The pools — rows (ONE kv head cannot be divided),
+    compressed keys, lane states and windows — are replicated
+    (``paged_pool_pspecs``)."""
     specs: Dict[str, P] = {}
     for i, kind in enumerate(cfg.mixer_types):
         p = f"l{i}_"
-        kv = P(None, m) if kind == LINEAR else P(None, None)
         specs.update({
             p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
+            p + "w_gate": P(None, m), p + "w_up": P(None, m),
+            p + "w_down": P(m, None),
+        })
+        if kind == MAMBA:
+            specs.update({
+                p + "w_in": P(None, m), p + "b_in": P(m),
+                p + "conv_w": P(None, m), p + "conv_b": P(m),
+                p + "w_x": P(m, None), p + "dt_norm_g": P(None),
+                p + "b_norm_g": P(None), p + "c_norm_g": P(None),
+                p + "w_dt": P(None, m), p + "b_dt": P(m),
+                p + "a_log": P(None, m), p + "d_skip": P(m),
+                p + "w_out": P(m, None), p + "b_out": P(None),
+            })
+            continue
+        kv = P(None, m) if kind == LINEAR else P(None, None)
+        specs.update({
             p + "q_norm_g": P(None), p + "k_norm_g": P(None),
             p + "wq": P(None, m), p + "wk": kv, p + "wv": kv,
             p + "w_ogate": P(None, m), p + "wo": P(m, None),
-            p + "w_gate": P(None, m), p + "w_up": P(None, m),
-            p + "w_down": P(m, None),
         })
         if kind == LINEAR:
             specs[p + "o_norm_g"] = P(None)
@@ -198,7 +218,7 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     one-all-reduce-per-Megatron-block budget as the dense programs)."""
     if is_latent(cfg):  # one row a token, no head axis: replicated
         return {f"c{i}": P() for i in range(cfg.num_layers)}
-    if is_hybrid(cfg):  # rows of 2 kv heads, lane states, the slot map
+    if is_hybrid(cfg):  # rows of 1-2 kv heads, lane states, the slot map
         import jax
 
         from docqa_tpu.engines.paged import init_paged_pools

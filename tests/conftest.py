@@ -69,6 +69,20 @@ _PR28_DIGITS = tuple(
     f"test_a_block_that_does_not_route_reads_what_it_read[mistral-{seed}]"
     for seed in (1, 2, 3))
 
+# ---- a pin on the list's END that the driver's own rule outdates (PR 42) ----
+# tests/benchmark/test_benchmark_scopes.py::
+# test_the_eight_entries_sit_at_the_end_with_the_two_cells (PR 40) asserts
+# that BENCHMARK.json's per-layer list ENDS with PR 40's eight.  ISSUE 42 put
+# its two metrics in front of them to keep that; the driver's check reads an
+# entry in the middle of a list as a CHANGE to the one that stood there and
+# refused the PR for it ("changes the per-layer metric decode_attention_ms"):
+# new entries go at the end, so the pin cannot hold.  The file is the
+# benchmark's; until a `benchmark` PR finds the eight by name there,
+# tests/benchmark/test_benchmark_jamba2.py::test_what_pr40s_pin_held_still_holds
+# asserts every line of it, the eight found by name.  STRICT, as above.
+_PR40_TAIL = ("tests/benchmark/test_benchmark_scopes.py::"
+              "test_the_eight_entries_sit_at_the_end_with_the_two_cells")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -82,3 +96,9 @@ def pytest_collection_modifyitems(items):
                 strict=True,
                 reason="digits of the program's int8 arithmetic before PR 41; "
                 "for a `benchmark` PR to re-record (PERF.md 7)"))
+        elif item.nodeid == _PR40_TAIL:
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="per_layer[-8:] pinned to PR 40's eight, outdated by "
+                "any metric appended at the end, where the driver wants it; "
+                "for a `benchmark` PR to loosen (PERF.md 7)"))
