@@ -36,6 +36,7 @@ from docqa_tpu.models.decoder import (
 from docqa_tpu.models.hybrid import (
     ATTENTION,
     HYBRID_BLOCK,
+    MAMBA,
     check_hybrid_config,
     is_hybrid,
 )
@@ -219,10 +220,12 @@ class GenerateEngine:
             use_flash = False
         if is_hybrid(cfg):
             # the batcher over the paged rows and the lane state only; the
-            # scans and the selection are XLA (no Pallas kernel yet), and
-            # the paged decode kernel reads the plain attention layers
+            # linear scan and the selection are XLA, the paged decode
+            # kernel reads the plain attention layers and a state-space
+            # layer's prefill scan has a kernel of its own (ops/ssm.py)
             check_hybrid_config(cfg)
-            use_flash = bool(use_flash) and ATTENTION in cfg.mixer_types
+            use_flash = bool(use_flash) and bool(
+                {ATTENTION, MAMBA} & set(cfg.mixer_types))
         self.use_flash = use_flash
         self._fns = {}
 

@@ -39,6 +39,7 @@ import threading
 from time import monotonic as _mono
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from docqa_tpu.config import DecoderConfig
@@ -696,6 +697,8 @@ def ragged_prefill_forward(
     prefix_lens=None,  # [B] int32 (warm mode): cached tokens per lane
     n_prefix_rows: int = 0,  # static prefix window (warm mode)
     block_size: Optional[int] = None,
+    use_flash: Optional[bool] = None,
+    mesh=None,
 ):
     """Prefill a whole admission round of MIXED-length prompts in one
     dispatch: every token computes through the shared trunk, scatters its
@@ -719,6 +722,12 @@ def ragged_prefill_forward(
 
     The latent block (``cfg.block``) returns a THIRD value, its routing
     record (:func:`_latent_prefill_forward`).
+
+    ``use_flash`` / ``mesh`` are what the engine observed (as
+    :func:`paged_decode_forward` takes them) and reach the one prefill op
+    that has a Pallas form: a state-space layer's scan (``ops/ssm.py``).
+    A caller that observed nothing (``None``: the benchmark's comparison)
+    gets the backend's own answer, as ``GenerateEngine`` derives it.
     """
     if is_latent(cfg):
         if n_prefix_rows:
@@ -738,9 +747,11 @@ def ragged_prefill_forward(
                 "generate.prefix_cache false (a shared prefix is a run of "
                 "pages, and a lane's state at the share boundary is in none)"
             )
+        if use_flash is None:
+            use_flash = jax.default_backend() == "tpu"
         return _hybrid_prefill_forward(
             params, cfg, pools, ids, seg_ids, positions, dest_rows,
-            last_rows, rope_len,
+            last_rows, rope_len, use_flash, mesh,
         )
     warm = n_prefix_rows > 0  # static host int, never a tracer
 
@@ -1042,7 +1053,8 @@ def _write_rows(pools, i, dest, k, v):
 
 
 def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
-                            dest_rows, last_rows, rope_len):
+                            dest_rows, last_rows, rope_len, use_flash=False,
+                            mesh=None):
     """One packed COLD prefill dispatch of the stack of mixer kinds, one
     handler a kind.  A row-keeping layer scatters K and V rows — a SPARSE
     one also the compressed keys of the windows that lie whole inside a
@@ -1051,8 +1063,9 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
     starts from ZERO at each segment's first row and leaves what the
     segment ends with in the lane's entry (found through ``state_slot``
     from the segment's first destination row): a LINEAR layer its chunked
-    scan's state, a state-space layer its scan's state and its last conv
-    inputs.
+    scan's state, a state-space layer its scan's state (the Pallas kernel
+    under ``use_flash`` with no ``mesh``, ``ops/ssm.py``) and its last
+    conv inputs.
 
     Returns (last_logits [B, vocab] f32, pools, selection record int32
     [sparse layers x kv heads, T, sparse_topk] of the packed rows) — two
@@ -1127,7 +1140,7 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
         with scope("state"):
             g, h = selective_scan_prefill(
                 c, delta[0], a, b_in[0], c_out[0], d_skip, seg_ids,
-                positions, last_rows)
+                positions, last_rows, use_flash=use_flash, mesh=mesh)
             pools[f"h{i}"] = pools[f"h{i}"].at[slots].set(h, mode="drop")
             return g[None], None
 

@@ -135,6 +135,7 @@ from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
 from docqa_tpu.ops.attention import RAGGED_ALIGN, paged_kernel_supported
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.ops.scopes import scope
+from docqa_tpu.ops.ssm import scan_kernel_chosen
 from docqa_tpu.resilience import faults
 from docqa_tpu.resilience.deadline import Deadline, DeadlineExceeded
 from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY, get_logger, span
@@ -894,6 +895,10 @@ class ContinuousBatcher:
         self._hybrid = is_hybrid(self.cfg)
         self._selects = bool(sparse_layers(self.cfg))
         self._scan_layers = len(mamba_layers(self.cfg))
+        # those layers' prefill scans run the Pallas kernel (the forward
+        # asks the same of what it is handed: ``_prefill_program``)
+        self._scan_kernel = bool(self._scan_layers) and scan_kernel_chosen(
+            self.engine.use_flash, self.mesh)
         self._state_bytes = lane_state_bytes(self.cfg)  # one lane's
         self._state_slot_np = (
             np.zeros((self.n_blocks * self.block_size,), np.int32)
@@ -958,7 +963,8 @@ class ContinuousBatcher:
         # chunks count choices, a prefill's are not counted
         logits, pools, *_ = ragged_prefill_forward(
             params, self.cfg, pools, ids, seg, pos, dest, last_rows,
-            rope_len=self.seq_capacity, **warm_kw,
+            rope_len=self.seq_capacity, use_flash=self.engine.use_flash,
+            mesh=self.mesh, **warm_kw,
         )
         with scope("sample"):
             toks = sample(
@@ -2695,6 +2701,12 @@ class ContinuousBatcher:
             # prompt tokens x the state-space layers that scanned them
             DEFAULT_REGISTRY.counter("serve_scan_tokens").inc(
                 prefill_tokens * self._scan_layers
+            )
+        if self._scan_kernel:
+            # over ``serve_prefill_dispatches``: 1.0 where every dispatch's
+            # state-space layers scanned in the kernel, absent elsewhere
+            DEFAULT_REGISTRY.counter("serve_scan_kernel_dispatches").inc(
+                len(groups)
             )
         # group-major, like ``ordered``: (slot, req, prompt tokens,
         # shared tokens, what the dispatch that carried it ran)

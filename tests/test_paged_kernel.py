@@ -382,3 +382,35 @@ class TestCompilesForTheChip:
         assert "tpu_custom_call" in exported.mlir_module()
         with pytest.raises(NotImplementedError, match="shard_map"):
             export.export(self._attend(None), platforms=["tpu"])(*args)
+
+    @pytest.mark.parametrize("rows", [512, 9728, 37888], ids=[
+        "warm-up", "one-prompt", "the-comparison"])
+    def test_the_state_space_scan_at_jamba2s_widths(self, topo, rows):
+        """ISSUE 43: ``ops/ssm._selective_scan_kernel`` — a dynamic lane
+        rotate, static lane slices spread over 128 lanes, ``h`` [16, 512]
+        in registers through a chunk — at
+        5,120 channels x 16 states, for the three row counts the cell's
+        process builds (this file holds the one topology fixture)."""
+        import functools
+
+        from jax.sharding import SingleDeviceSharding
+
+        from docqa_tpu.ops import ssm
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        d, n, bf16, i32 = 5120, 16, jnp.bfloat16, jnp.int32
+        compiled = jax.jit(functools.partial(
+            ssm.selective_scan_prefill, use_flash=True)).lower(
+            arg((rows, d), bf16), arg((rows, d), jnp.float32),
+            arg((n, d), jnp.float32), arg((rows, n), bf16),
+            arg((rows, n), bf16), arg((d,), bf16), arg((rows,), i32),
+            arg((rows,), i32), arg((4,), i32)).compile()
+        hlo = compiled.as_text()
+        assert "_selective_scan_kernel" in hlo
+        # nothing of [chunks, rows, n, d] or [rows, n, d] beside the kernel
+        assert f"f32[{rows},{n},{d}]" not in hlo
+        assert f"f32[{rows // 128},128,{n},{d}]" not in hlo

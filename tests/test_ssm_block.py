@@ -189,37 +189,98 @@ def _scan_inputs(t, d=24, n=4, seed=0):
         a=-jnp.exp(f(n, d) * 0.5), b=f(t, n), cc=f(t, n), d_skip=f(d))
 
 
-def _recurrence(x, rows):
-    """Token by token over ``rows`` from a zero state: (g, h)."""
-    h = jnp.zeros((1, *x["a"].shape), jnp.float32)
-    out = []
-    for t in rows:
-        g, h = ssm.selective_scan_step(
-            x["c"][t:t + 1], x["delta"][t:t + 1], x["a"], x["b"][t:t + 1],
-            x["cc"][t:t + 1], x["d_skip"], h)
-        out.append(g[0])
-    return jnp.stack(out), h[0]
+@jax.jit
+def _recurrence_of(x, start, rows):
+    def step(h, xs):
+        c, delta, b, cc, live = xs
+        g, stepped = ssm.selective_scan_step(
+            c[None], delta[None], x["a"], b[None], cc[None], x["d_skip"], h)
+        return jnp.where(live, stepped, h), g[0]
+
+    at = jnp.arange(x["c"].shape[0])
+    h, g = jax.lax.scan(
+        step, jnp.zeros((1, *x["a"].shape), jnp.float32),
+        (x["c"], x["delta"], x["b"], x["cc"],
+         (at >= start) & (at < start + rows)))
+    return g, h[0]
 
 
-def test_the_chunked_scan_equals_the_recurrence_across_chunk_edges():
+def _recurrence(x, start, rows):
+    """``selective_scan_step`` token by token over packed rows ``start ..
+    start + rows`` from a zero state: (g of those rows, h after them)."""
+    g, h = _recurrence_of(x, start, rows)
+    return np.asarray(g[start:start + rows]), np.asarray(h)
+
+
+# the XLA form, and the Pallas kernel a TPU runs (interpreted here)
+SCAN_FORMS = {"xla": {}, "kernel": {"interpret": True}}
+
+
+def _packed(t, segments):
+    """(seg_ids, positions, last_rows) of ``segments`` [(first row,
+    length)] in ``t`` packed rows; padding everywhere else."""
+    seg = np.full(t, -1, np.int32)
+    pos = np.zeros(t, np.int32)
+    for s, (start, n) in enumerate(segments):
+        seg[start:start + n] = s
+        pos[start:start + n] = np.arange(n)
+    last = [start + n - 1 for start, n in segments]
+    return jnp.asarray(seg), jnp.asarray(pos), jnp.asarray(last, jnp.int32)
+
+
+@pytest.mark.parametrize("form", sorted(SCAN_FORMS))
+def test_the_chunked_scan_equals_the_recurrence_across_chunk_edges(form):
     """Two segments in one packed batch: 300 rows (two chunk edges inside)
     from row 0, 130 rows (one edge) from row 384; padding between."""
     t = 640
     x = _scan_inputs(t)
-    seg = np.full(t, -1, np.int32)
-    pos = np.zeros(t, np.int32)
-    for s, (start, n) in enumerate([(0, 300), (384, 130)]):
-        seg[start:start + n] = s
-        pos[start:start + n] = np.arange(n)
-    last = jnp.asarray([299, 384 + 129])
+    segments = [(0, 300), (384, 130)]
     g, h = ssm.selective_scan_prefill(
         x["c"], x["delta"], x["a"], x["b"], x["cc"], x["d_skip"],
-        jnp.asarray(seg), jnp.asarray(pos), last)
-    for s, (start, n) in enumerate([(0, 300), (384, 130)]):
-        want_g, want_h = _recurrence(x, range(start, start + n))
+        *_packed(t, segments), **SCAN_FORMS[form])
+    for s, (start, n) in enumerate(segments):
+        want_g, want_h = _recurrence(x, start, n)
         assert np.abs(np.asarray(g[start:start + n]) - want_g).max() < 2e-4
         assert np.abs(np.asarray(h[s]) - want_h).max() < 2e-4
     assert g.dtype == jnp.float32 and h.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("t, d, n, segments", [
+    (512, 24, 4, [(0, 256), (256, 200)]),
+    (640, 24, 4, [(0, 128), (128, 256), (384, 250)]),
+    (256, 24, 4, [(0, 130)]),
+    (384, 24, 4, [(0, 128), (256, 100)]),
+    (128, 24, 4, [(0, 128)]),
+    (37 * 128, 24, 4, [(0, 2000), (2048, 37 * 128 - 2048 - 5)]),
+    (384, 7 * 128, 8, [(0, 200), (256, 128)]),
+    (384, 128, 16, [(0, 384)]),
+    (256, 200, 4, [(0, 150)]),
+], ids=["two-back-to-back", "three-back-to-back", "last-chunk-mostly-padding",
+        "a-padding-chunk-between", "one-chunk", "thirty-seven-chunks",
+        "seven-blocks-of-channels", "one-block-of-channels",
+        "a-width-that-is-no-whole-lane-tile"])
+def test_the_kernel_alone_equals_the_step_looped(t, d, n, segments):
+    """The Pallas kernel (interpreted) against ``selective_scan_step`` row
+    by row from a zero state: ``g`` over each segment's rows, and the
+    state after each segment's LAST TOKEN (not after the padding that
+    fills its last chunk)."""
+    x = _scan_inputs(t, d=d, n=n, seed=t + d)
+    g, h = ssm.selective_scan_prefill(
+        x["c"], x["delta"], x["a"], x["b"], x["cc"], x["d_skip"],
+        *_packed(t, segments), interpret=True)
+    assert g.shape == (t, d) and h.shape == (len(segments), n, d)
+    assert g.dtype == jnp.float32 and h.dtype == jnp.float32
+    for s, (start, rows) in enumerate(segments):
+        want_g, want_h = _recurrence(x, start, rows)
+        assert np.abs(np.asarray(g[start:start + rows]) - want_g).max() < 2e-4
+        assert np.abs(np.asarray(h[s]) - want_h).max() < 2e-4
+
+
+def test_the_kernels_blocks_are_whole_lane_tiles_where_the_width_has_them():
+    assert ssm._scan_block(5120) == 512  # Jamba2-3B: ten blocks a chunk
+    assert ssm._scan_block(7 * 128) == 128
+    assert ssm._scan_block(128) == 128
+    assert ssm._scan_block(24) == 24  # a toy: the array itself
 
 
 def test_the_conv_reads_nothing_before_a_segments_first_row():
@@ -293,17 +354,18 @@ BF16 = dataclasses.replace(TOY, dtype="bfloat16", max_seq_len=256)
 COUNTERS = (
     "serve_state_lane_steps", "serve_state_bytes_rw",
     "serve_lane_state_resets", "serve_scan_tokens", "serve_prefill_tokens",
-    "serve_sparse_blocks_selected",
+    "serve_sparse_blocks_selected", "serve_scan_kernel_dispatches",
+    "serve_prefill_dispatches",
 )
 
 
-def _batcher(n_slots, params, **kw):
+def _batcher(n_slots, params, use_flash=None, **kw):
     from docqa_tpu.engines.serve import ContinuousBatcher
 
     gen = dataclasses.replace(
         GenerateConfig(), speculative_k=0, prefix_cache=False, decode_chunk=4,
         max_concurrent=n_slots)
-    engine = GenerateEngine(BF16, gen=gen, params=params)
+    engine = GenerateEngine(BF16, gen=gen, params=params, use_flash=use_flash)
     return ContinuousBatcher(engine, n_slots=n_slots, chunk=4, cache_len=256,
                              kv_block_size=16, prefix_cache=False, **kw)
 
@@ -351,6 +413,9 @@ def test_a_slot_a_longer_lane_left_starts_from_zeros_and_the_counters_count():
     assert steps >= sum(len(g) - 1 for g in got) > 0
     assert gained["serve_state_bytes_rw"] == steps * 2 * state_bytes
     assert gained["serve_sparse_blocks_selected"] == 0
+    # a CPU: the XLA form scanned, and the kernel's counter stays still
+    assert gained["serve_prefill_dispatches"] > 0
+    assert gained["serve_scan_kernel_dispatches"] == 0
     for prompt, toks in zip(prompts, got):
         fresh = _batcher(1, served_params)
         try:
@@ -359,6 +424,36 @@ def test_a_slot_a_longer_lane_left_starts_from_zeros_and_the_counters_count():
         finally:
             fresh.stop()
         assert list(alone) == list(toks)
+
+
+def test_the_batcher_counts_the_dispatches_that_scanned_in_the_kernel(
+        monkeypatch):
+    """An engine that saw a TPU (``use_flash``): every prefill dispatch's
+    state-space layers scan in the kernel — interpreted here, the one
+    thing a CPU cannot take from it — and the counter over
+    ``serve_prefill_dispatches`` reads 1.0; the tokens are the XLA form's."""
+    real = ssm._scan_rows_in_order
+    monkeypatch.setattr(
+        ssm, "_scan_rows_in_order",
+        lambda *args, interpret: real(*args, interpret=True))
+    served_params = PACKAGE.weights.make_decoder_params(BF16, 3)
+    prompts = [[5 + (11 * i + j) % 250 for j in range(140 - 50 * i)]
+               for i in range(2)]
+    got = {}
+    for flash in (True, False):
+        before = _counters()
+        b = _batcher(2, served_params, use_flash=flash)
+        try:
+            assert b._scan_kernel == flash
+            got[flash] = [list(h.result(timeout=600)) for h in
+                          [b.submit_ids(p, max_new_tokens=6) for p in prompts]]
+        finally:
+            b.stop()
+        gained = {k: v - before[k] for k, v in _counters().items()}
+        assert gained["serve_prefill_dispatches"] > 0
+        assert gained["serve_scan_kernel_dispatches"] == (
+            gained["serve_prefill_dispatches"] if flash else 0)
+    assert got[True] == got[False]
 
 
 # ---- types, bytes and counts by hand ----------------------------------------
@@ -506,15 +601,20 @@ def test_the_solo_engine_refuses_the_stack_by_name(params):
 
 def test_the_engine_keeps_the_paged_kernel_for_a_stack_with_plain_attention(
         params):
-    """``use_flash`` reaches the plain attention layers' decode and
-    nothing else of the stack; a stack without them has no use for it."""
+    """``use_flash`` reaches the plain attention layers' decode and the
+    state-space layers' prefill scan (ISSUE 43) and nothing else of the
+    stack; a stack with neither kind has no use for it."""
     gen = GenerateConfig()
     assert GenerateEngine(TOY, gen=gen, params=params, use_flash=True).use_flash
-    sala_like = dataclasses.replace(
+    scans_only = dataclasses.replace(
         TOY, mixer_types=("mamba",) * 4)
+    assert GenerateEngine(
+        scans_only, gen=gen, use_flash=True,
+        params=PACKAGE.weights.make_decoder_params(scans_only, 1)).use_flash
+    sala = arch.load({"architecture": "minicpm_sala"})
     assert not GenerateEngine(
-        sala_like, gen=gen, use_flash=True,
-        params=PACKAGE.weights.make_decoder_params(sala_like, 1)).use_flash
+        SALA_TOY, gen=gen, use_flash=True,
+        params=sala.weights.make_decoder_params(SALA_TOY, 1)).use_flash
 
 
 # ---- through the harness's own comparison ------------------------------------
@@ -571,40 +671,54 @@ SALA_TOY = DecoderConfig(
 )
 
 
-@pytest.fixture(scope="module")
-def sala_lowered():
+def _batcher_programs_lowered(package, cfg, use_flash):
+    """{"prefill", "decode"}: lowered text of a toy batcher's two programs
+    for ``cfg``; under ``use_flash`` cross-lowered for a TPU (a Mosaic
+    kernel does not lower for the CPU)."""
     from docqa_tpu.engines.serve import ContinuousBatcher
 
-    package = arch.load({"architecture": "minicpm_sala"})
     sds, i32 = jax.ShapeDtypeStruct, jnp.int32
     gen = dataclasses.replace(
         GenerateConfig(), speculative_k=0, prefix_cache=False,
         max_concurrent=4, decode_chunk=4)
+    engine = GenerateEngine(
+        cfg, gen=gen, use_flash=use_flash,
+        params=package.weights.make_decoder_params(cfg, 1))
+    b = ContinuousBatcher(engine, n_slots=4, chunk=4, cache_len=256,
+                          kv_block_size=16, prefix_cache=False)
+    try:
+        pools = jax.eval_shape(lambda: paged.init_paged_pools(
+            b.cfg, b.n_blocks, b.block_size, n_lanes=b.n_slots))
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), engine.params)
+        rng = sds((2,), jnp.uint32)
+        lane, flag = sds((4,), i32), sds((4,), jnp.bool_)
+        packed = (sds((256,), i32),) * 4 + (lane,) * 2
+        tables = sds((4, b.blocks_per_seq), i32)
+        platforms = ("tpu" if use_flash else "cpu",)
+        return {
+            "prefill": b._get_prefill_fn().trace(
+                params, pools, *packed, rng).lower(
+                lowering_platforms=platforms).as_text(),
+            "decode": b._get_decode_fn().trace(
+                params, pools, tables, lane, lane, lane, flag, rng).lower(
+                lowering_platforms=platforms).as_text(),
+        }
+    finally:
+        b.stop()
+
+
+@pytest.fixture(scope="module")
+def sala_lowered():
+    package = arch.load({"architecture": "minicpm_sala"})
     out = {}
     for kind, cfg in (
             ("float32", SALA_TOY),
             ("bf16_int8", dataclasses.replace(
                 SALA_TOY, dtype="bfloat16", quantize_weights=True))):
-        engine = GenerateEngine(
-            cfg, gen=gen, use_flash=False,
-            params=package.weights.make_decoder_params(cfg, 1))
-        b = ContinuousBatcher(engine, n_slots=4, chunk=4, cache_len=256,
-                              kv_block_size=16, prefix_cache=False)
-        try:
-            pools = jax.eval_shape(lambda: paged.init_paged_pools(
-                b.cfg, b.n_blocks, b.block_size, n_lanes=b.n_slots))
-            params = jax.tree_util.tree_map(
-                lambda a: sds(a.shape, a.dtype), engine.params)
-            rng = sds((2,), jnp.uint32)
-            lane, flag = sds((4,), i32), sds((4,), jnp.bool_)
-            packed = (sds((256,), i32),) * 4 + (lane,) * 2
-            tables = sds((4, b.blocks_per_seq), i32)
-            out[kind, "prefill"] = b._get_prefill_fn().lower(
-                params, pools, *packed, rng).as_text()
-            out[kind, "decode"] = b._get_decode_fn().lower(
-                params, pools, tables, lane, lane, lane, flag, rng).as_text()
-        finally:
-            b.stop()
+        for program, text in _batcher_programs_lowered(
+                package, cfg, False).items():
+            out[kind, program] = text
     return out
 
 
@@ -618,3 +732,86 @@ def test_salas_programs_lower_to_the_text_they_lowered_to(
     digest, length = SALA_LOWERED_BEFORE[program]
     assert len(text) == length
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# ---- who scans: the kernel under ``use_flash``, the XLA form otherwise ------
+
+# sha256 (first 16 hex digits) and length of the lowered text of the toy
+# Jamba batcher programs on the parent commit b1630a0 (jax 0.9.0, CPU),
+# recorded before ISSUE 43 touched ``ops/ssm.py``: the decode program does
+# not move whatever ``use_flash`` says (``selective_scan_step`` is not
+# touched; at 16-wide heads the paged kernel is refused, so its text holds
+# no kernel either way), and the prefill without ``use_flash`` still
+# lowers the XLA form as it stood.
+JAMBA_LOWERED_BEFORE = {
+    ("float32", "prefill"): ("8fcfb247c2f4b909", 204322),
+    ("float32", "decode"): ("d537244f293a18b0", 120176),
+    ("bfloat16", "prefill"): ("3ce99c270df9f737", 217973),
+    ("bfloat16", "decode"): ("6617afac339bd5a9", 131355),
+}
+KERNEL = "_selective_scan_kernel"
+KERNEL_CALL = "call @_scan_rows_in_order"
+
+
+@pytest.fixture(scope="module")
+def jamba_lowered():
+    """{(kind, program, use_flash): lowered text} of the toy batcher's two
+    programs."""
+    out = {}
+    for kind, cfg in (
+            ("float32", dataclasses.replace(TOY, max_seq_len=256)),
+            ("bfloat16", BF16)):
+        for flash in (False, True):
+            for program, text in _batcher_programs_lowered(
+                    PACKAGE, cfg, flash).items():
+                out[kind, program, flash] = text
+    return out
+
+
+@pytest.mark.parametrize("use_flash", [False, True], ids=["xla", "flash"])
+@pytest.mark.parametrize(
+    "program", sorted(JAMBA_LOWERED_BEFORE), ids="-".join)
+def test_jambas_programs_lower_to_the_text_they_lowered_to(
+        jamba_lowered, program, use_flash):
+    """The decode program whatever ``use_flash`` says, and the prefill
+    without it, are the parent's to the byte; the prefill under it is
+    another program: one kernel, lowered once, called a state-space layer."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded text is jax 0.9.0's")
+    text = jamba_lowered[(*program, use_flash)]
+    digest, length = JAMBA_LOWERED_BEFORE[program]
+    if use_flash and program[1] == "prefill":
+        assert text.count(KERNEL_CALL) == len(hybrid.mamba_layers(TOY)) == 3
+        assert text.count("tpu_custom_call") == 1 and KERNEL in text
+        return
+    assert KERNEL not in text
+    assert len(text) == length
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+@pytest.mark.parametrize("use_flash, meshed, calls", [
+    (True, False, 3), (False, False, 0), (None, False, 0), (True, True, 0),
+], ids=["flash", "no-flash", "nothing-observed-on-a-cpu", "flash-on-a-mesh"])
+def test_the_forward_scans_in_the_kernel_only_under_flash_and_no_mesh(
+        params, use_flash, meshed, calls):
+    """``ragged_prefill_forward`` hands ``use_flash`` and ``mesh`` to the
+    scan as it hands them to nothing else: the kernel a state-space layer
+    under ``use_flash`` with no mesh, the XLA form otherwise.  Handed
+    nothing (the harness's comparison) it asks the backend — a CPU here."""
+    from docqa_tpu.runtime.mesh import host_cpu_mesh
+
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    mesh = host_cpu_mesh(2) if meshed else None
+    assert ssm.scan_kernel_chosen(use_flash, mesh) == bool(calls)
+    pools = jax.eval_shape(lambda: paged.init_paged_pools(TOY, 32, BS))
+
+    def prefill(params, pools, ids, seg, pos, dest, last):
+        return paged.ragged_prefill_forward(
+            params, TOY, pools, ids, seg, pos, dest, last, rope_len=CAP,
+            use_flash=use_flash, mesh=mesh)
+
+    text = jax.jit(prefill).trace(
+        params, pools, *(sds((256,), i32),) * 4, sds((2,), i32)
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count(KERNEL_CALL) == calls
+    assert (KERNEL in text) == bool(calls)
