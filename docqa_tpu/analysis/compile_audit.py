@@ -63,7 +63,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from docqa_tpu.utils import compiled_memory_stats as memory_of
 
 WORKLOADS = (
-    "serve", "serve_latent", "serve_hybrid", "serve_ssm", "generate", "retrieve_fused", "seq2seq",
+    "serve", "serve_latent", "serve_hybrid", "serve_ssm", "serve_loop", "generate", "retrieve_fused", "seq2seq",
     "encoder",
 )
 
@@ -222,7 +222,7 @@ def _audit_encoder_cfg():
 
 
 def _audit_serve(latent: bool = False, hybrid: bool = False,
-                 ssm: bool = False) -> Dict[str, Any]:
+                 ssm: bool = False, looped: bool = False) -> Dict[str, Any]:
     """The PAGED batcher's whole compile surface: one ragged prefill
     program per packed token budget (<= 2) plus the one block-table
     decode chunk — the collapse from the pre-paged (2 shape families x
@@ -242,7 +242,12 @@ def _audit_serve(latent: bool = False, hybrid: bool = False,
     sums.  ``ssm``: the same stack's other two kinds (workload
     ``serve_ssm``, a state-space and a plain attention layer): a prefill
     that convolves and scans, a decode chunk that advances windows and
-    states and carries no sums."""
+    states and carries no sums.
+
+    ``looped``: the GQA block's looped trunk (workload ``serve_loop``:
+    ``loop_steps`` 4 with the sandwich norms): cold prefill budgets and a
+    decode chunk whose ``while`` nests the step loop over pools of four
+    ranges; no warm family, no speculation (refused by name)."""
     import dataclasses
 
     import jax
@@ -258,6 +263,9 @@ def _audit_serve(latent: bool = False, hybrid: bool = False,
         gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
     if hybrid or ssm:
         cfg = _audit_ssm_cfg() if ssm else _audit_hybrid_cfg()
+        gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
+    if looped:
+        cfg = dataclasses.replace(cfg, loop_steps=4, sandwich_norm=True)
         gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
     engine = GenerateEngine(cfg, gen)
     # cache_len 256: large enough that the 128-aligned prefix cache is
@@ -423,9 +431,10 @@ def _audit_serve(latent: bool = False, hybrid: bool = False,
         }
         if not batcher.prefix_cache_enabled:
             del report["roots"]["serve_prefill_warm"]
-        if latent or hybrid or ssm:
+        if latent or hybrid or ssm or looped:
             prefix = ("serve_latent_" if latent else
-                      "serve_ssm_" if ssm else "serve_hybrid_")
+                      "serve_ssm_" if ssm else
+                      "serve_loop_" if looped else "serve_hybrid_")
             report["roots"] = {
                 name.replace("serve_", prefix): root
                 for name, root in report["roots"].items()
@@ -596,6 +605,7 @@ _AUDITS = {
     "serve_latent": functools.partial(_audit_serve, latent=True),
     "serve_hybrid": functools.partial(_audit_serve, hybrid=True),
     "serve_ssm": functools.partial(_audit_serve, ssm=True),
+    "serve_loop": functools.partial(_audit_serve, looped=True),
     "generate": _audit_generate,
     "retrieve_fused": _audit_retrieve,
     "seq2seq": _audit_seq2seq,

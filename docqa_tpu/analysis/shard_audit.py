@@ -71,6 +71,8 @@ AUDIT_PROGRAMS = (
     "hybrid_ragged_prefill",
     "ssm_paged_decode",
     "ssm_ragged_prefill",
+    "loop_paged_decode",
+    "loop_ragged_prefill",
     "ring_attention",
     "ulysses_attention",
     "retrieve_fused",
@@ -326,7 +328,8 @@ def _audit_decoder(mesh_name: str, prefill: bool, pspec_fn=None):
 
 
 def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
-                 hybrid: bool = False, ssm: bool = False):
+                 hybrid: bool = False, ssm: bool = False,
+                 looped: bool = False):
     """Lower the PAGED serving programs (engines/paged.py) under the
     same Megatron layout: the block-pool gather/scatter must not change
     the collective story — still exactly one all-reduce per Megatron
@@ -348,7 +351,15 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     slot map — replicated): lowered on every mesh, collectives recorded.
     ``ssm``: the same stack with a state-space and a plain attention layer
     (the state-space mixer divided along its inner channels, windows and
-    states replicated with the rows of the one kv head)."""
+    states replicated with the rows of the one kv head).
+
+    ``looped``: the GQA block's looped trunk (``loop_steps`` 4 with the
+    sandwich norms): the step loop is ONE loop in the program, so the
+    text holds each Megatron block's all-reduce once — the same count as
+    the plain trunk, and held to it — and the pools, four ranges of rows
+    along their unsharded row axis, keep their kv heads over ``model``."""
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -364,6 +375,8 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     )
 
     cfg = _audit_latent_cfg() if latent else _audit_decoder_cfg()
+    if looped:
+        cfg = dataclasses.replace(cfg, loop_steps=4, sandwich_norm=True)
     hybrid = hybrid or ssm
     if hybrid:
         cfg = _audit_ssm_cfg() if ssm else _audit_hybrid_cfg()
@@ -378,7 +391,7 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
         for i in range(cfg.num_layers)
         for kv, (heads, width) in kv_row_shapes(cfg).items()
     }
-    if hybrid:
+    if hybrid or looped:
         from docqa_tpu.engines.paged import init_paged_pools
 
         pools = jax.eval_shape(
@@ -450,6 +463,8 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     if hybrid:
         del meta["megatron_blocks"]
         meta["mixer_types"] = list(cfg.mixer_types)
+    if looped:
+        meta["loop_steps"] = cfg.loop_steps
     return counts, meta
 
 
@@ -771,6 +786,12 @@ _AUDITS: Dict[str, Callable[[str], Tuple[Dict[str, int], Dict[str, Any]]]] = {
     "ssm_ragged_prefill": functools.partial(
         _audit_paged, prefill=True, ssm=True
     ),
+    "loop_paged_decode": functools.partial(
+        _audit_paged, prefill=False, looped=True
+    ),
+    "loop_ragged_prefill": functools.partial(
+        _audit_paged, prefill=True, looped=True
+    ),
     "ring_attention": _audit_ring,
     "ulysses_attention": _audit_ulysses,
     "retrieve_fused": _audit_retrieve,
@@ -862,6 +883,8 @@ def semantic_violations(report: Dict[str, Any]) -> List[str]:
         "decoder_prefill",
         "decoder_paged_decode",
         "decoder_ragged_prefill",
+        "loop_paged_decode",
+        "loop_ragged_prefill",
     ):
         prog = progs.get(name)
         if not prog:

@@ -245,6 +245,20 @@ class DecoderConfig:
     ssm_expand: int = 2
     ssm_conv_bias: bool = True
     ssm_proj_bias: bool = False
+    # ---- a looped trunk ("gqa_swiglu" alone reads these) -----------------
+    # ``loop_steps`` T > 1: the whole stack of ``num_layers`` layers runs
+    # T times over the SAME parameters (arXiv:2510.25741); the final norm
+    # closes EVERY step and its output enters the next; a token keeps K
+    # and V per (step, layer) — ``models/decoder.kv_entries`` — and the
+    # tree holds an exit gate (``exit_gate_w`` [hidden, 1], ``exit_gate_b``
+    # [1]) that ``loop_exit_threshold`` 1.0 never evaluates (no step exits
+    # early; a value under 1 is refused: a depth that differs by lane
+    # needs a scheduler).  ``sandwich_norm``: a norm on each sublayer's
+    # OUTPUT before its residual add, beside the two pre-norms.  At the
+    # defaults the block's programs are what they were.
+    loop_steps: int = 1
+    sandwich_norm: bool = False
+    loop_exit_threshold: float = 1.0
 
     @staticmethod
     def mistral_7b() -> "DecoderConfig":

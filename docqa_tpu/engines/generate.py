@@ -29,6 +29,7 @@ from docqa_tpu.config import DecoderConfig, GenerateConfig
 from docqa_tpu.models.decoder import (
     KVCache,
     Params,
+    check_loop_config,
     decoder_forward,
     init_decoder_params,
     init_kv_cache,
@@ -161,6 +162,7 @@ class GenerateEngine:
             self._chat_template: Optional[str] = resolved
         else:
             self._chat_template = None
+        check_loop_config(cfg)  # before a tree is drawn for it
         if params is None:
             if cfg.quantize_weights:
                 from docqa_tpu.models.quant import (
@@ -471,6 +473,13 @@ class GenerateEngine:
                 "generate through the batcher "
                 "(engines/serve.ContinuousBatcher), which serves it over the "
                 "paged rows and the lane state"
+            )
+        if self.cfg.loop_steps > 1:
+            raise NotImplementedError(
+                "the solo dense-cache engine runs loop_steps 1 only (got "
+                f"{self.cfg.loop_steps}): generate through the batcher "
+                "(engines/serve.ContinuousBatcher), which serves the looped "
+                "trunk over the paged cache, an entry a (step, layer)"
             )
         spec_k = self.gen.speculative_k
         if greedy and spec_k >= 2:

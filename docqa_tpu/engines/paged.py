@@ -47,6 +47,7 @@ from docqa_tpu.models.decoder import (
     Params,
     decoder_head,
     decoder_layer_stack,
+    kv_entries,
     kv_row_shapes,
     lane_state_shapes,
 )
@@ -643,6 +644,12 @@ def init_paged_pools(
     consecutive rows, one contiguous page the TPU decode kernel DMAs as a
     whole (``ops/attention.paged_flash_decode``).
 
+    Under the looped trunk (``models/decoder.kv_entries`` T > 1) a layer's
+    pool holds T such ranges one after the other, ``[T * n_blocks *
+    block_size, ...]``: step ``t`` reads and writes block ``b`` at page
+    ``t * n_blocks + b`` (:func:`_step_view`), so a block id stays ONE
+    allocation that owns its rows in every step's range.
+
     ``sharding`` (``parallel.sharding.paged_pool_sharding`` on a mesh):
     each pool is CREATED under it — every device zero-fills only its own
     kv-head slice, nothing pool-sized is staged on one device first.  The
@@ -656,12 +663,33 @@ def init_paged_pools(
         return _init_hybrid_pools(
             cfg, n_blocks, block_size, dtype, sharding, n_lanes)
     pools: PagedPools = {}
+    rows = kv_entries(cfg) * n_blocks * block_size
     for i in range(cfg.num_layers):
         for prefix, (heads, width) in kv_row_shapes(cfg).items():
             pools[f"{prefix}{i}"] = jnp.zeros(
-                (n_blocks * block_size, heads, width), dtype, device=sharding
+                (rows, heads, width), dtype, device=sharding
             )
     return pools
+
+
+def _step_view(cfg, step, n_rows, dest, block_tables=None, block_size=None):
+    """Where step ``step`` of the looped trunk writes and reads: ``dest``
+    (flat pool rows) and ``block_tables`` (block ids) moved into the
+    step's own range of a pool that holds ``kv_entries(cfg)`` ranges of
+    ``n_rows`` rows.  A hole stays a hole: a row or a block id past ONE
+    range (a dropped write, an unallocated or retired table entry) goes
+    past the WHOLE pool, never into the next step's range.  ``step`` None
+    (the plain trunk): both as they came."""
+    if step is None:
+        return dest, block_tables
+    total = kv_entries(cfg) * n_rows
+    dest = jnp.where(dest < n_rows, dest + step * n_rows, total)
+    if block_tables is not None:
+        n_blocks = n_rows // block_size
+        block_tables = jnp.where(
+            block_tables < n_blocks, block_tables + step * n_blocks,
+            total // block_size)
+    return dest, block_tables
 
 
 def kv_bytes_per_token(cfg: DecoderConfig) -> int:
@@ -671,7 +699,9 @@ def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     mixer kinds: the K and V rows of its row-keeping layers (sparse,
     attention), plus a SPARSE layer's share of a compressed key (one per
     ``sparse_kernel_stride`` tokens); its state-keeping layers (linear,
-    state-space) keep nothing a token (``models/hybrid.lane_state_bytes``)."""
+    state-space) keep nothing a token (``models/hybrid.lane_state_bytes``).
+    The looped trunk: an entry a (step, layer), ``kv_entries`` times a
+    plain model's."""
     item = jnp.dtype(cfg.dtype).itemsize
     per_layer = sum(h * w for h, w in kv_row_shapes(cfg).values())
     if is_hybrid(cfg):
@@ -679,7 +709,7 @@ def kv_bytes_per_token(cfg: DecoderConfig) -> int:
         return item * (
             len(layers_of(cfg, SPARSE, ATTENTION)) * per_layer
             + len(sparse_layers(cfg)) * (key // cfg.sparse_kernel_stride))
-    return cfg.num_layers * per_layer * item
+    return kv_entries(cfg) * cfg.num_layers * per_layer * item
 
 
 def ragged_prefill_forward(
@@ -754,15 +784,23 @@ def ragged_prefill_forward(
             last_rows, rope_len, use_flash, mesh,
         )
     warm = n_prefix_rows > 0  # static host int, never a tracer
+    if warm and kv_entries(cfg) > 1:
+        raise NotImplementedError(
+            "the looped trunk (loop_steps > 1) prefills cold only: set "
+            "generate.prefix_cache false (a warm prefill through the "
+            "steps' ranges is untested)"
+        )
+    n_rows = pools["k0"].shape[0] // kv_entries(cfg)
 
-    def attend(i, q, k, v):
+    def attend(i, q, k, v, step=None):
         with scope("cache_write"):
+            dest, _ = _step_view(cfg, step, n_rows, dest_rows)
             kp = pools[f"k{i}"]
-            pools[f"k{i}"] = kp.at[dest_rows].set(
+            pools[f"k{i}"] = kp.at[dest].set(
                 k[0].astype(kp.dtype), mode="drop"
             )
             vp = pools[f"v{i}"]
-            pools[f"v{i}"] = vp.at[dest_rows].set(
+            pools[f"v{i}"] = vp.at[dest].set(
                 v[0].astype(vp.dtype), mode="drop"
             )
         # attention over the packed batch itself (cold: every KV row a
@@ -783,7 +821,8 @@ def ragged_prefill_forward(
             )[None]
 
     x = decoder_layer_stack(
-        params, cfg, ids[None, :], positions[None, :], rope_len, attend
+        params, cfg, ids[None, :], positions[None, :], rope_len, attend,
+        cache=pools,
     )
     with scope("head"):
         x_last = x[0][last_rows]  # [B, hidden]
@@ -832,7 +871,7 @@ def paged_decode_forward(
         )
     S, s = tok.shape
     nb = block_tables.shape[1]
-    P = pools["k0"].shape[0]
+    P = pools["k0"].shape[0] // kv_entries(cfg)  # rows of ONE step's range
     n_blocks = P // block_size
 
     with scope("cache_write"):
@@ -849,23 +888,26 @@ def paged_decode_forward(
     rope_pos = jnp.minimum(pos, rope_len - 1)
     attn_lengths = lengths + s
 
-    def attend(i, q, k, v):
+    def attend(i, q, k, v, step=None):
         with scope("cache_write"):
+            rows, tables = _step_view(
+                cfg, step, P, dest, block_tables, block_size)
             kp = pools[f"k{i}"]
-            pools[f"k{i}"] = kp.at[dest].set(
+            pools[f"k{i}"] = kp.at[rows].set(
                 k.astype(kp.dtype), mode="drop")
             vp = pools[f"v{i}"]
-            pools[f"v{i}"] = vp.at[dest].set(
+            pools[f"v{i}"] = vp.at[rows].set(
                 v.astype(vp.dtype), mode="drop")
         with scope("attend"):
             return paged_decode_attention(
-                q, pools[f"k{i}"], pools[f"v{i}"], block_tables,
+                q, pools[f"k{i}"], pools[f"v{i}"], tables,
                 attn_lengths, block_size=block_size, q_offset=lengths,
                 sliding_window=cfg.sliding_window, use_flash=use_flash,
                 mesh=mesh,
             )
 
-    x = decoder_layer_stack(params, cfg, tok, rope_pos, rope_len, attend)
+    x = decoder_layer_stack(
+        params, cfg, tok, rope_pos, rope_len, attend, cache=pools)
     logits = decoder_head(params, cfg, x)
     return logits, pools
 

@@ -123,6 +123,7 @@ from docqa_tpu.engines.qos import QoSPolicy, request_class
 from docqa_tpu.engines.spine import spine_run, spine_submit
 from docqa_tpu.models.decoder import (
     init_decoder_params,  # noqa: F401  (re-export convenience for tests)
+    kv_entries,
     kv_row_shapes,
 )
 from docqa_tpu.models.hybrid import (
@@ -165,6 +166,17 @@ SPARSE_SUMS = (
     "serve_sparse_blocks_selected", "serve_sparse_blocks_live",
     "serve_sparse_dense_lane_steps", "serve_state_lane_steps",
 )
+
+
+def _refuse_unserved(what: str, settings, advice: str) -> None:
+    """``ValueError`` naming every setting of ``settings`` ((name, on)
+    pairs) that is on and that ``what`` is not served with."""
+    unserved = [name for name, on in settings if on]
+    if unserved:
+        raise ValueError(
+            f"{what} is served without " + " and ".join(unserved)
+            + ": " + advice
+        )
 
 
 @dataclass
@@ -732,39 +744,44 @@ class ContinuousBatcher:
             # latent block prefills cold only (a warm prefill would
             # up-project cached rows, which no path does) and its
             # speculative chunk would drop the routing record
-            unserved = [
-                name for name, on in (
-                    ("generate.prefix_cache", want_cache),
-                    ("generate.speculative_k", self.spec_k),
-                ) if on
-            ]
-            if unserved:
-                raise ValueError(
-                    f'DecoderConfig(block="{self.cfg.block}") is served '
-                    "without " + " and ".join(unserved)
-                    + ": set prefix_cache false and speculative_k 0"
-                )
+            _refuse_unserved(
+                f'DecoderConfig(block="{self.cfg.block}")',
+                (("generate.prefix_cache", want_cache),
+                 ("generate.speculative_k", self.spec_k)),
+                "set prefix_cache false and speculative_k 0",
+            )
         if is_hybrid(self.cfg):
             # refused at construction, by name: a shared prefix is a run of
             # pages and a lane's state at the share boundary is in none of
             # them; a verify step of several tokens would need the state
             # after each; and a preempted lane resumes from pages alone
             policy = QoSPolicy.coerce(qos)
-            unserved = [
-                name for name, on in (
-                    ("generate.prefix_cache", want_cache),
-                    ("generate.speculative_k", self.spec_k),
-                    ("qos.preemption", policy is not None
-                     and policy.preemption != "off"),
-                ) if on
-            ]
-            if unserved:
-                raise ValueError(
-                    f'DecoderConfig(block="{self.cfg.block}") is served '
-                    "without " + " and ".join(unserved)
-                    + ": set prefix_cache false, speculative_k 0 and "
-                    "qos.preemption off"
-                )
+            _refuse_unserved(
+                f'DecoderConfig(block="{self.cfg.block}")',
+                (("generate.prefix_cache", want_cache),
+                 ("generate.speculative_k", self.spec_k),
+                 ("qos.preemption", policy is not None
+                  and policy.preemption != "off")),
+                "set prefix_cache false, speculative_k 0 and "
+                "qos.preemption off",
+            )
+        # the looped trunk (``loop_steps`` > 1): what one token costs, a
+        # gauge; what a chunk ran, two counters (``_count_loop_passes``);
+        # all absent at 1
+        self._loop_steps = kv_entries(self.cfg)
+        # on a request's ``serve_prefill`` and ``serve_decode_chunk`` spans
+        self._loop_attrs = (
+            {"loop_steps": self._loop_steps} if self._loop_steps > 1 else {}
+        )
+        if self._loop_steps > 1:
+            # refused at construction, by name: a warm prefill and a verify
+            # step through the steps' ranges of the pools are untested
+            _refuse_unserved(
+                f"DecoderConfig(loop_steps={self._loop_steps})",
+                (("generate.prefix_cache", want_cache),
+                 ("generate.speculative_k", self.spec_k)),
+                "set prefix_cache false and speculative_k 0",
+            )
         if want_cache and self._share_align < self.seq_capacity:
             self._prefix_cache = PrefixCache(
                 self._alloc, self._share_align,
@@ -1996,6 +2013,8 @@ class ContinuousBatcher:
             {"state_bytes_per_lane": self._state_bytes}
             if self._hybrid else {}
         )
+        if self._loop_steps > 1:  # gauge ``serve_loop_steps``
+            state["loop_steps"] = self._loop_steps
         out = {
             **state,
             "blocks_total": self.n_blocks,
@@ -2729,6 +2748,7 @@ class ContinuousBatcher:
                     shared_tokens=shared, budget_tokens=T,
                     packed_tokens=rows,
                     **self._hybrid_prefill_attrs(len(ids), len(good)),
+                    **self._loop_attrs,
                 )
                 meta.append((
                     slot, req, len(ids), shared,
@@ -3062,6 +3082,7 @@ class ContinuousBatcher:
             _req_span(
                 req, "serve_decode_chunk", t_fetch0, t_fetch1,
                 slot=slot, tokens=len(req.tokens) - before,
+                **self._loop_attrs,
             )
             _cost_add(req, "decode_tokens", len(req.tokens) - before)
             if len(req.tokens) > before:  # wake streamers per chunk
@@ -3116,6 +3137,8 @@ class ContinuousBatcher:
             # no sums row rides this stack's chunks: a lane-step is a
             # position a lane advanced, which the host holds
             self._count_state_steps(sum(adv for _, adv in lanes))
+        if self._loop_steps > 1:
+            self._count_loop_passes(sum(adv for _, adv in lanes))
         rows_read, rows_live = self._chunk_kv_rows(lanes)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_read").inc(rows_read)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_live").inc(rows_live)
@@ -3171,10 +3194,22 @@ class ContinuousBatcher:
             2 * self._state_bytes * lane_steps
         )
 
+    def _count_loop_passes(self, lane_steps: int) -> None:
+        """``lane_steps`` positions the lanes of a fetched chunk advanced
+        under the looped trunk, and the passes of the stack they took:
+        ``loop_steps`` each while no step exits early — host arithmetic,
+        as ``_count_state_steps`` is.  Their ratio is the passes a token."""
+        DEFAULT_REGISTRY.counter("serve_loop_lane_steps").inc(lane_steps)
+        DEFAULT_REGISTRY.counter("serve_loop_passes").inc(
+            lane_steps * self._loop_steps
+        )
+
     def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
-        """(KV rows fetched, KV rows live) PER LAYER over one chunk's
-        steps, as the decode program is built — host arithmetic on what
-        ``_process_chunk`` holds, no device fetch.
+        """(KV rows fetched, KV rows live) PER CACHE ENTRY — a layer's; a
+        (step, layer)'s under the looped trunk, whose every pass reads its
+        own entry over the same lengths, so the two stay a ratio — over
+        one chunk's steps, as the decode program is built — host
+        arithmetic on what ``_process_chunk`` holds, no device fetch.
 
         ``lanes``: per lane of the chunk's snapshot, (KV length at
         dispatch, positions it advanced).  A step attends ``width`` new

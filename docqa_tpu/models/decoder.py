@@ -78,6 +78,52 @@ def decoder_param_schema(cfg: DecoderConfig):
         yield (f"l{i}_w_gate", "normal", (h, cfg.mlp_dim), h)
         yield (f"l{i}_w_up", "normal", (h, cfg.mlp_dim), h)
         yield (f"l{i}_w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
+    # behind every entry the plain block has, so that its draws keep
+    # their place in the stream
+    if cfg.sandwich_norm:
+        for i in range(cfg.num_layers):
+            yield (f"l{i}_attn_post_norm_g", "ones", (h,), None)
+            yield (f"l{i}_mlp_post_norm_g", "ones", (h,), None)
+    if cfg.loop_steps > 1:
+        yield ("exit_gate_w", "normal", (h, 1), h)
+        yield ("exit_gate_b", "normal", (1,), h)
+
+
+def check_loop_config(cfg: DecoderConfig) -> None:
+    """Refuse, by field, what the looped trunk cannot run.  The three
+    fields belong to the ``gqa_swiglu`` block; the other blocks' trunks
+    do not read them and say so here rather than run something else."""
+    problems = []
+    if cfg.loop_steps < 1:
+        problems.append("loop_steps under 1")
+    if cfg.loop_exit_threshold != 1.0:
+        problems.append(
+            "loop_exit_threshold (only 1.0 is served: every lane runs every "
+            "step; a pass count that differs by lane needs a scheduler)")
+    looped = cfg.loop_steps > 1 or cfg.sandwich_norm
+    if looped and cfg.block != "gqa_swiglu":
+        problems.append(
+            f'loop_steps / sandwich_norm (block "{cfg.block}" does not '
+            'read them: "gqa_swiglu" alone does)')
+    if looped and cfg.quantize_weights:
+        problems.append(
+            "quantize_weights (int8 / int4 weights under the looped or "
+            "sandwich-normed trunk are untested: float weights only)")
+    if problems:
+        raise ValueError(
+            "DecoderConfig cannot be served: " + "; ".join(problems))
+
+
+def kv_entries(cfg: DecoderConfig) -> int:
+    """Cache entries a weight layer keeps per token: one, and
+    ``loop_steps`` under the looped trunk — step ``t`` of layer ``i``
+    attends over entry ``(t, i)`` alone.  The one place the pools' extent,
+    the bytes a token, the allocator's accounting and the rows a decode
+    chunk read hear it from.  Entry ``(t, i)`` is the ``t``-th range of
+    ``n_blocks * block_size`` rows of layer ``i``'s pools
+    (``engines/paged.init_paged_pools``): a block id owns its rows in
+    every step's range."""
+    return cfg.loop_steps
 
 
 def kv_row_shapes(
@@ -247,6 +293,7 @@ def decoder_layer_stack(
     positions: jax.Array,  # [b, s] absolute position per token (RoPE)
     rope_len: int,  # RoPE table length (>= max position + 1)
     attend,  # attend(layer, q, k, v) -> [b, s, num_heads, head_dim]
+    cache: Optional[Dict[str, jax.Array]] = None,
 ) -> jax.Array:
     """The shared transformer trunk: embed, then per layer project
     q/k/v, apply RoPE at ``positions``, delegate KV-cache writes AND
@@ -260,40 +307,100 @@ def decoder_layer_stack(
     every op outside ``attend`` is shared code, so batcher output stays
     token-exact with the solo engine by construction.
 
-    Returns the final hidden states [b, s, hidden] (pre final-norm;
-    :func:`decoder_head` finishes the stack)."""
+    The looped trunk (``cfg.loop_steps`` T > 1): the layers run T times
+    over the same parameters as ONE loop in the program — the layer
+    bodies are traced once, whatever T — the final norm closes every
+    step, and ``attend(i, q, k, v, step=t)`` is told the traced step.
+    ``cache`` is the dict ``attend`` reads and writes its arrays in: the
+    loop carries it, so ``attend`` must reach them through that dict and
+    nothing else.
+
+    Returns the final hidden states [b, s, hidden]: pre final-norm at
+    ``loop_steps`` 1, normed by the last step's close under the loop
+    (:func:`decoder_head` finishes the stack either way)."""
     b, s = ids.shape
     dtype = jnp.dtype(cfg.dtype)
     with scope("proj"):
         cos, sin = rope_angles(cfg.head_dim, rope_len, cfg.rope_theta)
+    # the residual stream: the activation type — float32 under the loop,
+    # where 4 x 96 adds into a bfloat16 stream of RMS ~10 each round away
+    # 2 % of the unit-RMS branch they add, and four passes over the same
+    # weights amplify what one pass injects (0.021 / 0.039 / 0.276 at 1 /
+    # 2 / 4 steps on the chip, PERF.md section 6, PR 44); matmul inputs
+    # stay the activation type
+    stream = jnp.float32 if cfg.loop_steps > 1 else dtype
     with scope("embed"):
-        x = params["tok_emb"][ids].astype(dtype)
-    for i in range(cfg.num_layers):
-        with scope("proj"):
-            y = rms_norm(x, params[f"l{i}_attn_norm_g"], cfg.norm_eps)
-            q = _qmatmul(y, params, f"l{i}_wq", dtype).reshape(
-                b, s, cfg.num_heads, cfg.head_dim
-            )
-            k = _qmatmul(y, params, f"l{i}_wk", dtype).reshape(
-                b, s, cfg.num_kv_heads, cfg.head_dim
-            )
-            v = _qmatmul(y, params, f"l{i}_wv", dtype).reshape(
-                b, s, cfg.num_kv_heads, cfg.head_dim
-            )
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        x = params["tok_emb"][ids].astype(dtype).astype(stream)
 
-        attn = attend(i, q, k, v)
-        with scope("proj"):
-            attn = attn.reshape(b, s, cfg.num_heads * cfg.head_dim)
-            x = x + _qmatmul(attn, params, f"l{i}_wo", dtype)
+    def pre(x, name):
+        """The stream normed into a sublayer, in the activation type."""
+        return rms_norm(x, params[name], cfg.norm_eps).astype(dtype)
 
-        with scope("mlp"):
-            y = rms_norm(x, params[f"l{i}_mlp_norm_g"], cfg.norm_eps)
-            gate = _qmatmul(y, params, f"l{i}_w_gate", dtype)
-            up = _qmatmul(y, params, f"l{i}_w_up", dtype)
-            act = jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * up
-            x = x + _qmatmul(act, params, f"l{i}_w_down", dtype)
+    def post(y, name):
+        """A sublayer's output on its way to the residual add."""
+        if not cfg.sandwich_norm:
+            return y
+        return rms_norm(y.astype(stream), params[name], cfg.norm_eps)
+
+    def heads(y, n):
+        """A q / k / v product [b, s, n * d] as heads [b, s, n, d].  Under
+        the loop the product is fenced first: left to fuse the reshape
+        into the dot, the TPU compiler lays ``wq`` / ``wk`` / ``wv`` out a
+        head at a time ([heads, d, in]) and — the weights being invariant
+        of the step loop — keeps a transposed copy of all 3 x 48 of them
+        for the life of EVERY loaded program: 1.2 GB each at the published
+        widths, which the chip does not have beside the pools (PERF.md
+        section 6, PR 44).  At ``loop_steps`` 1 the programs stay what
+        they were."""
+        if cfg.loop_steps > 1:
+            y = jax.lax.optimization_barrier(y)
+        return y.reshape(b, s, n, cfg.head_dim)
+
+    def layers(x, **step):
+        for i in range(cfg.num_layers):
+            with scope("proj"):
+                y = pre(x, f"l{i}_attn_norm_g")
+                q = heads(_qmatmul(y, params, f"l{i}_wq", dtype),
+                          cfg.num_heads)
+                k = heads(_qmatmul(y, params, f"l{i}_wk", dtype),
+                          cfg.num_kv_heads)
+                v = heads(_qmatmul(y, params, f"l{i}_wv", dtype),
+                          cfg.num_kv_heads)
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
+
+            attn = attend(i, q, k, v, **step)
+            with scope("proj"):
+                attn = attn.reshape(b, s, cfg.num_heads * cfg.head_dim)
+                x = x + post(_qmatmul(attn, params, f"l{i}_wo", dtype),
+                             f"l{i}_attn_post_norm_g")
+
+            with scope("mlp"):
+                y = pre(x, f"l{i}_mlp_norm_g")
+                gate = _qmatmul(y, params, f"l{i}_w_gate", dtype)
+                up = _qmatmul(y, params, f"l{i}_w_up", dtype)
+                act = jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * up
+                x = x + post(_qmatmul(act, params, f"l{i}_w_down", dtype),
+                             f"l{i}_mlp_post_norm_g")
+        return x
+
+    if cfg.loop_steps == 1:
+        return layers(x)
+
+    def one_step(t, carry):
+        x, held = carry
+        cache.update(held)
+        x = layers(x, step=t)
+        with scope("loop_close"):
+            # the exit gate (sigmoid(x . exit_gate_w + exit_gate_b)) would
+            # be read here; at loop_exit_threshold 1 no step exits early
+            # and it is not evaluated
+            x = rms_norm(x, params["final_norm_g"], cfg.norm_eps)
+        return x, dict(cache)
+
+    x, held = jax.lax.fori_loop(
+        0, cfg.loop_steps, one_step, (x, dict(cache)))
+    cache.update(held)
     return x
 
 
@@ -304,7 +411,9 @@ def decoder_head(
     new_lengths: Optional[jax.Array] = None,
     last_token_only: bool = False,
 ) -> jax.Array:
-    """Final norm + lm_head over the trunk's hidden states (f32 logits)."""
+    """Final norm + lm_head over the trunk's hidden states (f32 logits).
+    Under the looped trunk the norm is the last step's close, applied in
+    the trunk: here it is the head alone."""
     dtype = jnp.dtype(cfg.dtype)
     with scope("head"):
         if last_token_only and x.shape[1] > 1:
@@ -314,7 +423,9 @@ def decoder_head(
             x = jnp.take_along_axis(
                 x, (new_lengths - 1)[:, None, None], axis=1
             )
-        x = rms_norm(x, params["final_norm_g"], cfg.norm_eps)
+        if cfg.loop_steps == 1:  # the looped trunk closed its last step
+            x = rms_norm(x, params["final_norm_g"], cfg.norm_eps)
+        x = x.astype(dtype)  # the looped trunk's stream is float32
         return _qmatmul(x, params, "lm_head", dtype).astype(jnp.float32)
 
 
@@ -350,6 +461,13 @@ def decoder_forward(
             f'the dense-cache solo forward has no "{HYBRID_BLOCK}" block: '
             "a stack of mixer kinds serves through the paged "
             "cache and its lane state (engines/paged.py, the batcher) only"
+        )
+    if cfg.loop_steps > 1:
+        raise NotImplementedError(
+            f"the dense-cache solo forward runs loop_steps 1 only (got "
+            f"{cfg.loop_steps}): a looped trunk keeps an entry a (step, "
+            "layer) and serves through the paged cache (engines/paged.py, "
+            "the batcher) only"
         )
     b, s = ids.shape
     max_len = cache["k0"].shape[1]

@@ -668,6 +668,10 @@ class TelemetrySampler:
                 # length
                 rec("serve_state_bytes_per_lane",
                     float(occ["state_bytes_per_lane"]), now=now)
+            if "loop_steps" in occ:
+                # the looped trunk: passes of the stack a token takes,
+                # and the factor in ``serve_kv_bytes_per_token``
+                rec("serve_loop_steps", float(occ["loop_steps"]), now=now)
         qos_status = getattr(b, "qos_status", None)
         if qos_status is not None:
             # multi-tenant QoS (docqa-qos): live deferral flag + class

@@ -15,8 +15,9 @@ runs when no trace is open.  JAX's persistent compile cache leaves
 metadata out of its key, so an executable cached BEFORE a scope was
 opened is found again without it: empty that cache to see a new scope.
 
-A scope names a PHASE, never a layer (the trunks are unrolled in Python:
-the 32 copies of a phase share its scope).  The vocabulary is closed — a
+A scope names a PHASE, never a layer or a pass (the trunks are unrolled in
+Python: the 32 copies of a phase share its scope, and so do the passes of
+a looped trunk).  The vocabulary is closed — a
 new trunk or kernel opens one of these, or adds its own to the tuple,
 to the table in ``docs/OBSERVABILITY.md`` and to PERF.md §3 in the same
 PR:
@@ -38,6 +39,8 @@ PR:
     route        router scores and the choice of experts
     experts      the held experts' turns
     head         final norm and ``lm_head``
+    loop_close   the looped trunk's norm that closes every step (and its
+                 exit gate, once a threshold under 1 evaluates it)
     sample       sampling, token and length bookkeeping, the chunk's
                  result array with its MoE / sparse sums
 """
@@ -51,14 +54,21 @@ DEVICE_SCOPES = (
     "embed", "proj", "cache_write", "attend", "select", "state", "mlp",
     "route", "experts", "head", "sample",
 )
+# A scope of the looped trunk alone (PR 44).  Beside the tuple, not in
+# it: ``tests/benchmark/test_benchmark_scopes.py`` holds the benchmark's
+# five decode metrics to partition ``DEVICE_SCOPES`` exactly, and only a
+# ``benchmark`` PR may give ``loop_close`` a metric to be read by
+# (PERF.md section 7); until then the by-scope table shows it and no
+# declared metric sums it.  ``scope()`` takes a name of either.
+LOOP_SCOPES = ("loop_close",)
 
 
 def scope(name: str):
     """``jax.named_scope("dq." + name)``; a name outside
     ``DEVICE_SCOPES`` is refused."""
-    if name not in DEVICE_SCOPES:
+    if name not in DEVICE_SCOPES + LOOP_SCOPES:
         raise ValueError(
-            f"{name!r} is no device scope: one of {DEVICE_SCOPES} "
-            "(docqa_tpu/ops/scopes.py)"
+            f"{name!r} is no device scope: one of "
+            f"{DEVICE_SCOPES + LOOP_SCOPES} (docqa_tpu/ops/scopes.py)"
         )
     return jax.named_scope(PREFIX + name)

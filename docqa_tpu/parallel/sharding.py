@@ -59,6 +59,12 @@ def decoder_param_pspecs(cfg: DecoderConfig, model_axis: str) -> Dict[str, P]:
                 f"l{i}_w_down": P(m, None),
             }
         )
+        if cfg.sandwich_norm:  # gains over the hidden axis: replicated
+            specs[f"l{i}_attn_post_norm_g"] = P(None)
+            specs[f"l{i}_mlp_post_norm_g"] = P(None)
+    if cfg.loop_steps > 1:  # the exit gate: 2,049 numbers, replicated
+        specs["exit_gate_w"] = P(None, None)
+        specs["exit_gate_b"] = P(None)
     return specs
 
 
@@ -215,7 +221,9 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     cache there is no batch axis to split over ``data``; the scatter /
     gather ride the unsharded row axis and insert no collective (the
     shard audit's decoder_paged_decode program holds that to the same
-    one-all-reduce-per-Megatron-block budget as the dense programs)."""
+    one-all-reduce-per-Megatron-block budget as the dense programs).
+    The looped trunk's pools hold its steps' ranges along that same
+    unsharded row axis (``engines/paged.init_paged_pools``)."""
     if is_latent(cfg):  # one row a token, no head axis: replicated
         return {f"c{i}": P() for i in range(cfg.num_layers)}
     if is_hybrid(cfg):  # rows of 1-2 kv heads, lane states, the slot map

@@ -83,6 +83,24 @@ _PR28_DIGITS = tuple(
 _PR40_TAIL = ("tests/benchmark/test_benchmark_scopes.py::"
               "test_the_eight_entries_sit_at_the_end_with_the_two_cells")
 
+# ---- two equality pins that ANY later cell or metric outdates (PR 44) -------
+# tests/benchmark/test_benchmark_jamba2.py::
+# test_the_lists_the_cell_joined_and_the_ones_it_did_not asserts that every
+# list of cells the Jamba cell joined EQUALS ``older + [its name]``, and
+# ::test_the_two_entries_are_the_last_two_behind_pr40s_eight that
+# ``per_layer`` ENDS with PR 42's two metrics and that the benchmark holds 4
+# cells: false once ISSUE 44's cell and metric are appended, as the
+# benchmark's rules tell it to.  The file is the benchmark's; until a
+# `benchmark` PR loosens them, tests/benchmark/test_benchmark_ouro.py::
+# test_what_pr42s_two_pins_held_still_holds asserts every line of both that is
+# still true, and the outdated ones by prefix and membership — the form in
+# which that file pins its own entries too, so that the next cell needs no
+# mark here.  STRICT, as above.
+_PR42_PINS = tuple(
+    "tests/benchmark/test_benchmark_jamba2.py::" + name for name in (
+        "test_the_lists_the_cell_joined_and_the_ones_it_did_not",
+        "test_the_two_entries_are_the_last_two_behind_pr40s_eight"))
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -96,6 +114,12 @@ def pytest_collection_modifyitems(items):
                 strict=True,
                 reason="digits of the program's int8 arithmetic before PR 41; "
                 "for a `benchmark` PR to re-record (PERF.md 7)"))
+        elif item.nodeid in _PR42_PINS:
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="lists of cells and the per-layer tail pinned by "
+                "equality to PR 42's, outdated by any cell or metric "
+                "appended; for a `benchmark` PR to loosen (PERF.md 7)"))
         elif item.nodeid == _PR40_TAIL:
             item.add_marker(pytest.mark.xfail(
                 strict=True,
