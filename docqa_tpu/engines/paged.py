@@ -754,8 +754,10 @@ def ragged_prefill_forward(
     record (:func:`_latent_prefill_forward`).
 
     ``use_flash`` / ``mesh`` are what the engine observed (as
-    :func:`paged_decode_forward` takes them) and reach the one prefill op
-    that has a Pallas form: a state-space layer's scan (``ops/ssm.py``).
+    :func:`paged_decode_forward` takes them) and reach the prefill ops
+    that have a Pallas form: a state-space layer's scan (``ops/ssm.py``)
+    and, by ``mesh`` alone, a routed layer's grouped product
+    (``ops/grouped.py``).
     A caller that observed nothing (``None``: the benchmark's comparison)
     gets the backend's own answer, as ``GenerateEngine`` derives it.
     """
@@ -768,7 +770,7 @@ def ragged_prefill_forward(
             )
         return _latent_prefill_forward(
             params, cfg, pools, ids, seg_ids, positions, dest_rows,
-            last_rows, rope_len,
+            last_rows, rope_len, mesh,
         )
     if is_hybrid(cfg):
         if n_prefix_rows:
@@ -856,13 +858,14 @@ def paged_decode_forward(
 
     Returns (logits [S, s, vocab] f32, pools) — and, from the latent
     block, its routing record (:func:`_latent_decode_forward`).  For that
-    block ``use_flash`` and ``mesh`` choose nothing: no Pallas kernel
-    reads a latent row, so the step is the XLA gather whatever they say
-    (GSPMD places it on a mesh)."""
+    block ``use_flash`` chooses nothing: no Pallas kernel reads a latent
+    row, so its attention is the XLA gather whatever it says (GSPMD
+    places it on a mesh); ``mesh`` reaches the routed layers' grouped
+    product alone (``ops/grouped.py``)."""
     if is_latent(cfg):
         return _latent_decode_forward(
             params, cfg, pools, block_tables, tok, lengths, block_size,
-            rope_len,
+            rope_len, mesh,
         )
     if is_hybrid(cfg):
         return _hybrid_decode_forward(
@@ -924,7 +927,7 @@ def _with_record(logits, pools, record):
 
 
 def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
-                            dest_rows, last_rows, rope_len):
+                            dest_rows, last_rows, rope_len, mesh=None):
     """The packed prefill of the latent block: each token's ONE cache row
     is scattered to its table-mapped pool row, and attention runs over
     the rows in flight in the non-absorbed form — keys and values
@@ -950,7 +953,8 @@ def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
             )[None]
 
     x, record = latent_layer_stack(
-        params, cfg, ids[None, :], positions[None, :], rope_len, attend
+        params, cfg, ids[None, :], positions[None, :], rope_len, attend,
+        mesh=mesh,
     )
     with scope("head"):
         logits = decoder_head(params, cfg, x[0][last_rows][:, None, :])
@@ -960,7 +964,7 @@ def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
 
 
 def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
-                           block_size, rope_len):
+                           block_size, rope_len, mesh=None):
     """A decode step of the latent block in the ABSORBED form: the new
     rows are written at their table-mapped pool rows, each head's query is
     carried into latent space, scores and the weighted sum are taken
@@ -1003,7 +1007,7 @@ def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
         return expand_output(params, cfg, i, o_lat)
 
     x, record = latent_layer_stack(
-        params, cfg, tok, rope_pos, rope_len, attend
+        params, cfg, tok, rope_pos, rope_len, attend, mesh=mesh
     )
     return _with_record(decoder_head(params, cfg, x), pools, record)
 

@@ -153,6 +153,15 @@ MOE_SUMS = (
     "serve_moe_picks", "serve_moe_picks_local", "serve_moe_experts_touched",
     "serve_moe_layer_steps",
 )
+# the same block's prefill dispatches (``_moe_prefill_sums``, behind the
+# first tokens in the fetch ``_finalize_admissions`` makes anyway): expert
+# picks of the packed prompt rows, and those that fell on an expert held
+# here — the row-expert products the grouped form runs
+# (``models/latent.held_experts_sum``), of rows x held had every held
+# expert run over every row
+MOE_PREFILL_SUMS = (
+    "serve_moe_prefill_picks", "serve_moe_prefill_picks_local",
+)
 
 # the same of a stack of mixer kinds in which a layer SELECTS
 # (``_sparse_step_sums`` / ``_sparse_chunk_sums``; a stack in which none
@@ -976,9 +985,9 @@ class ContinuousBatcher:
                 n_prefix_rows=self.seq_capacity,
                 block_size=self.block_size,
             )
-        # a block that routes hands back its record too; the decode
-        # chunks count choices, a prefill's are not counted
-        logits, pools, *_ = ragged_prefill_forward(
+        # a block that routes hands back its record too: its picks are
+        # summed here and ride behind the first tokens (MOE_PREFILL_SUMS)
+        logits, pools, *routed = ragged_prefill_forward(
             params, self.cfg, pools, ids, seg, pos, dest, last_rows,
             rope_len=self.seq_capacity, use_flash=self.engine.use_flash,
             mesh=self.mesh, **warm_kw,
@@ -988,6 +997,10 @@ class ContinuousBatcher:
                 logits, rng, self.gen.temperature, self.gen.top_k,
                 self.gen.top_p,
             )
+            if self._routed_layers:
+                toks = jnp.concatenate(
+                    [toks, self._moe_prefill_sums(routed[0], seg)]
+                )
             if table is None:
                 return pools, toks
             # per-lane bigram rows from the packed stream: a (prev, next)
@@ -1100,6 +1113,19 @@ class ContinuousBatcher:
             jnp.sum(per_expert),
             jnp.sum(per_expert > 0),
             jnp.any(active) * record.shape[0],
+        ]).astype(jnp.int32)
+
+    def _moe_prefill_sums(self, record, seg):
+        """``MOE_PREFILL_SUMS`` of one prefill dispatch, int32, from its
+        routing record [routed_layers, T, k] and the packed rows' lanes
+        (``seg`` < 0: padding, which routes too and is not counted) —
+        summed on the device, as ``_moe_step_sums`` is."""
+        lo, held = experts_held(self.cfg)
+        live = (seg >= 0)[None, :, None]
+        local = record - lo
+        return jnp.stack([
+            jnp.sum(live & (record >= 0)),
+            jnp.sum(live & (local >= 0) & (local < held)),
         ]).astype(jnp.int32)
 
     def _sparse_step_sums(self, record, lengths, active):
@@ -2647,7 +2673,7 @@ class ContinuousBatcher:
                 self._pools[STATE_SLOT] = jax.device_put(
                     self._state_slot_np, self._state_sharding
                 )
-            parts = []
+            parts, sums = [], []
             for (T, ids_flat, seg, pos, dest, last_rows, slots_arr,
                  n_lanes, warm_flag, tables_np, plens_np) in group_inputs:
                 packed = (
@@ -2676,6 +2702,8 @@ class ContinuousBatcher:
                         self.engine.params, self._pools, *args
                     )
                 parts.append(toks[:n_lanes])
+                if self._routed_layers:  # its picks, behind the tokens
+                    sums.append(toks[S:])
             first = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
             idx = jnp.asarray(slots_np)
             alive = (first != self.gen.eos_id) & jnp.asarray(budget_ok)
@@ -2685,6 +2713,8 @@ class ContinuousBatcher:
             # the scatters are DOWNSTREAM of `first` — returned alongside
             # it so strict mode's block_until_ready covers every program
             # this item issued, not just the first-token chain
+            if sums:  # a pair a dispatch, behind the round's tokens
+                first = jnp.concatenate([first, *sums])
             return first, self._tok, self._lengths, self._active
 
         t_prefill0 = _now()
@@ -2812,11 +2842,16 @@ class ContinuousBatcher:
                     lambda: np.asarray(round_toks),
                     cost_key=cost_keys,
                 )
-                firsts = ticket.result()[: len(meta)]
+                fetched = ticket.result()
+                firsts = fetched[: len(meta)]
         except Exception as e:
             log.exception("admission fetch failed; resetting")
             self._fail_active(e)
             return False
+        if self._routed_layers:
+            sums = fetched[len(meta):].reshape(-1, len(MOE_PREFILL_SUMS))
+            for name, value in zip(MOE_PREFILL_SUMS, sums.sum(axis=0)):
+                DEFAULT_REGISTRY.counter(name).inc(int(value))
         # ---- per-request cost attribution (docqa-costscope): split the
         # round's measured device time across its requests proportional
         # to the NOVEL (suffix) tokens each one packed — warm lanes bill

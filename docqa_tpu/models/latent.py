@@ -32,22 +32,29 @@ of the kept scores are taken; gate = ``routed_scale x score``, not
 renormalised.  The process holds the experts ``[experts_held_start,
 + experts_held)`` and computes their part of the sum (plus the shared
 experts, which every holder computes alike); the rest is left out —
-expert parallelism's local half, with no stand-in for the exchange.  The
+expert parallelism's local half, with no stand-in for the exchange.  That
+part is ONE grouped product over the dispatch's picks sorted by expert
+(:func:`held_experts_sum`, ``ops/grouped.py``): a row passes through the
+experts it picked, an expert no row picked is not read — the same form
+for a prefill's hundreds of rows and a decode step's few lanes.  The
 trunk hands back the expert ids it took (the routing record,
 benchmark/README.md "A block that routes").
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.ops.grouped import grouped_matmul, row_tile
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, yarn_mscale, yarn_rope_angles
 from docqa_tpu.ops.scopes import scope
+from docqa_tpu.utils import round_up
 
 Params = Dict[str, jax.Array]
 
@@ -227,39 +234,55 @@ def select_experts(scores, cfg: DecoderConfig):
 
 
 def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
-                     i: int):
+                     i: int, *, mesh=None):
     """``sum_e gate_e . swiglu_e(y)`` over the experts HELD here, float32
     [n, hidden].  ``taken`` [n, k] expert ids as the router numbers them,
     ``gates`` [n, k] float32.
 
-    One turn per held expert: a dense pass over every row, weighted by the
-    row's gate for it, SKIPPED (``lax.cond``, the expert's slice taken
-    inside the branch) when no row took it.  A prefill of hundreds of
-    rows takes nearly every turn; a decode step of a few lanes reads only
-    the experts its tokens touched, so its time follows the routing."""
+    A GROUPED product (``ops/grouped.py``): the ``n . k`` picks are
+    sorted by the held expert they fell on, ``y``'s rows gathered in that
+    order, and each run of rows multiplied by its own expert's slice of
+    the stacked weights.  A pick on an expert held elsewhere (or ``-1``)
+    sorts behind every group and is never computed; an expert no row took
+    is never read.  So a prefill of hundreds of rows streams each held
+    expert once under the few rows that took it, and a decode step of a
+    few lanes reads only the experts its tokens touched: its time follows
+    the routing.  ``mesh``: what the engine serves on (it chooses the
+    product's form, nothing else)."""
     lo, held = experts_held(cfg)
+    n, k = taken.shape
     dtype = y.dtype
-    local = taken - lo  # [n, k]; outside 0..held-1: an absent expert
-    acc = jnp.zeros((y.shape[0], cfg.hidden_dim), jnp.float32)
-    for e in range(held):
-        mine = local == e
-
-        def turn(acc, e=e, mine=mine):
-            weight = jnp.sum(jnp.where(mine, gates, 0.0), axis=-1)  # [n]
-            g = y @ params[f"l{i}_e_gate"][e].astype(dtype)
-            u = y @ params[f"l{i}_e_up"][e].astype(dtype)
-            act = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
-            out = jnp.dot(
-                act, params[f"l{i}_e_down"][e].astype(dtype),
-                preferred_element_type=jnp.float32,
-            )
-            return acc + out * weight[:, None]
-
-        acc = jax.lax.cond(jnp.any(mine), turn, lambda acc: acc, acc)
+    local = (taken - lo).reshape(-1)  # [n . k]
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)  # sorted pick -> flat pick
+    sizes = jnp.sum(
+        local[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32
+    )  # [held]: the picks on absent experts lie past their sum
+    # whole row tiles; the rows that fill the last one lie there too
+    m = round_up(n * k, row_tile(n * k))
+    pick = jnp.pad(order, (0, m - n * k))
+    rows = y[pick // k]
+    product = functools.partial(grouped_matmul, group_sizes=sizes, mesh=mesh)
+    g = product(rows, params[f"l{i}_e_gate"].astype(dtype), out_dtype=dtype)
+    u = product(rows, params[f"l{i}_e_up"].astype(dtype), out_dtype=dtype)
+    act = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
+    out = product(
+        act, params[f"l{i}_e_down"].astype(dtype), out_dtype=jnp.float32
+    )
+    # un-sort and sum a row's k picks in one pass over the product: a
+    # pick held elsewhere points at a row past the groups, which holds
+    # whatever was there — never a product
+    back = jnp.argsort(order).reshape(n, k)  # pick -> its sorted row
+    here = (local < held).reshape(n, k)
+    acc = jnp.zeros((n, cfg.hidden_dim), jnp.float32)
+    for j in range(k):
+        acc = acc + jnp.where(
+            here[:, j, None], out[back[:, j]] * gates[:, j, None], 0.0
+        )
     return acc
 
 
-def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int):
+def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int, *, mesh=None):
     """(what the routed layer adds [n, hidden], expert ids taken [n, k])."""
     with scope("route"):
         logits = jnp.dot(
@@ -271,7 +294,8 @@ def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int):
         taken, taken_scores = select_experts(scores, cfg)
     with scope("experts"):
         out = held_experts_sum(
-            y, taken, cfg.routed_scale * taken_scores, params, cfg, i
+            y, taken, cfg.routed_scale * taken_scores, params, cfg, i,
+            mesh=mesh,
         )
     with scope("mlp"):
         if cfg.num_shared_experts:
@@ -284,12 +308,13 @@ def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int):
 # ---- the trunk -------------------------------------------------------------
 
 def latent_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
-                       rope_len: int, attend):
+                       rope_len: int, attend, *, mesh=None):
     """The block's trunk, as ``decoder_layer_stack`` is the GQA block's.
 
     ``attend(i, q_nope [b, s, heads, nope], q_rope [b, s, heads, rope],
     row [b, s, r + rope]) -> [b, s, heads, v]`` owns the cache: it writes
-    ``row`` and attends in whichever form suits it.
+    ``row`` and attends in whichever form suits it.  ``mesh``: the mesh
+    the engine serves on, for the routed layers' grouped product.
 
     Returns (hidden states [b, s, hidden] before the final norm, routing
     record int32 [routed_layers, b, s, experts_per_token])."""
@@ -344,7 +369,9 @@ def latent_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
             continue
         with scope("mlp"):
             y = rms_norm(x, params[p + "mlp_norm_g"], cfg.norm_eps)
-            add, taken = routed_mlp(y.reshape(b * s, -1), params, cfg, i)
+            add, taken = routed_mlp(
+                y.reshape(b * s, -1), params, cfg, i, mesh=mesh
+            )
             x = x + add.reshape(b, s, -1)
         record.append(taken.reshape(b, s, -1))
     return x, (jnp.stack(record) if record else None)

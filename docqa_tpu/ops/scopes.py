@@ -37,7 +37,8 @@ PR:
     mlp          the dense SwiGLU with its norm and residual add; shared
                  experts
     route        router scores and the choice of experts
-    experts      the held experts' turns
+    experts      the held experts' grouped sum: the picks' sort, the
+                 rows' gather, the grouped matmuls, the gate, the un-sort
     head         final norm and ``lm_head``
     loop_close   the looped trunk's norm that closes every step (and its
                  exit gate, once a threshold under 1 evaluates it)

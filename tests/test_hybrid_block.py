@@ -594,12 +594,16 @@ def test_a_configuration_the_block_cannot_run_is_refused_by_field(
 # ---- the other blocks' programs came out the same ----------------------------
 
 # sha256 (first 16 hex digits) and length of the lowered text of the toy
-# LATENT batcher programs on the parent commit 6710925 (jax 0.9.0, CPU):
-# the programs ISSUE 38 may not move (the GQA block's: PR 35's test in
-# tests/test_latent_block.py, which still passes).
+# LATENT batcher programs (jax 0.9.0, CPU): the programs a change to
+# THIS stack may not move (the GQA block's: PR 35's test in
+# tests/test_latent_block.py).  Recorded on the parent commit 6710925 by
+# ISSUE 38 (decode c5237060d2c653f2 / 178793, prefill 2957852ca188bc6e /
+# 164811) and again by ISSUE 45, which moved both on purpose: the held
+# experts' forty ``lax.cond`` turns became one grouped product
+# (models/latent.held_experts_sum) and the prefill sums its picks.
 LATENT_LOWERED_BEFORE = {
-    "decode": ("c5237060d2c653f2", 178793),
-    "prefill": ("2957852ca188bc6e", 164811),
+    "decode": ("f50d0a54168d26ee", 174037),
+    "prefill": ("9bf55701c84d7e7a", 163877),
 }
 LATENT_TOY = DecoderConfig(
     vocab_size=512, hidden_dim=128, num_layers=3, num_heads=4, num_kv_heads=1,

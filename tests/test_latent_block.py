@@ -8,6 +8,8 @@ its architecture package (``benchmark/architectures/deepseek_v2``):
 * absorbed decode = non-absorbed decode on the same pool;
 * the four chips' partial expert sums, the shared experts counted once, add
   up to the uncut layer — in the program and in the reference;
+* the held experts' grouped sum against the plain sum over picks, at a
+  decode step's and a prefill's row counts, in both forms of the product;
 * group-limited selection on a hand-built case;
 * the routing record's shapes and ids, and two values from the GQA block;
 * the latent pool's bytes a token; YaRN's frequencies and m^2 by hand;
@@ -254,6 +256,78 @@ def test_the_four_shares_partial_sums_add_up_to_the_uncut_layer():
     np.testing.assert_array_equal(np.sort(ref_taken), np.sort(taken))
     np.testing.assert_allclose(np.asarray(uncut), np.asarray(ref_uncut),
                                rtol=2e-3, atol=2e-4)
+
+
+# ---- the held experts' sum: a grouped product over rows sorted by expert ----
+
+def _plain_sum_over_picks(y, taken, gates, params, cfg, i):
+    """``sum_j gate_j . swiglu_{taken_j}(y)`` over the picks held here,
+    one pick at a time in float32: what ``held_experts_sum`` regroups."""
+    lo, held = latent.experts_held(cfg)
+    y = np.asarray(y, np.float32)
+    w = {n: np.asarray(params[f"l{i}_e_{n}"], np.float32)
+         for n in ("gate", "up", "down")}
+    out = np.zeros((y.shape[0], cfg.hidden_dim), np.float32)
+    for row, (ids, row_gates) in enumerate(
+            zip(np.asarray(taken), np.asarray(gates))):
+        for e, gate in zip(ids - lo, row_gates):
+            if 0 <= e < held:
+                g = y[row] @ w["gate"][e]
+                act = g / (1.0 + np.exp(-g)) * (y[row] @ w["up"][e])
+                out[row] += gate * (act @ w["down"][e])
+    return out
+
+
+def _picks(case, n, cfg, rng):
+    lo, held = latent.experts_held(cfg)
+    k = cfg.experts_per_token
+    if case == "none_local":  # every pick on an expert another chip holds
+        return (lo + held + rng.integers(0, 8, (n, k))) % cfg.num_experts
+    if case == "one_expert":  # the same held expert, k times a row
+        return np.full((n, k), lo + 3)
+    if case == "outside_and_absent":  # held, held elsewhere, and -1
+        taken = rng.integers(0, cfg.num_experts, (n, k))
+        taken[::3, 0] = -1
+        taken[1::4, :] = -1
+        return taken
+    return np.stack([  # "routed": k distinct experts a row
+        rng.choice(cfg.num_experts, k, replace=False) for _ in range(n)])
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "pallas_interpret"])
+@pytest.mark.parametrize("n", [8, 512])
+@pytest.mark.parametrize("case, start", [
+    ("routed", 0), ("routed", 16), ("none_local", 0), ("none_local", 24),
+    ("one_expert", 8), ("outside_and_absent", 0), ("outside_and_absent", 16),
+])
+def test_the_held_experts_sum_is_the_plain_sum_over_picks(
+        case, start, n, interpret, monkeypatch):
+    """Both forms of the grouped product (``ops/grouped.py``: the XLA one
+    this CPU runs, the Pallas kernel a TPU runs — here in interpret mode,
+    where the rows no group owns read NaN), at a decode step's row count
+    and a prefill's: the float32 sum over the picks held here."""
+    cfg = dataclasses.replace(
+        TOY, dtype="float32", experts_held_start=start, experts_held=8)
+    params = PACKAGE.weights.make_decoder_params(cfg, 5)
+    rng = np.random.default_rng(n + start)
+    y = jnp.asarray(rng.standard_normal((n, cfg.hidden_dim)), jnp.float32)
+    taken = jnp.asarray(_picks(case, n, cfg, rng), jnp.int32)
+    gates = jnp.asarray(rng.random(taken.shape), jnp.float32)
+    if interpret:
+        from docqa_tpu.ops import grouped
+
+        monkeypatch.setattr(
+            latent, "grouped_matmul",
+            lambda *a, **kw: grouped.grouped_matmul(*a, **kw, interpret=True))
+    got = np.asarray(latent.held_experts_sum(y, taken, gates, params, cfg, 1))
+    want = _plain_sum_over_picks(y, taken, gates, params, cfg, 1)
+    assert np.isfinite(got).all()
+    if case == "none_local":
+        assert not got.any()
+    else:
+        assert np.abs(want).max() > 1e-3
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
 
 
 def test_a_token_whose_groups_are_all_elsewhere_gets_the_shared_part_alone():
@@ -595,10 +669,11 @@ def test_the_gqa_blocks_programs_lower_to_the_text_they_lowered_to(
 # ---- through the batcher ----------------------------------------------------
 
 def _counters():
-    from docqa_tpu.engines.serve import MOE_SUMS
+    from docqa_tpu.engines.serve import MOE_PREFILL_SUMS, MOE_SUMS
     from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
 
-    return {n: DEFAULT_REGISTRY.counter(n).value for n in MOE_SUMS}
+    return {n: DEFAULT_REGISTRY.counter(n).value
+            for n in MOE_SUMS + MOE_PREFILL_SUMS}
 
 
 def test_the_batcher_serves_the_block_and_counts_its_choices():
@@ -637,6 +712,13 @@ def test_the_batcher_serves_the_block_and_counts_its_choices():
     assert gained["serve_moe_layer_steps"] % layers == 0
     assert gained["serve_moe_experts_touched"] <= 8 * gained[
         "serve_moe_layer_steps"]
+    # the prefill's side: every prompt token (padding rows route too and
+    # are not counted) picks k experts in each routed layer, some of them
+    # held here
+    assert gained["serve_moe_prefill_picks"] == k * layers * sum(
+        len(p) for p in prompts)
+    assert 0 < gained["serve_moe_prefill_picks_local"] < gained[
+        "serve_moe_prefill_picks"]
     # the same answers by hand: prefill, then steps through a pool of one
     # lane, teacher-forced with the batcher's tokens — each of which has
     # to be the argmax there too, or within bfloat16's reach of it (the

@@ -414,3 +414,39 @@ class TestCompilesForTheChip:
         # nothing of [chunks, rows, n, d] or [rows, n, d] beside the kernel
         assert f"f32[{rows},{n},{d}]" not in hlo
         assert f"f32[{rows // 128},128,{n},{d}]" not in hlo
+
+    @pytest.mark.parametrize("rows", [48, 3072, 18432], ids=[
+        "decode-step", "one-dispatch", "the-comparison"])
+    @pytest.mark.parametrize("k, n", [(5120, 1536), (1536, 5120)], ids=[
+        "gate-up", "down"])
+    def test_the_grouped_product_at_deepseek_v2s_widths(
+            self, topo, monkeypatch, rows, k, n):
+        """ISSUE 45: ``ops/grouped.grouped_matmul`` over 40 held experts
+        at the published widths — the tiles ``_tile`` picks (the
+        contraction whole, 4 MB of weight a tile) fit the kernel's fast
+        memory, for a decode step's 8 x 6 picks as ONE row tile, a
+        512-row dispatch's 3,072 and the comparison's 18,432 (this CPU
+        would choose ``ragged_dot``: the test steers the choice)."""
+        from jax.sharding import SingleDeviceSharding
+
+        from docqa_tpu.ops import grouped
+
+        monkeypatch.setattr(grouped, "grouped_kernel_chosen", lambda mesh: True)
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        assert rows % grouped.row_tile(rows) == 0
+        hlo = jax.jit(
+            lambda x, w, sizes: grouped.grouped_matmul(
+                x, w, sizes, jnp.float32)
+        ).lower(
+            arg((rows, k), jnp.bfloat16), arg((40, k, n), jnp.bfloat16),
+            arg((40,), jnp.int32)).compile().as_text()
+        assert "tpu_custom_call" in hlo and "gmm" in hlo
+        # the stacked weights reach the kernel as they lie
+        assert not [
+            line for line in hlo.splitlines()
+            if f" = bf16[40,{k},{n}]" in line and " parameter(" not in line
+        ]
