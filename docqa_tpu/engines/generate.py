@@ -38,6 +38,7 @@ from docqa_tpu.models.hybrid import (
     ATTENTION,
     HYBRID_BLOCK,
     MAMBA,
+    SPARSE,
     check_hybrid_config,
     is_hybrid,
 )
@@ -47,6 +48,7 @@ from docqa_tpu.models.latent import (
     is_latent,
 )
 from docqa_tpu.engines.spine import spine_run
+from docqa_tpu.ops.attention import paged_kernel_supported
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.parallel.sharding import cache_pspecs, shard_decoder_params
 from docqa_tpu.runtime.mesh import MeshContext
@@ -223,11 +225,16 @@ class GenerateEngine:
         if is_hybrid(cfg):
             # the batcher over the paged rows and the lane state only; the
             # linear scan and the selection are XLA, the paged decode
-            # kernel reads the plain attention layers and a state-space
-            # layer's prefill scan has a kernel of its own (ops/ssm.py)
+            # kernel reads the plain attention layers and — where it
+            # reads this geometry — the blocks a sparse layer's decode
+            # step took, and a state-space layer's prefill scan has a
+            # kernel of its own (ops/ssm.py)
             check_hybrid_config(cfg)
+            kinds = set(cfg.mixer_types)
             use_flash = bool(use_flash) and bool(
-                {ATTENTION, MAMBA} & set(cfg.mixer_types))
+                {ATTENTION, MAMBA} & kinds
+                or SPARSE in kinds and paged_kernel_supported(
+                    cfg.dtype, cfg.num_kv_heads, cfg.head_dim))
         self.use_flash = use_flash
         self._fns = {}
 

@@ -1210,9 +1210,11 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
     handler a kind.  A row-keeping layer writes the token's K and V at its
     table-mapped row; a SPARSE one also writes the compressed key of the
     window the token COMPLETES (if it does), selects among the lane's
-    compressed keys and reads the rows of the blocks taken; an ATTENTION
-    one reads the lane's live pages (``paged_decode_attention``: the paged
-    kernel under ``use_flash``).  A state-keeping layer advances the
+    compressed keys and reads the rows of the blocks taken (as pages,
+    through the paged kernel, under ``use_flash`` with no ``mesh``:
+    ``ops/attention.sparse_paged_chosen``); an ATTENTION one reads the
+    lane's live pages (``paged_decode_attention``: the paged kernel under
+    ``use_flash``).  A state-keeping layer advances the
     lane's entries IN PLACE (read, one step, written back): a LINEAR layer
     its state, a state-space layer its conv window (shifted by the token)
     and its state.  A lane whose table starts with a hole (a retired slot)
@@ -1281,6 +1283,7 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             out, taken = sparse_decode_attention(
                 q[:, 0], pools[f"k{i}"], pools[f"v{i}"], pools[f"ck{i}"],
                 block_tables, lengths + 1, block_size=block_size, **sizes,
+                use_flash=use_flash, mesh=mesh,
             )
             return out[:, None], taken[:, :, None]
 

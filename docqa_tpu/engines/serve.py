@@ -133,7 +133,11 @@ from docqa_tpu.models.hybrid import (
     sparse_layers,
 )
 from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
-from docqa_tpu.ops.attention import RAGGED_ALIGN, paged_kernel_supported
+from docqa_tpu.ops.attention import (
+    RAGGED_ALIGN,
+    paged_kernel_supported,
+    sparse_paged_chosen,
+)
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.ops.scopes import scope
 from docqa_tpu.ops.ssm import scan_kernel_chosen
@@ -925,6 +929,13 @@ class ContinuousBatcher:
         # asks the same of what it is handed: ``_prefill_program``)
         self._scan_kernel = bool(self._scan_layers) and scan_kernel_chosen(
             self.engine.use_flash, self.mesh)
+        # the sparse layers' decode step reads the blocks taken as pages
+        # through the paged kernel (the forward asks the same of what it
+        # is handed: ``ops/attention.sparse_decode_attention``)
+        self._sparse_paged = self._selects and sparse_paged_chosen(
+            self.engine.use_flash, self.mesh, self.cfg.dtype,
+            self.cfg.num_kv_heads, self.cfg.head_dim,
+            self.cfg.sparse_block_size, self.block_size)
         self._state_bytes = lane_state_bytes(self.cfg)  # one lane's
         self._state_slot_np = (
             np.zeros((self.n_blocks * self.block_size,), np.int32)
@@ -3168,6 +3179,10 @@ class ContinuousBatcher:
             float(n_appended)
         )
         DEFAULT_REGISTRY.counter("serve_decode_chunks").inc()
+        if self._sparse_paged:
+            # over ``serve_decode_chunks``: 1.0 where every chunk's sparse
+            # layers read the blocks taken as pages, absent elsewhere
+            DEFAULT_REGISTRY.counter("serve_sparse_paged_chunks").inc()
         if self._hybrid and not self._selects:
             # no sums row rides this stack's chunks: a lane-step is a
             # position a lane advanced, which the host holds
@@ -3270,19 +3285,27 @@ class ContinuousBatcher:
             live += int(lens.sum())
             read += int((-(-lens // self.block_size)).sum()) * self.block_size
         if self._selects:
-            # a sparse layer reads the rows of the blocks taken, every
-            # table once a lane of the step is still under dense_len
-            # (ops/attention.sparse_decode_attention)
+            # a sparse layer reads the rows of the blocks taken; in the
+            # XLA form every table once a lane of the step is still under
+            # dense_len (ops/attention.sparse_decode_attention)
             taken = self.cfg.sparse_topk * self.cfg.sparse_block_size
             all_lens = np.stack([
                 length + 1 + np.minimum(np.arange(steps), adv)
                 for length, adv in lanes
             ]) if lanes else np.zeros((0, steps), np.int64)
-            dense = (all_lens < self.cfg.sparse_dense_len).any(axis=0)
-            read = int(np.where(
-                dense, self.n_slots * self.seq_capacity,
-                len(lanes) * taken,
-            ).sum())
+            under = all_lens < self.cfg.sparse_dense_len
+            if self._sparse_paged:
+                # one virtual lane a (lane, kv head), and a page carries
+                # every kv head: a selecting lane's taken rows, the live
+                # pages of a lane under dense_len, kv-heads times
+                pages = -(-all_lens // self.block_size) * self.block_size
+                read = self.cfg.num_kv_heads * int(np.where(
+                    under, pages, np.minimum(taken, pages)).sum())
+            else:
+                read = int(np.where(
+                    under.any(axis=0), self.n_slots * self.seq_capacity,
+                    len(lanes) * taken,
+                ).sum())
         elif not self._pages_read_in_place:
             read = steps * self.n_slots * self.seq_capacity
         return read, live

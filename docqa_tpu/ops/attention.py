@@ -994,7 +994,9 @@ def attention(q, k, v, **kwargs):
 # --------------------------------------------------------------------------
 # The two-mixer block (models/hybrid.py): decayed linear attention whose
 # past is a state a lane, and block-sparse attention that selects inside
-# the paged cache.  XLA throughout; no Pallas kernel reads these yet.
+# the paged cache.  XLA but for the decode step's read of the blocks taken,
+# which on a TPU is :func:`paged_flash_decode` through a page table of its
+# own (:func:`sparse_paged_chosen`, :func:`taken_page_tables`).
 # --------------------------------------------------------------------------
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -1105,12 +1107,12 @@ def _taken_blocks(block_score, exists, forced, topk: int):
     return ids.astype(jnp.int32), vals >= 0.0
 
 
-def _pad_record(rec, topk: int):
+def _pad_record(rec, topk: int, fill: int = -1):
     short = topk - rec.shape[-1]
     if short <= 0:
         return rec
     return jnp.pad(rec, [(0, 0)] * (rec.ndim - 1) + [(0, short)],
-                   constant_values=-1)
+                   constant_values=fill)
 
 
 def compressed_keys(k, seg_ids, positions, kernel_size: int, stride: int):
@@ -1218,18 +1220,89 @@ def sparse_prefill_attention(q, k, v, seg_ids, positions, seg_lens, ck, ck_ok,
     return out.reshape(t, hq, d), _pad_record(rec, topk)
 
 
+def sparse_paged_chosen(use_flash, mesh, pool_dtype, kv_heads: int,
+                        head_dim: int, block: int, block_size: int) -> bool:
+    """Whether :func:`sparse_decode_attention` reads the blocks taken as
+    PAGES through the paged kernel: the engine saw a TPU (``use_flash``),
+    there is no mesh (``ops/ssm.scan_kernel_chosen``'s rule: the XLA form
+    is what GSPMD places), the kernel reads a pool of this geometry
+    (:func:`paged_kernel_supported`) and a selection block is a whole
+    number of pages.  The batcher counts by the same answer."""
+    return bool(
+        use_flash and mesh is None and block % block_size == 0
+        and paged_kernel_supported(pool_dtype, kv_heads, head_dim))
+
+
+def taken_page_tables(ids, took, sparse_lane, block_tables, lengths, *,
+                      block_size: int, block: int, dense_len: int,
+                      n_blocks: int):
+    """What a step's selection reads, as page tables the paged kernel
+    walks: one VIRTUAL lane a (lane, kv head).
+
+    ids, took    [S, g, kk] the blocks a lane's kv head took (any order)
+    sparse_lane  [S] the lane selects (holds ``dense_len`` tokens or more)
+    block_tables [S, NB] the lanes' own tables; entries >= ``n_blocks``
+                 are holes
+    lengths      [S] AFTER this step
+
+    Returns (tables int32 [S * g, width], lengths int32 [S * g]).  A
+    selecting lane's row: the pages of its taken blocks in ASCENDING block
+    order (``block // block_size`` pages a block, in order), holes behind
+    them; every taken block is full but the one that holds the query's
+    position, which the window forces and ascending order puts last, so
+    the length is ``(taken - 1) * block`` plus that block's rows up to the
+    query.  A lane under ``dense_len``: its own table row and length
+    (``width`` spans ``dense_len``).  An all-hole row stays all holes —
+    the kernel clamps its length to the allocated pages, 0."""
+    s_, g, kk = ids.shape
+    nbt = block_tables.shape[1]
+    ppb = block // block_size  # pages a block
+    nb = nbt * block_size // block
+    width = max(kk * ppb, min(nbt, -(-dense_len // block_size)))
+    t = lengths - 1
+    blocks = jnp.arange(nb)
+    sel = jnp.any((ids[..., None] == blocks) & took[..., None], axis=-2)
+    n_taken = jnp.sum(sel, axis=-1, dtype=jnp.int32)  # [S, g]
+    # slot j holds the taken block that has j taken blocks before it
+    slot = jnp.cumsum(sel, axis=-1, dtype=jnp.int32) - 1
+    hit = sel[..., None, :] & (slot[..., None, :] == jnp.arange(kk)[:, None])
+    ordered = jnp.sum(jnp.where(hit, blocks, 0), axis=-1)  # [S, g, kk]
+    entry = (ordered[..., None] * ppb + jnp.arange(ppb)).reshape(s_, -1)
+    pages = jnp.take_along_axis(block_tables, entry, axis=1)
+    filled = jnp.repeat(jnp.arange(kk) < n_taken[..., None], ppb, axis=-1)
+    pages = jnp.where(filled, pages.reshape(s_, g, kk * ppb), n_blocks)
+    tables = jnp.where(
+        sparse_lane[:, None, None], _pad_record(pages, width, n_blocks),
+        _pad_record(block_tables[:, :width], width, n_blocks)[:, None, :])
+    last = jnp.max(jnp.where(sel, blocks, -1), axis=-1)  # [S, g]
+    rows_taken = jnp.where(
+        n_taken > 0,
+        (n_taken - 1) * block
+        + jnp.clip(t[:, None] - last * block + 1, 0, block), 0)
+    lens = jnp.where(sparse_lane[:, None], rows_taken, lengths[:, None])
+    return tables.reshape(s_ * g, width), lens.reshape(s_ * g)
+
+
 def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
                             *, block_size: int, kernel_size: int, stride: int,
                             block: int, topk: int, init_blocks: int,
-                            window: int, dense_len: int):
+                            window: int, dense_len: int, use_flash=False,
+                            mesh=None, interpret: bool = False):
     """The decode step of the same THROUGH A BLOCK TABLE: one query a
     lane selects among the lane's compressed keys (gathered through the
     table: one row per ``stride`` tokens) and reads the K / V rows of the
     blocks it took, and only those — ``topk * block`` rows a kv head,
     whatever the lane's length.  A lane that holds fewer than
-    ``dense_len`` tokens reads every row; while any LIVE lane (first
-    table entry allocated) is such a lane, the step gathers every lane's
-    whole table and masks (``lax.cond``: the other branch is not run).
+    ``dense_len`` tokens reads every row.
+
+    Under :func:`sparse_paged_chosen` (``interpret`` for a CPU test of
+    it) ONE call of the paged kernel reads them in place, a virtual lane
+    a (lane, kv head) through :func:`taken_page_tables`: a lane under
+    ``dense_len`` is a virtual lane whose table is its own.  Otherwise the
+    XLA form: a row gather of the blocks taken; while any LIVE lane
+    (first table entry allocated) is under ``dense_len``, the step
+    gathers every lane's whole table and masks (``lax.cond``: the other
+    branch is not run).
 
     q [S, heads, d]; pools flat ([P, kv heads, d]; ``ck_pool`` [P /
     stride, ...]); ``lengths`` [S] AFTER this step.  Returns (out
@@ -1276,6 +1349,20 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
         live = block_tables[:, 0] < pool_rows // block_size
         rec = jnp.where(took & sparse_lane[:, None, None], ids, -1)
 
+    def taken_as_pages():
+        tables, lens = taken_page_tables(
+            ids, took, sparse_lane, block_tables, lengths,
+            block_size=block_size, block=block, dense_len=dense_len,
+            n_blocks=pool_rows // block_size)
+        # the lane's query once a kv head (at position ``lens - 1`` of
+        # what its table spans); a page carries every kv head, so a
+        # virtual lane keeps its own head's share of what comes back
+        out = paged_flash_decode(
+            jnp.repeat(q[:, None], g, axis=0), k_pool, v_pool, tables, lens,
+            block_size=block_size, interpret=interpret,
+        ).reshape(s_, g, g, per, d)
+        return jnp.stack([out[:, h, h] for h in range(g)], axis=1)
+
     def finish(scores, mask, values, spec):
         scores = jnp.where(mask[:, :, None], scores * scale, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
@@ -1312,7 +1399,11 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
             scores, mask, gather_paged_kv(v_pool, block_tables, block_size),
             "sgpk,skgd->sgpd")
 
-    out = jax.lax.cond(
-        jnp.any(live & ~sparse_lane), whole_tables, taken_rows_only, None)
+    if interpret or sparse_paged_chosen(
+            use_flash, mesh, k_pool.dtype, g, d, block, block_size):
+        out = taken_as_pages()
+    else:
+        out = jax.lax.cond(
+            jnp.any(live & ~sparse_lane), whole_tables, taken_rows_only, None)
     rec = jnp.moveaxis(rec, 1, 0)  # [g, S, kk]
     return out.reshape(s_, hq, d).astype(q.dtype), _pad_record(rec, topk)

@@ -450,3 +450,32 @@ class TestCompilesForTheChip:
             line for line in hlo.splitlines()
             if f" = bf16[40,{k},{n}]" in line and " parameter(" not in line
         ]
+
+    @pytest.mark.parametrize("lanes, pool_rows", [(4, 38912), (4, 77824)],
+                             ids=["served", "the-comparison"])
+    def test_the_sparse_step_at_minicpm_salas_widths(
+            self, topo, lanes, pool_rows):
+        """ISSUE 46: ``sparse_decode_attention`` under ``use_flash`` — the
+        paged kernel at 2 kv heads x 16 query rows over 8 virtual lanes of
+        a 512-entry table of 8 KB pages — for the pool the cell serves and
+        the comparison's; no array of the taken rows is written."""
+        from jax.sharding import SingleDeviceSharding
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32 = jnp.bfloat16, jnp.int32
+        hlo = jax.jit(lambda *args: A.sparse_decode_attention(
+            *args, block_size=BS, kernel_size=32, stride=16, block=64,
+            topk=64, init_blocks=1, window=2048, dense_len=8192,
+            use_flash=True,
+        )).lower(
+            arg((lanes, 32, 128), bf16), arg((pool_rows, 2, 128), bf16),
+            arg((pool_rows, 2, 128), bf16),
+            arg((pool_rows // 16, 2, 128), bf16), arg((lanes, 608), i32),
+            arg((lanes,), i32)).compile().as_text()
+        assert "_paged_decode_kernel" in hlo and "conditional" not in hlo
+        assert f"bf16[{lanes},2,4096,128]" not in hlo
+        assert f"bf16[{lanes * 2 * 4096},128]" not in hlo

@@ -734,6 +734,127 @@ def test_salas_programs_lower_to_the_text_they_lowered_to(
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
+# ---- ISSUE 46: SALA's decode step under ``use_flash`` reads pages -------------
+
+# the toy at a geometry the paged kernel reads (two bf16 kv heads x 128)
+# and blocks of two pages: what an engine that saw a TPU builds
+SALA_PAGED_TOY = dataclasses.replace(
+    SALA_TOY, dtype="bfloat16", head_dim=128, sparse_block_size=32)
+PAGED_KERNEL = "_paged_decode_kernel"
+PAGED_KERNEL_CALL = "call @_paged_attend_local"
+# [S, g, topk x block, d]: what ``taken_rows_only`` gathers a sparse layer
+TAKEN_ROWS = "tensor<4x2x128x128xbf16>"
+
+
+@pytest.mark.parametrize("use_flash", [False, True], ids=["xla", "flash"])
+def test_salas_decode_program_reads_the_taken_blocks_as_pages_under_flash(
+        use_flash):
+    """The paged kernel lowered ONCE and called once a sparse layer, no
+    gather of the taken rows and no ``cond`` between two forms; without
+    ``use_flash`` the XLA form as it stands, and no kernel."""
+    package = arch.load({"architecture": "minicpm_sala"})
+    text = _batcher_programs_lowered(
+        package, SALA_PAGED_TOY, use_flash)["decode"]
+    layers = len(hybrid.sparse_layers(SALA_PAGED_TOY))
+    assert layers == 2
+    if use_flash:
+        assert text.count(PAGED_KERNEL_CALL) == layers
+        assert text.count("tpu_custom_call") == 1 and PAGED_KERNEL in text
+        assert TAKEN_ROWS not in text
+        assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    else:
+        assert PAGED_KERNEL not in text and "tpu_custom_call" not in text
+        assert text.count(TAKEN_ROWS) >= 2 * layers  # K and V a layer
+
+
+@pytest.fixture
+def paged_kernel_interpreted(monkeypatch):
+    """``paged_flash_decode`` as the forward calls it, interpreted: the
+    one thing a CPU cannot take from it."""
+    import importlib
+
+    attention = importlib.import_module("docqa_tpu.ops.attention")
+    real = attention.paged_flash_decode
+    monkeypatch.setattr(
+        attention, "paged_flash_decode",
+        lambda *args, **kw: real(*args, **{**kw, "interpret": True}))
+
+
+def test_the_batcher_counts_the_chunks_that_read_the_taken_blocks_as_pages(
+        paged_kernel_interpreted):
+    """An engine that saw a TPU (``use_flash``): every decode chunk's
+    sparse layers read the blocks taken through the paged kernel —
+    interpreted here — and the counter over ``serve_decode_chunks`` reads
+    1.0; the tokens and what the record sums to are the XLA form's."""
+    from docqa_tpu.engines.serve import ContinuousBatcher
+    from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
+
+    package = arch.load({"architecture": "minicpm_sala"})
+    params = package.weights.make_decoder_params(SALA_PAGED_TOY, 3)
+    names = ("serve_decode_chunks", "serve_sparse_paged_chunks",
+             "serve_sparse_blocks_selected", "serve_sparse_blocks_live",
+             "serve_sparse_dense_lane_steps", "serve_state_lane_steps")
+    # one lane selects from its first step, one crosses dense_len (40)
+    # while it decodes: both kinds of virtual lane in one chunk
+    prompts = [[5 + (11 * i + j) % 250 for j in range(150 - 115 * i)]
+               for i in range(2)]
+    gen = dataclasses.replace(
+        GenerateConfig(), speculative_k=0, prefix_cache=False, decode_chunk=4,
+        max_concurrent=2)
+    got, gained = {}, {}
+    for flash in (True, False):
+        before = {n: DEFAULT_REGISTRY.counter(n).value for n in names}
+        engine = GenerateEngine(
+            SALA_PAGED_TOY, gen=gen, params=params, use_flash=flash)
+        assert engine.use_flash == flash
+        b = ContinuousBatcher(engine, n_slots=2, chunk=4, cache_len=256,
+                              kv_block_size=16, prefix_cache=False)
+        try:
+            assert b._sparse_paged == flash
+            got[flash] = [list(h.result(timeout=600)) for h in
+                          [b.submit_ids(p, max_new_tokens=10) for p in prompts]]
+        finally:
+            b.stop()
+        gained[flash] = {
+            n: DEFAULT_REGISTRY.counter(n).value - before[n] for n in names}
+        assert gained[flash]["serve_decode_chunks"] > 0
+        assert gained[flash]["serve_sparse_paged_chunks"] == (
+            gained[flash]["serve_decode_chunks"] if flash else 0)
+    assert got[True] == got[False]
+    assert gained[True]["serve_sparse_dense_lane_steps"] > 0
+    for name in names[2:]:
+        assert gained[True][name] == gained[False][name], name
+
+
+def test_one_step_of_both_forms_leaves_the_same_record(
+        paged_kernel_interpreted):
+    """``paged_decode_forward`` with and without ``use_flash`` on the same
+    pools: the record to the last id, the logits to bfloat16's rounding."""
+    package = arch.load({"architecture": "minicpm_sala"})
+    cfg = SALA_PAGED_TOY
+    params = package.weights.make_decoder_params(cfg, 5)
+    rng = np.random.default_rng(1)
+    n_blocks, lanes = 48, 3
+    pools = paged.init_paged_pools(cfg, n_blocks, 16, n_lanes=lanes)
+    pools = {
+        k: jnp.asarray(rng.standard_normal(v.shape, np.float32), v.dtype)
+        if k[0] in "kvc" and k[1:].lstrip("k").isdigit() else v
+        for k, v in pools.items()}
+    tables = jnp.asarray(rng.permutation(n_blocks).reshape(lanes, 16), jnp.int32)
+    tok = jnp.asarray(rng.integers(5, 256, (lanes, 1)), jnp.int32)
+    lengths = jnp.asarray([200, 39, 20], jnp.int32)  # selects, AT 40, under
+    out = {
+        flash: paged.paged_decode_forward(
+            params, cfg, dict(pools), tables, tok, lengths, block_size=16,
+            rope_len=256, use_flash=flash)
+        for flash in (True, False)}
+    record = np.asarray(out[False][2])
+    assert (np.asarray(out[True][2]) == record).all()
+    assert (record[:, :2] >= 0).any(-1).all() and (record[:, 2] == -1).all()
+    err = rel_err(np.asarray(out[True][0]), np.asarray(out[False][0]))
+    assert err.max() < 0.02
+
+
 # ---- who scans: the kernel under ``use_flash``, the XLA form otherwise ------
 
 # sha256 (first 16 hex digits) and length of the lowered text of the toy
