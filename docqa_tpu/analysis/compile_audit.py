@@ -51,6 +51,7 @@ the budget format and amendment workflow.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import math
@@ -61,11 +62,6 @@ from typing import Any, Dict, List, Optional, Sequence
 # (GenerateEngine.decode_memory_analysis) — it lives in utils because
 # engines must never import the lint tree
 from docqa_tpu.utils import compiled_memory_stats as memory_of
-
-WORKLOADS = (
-    "serve", "serve_latent", "serve_hybrid", "serve_ssm", "serve_loop", "generate", "retrieve_fused", "seq2seq",
-    "encoder",
-)
 
 # headroom factor applied when a ceiling must grow (or is first written):
 # measured bytes wobble a few percent across jaxlib versions; a regression
@@ -190,6 +186,24 @@ def _audit_hybrid_cfg():
     )
 
 
+def _audit_loop_cfg():
+    """The GQA block's looped trunk, with the sandwich norms."""
+    return dataclasses.replace(
+        _audit_decoder_cfg(), loop_steps=4, sandwich_norm=True)
+
+
+# the serving workloads: one toy configuration a block kind the batcher
+# serves (``_audit_serve``); what a kind is served without, the audit
+# reads from the kind's record (``models/serving.BlockServing``)
+SERVE_CFGS = {
+    "serve": _audit_decoder_cfg,
+    "serve_latent": _audit_latent_cfg,
+    "serve_hybrid": _audit_hybrid_cfg,
+    "serve_ssm": _audit_ssm_cfg,
+    "serve_loop": _audit_loop_cfg,
+}
+
+
 def _audit_gen_cfg():
     from docqa_tpu.config import GenerateConfig
 
@@ -221,8 +235,7 @@ def _audit_encoder_cfg():
 # ---------------------------------------------------------------------------
 
 
-def _audit_serve(latent: bool = False, hybrid: bool = False,
-                 ssm: bool = False, looped: bool = False) -> Dict[str, Any]:
+def _audit_serve(workload: str = "serve") -> Dict[str, Any]:
     """The PAGED batcher's whole compile surface: one ragged prefill
     program per packed token budget (<= 2) plus the one block-table
     decode chunk — the collapse from the pre-paged (2 shape families x
@@ -231,42 +244,24 @@ def _audit_serve(latent: bool = False, hybrid: bool = False,
     MIXED prompt lengths AFTER warmup; both must hit warm programs (mixed
     lengths sharing one program is the point of ragged prefill).
 
-    ``latent``: the same batcher over the latent block (workload
-    ``serve_latent``): its cold prefill budgets and its decode chunk,
-    which carries the expert-choice sums.  That block prefills cold only
-    (prefix cache off, no speculation): there is no warm family.
-
-    ``hybrid``: the same over the stack of mixer kinds (workload
-    ``serve_hybrid``, a sparse and a linear layer): cold prefill budgets,
-    and a decode chunk that advances lane states and carries the selection
-    sums.  ``ssm``: the same stack's other two kinds (workload
-    ``serve_ssm``, a state-space and a plain attention layer): a prefill
-    that convolves and scans, a decode chunk that advances windows and
-    states and carries no sums.
-
-    ``looped``: the GQA block's looped trunk (workload ``serve_loop``:
-    ``loop_steps`` 4 with the sandwich norms): cold prefill budgets and a
-    decode chunk whose ``while`` nests the step loop over pools of four
-    ranges; no warm family, no speculation (refused by name)."""
-    import dataclasses
-
+    ``workload`` names the toy configuration (``SERVE_CFGS``), and its
+    roots carry the name.  What its block kind is not served with
+    (``BlockServing.unserved``) is turned off, so a kind that prefills
+    cold only has no warm family; its decode chunk carries the kind's
+    sums, advances its lane states, nests its step loop."""
     import jax
     import jax.numpy as jnp
 
     from docqa_tpu.engines.generate import GenerateEngine
     from docqa_tpu.engines.paged import kv_bytes_per_token
     from docqa_tpu.engines.serve import ContinuousBatcher
+    from docqa_tpu.models.decoder import block_serving
 
-    cfg, gen = _audit_decoder_cfg(), _audit_gen_cfg()
-    if latent:
-        cfg = _audit_latent_cfg()
-        gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
-    if hybrid or ssm:
-        cfg = _audit_ssm_cfg() if ssm else _audit_hybrid_cfg()
-        gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
-    if looped:
-        cfg = dataclasses.replace(cfg, loop_steps=4, sandwich_norm=True)
-        gen = dataclasses.replace(gen, speculative_k=0, prefix_cache=False)
+    cfg, gen = SERVE_CFGS[workload](), _audit_gen_cfg()
+    off = {"generate.prefix_cache": {"prefix_cache": False},
+           "generate.speculative_k": {"speculative_k": 0}}
+    for setting in block_serving(cfg).unserved:
+        gen = dataclasses.replace(gen, **off.get(setting, {}))
     engine = GenerateEngine(cfg, gen)
     # cache_len 256: large enough that the 128-aligned prefix cache is
     # ENABLED (share_alignment < seq_capacity), so the warm prefill
@@ -431,14 +426,10 @@ def _audit_serve(latent: bool = False, hybrid: bool = False,
         }
         if not batcher.prefix_cache_enabled:
             del report["roots"]["serve_prefill_warm"]
-        if latent or hybrid or ssm or looped:
-            prefix = ("serve_latent_" if latent else
-                      "serve_ssm_" if ssm else
-                      "serve_loop_" if looped else "serve_hybrid_")
-            report["roots"] = {
-                name.replace("serve_", prefix): root
-                for name, root in report["roots"].items()
-            }
+        report["roots"] = {
+            name.replace("serve", workload, 1): root
+            for name, root in report["roots"].items()
+        }
         return report
     finally:
         batcher.stop()
@@ -602,15 +593,16 @@ def _audit_encoder() -> Dict[str, Any]:
 
 _AUDITS = {
     "serve": _audit_serve,
-    "serve_latent": functools.partial(_audit_serve, latent=True),
-    "serve_hybrid": functools.partial(_audit_serve, hybrid=True),
-    "serve_ssm": functools.partial(_audit_serve, ssm=True),
-    "serve_loop": functools.partial(_audit_serve, looped=True),
+    **{name: functools.partial(_audit_serve, name)
+       for name in SERVE_CFGS if name != "serve"},
     "generate": _audit_generate,
     "retrieve_fused": _audit_retrieve,
     "seq2seq": _audit_seq2seq,
     "encoder": _audit_encoder,
 }
+
+# every workload the audit drives, in report order
+WORKLOADS = tuple(_AUDITS)
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,7 @@ format and how to amend it deliberately.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import os
@@ -59,28 +60,6 @@ MESH_SHAPES: Dict[str, Tuple[int, int]] = {
     "2x4": (2, 4),
     "1x8": (1, 8),
 }
-
-AUDIT_PROGRAMS = (
-    "decoder_decode",
-    "decoder_prefill",
-    "decoder_paged_decode",
-    "decoder_ragged_prefill",
-    "latent_paged_decode",
-    "latent_ragged_prefill",
-    "hybrid_paged_decode",
-    "hybrid_ragged_prefill",
-    "ssm_paged_decode",
-    "ssm_ragged_prefill",
-    "loop_paged_decode",
-    "loop_ragged_prefill",
-    "ring_attention",
-    "ulysses_attention",
-    "retrieve_fused",
-    "retrieve_ivf_sharded",
-    "retrieve_lexical_sharded",
-    "retrieve_hybrid_sharded",
-)
-
 
 def default_budget_path() -> str:
     pkg_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -224,6 +203,24 @@ def _audit_hybrid_cfg():
     )
 
 
+def _audit_loop_cfg():
+    """The GQA block's looped trunk, with the sandwich norms."""
+    return dataclasses.replace(
+        _audit_decoder_cfg(), loop_steps=4, sandwich_norm=True)
+
+
+# the paged programs' toy configurations, one a block kind the batcher
+# serves (``_audit_paged``: programs ``<name>_paged_decode`` /
+# ``<name>_ragged_prefill``)
+PAGED_CFGS = {
+    "decoder": _audit_decoder_cfg,
+    "latent": _audit_latent_cfg,
+    "hybrid": _audit_hybrid_cfg,
+    "ssm": _audit_ssm_cfg,
+    "loop": _audit_loop_cfg,
+}
+
+
 def _audit_encoder_cfg():
     from docqa_tpu.config import EncoderConfig
 
@@ -327,9 +324,7 @@ def _audit_decoder(mesh_name: str, prefill: bool, pspec_fn=None):
     return counts, meta
 
 
-def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
-                 hybrid: bool = False, ssm: bool = False,
-                 looped: bool = False):
+def _audit_paged(mesh_name: str, prefill: bool, kind: str = "decoder"):
     """Lower the PAGED serving programs (engines/paged.py) under the
     same Megatron layout: the block-pool gather/scatter must not change
     the collective story — still exactly one all-reduce per Megatron
@@ -338,9 +333,13 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     that unsharded axis).  This is the ISSUE's "unchanged collective
     budget" evidence for the paged KV tentpole.
 
+    ``kind`` names the toy configuration (``PAGED_CFGS``); what its pools
+    and its parameters take beyond the GQA rules is its record's
+    (``models/serving.BlockServing``).
+
     ``latent``: the same two programs of the latent block, whose row pool
     is replicated and whose routed experts ride the ``model`` axis on
-    their expert axis (parallel/sharding._latent_param_pspecs).  They have
+    their expert axis (models/latent.latent_param_pspecs).  They have
     to LOWER on every mesh; their collectives are recorded, not held to
     the Megatron count — GSPMD's handling of a per-expert loop over a
     sharded expert axis is not the exchange a deployment would run, and no
@@ -353,49 +352,33 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
     (the state-space mixer divided along its inner channels, windows and
     states replicated with the rows of the one kv head).
 
-    ``looped``: the GQA block's looped trunk (``loop_steps`` 4 with the
+    ``loop``: the GQA block's looped trunk (``loop_steps`` 4 with the
     sandwich norms): the step loop is ONE loop in the program, so the
     text holds each Megatron block's all-reduce once — the same count as
     the plain trunk, and held to it — and the pools, four ranges of rows
     along their unsharded row axis, keep their kv heads over ``model``."""
-    import dataclasses
-
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from docqa_tpu.engines.paged import (
+        init_paged_pools,
         paged_decode_forward,
         ragged_prefill_forward,
     )
-    from docqa_tpu.models.decoder import kv_row_shapes
+    from docqa_tpu.models.decoder import block_serving, kv_entries
     from docqa_tpu.parallel.sharding import (
         decoder_param_pspecs,
         paged_pool_pspecs,
     )
 
-    cfg = _audit_latent_cfg() if latent else _audit_decoder_cfg()
-    if looped:
-        cfg = dataclasses.replace(cfg, loop_steps=4, sandwich_norm=True)
-    hybrid = hybrid or ssm
-    if hybrid:
-        cfg = _audit_ssm_cfg() if ssm else _audit_hybrid_cfg()
+    cfg = PAGED_CFGS[kind]()
     mesh = _mesh(mesh_name)
     slots, block_size, n_blocks = 4, 8, 16
     rope_len = 32
     params, _cache, _ids, _lengths = _decoder_abstract_args(cfg, slots, 1, 8)
-    pools = {
-        f"{kv}{i}": jax.ShapeDtypeStruct(
-            (n_blocks * block_size, heads, width), jnp.bfloat16,
-        )
-        for i in range(cfg.num_layers)
-        for kv, (heads, width) in kv_row_shapes(cfg).items()
-    }
-    if hybrid or looped:
-        from docqa_tpu.engines.paged import init_paged_pools
-
-        pools = jax.eval_shape(
-            lambda: init_paged_pools(cfg, n_blocks, block_size))
+    pools = jax.eval_shape(
+        lambda: init_paged_pools(cfg, n_blocks, block_size))
     pspecs = decoder_param_pspecs(cfg, mesh.model_axis)
     pool_specs = paged_pool_pspecs(cfg, mesh)
     replicated = NamedSharding(mesh.mesh, P())
@@ -457,13 +440,15 @@ def _audit_paged(mesh_name: str, prefill: bool, latent: bool = False,
         "block_size": block_size,
         "model_parallel": mesh.n_model,
     }
-    if latent:
+    # by what the configuration says of itself: a kind with layers of its
+    # own is not held to the Megatron count
+    if block_serving(cfg).param_pspecs is not None:
         del meta["megatron_blocks"]
+    if cfg.num_experts:
         meta["experts_held"] = cfg.experts_held
-    if hybrid:
-        del meta["megatron_blocks"]
+    if cfg.mixer_types:
         meta["mixer_types"] = list(cfg.mixer_types)
-    if looped:
+    if kv_entries(cfg) != 1:
         meta["loop_steps"] = cfg.loop_steps
     return counts, meta
 
@@ -766,32 +751,13 @@ def _audit_retrieve_hybrid(mesh_name: str):
 _AUDITS: Dict[str, Callable[[str], Tuple[Dict[str, int], Dict[str, Any]]]] = {
     "decoder_decode": functools.partial(_audit_decoder, prefill=False),
     "decoder_prefill": functools.partial(_audit_decoder, prefill=True),
-    "decoder_paged_decode": functools.partial(_audit_paged, prefill=False),
-    "decoder_ragged_prefill": functools.partial(_audit_paged, prefill=True),
-    "latent_paged_decode": functools.partial(
-        _audit_paged, prefill=False, latent=True
-    ),
-    "latent_ragged_prefill": functools.partial(
-        _audit_paged, prefill=True, latent=True
-    ),
-    "hybrid_paged_decode": functools.partial(
-        _audit_paged, prefill=False, hybrid=True
-    ),
-    "hybrid_ragged_prefill": functools.partial(
-        _audit_paged, prefill=True, hybrid=True
-    ),
-    "ssm_paged_decode": functools.partial(
-        _audit_paged, prefill=False, ssm=True
-    ),
-    "ssm_ragged_prefill": functools.partial(
-        _audit_paged, prefill=True, ssm=True
-    ),
-    "loop_paged_decode": functools.partial(
-        _audit_paged, prefill=False, looped=True
-    ),
-    "loop_ragged_prefill": functools.partial(
-        _audit_paged, prefill=True, looped=True
-    ),
+    **{
+        f"{kind}_{program}": functools.partial(
+            _audit_paged, prefill=prefill, kind=kind)
+        for kind in PAGED_CFGS
+        for program, prefill in (
+            ("paged_decode", False), ("ragged_prefill", True))
+    },
     "ring_attention": _audit_ring,
     "ulysses_attention": _audit_ulysses,
     "retrieve_fused": _audit_retrieve,
@@ -799,6 +765,9 @@ _AUDITS: Dict[str, Callable[[str], Tuple[Dict[str, int], Dict[str, Any]]]] = {
     "retrieve_lexical_sharded": _audit_retrieve_lexical,
     "retrieve_hybrid_sharded": _audit_retrieve_hybrid,
 }
+
+# every program the audit lowers, in report order
+AUDIT_PROGRAMS = tuple(_AUDITS)
 
 
 # ---------------------------------------------------------------------------

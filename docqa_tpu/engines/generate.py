@@ -29,26 +29,13 @@ from docqa_tpu.config import DecoderConfig, GenerateConfig
 from docqa_tpu.models.decoder import (
     KVCache,
     Params,
-    check_loop_config,
+    block_serving,
     decoder_forward,
     init_decoder_params,
     init_kv_cache,
-)
-from docqa_tpu.models.hybrid import (
-    ATTENTION,
-    HYBRID_BLOCK,
-    MAMBA,
-    SPARSE,
-    check_hybrid_config,
-    is_hybrid,
-)
-from docqa_tpu.models.latent import (
-    LATENT_BLOCK,
-    check_latent_config,
-    is_latent,
+    kernel_forms,
 )
 from docqa_tpu.engines.spine import spine_run
-from docqa_tpu.ops.attention import paged_kernel_supported
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.parallel.sharding import cache_pspecs, shard_decoder_params
 from docqa_tpu.runtime.mesh import MeshContext
@@ -164,7 +151,10 @@ class GenerateEngine:
             self._chat_template: Optional[str] = resolved
         else:
             self._chat_template = None
-        check_loop_config(cfg)  # before a tree is drawn for it
+        # what the block kind asks of its surroundings (models/serving.py);
+        # a configuration it cannot run is refused here, by field, before
+        # a tree is drawn for it
+        self.block = block_serving(cfg)
         if params is None:
             if cfg.quantize_weights:
                 from docqa_tpu.models.quant import (
@@ -217,25 +207,17 @@ class GenerateEngine:
         self.params = params
         if use_flash is None:
             use_flash = jax.default_backend() == "tpu" and cfg.head_dim % 64 == 0
-        if is_latent(cfg):
-            # served by the batcher over the paged latent pool only; no
-            # Pallas kernel reads that pool yet (ops/attention.py)
-            check_latent_config(cfg)
-            use_flash = False
-        if is_hybrid(cfg):
-            # the batcher over the paged rows and the lane state only; the
-            # linear scan and the selection are XLA, the paged decode
-            # kernel reads the plain attention layers and — where it
-            # reads this geometry — the blocks a sparse layer's decode
-            # step took, and a state-space layer's prefill scan has a
-            # kernel of its own (ops/ssm.py)
-            check_hybrid_config(cfg)
-            kinds = set(cfg.mixer_types)
-            use_flash = bool(use_flash) and bool(
-                {ATTENTION, MAMBA} & kinds
-                or SPARSE in kinds and paged_kernel_supported(
-                    cfg.dtype, cfg.num_kv_heads, cfg.head_dim))
-        self.use_flash = use_flash
+        # THE observation every choice of kernel goes by — a TPU whose
+        # kernels read this head width, or what the caller said:
+        # ``kernel_forms(block_size=)`` is the Pallas forms
+        # (``models/serving.KernelForms``) the paged forwards over pools of
+        # such pages run for this engine; the batcher hands them to its
+        # programs and counts by them
+        self.kernel_forms = functools.partial(
+            kernel_forms, cfg, on_tpu=bool(use_flash), mesh=mesh)
+        # kept only by a kind one of whose kernels the flag reaches (the
+        # warm-up checks kernels against their references by it)
+        self.use_flash = bool(use_flash) and self.block.uses_flash
         self._fns = {}
 
     # ---- device program ------------------------------------------------------
@@ -467,27 +449,9 @@ class GenerateEngine:
         return out, n_emit
 
     def _get_fn(self, b: int, bucket: int, max_new: int, greedy: bool):
-        if is_latent(self.cfg):
+        if self.block.solo is not None:
             raise NotImplementedError(
-                f'the solo dense-cache engine has no "{LATENT_BLOCK}" block '
-                "(model_type deepseek_v2): generate through the batcher "
-                "(engines/serve.ContinuousBatcher), which serves it over the "
-                "paged latent cache"
-            )
-        if is_hybrid(self.cfg):
-            raise NotImplementedError(
-                f'the solo dense-cache engine has no "{HYBRID_BLOCK}" block: '
-                "generate through the batcher "
-                "(engines/serve.ContinuousBatcher), which serves it over the "
-                "paged rows and the lane state"
-            )
-        if self.cfg.loop_steps > 1:
-            raise NotImplementedError(
-                "the solo dense-cache engine runs loop_steps 1 only (got "
-                f"{self.cfg.loop_steps}): generate through the batcher "
-                "(engines/serve.ContinuousBatcher), which serves the looped "
-                "trunk over the paged cache, an entry a (step, layer)"
-            )
+                "the solo dense-cache engine " + self.block.solo)
         spec_k = self.gen.speculative_k
         if greedy and spec_k >= 2:
             key = (b, bucket, max_new, "spec", spec_k)
@@ -608,7 +572,7 @@ class GenerateEngine:
           serves) through a SCATTERED block table — ragged lengths, a
           page boundary, a free lane, hole entries past each length —
           against the gather reference, when the kernel reads this
-          geometry (``paged_kernel_supported``).
+          geometry (``kernel_forms``'s ``paged``).
 
         Raises when a pair disagrees: a kernel that compiles but computes
         something else must fail the warm-up, not serve.  Tolerance: both
@@ -618,7 +582,6 @@ class GenerateEngine:
             attention_reference,
             flash_attention,
             paged_decode_attention,
-            paged_kernel_supported,
         )
 
         cfg, tol = self.cfg, 2.0 ** -6
@@ -634,9 +597,7 @@ class GenerateEngine:
         # table entries a lane; lengths end inside a page, on a page
         # boundary, at 0 (free lane: all holes) and at the table's span
         block_size, n_pages, per_lane = self.gen.kv_block_size, 64, 16
-        paged = paged_kernel_supported(
-            dtype, cfg.num_kv_heads, cfg.head_dim, self.mesh
-        )
+        paged = self.kernel_forms(block_size=block_size).paged
         lane_lens = np.tile(
             np.array([block_size * 5 - 3, block_size * 4, 0,
                       block_size * per_lane], np.int32), b,

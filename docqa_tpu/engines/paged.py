@@ -45,8 +45,10 @@ import jax.numpy as jnp
 from docqa_tpu.config import DecoderConfig
 from docqa_tpu.models.decoder import (
     Params,
+    block_serving,
     decoder_head,
     decoder_layer_stack,
+    kernel_forms,
     kv_entries,
     kv_row_shapes,
     lane_state_shapes,
@@ -56,6 +58,7 @@ from docqa_tpu.models.hybrid import (
     LINEAR,
     MAMBA,
     SPARSE,
+    STATE_SLOT,
     decay_slopes,
     hybrid_head,
     hybrid_layer_stack,
@@ -73,6 +76,7 @@ from docqa_tpu.models.latent import (
     softmax_scale,
     up_projected,
 )
+from docqa_tpu.models.serving import KernelForms
 from docqa_tpu.ops.attention import (
     RAGGED_ALIGN,
     compressed_keys,
@@ -98,7 +102,6 @@ from docqa_tpu.ops.ssm import (
 # where one selects), the lane-state entries of its state-keeping layers
 # and STATE_SLOT (``_init_hybrid_pools``)
 PagedPools = Dict[str, "jnp.ndarray"]
-STATE_SLOT = "state_slot"
 
 
 class OutOfBlocks(RuntimeError):
@@ -712,6 +715,21 @@ def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     return kv_entries(cfg) * cfg.num_layers * per_layer * item
 
 
+def _forms(cfg, kernels, use_flash, mesh, block_size) -> KernelForms:
+    """The kernel forms a public forward runs: the engine's, handed down
+    by the batcher (``kernels``); else derived here as the engine derives
+    them, from the ``use_flash`` / ``mesh`` of a caller without an engine
+    (the benchmark's comparison).  The backend is asked for a caller that
+    observed nothing (``None``) and for the latent block, whose
+    ``use_flash`` says nothing of it (its engine keeps the flag false)."""
+    if kernels is not None:
+        return kernels
+    if use_flash is None or is_latent(cfg):
+        use_flash = jax.default_backend() == "tpu"
+    return kernel_forms(
+        cfg, on_tpu=use_flash, mesh=mesh, block_size=block_size)
+
+
 def ragged_prefill_forward(
     params: Params,
     cfg: DecoderConfig,
@@ -729,6 +747,7 @@ def ragged_prefill_forward(
     block_size: Optional[int] = None,
     use_flash: Optional[bool] = None,
     mesh=None,
+    kernels: Optional[KernelForms] = None,
 ):
     """Prefill a whole admission round of MIXED-length prompts in one
     dispatch: every token computes through the shared trunk, scatters its
@@ -753,44 +772,26 @@ def ragged_prefill_forward(
     The latent block (``cfg.block``) returns a THIRD value, its routing
     record (:func:`_latent_prefill_forward`).
 
-    ``use_flash`` / ``mesh`` are what the engine observed (as
-    :func:`paged_decode_forward` takes them) and reach the prefill ops
-    that have a Pallas form: a state-space layer's scan (``ops/ssm.py``)
-    and, by ``mesh`` alone, a routed layer's grouped product
-    (``ops/grouped.py``).
-    A caller that observed nothing (``None``: the benchmark's comparison)
-    gets the backend's own answer, as ``GenerateEngine`` derives it.
+    ``kernels``: the forms the engine chose, for the prefill ops that
+    have a Pallas form — a state-space layer's scan (``ops/ssm.py``), a
+    routed layer's grouped product (``ops/grouped.py``); derived from
+    ``use_flash`` / ``mesh`` for a caller without an engine (:func:`_forms`).
     """
+    kernels = _forms(cfg, kernels, use_flash, mesh, block_size)
+    warm = n_prefix_rows > 0  # static host int, never a tracer
+    if warm and "generate.prefix_cache" in block_serving(cfg).unserved:
+        raise NotImplementedError(  # why: the kind's record, models/
+            f"{block_serving(cfg).label} prefills cold only: set "
+            "generate.prefix_cache false")
     if is_latent(cfg):
-        if n_prefix_rows:
-            raise NotImplementedError(
-                "the latent block prefills cold only: set "
-                "generate.prefix_cache false (a warm prefill would "
-                "up-project cached rows, which no path here does)"
-            )
         return _latent_prefill_forward(
             params, cfg, pools, ids, seg_ids, positions, dest_rows,
-            last_rows, rope_len, mesh,
+            last_rows, rope_len, kernels.grouped,
         )
     if is_hybrid(cfg):
-        if n_prefix_rows:
-            raise NotImplementedError(
-                "the stack of mixer kinds prefills cold only: set "
-                "generate.prefix_cache false (a shared prefix is a run of "
-                "pages, and a lane's state at the share boundary is in none)"
-            )
-        if use_flash is None:
-            use_flash = jax.default_backend() == "tpu"
         return _hybrid_prefill_forward(
             params, cfg, pools, ids, seg_ids, positions, dest_rows,
-            last_rows, rope_len, use_flash, mesh,
-        )
-    warm = n_prefix_rows > 0  # static host int, never a tracer
-    if warm and kv_entries(cfg) > 1:
-        raise NotImplementedError(
-            "the looped trunk (loop_steps > 1) prefills cold only: set "
-            "generate.prefix_cache false (a warm prefill through the "
-            "steps' ranges is untested)"
+            last_rows, rope_len, kernels,
         )
     n_rows = pools["k0"].shape[0] // kv_entries(cfg)
 
@@ -844,11 +845,14 @@ def paged_decode_forward(
     rope_len: int,
     use_flash: bool = False,
     mesh=None,  # MeshContext: the paged kernel shards over it (ops/attention)
+    kernels: Optional[KernelForms] = None,
 ):
     """Advance every lane ``s`` tokens against the block pool: write each
     new token's K/V at its table-mapped row (in place: a scatter of
     ``S x s`` rows into the donated pool), attend through the table —
-    with ``use_flash`` by a kernel that reads only each lane's live pages.
+    under ``kernels.paged`` by a kernel that reads only each lane's live
+    pages (``kernels``: the engine's choice, or derived from ``use_flash``
+    / ``mesh`` as :func:`ragged_prefill_forward` says).
 
     Writes whose position falls past a lane's allocated blocks (hole
     entries / retired lanes whose table row went sentinel) are DROPPED —
@@ -857,20 +861,20 @@ def paged_decode_forward(
     belongs to an inactive lane re-writing its scratch row.
 
     Returns (logits [S, s, vocab] f32, pools) — and, from the latent
-    block, its routing record (:func:`_latent_decode_forward`).  For that
-    block ``use_flash`` chooses nothing: no Pallas kernel reads a latent
-    row, so its attention is the XLA gather whatever it says (GSPMD
-    places it on a mesh); ``mesh`` reaches the routed layers' grouped
-    product alone (``ops/grouped.py``)."""
+    block, its routing record (:func:`_latent_decode_forward`).  No Pallas
+    kernel reads a latent row: its attention is the XLA gather (GSPMD
+    places it on a mesh), and ``kernels.grouped`` is the form of the
+    routed layers' product alone (``ops/grouped.py``)."""
+    kernels = _forms(cfg, kernels, use_flash, mesh, block_size)
     if is_latent(cfg):
         return _latent_decode_forward(
             params, cfg, pools, block_tables, tok, lengths, block_size,
-            rope_len, mesh,
+            rope_len, kernels.grouped,
         )
     if is_hybrid(cfg):
         return _hybrid_decode_forward(
             params, cfg, pools, block_tables, tok, lengths, block_size,
-            rope_len, use_flash, mesh,
+            rope_len, kernels, mesh,
         )
     S, s = tok.shape
     nb = block_tables.shape[1]
@@ -905,7 +909,7 @@ def paged_decode_forward(
             return paged_decode_attention(
                 q, pools[f"k{i}"], pools[f"v{i}"], tables,
                 attn_lengths, block_size=block_size, q_offset=lengths,
-                sliding_window=cfg.sliding_window, use_flash=use_flash,
+                sliding_window=cfg.sliding_window, use_flash=kernels.paged,
                 mesh=mesh,
             )
 
@@ -927,7 +931,7 @@ def _with_record(logits, pools, record):
 
 
 def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
-                            dest_rows, last_rows, rope_len, mesh=None):
+                            dest_rows, last_rows, rope_len, grouped):
     """The packed prefill of the latent block: each token's ONE cache row
     is scattered to its table-mapped pool row, and attention runs over
     the rows in flight in the non-absorbed form — keys and values
@@ -954,7 +958,7 @@ def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
 
     x, record = latent_layer_stack(
         params, cfg, ids[None, :], positions[None, :], rope_len, attend,
-        mesh=mesh,
+        use_flash=grouped,
     )
     with scope("head"):
         logits = decoder_head(params, cfg, x[0][last_rows][:, None, :])
@@ -964,7 +968,7 @@ def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
 
 
 def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
-                           block_size, rope_len, mesh=None):
+                           block_size, rope_len, grouped):
     """A decode step of the latent block in the ABSORBED form: the new
     rows are written at their table-mapped pool rows, each head's query is
     carried into latent space, scores and the weighted sum are taken
@@ -1007,7 +1011,7 @@ def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
         return expand_output(params, cfg, i, o_lat)
 
     x, record = latent_layer_stack(
-        params, cfg, tok, rope_pos, rope_len, attend, mesh=mesh
+        params, cfg, tok, rope_pos, rope_len, attend, use_flash=grouped
     )
     return _with_record(decoder_head(params, cfg, x), pools, record)
 
@@ -1099,8 +1103,7 @@ def _write_rows(pools, i, dest, k, v):
 
 
 def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
-                            dest_rows, last_rows, rope_len, use_flash=False,
-                            mesh=None):
+                            dest_rows, last_rows, rope_len, kernels):
     """One packed COLD prefill dispatch of the stack of mixer kinds, one
     handler a kind.  A row-keeping layer scatters K and V rows — a SPARSE
     one also the compressed keys of the windows that lie whole inside a
@@ -1110,8 +1113,7 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
     segment ends with in the lane's entry (found through ``state_slot``
     from the segment's first destination row): a LINEAR layer its chunked
     scan's state, a state-space layer its scan's state (the Pallas kernel
-    under ``use_flash`` with no ``mesh``, ``ops/ssm.py``) and its last
-    conv inputs.
+    under ``kernels.scan``, ``ops/ssm.py``) and its last conv inputs.
 
     Returns (last_logits [B, vocab] f32, pools, selection record int32
     [sparse layers x kv heads, T, sparse_topk] of the packed rows) — two
@@ -1186,7 +1188,7 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
         with scope("state"):
             g, h = selective_scan_prefill(
                 c, delta[0], a, b_in[0], c_out[0], d_skip, seg_ids,
-                positions, last_rows, use_flash=use_flash, mesh=mesh)
+                positions, last_rows, use_flash=kernels.scan)
             pools[f"h{i}"] = pools[f"h{i}"].at[slots].set(h, mode="drop")
             return g[None], None
 
@@ -1205,16 +1207,16 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
 
 
 def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
-                           block_size, rope_len, use_flash=False, mesh=None):
+                           block_size, rope_len, kernels, mesh):
     """A decode step of the stack of mixer kinds, one token a lane, one
     handler a kind.  A row-keeping layer writes the token's K and V at its
     table-mapped row; a SPARSE one also writes the compressed key of the
     window the token COMPLETES (if it does), selects among the lane's
     compressed keys and reads the rows of the blocks taken (as pages,
-    through the paged kernel, under ``use_flash`` with no ``mesh``:
-    ``ops/attention.sparse_paged_chosen``); an ATTENTION one reads the
-    lane's live pages (``paged_decode_attention``: the paged kernel under
-    ``use_flash``).  A state-keeping layer advances the
+    through the paged kernel, under ``kernels.sparse_paged``); an
+    ATTENTION one reads the lane's live pages
+    (``paged_decode_attention``: the paged kernel under
+    ``kernels.paged``).  A state-keeping layer advances the
     lane's entries IN PLACE (read, one step, written back): a LINEAR layer
     its state, a state-space layer its conv window (shifted by the token)
     and its state.  A lane whose table starts with a hole (a retired slot)
@@ -1283,7 +1285,7 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             out, taken = sparse_decode_attention(
                 q[:, 0], pools[f"k{i}"], pools[f"v{i}"], pools[f"ck{i}"],
                 block_tables, lengths + 1, block_size=block_size, **sizes,
-                use_flash=use_flash, mesh=mesh,
+                use_flash=kernels.sparse_paged,
             )
             return out[:, None], taken[:, :, None]
 
@@ -1294,7 +1296,7 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             return paged_decode_attention(
                 q, pools[f"k{i}"], pools[f"v{i}"], block_tables,
                 lengths + 1, block_size=block_size, q_offset=lengths,
-                use_flash=use_flash, mesh=mesh,
+                use_flash=kernels.paged, mesh=mesh,
             ), None
 
     def mamba(i, u, project):

@@ -92,7 +92,6 @@ the model axis, block rows replicated); slots ride the batch axis.
 from __future__ import annotations
 
 import collections
-import functools
 import itertools
 import threading
 from dataclasses import dataclass, field
@@ -121,76 +120,15 @@ from docqa_tpu.engines.paged import (
 from docqa_tpu.engines.generate import accept_drafts, draft_tokens
 from docqa_tpu.engines.qos import QoSPolicy, request_class
 from docqa_tpu.engines.spine import spine_run, spine_submit
-from docqa_tpu.models.decoder import (
-    init_decoder_params,  # noqa: F401  (re-export convenience for tests)
-    kv_entries,
-    kv_row_shapes,
-)
-from docqa_tpu.models.hybrid import (
-    is_hybrid,
-    lane_state_bytes,
-    mamba_layers,
-    sparse_layers,
-)
-from docqa_tpu.models.latent import experts_held, is_latent, routed_layers
-from docqa_tpu.ops.attention import (
-    RAGGED_ALIGN,
-    paged_kernel_supported,
-    sparse_paged_chosen,
-)
+from docqa_tpu.ops.attention import RAGGED_ALIGN
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.ops.scopes import scope
-from docqa_tpu.ops.ssm import scan_kernel_chosen
 from docqa_tpu.resilience import faults
 from docqa_tpu.resilience.deadline import Deadline, DeadlineExceeded
 from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY, get_logger, span
 from docqa_tpu.utils import round_up
 
 log = get_logger("docqa.serve")
-
-# counters of a routing block's decode chunks, in the order the decode
-# program sums them on the device (``_moe_step_sums``) and the worker adds
-# them (``_moe_chunk_sums``): expert picks of the live lanes; those that
-# fell on an expert held here; distinct held experts touched, summed over
-# (routed layer, step); and the (routed layer, step)s with a live lane
-MOE_SUMS = (
-    "serve_moe_picks", "serve_moe_picks_local", "serve_moe_experts_touched",
-    "serve_moe_layer_steps",
-)
-# the same block's prefill dispatches (``_moe_prefill_sums``, behind the
-# first tokens in the fetch ``_finalize_admissions`` makes anyway): expert
-# picks of the packed prompt rows, and those that fell on an expert held
-# here — the row-expert products the grouped form runs
-# (``models/latent.held_experts_sum``), of rows x held had every held
-# expert run over every row
-MOE_PREFILL_SUMS = (
-    "serve_moe_prefill_picks", "serve_moe_prefill_picks_local",
-)
-
-# the same of a stack of mixer kinds in which a layer SELECTS
-# (``_sparse_step_sums`` / ``_sparse_chunk_sums``; a stack in which none
-# does carries no such row and counts its lane-steps on the host,
-# ``_count_state_steps``), over a chunk's steps and live lanes: blocks the
-# sparse layers' queries READ (the blocks taken; every live block on a
-# lane still under ``sparse_dense_len``), blocks live for them, lane-steps
-# that ran dense, and lane-steps in all (each reads and writes the lane's
-# state once: ``serve_state_bytes_rw`` is that times the state's bytes)
-SPARSE_SUMS = (
-    "serve_sparse_blocks_selected", "serve_sparse_blocks_live",
-    "serve_sparse_dense_lane_steps", "serve_state_lane_steps",
-)
-
-
-def _refuse_unserved(what: str, settings, advice: str) -> None:
-    """``ValueError`` naming every setting of ``settings`` ((name, on)
-    pairs) that is on and that ``what`` is not served with."""
-    unserved = [name for name, on in settings if on]
-    if unserved:
-        raise ValueError(
-            f"{what} is served without " + " and ".join(unserved)
-            + ": " + advice
-        )
-
 
 @dataclass
 class _Request:
@@ -751,49 +689,26 @@ class ContinuousBatcher:
             if prefix_cache is None
             else bool(prefix_cache)  # A/B + test override
         )
-        if is_latent(self.cfg):
-            # refused here, at construction, not when a request's warm
-            # prefill or verify step is traced on the worker thread: the
-            # latent block prefills cold only (a warm prefill would
-            # up-project cached rows, which no path does) and its
-            # speculative chunk would drop the routing record
-            _refuse_unserved(
-                f'DecoderConfig(block="{self.cfg.block}")',
-                (("generate.prefix_cache", want_cache),
-                 ("generate.speculative_k", self.spec_k)),
-                "set prefix_cache false and speculative_k 0",
-            )
-        if is_hybrid(self.cfg):
-            # refused at construction, by name: a shared prefix is a run of
-            # pages and a lane's state at the share boundary is in none of
-            # them; a verify step of several tokens would need the state
-            # after each; and a preempted lane resumes from pages alone
-            policy = QoSPolicy.coerce(qos)
-            _refuse_unserved(
-                f'DecoderConfig(block="{self.cfg.block}")',
-                (("generate.prefix_cache", want_cache),
-                 ("generate.speculative_k", self.spec_k),
-                 ("qos.preemption", policy is not None
-                  and policy.preemption != "off")),
-                "set prefix_cache false, speculative_k 0 and "
-                "qos.preemption off",
-            )
-        # the looped trunk (``loop_steps`` > 1): what one token costs, a
-        # gauge; what a chunk ran, two counters (``_count_loop_passes``);
-        # all absent at 1
-        self._loop_steps = kv_entries(self.cfg)
-        # on a request's ``serve_prefill`` and ``serve_decode_chunk`` spans
-        self._loop_attrs = (
-            {"loop_steps": self._loop_steps} if self._loop_steps > 1 else {}
-        )
-        if self._loop_steps > 1:
-            # refused at construction, by name: a warm prefill and a verify
-            # step through the steps' ranges of the pools are untested
-            _refuse_unserved(
-                f"DecoderConfig(loop_steps={self._loop_steps})",
-                (("generate.prefix_cache", want_cache),
-                 ("generate.speculative_k", self.spec_k)),
-                "set prefix_cache false and speculative_k 0",
+        # what the block kind asks of its surroundings (the record its
+        # model module keeps, ``models/serving.py``) and the kernels its
+        # programs run (the engine's choice, by what it observed): the
+        # batcher reads both and branches on no kind
+        self._block = self.engine.block
+        self._kernels = self.engine.kernel_forms(block_size=self.block_size)
+        # refused here, at construction, by name — not when a request's
+        # warm prefill or verify step is traced on the worker thread
+        policy = QoSPolicy.coerce(qos)
+        on = {
+            "generate.prefix_cache": want_cache,
+            "generate.speculative_k": self.spec_k,
+            "qos.preemption": policy is not None
+            and policy.preemption != "off",
+        }
+        refused = [name for name in self._block.unserved if on[name]]
+        if refused:
+            raise ValueError(
+                f"{self._block.label} is served without "
+                + " and ".join(refused) + ": " + self._block.advice
             )
         if want_cache and self._share_align < self.seq_capacity:
             self._prefix_cache = PrefixCache(
@@ -902,48 +817,13 @@ class ContinuousBatcher:
         # Mosaic custom calls in the lowered decode program, counted by
         # annotate_costs (None until then; 0 = XLA reference attention)
         self.decode_kernel_calls: Optional[int] = None
-        # which attention the decode program was built with: the kernel
-        # reads live pages in place, the reference gathers every table
-        self._pages_read_in_place = self.engine.use_flash and (
-            paged_kernel_supported(
-                self.cfg.dtype, *next(iter(kv_row_shapes(self.cfg).values())),
-                self.mesh,
-            )
-        )
-        # layers of the block that route (0: the GQA block).  A routing
-        # block's decode chunk carries its expert-choice sums to the host
-        # in one more row of the array the worker fetches anyway
-        # (``_moe_chunk_sums``); a block that does not route adds nothing
-        # to its programs or to the worker's work per chunk.
-        self._routed_layers = routed_layers(self.cfg)
-        # the stack of mixer kinds: which state entry a lane owns travels
-        # in the pools (``paged.STATE_SLOT``: keyed by the pool row of a
-        # lane's first token); this is its host copy, written at admission
-        # and uploaded with the round's prefill.  Where a layer SELECTS,
-        # its decode chunks carry SPARSE_SUMS the way a routing block's
-        # carry MOE_SUMS.
-        self._hybrid = is_hybrid(self.cfg)
-        self._selects = bool(sparse_layers(self.cfg))
-        self._scan_layers = len(mamba_layers(self.cfg))
-        # those layers' prefill scans run the Pallas kernel (the forward
-        # asks the same of what it is handed: ``_prefill_program``)
-        self._scan_kernel = bool(self._scan_layers) and scan_kernel_chosen(
-            self.engine.use_flash, self.mesh)
-        # the sparse layers' decode step reads the blocks taken as pages
-        # through the paged kernel (the forward asks the same of what it
-        # is handed: ``ops/attention.sparse_decode_attention``)
-        self._sparse_paged = self._selects and sparse_paged_chosen(
-            self.engine.use_flash, self.mesh, self.cfg.dtype,
-            self.cfg.num_kv_heads, self.cfg.head_dim,
-            self.cfg.sparse_block_size, self.block_size)
-        self._state_bytes = lane_state_bytes(self.cfg)  # one lane's
+        # a kind whose lanes keep state beside their rows: which state
+        # entry a lane owns travels in the pools (``paged.STATE_SLOT``:
+        # keyed by the pool row of a lane's first token); this is its host
+        # copy, written at admission and uploaded with the round's prefill
         self._state_slot_np = (
             np.zeros((self.n_blocks * self.block_size,), np.int32)
-            if self._hybrid else None
-        )
-        self._chunk_sum_names = (
-            MOE_SUMS if self._routed_layers
-            else SPARSE_SUMS if self._selects else ()
+            if self._block.lane_state else None
         )
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="continuous-batcher"
@@ -996,11 +876,11 @@ class ContinuousBatcher:
                 n_prefix_rows=self.seq_capacity,
                 block_size=self.block_size,
             )
-        # a block that routes hands back its record too: its picks are
-        # summed here and ride behind the first tokens (MOE_PREFILL_SUMS)
+        # a block that routes hands back its record too: its sums ride
+        # behind the first tokens (the block's ``prefill_sums``)
         logits, pools, *routed = ragged_prefill_forward(
             params, self.cfg, pools, ids, seg, pos, dest, last_rows,
-            rope_len=self.seq_capacity, use_flash=self.engine.use_flash,
+            rope_len=self.seq_capacity, kernels=self._kernels,
             mesh=self.mesh, **warm_kw,
         )
         with scope("sample"):
@@ -1008,9 +888,9 @@ class ContinuousBatcher:
                 logits, rng, self.gen.temperature, self.gen.top_k,
                 self.gen.top_p,
             )
-            if self._routed_layers:
+            if self._block.prefill_sums is not None:
                 toks = jnp.concatenate(
-                    [toks, self._moe_prefill_sums(routed[0], seg)]
+                    [toks, self._block.prefill_sums(routed[0], seg)]
                 )
             if table is None:
                 return pools, toks
@@ -1042,11 +922,11 @@ class ContinuousBatcher:
         with scope("sample"):
             out0 = jnp.full((S, self.chunk), self.gen.pad_id, jnp.int32)
             valid0 = jnp.zeros((S, self.chunk), bool)
-            # the chunk's expert-choice sums; an empty pytree (nothing in
-            # the program) for a block that does not route
+            # the chunk's sums of the block's record; an empty pytree
+            # (nothing in the program) for a block that hands none back
             moe0 = (
-                (jnp.zeros((len(self._chunk_sum_names),), jnp.int32),)
-                if self._chunk_sum_names else ()
+                (jnp.zeros((len(self._block.step_sum_names),), jnp.int32),)
+                if self._block.step_sum_names else ()
             )
 
         def body(t, carry):
@@ -1054,14 +934,12 @@ class ContinuousBatcher:
             logits, pools, *routed = paged_decode_forward(
                 params, self.cfg, pools, tables, tok[:, None], lengths,
                 block_size=self.block_size, rope_len=self.seq_capacity,
-                use_flash=self.engine.use_flash, mesh=self.mesh,
+                kernels=self._kernels, mesh=self.mesh,
             )
             with scope("sample"):
-                if routed and self._hybrid:
-                    moe = (moe[0] + self._sparse_step_sums(
+                if routed:
+                    moe = (moe[0] + self._block.step_sums(
                         routed[0], lengths, active),)
-                elif routed:
-                    moe = (moe[0] + self._moe_step_sums(routed[0], active),)
                 rng, sub = jax.random.split(rng)
                 nxt = sample(
                     logits[:, 0], sub, self.gen.temperature, self.gen.top_k,
@@ -1107,56 +985,6 @@ class ContinuousBatcher:
                 )
         return pools, tok, lengths, active, packed
 
-    def _moe_step_sums(self, record, active):
-        """``MOE_SUMS`` of one decode step, int32, from its routing record
-        [routed_layers, S, 1, k] and the lanes live in it — summed on the
-        device, so that the host reads a handful of numbers a chunk and
-        nothing waits on them."""
-        lo, held = experts_held(self.cfg)
-        taken = record[:, :, 0, :]  # [layers, S, k]
-        live = active[None, :, None]
-        per_expert = jnp.sum(
-            live[..., None] & (taken[..., None] - lo == jnp.arange(held)),
-            axis=(1, 2),
-        )  # [layers, held] live picks of each held expert
-        return jnp.stack([
-            jnp.sum(live & (taken >= 0)),
-            jnp.sum(per_expert),
-            jnp.sum(per_expert > 0),
-            jnp.any(active) * record.shape[0],
-        ]).astype(jnp.int32)
-
-    def _moe_prefill_sums(self, record, seg):
-        """``MOE_PREFILL_SUMS`` of one prefill dispatch, int32, from its
-        routing record [routed_layers, T, k] and the packed rows' lanes
-        (``seg`` < 0: padding, which routes too and is not counted) —
-        summed on the device, as ``_moe_step_sums`` is."""
-        lo, held = experts_held(self.cfg)
-        live = (seg >= 0)[None, :, None]
-        local = record - lo
-        return jnp.stack([
-            jnp.sum(live & (record >= 0)),
-            jnp.sum(live & (local >= 0) & (local < held)),
-        ]).astype(jnp.int32)
-
-    def _sparse_step_sums(self, record, lengths, active):
-        """``SPARSE_SUMS`` of one decode step, int32, from the selection
-        record [sparse layers x kv heads, S, 1, topk], the lanes' lengths
-        BEFORE the step and the lanes live in it — summed on the device,
-        as ``_moe_step_sums`` is."""
-        took = record[:, :, 0, :] >= 0  # [decisions, S, topk]
-        selected = took[0, :, 0]  # a lane that selected: first id >= 0
-        live_blocks = record.shape[0] * (
-            lengths // self.cfg.sparse_block_size + 1
-        )
-        read = jnp.where(selected, jnp.sum(took, axis=(0, 2)), live_blocks)
-        return jnp.stack([
-            jnp.sum(jnp.where(active, read, 0)),
-            jnp.sum(jnp.where(active, live_blocks, 0)),
-            jnp.sum(active & ~selected),
-            jnp.sum(active),
-        ]).astype(jnp.int32)
-
     def _decode_spec_program(self, params, pools, tables, caps, table, tok,
                              lengths, active):
         """Speculative decode chunk over the block pool: loop verify-steps
@@ -1199,7 +1027,7 @@ class ContinuousBatcher:
             logits, pools, *_ = paged_decode_forward(
                 params, self.cfg, pools, tables, verify_in, lengths,
                 block_size=self.block_size, rope_len=self.seq_capacity,
-                use_flash=self.engine.use_flash, mesh=self.mesh,
+                kernels=self._kernels, mesh=self.mesh,
             )
             with scope("sample"):
                 g, m, cand, is_eos, eos_pos = accept_drafts(
@@ -2046,14 +1874,9 @@ class ContinuousBatcher:
             req = self._slot_req[slot]
             if req is not None:
                 tokens += req.kv_prompt + len(req.tokens)
-        state = (
-            {"state_bytes_per_lane": self._state_bytes}
-            if self._hybrid else {}
-        )
-        if self._loop_steps > 1:  # gauge ``serve_loop_steps``
-            state["loop_steps"] = self._loop_steps
         out = {
-            **state,
+            # the kind's own: ``state_bytes_per_lane``, ``loop_steps``
+            **self._block.occupancy,
             "blocks_total": self.n_blocks,
             "blocks_used": used,
             "block_size": self.block_size,
@@ -2571,7 +2394,7 @@ class ContinuousBatcher:
             row[:] = self.n_blocks
             row[: len(table.blocks)] = table.blocks
             self._caps_np[slot] = table.capacity
-            if self._hybrid:
+            if self._state_slot_np is not None:
                 # the slot owns state entry ``slot`` until it retires; the
                 # round's prefill starts it from zeros (a reused slot
                 # inherits nothing) and the map goes up with that dispatch
@@ -2680,7 +2503,7 @@ class ContinuousBatcher:
             right behind it; the host-side fetch of first tokens
             (_finalize_admissions) then overlaps that chunk."""
             self._apply_deact_on_lane()
-            if self._hybrid:
+            if self._state_slot_np is not None:
                 self._pools[STATE_SLOT] = jax.device_put(
                     self._state_slot_np, self._state_sharding
                 )
@@ -2713,7 +2536,7 @@ class ContinuousBatcher:
                         self.engine.params, self._pools, *args
                     )
                 parts.append(toks[:n_lanes])
-                if self._routed_layers:  # its picks, behind the tokens
+                if self._block.prefill_sum_names:  # behind the tokens
                     sums.append(toks[S:])
             first = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
             idx = jnp.asarray(slots_np)
@@ -2724,7 +2547,7 @@ class ContinuousBatcher:
             # the scatters are DOWNSTREAM of `first` — returned alongside
             # it so strict mode's block_until_ready covers every program
             # this item issued, not just the first-token chain
-            if sums:  # a pair a dispatch, behind the round's tokens
+            if sums:  # a row a dispatch, behind the round's tokens
                 first = jnp.concatenate([first, *sums])
             return first, self._tok, self._lengths, self._active
 
@@ -2741,10 +2564,6 @@ class ContinuousBatcher:
         DEFAULT_REGISTRY.counter("serve_admit_rounds").inc()
         DEFAULT_REGISTRY.counter("serve_admitted").inc(len(ordered))
         DEFAULT_REGISTRY.counter("serve_prefill_dispatches").inc(len(groups))
-        if self._hybrid:
-            DEFAULT_REGISTRY.counter("serve_lane_state_resets").inc(
-                len(ordered)
-            )
         # split: the round ran more groups than it would have had every
         # group been allowed the largest budget — how often rule 1 of
         # partition_prefill_round engages
@@ -2757,17 +2576,13 @@ class ContinuousBatcher:
         )
         prefill_tokens = sum(novel for _T, _rows, novel in group_rows)
         DEFAULT_REGISTRY.counter("serve_prefill_tokens").inc(prefill_tokens)
-        if self._scan_layers:
-            # prompt tokens x the state-space layers that scanned them
-            DEFAULT_REGISTRY.counter("serve_scan_tokens").inc(
-                prefill_tokens * self._scan_layers
-            )
-        if self._scan_kernel:
-            # over ``serve_prefill_dispatches``: 1.0 where every dispatch's
-            # state-space layers scanned in the kernel, absent elsewhere
-            DEFAULT_REGISTRY.counter("serve_scan_kernel_dispatches").inc(
-                len(groups)
-            )
+        # what the block kind counts of a round (lane states reset, rows
+        # scanned, dispatches that scanned in the kernel)
+        for name, amount in self._block.prefill_counts(
+            lanes=len(ordered), tokens=prefill_tokens,
+            dispatches=len(groups), kernels=self._kernels,
+        ).items():
+            DEFAULT_REGISTRY.counter(name).inc(amount)
         # group-major, like ``ordered``: (slot, req, prompt tokens,
         # shared tokens, what the dispatch that carried it ran)
         meta = []
@@ -2788,8 +2603,8 @@ class ContinuousBatcher:
                     prompt_tokens=len(ids), blocks=len(table.blocks),
                     shared_tokens=shared, budget_tokens=T,
                     packed_tokens=rows,
-                    **self._hybrid_prefill_attrs(len(ids), len(good)),
-                    **self._loop_attrs,
+                    **self._block.prefill_attrs(len(ids), len(good)),
+                    **self._block.span_attrs,
                 )
                 meta.append((
                     slot, req, len(ids), shared,
@@ -2803,23 +2618,6 @@ class ContinuousBatcher:
             ("warm", g[0]) if g[8] else g[0] for g in group_inputs
         ]
         return meta, first_toks, cost_keys, t_prefill1
-
-    def _hybrid_prefill_attrs(self, n_ids: int, n_lanes: int) -> dict:
-        """What the stack of mixer kinds adds to a request's
-        ``serve_prefill`` span: the lanes whose state the round started
-        from zeros; where a layer SELECTS, the rows of the prompt that
-        selected (all of them once it holds ``sparse_dense_len`` tokens,
-        none under it); where a layer SCANS (state-space), the rows its
-        scan ran over.  Nothing for any other block."""
-        if not self._hybrid:
-            return {}
-        out = {"state_lanes": n_lanes}
-        if self._selects:
-            selects = n_ids >= self.cfg.sparse_dense_len
-            out["sparse_rows"] = n_ids if selects else 0
-        if self._scan_layers:
-            out["scan_rows"] = n_ids
-        return out
 
     def _finalize_admissions(self, admitted) -> bool:
         """Host-side bookkeeping for an admission round: ONE device fetch
@@ -2859,9 +2657,10 @@ class ContinuousBatcher:
             log.exception("admission fetch failed; resetting")
             self._fail_active(e)
             return False
-        if self._routed_layers:
-            sums = fetched[len(meta):].reshape(-1, len(MOE_PREFILL_SUMS))
-            for name, value in zip(MOE_PREFILL_SUMS, sums.sum(axis=0)):
+        names = self._block.prefill_sum_names
+        if names:  # a row a dispatch, summed on the device
+            sums = fetched[len(meta):].reshape(-1, len(names))
+            for name, value in zip(names, sums.sum(axis=0)):
                 DEFAULT_REGISTRY.counter(name).inc(int(value))
         # ---- per-request cost attribution (docqa-costscope): split the
         # round's measured device time across its requests proportional
@@ -3075,11 +2874,9 @@ class ContinuousBatcher:
                 _cost_add(req, "spine_queue_wait_ms", qw_ms)
                 if fl:
                     _cost_add(req, "flops_est", fl)
-        if self._chunk_sum_names and not self.spec_k:
-            if self._selects:
-                self._sparse_chunk_sums(packed_h[self.n_slots])
-            else:
-                self._moe_chunk_sums(packed_h[self.n_slots])
+        sums_row = None  # the block's step sums, one more row of the fetch
+        if self._block.step_sum_names and not self.spec_k:
+            sums_row = packed_h[self.n_slots]
             packed_h = packed_h[: self.n_slots]
         if self.spec_k:
             width = self.chunk + 2 * self.spec_k
@@ -3128,7 +2925,7 @@ class ContinuousBatcher:
             _req_span(
                 req, "serve_decode_chunk", t_fetch0, t_fetch1,
                 slot=slot, tokens=len(req.tokens) - before,
-                **self._loop_attrs,
+                **self._block.span_attrs,
             )
             _cost_add(req, "decode_tokens", len(req.tokens) - before)
             if len(req.tokens) > before:  # wake streamers per chunk
@@ -3179,16 +2976,16 @@ class ContinuousBatcher:
             float(n_appended)
         )
         DEFAULT_REGISTRY.counter("serve_decode_chunks").inc()
-        if self._sparse_paged:
-            # over ``serve_decode_chunks``: 1.0 where every chunk's sparse
-            # layers read the blocks taken as pages, absent elsewhere
-            DEFAULT_REGISTRY.counter("serve_sparse_paged_chunks").inc()
-        if self._hybrid and not self._selects:
-            # no sums row rides this stack's chunks: a lane-step is a
-            # position a lane advanced, which the host holds
-            self._count_state_steps(sum(adv for _, adv in lanes))
-        if self._loop_steps > 1:
-            self._count_loop_passes(sum(adv for _, adv in lanes))
+        # what the block kind counts of a chunk, from its sums row and
+        # from the positions the lanes advanced, which the host holds
+        counts, samples = self._block.chunk_counts(
+            lane_steps=sum(adv for _, adv in lanes), row=sums_row,
+            kernels=self._kernels,
+        )
+        for name, amount in counts.items():
+            DEFAULT_REGISTRY.counter(name).inc(amount)
+        for name, sample_ in samples.items():
+            DEFAULT_REGISTRY.histogram(name).observe(sample_)
         rows_read, rows_live = self._chunk_kv_rows(lanes)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_read").inc(rows_read)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_live").inc(rows_live)
@@ -3202,57 +2999,6 @@ class ContinuousBatcher:
             # — the worker never issues device ops from its own thread
             self._deact_pending.extend(deactivate)
         return True
-
-    def _moe_chunk_sums(self, row) -> None:
-        """One fetched chunk's expert-choice sums (``MOE_SUMS``, summed on
-        the device over its steps and live lanes) into the counters the
-        routed layer's metrics read: picks made, picks that fell on an
-        expert held here, distinct held experts a (layer, step) touched —
-        the weights a step had to read — and the (layer, step)s counted."""
-        sums = dict(zip(MOE_SUMS, (int(v) for v in row[: len(MOE_SUMS)])))
-        for name, value in sums.items():
-            DEFAULT_REGISTRY.counter(name).inc(value)
-        if sums["serve_moe_experts_touched"]:
-            DEFAULT_REGISTRY.histogram("serve_moe_tokens_per_expert").observe(
-                sums["serve_moe_picks_local"]
-                / sums["serve_moe_experts_touched"]
-            )
-
-    def _sparse_chunk_sums(self, row) -> None:
-        """One fetched chunk's ``SPARSE_SUMS`` into the counters a
-        selecting stack's metrics read, the bytes of lane state its steps
-        read and wrote, and — one sample a chunk — the tokens a selecting
-        query read per sparse layer and kv head."""
-        sums = dict(zip(SPARSE_SUMS, (int(v) for v in row[: len(SPARSE_SUMS)])))
-        lane_steps = sums.pop("serve_state_lane_steps")
-        for name, value in sums.items():
-            DEFAULT_REGISTRY.counter(name).inc(value)
-        self._count_state_steps(lane_steps)
-        selecting = lane_steps - sums["serve_sparse_dense_lane_steps"]
-        if selecting and not sums["serve_sparse_dense_lane_steps"]:
-            decisions = len(sparse_layers(self.cfg)) * self.cfg.num_kv_heads
-            DEFAULT_REGISTRY.histogram("serve_sparse_selected_tokens").observe(
-                sums["serve_sparse_blocks_selected"]
-                * self.cfg.sparse_block_size / (selecting * decisions)
-            )
-
-    def _count_state_steps(self, lane_steps: int) -> None:
-        """``lane_steps`` decode steps of a live lane: each read and wrote
-        every entry the lane's state-keeping layers hold, once."""
-        DEFAULT_REGISTRY.counter("serve_state_lane_steps").inc(lane_steps)
-        DEFAULT_REGISTRY.counter("serve_state_bytes_rw").inc(
-            2 * self._state_bytes * lane_steps
-        )
-
-    def _count_loop_passes(self, lane_steps: int) -> None:
-        """``lane_steps`` positions the lanes of a fetched chunk advanced
-        under the looped trunk, and the passes of the stack they took:
-        ``loop_steps`` each while no step exits early — host arithmetic,
-        as ``_count_state_steps`` is.  Their ratio is the passes a token."""
-        DEFAULT_REGISTRY.counter("serve_loop_lane_steps").inc(lane_steps)
-        DEFAULT_REGISTRY.counter("serve_loop_passes").inc(
-            lane_steps * self._loop_steps
-        )
 
     def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
         """(KV rows fetched, KV rows live) PER CACHE ENTRY — a layer's; a
@@ -3284,29 +3030,17 @@ class ContinuousBatcher:
             )
             live += int(lens.sum())
             read += int((-(-lens // self.block_size)).sum()) * self.block_size
-        if self._selects:
-            # a sparse layer reads the rows of the blocks taken; in the
-            # XLA form every table once a lane of the step is still under
-            # dense_len (ops/attention.sparse_decode_attention)
-            taken = self.cfg.sparse_topk * self.cfg.sparse_block_size
-            all_lens = np.stack([
-                length + 1 + np.minimum(np.arange(steps), adv)
-                for length, adv in lanes
-            ]) if lanes else np.zeros((0, steps), np.int64)
-            under = all_lens < self.cfg.sparse_dense_len
-            if self._sparse_paged:
-                # one virtual lane a (lane, kv head), and a page carries
-                # every kv head: a selecting lane's taken rows, the live
-                # pages of a lane under dense_len, kv-heads times
-                pages = -(-all_lens // self.block_size) * self.block_size
-                read = self.cfg.num_kv_heads * int(np.where(
-                    under, pages, np.minimum(taken, pages)).sum())
-            else:
-                read = int(np.where(
-                    under.any(axis=0), self.n_slots * self.seq_capacity,
-                    len(lanes) * taken,
-                ).sum())
-        elif not self._pages_read_in_place:
+        if self._block.kv_rows_read is not None:
+            # a kind that reads by a rule of its own (a layer that selects)
+            read = self._block.kv_rows_read(
+                np.stack([
+                    length + 1 + np.minimum(np.arange(steps), adv)
+                    for length, adv in lanes
+                ]) if lanes else np.zeros((0, steps), np.int64),
+                kernels=self._kernels, block_size=self.block_size,
+                table_rows=self.n_slots * self.seq_capacity,
+            )
+        elif not self._kernels.paged:
             read = steps * self.n_slots * self.seq_capacity
         return read, live
 

@@ -27,19 +27,29 @@ import jax.numpy as jnp
 
 from docqa_tpu.config import DecoderConfig
 from docqa_tpu.models.hybrid import (
-    HYBRID_BLOCK,
     MIXERS,
+    check_hybrid_config,
     hybrid_param_schema,
+    hybrid_serving,
     is_hybrid,
     lane_state_entries,
+    mamba_layers,
+    sparse_layers,
 )
 from docqa_tpu.models.latent import (
-    LATENT_BLOCK,
+    check_latent_config,
     is_latent,
     latent_param_schema,
     latent_row_width,
+    latent_serving,
+    routed_layers,
 )
-from docqa_tpu.ops.attention import attention_reference, flash_attention
+from docqa_tpu.models.serving import BlockServing, KernelForms
+from docqa_tpu.ops.attention import (
+    attention_reference,
+    flash_attention,
+    paged_kernel_supported,
+)
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
 from docqa_tpu.ops.scopes import scope
@@ -162,6 +172,80 @@ def lane_state_dtypes(cfg: DecoderConfig) -> Dict[str, str]:
     """The element type of each entry of :func:`lane_state_shapes`:
     float32 for a state, the activation type for a conv window."""
     return {n: dtype for n, (_, dtype) in lane_state_entries(cfg).items()}
+
+
+def _loop_chunk_counts(steps: int, *, lane_steps, **_):
+    # the positions a fetched chunk's lanes advanced and the passes of the
+    # stack they took (``loop_steps`` each while no step exits early):
+    # their ratio is the passes a token
+    return {"serve_loop_lane_steps": lane_steps,
+            "serve_loop_passes": lane_steps * steps}, {}
+
+
+def block_serving(cfg: DecoderConfig) -> BlockServing:
+    """The record of ``cfg``'s block kind (``models/serving.py``).  The
+    GQA block's is the defaults; its looped trunk (``loop_steps`` > 1) is
+    served cold and unspeculated — a warm prefill and a verify step
+    through the steps' ranges of the pools are untested — and counts what
+    a token costs.  A configuration its kind cannot run is refused HERE,
+    by field: the loop's fields first (the GQA block's alone)."""
+    check_loop_config(cfg)
+    if is_latent(cfg):
+        check_latent_config(cfg)
+        return latent_serving(cfg)
+    if is_hybrid(cfg):
+        check_hybrid_config(cfg)
+        return hybrid_serving(cfg)
+    steps = kv_entries(cfg)
+    if steps == 1:
+        return BlockServing(label=f'DecoderConfig(block="{cfg.block}")')
+    attrs = {"loop_steps": steps}
+    return BlockServing(
+        label=f"DecoderConfig(loop_steps={steps})",
+        unserved=("generate.prefix_cache", "generate.speculative_k"),
+        advice="set prefix_cache false and speculative_k 0",
+        solo=(
+            f"runs loop_steps 1 only (got {steps}): a looped trunk keeps an "
+            "entry a (step, layer) and serves through the batcher "
+            "(engines/serve.ContinuousBatcher) over the paged cache "
+            "(engines/paged.py) only"
+        ),
+        chunk_counts=functools.partial(_loop_chunk_counts, steps),
+        span_attrs=attrs,
+        occupancy=attrs,
+    )
+
+
+def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
+                 block_size: Optional[int]) -> KernelForms:
+    """Which Pallas forms the paged forwards of ``cfg`` run — THE place
+    the choice is made: the engine asks once with what it observed, the
+    forwards hand the answer to their ops as static booleans, the batcher
+    counts by it.  ``on_tpu``: the one observation — a TPU whose kernels
+    read this head width, or what the engine's caller said
+    (``use_flash``); ``block_size``: the pools' page, ``None`` for a
+    prefill.
+
+    * ``paged``: K / V rows of a geometry the paged kernel reads, sharded
+      over ``mesh`` as they are (no kernel reads a latent row);
+    * ``sparse_paged``: a layer selects, NO mesh (the XLA form is what
+      GSPMD places), and a selection block is a whole number of pages;
+    * ``scan``: a state-space layer, and NO mesh (the ``ssm_*`` arrays are
+      replicated there and the XLA form lowers as it stands);
+    * ``grouped``: a routed layer, and NO mesh (``ragged_dot`` partitions
+      experts sharded along their leading axis, a custom call cannot)."""
+    alone = on_tpu and mesh is None
+    geometry = (cfg.dtype, cfg.num_kv_heads, cfg.head_dim)
+    return KernelForms(
+        paged=on_tpu and not is_latent(cfg)
+        and paged_kernel_supported(*geometry, mesh),
+        sparse_paged=alone and len(sparse_layers(cfg)) > 0
+        and block_size is not None
+        and cfg.sparse_block_size % block_size == 0
+        and paged_kernel_supported(*geometry),
+        scan=alone and len(mamba_layers(cfg)) > 0,
+        grouped=alone and routed_layers(cfg) > 0,
+    )
 
 
 def param_putter(cfg: DecoderConfig, mesh=None):
@@ -450,25 +534,9 @@ def decoder_forward(
 
     Returns (logits [b, s, vocab] f32, updated cache).
     """
-    if is_latent(cfg):
-        raise NotImplementedError(
-            f'the dense-cache solo forward has no "{LATENT_BLOCK}" block '
-            "(model_type deepseek_v2): that block serves through the paged "
-            "cache (engines/paged.py, the batcher) only"
-        )
-    if is_hybrid(cfg):
-        raise NotImplementedError(
-            f'the dense-cache solo forward has no "{HYBRID_BLOCK}" block: '
-            "a stack of mixer kinds serves through the paged "
-            "cache and its lane state (engines/paged.py, the batcher) only"
-        )
-    if cfg.loop_steps > 1:
-        raise NotImplementedError(
-            f"the dense-cache solo forward runs loop_steps 1 only (got "
-            f"{cfg.loop_steps}): a looped trunk keeps an entry a (step, "
-            "layer) and serves through the paged cache (engines/paged.py, "
-            "the batcher) only"
-        )
+    solo = block_serving(cfg).solo
+    if solo is not None:
+        raise NotImplementedError("the dense-cache solo forward " + solo)
     b, s = ids.shape
     max_len = cache["k0"].shape[1]
 

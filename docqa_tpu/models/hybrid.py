@@ -58,13 +58,18 @@ for a stack in which no layer selects.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.serving import BlockServing
+from docqa_tpu.ops.attention import paged_kernel_supported
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
 from docqa_tpu.ops.scopes import scope
@@ -73,6 +78,9 @@ Params = Dict[str, jax.Array]
 
 HYBRID_BLOCK = "sparse_linear"
 SPARSE, LINEAR, ATTENTION, MAMBA = "sparse", "linear", "attention", "mamba"
+# the pool that maps a lane's first pool row to its state entry
+# (``engines/paged._init_hybrid_pools``)
+STATE_SLOT = "state_slot"
 # prefill rows one MLP tile holds: the gate / up activations of a longer
 # dispatch are never whole (38k rows x 16384 would be 1.2 GB each)
 MLP_TILE_ROWS = 2048
@@ -493,3 +501,213 @@ def hybrid_head(params: Params, cfg: DecoderConfig, x):
         else:
             logits = decoder_head(params, cfg, x)
         return logits * logit_scale(cfg)
+
+
+# ---- what the stack's surroundings ask of it (models/serving.py) -----------
+
+# counters of a stack in which a layer SELECTS (:func:`sparse_step_sums` /
+# :func:`hybrid_chunk_counts`; a stack in which none does carries no such
+# row and counts its lane-steps on the host), over a chunk's steps and live
+# lanes: blocks the sparse layers' queries READ (the blocks taken; every
+# live block on a lane still under ``sparse_dense_len``), blocks live for
+# them, lane-steps that ran dense, and lane-steps in all (each reads and
+# writes the lane's state once: ``serve_state_bytes_rw`` is that times the
+# state's bytes)
+SPARSE_SUMS = (
+    "serve_sparse_blocks_selected", "serve_sparse_blocks_live",
+    "serve_sparse_dense_lane_steps", "serve_state_lane_steps",
+)
+
+
+def sparse_step_sums(cfg: DecoderConfig, record, lengths, active):
+    """``SPARSE_SUMS`` of one decode step, int32, from the selection
+    record [sparse layers x kv heads, S, 1, topk], the lanes' lengths
+    BEFORE the step and the lanes live in it — summed on the device, as
+    ``models/latent.moe_step_sums`` is."""
+    took = record[:, :, 0, :] >= 0  # [decisions, S, topk]
+    selected = took[0, :, 0]  # a lane that selected: first id >= 0
+    live_blocks = record.shape[0] * (
+        lengths // cfg.sparse_block_size + 1
+    )
+    read = jnp.where(selected, jnp.sum(took, axis=(0, 2)), live_blocks)
+    return jnp.stack([
+        jnp.sum(jnp.where(active, read, 0)),
+        jnp.sum(jnp.where(active, live_blocks, 0)),
+        jnp.sum(active & ~selected),
+        jnp.sum(active),
+    ]).astype(jnp.int32)
+
+
+def hybrid_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
+    """One fetched chunk's counters and samples: its ``SPARSE_SUMS`` row
+    where a layer selects, else the lane-steps the host holds."""
+    counts, samples = {}, {}
+    if row is not None:
+        # the blocks the sparse layers' queries read and, one sample a
+        # chunk, the tokens a selecting query read per layer and kv head
+        counts = dict(
+            zip(SPARSE_SUMS, (int(v) for v in row[: len(SPARSE_SUMS)])))
+        lane_steps = counts.pop("serve_state_lane_steps")
+        dense = counts["serve_sparse_dense_lane_steps"]
+        selecting = lane_steps - dense
+        if selecting and not dense:
+            decisions = len(sparse_layers(cfg)) * cfg.num_kv_heads
+            samples["serve_sparse_selected_tokens"] = (
+                counts["serve_sparse_blocks_selected"]
+                * cfg.sparse_block_size / (selecting * decisions)
+            )
+    # a lane-step read and wrote every entry the lane's state-keeping
+    # layers hold, once
+    counts["serve_state_lane_steps"] = lane_steps
+    counts["serve_state_bytes_rw"] = (
+        2 * lane_state_bytes(cfg) * lane_steps)
+    if kernels.sparse_paged:
+        # over ``serve_decode_chunks``: 1.0 where every chunk's sparse
+        # layers read the blocks taken as pages, absent elsewhere
+        counts["serve_sparse_paged_chunks"] = 1
+    return counts, samples
+
+
+def hybrid_prefill_counts(cfg: DecoderConfig, *, lanes, tokens, dispatches,
+                          kernels):
+    """One admission round's counters: lane states started from zeros."""
+    counts = {"serve_lane_state_resets": lanes}
+    scans = len(mamba_layers(cfg))
+    if scans:
+        # prompt tokens x the state-space layers that scanned them
+        counts["serve_scan_tokens"] = tokens * scans
+    if kernels.scan:
+        # over ``serve_prefill_dispatches``: 1.0 where every dispatch's
+        # state-space layers scanned in the kernel, absent elsewhere
+        counts["serve_scan_kernel_dispatches"] = dispatches
+    return counts
+
+
+def hybrid_prefill_attrs(cfg: DecoderConfig, n_ids: int, n_lanes: int):
+    """What the stack adds to a request's ``serve_prefill`` span: the
+    lanes whose state the round started from zeros; where a layer
+    SELECTS, the rows of the prompt that selected (all of them once it
+    holds ``sparse_dense_len`` tokens, none under it); where a layer
+    SCANS (state-space), the rows its scan ran over."""
+    out = {"state_lanes": n_lanes}
+    if sparse_layers(cfg):
+        selects = n_ids >= cfg.sparse_dense_len
+        out["sparse_rows"] = n_ids if selects else 0
+    if mamba_layers(cfg):
+        out["scan_rows"] = n_ids
+    return out
+
+
+def sparse_rows_read(cfg: DecoderConfig, lens, *, kernels, block_size: int,
+                     table_rows: int) -> int:
+    """KV rows a chunk's steps fetched per cache entry, from the length
+    each lane's step attended (``lens`` [lanes, steps])."""
+    # a sparse layer reads the rows of the blocks taken; in the XLA form
+    # every table once a lane of the step is still under dense_len
+    # (ops/attention.sparse_decode_attention)
+    taken = cfg.sparse_topk * cfg.sparse_block_size
+    under = lens < cfg.sparse_dense_len
+    if kernels.sparse_paged:
+        # one virtual lane a (lane, kv head), and a page carries every kv
+        # head: a selecting lane's taken rows, the live pages of a lane
+        # under dense_len, kv-heads times
+        pages = -(-lens // block_size) * block_size
+        return cfg.num_kv_heads * int(np.where(
+            under, pages, np.minimum(taken, pages)).sum())
+    return int(np.where(
+        under.any(axis=0), table_rows, lens.shape[0] * taken).sum())
+
+
+def hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
+    """Megatron per layer.  An attention kind: q, the output gate and the
+    MLP's gate / up column-parallel, ``wo`` and ``w_down`` row-parallel; a
+    linear layer's k and v are as wide as its q and go column-parallel
+    with it; the few kv heads of a sparse or a plain attention layer are
+    replicated (1 or 2 heads do not divide over 4 or 8 devices), as are
+    the per-head norm gains.  The state-space kind along its INNER
+    channels: ``w_in`` column-parallel over its ``2 x inner`` columns
+    (GSPMD re-lays the ``u`` and the ``z`` half along ``inner``), the
+    conv's taps and bias, ``w_x``'s input, ``w_dt``'s output, ``b_dt``,
+    ``A_log`` and ``D`` along that axis, ``w_out`` row-parallel; the three
+    inner norms replicated.  The pools — rows (ONE kv head cannot be
+    divided), compressed keys, lane states and windows — are replicated
+    (:func:`hybrid_pool_pspecs`)."""
+    specs: Dict[str, P] = {}
+    for i, kind in enumerate(cfg.mixer_types):
+        p = f"l{i}_"
+        specs.update({
+            p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
+            p + "w_gate": P(None, m), p + "w_up": P(None, m),
+            p + "w_down": P(m, None),
+        })
+        if kind == MAMBA:
+            specs.update({
+                p + "w_in": P(None, m), p + "b_in": P(m),
+                p + "conv_w": P(None, m), p + "conv_b": P(m),
+                p + "w_x": P(m, None), p + "dt_norm_g": P(None),
+                p + "b_norm_g": P(None), p + "c_norm_g": P(None),
+                p + "w_dt": P(None, m), p + "b_dt": P(m),
+                p + "a_log": P(None, m), p + "d_skip": P(m),
+                p + "w_out": P(m, None), p + "b_out": P(None),
+            })
+            continue
+        kv = P(None, m) if kind == LINEAR else P(None, None)
+        specs.update({
+            p + "q_norm_g": P(None), p + "k_norm_g": P(None),
+            p + "wq": P(None, m), p + "wk": kv, p + "wv": kv,
+            p + "w_ogate": P(None, m), p + "wo": P(m, None),
+        })
+        if kind == LINEAR:
+            specs[p + "o_norm_g"] = P(None)
+    return specs
+
+
+def hybrid_pool_pspecs(cfg: DecoderConfig) -> Dict[str, P]:
+    """Every pool of the stack (``engines/paged.py``), replicated."""
+    names = [STATE_SLOT, *lane_state_entries(cfg)]
+    for i, kind in enumerate(cfg.mixer_types):
+        names += [f"{prefix}{i}" for prefix in MIXERS[kind].rows(cfg)]
+    names += [f"ck{i}" for i in sparse_layers(cfg)]
+    return {name: P() for name in names}
+
+
+def hybrid_serving(cfg: DecoderConfig) -> BlockServing:
+    """The stack's record (``cfg`` checked: :func:`check_hybrid_config`)."""
+    # served cold, unspeculated and unpreempted: a shared prefix is a run
+    # of pages and a lane's state at the share boundary is in none of them;
+    # a verify step of several tokens would need the state after each; and
+    # a preempted lane resumes from pages alone.  ``use_flash`` reaches the
+    # plain attention layers' decode, the state-space layers' prefill scan
+    # and — where the paged kernel reads this geometry — the blocks a
+    # sparse layer's decode step took, and nothing else of the stack
+    kinds = set(cfg.mixer_types)
+    selects = SPARSE in kinds
+    sums = dict(
+        step_sum_names=SPARSE_SUMS,
+        step_sums=functools.partial(sparse_step_sums, cfg),
+        kv_rows_read=functools.partial(sparse_rows_read, cfg),
+    ) if selects else {}
+    return BlockServing(
+        label=f'DecoderConfig(block="{cfg.block}")',
+        unserved=("generate.prefix_cache", "generate.speculative_k",
+                  "qos.preemption"),
+        advice="set prefix_cache false, speculative_k 0 and "
+               "qos.preemption off",
+        solo=(
+            f'has no "{HYBRID_BLOCK}" block: a stack of mixer kinds serves '
+            "through the batcher (engines/serve.ContinuousBatcher) over "
+            "the paged rows and the lane state (engines/paged.py) only"
+        ),
+        uses_flash=bool(
+            {ATTENTION, MAMBA} & kinds
+            or selects and paged_kernel_supported(
+                cfg.dtype, cfg.num_kv_heads, cfg.head_dim)),
+        chunk_counts=functools.partial(hybrid_chunk_counts, cfg),
+        prefill_counts=functools.partial(hybrid_prefill_counts, cfg),
+        prefill_attrs=functools.partial(hybrid_prefill_attrs, cfg),
+        occupancy={"state_bytes_per_lane": lane_state_bytes(cfg)},
+        lane_state=True,
+        param_pspecs=functools.partial(hybrid_param_pspecs, cfg),
+        pool_pspecs=functools.partial(hybrid_pool_pspecs, cfg),
+        **sums,
+    )

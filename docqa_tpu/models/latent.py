@@ -48,8 +48,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.serving import BlockServing
 from docqa_tpu.ops.grouped import grouped_matmul, row_tile
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, yarn_mscale, yarn_rope_angles
@@ -234,7 +236,7 @@ def select_experts(scores, cfg: DecoderConfig):
 
 
 def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
-                     i: int, *, mesh=None):
+                     i: int, *, use_flash: bool = False):
     """``sum_e gate_e . swiglu_e(y)`` over the experts HELD here, float32
     [n, hidden].  ``taken`` [n, k] expert ids as the router numbers them,
     ``gates`` [n, k] float32.
@@ -247,8 +249,8 @@ def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
     is never read.  So a prefill of hundreds of rows streams each held
     expert once under the few rows that took it, and a decode step of a
     few lanes reads only the experts its tokens touched: its time follows
-    the routing.  ``mesh``: what the engine serves on (it chooses the
-    product's form, nothing else)."""
+    the routing.  ``use_flash``: the product's form
+    (``models/decoder.kernel_forms``'s ``grouped``), nothing else."""
     lo, held = experts_held(cfg)
     n, k = taken.shape
     dtype = y.dtype
@@ -262,7 +264,8 @@ def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
     m = round_up(n * k, row_tile(n * k))
     pick = jnp.pad(order, (0, m - n * k))
     rows = y[pick // k]
-    product = functools.partial(grouped_matmul, group_sizes=sizes, mesh=mesh)
+    product = functools.partial(
+        grouped_matmul, group_sizes=sizes, use_flash=use_flash)
     g = product(rows, params[f"l{i}_e_gate"].astype(dtype), out_dtype=dtype)
     u = product(rows, params[f"l{i}_e_up"].astype(dtype), out_dtype=dtype)
     act = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
@@ -282,7 +285,8 @@ def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
     return acc
 
 
-def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int, *, mesh=None):
+def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int, *,
+               use_flash: bool = False):
     """(what the routed layer adds [n, hidden], expert ids taken [n, k])."""
     with scope("route"):
         logits = jnp.dot(
@@ -295,7 +299,7 @@ def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int, *, mesh=None):
     with scope("experts"):
         out = held_experts_sum(
             y, taken, cfg.routed_scale * taken_scores, params, cfg, i,
-            mesh=mesh,
+            use_flash=use_flash,
         )
     with scope("mlp"):
         if cfg.num_shared_experts:
@@ -308,13 +312,13 @@ def routed_mlp(y, params: Params, cfg: DecoderConfig, i: int, *, mesh=None):
 # ---- the trunk -------------------------------------------------------------
 
 def latent_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
-                       rope_len: int, attend, *, mesh=None):
+                       rope_len: int, attend, *, use_flash: bool = False):
     """The block's trunk, as ``decoder_layer_stack`` is the GQA block's.
 
     ``attend(i, q_nope [b, s, heads, nope], q_rope [b, s, heads, rope],
     row [b, s, r + rope]) -> [b, s, heads, v]`` owns the cache: it writes
-    ``row`` and attends in whichever form suits it.  ``mesh``: the mesh
-    the engine serves on, for the routed layers' grouped product.
+    ``row`` and attends in whichever form suits it.  ``use_flash``: the
+    form of the routed layers' grouped product (:func:`held_experts_sum`).
 
     Returns (hidden states [b, s, hidden] before the final norm, routing
     record int32 [routed_layers, b, s, experts_per_token])."""
@@ -370,8 +374,145 @@ def latent_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
         with scope("mlp"):
             y = rms_norm(x, params[p + "mlp_norm_g"], cfg.norm_eps)
             add, taken = routed_mlp(
-                y.reshape(b * s, -1), params, cfg, i, mesh=mesh
+                y.reshape(b * s, -1), params, cfg, i, use_flash=use_flash
             )
             x = x + add.reshape(b, s, -1)
         record.append(taken.reshape(b, s, -1))
     return x, (jnp.stack(record) if record else None)
+
+
+# ---- what the block's surroundings ask of it (models/serving.py) ----------
+
+# counters of the block's decode chunks, in the order the decode program
+# sums them on the device (:func:`moe_step_sums`) and the worker adds them
+# (:func:`moe_chunk_counts`): expert picks of the live lanes; those that
+# fell on an expert held here; distinct held experts touched, summed over
+# (routed layer, step); and the (routed layer, step)s with a live lane
+MOE_SUMS = (
+    "serve_moe_picks", "serve_moe_picks_local", "serve_moe_experts_touched",
+    "serve_moe_layer_steps",
+)
+# the same block's prefill dispatches (:func:`moe_prefill_sums`, behind the
+# first tokens in the fetch the batcher's ``_finalize_admissions`` makes
+# anyway): expert picks of the packed prompt rows, and those that fell on
+# an expert held here — the row-expert products the grouped form runs
+# (:func:`held_experts_sum`), of rows x held had every held expert run
+# over every row
+MOE_PREFILL_SUMS = (
+    "serve_moe_prefill_picks", "serve_moe_prefill_picks_local",
+)
+
+
+def moe_step_sums(cfg: DecoderConfig, record, lengths, active):
+    """``MOE_SUMS`` of one decode step, int32, from its routing record
+    [routed_layers, S, 1, k] and the lanes live in it — summed on the
+    device, so that the host reads a handful of numbers a chunk and
+    nothing waits on them."""
+    lo, held = experts_held(cfg)
+    taken = record[:, :, 0, :]  # [layers, S, k]
+    live = active[None, :, None]
+    per_expert = jnp.sum(
+        live[..., None] & (taken[..., None] - lo == jnp.arange(held)),
+        axis=(1, 2),
+    )  # [layers, held] live picks of each held expert
+    return jnp.stack([
+        jnp.sum(live & (taken >= 0)),
+        jnp.sum(per_expert),
+        jnp.sum(per_expert > 0),
+        jnp.any(active) * record.shape[0],
+    ]).astype(jnp.int32)
+
+
+def moe_prefill_sums(cfg: DecoderConfig, record, seg):
+    """``MOE_PREFILL_SUMS`` of one prefill dispatch, int32, from its
+    routing record [routed_layers, T, k] and the packed rows' lanes
+    (``seg`` < 0: padding, which routes too and is not counted) —
+    summed on the device, as :func:`moe_step_sums` is."""
+    lo, held = experts_held(cfg)
+    live = (seg >= 0)[None, :, None]
+    local = record - lo
+    return jnp.stack([
+        jnp.sum(live & (record >= 0)),
+        jnp.sum(live & (local >= 0) & (local < held)),
+    ]).astype(jnp.int32)
+
+
+def moe_chunk_counts(*, row, **_):
+    """One fetched chunk's expert-choice sums (``MOE_SUMS``, summed on
+    the device over its steps and live lanes) as the counters the routed
+    layer's metrics read: picks made, picks that fell on an expert held
+    here, distinct held experts a (layer, step) touched — the weights a
+    step had to read — and the (layer, step)s counted; and one sample of
+    ``serve_moe_tokens_per_expert`` where an expert was touched."""
+    sums = dict(zip(MOE_SUMS, (int(v) for v in row[: len(MOE_SUMS)])))
+    samples = {}
+    if sums["serve_moe_experts_touched"]:
+        samples["serve_moe_tokens_per_expert"] = (
+            sums["serve_moe_picks_local"] / sums["serve_moe_experts_touched"]
+        )
+    return sums, samples
+
+
+def latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
+    """Attention: the low-rank down-projections and their norms replicated
+    (every device forms the same latent row, and the row pool is
+    replicated); the per-head up-projections column-parallel over heads,
+    ``wo`` row-parallel — one psum, as for the GQA block.  Dense and
+    shared MLPs: Megatron.  Routed experts: the EXPERT axis over ``model``
+    — expert parallelism; the range a process holds (``experts_held``) is
+    one device's shard of a layer's experts, and the router is
+    replicated."""
+    specs: Dict[str, P] = {}
+    for i in range(cfg.num_layers):
+        p = f"l{i}_"
+        specs.update({
+            p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
+            p + "wq_a": P(None, None), p + "q_norm_g": P(None),
+            p + "wq_b": P(None, m),
+            p + "wkv_a": P(None, None), p + "kv_norm_g": P(None),
+            p + "wk_b": P(None, m), p + "wv_b": P(None, m),
+            p + "wo": P(m, None),
+        })
+        if i < cfg.first_dense_layers:
+            specs.update({p + "w_gate": P(None, m), p + "w_up": P(None, m),
+                          p + "w_down": P(m, None)})
+            continue
+        specs.update({
+            p + "router": P(None, None),
+            p + "e_gate": P(m, None, None), p + "e_up": P(m, None, None),
+            p + "e_down": P(m, None, None),
+            p + "s_gate": P(None, m), p + "s_up": P(None, m),
+            p + "s_down": P(m, None),
+        })
+    return specs
+
+
+def latent_serving(cfg: DecoderConfig) -> BlockServing:
+    """The block's record (``cfg`` checked: :func:`check_latent_config`)."""
+    # served cold and unspeculated: a warm prefill would up-project cached
+    # rows, which no path does, and a speculative chunk would drop the
+    # routing record.  No Pallas kernel reads a latent row; a block that
+    # does not route carries no sums.  One row a token, no head axis: its
+    # pools are replicated
+    routes = bool(routed_layers(cfg))
+    sums = dict(
+        step_sum_names=MOE_SUMS,
+        step_sums=functools.partial(moe_step_sums, cfg),
+        prefill_sum_names=MOE_PREFILL_SUMS,
+        prefill_sums=functools.partial(moe_prefill_sums, cfg),
+        chunk_counts=moe_chunk_counts,
+    ) if routes else {}
+    return BlockServing(
+        label=f'DecoderConfig(block="{cfg.block}")',
+        unserved=("generate.prefix_cache", "generate.speculative_k"),
+        advice="set prefix_cache false and speculative_k 0",
+        solo=(
+            f'has no "{LATENT_BLOCK}" block (model_type deepseek_v2): it '
+            "serves through the batcher (engines/serve.ContinuousBatcher) "
+            "over the paged latent cache (engines/paged.py) only"
+        ),
+        uses_flash=False,
+        param_pspecs=functools.partial(latent_param_pspecs, cfg),
+        pool_pspecs=lambda: {f"c{i}": P() for i in range(cfg.num_layers)},
+        **sums,
+    )

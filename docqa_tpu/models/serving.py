@@ -1,0 +1,88 @@
+"""What a decoder block's SURROUNDINGS ask of its kind: one record a kind
+(:class:`BlockServing`), kept by the model module that owns the kind
+(``models/decoder.block_serving`` picks it) and READ by the batcher, the
+solo engine, the sharding rules and the two audits — none of which
+branches on a kind.  This module imports nothing of the package.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Dict, Mapping, NamedTuple, Optional, Tuple
+
+
+class KernelForms(NamedTuple):
+    """Which ops of the paged forwards run their Pallas form — decided
+    once (``models/decoder.kernel_forms``), handed to the ops as plain
+    static booleans, counted by the batcher as they stand."""
+
+    paged: bool  # ``paged_decode_attention`` reads live pages in place
+    sparse_paged: bool  # a sparse layer's decode reads its blocks as pages
+    scan: bool  # a state-space layer's prefill scan keeps ``h`` on the chip
+    grouped: bool  # a routed layer's products are ``megablox.gmm``
+
+
+def _no_counts(**_) -> Tuple[Dict[str, int], Dict[str, float]]:
+    return {}, {}
+
+
+def _nothing(*_, **__) -> dict:
+    return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockServing:
+    """One block kind's answers, in the order a new kind fills them in.
+    The defaults are the plain GQA block's: nothing refused, no sums, no
+    counters, the GQA sharding rules.
+
+    What it is NOT served with: ``label`` names the kind in a refusal,
+    ``unserved`` the settings the batcher refuses it with, by dotted name
+    (``generate.prefix_cache``, ``generate.speculative_k``,
+    ``qos.preemption``), ``advice`` what to set instead; ``solo`` why the
+    dense-cache engine and forward do not run it (``None``: they do);
+    ``uses_flash`` whether ``use_flash`` reaches a kernel of the kind at
+    all.  (A configuration the kind cannot run is refused by field where
+    the record is built.)
+
+    The sums a program carries to the host in the fetch the worker makes
+    anyway, summed inside the jitted programs under the names of the
+    counters they feed: ``step_sums(record, lengths, active)`` of one
+    decode step (the record its forward hands back, the lanes' lengths
+    BEFORE the step, the lanes live in it), ``prefill_sums(record, seg)``
+    of one dispatch (``seg`` < 0: padding).
+
+    What the host counts, pure arithmetic (the batcher increments):
+    ``chunk_counts(lane_steps=, row=, kernels=)`` of a fetched chunk ->
+    ({counter: amount}, {histogram: sample}); ``prefill_counts(lanes=,
+    tokens=, dispatches=, kernels=)`` of an admission round; ``kv_rows_read
+    (lens, kernels=, block_size=, table_rows=)`` where the kind reads KV
+    by a rule of its own; ``span_attrs`` on a request's ``serve_prefill``
+    and ``serve_decode_chunk`` spans, ``prefill_attrs(n_ids, n_lanes)`` on
+    the first; ``occupancy`` beside the block-pool gauges; ``lane_state``:
+    a lane keeps state beside its rows, found through the slot map in the
+    pools (``engines/paged.STATE_SLOT``) whose host copy the batcher writes.
+
+    The sharding beyond the GQA rules, ``PartitionSpec``s by name:
+    ``param_pspecs(model_axis)`` of every per-layer parameter of a kind
+    with layers of its own, ``pool_pspecs()`` of every pool.
+    """
+
+    label: str
+    unserved: Tuple[str, ...] = ()
+    advice: str = ""
+    solo: Optional[str] = None
+    uses_flash: bool = True
+    step_sum_names: Tuple[str, ...] = ()
+    step_sums: Optional[Callable] = None
+    prefill_sum_names: Tuple[str, ...] = ()
+    prefill_sums: Optional[Callable] = None
+    chunk_counts: Callable = _no_counts
+    prefill_counts: Callable = _nothing
+    kv_rows_read: Optional[Callable] = None
+    span_attrs: Mapping = dataclasses.field(default_factory=dict)
+    prefill_attrs: Callable = _nothing
+    occupancy: Mapping = dataclasses.field(default_factory=dict)
+    lane_state: bool = False
+    param_pspecs: Optional[Callable] = None
+    pool_pspecs: Optional[Callable] = None

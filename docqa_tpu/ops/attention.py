@@ -304,24 +304,22 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     """Decode-side attention through a block table (the decode half of
     Ragged Paged Attention).
 
-    ``use_flash`` (derived from the backend, ``engines/generate.py``):
-    the Pallas kernel :func:`paged_flash_decode` reads each lane's live
-    pages straight out of the pool — nothing of the table's span or the
-    pool's size is gathered, transposed or fetched.  Otherwise (every CPU
-    run, and a head geometry the kernel does not read —
-    :func:`paged_kernel_supported`) the XLA reference: gather the pages into a per-sequence
-    contiguous view, then the standard masked attention — the plain
-    reference the kernel is tested against, the same way
-    :func:`attention_reference` backs :func:`flash_attention`.
+    ``use_flash`` (``models/decoder.kernel_forms``'s ``paged``: a TPU and
+    a head geometry the kernel reads, :func:`paged_kernel_supported`): the
+    Pallas kernel :func:`paged_flash_decode` reads each lane's live pages
+    straight out of the pool — nothing of the table's span or the pool's
+    size is gathered, transposed or fetched.  Otherwise (every CPU run)
+    the XLA reference: gather the pages into a per-sequence contiguous
+    view, then the standard masked attention — the plain reference the
+    kernel is tested against, the same way :func:`attention_reference`
+    backs :func:`flash_attention`.
 
     q            [S, s, q_heads, d] (s = 1 plain step, K spec verify)
     k/v_pool     [P, kv_heads, d] flat block pool
     block_tables [S, NB] int32
     lengths      [S] valid kv length per sequence AFTER this step
     """
-    if use_flash and paged_kernel_supported(
-        k_pool.dtype, k_pool.shape[1], k_pool.shape[2], mesh
-    ):
+    if use_flash:
         return paged_flash_decode(
             q, k_pool, v_pool, block_tables, lengths, q_offset,
             block_size=block_size, sliding_window=sliding_window,
@@ -996,7 +994,7 @@ def attention(q, k, v, **kwargs):
 # past is a state a lane, and block-sparse attention that selects inside
 # the paged cache.  XLA but for the decode step's read of the blocks taken,
 # which on a TPU is :func:`paged_flash_decode` through a page table of its
-# own (:func:`sparse_paged_chosen`, :func:`taken_page_tables`).
+# own (:func:`taken_page_tables`).
 # --------------------------------------------------------------------------
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -1220,19 +1218,6 @@ def sparse_prefill_attention(q, k, v, seg_ids, positions, seg_lens, ck, ck_ok,
     return out.reshape(t, hq, d), _pad_record(rec, topk)
 
 
-def sparse_paged_chosen(use_flash, mesh, pool_dtype, kv_heads: int,
-                        head_dim: int, block: int, block_size: int) -> bool:
-    """Whether :func:`sparse_decode_attention` reads the blocks taken as
-    PAGES through the paged kernel: the engine saw a TPU (``use_flash``),
-    there is no mesh (``ops/ssm.scan_kernel_chosen``'s rule: the XLA form
-    is what GSPMD places), the kernel reads a pool of this geometry
-    (:func:`paged_kernel_supported`) and a selection block is a whole
-    number of pages.  The batcher counts by the same answer."""
-    return bool(
-        use_flash and mesh is None and block % block_size == 0
-        and paged_kernel_supported(pool_dtype, kv_heads, head_dim))
-
-
 def taken_page_tables(ids, took, sparse_lane, block_tables, lengths, *,
                       block_size: int, block: int, dense_len: int,
                       n_blocks: int):
@@ -1287,7 +1272,7 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
                             *, block_size: int, kernel_size: int, stride: int,
                             block: int, topk: int, init_blocks: int,
                             window: int, dense_len: int, use_flash=False,
-                            mesh=None, interpret: bool = False):
+                            interpret: bool = False):
     """The decode step of the same THROUGH A BLOCK TABLE: one query a
     lane selects among the lane's compressed keys (gathered through the
     table: one row per ``stride`` tokens) and reads the K / V rows of the
@@ -1295,8 +1280,10 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
     whatever the lane's length.  A lane that holds fewer than
     ``dense_len`` tokens reads every row.
 
-    Under :func:`sparse_paged_chosen` (``interpret`` for a CPU test of
-    it) ONE call of the paged kernel reads them in place, a virtual lane
+    Under ``use_flash`` (``models/decoder.kernel_forms``'s
+    ``sparse_paged``: a TPU, no mesh, a geometry the paged kernel reads, a
+    selection block a whole number of pages; ``interpret`` for a CPU test
+    of it) ONE call of the paged kernel reads them in place, a virtual lane
     a (lane, kv head) through :func:`taken_page_tables`: a lane under
     ``dense_len`` is a virtual lane whose table is its own.  Otherwise the
     XLA form: a row gather of the blocks taken; while any LIVE lane
@@ -1399,8 +1386,7 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
             scores, mask, gather_paged_kv(v_pool, block_tables, block_size),
             "sgpk,skgd->sgpd")
 
-    if interpret or sparse_paged_chosen(
-            use_flash, mesh, k_pool.dtype, g, d, block, block_size):
+    if interpret or use_flash:
         out = taken_as_pages()
     else:
         out = jax.lax.cond(

@@ -6,8 +6,8 @@ the stacked weights,
 
 and nothing for a row past ``sum(sizes)`` — what lies there in the result
 is UNDEFINED (the kernel never writes it), so a caller masks those rows.
-Two forms, chosen by what can be observed of the backend, as
-``ops/attention.attention`` and ``ops/ssm.selective_scan_prefill`` choose:
+Two forms, chosen where every kernel is (``models/decoder.kernel_forms``'s
+``grouped``) and handed down as ``use_flash``:
 
 * a TPU with no mesh: ``megablox.gmm``, the Pallas kernel that ships
   with JAX.  Its grid walks only the (row tile, group) pairs that hold
@@ -40,13 +40,6 @@ _LANE = 128
 _CONTRACTION = 8192
 
 
-def grouped_kernel_chosen(mesh) -> bool:
-    """Whether :func:`grouped_matmul` runs the Pallas kernel: a TPU and no
-    mesh (on a mesh the stacked experts are sharded along their leading
-    axis, which ``ragged_dot`` partitions and a custom call cannot)."""
-    return jax.default_backend() == "tpu" and mesh is None
-
-
 def _tile(size: int, most: int) -> int:
     """The largest multiple of a lane that divides ``size`` and is at most
     ``most``; ``size`` itself where it is small or has none."""
@@ -62,13 +55,13 @@ def row_tile(m: int) -> int:
     return min(ROW_TILE, round_up(m, 16))
 
 
-def grouped_matmul(lhs, rhs, group_sizes, out_dtype, *, mesh=None,
-                   interpret: bool = False):
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype, *,
+                   use_flash: bool = False, interpret: bool = False):
     """``lhs`` [m, k] (rows sorted by group; ``m`` a multiple of
     :func:`row_tile`), ``rhs`` [groups, k, n], ``group_sizes`` [groups]
     int32 -> [m, n] in ``out_dtype``, accumulated in float32.  A group
     of size zero is not read; rows past the groups are undefined."""
-    if not (grouped_kernel_chosen(mesh) or interpret):
+    if not (use_flash or interpret):
         return jax.lax.ragged_dot(
             lhs, rhs, group_sizes, preferred_element_type=out_dtype
         )

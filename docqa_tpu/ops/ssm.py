@@ -82,17 +82,8 @@ def causal_conv_step(u, window, weight, bias):
     return jax.nn.silu(acc).astype(u.dtype), full[:, 1:].astype(window.dtype)
 
 
-def scan_kernel_chosen(use_flash, mesh) -> bool:
-    """Whether :func:`selective_scan_prefill` runs the Pallas kernel: the
-    engine saw a TPU (``use_flash``, ``engines/generate.py``) and there is
-    no mesh (the stack's ``ssm_*`` arrays are replicated there and the XLA
-    form lowers as it stands).  The batcher counts by the same answer."""
-    return True if use_flash and mesh is None else False
-
-
 def selective_scan_prefill(c, delta, a, b, cc, d_skip, seg_ids, positions,
-                           last_rows, *, use_flash=False, mesh=None,
-                           interpret=False):
+                           last_rows, *, use_flash=False, interpret=False):
     """The selective scan over a PACKED batch.
 
     c      [T, d] conv output; delta [T, d] float32 step sizes (> 0)
@@ -106,11 +97,12 @@ def selective_scan_prefill(c, delta, a, b, cc, d_skip, seg_ids, positions,
     Returns (g [T, d] in c's type, the state after each segment's last row
     float32 [B, n, d]).  A padding row leaves the state as it is: its
     ``delta`` is masked to 0, so the decay is ``exp(0) = 1`` and the input
-    term 0.  Under :func:`scan_kernel_chosen` the Pallas kernel walks the
-    rows (``interpret`` for a CPU test of it); otherwise the XLA form."""
+    term 0.  Under ``use_flash`` (``models/decoder.kernel_forms``'s
+    ``scan``: a TPU and no mesh) the Pallas kernel walks the rows
+    (``interpret`` for a CPU test of it); otherwise the XLA form."""
     f32 = jnp.float32
     delta = jnp.where((seg_ids >= 0)[:, None], delta.astype(f32), 0.0)
-    if scan_kernel_chosen(use_flash, mesh) or interpret:
+    if use_flash or interpret:
         g, h_chunk_end = _scan_rows_in_order(
             c, delta, a, b, cc, d_skip,
             (positions[::RAGGED_ALIGN] == 0).astype(jnp.int32),

@@ -7,14 +7,12 @@ from docqa_tpu.parallel.sharding import (
     cache_pspecs,
     decoder_param_pspecs,
     shard_decoder_params,
-    shard_kv_cache,
 )
 
 __all__ = [
     "decoder_param_pspecs",
     "cache_pspecs",
     "shard_decoder_params",
-    "shard_kv_cache",
     "ring_attention",
     "ring_attention_local",
     "ulysses_attention",

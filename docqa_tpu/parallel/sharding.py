@@ -27,23 +27,24 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
-from docqa_tpu.models.hybrid import LINEAR, MAMBA, is_hybrid
-from docqa_tpu.models.latent import is_latent
+from docqa_tpu.models.decoder import block_serving, decoder_param_schema
 from docqa_tpu.runtime.mesh import MeshContext
 
 
 def decoder_param_pspecs(cfg: DecoderConfig, model_axis: str) -> Dict[str, P]:
+    """A kind with layers of its own names every per-layer parameter
+    (``models/serving.BlockServing.param_pspecs``); the GQA tree takes the
+    Megatron rules below, and whatever else it holds — the sandwich norms'
+    gains, the looped trunk's exit gate of 2,049 numbers — is replicated."""
     m = model_axis
     specs: Dict[str, P] = {
         "tok_emb": P(None, None),  # replicated (gather-heavy; small at 7B)
         "final_norm_g": P(None),
         "lm_head": P(None, m),  # vocab-sharded logits
     }
-    if is_latent(cfg):
-        specs.update(_latent_param_pspecs(cfg, m))
-        return specs
-    if is_hybrid(cfg):
-        specs.update(_hybrid_param_pspecs(cfg, m))
+    own = block_serving(cfg).param_pspecs
+    if own is not None:
+        specs.update(own(m))
         return specs
     for i in range(cfg.num_layers):
         specs.update(
@@ -59,91 +60,8 @@ def decoder_param_pspecs(cfg: DecoderConfig, model_axis: str) -> Dict[str, P]:
                 f"l{i}_w_down": P(m, None),
             }
         )
-        if cfg.sandwich_norm:  # gains over the hidden axis: replicated
-            specs[f"l{i}_attn_post_norm_g"] = P(None)
-            specs[f"l{i}_mlp_post_norm_g"] = P(None)
-    if cfg.loop_steps > 1:  # the exit gate: 2,049 numbers, replicated
-        specs["exit_gate_w"] = P(None, None)
-        specs["exit_gate_b"] = P(None)
-    return specs
-
-
-def _latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
-    """The latent block (models/latent.py).  Attention: the low-rank
-    down-projections and their norms replicated (every device forms the
-    same latent row, and the row pool is replicated); the per-head
-    up-projections column-parallel over heads, ``wo`` row-parallel — one
-    psum, as for the GQA block.  Dense and shared MLPs: Megatron.  Routed
-    experts: the EXPERT axis over ``model`` — expert parallelism; the
-    range a process holds (``experts_held``) is one device's shard of a
-    layer's experts, and the router is replicated."""
-    specs: Dict[str, P] = {}
-    for i in range(cfg.num_layers):
-        p = f"l{i}_"
-        specs.update({
-            p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
-            p + "wq_a": P(None, None), p + "q_norm_g": P(None),
-            p + "wq_b": P(None, m),
-            p + "wkv_a": P(None, None), p + "kv_norm_g": P(None),
-            p + "wk_b": P(None, m), p + "wv_b": P(None, m),
-            p + "wo": P(m, None),
-        })
-        if i < cfg.first_dense_layers:
-            specs.update({p + "w_gate": P(None, m), p + "w_up": P(None, m),
-                          p + "w_down": P(m, None)})
-            continue
-        specs.update({
-            p + "router": P(None, None),
-            p + "e_gate": P(m, None, None), p + "e_up": P(m, None, None),
-            p + "e_down": P(m, None, None),
-            p + "s_gate": P(None, m), p + "s_up": P(None, m),
-            p + "s_down": P(m, None),
-        })
-    return specs
-
-
-def _hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
-    """The stack of mixer kinds (models/hybrid.py): Megatron per layer.
-    An attention kind: q, the output gate and the MLP's gate / up
-    column-parallel, ``wo`` and ``w_down`` row-parallel; a linear layer's
-    k and v are as wide as its q and go column-parallel with it; the few
-    kv heads of a sparse or a plain attention layer are replicated (1 or
-    2 heads do not divide over 4 or 8 devices), as are the per-head norm
-    gains.  The state-space kind along its INNER channels: ``w_in``
-    column-parallel over its ``2 x inner`` columns (GSPMD re-lays the
-    ``u`` and the ``z`` half along ``inner``), the conv's taps and bias,
-    ``w_x``'s input, ``w_dt``'s output, ``b_dt``, ``A_log`` and ``D``
-    along that axis, ``w_out`` row-parallel; the three inner norms
-    replicated.  The pools — rows (ONE kv head cannot be divided),
-    compressed keys, lane states and windows — are replicated
-    (``paged_pool_pspecs``)."""
-    specs: Dict[str, P] = {}
-    for i, kind in enumerate(cfg.mixer_types):
-        p = f"l{i}_"
-        specs.update({
-            p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
-            p + "w_gate": P(None, m), p + "w_up": P(None, m),
-            p + "w_down": P(m, None),
-        })
-        if kind == MAMBA:
-            specs.update({
-                p + "w_in": P(None, m), p + "b_in": P(m),
-                p + "conv_w": P(None, m), p + "conv_b": P(m),
-                p + "w_x": P(m, None), p + "dt_norm_g": P(None),
-                p + "b_norm_g": P(None), p + "c_norm_g": P(None),
-                p + "w_dt": P(None, m), p + "b_dt": P(m),
-                p + "a_log": P(None, m), p + "d_skip": P(m),
-                p + "w_out": P(m, None), p + "b_out": P(None),
-            })
-            continue
-        kv = P(None, m) if kind == LINEAR else P(None, None)
-        specs.update({
-            p + "q_norm_g": P(None), p + "k_norm_g": P(None),
-            p + "wq": P(None, m), p + "wk": kv, p + "wv": kv,
-            p + "w_ogate": P(None, m), p + "wo": P(m, None),
-        })
-        if kind == LINEAR:
-            specs[p + "o_norm_g"] = P(None)
+    for name, _kind, shape, _fan_in in decoder_param_schema(cfg):
+        specs.setdefault(name, P(*(None,) * len(shape)))
     return specs
 
 
@@ -204,14 +122,6 @@ def shard_decoder_params(params, cfg: DecoderConfig, mesh: MeshContext):
     }
 
 
-def shard_kv_cache(cache, cfg: DecoderConfig, mesh: MeshContext):
-    specs = cache_pspecs(cfg, mesh)
-    return {
-        k: jax.device_put(v, NamedSharding(mesh.mesh, specs[k]))
-        for k, v in cache.items()
-    }
-
-
 def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     """Paged KV block pool [n_blocks * block_size, kv_heads, head_dim]
     (engines/paged.py): kv heads over the model axis — decode attention
@@ -224,15 +134,9 @@ def paged_pool_pspecs(cfg: DecoderConfig, mesh: MeshContext) -> Dict[str, P]:
     one-all-reduce-per-Megatron-block budget as the dense programs).
     The looped trunk's pools hold its steps' ranges along that same
     unsharded row axis (``engines/paged.init_paged_pools``)."""
-    if is_latent(cfg):  # one row a token, no head axis: replicated
-        return {f"c{i}": P() for i in range(cfg.num_layers)}
-    if is_hybrid(cfg):  # rows of 1-2 kv heads, lane states, the slot map
-        import jax
-
-        from docqa_tpu.engines.paged import init_paged_pools
-
-        names = jax.eval_shape(lambda: init_paged_pools(cfg, 1, 16))
-        return {name: P() for name in names}
+    own = block_serving(cfg).pool_pspecs
+    if own is not None:  # a kind whose pools have no head axis to divide
+        return own()
     spec = paged_pool_sharding(mesh).spec
     out: Dict[str, P] = {}
     for i in range(cfg.num_layers):

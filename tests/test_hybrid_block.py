@@ -338,7 +338,7 @@ def _batcher(n_slots, params):
 
 
 def _sums():
-    from docqa_tpu.engines.serve import SPARSE_SUMS
+    from docqa_tpu.models.hybrid import SPARSE_SUMS
     from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
 
     names = SPARSE_SUMS + ("serve_state_bytes_rw", "serve_lane_state_resets")
@@ -396,7 +396,7 @@ def test_a_block_without_chunk_sums_adds_nothing_to_a_chunk():
     b = ContinuousBatcher(engine, n_slots=4, chunk=4, cache_len=256,
                           kv_block_size=16)
     try:
-        assert b._chunk_sum_names == () and not b._hybrid
+        assert b._block.step_sum_names == () and not b._block.lane_state
         assert "state_bytes_per_lane" not in b.kv_block_occupancy()
         out = jax.eval_shape(
             b._decode_program, engine.params,

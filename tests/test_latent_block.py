@@ -669,7 +669,7 @@ def test_the_gqa_blocks_programs_lower_to_the_text_they_lowered_to(
 # ---- through the batcher ----------------------------------------------------
 
 def _counters():
-    from docqa_tpu.engines.serve import MOE_PREFILL_SUMS, MOE_SUMS
+    from docqa_tpu.models.latent import MOE_PREFILL_SUMS, MOE_SUMS
     from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
 
     return {n: DEFAULT_REGISTRY.counter(n).value
@@ -691,8 +691,9 @@ def test_the_batcher_serves_the_block_and_counts_its_choices():
     b = ContinuousBatcher(engine, n_slots=4, chunk=4, cache_len=256,
                           kv_block_size=16, prefix_cache=False)
     try:
-        assert b._routed_layers == 2 and b.kv_bytes_per_token == 3 * 48 * 2
-        assert not b._pages_read_in_place
+        assert b._block.step_sum_names == latent.MOE_SUMS
+        assert b.kv_bytes_per_token == 3 * 48 * 2
+        assert not b._kernels.paged
         prompts = [[5 + (7 * i + j) % 500 for j in range(20 + 9 * i)]
                    for i in range(3)]
         handles = [b.submit_ids(p, max_new_tokens=9) for p in prompts]
@@ -767,7 +768,7 @@ def test_a_block_that_does_not_route_adds_nothing_to_a_chunk():
     b = ContinuousBatcher(engine, n_slots=4, chunk=4, cache_len=256,
                           kv_block_size=16)
     try:
-        assert b._routed_layers == 0
+        assert b._block.step_sum_names == ()
         out = jax.eval_shape(
             b._decode_program, engine.params,
             paged.init_paged_pools(cfg, b.n_blocks, b.block_size),

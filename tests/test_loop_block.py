@@ -602,7 +602,7 @@ def _serve(cfg, params, prompts, n_slots=2):
                           cache_len=256, kv_block_size=16, prefix_cache=False)
     try:
         occupancy = b.kv_block_occupancy()
-        attrs = b._loop_attrs
+        attrs = b._block.span_attrs
         got = [list(h.result(timeout=600)) for h in
                [b.submit_ids(p, max_new_tokens=9) for p in prompts]]
         in_use, free = b._alloc.blocks_in_use, b._alloc.n_free

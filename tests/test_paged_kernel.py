@@ -239,7 +239,7 @@ class TestKvRowCounters:
         from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
 
         assert batcher.block_size == 16
-        batcher._pages_read_in_place = in_place
+        batcher._kernels = batcher._kernels._replace(paged=in_place)
         lanes = []
         for kv_prompt, delivered in ((30, 8), (14, 3)):
             req = make_request([3] * kv_prompt, 16)
@@ -286,7 +286,7 @@ class TestKvRowCounters:
 
     def test_the_path_is_observed_not_set(self, batcher):
         # a CPU run serves the gather reference
-        assert batcher._pages_read_in_place is False
+        assert batcher._kernels.paged is False
         assert batcher.engine.use_flash is False
 
 
@@ -420,18 +420,16 @@ class TestCompilesForTheChip:
     @pytest.mark.parametrize("k, n", [(5120, 1536), (1536, 5120)], ids=[
         "gate-up", "down"])
     def test_the_grouped_product_at_deepseek_v2s_widths(
-            self, topo, monkeypatch, rows, k, n):
+            self, topo, rows, k, n):
         """ISSUE 45: ``ops/grouped.grouped_matmul`` over 40 held experts
         at the published widths — the tiles ``_tile`` picks (the
         contraction whole, 4 MB of weight a tile) fit the kernel's fast
         memory, for a decode step's 8 x 6 picks as ONE row tile, a
-        512-row dispatch's 3,072 and the comparison's 18,432 (this CPU
-        would choose ``ragged_dot``: the test steers the choice)."""
+        512-row dispatch's 3,072 and the comparison's 18,432."""
         from jax.sharding import SingleDeviceSharding
 
         from docqa_tpu.ops import grouped
 
-        monkeypatch.setattr(grouped, "grouped_kernel_chosen", lambda mesh: True)
         one_chip = SingleDeviceSharding(topo.devices[0])
 
         def arg(shape, dtype):
@@ -440,7 +438,7 @@ class TestCompilesForTheChip:
         assert rows % grouped.row_tile(rows) == 0
         hlo = jax.jit(
             lambda x, w, sizes: grouped.grouped_matmul(
-                x, w, sizes, jnp.float32)
+                x, w, sizes, jnp.float32, use_flash=True)
         ).lower(
             arg((rows, k), jnp.bfloat16), arg((40, k, n), jnp.bfloat16),
             arg((40,), jnp.int32)).compile().as_text()

@@ -8,8 +8,9 @@ call of ``paged_flash_decode`` over a virtual lane a (lane, kv head).
   tables shuffled; the query at a block's first and last row; fewer
   blocks than ``topk``.  Outputs within the paged kernel's own tolerance,
   ``taken`` identical;
-* the virtual table (``taken_page_tables``) by hand;
-* who chooses it (``sparse_paged_chosen``).
+* the virtual table (``taken_page_tables``) by hand.
+
+Who chooses it: ``models/decoder.kernel_forms`` (tests/test_block_serving.py).
 """
 
 import importlib
@@ -192,23 +193,3 @@ def test_a_table_narrower_than_the_span_is_filled_with_holes():
         [[[0, 1, 0, 0]]], [[[1, 1, 0, 0]]], [90], own[None])
     assert table.shape == (1, 16) and lens.tolist() == [90]
     assert (table[0, :8] == own).all() and (table[0, 8:] >= N_PAGES).all()
-
-
-# ---- who chooses it ------------------------------------------------------------
-
-@pytest.mark.parametrize("kw, chosen", [
-    (dict(), True),
-    (dict(use_flash=False), False),  # a CPU
-    (dict(use_flash=None), False),  # nothing observed
-    (dict(mesh=object()), False),  # GSPMD places the XLA form
-    (dict(head_dim=16), False),  # the kernel does not read the geometry
-    (dict(kv_heads=3), False),
-    (dict(block=8), False),  # a block is half a page
-    (dict(block=24), False),
-    (dict(pool_dtype=jnp.float32, kv_heads=3), True),
-], ids=["flash-no-mesh", "no-flash", "nothing-observed", "mesh", "narrow-head",
-        "odd-heads", "half-a-page", "ragged-pages", "float32"])
-def test_the_path_is_chosen_by_what_the_program_observes(kw, chosen):
-    args = dict(use_flash=True, mesh=None, pool_dtype=jnp.bfloat16,
-                kv_heads=2, head_dim=128, block=64, block_size=16)
-    assert A.sparse_paged_chosen(**{**args, **kw}) is chosen

@@ -388,7 +388,8 @@ def test_a_slot_a_longer_lane_left_starts_from_zeros_and_the_counters_count():
     before = _counters()
     b = _batcher(2, served_params)
     try:
-        assert b._chunk_sum_names == () and b._hybrid and not b._selects
+        assert b._block.step_sum_names == () and b._block.lane_state
+        assert b._block.kv_rows_read is None  # no layer selects
         assert b.kv_bytes_per_token == 1 * (2 * 1 * 16) * 2
         assert b.kv_block_occupancy()["state_bytes_per_lane"] == state_bytes
         out = jax.eval_shape(
@@ -399,7 +400,7 @@ def test_a_slot_a_longer_lane_left_starts_from_zeros_and_the_counters_count():
             jnp.zeros((2,), jnp.int32), jnp.zeros((2,), bool),
             jax.random.PRNGKey(0))
         assert out[-1].shape == (2, 2 * 4 + 1)  # one row a slot, no sums
-        assert b._hybrid_prefill_attrs(100, 2) == {
+        assert b._block.prefill_attrs(100, 2) == {
             "state_lanes": 2, "scan_rows": 100}  # no sparse_rows: none selects
         got = [h.result(timeout=600) for h in
                [b.submit_ids(p, max_new_tokens=10) for p in prompts]]
@@ -444,7 +445,7 @@ def test_the_batcher_counts_the_dispatches_that_scanned_in_the_kernel(
         before = _counters()
         b = _batcher(2, served_params, use_flash=flash)
         try:
-            assert b._scan_kernel == flash
+            assert b._kernels.scan == flash
             got[flash] = [list(h.result(timeout=600)) for h in
                           [b.submit_ids(p, max_new_tokens=6) for p in prompts]]
         finally:
@@ -810,7 +811,7 @@ def test_the_batcher_counts_the_chunks_that_read_the_taken_blocks_as_pages(
         b = ContinuousBatcher(engine, n_slots=2, chunk=4, cache_len=256,
                               kv_block_size=16, prefix_cache=False)
         try:
-            assert b._sparse_paged == flash
+            assert b._kernels.sparse_paged == flash
             got[flash] = [list(h.result(timeout=600)) for h in
                           [b.submit_ids(p, max_new_tokens=10) for p in prompts]]
         finally:
@@ -923,7 +924,7 @@ def test_the_forward_scans_in_the_kernel_only_under_flash_and_no_mesh(
 
     sds, i32 = jax.ShapeDtypeStruct, jnp.int32
     mesh = host_cpu_mesh(2) if meshed else None
-    assert ssm.scan_kernel_chosen(use_flash, mesh) == bool(calls)
+    assert paged._forms(TOY, None, use_flash, mesh, BS).scan == bool(calls)
     pools = jax.eval_shape(lambda: paged.init_paged_pools(TOY, 32, BS))
 
     def prefill(params, pools, ids, seg, pos, dest, last):
