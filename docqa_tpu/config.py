@@ -192,6 +192,14 @@ class DecoderConfig:
     routed_scale: float = 1.0
     experts_held_start: int = 0
     experts_held: int = 0
+    # the router (models/routed.py, both blocks that route): how it scores
+    # ("softmax" over the experts | "sigmoid" of each), a float32 bias a
+    # routed layer adds for the CHOICE alone (``l{i}_router_bias``: never
+    # to a weight), and whether the taken scores are normalised over the k
+    # taken (+ 1e-20) before ``routed_scale``
+    router_score: str = "softmax"
+    router_bias: bool = False
+    router_norm: bool = False
     # ---- "sparse_linear" (models/hybrid.py) ------------------------------
     # A stack of mixer kinds, one name per layer in ``mixer_types``
     # (``len == num_layers``): "linear" — decayed linear attention whose
@@ -235,6 +243,14 @@ class DecoderConfig:
     # (``use_output_gate``), the linear mixer's per-head output norm
     # (``use_output_norm``).  ``tie_embeddings``: the tree holds no
     # ``lm_head``, logits are taken against ``tok_emb``.
+    # "window": GQA softmax attention with RoPE over the last
+    # ``sliding_window`` rows alone (a stack that names the kind sets the
+    # field; none else may): its pools hold a RING of pages a lane — the
+    # window plus one page — whatever the lane's length
+    # (``engines/paged.py``).  ``num_experts`` > 1: the layers past
+    # ``first_dense_layers`` route (the fields above, ``models/routed.py``)
+    # and the leading ones keep the dense SwiGLU of ``mlp_dim``;
+    # ``sandwich_norm`` (below) is read by this stack too.
     qk_norm: bool = True
     use_output_gate: bool = True
     use_output_norm: bool = True
@@ -254,8 +270,9 @@ class DecoderConfig:
     # [1]) that ``loop_exit_threshold`` 1.0 never evaluates (no step exits
     # early; a value under 1 is refused: a depth that differs by lane
     # needs a scheduler).  ``sandwich_norm``: a norm on each sublayer's
-    # OUTPUT before its residual add, beside the two pre-norms.  At the
-    # defaults the block's programs are what they were.
+    # OUTPUT before its residual add, beside the two pre-norms (the stack
+    # of mixer kinds reads it too).  At the defaults the block's programs
+    # are what they were.
     loop_steps: int = 1
     sandwich_norm: bool = False
     loop_exit_threshold: float = 1.0

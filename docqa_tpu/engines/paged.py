@@ -59,14 +59,18 @@ from docqa_tpu.models.hybrid import (
     MAMBA,
     SPARSE,
     STATE_SLOT,
+    WINDOW,
+    WINDOW_PAGES,
     decay_slopes,
     hybrid_head,
     hybrid_layer_stack,
     is_hybrid,
     lane_state_entries,
     layers_of,
+    ring_pages,
     sparse_layers,
     ssm_constants,
+    window_layers,
 )
 from docqa_tpu.models.latent import (
     absorb_query,
@@ -99,8 +103,9 @@ from docqa_tpu.ops.ssm import (
 
 # "k0".."k{L-1}", "v0".."v{L-1}"; the latent block: "c0".."c{L-1}"; the
 # stack of mixer kinds: "k{i}" / "v{i}" of its row-keeping layers ("ck{i}"
-# where one selects), the lane-state entries of its state-keeping layers
-# and STATE_SLOT (``_init_hybrid_pools``)
+# where one selects; a WINDOW layer's hold a ring of pages a lane), the
+# lane-state entries of its state-keeping layers, STATE_SLOT and
+# WINDOW_PAGES (``_init_hybrid_pools``)
 PagedPools = Dict[str, "jnp.ndarray"]
 
 
@@ -127,7 +132,7 @@ class BlockTable:
 
     __slots__ = (
         "blocks", "n_shared", "released", "_alloc", "acc_base",
-        "billed_block_seconds",
+        "billed_block_seconds", "ring",
     )
 
     def __init__(self, alloc: "BlockAllocator") -> None:
@@ -143,6 +148,11 @@ class BlockTable:
         # the block's total in-use time (exactness under sharing).
         self.acc_base: List[float] = []
         self.billed_block_seconds = 0.0
+        # a lane's SECOND table, of a second allocator: the pages of its
+        # ring in the pools of window layers (``models/hybrid.ring_pages``),
+        # taken with this table at admission and released with it, so that
+        # every path that frees a lane frees both exactly once
+        self.ring: Optional["BlockTable"] = None
 
     @property
     def capacity(self) -> int:
@@ -156,10 +166,13 @@ class BlockTable:
         self._alloc.grow(self, n_tokens)
 
     def release(self) -> None:
-        """Return every block to the pool.  Idempotent and thread-safe:
-        retire (worker), stop-sweep (caller thread), and failover paths
-        may all reach a table — exactly one of them frees it."""
+        """Return every block to the pool (and the lane's ring to its
+        own).  Idempotent and thread-safe: retire (worker), stop-sweep
+        (caller thread), and failover paths may all reach a table —
+        exactly one of them frees it."""
         self._alloc.release(self)
+        if self.ring is not None:
+            self.ring.release()
 
 
 class BlockAllocator:
@@ -702,7 +715,9 @@ def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     mixer kinds: the K and V rows of its row-keeping layers (sparse,
     attention), plus a SPARSE layer's share of a compressed key (one per
     ``sparse_kernel_stride`` tokens); its state-keeping layers (linear,
-    state-space) keep nothing a token (``models/hybrid.lane_state_bytes``).
+    state-space) keep nothing a token (``models/hybrid.lane_state_bytes``),
+    and neither does a WINDOW layer: what it holds is a ring a lane,
+    whatever the lane's length (``models/hybrid.ring_pages``).
     The looped trunk: an entry a (step, layer), ``kv_entries`` times a
     plain model's."""
     item = jnp.dtype(cfg.dtype).itemsize
@@ -1023,8 +1038,18 @@ def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
     """The pools of the stack of mixer kinds, by what each layer's kind
     keeps (``models/hybrid.MIXERS``):
 
+    * ``k{i}`` / ``v{i}`` [n_lanes * ring_pages * block_size, kv heads, d]
+      of each WINDOW layer: a second extent, in which a lane owns a RING of
+      ``models/hybrid.ring_pages`` pages — position ``p`` lives in page
+      ``(p // block_size) % ring_pages`` of it — named by its row of
+      ``window_pages`` [n_lanes, ring_pages] int32 (the lane's entry is
+      found through ``state_slot``, as its state is).  It starts as lane
+      after lane (entry ``l`` owns pages ``l * ring_pages ...``); an
+      allocator's owner writes the pages it took at admission
+      (``engines/serve.py``).  Pages past the window alias newer ones and
+      are never read: the window's mask, and the kernel's first block;
     * ``k{i}`` / ``v{i}`` [n_blocks * block_size, kv heads, d] of each
-      row-keeping layer (sparse, attention), and of a SPARSE layer
+      other row-keeping layer (sparse, attention), and of a SPARSE layer
       ``ck{i}`` [rows / sparse_kernel_stride, kv heads, d]: the
       mean-pooled key of the window that STARTS at that stride of that
       page (written when the window completes, by the prefill and by the
@@ -1056,10 +1081,19 @@ def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
     per_lane = -(-cfg.max_seq_len // block_size)
     n_lanes = n_lanes or max(1, n_blocks // per_lane)
     pools: PagedPools = {}
+    ring = ring_pages(cfg, block_size) if window_layers(cfg) else 0
     for i in range(cfg.num_layers):
+        held = (n_lanes * ring * block_size
+                if cfg.mixer_types[i] == WINDOW else rows)
         for prefix, (heads, width) in kv_row_shapes(cfg, i).items():
             pools[f"{prefix}{i}"] = jnp.zeros(
-                (rows, heads, width), dtype, device=sharding)
+                (held, heads, width), dtype, device=sharding)
+    if ring:
+        pages = jnp.arange(n_lanes * ring, dtype=jnp.int32).reshape(
+            n_lanes, ring)
+        pools[WINDOW_PAGES] = (
+            pages if sharding is None
+            else jnp.asarray(pages, device=sharding))
     for i in selecting:
         pools[f"ck{i}"] = jnp.zeros(
             (rows // st, cfg.num_kv_heads, cfg.head_dim), dtype,
@@ -1089,9 +1123,26 @@ def _state_slots(pools, cfg, first_rows, ok=True):
     rows ``first_rows``; out of bounds (a zero read, a dropped write)
     where ``ok`` is false or the row is past the pool (a hole)."""
     slot_of = pools[STATE_SLOT]
-    n_slots = pools[next(iter(lane_state_shapes(cfg)))].shape[0]
+    n_slots = pools[
+        next(iter(lane_state_shapes(cfg)), WINDOW_PAGES)].shape[0]
     slot = slot_of[jnp.minimum(first_rows, slot_of.shape[0] - 1)]
     return jnp.where(ok & (first_rows < slot_of.shape[0]), slot, n_slots)
+
+
+def _ring_rows(pools, cfg, slots, pos, ok=True):
+    """Flat rows in a WINDOW layer's pools of the positions ``pos`` of the
+    lanes whose entries are ``slots`` (same shape) — out of bounds, a
+    dropped write, where ``ok`` is false or the entry or its page is a
+    hole.  The block size is read off the pools: the one extent a prefill
+    is not told."""
+    pages = pools[WINDOW_PAGES]
+    n_pages = pages.size
+    held = pools[f"k{window_layers(cfg)[0]}"].shape[0]
+    block_size = held // n_pages
+    page = pages.at[slots, (pos // block_size) % pages.shape[1]].get(
+        mode="fill", fill_value=n_pages)
+    return jnp.where(
+        ok & (page < n_pages), page * block_size + pos % block_size, held)
 
 
 def _write_rows(pools, i, dest, k, v):
@@ -1115,9 +1166,14 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
     scan's state, a state-space layer its scan's state (the Pallas kernel
     under ``kernels.scan``, ``ops/ssm.py``) and its last conv inputs.
 
+    A WINDOW layer scatters into the lane's ring only the rows a later
+    step can still see and attends over the rows in flight inside the
+    window (the key blocks wholly outside it skipped).
+
     Returns (last_logits [B, vocab] f32, pools, selection record int32
-    [sparse layers x kv heads, T, sparse_topk] of the packed rows) — two
-    values where no layer selects."""
+    [sparse layers x kv heads, T, sparse_topk] of the packed rows — the
+    routing record int32 [routed layers, T, experts_per_token] of a stack
+    that routes) — two values where no layer selects or routes."""
     sizes = _sparse_sizes(cfg)
     st = sizes["stride"]
     t = ids.shape[0]
@@ -1129,8 +1185,12 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
         seg_len = jnp.where(seg_ok, positions[last_rows] + 1, 0)
         seg_lens = jnp.where(
             seg_ids >= 0, seg_len[jnp.maximum(seg_ids, 0)], 0)
-    slots = chunk_slot = None
-    if kinds & {LINEAR, MAMBA}:
+    slots = chunk_slot = ring_dest = None
+    # several kv heads: a group's query heads share ONE copy of a head's
+    # K and V (a single kv head's repeat is a broadcast as it stands); no
+    # segment is longer than the sequence capacity, ``rope_len``
+    grouped_heads = cfg.num_kv_heads > 1
+    if kinds & {LINEAR, MAMBA, WINDOW}:
         with scope("state"):
             first_rows = last_rows - positions[last_rows]
             slots = _state_slots(pools, cfg, dest_rows[first_rows], seg_ok)
@@ -1143,6 +1203,16 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
                     == jnp.arange(t // RAGGED_ALIGN))
                 chunk_slot = jnp.where(
                     is_last, slots[at], jnp.iinfo(jnp.int32).max)
+    if WINDOW in kinds:
+        with scope("cache_write"):
+            # a window layer keeps the rows a LATER step can still see —
+            # the first one, at the segment's length, sees the positions
+            # above ``length - window`` — at their places in the lane's ring
+            lane = jnp.maximum(seg_ids, 0)
+            kept = (seg_ids >= 0) & (
+                positions > (positions[last_rows] + 1)[lane]
+                - cfg.sliding_window)
+            ring_dest = _ring_rows(pools, cfg, slots[lane], positions, kept)
 
     def linear(i, q, k, v):
         with scope("state"):
@@ -1168,12 +1238,28 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
             )
             return out[None], taken[:, None]
 
-    def attention(i, q, k, v):
+    def rows_then_attend(i, dest, q, k, v, window):
         with scope("cache_write"):
-            _write_rows(pools, i, dest_rows, k[0], v[0])
+            _write_rows(pools, i, dest, k[0], v[0])
         with scope("attend"):
-            return ragged_prefill_attention(
-                q[0], k[0], v[0], seg_ids, positions)[None], None
+            out = ragged_prefill_attention(
+                q[0], k[0], v[0], seg_ids, positions, sliding_window=window,
+                grouped_heads=grouped_heads, max_segment=rope_len)
+        if grouped_heads:
+            # nothing later in the program reads the pools, so the
+            # scheduler would leave every layer's scatter to the end and
+            # hold K and V of them all until then (78 MB a layer at
+            # 37,888 rows x 4 kv heads): the rows go in with their layer
+            pools[f"k{i}"], pools[f"v{i}"], out = (
+                jax.lax.optimization_barrier(
+                    (pools[f"k{i}"], pools[f"v{i}"], out)))
+        return out[None], None
+
+    def attention(i, q, k, v):
+        return rows_then_attend(i, dest_rows, q, k, v, None)
+
+    def window(i, q, k, v):
+        return rows_then_attend(i, ring_dest, q, k, v, cfg.sliding_window)
 
     def mamba(i, u, project):
         conv_w, conv_b, a, d_skip = ssm_constants(params, cfg, i)
@@ -1193,10 +1279,11 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
             return g[None], None
 
     handlers = {LINEAR: linear, SPARSE: sparse, ATTENTION: attention,
-                MAMBA: mamba}
+                WINDOW: window, MAMBA: mamba}
     x, record = hybrid_layer_stack(
         params, cfg, ids[None, :], positions[None, :], rope_len,
         lambda i, kind, *args: handlers[kind](i, *args),
+        grouped=kernels.grouped,
     )
     with scope("head"):
         x_last = x[0][last_rows][:, None, :]
@@ -1216,15 +1303,18 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
     through the paged kernel, under ``kernels.sparse_paged``); an
     ATTENTION one reads the lane's live pages
     (``paged_decode_attention``: the paged kernel under
-    ``kernels.paged``).  A state-keeping layer advances the
+    ``kernels.paged``); a WINDOW one writes the token at its place in the
+    lane's ring and reads, through the ring as a table, the pages the
+    window still sees.  A state-keeping layer advances the
     lane's entries IN PLACE (read, one step, written back): a LINEAR layer
     its state, a state-space layer its conv window (shifted by the token)
     and its state.  A lane whose table starts with a hole (a retired slot)
     reads zeros and writes nothing.
 
     Returns (logits [S, 1, vocab] f32, pools, selection record int32
-    [sparse layers x kv heads, S, 1, sparse_topk]) — two values where no
-    layer selects."""
+    [sparse layers x kv heads, S, 1, sparse_topk] — the routing record
+    int32 [routed layers, S, 1, experts_per_token] of a stack that routes)
+    — two values where no layer selects or routes."""
     S, s = tok.shape
     if s != 1:
         raise NotImplementedError(
@@ -1258,10 +1348,21 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             w_dest = jnp.where(
                 w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
     slots = None
-    if kinds & {LINEAR, MAMBA}:
+    if kinds & {LINEAR, MAMBA, WINDOW}:
         with scope("state"):
             slots = _state_slots(
                 pools, cfg, block_tables[:, 0] * block_size)
+    if WINDOW in kinds:
+        with scope("cache_write"):
+            ring_dest = _ring_rows(pools, cfg, slots, lengths)
+            # the lane's ring as a table of ``nb`` pages: page ``j`` of the
+            # lane lives in ring page ``j % ring``; what that names for a
+            # ``j`` the window has left is a newer page, which the window's
+            # mask (and the kernel's first block) never reads
+            pages = pools[WINDOW_PAGES]
+            ring_tables = pages.at[slots].get(
+                mode="fill", fill_value=pages.size
+            )[:, jnp.arange(nb) % pages.shape[1]]
     rope_pos = jnp.minimum(lengths, rope_len - 1)[:, None]
 
     def linear(i, q, k, v):
@@ -1299,6 +1400,17 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
                 use_flash=kernels.paged, mesh=mesh,
             ), None
 
+    def window(i, q, k, v):
+        with scope("cache_write"):
+            _write_rows(pools, i, ring_dest, k[:, 0], v[:, 0])
+        with scope("attend"):
+            return paged_decode_attention(
+                q, pools[f"k{i}"], pools[f"v{i}"], ring_tables,
+                lengths + 1, block_size=block_size, q_offset=lengths,
+                sliding_window=cfg.sliding_window, use_flash=kernels.paged,
+                mesh=mesh,
+            ), None
+
     def mamba(i, u, project):
         conv_w, conv_b, a, d_skip = ssm_constants(params, cfg, i)
         with scope("state"):
@@ -1316,8 +1428,9 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             return g[:, None], None
 
     handlers = {LINEAR: linear, SPARSE: sparse, ATTENTION: attention,
-                MAMBA: mamba}
+                WINDOW: window, MAMBA: mamba}
     x, record = hybrid_layer_stack(
         params, cfg, tok, rope_pos, rope_len,
-        lambda i, kind, *args: handlers[kind](i, *args))
+        lambda i, kind, *args: handlers[kind](i, *args),
+        grouped=kernels.grouped)
     return _with_record(hybrid_head(params, cfg, x), pools, record)

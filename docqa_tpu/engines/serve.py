@@ -108,6 +108,7 @@ from docqa_tpu.obs.costs import DEFAULT_COST_LEDGER, cost_record_of
 from docqa_tpu.obs.observatory import DEFAULT_OBSERVATORY
 from docqa_tpu.engines.paged import (
     STATE_SLOT,
+    WINDOW_PAGES,
     BlockAllocator,
     OutOfBlocks,
     PrefixCache,
@@ -710,6 +711,15 @@ class ContinuousBatcher:
                 f"{self._block.label} is served without "
                 + " and ".join(refused) + ": " + self._block.advice
             )
+        # a kind whose window layers hold a RING of pages a lane: a second
+        # extent with an allocator of its own (a ring a slot: a free slot
+        # always finds one), taken and released with the lane's table
+        self._ring_pages = (
+            self._block.ring_pages(self.block_size)
+            if self._block.ring_pages is not None else 0)
+        self._ring_alloc = (
+            BlockAllocator(self.n_slots * self._ring_pages, self.block_size)
+            if self._ring_pages else None)
         if want_cache and self._share_align < self.seq_capacity:
             self._prefix_cache = PrefixCache(
                 self._alloc, self._share_align,
@@ -824,6 +834,14 @@ class ContinuousBatcher:
         self._state_slot_np = (
             np.zeros((self.n_blocks * self.block_size,), np.int32)
             if self._block.lane_state else None
+        )
+        # the pages of each slot's ring (``paged.WINDOW_PAGES``), its host
+        # copy: written at admission from the ring's table, uploaded with
+        # the slot map (a hole — the extent's size — until a slot admits)
+        self._ring_pages_np = (
+            np.full((self.n_slots, self._ring_pages),
+                    self.n_slots * self._ring_pages, np.int32)
+            if self._ring_pages else None
         )
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="continuous-batcher"
@@ -1886,6 +1904,13 @@ class ContinuousBatcher:
             "tokens_committed": tokens,
             "utilization": used / self.n_blocks,
         }
+        if self._ring_alloc is not None:
+            # the second extent: rows the window layers' pools hold for
+            # the lanes admitted (a ring each, whatever their lengths)
+            out["window_rows_held"] = (
+                self._ring_alloc.blocks_in_use * self.block_size)
+            out["window_rows_total"] = (
+                self._ring_alloc.n_blocks * self.block_size)
         if self._prefix_cache is not None:
             # prefix-cache occupancy (docqa-prefix): entries + the
             # blocks the cache pins, plus the lifetime hit economics —
@@ -2270,6 +2295,8 @@ class ContinuousBatcher:
                 _finish(req)
                 continue
             table = self._alloc.new_table()
+            if self._ring_alloc is not None:
+                table.ring = self._ring_alloc.new_table()
             shared = 0
             try:
                 if self._prefix_cache is not None:
@@ -2281,6 +2308,10 @@ class ContinuousBatcher:
                 table.ensure(
                     min(len(ids) + self._grow_margin, self.seq_capacity)
                 )
+                if table.ring is not None:
+                    # all or nothing with the table above: the release
+                    # below returns both
+                    table.ring.ensure(self._ring_pages * self.block_size)
             except OutOfBlocks:
                 # the pool drained between the _pop_free_slots capacity
                 # check and here (same thread, so only by THIS round's
@@ -2401,6 +2432,8 @@ class ContinuousBatcher:
                 self._state_slot_np[
                     table.blocks[0] * self.block_size
                 ] = slot
+            if table.ring is not None:
+                self._ring_pages_np[slot] = table.ring.blocks
         self._tables_dirty = True
         for _slot, req, ids, table, shared in good:
             if shared and self._prefix_cache is not None:
@@ -2506,6 +2539,10 @@ class ContinuousBatcher:
             if self._state_slot_np is not None:
                 self._pools[STATE_SLOT] = jax.device_put(
                     self._state_slot_np, self._state_sharding
+                )
+            if self._ring_pages_np is not None:
+                self._pools[WINDOW_PAGES] = jax.device_put(
+                    self._ring_pages_np, self._state_sharding
                 )
             parts, sums = [], []
             for (T, ids_flat, seg, pos, dest, last_rows, slots_arr,
@@ -3031,8 +3068,9 @@ class ContinuousBatcher:
             live += int(lens.sum())
             read += int((-(-lens // self.block_size)).sum()) * self.block_size
         if self._block.kv_rows_read is not None:
-            # a kind that reads by a rule of its own (a layer that selects)
-            read = self._block.kv_rows_read(
+            # a kind that reads by a rule of its own (a layer that selects,
+            # layer kinds that read differently): what it counts beside
+            read, counts = self._block.kv_rows_read(
                 np.stack([
                     length + 1 + np.minimum(np.arange(steps), adv)
                     for length, adv in lanes
@@ -3040,6 +3078,8 @@ class ContinuousBatcher:
                 kernels=self._kernels, block_size=self.block_size,
                 table_rows=self.n_slots * self.seq_capacity,
             )
+            for name, amount in counts.items():
+                DEFAULT_REGISTRY.counter(name).inc(amount)
         elif not self._kernels.paged:
             read = steps * self.n_slots * self.seq_capacity
         return read, live

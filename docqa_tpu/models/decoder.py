@@ -42,8 +42,8 @@ from docqa_tpu.models.latent import (
     latent_param_schema,
     latent_row_width,
     latent_serving,
-    routed_layers,
 )
+from docqa_tpu.models.routed import routed_layers
 from docqa_tpu.models.serving import BlockServing, KernelForms
 from docqa_tpu.ops.attention import (
     attention_reference,
@@ -61,8 +61,9 @@ KVCache = Dict[str, jax.Array]  # "k0".."k{L-1}", "v0".."v{L-1}"
 def decoder_param_schema(cfg: DecoderConfig):
     """The single source of truth for the decoder's parameter tree:
     yields ``(name, kind, shape, fan_in)`` with kind ∈ {"normal", "ones"}.
-    Both ``init_decoder_params`` and the int8 incremental init
-    (``models/quant.py``) consume this — the RNG stream order is defined
+    (``"zeros_f32"``: float32 zeros whatever the tree's type, a router's
+    selection bias.)  Both ``init_decoder_params`` and the int8 incremental
+    init (``models/quant.py``) consume this — the RNG stream order is defined
     by the order of "normal" entries here, so the two inits can never
     desynchronize.  The latent block's tree is ``models/latent.py``'s,
     the stack of mixer kinds' ``models/hybrid.py``'s."""
@@ -111,10 +112,13 @@ def check_loop_config(cfg: DecoderConfig) -> None:
             "loop_exit_threshold (only 1.0 is served: every lane runs every "
             "step; a pass count that differs by lane needs a scheduler)")
     looped = cfg.loop_steps > 1 or cfg.sandwich_norm
-    if looped and cfg.block != "gqa_swiglu":
+    unread = cfg.loop_steps > 1 and cfg.block != "gqa_swiglu" or (
+        cfg.sandwich_norm and is_latent(cfg))
+    if unread:
         problems.append(
             f'loop_steps / sandwich_norm (block "{cfg.block}" does not '
-            'read them: "gqa_swiglu" alone does)')
+            'read them: "gqa_swiglu" reads both, the stack of mixer kinds '
+            "sandwich_norm alone)")
     if looped and cfg.quantize_weights:
         problems.append(
             "quantize_weights (int8 / int4 weights under the looped or "
@@ -288,6 +292,8 @@ def init_decoder_params(
         for name, kind, shape, fan_in in decoder_param_schema(cfg):
             if kind == "ones":
                 p[name] = put(name, _np.ones(shape, param_dtype))
+            elif kind == "zeros_f32":
+                p[name] = put(name, _np.zeros(shape, _np.float32))
             else:
                 w = host_rng.standard_normal(shape, _np.float32) * (
                     fan_in ** -0.5
@@ -302,6 +308,8 @@ def init_decoder_params(
     for name, kind, shape, fan_in in schema:
         if kind == "ones":
             p[name] = jnp.ones(shape, param_dtype)
+        elif kind == "zeros_f32":  # a router's selection bias
+            p[name] = jnp.zeros(shape, jnp.float32)
         else:
             p[name] = (
                 jax.random.normal(next(keys), shape, jnp.float32)

@@ -1,6 +1,6 @@
 """A decoder stack of several MIXER KINDS — ``DecoderConfig.block ==
 "sparse_linear"``: one name per layer in ``cfg.mixer_types``, chosen at
-trace time.  Four kinds, one trunk:
+trace time.  Five kinds, one trunk:
 
 * ``linear`` — decayed linear attention (Lightning Attention-2,
   arXiv:2401.04658): RoPE, a [d, d] float32 state a head A LANE;
@@ -8,6 +8,10 @@ trace time.  Four kinds, one trunk:
   arXiv:2509.24663): no RoPE, K / V rows and compressed keys in the cache;
 * ``attention`` — plain causal GQA / MQA softmax attention over every row:
   no RoPE, K / V rows in the cache, read at decode by the paged kernel;
+* ``window`` — causal GQA softmax attention over the last
+  ``sliding_window`` rows: RoPE, K / V rows in a RING of pages a lane (the
+  window plus one page, whatever the lane's length), read at decode by the
+  paged kernel from the first page the window can still see;
 * ``mamba`` — a Mamba-1 state-space mixer (arXiv:2312.00752) with its own
   projections; a lane keeps its last conv inputs and one state.
 
@@ -33,6 +37,8 @@ sqrt(L)`` (1 where ``scale_depth`` is 0):
                    ``sparse_dense_len`` tokens, else the rows of the
                    ``sparse_topk`` blocks the row selects
         attention: causal softmax attention over every row
+        window:    q, k = rope(q), rope(k); causal softmax attention over
+                   the rows j > t - ``sliding_window``
         branch = (o * sigmoid(y Wg)) Wo   (``use_output_gate``; else o Wo)
     mamba (``inner = ssm_expand * hidden``):
         [u, z] = y W_in
@@ -42,17 +48,23 @@ sqrt(L)`` (1 where ``scale_depth`` is 0):
         h_t = exp(D_t (x) A) h_{t-1} + (D_t c_t) (x) B_t,  A = -exp(A_log)
         g_t = h_t C_t + D c_t                  [state, inner] float32
         branch = (g * silu(z)) W_out
-    x = x + r * branch
-    x = x + r * swiglu(rmsnorm(x))
+    x = x + r * branch                  (``sandwich_norm``: rmsnorm(branch))
+    x = x + r * ff(rmsnorm(x))          (``sandwich_norm``: rmsnorm(ff(..)))
 
-and ``h_0 = scale_emb * E[ids]``, logits ``= head(rmsnorm(h)) /
+``ff`` is one dense SwiGLU of ``mlp_dim`` — in every layer, or, where
+``num_experts`` > 1, in the first ``first_dense_layers`` alone: the later
+layers ROUTE (``models/routed.py``: the router's scores, the held experts'
+grouped sum, the shared experts), a long packed dispatch a tile of rows
+at a time.  ``h_0 = scale_emb * E[ids]``, logits ``= head(rmsnorm(h)) /
 (hidden_dim / dim_model_base)`` (unscaled where ``dim_model_base`` is 0;
 against ``E`` itself under ``tie_embeddings``).  ``lambda_h = exp(-s_h)``
 with the family's slopes (:func:`decay_slopes`).
 
 The trunk hands back the blocks each sparse layer and kv head took (the
-selection record; benchmark/README.md "A block that routes") — ``None``
-for a stack in which no layer selects.
+selection record; benchmark/README.md "A block that routes") — in a stack
+that ROUTES the expert ids each routed layer took instead (the routing
+record; one that both routes and selects is refused by field) — ``None``
+for a stack in which no layer does either.
 """
 
 from __future__ import annotations
@@ -68,8 +80,20 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from docqa_tpu.config import DecoderConfig
+from docqa_tpu.models.routed import (
+    MOE_PREFILL_SUMS,
+    MOE_SUMS,
+    experts_held,
+    moe_chunk_counts,
+    moe_prefill_sums,
+    moe_step_sums,
+    routed_layers,
+    routed_mlp,
+    routed_param_schema,
+    routing_problems,
+)
 from docqa_tpu.models.serving import BlockServing
-from docqa_tpu.ops.attention import paged_kernel_supported
+from docqa_tpu.ops.attention import PAGED_BLOCK_ROWS, paged_kernel_supported
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
 from docqa_tpu.ops.scopes import scope
@@ -78,9 +102,15 @@ Params = Dict[str, jax.Array]
 
 HYBRID_BLOCK = "sparse_linear"
 SPARSE, LINEAR, ATTENTION, MAMBA = "sparse", "linear", "attention", "mamba"
+WINDOW = "window"
+# the kinds whose q and k are rotated (each at its own head width)
+ROTATED = (LINEAR, WINDOW)
 # the pool that maps a lane's first pool row to its state entry
 # (``engines/paged._init_hybrid_pools``)
 STATE_SLOT = "state_slot"
+# the pool that maps a lane's entry to the pages of its ring, [lanes,
+# ring_pages] int32 (``engines/paged._init_hybrid_pools``)
+WINDOW_PAGES = "window_pages"
 # prefill rows one MLP tile holds: the gate / up activations of a longer
 # dispatch are never whole (38k rows x 16384 would be 1.2 GB each)
 MLP_TILE_ROWS = 2048
@@ -100,6 +130,21 @@ def layers_of(cfg: DecoderConfig, *kinds: str) -> Tuple[int, ...]:
 def sparse_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
     """Indices of the layers that keep rows in the cache and select."""
     return layers_of(cfg, SPARSE)
+
+
+def window_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
+    """Indices of the layers that keep a ring of pages a lane."""
+    return layers_of(cfg, WINDOW)
+
+
+def ring_pages(cfg: DecoderConfig, block_size: int) -> int:
+    """Pages of ``block_size`` rows one lane's ring holds in every window
+    layer: position ``p`` lives in page ``(p // block_size) % ring_pages``
+    of the lane's ring, so the page being written and the
+    ``ring_pages - 1`` before it are whole — every row ``j > t -
+    sliding_window`` of a step at ``t``, wherever ``t`` falls in its page.
+    The window plus at most one page, whatever the lane's length."""
+    return -(-(cfg.sliding_window - 1) // block_size) + 1
 
 
 def mamba_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
@@ -187,14 +232,30 @@ def check_hybrid_config(cfg: DecoderConfig) -> None:
         problems.append("linear_heads / linear_head_dim unset")
     if cfg.linear_head_dim % 2:
         problems.append("linear_head_dim is odd")
-    if cfg.sliding_window is not None:
-        problems.append("sliding_window (the sparse mixer has its own)")
+    if WINDOW in cfg.mixer_types:
+        if not cfg.sliding_window or cfg.sliding_window < 2:
+            problems.append(
+                "sliding_window (a stack with a window layer sets it, to "
+                "2 or more)")
+        if cfg.head_dim % 2:
+            problems.append("head_dim is odd (a window layer is rotated)")
+    elif cfg.sliding_window is not None:
+        problems.append(
+            "sliding_window (a window layer reads it; the sparse mixer has "
+            "its own)")
     if cfg.quantize_weights and cfg.quant_bits != 8:
         problems.append("quant_bits (this block serves int8 or float)")
     if cfg.num_experts > 1:
-        problems.append(
-            "num_experts (every layer's feed-forward is one dense SwiGLU: a "
-            "sparse-MoE feed-forward inside this stack is not brought)")
+        problems += routing_problems(cfg)
+        if SPARSE in cfg.mixer_types:
+            problems.append(
+                "num_experts with a sparse layer (the forwards hand back "
+                "ONE record: the routing or the selection)")
+        if cfg.quantize_weights:
+            problems.append(
+                "quantize_weights (routed layers serve float weights)")
+        if not 0 <= cfg.first_dense_layers <= cfg.num_layers:
+            problems.append("first_dense_layers outside 0..num_layers")
     if MAMBA in cfg.mixer_types and min(
             cfg.ssm_state_dim, cfg.ssm_dt_rank, cfg.ssm_expand) <= 0:
         problems.append("ssm_state_dim / ssm_dt_rank / ssm_expand unset")
@@ -251,7 +312,7 @@ def _attention_schema(kind: str):
 def _attention_branch(params: Params, cfg: DecoderConfig, i: int, kind: str,
                       y, rope, mix):
     """q, k, v with what the file says of their norms (and RoPE for the
-    linear kind), ``mix(i, kind, q, k, v) -> (out, taken)``, then the
+    ``ROTATED`` kinds), ``mix(i, kind, q, k, v) -> (out, taken)``, then the
     output norm, gate and projection."""
     p = f"l{i}_"
     b, s, _ = y.shape
@@ -265,8 +326,8 @@ def _attention_branch(params: Params, cfg: DecoderConfig, i: int, kind: str,
         if cfg.qk_norm:
             q = rms_norm(q, params[p + "q_norm_g"], eps)
             k = rms_norm(k, params[p + "k_norm_g"], eps)
-        if kind == LINEAR:
-            cos, sin, positions = rope
+        if kind in ROTATED:
+            cos, sin, positions = rope[kind]
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
     out, taken = mix(i, kind, q, k, v)
@@ -374,6 +435,10 @@ MIXERS: Dict[str, Mixer] = {
                   _attention_branch),
     ATTENTION: Mixer(_attention_schema(ATTENTION), _kv_rows, _nothing,
                      _attention_branch),
+    # the same rows as ``attention``; how many of them a lane's pools hold
+    # is :func:`ring_pages`'
+    WINDOW: Mixer(_attention_schema(WINDOW), _kv_rows, _nothing,
+                  _attention_branch),
     LINEAR: Mixer(
         _attention_schema(LINEAR), _nothing,
         lambda cfg: {"s": (lane_state_shape(cfg), "float32")},
@@ -405,10 +470,22 @@ def hybrid_param_schema(cfg: DecoderConfig):
         yield (p + "attn_norm_g", "ones", (h,), None)
         for name, init, shape, fan_in in MIXERS[kind].schema(cfg):
             yield (p + name, init, shape, fan_in)
+        if cfg.sandwich_norm:
+            yield (p + "attn_post_norm_g", "ones", (h,), None)
         yield (p + "mlp_norm_g", "ones", (h,), None)
-        yield (p + "w_gate", "normal", (h, cfg.mlp_dim), h)
-        yield (p + "w_up", "normal", (h, cfg.mlp_dim), h)
-        yield (p + "w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
+        if layer_routes(cfg, i):
+            yield from routed_param_schema(cfg, p)
+        else:
+            yield (p + "w_gate", "normal", (h, cfg.mlp_dim), h)
+            yield (p + "w_up", "normal", (h, cfg.mlp_dim), h)
+            yield (p + "w_down", "normal", (cfg.mlp_dim, h), cfg.mlp_dim)
+        if cfg.sandwich_norm:
+            yield (p + "mlp_post_norm_g", "ones", (h,), None)
+
+
+def layer_routes(cfg: DecoderConfig, i: int) -> bool:
+    """Whether layer ``i``'s feed-forward routes."""
+    return routed_layers(cfg) > 0 and i >= cfg.first_dense_layers
 
 
 def _swiglu_tiled(y, params: Params, p: str, dtype):
@@ -429,6 +506,29 @@ def _swiglu_tiled(y, params: Params, p: str, dtype):
     return out.reshape(tiles * MLP_TILE_ROWS, h)[None, :s]
 
 
+def _routed_tiled(y, params: Params, cfg: DecoderConfig, i: int,
+                  grouped: bool):
+    """A routed layer's feed-forward over ``y`` [b, s, h] -> (what it adds
+    [b, s, h], the expert ids taken [b, s, k]).  Of a long packed dispatch
+    (b == 1) a tile of ``MLP_TILE_ROWS`` rows routes, gathers its picks
+    and runs its grouped products on its own: the gathered rows and the
+    float32 products of ``rows x k`` picks exist a tile at a time (at
+    37,888 rows x 8 they would be 1.2 and 2.5 GB a layer), and a tile
+    streams the held experts once more."""
+    def route(rows):
+        return routed_mlp(rows, params, cfg, i, use_flash=grouped)
+
+    b, s, h = y.shape
+    if b != 1 or s <= 2 * MLP_TILE_ROWS:
+        add, taken = route(y.reshape(b * s, h))
+        return add.reshape(b, s, h), taken.reshape(b, s, -1)
+    tiles = -(-s // MLP_TILE_ROWS)
+    padded = jnp.pad(y[0], ((0, tiles * MLP_TILE_ROWS - s), (0, 0)))
+    add, taken = jax.lax.map(route, padded.reshape(tiles, MLP_TILE_ROWS, h))
+    return (add.reshape(-1, h)[None, :s],
+            taken.reshape(tiles * MLP_TILE_ROWS, -1)[None, :s])
+
+
 def _residual(x, r: float, branch):
     """``x + r * branch``, summed in float32 and rounded once."""
     f32 = jnp.float32
@@ -436,7 +536,7 @@ def _residual(x, r: float, branch):
 
 
 def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
-                       rope_len: int, mix):
+                       rope_len: int, mix, *, grouped: bool = False):
     """The block's trunk, as ``decoder_layer_stack`` is the GQA block's.
 
     ``mix(i, kind, ...)`` owns the cache and the lane state; what it is
@@ -448,18 +548,23 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
     state.  The state-space kind: ``mix(i, kind, u [b, s, inner],
     project) -> (g [b, s, inner], None)``.
 
+    ``grouped``: the form of the routed layers' product
+    (``models/decoder.kernel_forms``).
+
     Returns (hidden states [b, s, hidden] before the final norm, the
-    selection record int32 [sparse layers x kv heads, b, s, topk]; None
-    where no layer selects)."""
+    selection record int32 [sparse layers x kv heads, b, s, topk] — of a
+    stack that routes the routing record int32 [routed layers, b, s,
+    experts_per_token]; None where no layer selects or routes)."""
     dtype = jnp.dtype(cfg.dtype)
     r = residual_scale(cfg)
     eps = cfg.norm_eps
-    rope = None
-    if LINEAR in cfg.mixer_types:
-        with scope("proj"):
-            cos, sin = rope_angles(
-                cfg.linear_head_dim, rope_len, cfg.rope_theta)
-            rope = (cos, sin, positions)
+    rope = {}
+    for kind in ROTATED:
+        if kind in cfg.mixer_types:
+            with scope("proj"):
+                cos, sin = rope_angles(
+                    mixer_geometry(cfg, kind)[2], rope_len, cfg.rope_theta)
+                rope[kind] = (cos, sin, positions)
     with scope("embed"):
         x = (params["tok_emb"][ids].astype(jnp.float32)
              * cfg.scale_emb).astype(dtype)
@@ -473,16 +578,28 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
         if kind == SPARSE:
             record.append(taken)
         with scope("proj"):
+            if cfg.sandwich_norm:
+                branch = rms_norm(branch, params[p + "attn_post_norm_g"], eps)
             x = _residual(x, r, branch)
         with scope("mlp"):
             y = rms_norm(x, params[p + "mlp_norm_g"], eps)
-            x = _residual(x, r, _swiglu_tiled(y, params, p, dtype))
+            if layer_routes(cfg, i):
+                ff, taken = _routed_tiled(y, params, cfg, i, grouped)
+                record.append(taken)
+            else:
+                ff = _swiglu_tiled(y, params, p, dtype)
+            if cfg.sandwich_norm:
+                ff = rms_norm(ff, params[p + "mlp_post_norm_g"], eps)
+            x = _residual(x, r, ff)
         # the stream is rounded HERE: without the barrier XLA carries it
         # in excess precision and re-sums every earlier layer's branch
         # where it needs it, which keeps them all alive (3.4 GB at 9.7k
         # rows x 32 layers)
         x = jax.lax.optimization_barrier(x)
-    return x, (jnp.concatenate(record) if record else None)
+    if not record:
+        return x, None
+    return x, (jnp.stack(record) if routed_layers(cfg)
+               else jnp.concatenate(record))
 
 
 def hybrid_head(params: Params, cfg: DecoderConfig, x):
@@ -523,7 +640,7 @@ def sparse_step_sums(cfg: DecoderConfig, record, lengths, active):
     """``SPARSE_SUMS`` of one decode step, int32, from the selection
     record [sparse layers x kv heads, S, 1, topk], the lanes' lengths
     BEFORE the step and the lanes live in it — summed on the device, as
-    ``models/latent.moe_step_sums`` is."""
+    ``models/routed.moe_step_sums`` is."""
     took = record[:, :, 0, :] >= 0  # [decisions, S, topk]
     selected = took[0, :, 0]  # a lane that selected: first id >= 0
     live_blocks = record.shape[0] * (
@@ -568,6 +685,16 @@ def hybrid_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
     return counts, samples
 
 
+def routed_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
+    """The same of a stack that ROUTES: its row holds the expert-choice
+    sums (``models/routed.moe_chunk_counts``), the lane-steps are the
+    host's."""
+    counts, samples = moe_chunk_counts(row=row)
+    state, _ = hybrid_chunk_counts(
+        cfg, lane_steps=lane_steps, row=None, kernels=kernels)
+    return {**counts, **state}, samples
+
+
 def hybrid_prefill_counts(cfg: DecoderConfig, *, lanes, tokens, dispatches,
                           kernels):
     """One admission round's counters: lane states started from zeros."""
@@ -588,8 +715,11 @@ def hybrid_prefill_attrs(cfg: DecoderConfig, n_ids: int, n_lanes: int):
     lanes whose state the round started from zeros; where a layer
     SELECTS, the rows of the prompt that selected (all of them once it
     holds ``sparse_dense_len`` tokens, none under it); where a layer
-    SCANS (state-space), the rows its scan ran over."""
+    SCANS (state-space), the rows its scan ran over; where a layer keeps
+    a WINDOW, the rows of the prompt its ring kept."""
     out = {"state_lanes": n_lanes}
+    if window_layers(cfg):
+        out["window_rows_kept"] = min(n_ids, cfg.sliding_window - 1)
     if sparse_layers(cfg):
         selects = n_ids >= cfg.sparse_dense_len
         out["sparse_rows"] = n_ids if selects else 0
@@ -599,9 +729,10 @@ def hybrid_prefill_attrs(cfg: DecoderConfig, n_ids: int, n_lanes: int):
 
 
 def sparse_rows_read(cfg: DecoderConfig, lens, *, kernels, block_size: int,
-                     table_rows: int) -> int:
-    """KV rows a chunk's steps fetched per cache entry, from the length
-    each lane's step attended (``lens`` [lanes, steps])."""
+                     table_rows: int):
+    """(KV rows a chunk's steps fetched per cache entry, no counter of its
+    own), from the length each lane's step attended (``lens`` [lanes,
+    steps])."""
     # a sparse layer reads the rows of the blocks taken; in the XLA form
     # every table once a lane of the step is still under dense_len
     # (ops/attention.sparse_decode_attention)
@@ -613,13 +744,51 @@ def sparse_rows_read(cfg: DecoderConfig, lens, *, kernels, block_size: int,
         # under dense_len, kv-heads times
         pages = -(-lens // block_size) * block_size
         return cfg.num_kv_heads * int(np.where(
-            under, pages, np.minimum(taken, pages)).sum())
+            under, pages, np.minimum(taken, pages)).sum()), {}
     return int(np.where(
-        under.any(axis=0), table_rows, lens.shape[0] * taken).sum())
+        under.any(axis=0), table_rows, lens.shape[0] * taken).sum()), {}
+
+
+# counters of a stack with WINDOW layers, over a fetched chunk's steps and
+# live lanes, PER WINDOW LAYER (:func:`window_rows_read`): the rows its
+# decode read, the rows its pools hold for those lanes (a ring each), and
+# the rows the lanes' positions would hold unwindowed
+WINDOW_COUNTS = (
+    "serve_window_kv_rows_read", "serve_window_kv_rows_held",
+    "serve_window_kv_rows_live",
+)
+
+
+def window_rows_read(cfg: DecoderConfig, lens, *, kernels, block_size: int,
+                     table_rows: int):
+    """(KV rows a chunk's steps fetched per cache entry — the mean over
+    the row-keeping layers, each by its kind —, ``WINDOW_COUNTS``), from
+    the length each lane's step attended (``lens`` [lanes, steps]).  A
+    global layer reads a lane's live pages; a window layer the pages from
+    the kernel's first compute block that the window can still see
+    (``ops/attention.PAGED_BLOCK_ROWS`` rows, a whole number of pages) to
+    the lane's last — the XLA form (every CPU run) gathers every table's
+    whole span in either kind."""
+    n_window = len(window_layers(cfg))
+    n_global = len(layers_of(cfg, ATTENTION))
+    steps = lens.shape[1] if lens.size else 0
+    pages = -(-lens // block_size) * block_size
+    if kernels.paged:
+        rows = max(PAGED_BLOCK_ROWS // block_size, 1) * block_size
+        first = np.maximum(lens - cfg.sliding_window, 0) // rows * rows
+        in_window, in_global = int((pages - first).sum()), int(pages.sum())
+    else:
+        in_window = in_global = steps * table_rows
+    held = lens.shape[0] * steps * ring_pages(cfg, block_size) * block_size
+    counts = dict(zip(WINDOW_COUNTS, (in_window, held, int(lens.sum()))))
+    mean = (n_window * in_window + n_global * in_global) // max(
+        n_window + n_global, 1)
+    return mean, counts
 
 
 def hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
-    """Megatron per layer.  An attention kind: q, the output gate and the
+    """Megatron per layer (a routed layer's experts: below).  An attention
+    kind (``window`` as ``attention``): q, the output gate and the
     MLP's gate / up column-parallel, ``wo`` and ``w_down`` row-parallel; a
     linear layer's k and v are as wide as its q and go column-parallel
     with it; the few kv heads of a sparse or a plain attention layer are
@@ -637,9 +806,21 @@ def hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
         p = f"l{i}_"
         specs.update({
             p + "attn_norm_g": P(None), p + "mlp_norm_g": P(None),
+            p + "attn_post_norm_g": P(None), p + "mlp_post_norm_g": P(None),
             p + "w_gate": P(None, m), p + "w_up": P(None, m),
             p + "w_down": P(m, None),
         })
+        if layer_routes(cfg, i):
+            # the EXPERT axis over ``model`` (expert parallelism: the range
+            # a process holds is one device's shard), the router and its
+            # bias replicated, the shared experts Megatron
+            specs.update({
+                p + "router": P(None, None), p + "router_bias": P(None),
+                p + "e_gate": P(m, None, None), p + "e_up": P(m, None, None),
+                p + "e_down": P(m, None, None),
+                p + "s_gate": P(None, m), p + "s_up": P(None, m),
+                p + "s_down": P(m, None),
+            })
         if kind == MAMBA:
             specs.update({
                 p + "w_in": P(None, m), p + "b_in": P(m),
@@ -665,6 +846,8 @@ def hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
 def hybrid_pool_pspecs(cfg: DecoderConfig) -> Dict[str, P]:
     """Every pool of the stack (``engines/paged.py``), replicated."""
     names = [STATE_SLOT, *lane_state_entries(cfg)]
+    if window_layers(cfg):
+        names.append(WINDOW_PAGES)
     for i, kind in enumerate(cfg.mixer_types):
         names += [f"{prefix}{i}" for prefix in MIXERS[kind].rows(cfg)]
     names += [f"ck{i}" for i in sparse_layers(cfg)]
@@ -687,6 +870,25 @@ def hybrid_serving(cfg: DecoderConfig) -> BlockServing:
         step_sums=functools.partial(sparse_step_sums, cfg),
         kv_rows_read=functools.partial(sparse_rows_read, cfg),
     ) if selects else {}
+    chunk_counts = functools.partial(hybrid_chunk_counts, cfg)
+    attrs, occupancy = {}, {"state_bytes_per_lane": lane_state_bytes(cfg)}
+    if routed_layers(cfg):
+        # the routing record in the selection record's place, summed as
+        # the latent block sums it (``models/routed.py``)
+        sums = dict(
+            step_sum_names=MOE_SUMS,
+            step_sums=functools.partial(moe_step_sums, cfg),
+            prefill_sum_names=MOE_PREFILL_SUMS,
+            prefill_sums=functools.partial(moe_prefill_sums, cfg),
+        )
+        attrs["experts_held"] = experts_held(cfg)[1]
+        chunk_counts = functools.partial(routed_chunk_counts, cfg)
+    if WINDOW in kinds:
+        sums["kv_rows_read"] = functools.partial(window_rows_read, cfg)
+        attrs.update(window_layers=len(window_layers(cfg)),
+                     global_layers=len(layers_of(cfg, ATTENTION)))
+        occupancy.update(window=cfg.sliding_window,
+                         window_layers=len(window_layers(cfg)))
     return BlockServing(
         label=f'DecoderConfig(block="{cfg.block}")',
         unserved=("generate.prefix_cache", "generate.speculative_k",
@@ -699,14 +901,17 @@ def hybrid_serving(cfg: DecoderConfig) -> BlockServing:
             "the paged rows and the lane state (engines/paged.py) only"
         ),
         uses_flash=bool(
-            {ATTENTION, MAMBA} & kinds
+            {ATTENTION, WINDOW, MAMBA} & kinds
             or selects and paged_kernel_supported(
                 cfg.dtype, cfg.num_kv_heads, cfg.head_dim)),
-        chunk_counts=functools.partial(hybrid_chunk_counts, cfg),
+        chunk_counts=chunk_counts,
         prefill_counts=functools.partial(hybrid_prefill_counts, cfg),
         prefill_attrs=functools.partial(hybrid_prefill_attrs, cfg),
-        occupancy={"state_bytes_per_lane": lane_state_bytes(cfg)},
+        span_attrs=attrs,
+        occupancy=occupancy,
         lane_state=True,
+        ring_pages=(functools.partial(ring_pages, cfg)
+                    if WINDOW in kinds else None),
         param_pspecs=functools.partial(hybrid_param_pspecs, cfg),
         pool_pspecs=functools.partial(hybrid_pool_pspecs, cfg),
         **sums,

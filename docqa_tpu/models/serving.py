@@ -56,12 +56,19 @@ class BlockServing:
     ``chunk_counts(lane_steps=, row=, kernels=)`` of a fetched chunk ->
     ({counter: amount}, {histogram: sample}); ``prefill_counts(lanes=,
     tokens=, dispatches=, kernels=)`` of an admission round; ``kv_rows_read
-    (lens, kernels=, block_size=, table_rows=)`` where the kind reads KV
-    by a rule of its own; ``span_attrs`` on a request's ``serve_prefill``
+    (lens, kernels=, block_size=, table_rows=) -> (rows read per cache
+    entry, {counter: amount})`` where the kind reads KV by a rule of its
+    own (a layer that selects; layer kinds that read differently, which
+    count per kind); ``span_attrs`` on a request's ``serve_prefill``
     and ``serve_decode_chunk`` spans, ``prefill_attrs(n_ids, n_lanes)`` on
     the first; ``occupancy`` beside the block-pool gauges; ``lane_state``:
     a lane keeps state beside its rows, found through the slot map in the
-    pools (``engines/paged.STATE_SLOT``) whose host copy the batcher writes.
+    pools (``engines/paged.STATE_SLOT``) whose host copy the batcher writes;
+    ``ring_pages(block_size)``: the pages of the RING a lane holds in the
+    pools of its window layers, whatever its length — a second extent with
+    an allocator and a table a lane of its own, taken and released with
+    the lane's block table (``engines/paged.WINDOW_PAGES``: the host copy
+    of the tables travels in the pools like the slot map).
 
     The sharding beyond the GQA rules, ``PartitionSpec``s by name:
     ``param_pspecs(model_axis)`` of every per-layer parameter of a kind
@@ -84,5 +91,6 @@ class BlockServing:
     prefill_attrs: Callable = _nothing
     occupancy: Mapping = dataclasses.field(default_factory=dict)
     lane_state: bool = False
+    ring_pages: Optional[Callable] = None
     param_pspecs: Optional[Callable] = None
     pool_pspecs: Optional[Callable] = None

@@ -668,6 +668,13 @@ class TelemetrySampler:
                 # length
                 rec("serve_state_bytes_per_lane",
                     float(occ["state_bytes_per_lane"]), now=now)
+            if "window_rows_held" in occ:
+                # a block whose window layers hold a RING a lane: the rows
+                # those pools hold for the lanes admitted, of the extent
+                rec("kv_window_rows_held",
+                    float(occ["window_rows_held"]), now=now)
+                rec("kv_window_rows_total",
+                    float(occ["window_rows_total"]), now=now)
             if "loop_steps" in occ:
                 # the looped trunk: passes of the stack a token takes,
                 # and the factor in ``serve_kv_bytes_per_token``

@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from docqa_tpu.ops.scopes import scope
+from docqa_tpu.utils import round_up
 
 NEG_INF = -1e30
 
@@ -122,11 +123,71 @@ def attention_reference(
 RAGGED_ALIGN = 128
 
 
+def _grouped_prefill_attention(q, k, v, seg_ids, positions, window, scale,
+                               max_segment=None):
+    """:func:`ragged_prefill_attention`, cold, for GROUPED heads: K and V
+    keep their few kv heads (one float32 copy of each, never ``groups``
+    of them) and a kv head's query heads go through one product with it.
+
+    A row REACHES back no further than ``window`` rows, and no further
+    than its segment's start: ``max_segment`` rows at most (the sequence
+    capacity, where the caller states it).  Where that reach is at most
+    half the packed axis a query block of ``RAGGED_ALIGN`` rows takes its
+    keys from the ``reach + RAGGED_ALIGN`` packed rows that END with it —
+    a segment is one contiguous run in position order, so every key a row
+    of the block can see lies there — and the key blocks wholly outside
+    are never multiplied: ``(reach + ALIGN) / T`` of the causal form's
+    scores.  The masks and the float32 softmax are the general form's; a
+    row reduces over the keys it is handed, in which every key it does
+    not see is an exact zero."""
+    t, hq, d = q.shape
+    hkv = k.shape[1]
+    span, front = t, 0
+    reach = min(r for r in (window, max_segment, t) if r is not None)
+    near = round_up(reach, RAGGED_ALIGN) + RAGGED_ALIGN
+    if t >= 2 * near:
+        # so that no slice starts before row 0
+        span, front = near, near - RAGGED_ALIGN
+    kf = jnp.pad(k.astype(jnp.float32), ((front, 0), (0, 0), (0, 0)))
+    vf = jnp.pad(v.astype(jnp.float32), ((front, 0), (0, 0), (0, 0)))
+    seg_k = jnp.pad(seg_ids, (front, 0), constant_values=-1)
+    pos_k = jnp.pad(positions, (front, 0))
+
+    def attend_block(r0):
+        rows = r0 + jnp.arange(RAGGED_ALIGN)
+        qb = (q[rows].astype(jnp.float32) * scale).reshape(
+            RAGGED_ALIGN, hkv, hq // hkv, d)
+        seg_q, pos_q = seg_ids[rows], positions[rows]
+        # the keys of this block: all of them, or the span that ends here
+        start = r0 if front else 0
+        keys, values, seg_b, pos_b = (
+            jax.lax.dynamic_slice_in_dim(a, start, span)
+            for a in (kf, vf, seg_k, pos_k))
+        mask = (
+            (seg_q[:, None] == seg_b[None, :])
+            & (seg_q >= 0)[:, None]
+            & (pos_b[None, :] <= pos_q[:, None])
+        )
+        if window is not None:
+            mask &= pos_b[None, :] > pos_q[:, None] - window
+        mask = mask[None, None]
+        scores = jnp.where(
+            mask, jnp.einsum("qhgd,khd->hgqk", qb, keys), NEG_INF)
+        probs = jnp.where(
+            jnp.any(mask, axis=-1, keepdims=True),
+            jax.nn.softmax(scores, axis=-1), 0.0)
+        return jnp.einsum("hgqk,khd->qhgd", probs, values)
+
+    out = jax.lax.map(attend_block, jnp.arange(0, t, RAGGED_ALIGN))
+    return out.reshape(t, hq, v.shape[-1]).astype(q.dtype)
+
+
 def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
                              sliding_window=None, scale=None,
                              k_pool=None, v_pool=None, block_tables=None,
                              prefix_lens=None, n_prefix_rows=0,
-                             block_size=None):
+                             block_size=None, grouped_heads=False,
+                             max_segment=None):
     """Self-attention over a PACKED batch of variable-length prompts —
     the prefill half of Ragged Paged Attention, XLA reference path.
 
@@ -167,11 +228,22 @@ def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
     capacity); unused rows are masked.  Segment starts must be aligned
     (each query block then belongs to exactly one segment, so one block
     table row serves the whole block).
+
+    ``grouped_heads`` (cold, a whole number of ``RAGGED_ALIGN`` rows):
+    K and V are not repeated over a group's query heads, and where a row
+    reaches back (its ``sliding_window``; ``max_segment``, the rows of the
+    longest segment there can be) at most half the packed axis, the key
+    blocks wholly out of a query block's reach are not multiplied
+    (:func:`_grouped_prefill_attention`) — the form of a stack whose
+    attention layers hold several kv heads (``engines/paged.py``).
     """
     t, hq, d = q.shape
     _, hkv, _ = k.shape
     groups = hq // hkv
     scale = scale if scale is not None else d ** -0.5
+    if grouped_heads and not n_prefix_rows and t % RAGGED_ALIGN == 0:
+        return _grouped_prefill_attention(
+            q, k, v, seg_ids, positions, sliding_window, scale, max_segment)
 
     qf = q.astype(jnp.float32) * scale
     kf = k.astype(jnp.float32)

@@ -315,10 +315,11 @@ def test_the_held_experts_sum_is_the_plain_sum_over_picks(
     taken = jnp.asarray(_picks(case, n, cfg, rng), jnp.int32)
     gates = jnp.asarray(rng.random(taken.shape), jnp.float32)
     if interpret:
+        from docqa_tpu.models import routed  # the function's home, PR 48
         from docqa_tpu.ops import grouped
 
         monkeypatch.setattr(
-            latent, "grouped_matmul",
+            routed, "grouped_matmul",
             lambda *a, **kw: grouped.grouped_matmul(*a, **kw, interpret=True))
     got = np.asarray(latent.held_experts_sum(y, taken, gates, params, cfg, 1))
     want = _plain_sum_over_picks(y, taken, gates, params, cfg, 1)
