@@ -34,6 +34,7 @@ from docqa_tpu.models.decoder import (
     init_decoder_params,
     init_kv_cache,
     kernel_forms,
+    ragged_prefill_counts,
 )
 from docqa_tpu.engines.spine import spine_run
 from docqa_tpu.ops.sampling import sample
@@ -215,6 +216,9 @@ class GenerateEngine:
         # programs and counts by them
         self.kernel_forms = functools.partial(
             kernel_forms, cfg, on_tpu=bool(use_flash), mesh=mesh)
+        # what a round's cold dispatches count where ``ragged`` is chosen
+        self.ragged_prefill_counts = functools.partial(
+            ragged_prefill_counts, cfg)
         # kept only by a kind one of whose kernels the flag reaches (the
         # warm-up checks kernels against their references by it)
         self.use_flash = bool(use_flash) and self.block.uses_flash
@@ -572,7 +576,12 @@ class GenerateEngine:
           serves) through a SCATTERED block table — ragged lengths, a
           page boundary, a free lane, hole entries past each length —
           against the gather reference, when the kernel reads this
-          geometry (``kernel_forms``'s ``paged``).
+          geometry (``kernel_forms``'s ``paged``);
+        * ``ragged_flash_prefill`` (what the batcher's cold prefill
+          program attends with) on a packed axis of two segments — one
+          longer than a 2,048-row window, one that ends inside a block —
+          and a tail of padding, against the XLA form, when it is chosen
+          (``kernel_forms``'s ``ragged``).
 
         Raises when a pair disagrees: a kernel that compiles but computes
         something else must fail the warm-up, not serve.  Tolerance: both
@@ -582,6 +591,7 @@ class GenerateEngine:
             attention_reference,
             flash_attention,
             paged_decode_attention,
+            ragged_prefill_attention,
         )
 
         cfg, tol = self.cfg, 2.0 ** -6
@@ -597,7 +607,8 @@ class GenerateEngine:
         # table entries a lane; lengths end inside a page, on a page
         # boundary, at 0 (free lane: all holes) and at the table's span
         block_size, n_pages, per_lane = self.gen.kv_block_size, 64, 16
-        paged = self.kernel_forms(block_size=block_size).paged
+        forms = self.kernel_forms(block_size=block_size)
+        paged, ragged = forms.paged, forms.ragged
         lane_lens = np.tile(
             np.array([block_size * 5 - 3, block_size * 4, 0,
                       block_size * per_lane], np.int32), b,
@@ -608,6 +619,12 @@ class GenerateEngine:
         paged_kernel = jax.jit(functools.partial(
             paged_decode_attention, use_flash=True, mesh=self.mesh, **paged_kw
         ))
+        ragged_reference = jax.jit(functools.partial(
+            ragged_prefill_attention, sliding_window=cfg.sliding_window))
+        ragged_kernel = jax.jit(functools.partial(
+            ragged_prefill_attention, sliding_window=cfg.sliding_window,
+            use_flash=True))
+
         def draw(rng, *shape):
             return jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
 
@@ -650,20 +667,42 @@ class GenerateEngine:
                 )
             return err_of(got, want)
 
-        def _check_on_lane() -> Tuple[float, float]:
+        def ragged_err(rng) -> float:
+            # segments start on aligned rows; the last 128 rows are padding
+            t, seg_lens = 2560, (2118, 130)
+            seg, pos, row = np.full(t, -1, np.int32), np.zeros(t, np.int32), 0
+            for lane, n in enumerate(seg_lens):
+                seg[row: row + n], pos[row: row + n] = lane, np.arange(n)
+                row += round_up(n, 128)
+            args = (
+                draw(rng, t, cfg.num_heads, cfg.head_dim),
+                draw(rng, t, cfg.num_kv_heads, cfg.head_dim),
+                draw(rng, t, cfg.num_kv_heads, cfg.head_dim),
+                jnp.asarray(seg), jnp.asarray(pos),
+            )
+            got = ragged_kernel(*args)
+            with jax.default_matmul_precision("highest"):
+                want = ragged_reference(*args)
+            return err_of(got, want)
+
+        def _check_on_lane() -> Tuple[float, float, float]:
             rng = np.random.default_rng(0)
             return (
                 max(dense_err(rng, sq) for sq in q_lens),
                 max(paged_err(rng, sq) for sq in q_lens) if paged else 0.0,
+                ragged_err(rng) if ragged else 0.0,
             )
 
-        err, err_paged = spine_run(
+        err, err_paged, err_ragged = spine_run(
             "kernel_check", _check_on_lane, stream="probe"
         )
         report = {"max_abs_err": err, "tolerance": tol, "q_lens": q_lens}
         if paged:
             report["paged_max_abs_err"] = err_paged
-        if not (err <= tol and err_paged <= tol):  # NaN fails too
+        if ragged:
+            report["ragged_max_abs_err"] = err_ragged
+        # NaN fails too
+        if not (err <= tol and err_paged <= tol and err_ragged <= tol):
             raise AssertionError(
                 f"a Pallas kernel disagrees with its reference: {report}"
             )

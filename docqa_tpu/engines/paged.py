@@ -835,7 +835,8 @@ def ragged_prefill_forward(
         with scope("attend"):
             return ragged_prefill_attention(
                 q[0], k[0], v[0], seg_ids, positions,
-                sliding_window=cfg.sliding_window, **kwargs,
+                sliding_window=cfg.sliding_window, max_segment=rope_len,
+                use_flash=kernels.ragged, **kwargs,
             )[None]
 
     x = decoder_layer_stack(
@@ -1244,7 +1245,8 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
         with scope("attend"):
             out = ragged_prefill_attention(
                 q[0], k[0], v[0], seg_ids, positions, sliding_window=window,
-                grouped_heads=grouped_heads, max_segment=rope_len)
+                grouped_heads=grouped_heads, max_segment=rope_len,
+                use_flash=kernels.ragged)
         if grouped_heads:
             # nothing later in the program reads the pools, so the
             # scheduler would leave every layer's scatter to the end and

@@ -2469,6 +2469,8 @@ class ContinuousBatcher:
         # what the prefill spans and the padding counters report
         group_rows: List[Tuple[int, int, int]] = []
         groups: List[Tuple[bool, List[tuple]]] = []
+        # (seg, pos) of each COLD group: what its attention layers see
+        cold_packings = []
         for warm_flag, T, members in plan:
             group = [good[i] for i in members]
             groups.append((warm_flag, group))
@@ -2514,6 +2516,8 @@ class ContinuousBatcher:
                 (T, ids_flat, seg, pos, dest, last_rows, slots_arr,
                  len(group), warm_flag, tables_np, plens_np)
             )
+            if not warm_flag:
+                cold_packings.append((seg, pos))
         # flattened group-major order: slot scatters and the first-token
         # fetch must line up with the concatenated dispatch outputs
         ordered = [e for _w, group in groups for e in group]
@@ -2620,6 +2624,14 @@ class ContinuousBatcher:
             dispatches=len(groups), kernels=self._kernels,
         ).items():
             DEFAULT_REGISTRY.counter(name).inc(amount)
+        if self._kernels.ragged:
+            # the cold dispatches attended in the flash kernel: over
+            # ``serve_prefill_dispatches`` 1.0 where every one did, and
+            # the key blocks it visited of the packed square's
+            for name, amount in self.engine.ragged_prefill_counts(
+                cold_packings, max_segment=self.seq_capacity,
+            ).items():
+                DEFAULT_REGISTRY.counter(name).inc(amount)
         # group-major, like ``ordered``: (slot, req, prompt tokens,
         # shared tokens, what the dispatch that carried it ran)
         meta = []

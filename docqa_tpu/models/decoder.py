@@ -28,11 +28,14 @@ import jax.numpy as jnp
 from docqa_tpu.config import DecoderConfig
 from docqa_tpu.models.hybrid import (
     MIXERS,
+    ATTENTION,
+    WINDOW,
     check_hybrid_config,
     hybrid_param_schema,
     hybrid_serving,
     is_hybrid,
     lane_state_entries,
+    layers_of,
     mamba_layers,
     sparse_layers,
 )
@@ -49,6 +52,7 @@ from docqa_tpu.ops.attention import (
     attention_reference,
     flash_attention,
     paged_kernel_supported,
+    ragged_key_block_counts,
 )
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
@@ -220,6 +224,45 @@ def block_serving(cfg: DecoderConfig) -> BlockServing:
     )
 
 
+def packed_attention_layers(
+    cfg: DecoderConfig,
+) -> Tuple[Tuple[Optional[int], int], ...]:
+    """((sliding window or None, calls a prefill dispatch), ...): the
+    layers whose prefill attends over the packed rows in flight
+    (``ops/attention.ragged_prefill_attention``), by the window they
+    attend under — the GQA trunk's every layer, once a loop step; the
+    mixer stack's ``attention`` and ``window`` kinds; none of the latent
+    block's (its keys are 192 wide, its own form) nor of a layer that
+    selects, scans or keeps a linear state."""
+    if is_latent(cfg):
+        return ()
+    if not is_hybrid(cfg):
+        return ((cfg.sliding_window, cfg.num_layers * kv_entries(cfg)),)
+    kinds = ((None, len(layers_of(cfg, ATTENTION))),
+             (cfg.sliding_window, len(layers_of(cfg, WINDOW))))
+    return tuple((window, n) for window, n in kinds if n)
+
+
+def ragged_prefill_counts(cfg: DecoderConfig, packings, *, max_segment):
+    """What one admission round's COLD dispatches count where they attend
+    in the kernel (``kernel_forms``'s ``ragged``): the dispatches, and the
+    key blocks their attention layers visited beside the blocks of the
+    whole packed square — host arithmetic on each dispatch's ``(seg_ids,
+    positions)``, the same block ranges the kernel prefetches."""
+    visited = packed = 0
+    for seg_ids, positions in packings:
+        for window, calls in packed_attention_layers(cfg):
+            seen, square = ragged_key_block_counts(
+                seg_ids, positions, window, max_segment)
+            visited += calls * seen
+            packed += calls * square
+    return {
+        "serve_prefill_attend_kernel_dispatches": len(packings),
+        "serve_prefill_key_blocks_visited": visited,
+        "serve_prefill_key_blocks_packed": packed,
+    }
+
+
 def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
                  block_size: Optional[int]) -> KernelForms:
     """Which Pallas forms the paged forwards of ``cfg`` run — THE place
@@ -237,7 +280,12 @@ def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
     * ``scan``: a state-space layer, and NO mesh (the ``ssm_*`` arrays are
       replicated there and the XLA form lowers as it stands);
     * ``grouped``: a routed layer, and NO mesh (``ragged_dot`` partitions
-      experts sharded along their leading axis, a custom call cannot)."""
+      experts sharded along their leading axis, a custom call cannot);
+    * ``ragged``: a layer attends over the packed rows of a prefill
+      (:func:`packed_attention_layers`), its heads are whole 128-lane
+      registers, and NO mesh (the XLA forms are what GSPMD places) — the
+      COLD dispatch's attention is ``ops/attention.ragged_flash_prefill``;
+      a warm one (a cached prefix through the block table) stays XLA."""
     alone = on_tpu and mesh is None
     geometry = (cfg.dtype, cfg.num_kv_heads, cfg.head_dim)
     return KernelForms(
@@ -249,6 +297,8 @@ def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
         and paged_kernel_supported(*geometry),
         scan=alone and len(mamba_layers(cfg)) > 0,
         grouped=alone and routed_layers(cfg) > 0,
+        ragged=alone and len(packed_attention_layers(cfg)) > 0
+        and cfg.head_dim % 128 == 0,
     )
 
 

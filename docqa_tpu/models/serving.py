@@ -20,6 +20,7 @@ class KernelForms(NamedTuple):
     sparse_paged: bool  # a sparse layer's decode reads its blocks as pages
     scan: bool  # a state-space layer's prefill scan keeps ``h`` on the chip
     grouped: bool  # a routed layer's products are ``megablox.gmm``
+    ragged: bool  # the cold packed prefill attends in the flash kernel
 
 
 def _no_counts(**_) -> Tuple[Dict[str, int], Dict[str, float]]:

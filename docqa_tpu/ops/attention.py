@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -187,9 +188,17 @@ def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
                              k_pool=None, v_pool=None, block_tables=None,
                              prefix_lens=None, n_prefix_rows=0,
                              block_size=None, grouped_heads=False,
-                             max_segment=None):
+                             max_segment=None, use_flash=False):
     """Self-attention over a PACKED batch of variable-length prompts —
-    the prefill half of Ragged Paged Attention, XLA reference path.
+    the prefill half of Ragged Paged Attention.
+
+    Which form runs where: under ``use_flash``
+    (``models/decoder.kernel_forms``'s ``ragged``: a TPU, no mesh, heads
+    of whole 128-lane registers) a COLD dispatch attends in the Pallas
+    kernel :func:`ragged_flash_prefill`, which visits only the key blocks
+    a query block can see.  Everything else is the XLA forms below: every
+    CPU run, a mesh, a WARM dispatch (no cell serves one on a chip), and
+    the reference the kernel is tested and self-checked against.
 
     q, k, v   [T, heads, d] — ONE flat token axis; each prompt occupies a
               contiguous run of rows (starts aligned to RAGGED_ALIGN)
@@ -209,8 +218,7 @@ def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
     bucketed prefill this replaced never materialized.  Per-row numerics
     are IDENTICAL to the single-shot form (each row still reduces over
     the same [T] axis), so the block split cannot perturb greedy
-    outputs.  The Pallas RPA kernel that also skips cross-segment
-    blocks entirely is the TPU follow-up.
+    outputs.
 
     WARM mode (``n_prefix_rows > 0``, the copy-on-write prefix-cache
     path, docqa-prefix): each segment may additionally attend a CACHED
@@ -224,7 +232,11 @@ def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
     pool's stored K/V are the very bf16 values a cold prefill would
     compute in-flight — so the softmax reduction trees, and therefore
     the sampled tokens, are bitwise identical to prefilling the whole
-    prompt cold.  ``n_prefix_rows`` is a static shape (the sequence
+    prompt cold IN THE XLA FORM (a CPU, a mesh): warm = cold bitwise is
+    the XLA pair's property.  Where the cold dispatch runs the kernel (a
+    TPU alone) a hit and a miss agree to the warm-up self-check's
+    tolerance, as the decode kernel and the solo engine already do.
+    ``n_prefix_rows`` is a static shape (the sequence
     capacity); unused rows are masked.  Segment starts must be aligned
     (each query block then belongs to exactly one segment, so one block
     table row serves the whole block).
@@ -241,6 +253,10 @@ def ragged_prefill_attention(q, k, v, seg_ids, positions, *,
     _, hkv, _ = k.shape
     groups = hq // hkv
     scale = scale if scale is not None else d ** -0.5
+    if use_flash and not n_prefix_rows:
+        return ragged_flash_prefill(
+            q, k, v, seg_ids, positions, sliding_window=sliding_window,
+            scale=scale, max_segment=max_segment)
     if grouped_heads and not n_prefix_rows and t % RAGGED_ALIGN == 0:
         return _grouped_prefill_attention(
             q, k, v, seg_ids, positions, sliding_window, scale, max_segment)
@@ -661,6 +677,247 @@ def flash_attention(
 
     out = out.reshape(b, hq, sq_p, d).transpose(0, 2, 1, 3)
     return out[:, :sq]
+
+
+# --------------------------------------------------------------------------
+# Pallas ragged prefill kernel
+# --------------------------------------------------------------------------
+
+# Key rows a grid step of the ragged prefill kernel covers: the largest of
+# these that divides the packed axis; a query block is RAGGED_ALIGN rows
+# (one segment's, or padding).  Of 128 / 256 / 512 query rows x 128 / 256 /
+# 512 key rows, 128 x 512 was the fastest in a 9,728-row global layer of
+# 32 / 4 heads (9.98 ms; 11.05 at 256 x 512, 17.88 at 128 x 256), in its
+# window layer (4.71; 5.26; 7.66), and in a 512-row call of 32 / 8 heads
+# (62 us; 81; 85) — v5e, chip run, PR 49 call 1; PERF.md §6.
+RAGGED_KEY_BLOCKS = (512, 256, 128)
+
+
+def ragged_key_block_rows(t: int) -> int:
+    """Key rows a grid step of :func:`ragged_flash_prefill` covers on a
+    packed axis of ``t`` rows — read from the shape."""
+    fits = [b for b in RAGGED_KEY_BLOCKS if t % b == 0]
+    if not fits:
+        raise ValueError(
+            f"ragged_flash_prefill needs a packed axis of whole "
+            f"{RAGGED_ALIGN}-row blocks (got T={t})")
+    return fits[0]
+
+
+def ragged_key_steps(t, window, max_segment, block_kv) -> int:
+    """The most key blocks one query block can see — the kernel's static
+    innermost grid extent: a row reaches back less than ``window``, and
+    less than ``max_segment`` (the rows of the longest segment there can
+    be), so a query block's keys lie in ``reach - 1 + RAGGED_ALIGN``
+    consecutive rows, at whatever offset inside a key block."""
+    reach = min(r for r in (window, max_segment, t) if r is not None)
+    return min((reach + RAGGED_ALIGN - 2) // block_kv + 2, t // block_kv)
+
+
+def ragged_key_blocks(seg_ids, positions, window, block_kv, xp=jnp):
+    """(first, count) int32 [T / RAGGED_ALIGN]: the key blocks of
+    ``block_kv`` packed rows a query block of ``RAGGED_ALIGN`` rows can
+    see — ``count`` consecutive ones from ``first``, 0 for a block of
+    padding.
+
+    A segment is one contiguous run in position order, so the key at
+    position ``p - n`` of row ``r`` (position ``p``) is row ``r - n``: a
+    live row sees the rows ``r - min(p, window - 1) .. r`` and nothing
+    else, and a query block — one segment's rows, or padding — the rows
+    from its first live row's first key to its last live row: every block
+    of the range has a pair the mask lets through.  ``xp``: ``numpy`` for
+    the host's counters (the batcher), the same arithmetic."""
+    t = seg_ids.shape[0]
+    rows = xp.arange(t, dtype=xp.int32)
+    live = seg_ids >= 0
+    back = positions if window is None else xp.minimum(positions, window - 1)
+    seen_from = xp.where(live, xp.maximum(rows - back, 0), t)
+    first = seen_from.reshape(-1, RAGGED_ALIGN).min(axis=1) // block_kv
+    last = xp.where(live, rows, -1).reshape(-1, RAGGED_ALIGN).max(axis=1)
+    count = xp.where(last >= 0, last // block_kv - first + 1, 0)
+    first = xp.where(count > 0, first, 0)
+    return first.astype(xp.int32), count.astype(xp.int32)
+
+
+def ragged_key_block_counts(seg_ids, positions, window, max_segment=None):
+    """(visited, packed): the (query block, key block) pairs one call of
+    :func:`ragged_flash_prefill` multiplies on this packing, and the pairs
+    of the whole packed square (what a form that masks and does not skip
+    multiplies) — host arithmetic on ``numpy`` arrays, in units of one
+    grid step, for the batcher's counters."""
+    t = len(seg_ids)
+    bk = ragged_key_block_rows(t)
+    _, count = ragged_key_blocks(
+        np.asarray(seg_ids), np.asarray(positions), window, bk, xp=np)
+    steps = ragged_key_steps(t, window, max_segment, bk)
+    visited = int(np.minimum(count, steps).sum())
+    return visited, (t // RAGGED_ALIGN) * (t // bk)
+
+
+def _ragged_prefill_kernel(
+    # scalar prefetch, [T / bq] int32 each (:func:`ragged_key_blocks`)
+    first_ref,  # the first key block a query block sees
+    count_ref,  # how many it sees (0: a block of padding)
+    # blocks
+    q_ref,  # [bq, groups * d]: a kv head's query heads side by side
+    k_ref,  # [bk, d]
+    v_ref,  # [bk, d]
+    seg_q_ref,  # [bq, 128] int32, a row's value in every lane
+    pos_q_ref,
+    seg_k_ref,  # [8, bk] int32, a key's value in every sublane
+    pos_k_ref,
+    o_ref,  # [bq, groups * d]
+    # scratch
+    qs_ref,  # [bq, groups * d]: q * scale, rounded once to q's type
+    m_ref,  # [groups, bq, 128] f32 running max (lane-replicated)
+    l_ref,  # [groups, bq, 128] f32 running denom
+    acc_ref,  # [groups, bq, d] f32
+    *,
+    groups: int,
+    sliding_window: Optional[int],
+    scale: float,
+):
+    """One grid step = (kv head, query block, one of the key blocks the
+    query block can see): ``_flash_kernel``'s online softmax for each of
+    the kv head's query heads on ONE fetch of the head's keys and values.
+    A step past the block's ``count`` multiplies nothing and — its index
+    map names the block already there — fetches nothing."""
+    qi = pl.program_id(1)
+    step = pl.program_id(2)
+    bk, d = k_ref.shape
+
+    @pl.when(step == 0)
+    def _init():
+        qs_ref[...] = (
+            q_ref[...].astype(jnp.float32) * scale).astype(qs_ref.dtype)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(step < count_ref[qi])
+    def _attend():
+        # the general form's mask, from the same two arrays
+        seg_q = jnp.tile(seg_q_ref[...], (1, bk // 128))  # [bq, bk]
+        pos_q = jnp.tile(pos_q_ref[...], (1, bk // 128))
+        seg_k, pos_k = seg_k_ref[:1, :], pos_k_ref[:1, :]  # [1, bk]
+        mask = (seg_q == seg_k) & (seg_q >= 0) & (pos_k <= pos_q)
+        if sliding_window is not None:
+            mask &= pos_k > pos_q - sliding_window
+        k, v = k_ref[...], v_ref[...]
+        for g in range(groups):
+            s = jax.lax.dot_general(
+                qs_ref[:, g * d: (g + 1) * d], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [bq, bk]
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[g, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # no re-mask: a hidden key reads exp(NEG_INF - m_new) = 0 once
+            # the row has seen a key; what a row gathers before its first
+            # one (m_new == NEG_INF, p = 1) that key's alpha = 0 wipes, and
+            # a row that never sees one is zeroed at the end
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_ref[g, :, :1] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [bq, d]
+            acc_ref[g] = acc_ref[g] * alpha + pv
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _finalize():
+        for g in range(groups):
+            denom = jnp.maximum(l_ref[g, :, :1], 1e-30)
+            has_key = m_ref[g, :, :1] > 0.5 * NEG_INF
+            o_ref[:, g * d: (g + 1) * d] = jnp.where(
+                has_key, acc_ref[g] / denom, 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("sliding_window", "scale", "max_segment", "interpret"),
+)
+def ragged_flash_prefill(q, k, v, seg_ids, positions, *, sliding_window=None,
+                         scale=None, max_segment=None, interpret=False):
+    """:func:`ragged_prefill_attention`, COLD, as one Pallas kernel that
+    visits only the key blocks a query block can see.
+
+    q [T, hq, d], k, v [T, hkv, d] as the projections leave them (no
+    transpose, no float32 copy, no repeat over a group: a block is a
+    column slice of the ``[T, heads * d]`` view); ``seg_ids``,
+    ``positions`` [T]; ``T`` a whole number of ``RAGGED_ALIGN`` rows, ``d``
+    of 128 lanes, segment starts aligned.  Returns [T, hq, d] in q's
+    type; padding rows and rows with no key are exact zeros.
+
+    Grid: kv head x query block x the key blocks it can see
+    (:func:`ragged_key_blocks`, from ``seg_ids`` / ``positions`` in the
+    program, scalar-prefetched): causal skipping in a global layer, window
+    skipping in a window layer, nothing for a block of padding, nothing
+    across segments.  The innermost extent is static
+    (:func:`ragged_key_steps`: ``max_segment`` bounds it where many
+    segments share a long axis).  Inside a visited block the mask is the
+    general form's.  Arithmetic: MXU operands as stored (``q * scale``
+    rounded once to q's type, ``p`` to v's — what the XLA form's
+    default-precision products feed the MXU), float32 accumulation,
+    running max and sum.  Jitted so that a prefill program traces and
+    lowers the kernel once a (shape, window), as ``_paged_attend_local``."""
+    t, hq, d = q.shape
+    hkv = k.shape[1]
+    if hq % hkv or d % 128:
+        raise ValueError(
+            f"ragged_flash_prefill: {hq} query heads over {hkv} kv heads "
+            f"of width {d} (needs whole groups of 128-lane heads)")
+    groups = hq // hkv
+    scale = scale if scale is not None else d ** -0.5
+    bq, bk = RAGGED_ALIGN, ragged_key_block_rows(t)
+    first, count = ragged_key_blocks(seg_ids, positions, sliding_window, bk)
+    steps = ragged_key_steps(t, sliding_window, max_segment, bk)
+
+    def key_block(i, j, first_ref, count_ref):
+        # past the count: the block already there (no fetch)
+        return first_ref[i] + jnp.minimum(j, jnp.maximum(count_ref[i] - 1, 0))
+
+    q_spec = pl.BlockSpec((bq, groups * d), lambda h, i, j, *_: (i, h))
+    kv_spec = pl.BlockSpec(
+        (bk, d), lambda h, i, j, *refs: (key_block(i, j, *refs), h))
+    row_spec = pl.BlockSpec((bq, 128), lambda h, i, j, *_: (i, 0))
+    key_spec = pl.BlockSpec(
+        (8, bk), lambda h, i, j, *refs: (0, key_block(i, j, *refs)))
+    seg_ids, positions = seg_ids.astype(jnp.int32), positions.astype(jnp.int32)
+    out = pl.pallas_call(
+        functools.partial(
+            _ragged_prefill_kernel, groups=groups,
+            sliding_window=sliding_window, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(hkv, t // bq, steps),
+            in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec,
+                      key_spec, key_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((bq, groups * d), q.dtype),
+                pltpu.VMEM((groups, bq, 128), jnp.float32),
+                pltpu.VMEM((groups, bq, 128), jnp.float32),
+                pltpu.VMEM((groups, bq, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t, hq * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="_ragged_prefill_kernel",
+    )(
+        first, count,
+        q.reshape(t, hq * d), k.reshape(t, hkv * d), v.reshape(t, hkv * d),
+        jnp.broadcast_to(seg_ids[:, None], (t, 128)),
+        jnp.broadcast_to(positions[:, None], (t, 128)),
+        jnp.broadcast_to(seg_ids[None, :], (8, t)),
+        jnp.broadcast_to(positions[None, :], (8, t)),
+    )
+    return out.reshape(t, hq, d)
 
 
 # --------------------------------------------------------------------------

@@ -415,6 +415,41 @@ class TestCompilesForTheChip:
         assert f"f32[{rows},{n},{d}]" not in hlo
         assert f"f32[{rows // 128},128,{n},{d}]" not in hlo
 
+    @pytest.mark.parametrize("rows, heads, kv_heads, window", [
+        (512, 16, 16, None), (512, 32, 8, 4096), (9728, 32, 4, None),
+        (9728, 32, 4, 2048), (37888, 32, 4, None), (37888, 32, 4, 2048),
+        (9728, 20, 1, None), (37888, 20, 1, None),
+    ], ids=["ouro", "mistral", "trinity-global", "trinity-window",
+            "trinity-global-comparison", "trinity-window-comparison",
+            "jamba2", "jamba2-comparison"])
+    def test_the_ragged_prefill_kernel_at_the_served_widths(
+            self, topo, rows, heads, kv_heads, window):
+        """ISSUE 49: ``ops/attention.ragged_flash_prefill`` — column blocks
+        of the ``[rows, heads * 128]`` views, four prefetched ranges, a kv
+        head's query heads unrolled in one step — for the four served head
+        geometries at the row counts their processes build; K and V enter
+        the call as they are (no float32 copy, no repeat over a group)."""
+        from jax.sharding import SingleDeviceSharding
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32 = jnp.bfloat16, jnp.int32
+        compiled = jax.jit(
+            lambda *args: A.ragged_prefill_attention(
+                *args, sliding_window=window, max_segment=9856,
+                use_flash=True)).lower(
+            arg((rows, heads, 128), bf16), arg((rows, kv_heads, 128), bf16),
+            arg((rows, kv_heads, 128), bf16), arg((rows,), i32),
+            arg((rows,), i32)).compile()
+        hlo = compiled.as_text()
+        assert "_ragged_prefill_kernel" in hlo
+        assert f"f32[{rows},{kv_heads},128]" not in hlo
+        assert f"bf16[{rows},{heads},128]{{" in hlo  # q in, out: not widened
+        assert f"f32[{rows},{heads},128]" not in hlo
+
     @pytest.mark.parametrize("rows", [48, 3072, 18432], ids=[
         "decode-step", "one-dispatch", "the-comparison"])
     @pytest.mark.parametrize("k, n", [(5120, 1536), (1536, 5120)], ids=[
