@@ -84,10 +84,10 @@ from docqa_tpu.models.serving import KernelForms
 from docqa_tpu.ops.attention import (
     RAGGED_ALIGN,
     compressed_keys,
+    latent_decode_attention,
     linear_attention_prefill,
     linear_attention_step,
     paged_decode_attention,
-    paged_latent_decode_attention,
     ragged_prefill_attention,
     sparse_decode_attention,
     sparse_prefill_attention,
@@ -877,15 +877,15 @@ def paged_decode_forward(
     belongs to an inactive lane re-writing its scratch row.
 
     Returns (logits [S, s, vocab] f32, pools) — and, from the latent
-    block, its routing record (:func:`_latent_decode_forward`).  No Pallas
-    kernel reads a latent row: its attention is the XLA gather (GSPMD
-    places it on a mesh), and ``kernels.grouped`` is the form of the
-    routed layers' product alone (``ops/grouped.py``)."""
+    block, its routing record (:func:`_latent_decode_forward`): under
+    ``kernels.paged`` its attention reads live pages through a kernel of
+    its own (else the XLA gather, which GSPMD places on a mesh), and
+    ``kernels.grouped`` is the form of its routed layers' product."""
     kernels = _forms(cfg, kernels, use_flash, mesh, block_size)
     if is_latent(cfg):
         return _latent_decode_forward(
             params, cfg, pools, block_tables, tok, lengths, block_size,
-            rope_len, kernels.grouped,
+            rope_len, kernels,
         )
     if is_hybrid(cfg):
         return _hybrid_decode_forward(
@@ -984,13 +984,13 @@ def _latent_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
 
 
 def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
-                           block_size, rope_len, grouped):
+                           block_size, rope_len, kernels):
     """A decode step of the latent block in the ABSORBED form: the new
     rows are written at their table-mapped pool rows, each head's query is
     carried into latent space, scores and the weighted sum are taken
-    against the pool rows as stored (one read serves key and value), and
-    the result goes back through the value half of the up-projection.  No
-    per-head key or value of a cached row exists at any point.
+    against the pool rows as stored (one read serves key and value; live
+    pages in place under ``kernels.paged``) and go back through the value
+    half of the up-projection.  No per-head key or value ever exists.
 
     Returns (logits [S, s, vocab] f32, pools, routing record int32
     [routed_layers, S, s, experts_per_token])."""
@@ -1020,15 +1020,15 @@ def _latent_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             )
         q_lat = absorb_query(params, cfg, i, q_nope)
         with scope("attend"):
-            o_lat = paged_latent_decode_attention(
+            o_lat = latent_decode_attention(
                 q_lat, q_rope, pools[f"c{i}"], block_tables, lengths + s,
                 block_size=block_size, q_offset=lengths, scale=scale,
-            )
+                use_flash=kernels.paged)
         return expand_output(params, cfg, i, o_lat)
 
     x, record = latent_layer_stack(
-        params, cfg, tok, rope_pos, rope_len, attend, use_flash=grouped
-    )
+        params, cfg, tok, rope_pos, rope_len, attend,
+        use_flash=kernels.grouped)
     return _with_record(decoder_head(params, cfg, x), pools, record)
 
 

@@ -52,6 +52,7 @@ from docqa_tpu.ops.attention import (
     attention_reference,
     flash_attention,
     paged_kernel_supported,
+    paged_latent_kernel_supported,
     ragged_key_block_counts,
 )
 from docqa_tpu.ops.norms import rms_norm
@@ -274,7 +275,13 @@ def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
     prefill.
 
     * ``paged``: K / V rows of a geometry the paged kernel reads, sharded
-      over ``mesh`` as they are (no kernel reads a latent row);
+      over ``mesh`` as they are; the latent block's one shared row a token
+      through a kernel of its own (``ops/attention.
+      paged_latent_flash_decode``), NO mesh (its pool is replicated there
+      and the XLA form lowers as it stands), by the pool's element type,
+      the latent's width and the page (``paged_latent_kernel_supported``)
+      — a decode step of one token or of several alike (the kernel reads
+      ``s`` from the shape);
     * ``sparse_paged``: a layer selects, NO mesh (the XLA form is what
       GSPMD places), and a selection block is a whole number of pages;
     * ``scan``: a state-space layer, and NO mesh (the ``ssm_*`` arrays are
@@ -288,9 +295,13 @@ def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
       a warm one (a cached prefix through the block table) stays XLA."""
     alone = on_tpu and mesh is None
     geometry = (cfg.dtype, cfg.num_kv_heads, cfg.head_dim)
+    if is_latent(cfg):
+        paged = alone and paged_latent_kernel_supported(
+            cfg.dtype, cfg.kv_lora_rank, block_size)
+    else:
+        paged = on_tpu and paged_kernel_supported(*geometry, mesh)
     return KernelForms(
-        paged=on_tpu and not is_latent(cfg)
-        and paged_kernel_supported(*geometry, mesh),
+        paged=paged,
         sparse_paged=alone and len(sparse_layers(cfg)) > 0
         and block_size is not None
         and cfg.sparse_block_size % block_size == 0

@@ -291,20 +291,33 @@ def latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
     return specs
 
 
+def latent_chunk_counts(*, row, kernels, **_):
+    """One fetched chunk's counters and samples: its expert-choice sums
+    where the block routes (``models/routed.moe_chunk_counts``), and how
+    its latent layers read the cache."""
+    counts, samples = ({}, {}) if row is None else moe_chunk_counts(row=row)
+    if kernels.paged:
+        # over ``serve_decode_chunks``: 1.0 where every chunk's latent
+        # layers read the lanes' live pages in place, absent elsewhere
+        # (the gather of every slot's whole table is running)
+        counts["serve_latent_paged_chunks"] = 1
+    return counts, samples
+
+
 def latent_serving(cfg: DecoderConfig) -> BlockServing:
     """The block's record (``cfg`` checked: :func:`check_latent_config`)."""
     # served cold and unspeculated: a warm prefill would up-project cached
     # rows, which no path does, and a speculative chunk would drop the
-    # routing record.  No Pallas kernel reads a latent row; a block that
-    # does not route carries no sums.  One row a token, no head axis: its
-    # pools are replicated
+    # routing record.  The engine's ``use_flash`` reaches no kernel of the
+    # block (no dense cache serves it: the forms its paged forwards run
+    # are ``kernel_forms``'s alone); a block that does not route carries
+    # no sums.  One row a token, no head axis: its pools are replicated
     routes = bool(routed_layers(cfg))
     sums = dict(
         step_sum_names=MOE_SUMS,
         step_sums=functools.partial(moe_step_sums, cfg),
         prefill_sum_names=MOE_PREFILL_SUMS,
         prefill_sums=functools.partial(moe_prefill_sums, cfg),
-        chunk_counts=moe_chunk_counts,
     ) if routes else {}
     return BlockServing(
         label=f'DecoderConfig(block="{cfg.block}")',
@@ -316,6 +329,7 @@ def latent_serving(cfg: DecoderConfig) -> BlockServing:
             "over the paged latent cache (engines/paged.py) only"
         ),
         uses_flash=False,
+        chunk_counts=latent_chunk_counts,
         param_pspecs=functools.partial(latent_param_pspecs, cfg),
         pool_pspecs=lambda: {f"c{i}": P() for i in range(cfg.num_layers)},
         **sums,

@@ -16,7 +16,7 @@ class KernelForms(NamedTuple):
     once (``models/decoder.kernel_forms``), handed to the ops as plain
     static booleans, counted by the batcher as they stand."""
 
-    paged: bool  # ``paged_decode_attention`` reads live pages in place
+    paged: bool  # the decode attention reads a lane's live pages in place
     sparse_paged: bool  # a sparse layer's decode reads its blocks as pages
     scan: bool  # a state-space layer's prefill scan keeps ``h`` on the chip
     grouped: bool  # a routed layer's products are ``megablox.gmm``
@@ -42,9 +42,11 @@ class BlockServing:
     (``generate.prefix_cache``, ``generate.speculative_k``,
     ``qos.preemption``), ``advice`` what to set instead; ``solo`` why the
     dense-cache engine and forward do not run it (``None``: they do);
-    ``uses_flash`` whether ``use_flash`` reaches a kernel of the kind at
-    all.  (A configuration the kind cannot run is refused by field where
-    the record is built.)
+    ``uses_flash`` whether the engine KEEPS ``use_flash`` for the kind —
+    its dense-cache forwards and the warm-up's ``kernel_selfcheck`` go by
+    the flag; the forms of the paged forwards are ``kernel_forms``'s,
+    asked with what the engine observed either way.  (A configuration the
+    kind cannot run is refused by field where the record is built.)
 
     The sums a program carries to the host in the fetch the worker makes
     anyway, summed inside the jitted programs under the names of the

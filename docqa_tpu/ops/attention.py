@@ -1722,3 +1722,315 @@ def sparse_decode_attention(q, k_pool, v_pool, ck_pool, block_tables, lengths,
             jnp.any(live & ~sparse_lane), whole_tables, taken_rows_only, None)
     rec = jnp.moveaxis(rec, 1, 0)  # [g, S, kk]
     return out.reshape(s_, hq, d).astype(q.dtype), _pad_record(rec, topk)
+
+
+# --------------------------------------------------------------------------
+# The latent block's paged decode kernel (models/latent.py): one pool for
+# key and value, one shared row a token, every head of a lane a row of ONE
+# product — a geometry of its own beside ``_paged_decode_kernel`` (kv
+# heads, head pairs, groups), so a body and a wrapper of its own.  Kept
+# BELOW every other kernel of the file, so that the serialized bodies above
+# keep their line numbers (a compile-cache key holds them).
+# --------------------------------------------------------------------------
+
+# pool rows one grid step covers (a whole number of pages, each an operand
+# of the call).  Of 128 / 256 / 512 / 1024 on a v5e, us a layer-call with
+# the schedule's share (chip runs, PR 50; PERF.md): 36.0 / 29.1 / 25.7 / 40.8
+# at 8 lanes x ~450 rows, 231 / 178 / 147 / 133 at 8 x 4,096 — a lane of a
+# few hundred rows is one step, and 64 page operands a step cost the
+# pipeline more bookkeeping than the fewer steps save
+LATENT_BLOCK_ROWS = 512
+
+
+def paged_latent_kernel_supported(pool_dtype, rank, block_size) -> bool:
+    """Whether :func:`paged_latent_flash_decode` reads a latent pool of
+    this geometry: a page (``block_size`` rows) is whole sublane tiles of
+    the pool's type — 16 rows of a 16-bit pool, 8 of a 32-bit one — so it
+    is one contiguous window of the pool, and the latent part of a row
+    (``rank`` values) is whole 128-lane registers, so the rotated key
+    behind it starts on one.  Anything else stays on the XLA reference."""
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    return (
+        itemsize in (2, 4) and block_size is not None
+        and block_size % (32 // itemsize) == 0 and rank % 128 == 0
+    )
+
+
+def latent_page_schedule(block_tables, lengths, *, block_size: int,
+                         n_blocks: int, ppb: int):
+    """The grid of :func:`paged_latent_flash_decode`, from the tables and
+    lengths in the program: one step a (lane, compute block of ``ppb``
+    pages) that is LIVE — a lane of no rows keeps one step, which writes
+    its zeros — so the steps follow the rows the lanes hold, not the
+    table's span.
+
+    Returns int32 (steps [] — how many of the ``W = S * ceil(NB / ppb)``
+    entries below count —, lane [W], block within the lane [W], blocks a
+    lane [S], kv length a lane [S] clamped to its ALLOCATED pages so that a
+    hole is never dereferenced, page [W * ppb]).  ``page`` names what each
+    of a step's ``ppb`` page operands holds: the lane's live page, or,
+    past the lane's last, WHAT THE OPERAND HELD the step before (page 0
+    before its first) — an unchanged index is not fetched again, so only
+    live pages are read, once.
+
+    A few hundred integers a decode step (every layer of it shares them),
+    linear in ``W``: only a lane's LAST block has operands past its pages,
+    so what they keep is settled a (lane, operand) — the block before it
+    in the lane, else the nearest earlier lane that fetched that operand.
+    Comparisons and sums over small dense arrays, which XLA fuses, and
+    three gathers; a cumulative sum and a sorted search are kernels each."""
+    i32 = jnp.int32
+    S, nb = block_tables.shape
+    per_lane = -(-nb // ppb)
+    tables = jnp.pad(
+        block_tables.astype(i32), ((0, 0), (0, per_lane * ppb - nb)),
+        constant_values=n_blocks)
+    allocated = jnp.sum((tables < n_blocks).astype(i32), axis=1) * block_size
+    tables = tables.reshape(S, per_lane, ppb)
+    kv_len = jnp.minimum(lengths.astype(i32), allocated)
+    n_pages = -(-kv_len // block_size)
+    blocks = jnp.maximum(-(-n_pages // ppb), 1)
+    tail = n_pages - (blocks - 1) * ppb  # operands its last block fetches
+    lanes = jnp.arange(S, dtype=i32)
+    slot = jnp.arange(ppb, dtype=i32)
+    earlier = lanes[None, :] < lanes[:, None]  # [lane, an earlier lane]
+    starts = jnp.sum(jnp.where(earlier, blocks[None, :], 0), axis=1)
+    steps = starts[-1] + blocks[-1]
+
+    def of_lane(values, which):
+        """``values[which]`` of a few lanes, as a comparison and a sum."""
+        return jnp.sum(jnp.where(
+            which[..., None] == lanes, values, 0), axis=-1)
+
+    step = jnp.arange(S * per_lane, dtype=i32)
+    lane = jnp.minimum(jnp.sum(
+        (step[:, None] >= (starts + blocks)[None, :]).astype(i32), axis=1),
+        S - 1)
+    blk = jnp.minimum(step - of_lane(starts, lane), per_lane - 1)
+    live = (
+        blk[:, None] * ppb + slot[None, :] < of_lane(n_pages, lane)[:, None]
+    ) & (step < steps)[:, None]
+
+    # [lane, operand]: whose block the operand still holds when the lane's
+    # last block does not fetch it — its own block before (two or more
+    # blocks), else the nearest earlier lane that fetched it, in ITS last
+    # block or the one before
+    fetches = (slot[None, :] < tail[:, None]) | (blocks[:, None] >= 2)
+    source = jnp.max(jnp.where(
+        (earlier[:, :, None] & fetches[None, :, :]) | (
+            (lanes[None, :] == lanes[:, None]) & (blocks[None, :] >= 2)
+        )[:, :, None], lanes[None, :, None], -1), axis=1)
+    of = jnp.maximum(source, 0)
+    in_last = (of != lanes[:, None]) & (slot[None, :] < of_lane(tail, of))
+    kept = tables[
+        of, jnp.maximum(of_lane(blocks, of) - jnp.where(in_last, 1, 2), 0),
+        slot[None, :]]
+    kept = jnp.where(source >= 0, kept, 0)  # before its first fetch: page 0
+
+    page = jnp.where(live, tables[lane, blk], jnp.sum(jnp.where(
+        (lane[:, None] == lanes)[:, :, None], kept[None, :, :], 0), axis=1))
+    page = jnp.minimum(page, n_blocks - 1)
+    return steps, lane, blk, blocks, kv_len, page.reshape(-1)
+
+
+def _paged_latent_kernel(
+    # scalar prefetch (:func:`latent_page_schedule`)
+    lane_ref,  # [W] int32 the lane a grid step works on
+    blk_ref,  # [W] int32 which of the lane's compute blocks
+    blocks_ref,  # [S] int32 compute blocks a lane has (>= 1)
+    lengths_ref,  # [S] int32 valid (and allocated) kv length
+    qoff_ref,  # [S] int32 absolute position of q row 0
+    page_ref,  # [W * ppb] int32: read by the page operands' index maps
+    # blocks
+    ql_ref,  # [1, s * heads, r]: row i is q position i // heads
+    qr_ref,  # [1, s * heads, dr]
+    *rest,  # ppb pages [1, block_size, r + dr] of the pool; then o_ref
+    # [1, s * heads, r]; then the scratch: rows [ppb * block_size, r + dr]
+    # (the step's pages side by side), qs [s * heads, r + dr] (q * scale,
+    # rounded once to q's type), m and l [s * heads, 128] f32 (running max
+    # and denominator, lane-replicated), acc [s * heads, r] f32
+    heads: int,
+    scale: float,
+):
+    """One grid step = one compute block of one lane: the pipeline has
+    fetched its live pages (the next step's are in flight), ONE fetch of a
+    row serves the score (all ``r + dr`` values: the latent and the
+    rotated key) and the weighted sum (its first ``r``), and the lane's
+    heads (times its ``s`` positions) are the rows of one product.  The
+    online softmax is ``_paged_decode_kernel``'s; a lane's last block
+    writes its output, zeros for a lane of no rows."""
+    *pages, o_ref, rows_ref, qs_ref, m_ref, l_ref, acc_ref = rest
+    step = pl.program_id(0)
+    lane, j = lane_ref[step], blk_ref[step]
+    n_q, r = acc_ref.shape
+    rows = rows_ref.shape[0]
+    block_size = rows // len(pages)
+    kv_len = lengths_ref[lane]
+
+    @pl.when(j == 0)
+    def _init():
+        qs_ref[:, :r] = (
+            ql_ref[0].astype(jnp.float32) * scale).astype(qs_ref.dtype)
+        qs_ref[:, r:] = (
+            qr_ref[0].astype(jnp.float32) * scale).astype(qs_ref.dtype)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * rows < kv_len)
+    def _attend():
+        # what an operand past the lane's last page holds (an earlier
+        # page, of any lane) is masked out of the scores and, a finite
+        # VALUE times a probability of exactly 0, adds nothing
+        for i, page in enumerate(pages):
+            rows_ref[i * block_size: (i + 1) * block_size, :] = page[0]
+        q_rows = jax.lax.broadcasted_iota(jnp.int32, (n_q, rows), 0)
+        kv_pos = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (n_q, rows), 1)
+        mask = (kv_pos < kv_len) & (
+            kv_pos <= qoff_ref[lane] + q_rows // heads)
+        # the latent against the latent, the rotated parts against each
+        # other: two products, both halves on whole registers
+        s = jax.lax.dot_general(
+            qs_ref[:, :r], rows_ref[:, :r], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            qs_ref[:, r:], rows_ref[:, r:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [n_q, rows]
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit re-mask, as in _paged_decode_kernel: a query row that
+        # sees no key of the block has m_new == NEG_INF, exp(s - m_new) 1
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(rows_ref.dtype), rows_ref[:, :r],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # [n_q, r]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == blocks_ref[lane] - 1)
+    def _finalize():
+        denom = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def paged_latent_flash_decode(q_lat, q_rope, pool, block_tables, lengths, *,
+                              block_size: int, q_offset, scale: float,
+                              interpret: bool = False):
+    """:func:`paged_latent_decode_attention` as a Pallas kernel that reads
+    a lane's live pages IN PLACE — the same arguments, the same output
+    ([S, s, heads, r] in q's type); ``s`` is read from the shape.
+
+    A page is an OPERAND: the pool, viewed ``[n_blocks, block_size,
+    r + dr]`` (a free reshape), is handed to the call once a page of a
+    compute block (``LATENT_BLOCK_ROWS`` rows), each a window of one page
+    whose index the schedule (:func:`latent_page_schedule`) names, and the
+    call's own pipeline fetches a page where the index changed — one
+    contiguous DMA of ``block_size x (r + dr)`` values, the next step's
+    while this one computes.  (A row of 576 values is 4.5 registers wide,
+    and Mosaic slices a memory reference by whole tiles only: a window of
+    the whole row is what it does copy.)  The grid is DYNAMIC: the live
+    compute blocks, lane after lane.  Nothing of the table's span or of
+    the pool's size is gathered or copied to float32.
+
+    Arithmetic: MXU operands as stored (``q * scale`` rounded once to q's
+    type, ``p`` to the rows' type), float32 scores, running max,
+    denominator and accumulator.  A length is clamped to the lane's
+    ALLOCATED pages, so a hole is never dereferenced: a free slot or a
+    retired lane (all-hole table row) reads nothing and outputs zeros,
+    where the gather reference attends to a clamped garbage row — only
+    ever for lanes nobody reads.  One device: the form a mesh runs is the
+    reference."""
+    r = q_lat.shape[-1]
+    if pool.shape[1] != 1 or pool.shape[2] != r + q_rope.shape[-1]:
+        raise ValueError(
+            f"paged_latent_flash_decode: a pool of rows {pool.shape[1:]} "
+            f"for queries of {r} + {q_rope.shape[-1]} values")
+    if not (interpret
+            or paged_latent_kernel_supported(pool.dtype, r, block_size)):
+        raise NotImplementedError(
+            f"paged_latent_flash_decode does not read a {pool.dtype} pool "
+            f"of {r}-wide latents in pages of {block_size} "
+            "(paged_latent_kernel_supported)")
+    return _paged_latent_attend_local(
+        q_lat, q_rope, pool, block_tables, lengths, q_offset,
+        block_size=block_size, scale=float(scale),
+        block_rows=LATENT_BLOCK_ROWS, interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "scale", "block_rows", "interpret"),
+)
+def _paged_latent_attend_local(q_lat, q_rope, pool, block_tables, lengths,
+                               q_offset, *, block_size, scale, block_rows,
+                               interpret):
+    """Jitted, as ``_paged_attend_local``: a decode program traces and
+    lowers the kernel once and calls it from each of its latent layers
+    (the schedule, the same for every layer of a step, is XLA's to share)."""
+    S, s, heads, r = q_lat.shape
+    width = r + q_rope.shape[-1]
+    n_blocks = pool.shape[0] // block_size
+    ppb = max(1, min(block_rows // block_size, block_tables.shape[1]))
+    n_q = s * heads
+    steps, lane, blk, blocks, kv_len, page = latent_page_schedule(
+        block_tables, lengths, block_size=block_size, n_blocks=n_blocks,
+        ppb=ppb)
+
+    def lane_block(cols):
+        return pl.BlockSpec(
+            (1, n_q, cols), lambda i, lane_ref, *_: (lane_ref[i], 0, 0))
+
+    def page_block(k):
+        return pl.BlockSpec(
+            (1, block_size, width),
+            lambda i, *refs: (refs[5][i * ppb + k], 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_paged_latent_kernel, heads=heads, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(steps,),
+            in_specs=[lane_block(r), lane_block(width - r)] + [
+                page_block(k) for k in range(ppb)],
+            out_specs=lane_block(r),
+            scratch_shapes=[
+                pltpu.VMEM((ppb * block_size, width), pool.dtype),
+                pltpu.VMEM((n_q, width), q_lat.dtype),
+                pltpu.VMEM((n_q, 128), jnp.float32),
+                pltpu.VMEM((n_q, 128), jnp.float32),
+                pltpu.VMEM((n_q, r), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, n_q, r), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="_paged_latent_kernel",
+    )(
+        lane, blk, blocks, kv_len, q_offset.astype(jnp.int32), page,
+        q_lat.reshape(S, n_q, r), q_rope.reshape(S, n_q, width - r),
+        *([pool.reshape(n_blocks, block_size, width)] * ppb),
+    )
+    return out.reshape(S, s, heads, r)
+
+
+def latent_decode_attention(q_lat, q_rope, pool, block_tables, lengths, *,
+                            block_size, q_offset, scale, use_flash=False):
+    """The latent block's decode attention in the form chosen for it:
+    under ``use_flash`` (``models/decoder.kernel_forms``'s ``paged``: a
+    TPU, no mesh, a pool :func:`paged_latent_kernel_supported` reads) the
+    kernel :func:`paged_latent_flash_decode`; otherwise (every CPU run, a
+    mesh) the XLA reference :func:`paged_latent_decode_attention`."""
+    attend = (
+        paged_latent_flash_decode if use_flash
+        else paged_latent_decode_attention)
+    return attend(
+        q_lat, q_rope, pool, block_tables, lengths,
+        block_size=block_size, q_offset=q_offset, scale=scale)

@@ -2,7 +2,8 @@
 
 * ``models/decoder.kernel_forms``: every rule of the four forms, the cases
   the three deleted predicates' tests held (the sparse pages' nine, the
-  scan's four, the grouped product's) and the paged geometry through it;
+  scan's four, the grouped product's), the paged geometry through it and
+  (ISSUE 50) the latent block's own answer to ``paged``;
 * the five benchmarked block settings at toy widths (the compile audit's
   own toy configurations): what each record says, that the batcher, the
   solo engine and the sharding rules import no ``is_latent`` /
@@ -42,6 +43,7 @@ SCANS = dataclasses.replace(
 ROUTES = dataclasses.replace(
     GQA, block="mla_moe", num_layers=2, num_kv_heads=1, first_dense_layers=1,
     num_experts=8)
+LATENT = dataclasses.replace(ROUTES, head_dim=192, kv_lora_rank=512)
 MESH = types.SimpleNamespace(n_devices=4, n_model=4)
 
 
@@ -79,7 +81,19 @@ MESH = types.SimpleNamespace(n_devices=4, n_model=4)
     ("paged", GQA, dict(num_kv_heads=6), dict(mesh=MESH), False),
     ("paged", GQA, dict(head_dim=64), {}, False),
     ("paged", SCANS, {}, {}, True),  # the plain attention layer's one head
-    ("paged", ROUTES, dict(head_dim=128), {}, False),  # no latent kernel
+    # the latent block's one shared row a token, through a kernel of its
+    # own (ISSUE 50): a TPU, NO mesh, pages that are whole tiles of the
+    # pool's type, a latent of whole registers — never the GQA geometry
+    ("paged", LATENT, {}, {}, True),
+    ("paged", LATENT, {}, dict(on_tpu=False), False),  # a CPU
+    ("paged", LATENT, {}, dict(on_tpu=None), False),  # asks: a CPU
+    ("paged", LATENT, {}, dict(mesh=MESH), False),  # GSPMD: XLA
+    ("paged", LATENT, {}, dict(block_size=None), False),  # a prefill
+    ("paged", LATENT, {}, dict(block_size=8), False),  # half a bf16 tile
+    ("paged", LATENT, dict(dtype="float32"), dict(block_size=8), True),
+    ("paged", LATENT, dict(kv_lora_rank=32), {}, False),  # a toy's latent
+    ("paged", LATENT, dict(head_dim=128), {}, True),  # not the GQA rule
+    ("grouped", LATENT, {}, {}, True),  # and its routed product as before
 ])
 def test_kernel_forms_holds_every_rule(form, cfg, change, kw, chosen):
     """``on_tpu`` None: a caller that observed nothing enters through the

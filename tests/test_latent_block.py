@@ -43,6 +43,7 @@ from docqa_tpu.engines.generate import GenerateEngine  # noqa: E402
 from docqa_tpu.models import latent  # noqa: E402
 from docqa_tpu.models.decoder import (  # noqa: E402
     decoder_param_schema,
+    kernel_forms,
     kv_row_shapes,
 )
 from docqa_tpu.ops import rope  # noqa: E402
@@ -555,7 +556,7 @@ def test_the_solo_engine_refuses_the_block_by_name():
     params = PACKAGE.weights.make_decoder_params(TOY, 1)
     engine = GenerateEngine(TOY, gen=GenerateConfig(), params=params,
                             use_flash=True)
-    assert engine.use_flash is False, "no kernel reads the latent pool"
+    assert engine.use_flash is False, "no dense cache serves the block"
     with pytest.raises(NotImplementedError, match="deepseek_v2.*batcher"):
         engine.generate_ids([[5, 6, 7]], max_new_tokens=4)
 
@@ -750,6 +751,140 @@ def test_the_batcher_serves_the_block_and_counts_its_choices():
                 params, TOY, pools, tables, jnp.asarray([[tokens[step]]]),
                 jnp.asarray([n + step]), block_size=16, rope_len=256)
             assert near_argmax(logits[0, 0], tokens[step + 1]), (step, tokens)
+
+
+# ---- the decode step's two forms (ISSUE 50) ---------------------------------
+
+# the toy with a latent of one whole register: the narrowest the kernel reads
+TOY128 = dataclasses.replace(TOY, kv_lora_rank=128)
+
+
+@pytest.fixture
+def latent_kernels_interpreted(monkeypatch):
+    """``paged_latent_flash_decode`` and the grouped product as the forwards
+    call them under ``use_flash``, interpreted: the one thing a CPU cannot
+    take from them."""
+    import importlib
+
+    from docqa_tpu.models import routed
+    from docqa_tpu.ops import grouped
+
+    attention = importlib.import_module("docqa_tpu.ops.attention")
+    real = attention.paged_latent_flash_decode
+    monkeypatch.setattr(
+        attention, "paged_latent_flash_decode",
+        lambda *args, **kw: real(*args, **{**kw, "interpret": True}))
+    monkeypatch.setattr(
+        routed, "grouped_matmul",
+        lambda *a, **kw: grouped.grouped_matmul(*a, **kw, interpret=True))
+
+
+@pytest.mark.parametrize("s", [1, 3], ids=["a-step", "a-verify-step"])
+def test_one_step_of_both_forms_leaves_the_same_record(
+        s, latent_kernels_interpreted):
+    """``paged_decode_forward`` with the kernel (``kernels.paged``) and with
+    the gather on the same pools, through scattered tables, a free slot
+    among the lanes: the routing record to the last id (a near-tie apart),
+    the logits within the tolerance the block is held to against its
+    reference."""
+    cfg = TOY128
+    params = PACKAGE.weights.make_decoder_params(cfg, 5)
+    rng = np.random.default_rng(1)
+    n_blocks, lanes = 64, 4
+    pools = {
+        k: jnp.asarray(0.5 * rng.standard_normal(v.shape, np.float32), v.dtype)
+        for k, v in paged.init_paged_pools(cfg, n_blocks, 16).items()}
+    tables = rng.permutation(n_blocks).reshape(lanes, 16).astype(np.int32)
+    tables[2] = n_blocks  # a free slot: every entry a hole
+    tok = jnp.asarray(rng.integers(5, 500, (lanes, s)), jnp.int32)
+    lengths = jnp.asarray([200, 39, 0, 16], jnp.int32)
+    forms = kernel_forms(cfg, on_tpu=True, mesh=None, block_size=16)
+    assert forms.paged and forms.grouped
+    out = {
+        flash: paged.paged_decode_forward(
+            params, cfg, dict(pools), jnp.asarray(tables), tok, lengths,
+            block_size=16, rope_len=256,
+            kernels=forms._replace(paged=flash))
+        for flash in (True, False)}
+    live = [0, 1, 3]
+    record = np.asarray(out[False][2])
+    assert record.shape == (2, lanes, s, 4)
+    # the same choices, but for a near-tie a bfloat16 rounding tips (one
+    # decision of the 18 under the verify step, one expert of its four)
+    ids = [np.sort(np.asarray(out[f][2])[:, live], -1) for f in (True, False)]
+    tipped = (ids[0] != ids[1]).any(-1)
+    assert tipped.sum() <= 1, (ids[0][tipped], ids[1][tipped])
+    for a, b in zip(ids[0][tipped], ids[1][tipped]):
+        assert len(set(a) ^ set(b)) == 2
+    got, want = (np.asarray(out[f][0], np.float32)[live] for f in (True, False))
+    err = np.linalg.norm(got - want, axis=-1) / np.linalg.norm(
+        want - want.mean(-1, keepdims=True), axis=-1)
+    assert err.shape == (3, s) and 0 < err.max() < TOLERANCE, err
+    # both wrote the new rows at the same places, nothing for the free
+    # slot: the first layer's to the bit (a row of it is the token's
+    # alone), a later layer's to what the layers before it rounded
+    for name in pools:
+        wrote = [np.asarray(out[f][1][name], np.float32) for f in (True, False)]
+        changed = [(w != np.asarray(pools[name], np.float32)).any(axis=(1, 2))
+                   for w in wrote]
+        assert (changed[0] == changed[1]).all() and changed[0].sum() == 3 * s
+        np.testing.assert_allclose(
+            *wrote, atol=0.0 if name == "c0" else 0.1, rtol=0.0)
+
+
+def test_the_batcher_counts_the_chunks_that_read_live_pages_in_place(
+        latent_kernels_interpreted):
+    """An engine that saw a TPU (``use_flash``): every decode chunk's
+    latent layers read the lanes' live pages through the kernel —
+    interpreted here —, the counter over ``serve_decode_chunks`` reads 1.0
+    and the rows read follow the form that ran; the tokens are the
+    gather's."""
+    from docqa_tpu.engines.serve import ContinuousBatcher
+    from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
+
+    params = PACKAGE.weights.make_decoder_params(TOY128, 2)
+    names = ("serve_decode_chunks", "serve_latent_paged_chunks",
+             "serve_decode_kv_rows_read", "serve_decode_kv_rows_live",
+             "serve_moe_picks")
+    prompts = [[5 + (7 * i + j) % 500 for j in range(20 + 9 * i)]
+               for i in range(3)]
+    gen = dataclasses.replace(
+        GenerateConfig(), speculative_k=0, prefix_cache=False, decode_chunk=4,
+        max_concurrent=4)
+    got, gained = {}, {}
+    for flash in (True, False):
+        before = {n: DEFAULT_REGISTRY.counter(n).value for n in names}
+        engine = GenerateEngine(TOY128, gen=gen, params=params, use_flash=flash)
+        assert engine.use_flash is False, "no dense cache serves the block"
+        b = ContinuousBatcher(engine, n_slots=4, chunk=4, cache_len=256,
+                              kv_block_size=16, prefix_cache=False)
+        try:
+            assert b._kernels.paged == b._kernels.grouped == flash
+            got[flash] = [list(h.result(timeout=600)) for h in
+                          [b.submit_ids(p, max_new_tokens=9) for p in prompts]]
+        finally:
+            b.stop()
+        gained[flash] = {
+            n: DEFAULT_REGISTRY.counter(n).value - before[n] for n in names}
+        chunks = gained[flash]["serve_decode_chunks"]
+        assert chunks > 0
+        assert gained[flash]["serve_latent_paged_chunks"] == (
+            chunks if flash else 0)
+    # the gather's tokens, until a near-tie of seeded random weights tips
+    # (the first token is the prefill's, the same program in both)
+    assert [len(t) for t in got[True]] == [9, 9, 9] == [
+        len(t) for t in got[False]]
+    same = [a == b for x, y in zip(got[True], got[False])
+            for a, b in zip(x, y)]
+    assert all(same[::9]) and sum(same) >= 18, (got, same)
+    live = gained[True]["serve_decode_kv_rows_live"]
+    assert live == gained[False]["serve_decode_kv_rows_live"] > 0
+    # the kernel: a lane's live pages (at most a page of 16 rows over its
+    # length); the gather: every slot's whole table, every step
+    assert live <= gained[True]["serve_decode_kv_rows_read"] < live + 16 * (
+        gained[True]["serve_moe_picks"] // (TOY128.experts_per_token * 2))
+    assert gained[False]["serve_decode_kv_rows_read"] == (
+        gained[False]["serve_decode_chunks"] * 4 * 4 * 256)
 
 
 def test_a_block_that_does_not_route_adds_nothing_to_a_chunk():

@@ -512,3 +512,34 @@ class TestCompilesForTheChip:
         assert "_paged_decode_kernel" in hlo and "conditional" not in hlo
         assert f"bf16[{lanes},2,4096,128]" not in hlo
         assert f"bf16[{lanes * 2 * 4096},128]" not in hlo
+
+    @pytest.mark.parametrize("lanes, pool_rows, s", [
+        (8, 32768, 1), (4, 16384, 1), (8, 32768, 4)],
+        ids=["served", "the-comparison", "a-verify-step"])
+    def test_the_latent_step_at_deepseek_v2s_widths(
+            self, topo, lanes, pool_rows, s):
+        """ISSUE 50: ``latent_decode_attention`` under ``use_flash`` — 128
+        heads as the rows of one product against pages of 16 x 576 values,
+        32 page operands a grid step over a 256-entry table, a dynamic grid
+        — for the pool the cell serves and the comparison's; nothing with
+        the tables' span of rows is gathered or widened to float32."""
+        from jax.sharding import SingleDeviceSharding
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32 = jnp.bfloat16, jnp.int32
+        hlo = jax.jit(lambda q_lat, q_rope, pool, tables, lengths: (
+            A.latent_decode_attention(
+                q_lat, q_rope, pool, tables, lengths, block_size=BS,
+                q_offset=lengths - s, scale=0.1147, use_flash=True)
+        )).lower(
+            arg((lanes, s, 128, 512), bf16), arg((lanes, s, 128, 64), bf16),
+            arg((pool_rows, 1, 576), bf16), arg((lanes, 256), i32),
+            arg((lanes,), i32)).compile().as_text()
+        assert "_paged_latent_kernel" in hlo
+        assert f"[{lanes},4096,576]" not in hlo
+        assert f"[{lanes},4096,1,576]" not in hlo
+        assert f"f32[{pool_rows}," not in hlo
