@@ -186,6 +186,19 @@ def _audit_hybrid_cfg():
     )
 
 
+def _audit_retention_cfg():
+    """The same stack at audit widths with retention layers alone: pools
+    that hold lane states and no K / V row."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=64, hidden_dim=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=8, mlp_dim=64, max_seq_len=128,
+        block="sparse_linear", mixer_types=("retention", "retention"),
+        use_output_gate=False, use_output_norm=False,
+    )
+
+
 def _audit_loop_cfg():
     """The GQA block's looped trunk, with the sandwich norms."""
     return dataclasses.replace(
@@ -201,6 +214,7 @@ SERVE_CFGS = {
     "serve_hybrid": _audit_hybrid_cfg,
     "serve_ssm": _audit_ssm_cfg,
     "serve_loop": _audit_loop_cfg,
+    "serve_retention": _audit_retention_cfg,
 }
 
 

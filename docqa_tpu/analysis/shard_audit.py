@@ -203,6 +203,21 @@ def _audit_hybrid_cfg():
     )
 
 
+def _audit_retention_cfg():
+    """The same stack with its state-keeping kind that shares kv heads:
+    two retention layers (8 query heads over 2 kv heads; k, v and the
+    decay projection replicated beside a state pool whole on every
+    device) — a stack in which NO layer keeps a row."""
+    from docqa_tpu.config import DecoderConfig
+
+    return DecoderConfig(
+        vocab_size=128, hidden_dim=64, num_layers=2, num_heads=8,
+        num_kv_heads=2, head_dim=8, mlp_dim=128, max_seq_len=32,
+        block="sparse_linear", mixer_types=("retention", "retention"),
+        use_output_gate=False, use_output_norm=False,
+    )
+
+
 def _audit_loop_cfg():
     """The GQA block's looped trunk, with the sandwich norms."""
     return dataclasses.replace(
@@ -218,6 +233,7 @@ PAGED_CFGS = {
     "hybrid": _audit_hybrid_cfg,
     "ssm": _audit_ssm_cfg,
     "loop": _audit_loop_cfg,
+    "retention": _audit_retention_cfg,
 }
 
 

@@ -251,6 +251,13 @@ class DecoderConfig:
     # ``first_dense_layers`` route (the fields above, ``models/routed.py``)
     # and the leading ones keep the dense SwiGLU of ``mlp_dim``;
     # ``sandwich_norm`` (below) is read by this stack too.
+    # "retention": gated power retention of degree 2 (arXiv:2507.04239) —
+    # GQA with RoPE whose weights are squared scores under a decay the
+    # token computes (a projection to the kv heads, ``l{i}_w_decay``),
+    # divided by their sum; its whole past is one float32 state of
+    # ``head_dim + 1`` by ``head_dim (head_dim + 1) / 2`` a kv head and
+    # LANE, and no row a token.  It has no field of its own: heads, kv
+    # heads, head width, ``rope_theta`` and ``qk_norm`` are the trunk's.
     qk_norm: bool = True
     use_output_gate: bool = True
     use_output_norm: bool = True

@@ -57,6 +57,7 @@ from docqa_tpu.models.hybrid import (
     ATTENTION,
     LINEAR,
     MAMBA,
+    RETENTION,
     SPARSE,
     STATE_SLOT,
     WINDOW,
@@ -88,6 +89,8 @@ from docqa_tpu.ops.attention import (
     linear_attention_prefill,
     linear_attention_step,
     paged_decode_attention,
+    power_retention_prefill,
+    power_retention_step,
     ragged_prefill_attention,
     sparse_decode_attention,
     sparse_prefill_attention,
@@ -715,9 +718,13 @@ def kv_bytes_per_token(cfg: DecoderConfig) -> int:
     mixer kinds: the K and V rows of its row-keeping layers (sparse,
     attention), plus a SPARSE layer's share of a compressed key (one per
     ``sparse_kernel_stride`` tokens); its state-keeping layers (linear,
-    state-space) keep nothing a token (``models/hybrid.lane_state_bytes``),
-    and neither does a WINDOW layer: what it holds is a ring a lane,
-    whatever the lane's length (``models/hybrid.ring_pages``).
+    state-space, retention) keep nothing a token
+    (``models/hybrid.lane_state_bytes``), and neither does a WINDOW layer:
+    what it holds is a ring a lane, whatever the lane's length
+    (``models/hybrid.ring_pages``).  A stack in which NO layer keeps a row
+    answers 0: its pages stay the unit of admission (a lane's positions)
+    and weigh nothing; what its memory is spent on is the state a lane
+    (the occupancy's ``state_bytes_per_lane`` / ``state_pool_bytes``).
     The looped trunk: an entry a (step, layer), ``kv_entries`` times a
     plain model's."""
     item = jnp.dtype(cfg.dtype).itemsize
@@ -1057,10 +1064,12 @@ def _init_hybrid_pools(cfg, n_blocks, block_size, dtype, sharding, n_lanes):
       decode step that completes it);
     * a lane's state, one entry a lane (``lane_state_shapes`` /
       ``lane_state_dtypes``): ``s{i}`` [n_lanes, heads, d, d] float32 of
-      each LINEAR layer; ``h{i}`` [n_lanes, state, inner] float32 and
-      ``u{i}`` [n_lanes, taps - 1, inner] (the activation type) of each
-      state-space layer.  ``n_lanes`` defaults to the lanes of
-      ``cfg.max_seq_len`` positions the pool holds;
+      each LINEAR layer; ``s{i}`` [n_lanes, d + 1, kv heads, d (d + 1) /
+      2] float32 of each RETENTION layer; ``h{i}`` [n_lanes, state, inner]
+      float32 and ``u{i}`` [n_lanes, taps - 1, inner] (the activation
+      type) of each state-space layer.  ``n_lanes`` defaults to the lanes
+      of ``cfg.max_seq_len`` positions the pool holds.  A stack in which
+      no layer keeps a row holds these and the slot map ONLY;
     * ``state_slot`` [n_blocks * block_size] int32: the state entry of
       the lane whose FIRST token lives at that pool row (only rows that
       start a block are ever looked up).  Both forwards find a lane's
@@ -1119,13 +1128,18 @@ def _sparse_sizes(cfg) -> dict:
     )
 
 
+def _n_state_entries(pools, cfg) -> int:
+    """Entries a lane-keyed pool of the stack holds (one a lane: a state,
+    or a ring's row of ``window_pages``)."""
+    return pools[next(iter(lane_state_shapes(cfg)), WINDOW_PAGES)].shape[0]
+
+
 def _state_slots(pools, cfg, first_rows, ok=True):
     """The state entry of the lanes whose first token lives at the pool
     rows ``first_rows``; out of bounds (a zero read, a dropped write)
     where ``ok`` is false or the row is past the pool (a hole)."""
     slot_of = pools[STATE_SLOT]
-    n_slots = pools[
-        next(iter(lane_state_shapes(cfg)), WINDOW_PAGES)].shape[0]
+    n_slots = _n_state_entries(pools, cfg)
     slot = slot_of[jnp.minimum(first_rows, slot_of.shape[0] - 1)]
     return jnp.where(ok & (first_rows < slot_of.shape[0]), slot, n_slots)
 
@@ -1164,8 +1178,11 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
     starts from ZERO at each segment's first row and leaves what the
     segment ends with in the lane's entry (found through ``state_slot``
     from the segment's first destination row): a LINEAR layer its chunked
-    scan's state, a state-space layer its scan's state (the Pallas kernel
-    under ``kernels.scan``, ``ops/ssm.py``) and its last conv inputs.
+    scan's state, a RETENTION layer the same of its own scan (chunks of
+    ``RAGGED_ALIGN`` rows: the attention form inside one, the expanded
+    state across them), a state-space layer its scan's state (the Pallas
+    kernel under ``kernels.scan``, ``ops/ssm.py``) and its last conv
+    inputs.
 
     A WINDOW layer scatters into the lane's ring only the rows a later
     step can still see and attends over the rows in flight inside the
@@ -1191,11 +1208,11 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
     # K and V (a single kv head's repeat is a broadcast as it stands); no
     # segment is longer than the sequence capacity, ``rope_len``
     grouped_heads = cfg.num_kv_heads > 1
-    if kinds & {LINEAR, MAMBA, WINDOW}:
+    if kinds & {LINEAR, MAMBA, WINDOW, RETENTION}:
         with scope("state"):
             first_rows = last_rows - positions[last_rows]
             slots = _state_slots(pools, cfg, dest_rows[first_rows], seg_ok)
-            if LINEAR in kinds:
+            if kinds & {LINEAR, RETENTION}:
                 # the chunk that holds a segment's last row takes its state
                 chunk_seg = seg_ids[:: RAGGED_ALIGN]
                 at = jnp.maximum(chunk_seg, 0)
@@ -1220,6 +1237,14 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
             out, pools[f"s{i}"] = linear_attention_prefill(
                 q[0], k[0], v[0], seg_ids, positions,
                 decay_slopes(cfg, i), pools[f"s{i}"], chunk_slot,
+            )
+            return out[None], None
+
+    def retention(i, q, k, v, log_gate):
+        with scope("state"):
+            out, pools[f"s{i}"] = power_retention_prefill(
+                q[0], k[0], v[0], log_gate[0], seg_ids, positions,
+                pools[f"s{i}"], chunk_slot,
             )
             return out[None], None
 
@@ -1281,7 +1306,7 @@ def _hybrid_prefill_forward(params, cfg, pools, ids, seg_ids, positions,
             return g[None], None
 
     handlers = {LINEAR: linear, SPARSE: sparse, ATTENTION: attention,
-                WINDOW: window, MAMBA: mamba}
+                WINDOW: window, MAMBA: mamba, RETENTION: retention}
     x, record = hybrid_layer_stack(
         params, cfg, ids[None, :], positions[None, :], rope_len,
         lambda i, kind, *args: handlers[kind](i, *args),
@@ -1310,8 +1335,13 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
     window still sees.  A state-keeping layer advances the
     lane's entries IN PLACE (read, one step, written back): a LINEAR layer
     its state, a state-space layer its conv window (shifted by the token)
-    and its state.  A lane whose table starts with a hole (a retired slot)
-    reads zeros and writes nothing.
+    and its state.  A RETENTION layer's step runs over its pool's ENTRIES
+    where they lie — each handed the token of the lane that owns it (the
+    inverse of the slot map's answer), an entry no live lane owns keeps
+    what it holds (gate 1, nothing added) — so the 34 MB a lane and layer
+    are neither gathered out of the pool nor scattered back.  A lane whose
+    table starts with a hole (a retired slot) reads zeros and writes
+    nothing.
 
     Returns (logits [S, 1, vocab] f32, pools, selection record int32
     [sparse layers x kv heads, S, 1, sparse_topk] — the routing record
@@ -1350,10 +1380,15 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             w_dest = jnp.where(
                 w_done & (w_rows[:, 0] < P), w_rows[:, 0] // st, P // st)
     slots = None
-    if kinds & {LINEAR, MAMBA, WINDOW}:
+    if kinds & {LINEAR, MAMBA, WINDOW, RETENTION}:
         with scope("state"):
             slots = _state_slots(
                 pools, cfg, block_tables[:, 0] * block_size)
+            if RETENTION in kinds:
+                # the lane that owns each state entry; ``S``: none
+                lane_of = jnp.full(
+                    (_n_state_entries(pools, cfg),), S, jnp.int32,
+                ).at[slots].set(jnp.arange(S, dtype=jnp.int32), mode="drop")
     if WINDOW in kinds:
         with scope("cache_write"):
             ring_dest = _ring_rows(pools, cfg, slots, lengths)
@@ -1375,6 +1410,17 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
                 q[:, 0], k[:, 0], v[:, 0], state, decay_slopes(cfg, i))
             pools[f"s{i}"] = pool.at[slots].set(state, mode="drop")
             return out[:, None], None
+
+    def retention(i, q, k, v, log_gate):
+        def of_entries(x):  # zeros (and a gate of 1) for an unowned entry
+            return x[:, 0].at[lane_of].get(mode="fill", fill_value=0)
+
+        with scope("state"):
+            out, pools[f"s{i}"] = power_retention_step(
+                of_entries(q), of_entries(k), of_entries(v),
+                of_entries(log_gate), pools[f"s{i}"])
+            return out.at[slots].get(
+                mode="fill", fill_value=0)[:, None], None
 
     def sparse(i, q, k, v):
         with scope("cache_write"):
@@ -1430,7 +1476,7 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             return g[:, None], None
 
     handlers = {LINEAR: linear, SPARSE: sparse, ATTENTION: attention,
-                WINDOW: window, MAMBA: mamba}
+                WINDOW: window, MAMBA: mamba, RETENTION: retention}
     x, record = hybrid_layer_stack(
         params, cfg, tok, rope_pos, rope_len,
         lambda i, kind, *args: handlers[kind](i, *args),

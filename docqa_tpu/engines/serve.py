@@ -1904,6 +1904,13 @@ class ContinuousBatcher:
             "tokens_committed": tokens,
             "utilization": used / self.n_blocks,
         }
+        if "state_bytes_per_lane" in out:
+            # what the pools spend on lane STATE, one entry a slot whatever
+            # the lanes' lengths — all of their memory where no layer keeps
+            # a row (``bytes_per_token`` 0: a page is then the unit of
+            # admission alone and ``pool_bytes`` reads 0)
+            out["state_pool_bytes"] = (
+                self.n_slots * out["state_bytes_per_lane"])
         if self._ring_alloc is not None:
             # the second extent: rows the window layers' pools hold for
             # the lanes admitted (a ring each, whatever their lengths)

@@ -1,6 +1,6 @@
 """A decoder stack of several MIXER KINDS — ``DecoderConfig.block ==
 "sparse_linear"``: one name per layer in ``cfg.mixer_types``, chosen at
-trace time.  Five kinds, one trunk:
+trace time.  Six kinds, one trunk:
 
 * ``linear`` — decayed linear attention (Lightning Attention-2,
   arXiv:2401.04658): RoPE, a [d, d] float32 state a head A LANE;
@@ -13,7 +13,12 @@ trace time.  Five kinds, one trunk:
   window plus one page, whatever the lane's length), read at decode by the
   paged kernel from the first page the window can still see;
 * ``mamba`` — a Mamba-1 state-space mixer (arXiv:2312.00752) with its own
-  projections; a lane keeps its last conv inputs and one state.
+  projections; a lane keeps its last conv inputs and one state;
+* ``retention`` — gated power retention of degree 2 (arXiv:2507.04239):
+  RoPE, GQA, weights that are SQUARED scores under a gate computed from
+  the token, normalised by their sum; a lane keeps one float32 state a kv
+  head, d + 1 by d (d + 1) / 2 (129 x 8,256 numbers at d = 128), and no
+  row.
 
 Same shape as ``models/decoder.py`` and ``models/latent.py``: a flat
 parameter tree, one pure-functional trunk, and a ``mix`` callback that owns
@@ -39,6 +44,12 @@ sqrt(L)`` (1 where ``scale_depth`` is 0):
         attention: causal softmax attention over every row
         window:    q, k = rope(q), rope(k); causal softmax attention over
                    the rows j > t - ``sliding_window``
+        retention: q, k = rope(q), rope(k);  g(h) the kv head of head h
+                   gamma_t = log sigmoid(y_t W_decay)    [kv heads] float32
+                   S_t = e^gamma_t S_{t-1} + phi(k_t) [v_t, 1]^T / d
+                   o_t^h = phi(q_t^h) S_t[:, :d] / (phi(q_t^h) S_t[:, d] + eps)
+                   phi(x): the products x_a x_b, a <= b — phi(q) . phi(k) =
+                   (q . k)^2 (``ops/attention.power_features``)
         branch = (o * sigmoid(y Wg)) Wo   (``use_output_gate``; else o Wo)
     mamba (``inner = ssm_expand * hidden``):
         [u, z] = y W_in
@@ -93,7 +104,11 @@ from docqa_tpu.models.routed import (
     routing_problems,
 )
 from docqa_tpu.models.serving import BlockServing
-from docqa_tpu.ops.attention import PAGED_BLOCK_ROWS, paged_kernel_supported
+from docqa_tpu.ops.attention import (
+    PAGED_BLOCK_ROWS,
+    paged_kernel_supported,
+    power_feature_count,
+)
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
 from docqa_tpu.ops.scopes import scope
@@ -102,9 +117,9 @@ Params = Dict[str, jax.Array]
 
 HYBRID_BLOCK = "sparse_linear"
 SPARSE, LINEAR, ATTENTION, MAMBA = "sparse", "linear", "attention", "mamba"
-WINDOW = "window"
+WINDOW, RETENTION = "window", "retention"
 # the kinds whose q and k are rotated (each at its own head width)
-ROTATED = (LINEAR, WINDOW)
+ROTATED = (LINEAR, WINDOW, RETENTION)
 # the pool that maps a lane's first pool row to its state entry
 # (``engines/paged._init_hybrid_pools``)
 STATE_SLOT = "state_slot"
@@ -152,8 +167,16 @@ def mamba_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
     return layers_of(cfg, MAMBA)
 
 
+def retention_layers(cfg: DecoderConfig) -> Tuple[int, ...]:
+    """Indices of the power-retention layers (one state a kv head)."""
+    return layers_of(cfg, RETENTION)
+
+
 def mixer_geometry(cfg: DecoderConfig, kind: str) -> Tuple[int, int, int]:
-    """(query heads, kv heads, head width) of one attention kind."""
+    """(query heads, kv heads, head width) of one attention kind — a
+    state-keeping kind answers for itself: ``linear`` one state a head of
+    its own count and width, ``retention`` one a KV head of the trunk's
+    (``num_heads // num_kv_heads`` query heads read it)."""
     if kind == LINEAR:
         return cfg.linear_heads, cfg.linear_heads, cfg.linear_head_dim
     return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -167,6 +190,18 @@ def ssm_inner(cfg: DecoderConfig) -> int:
 def lane_state_shape(cfg: DecoderConfig) -> Tuple[int, int, int]:
     """What ONE lane holds per linear layer: [heads, d, d], float32."""
     return (cfg.linear_heads, cfg.linear_head_dim, cfg.linear_head_dim)
+
+
+def retention_state_shape(cfg: DecoderConfig) -> Tuple[int, int, int]:
+    """What ONE lane holds per retention layer: [d + 1, kv heads,
+    d (d + 1) / 2] float32 — the value channels and, last, the running
+    sum of weights, by the kv heads, by the degree-2 features of a key.
+    The order the chip rests such an array in whatever its shape says:
+    the kv heads on the sublanes and the features on the lanes are whole
+    (8, 128) tiles (8,256 lanes pad to 8,320; a minor axis of 129 would
+    pad to 256 and a second-minor one to 136)."""
+    d = cfg.head_dim
+    return (d + 1, cfg.num_kv_heads, power_feature_count(d))
 
 
 def lane_state_entries(cfg: DecoderConfig) -> Dict[str, Tuple[tuple, str]]:
@@ -243,6 +278,15 @@ def check_hybrid_config(cfg: DecoderConfig) -> None:
         problems.append(
             "sliding_window (a window layer reads it; the sparse mixer has "
             "its own)")
+    if RETENTION in cfg.mixer_types:
+        if cfg.head_dim % 2:
+            problems.append(
+                "head_dim is odd (a retention layer is rotated, and its "
+                "features pair the channels by their distance)")
+        if cfg.num_kv_heads <= 0 or cfg.num_heads % cfg.num_kv_heads:
+            problems.append(
+                "num_heads is no multiple of num_kv_heads (a retention "
+                "layer keeps one state a kv head)")
     if cfg.quantize_weights and cfg.quant_bits != 8:
         problems.append("quant_bits (this block serves int8 or float)")
     if cfg.num_experts > 1:
@@ -302,6 +346,10 @@ def _attention_schema(kind: str):
             yield ("k_norm_g", "ones", (d,), None)
         if kind == LINEAR and cfg.use_output_norm:
             yield ("o_norm_g", "ones", (heads * d,), None)
+        if kind == RETENTION:
+            # a decay a token and kv head; 8 columns: never quantized
+            # (``models/quant.should_quantize`` goes by name)
+            yield ("w_decay", "normal", (h, kv_heads), h)
         if cfg.use_output_gate:
             yield ("w_ogate", "normal", (h, heads * d), h)
         yield ("wo", "normal", (heads * d, h), heads * d)
@@ -312,8 +360,10 @@ def _attention_schema(kind: str):
 def _attention_branch(params: Params, cfg: DecoderConfig, i: int, kind: str,
                       y, rope, mix):
     """q, k, v with what the file says of their norms (and RoPE for the
-    ``ROTATED`` kinds), ``mix(i, kind, q, k, v) -> (out, taken)``, then the
-    output norm, gate and projection."""
+    ``ROTATED`` kinds), ``mix(i, kind, q, k, v) -> (out, taken)`` — a
+    RETENTION layer's also takes ``log sigmoid`` of its decay projection,
+    float32 [b, s, kv heads] —, then the output norm, gate and
+    projection."""
     p = f"l{i}_"
     b, s, _ = y.shape
     dtype = jnp.dtype(cfg.dtype)
@@ -330,7 +380,12 @@ def _attention_branch(params: Params, cfg: DecoderConfig, i: int, kind: str,
             cos, sin, positions = rope[kind]
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
-    out, taken = mix(i, kind, q, k, v)
+        gates = ()
+        if kind == RETENTION:
+            gates = (jax.nn.log_sigmoid(
+                (y @ params[p + "w_decay"].astype(dtype)).astype(
+                    jnp.float32)),)
+    out, taken = mix(i, kind, q, k, v, *gates)
     with scope("proj"):
         if kind == LINEAR and cfg.use_output_norm:
             out = rms_norm(
@@ -443,6 +498,12 @@ MIXERS: Dict[str, Mixer] = {
         _attention_schema(LINEAR), _nothing,
         lambda cfg: {"s": (lane_state_shape(cfg), "float32")},
         _attention_branch),
+    # no row at all: a stack of these alone has pools of states and the
+    # slot map only (``engines/paged._init_hybrid_pools``)
+    RETENTION: Mixer(
+        _attention_schema(RETENTION), _nothing,
+        lambda cfg: {"s": (retention_state_shape(cfg), "float32")},
+        _attention_branch),
     # the window holds conv INPUTS as the in-projection rounded them (the
     # activation type: nothing is lost); the state is float32
     MAMBA: Mixer(
@@ -545,8 +606,10 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
     [b, s, heads, d], taken)`` — a row-keeping layer writes its rows and
     attends (``taken``: the blocks a SPARSE layer took, int32 [kv heads,
     b, s, topk]; None from every other kind), a linear layer advances its
-    state.  The state-space kind: ``mix(i, kind, u [b, s, inner],
-    project) -> (g [b, s, inner], None)``.
+    state, and so does a retention layer, which is handed its log gates
+    [b, s, kv heads] float32 after ``v``.  The state-space kind:
+    ``mix(i, kind, u [b, s, inner], project) -> (g [b, s, inner],
+    None)``.
 
     ``grouped``: the form of the routed layers' product
     (``models/decoder.kernel_forms``).
@@ -699,9 +762,10 @@ def hybrid_prefill_counts(cfg: DecoderConfig, *, lanes, tokens, dispatches,
                           kernels):
     """One admission round's counters: lane states started from zeros."""
     counts = {"serve_lane_state_resets": lanes}
-    scans = len(mamba_layers(cfg))
+    scans = len(layers_of(cfg, MAMBA, RETENTION))
     if scans:
-        # prompt tokens x the state-space layers that scanned them
+        # prompt tokens x the layers whose chunked scan ran over them
+        # (state-space, retention)
         counts["serve_scan_tokens"] = tokens * scans
     if kernels.scan:
         # over ``serve_prefill_dispatches``: 1.0 where every dispatch's
@@ -715,7 +779,8 @@ def hybrid_prefill_attrs(cfg: DecoderConfig, n_ids: int, n_lanes: int):
     lanes whose state the round started from zeros; where a layer
     SELECTS, the rows of the prompt that selected (all of them once it
     holds ``sparse_dense_len`` tokens, none under it); where a layer
-    SCANS (state-space), the rows its scan ran over; where a layer keeps
+    SCANS (state-space, retention), the rows its scan ran over; where a
+    layer keeps
     a WINDOW, the rows of the prompt its ring kept."""
     out = {"state_lanes": n_lanes}
     if window_layers(cfg):
@@ -723,7 +788,7 @@ def hybrid_prefill_attrs(cfg: DecoderConfig, n_ids: int, n_lanes: int):
     if sparse_layers(cfg):
         selects = n_ids >= cfg.sparse_dense_len
         out["sparse_rows"] = n_ids if selects else 0
-    if mamba_layers(cfg):
+    if layers_of(cfg, MAMBA, RETENTION):
         out["scan_rows"] = n_ids
     return out
 
@@ -793,7 +858,10 @@ def hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
     linear layer's k and v are as wide as its q and go column-parallel
     with it; the few kv heads of a sparse or a plain attention layer are
     replicated (1 or 2 heads do not divide over 4 or 8 devices), as are
-    the per-head norm gains.  The state-space kind along its INNER
+    the per-head norm gains; a retention layer's k, v and decay too,
+    beside a state pool that is whole on every device (its q heads divide;
+    each device advances the whole state and reads it with its own).
+    The state-space kind along its INNER
     channels: ``w_in`` column-parallel over its ``2 x inner`` columns
     (GSPMD re-lays the ``u`` and the ``z`` half along ``inner``), the
     conv's taps and bias, ``w_x``'s input, ``w_dt``'s output, ``b_dt``,
@@ -840,6 +908,8 @@ def hybrid_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
         })
         if kind == LINEAR:
             specs[p + "o_norm_g"] = P(None)
+        if kind == RETENTION:
+            specs[p + "w_decay"] = P(None, None)
     return specs
 
 
@@ -883,6 +953,9 @@ def hybrid_serving(cfg: DecoderConfig) -> BlockServing:
         )
         attrs["experts_held"] = experts_held(cfg)[1]
         chunk_counts = functools.partial(routed_chunk_counts, cfg)
+    if RETENTION in kinds:
+        attrs.update(retention_layers=len(retention_layers(cfg)),
+                     state_bytes_a_lane=lane_state_bytes(cfg))
     if WINDOW in kinds:
         sums["kv_rows_read"] = functools.partial(window_rows_read, cfg)
         attrs.update(window_layers=len(window_layers(cfg)),

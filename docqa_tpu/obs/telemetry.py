@@ -668,6 +668,8 @@ class TelemetrySampler:
                 # length
                 rec("serve_state_bytes_per_lane",
                     float(occ["state_bytes_per_lane"]), now=now)
+                rec("serve_state_pool_bytes",
+                    float(occ["state_pool_bytes"]), now=now)
             if "window_rows_held" in occ:
                 # a block whose window layers hold a RING a lane: the rows
                 # those pools hold for the lanes admitted, of the extent

@@ -2034,3 +2034,186 @@ def latent_decode_attention(q_lat, q_rope, pool, block_tables, lengths, *,
     return attend(
         q_lat, q_rope, pool, block_tables, lengths,
         block_size=block_size, q_offset=q_offset, scale=scale)
+
+
+# --------------------------------------------------------------------------
+# Gated power retention of degree 2 (models/hybrid.py, the ``retention``
+# mixer; arXiv:2507.04239): attention whose weights are SQUARED scores under
+# a gate computed from the token, ``a_tj = ((q_t . k_j) / sqrt(d))^2
+# exp(G_t - G_j)``, normalised by their sum.  A square is a dot product of
+# degree-2 features, ``phi(q) . phi(k) = (q . k)^2``, so the past of a lane
+# is ONE state a kv head — ``sum_j decay phi(k_j) [v_j, 1]^T / d`` — and no
+# row.  XLA: at the END of the file, so that no kernel above moves a line
+# (a Mosaic payload carries source locations).
+# --------------------------------------------------------------------------
+
+RETENTION_EPS = 1e-6  # added to the running sum of weights a row divides by
+
+
+def power_feature_count(d: int) -> int:
+    """Degree-2 features of a ``d``-wide head: the ``d (d + 1) / 2``
+    products ``x_a x_b``, a <= b (8,256 at 128)."""
+    return d * (d + 1) // 2
+
+
+@functools.lru_cache(maxsize=8)
+def _pair_partners(d: int, key_side: bool) -> np.ndarray:
+    """[d, (d / 2 + 1) d] of 0, 1 and 2: column ``(s, a)`` picks channel
+    ``(a + s) mod d`` — the partner of channel ``a`` at circular distance
+    ``s`` — weighed 2 on the key side where the two differ; the last
+    block's upper half (the pairs half the head apart, a second time)
+    picks nothing."""
+    half = d // 2
+    out = np.zeros((d, half + 1, d), np.float32)
+    a = np.arange(d)
+    for s in range(half + 1):
+        live = a if s < half else a[:half]
+        out[(live + s) % d, s, live] = 2.0 if key_side and s else 1.0
+    return out.reshape(d, (half + 1) * d)
+
+
+def power_features(x, *, key_side: bool = False):
+    """``phi(x)`` [..., d (d + 1) / 2] float32 of ``x`` [..., d], d even:
+    the products ``x_a x_b`` of every unordered pair, laid out by their
+    circular distance ``s = (b - a) mod d`` — block ``s`` < d / 2 is ``x *
+    roll(x, -s)`` (d pairs; block 0 the squares), the last block the d / 2
+    pairs half the head apart.  The rotated copies are ONE product with a
+    constant of zeros and ones (exact: a column picks one channel), the
+    features one multiply over it: no gather, no concatenation of 65
+    pieces.  A pair of different channels stands for both orders of the
+    square's expansion: ``key_side`` weighs it by 2 (exact in any type), so
+    ``power_features(q) . power_features(k, key_side=True) == (q . k)^2``
+    — the published map puts ``sqrt(2)`` on either side; the product, and
+    so the function, is the same."""
+    d = x.shape[-1]
+    blocks = d // 2 + 1
+    partners = jnp.einsum(
+        "...c,cf->...f", x, jnp.asarray(_pair_partners(d, key_side), x.dtype),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+    pairs = (x.astype(jnp.float32)[..., None, :]
+             * partners.reshape(*x.shape[:-1], blocks, d))
+    return pairs.reshape(*x.shape[:-1], blocks * d)[
+        ..., :power_feature_count(d)]
+
+
+def power_retention_prefill(q, k, v, log_gate, seg_ids, positions,
+                            state_pool, chunk_slot, *,
+                            precision=None):
+    """Gated power retention over a PACKED batch in its chunked form,
+    ``RAGGED_ALIGN`` rows at a time: inside a chunk the attention form
+    with the chunk's own cumulative log gate, across chunks the expanded
+    state (read by ``phi(q)``, advanced by ``phi(k) [v, 1]^T``).
+
+    q          [T, heads, d]; k, v [T, kv heads, d] — heads a multiple of
+               kv heads, ``heads // kv heads`` query heads read one state;
+               segments start on chunk boundaries (as
+               :func:`linear_attention_prefill`)
+    log_gate   [T, kv heads] float32, ``log sigmoid`` of the gate: <= 0
+    seg_ids, positions [T]; a chunk whose first position is 0 starts from
+               a ZERO state
+    state_pool [n_slots, d + 1, kv heads, d (d + 1) / 2] float32: a lane's
+               state, the value channels (and, last, the running sum of
+               weights) by the kv heads by the features — the order the
+               chip rests it in whatever its shape says (kv heads on the
+               sublanes, features on the lanes: whole tiles)
+    chunk_slot [T / RAGGED_ALIGN] int32: the entry that takes the state as
+               it stands after this chunk (>= n_slots: none)
+    precision  the TESTS' oracle only; no program path sets it.  ``None``,
+               the one form served, rounds the inputs of the two LARGE
+               products (``phi(q) S`` and ``[v, 1]^T phi(k)``) to bfloat16
+               (one MXU pass, sums float32); ``Precision.HIGHEST`` keeps
+               them float32 (six passes), so that a test can hold the
+               chunked algebra to the attention form at 2e-4 and the
+               served rounding to what it costs beside it (PERF.md
+               section 2 has both readings on the chip, on gates that
+               keep hundreds of tokens)
+
+    Returns (out [T, heads, d] in q's type, state_pool).  The state, the
+    gates' sums and every sum of weights are float32; every decay factor
+    is ``exp`` of a difference <= 0 (nothing overflows however strong
+    the gate)."""
+    t, heads, d = q.shape
+    kv_heads = k.shape[1]
+    per = heads // kv_heads
+    c = RAGGED_ALIGN
+    n = t // c
+    f32 = jnp.float32
+    big = f32 if precision is not None else jnp.bfloat16
+    causal = jnp.tril(jnp.ones((c, c), bool))
+    ones = jnp.ones((c, kv_heads, 1), f32)
+
+    def step(carry, xs):
+        state, pool = carry
+        qb, kb, vb, gb, ok, pos0, slot = xs
+        # a segment's first chunk starts from ZERO: what the carried state
+        # weighs is folded into the two factors it meets (no pass over it)
+        kept = jnp.where(pos0 == 0, 0.0, 1.0)
+        qg = qb.reshape(c, kv_heads, per, d)
+        kf = jnp.where(ok[:, None, None], kb, 0).astype(kb.dtype)
+        # G_t - G(chunk start), <= 0 and falling; a padding row adds nothing
+        cum = jnp.cumsum(jnp.where(ok[:, None], gb, 0.0), axis=0)  # [c, g]
+        # inside the chunk: the attention form over its own rows
+        s = jnp.einsum("tgpd,sgd->gpts", qg, kf,
+                       preferred_element_type=f32)
+        rel = cum.T[:, :, None] - cum.T[:, None, :]  # [g, t, s]
+        decay = jnp.where(causal, jnp.exp(jnp.minimum(rel, 0.0)), 0.0)
+        a = s * s * decay[:, None] / d  # [g, p, t, s]
+        vv = jnp.concatenate([vb.astype(f32), ones], axis=-1)  # [c, g, d+1]
+        num = jnp.einsum("gpts,sge->tgpe", a, vv, precision=_HIGHEST)
+        # across chunks: what the state holds, as row t's gate leaves it
+        phi_q = power_features(qg).astype(big)
+        read = jnp.einsum("tgpf,egf->tgpe", phi_q, state.astype(big),
+                          preferred_element_type=f32, precision=precision)
+        num = num + (kept * jnp.exp(cum))[:, :, None, None] * read
+        out = num[..., :d] / (num[..., d:] + RETENTION_EPS)
+        # the state after the chunk's last valid row
+        left = jnp.where(ok[:, None], jnp.exp(cum[-1][None, :] - cum), 0.0)
+        phi_k = power_features(kf, key_side=True).astype(big)
+        state = (kept * jnp.exp(cum[-1]))[None, :, None] * state + jnp.einsum(
+            "tge,tgf->egf", (vv * (left / d)[:, :, None]).astype(big), phi_k,
+            preferred_element_type=f32, precision=precision)
+        # written where the chunk is a segment's last, skipped elsewhere
+        pool = jax.lax.cond(
+            slot < pool.shape[0],
+            lambda p: jax.lax.dynamic_update_slice(
+                p, state[None], (slot, 0, 0, 0)),
+            lambda p: p, pool)
+        return (state, pool), out.reshape(c, heads, d).astype(q.dtype)
+
+    chunks = lambda x: x.reshape(n, c, *x.shape[1:])  # noqa: E731
+    (_, state_pool), out = jax.lax.scan(
+        step,
+        (jnp.zeros(state_pool.shape[1:], f32), state_pool),
+        (chunks(q), chunks(k), chunks(v), chunks(log_gate.astype(f32)),
+         chunks(seg_ids >= 0), chunks(positions)[:, 0], chunk_slot),
+    )
+    return out.reshape(t, heads, d), state_pool
+
+
+def power_retention_step(q, k, v, log_gate, state):
+    """One decode step of the same, the recurrence itself: q [S, heads,
+    d]; k, v [S, kv heads, d]; log_gate [S, kv heads] float32; state [S, d +
+    1, kv heads, d (d + 1) / 2] float32 -> (out [S, heads, d] in q's
+    type, the advanced state ``e^gate S + [v, 1] phi(k)^T / d``).
+
+    One elementwise pass advances the state where it lies and a multiply
+    and sum over the features reads it, float32 throughout.  The step is
+    bound by the state's bytes in either form of the read (0.67 ms a
+    layer-step of four lanes at the published widths against 0.70 as a
+    product on the matmul unit, PERF.md section 6), and this one leaves
+    the pool in the order it rests in: the product wanted the value
+    channels on the sublanes, and a decode chunk then re-laid every
+    layer's pool on entry and on exit."""
+    S, heads, d = q.shape
+    kv_heads = k.shape[1]
+    f32 = jnp.float32
+    vv = jnp.concatenate(
+        [v.astype(f32), jnp.ones((S, kv_heads, 1), f32)], axis=-1)
+    phi_q = power_features(q.reshape(S, kv_heads, heads // kv_heads, d))
+    phi_k = power_features(k, key_side=True)  # [S, g, F]
+    state = (jnp.exp(log_gate.astype(f32))[:, None, :, None] * state
+             + jnp.swapaxes(vv / d, 1, 2)[..., None] * phi_k[:, None])
+    num = jnp.sum(phi_q[:, None] * state[:, :, :, None, :], axis=-1)
+    num = jnp.transpose(num, (0, 2, 3, 1))  # [S, g, p, d + 1]
+    out = num[..., :d] / (num[..., d:] + RETENTION_EPS)
+    return out.reshape(S, heads, d).astype(q.dtype), state

@@ -31,9 +31,11 @@ PR:
                  row, the compressed key, ``state_slot``
     attend       softmax attention over cached or in-flight rows
     select       block scoring and top-k of the sparse mixer
-    state        the linear mixer's scan / step and the state-space
-                 mixer's conv and selective scan / step, the state's (and
-                 the conv window's) gather and scatter included
+    state        the linear mixer's scan / step, the retention mixer's
+                 (its degree-2 features, chunk attention, state read and
+                 update: one scope) and the state-space mixer's conv and
+                 selective scan / step, the state's (and the conv
+                 window's) gather and scatter included
     mlp          the dense SwiGLU with its norm and residual add; shared
                  experts
     route        router scores and the choice of experts

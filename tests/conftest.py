@@ -101,6 +101,19 @@ _PR42_PINS = tuple(
         "test_the_lists_the_cell_joined_and_the_ones_it_did_not",
         "test_the_two_entries_are_the_last_two_behind_pr40s_eight"))
 
+# ---- one pinned number that ISSUE 51 asked to be another (PR 51) -----------
+# tests/benchmark/test_benchmark_schema.py::test_configuration holds EVERY
+# configuration file to ``kv_cache_bits == 16``.  ISSUE 51's configuration
+# states 32: its pools hold float32 state entries and no K / V row, and 32 is
+# what makes ``kv_cache_bits_missing`` hold the state's type exactly (at 16 a
+# bfloat16 state pool would pass).  The file is the benchmark's; until a
+# `benchmark` PR lets a file state the width of its narrowest pool array,
+# tests/benchmark/test_benchmark_brumby.py::
+# test_what_the_schemas_pin_held_still_holds asserts every other line of that
+# test for this configuration.  STRICT, as above.
+_PR51_BITS = ("tests/benchmark/test_benchmark_schema.py::"
+              "test_configuration[brumby-14b-l12-int8]")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -120,6 +133,12 @@ def pytest_collection_modifyitems(items):
                 reason="lists of cells and the per-layer tail pinned by "
                 "equality to PR 42's, outdated by any cell or metric "
                 "appended; for a `benchmark` PR to loosen (PERF.md 7)"))
+        elif item.nodeid == _PR51_BITS:
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="kv_cache_bits pinned to 16 for every file; this "
+                "stack's narrowest pool array is its float32 state (32); "
+                "for a `benchmark` PR to loosen (PERF.md 7)"))
         elif item.nodeid == _PR40_TAIL:
             item.add_marker(pytest.mark.xfail(
                 strict=True,

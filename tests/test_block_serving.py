@@ -157,6 +157,11 @@ REFUSALS = {
         "DecoderConfig(loop_steps=4) is served without "
         "generate.prefix_cache and generate.speculative_k: set prefix_cache "
         "false and speculative_k 0"),
+    "serve_retention": (
+        'DecoderConfig(block="sparse_linear") is served without '
+        "generate.prefix_cache and generate.speculative_k and "
+        "qos.preemption: set prefix_cache false, speculative_k 0 and "
+        "qos.preemption off"),
 }
 # (decode sums, prefill sums, lane state, own parameter rules, solo engine)
 RECORDS = {
@@ -165,6 +170,7 @@ RECORDS = {
     "serve_hybrid": (SPARSE_SUMS, (), True, True, False),
     "serve_ssm": ((), (), True, True, False),
     "serve_loop": ((), (), False, False, False),
+    "serve_retention": ((), (), True, True, False),
 }
 
 
