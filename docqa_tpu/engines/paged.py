@@ -90,11 +90,11 @@ from docqa_tpu.ops.attention import (
     linear_attention_step,
     paged_decode_attention,
     power_retention_prefill,
-    power_retention_step,
     ragged_prefill_attention,
     sparse_decode_attention,
     sparse_prefill_attention,
 )
+from docqa_tpu.ops.retention import retention_decode_step
 from docqa_tpu.ops.scopes import scope
 from docqa_tpu.ops.ssm import (
     causal_conv_prefill,
@@ -1338,10 +1338,13 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
     and its state.  A RETENTION layer's step runs over its pool's ENTRIES
     where they lie — each handed the token of the lane that owns it (the
     inverse of the slot map's answer), an entry no live lane owns keeps
-    what it holds (gate 1, nothing added) — so the 34 MB a lane and layer
-    are neither gathered out of the pool nor scattered back.  A lane whose
-    table starts with a hole (a retired slot) reads zeros and writes
-    nothing.
+    what it holds — so the 34 MB a lane and layer are neither gathered
+    out of the pool nor scattered back: under ``kernels.retention`` one
+    pass of a kernel over the entries a live lane owns
+    (``ops/retention.retention_decode_step``), else the XLA form over
+    every entry (an unowned one under a gate of 1, nothing added).  A
+    lane whose table starts with a hole (a retired slot) reads zeros and
+    writes nothing.
 
     Returns (logits [S, 1, vocab] f32, pools, selection record int32
     [sparse layers x kv heads, S, 1, sparse_topk] — the routing record
@@ -1389,6 +1392,13 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
                 lane_of = jnp.full(
                     (_n_state_entries(pools, cfg),), S, jnp.int32,
                 ).at[slots].set(jnp.arange(S, dtype=jnp.int32), mode="drop")
+                owned = n_owned = None
+                if kernels.retention:
+                    # the entries a live lane owns, first: what the
+                    # step's kernel walks
+                    owned = jnp.argsort(lane_of >= S, stable=True).astype(
+                        jnp.int32)
+                    n_owned = jnp.sum(lane_of < S, dtype=jnp.int32)
     if WINDOW in kinds:
         with scope("cache_write"):
             ring_dest = _ring_rows(pools, cfg, slots, lengths)
@@ -1416,9 +1426,10 @@ def _hybrid_decode_forward(params, cfg, pools, block_tables, tok, lengths,
             return x[:, 0].at[lane_of].get(mode="fill", fill_value=0)
 
         with scope("state"):
-            out, pools[f"s{i}"] = power_retention_step(
+            out, pools[f"s{i}"] = retention_decode_step(
                 of_entries(q), of_entries(k), of_entries(v),
-                of_entries(log_gate), pools[f"s{i}"])
+                of_entries(log_gate), pools[f"s{i}"], owned, n_owned,
+                use_flash=kernels.retention)
             return out.at[slots].get(
                 mode="fill", fill_value=0)[:, None], None
 

@@ -37,6 +37,7 @@ from docqa_tpu.models.hybrid import (
     lane_state_entries,
     layers_of,
     mamba_layers,
+    retention_layers,
     sparse_layers,
 )
 from docqa_tpu.models.latent import (
@@ -56,6 +57,7 @@ from docqa_tpu.ops.attention import (
     ragged_key_block_counts,
 )
 from docqa_tpu.ops.norms import rms_norm
+from docqa_tpu.ops.retention import retention_kernel_supported
 from docqa_tpu.ops.rope import apply_rope, rope_angles
 from docqa_tpu.ops.scopes import scope
 
@@ -292,7 +294,13 @@ def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
       (:func:`packed_attention_layers`), its heads are whole 128-lane
       registers, and NO mesh (the XLA forms are what GSPMD places) — the
       COLD dispatch's attention is ``ops/attention.ragged_flash_prefill``;
-      a warm one (a cached prefix through the block table) stays XLA."""
+      a warm one (a cached prefix through the block table) stays XLA;
+    * ``retention``: a retention layer, NO mesh (the state is replicated
+      there and the XLA form lowers as it stands), and a state of a
+      geometry the kernel's blocks hold (``ops/retention.
+      retention_kernel_supported``) — the layer's decode step advances
+      and reads the entries a live lane owns in ONE pass, in place
+      (``ops/retention.power_retention_step_fused``)."""
     alone = on_tpu and mesh is None
     geometry = (cfg.dtype, cfg.num_kv_heads, cfg.head_dim)
     if is_latent(cfg):
@@ -310,6 +318,9 @@ def kernel_forms(cfg: DecoderConfig, *, on_tpu: bool, mesh,
         grouped=alone and routed_layers(cfg) > 0,
         ragged=alone and len(packed_attention_layers(cfg)) > 0
         and cfg.head_dim % 128 == 0,
+        retention=alone and len(retention_layers(cfg)) > 0
+        and retention_kernel_supported(
+            cfg.num_heads, cfg.num_kv_heads, cfg.head_dim),
     )
 
 

@@ -745,6 +745,10 @@ def hybrid_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
         # over ``serve_decode_chunks``: 1.0 where every chunk's sparse
         # layers read the blocks taken as pages, absent elsewhere
         counts["serve_sparse_paged_chunks"] = 1
+    if kernels.retention:
+        # over ``serve_decode_chunks``: 1.0 where every chunk's retention
+        # layers stepped in the one-pass kernel, absent elsewhere
+        counts["serve_retention_fused_chunks"] = 1
     return counts, samples
 
 

@@ -21,6 +21,9 @@ class KernelForms(NamedTuple):
     scan: bool  # a state-space layer's prefill scan keeps ``h`` on the chip
     grouped: bool  # a routed layer's products are ``megablox.gmm``
     ragged: bool  # the cold packed prefill attends in the flash kernel
+    # a retention layer's decode step is one pass over the owned entries,
+    # in place
+    retention: bool
 
 
 def _no_counts(**_) -> Tuple[Dict[str, int], Dict[str, float]]:

@@ -2196,14 +2196,19 @@ def power_retention_step(q, k, v, log_gate, state):
     1, kv heads, d (d + 1) / 2] float32 -> (out [S, heads, d] in q's
     type, the advanced state ``e^gate S + [v, 1] phi(k)^T / d``).
 
-    One elementwise pass advances the state where it lies and a multiply
-    and sum over the features reads it, float32 throughout.  The step is
-    bound by the state's bytes in either form of the read (0.67 ms a
-    layer-step of four lanes at the published widths against 0.70 as a
-    product on the matmul unit, PERF.md section 6), and this one leaves
-    the pool in the order it rests in: the product wanted the value
-    channels on the sublanes, and a decode chunk then re-laid every
-    layer's pool on entry and on exit."""
+    The XLA form — what a CPU, a mesh and the tests' oracle run; a TPU
+    with no mesh steps the owned entries in ONE pass instead
+    (``ops/retention.power_retention_step_fused``, chosen by
+    ``models/decoder.kernel_forms``).  One elementwise pass advances the
+    state where it lies and a multiply and sum over the features reads it
+    again, float32 throughout: two fusions, the state read twice and
+    written once, over every entry handed in (0.63-0.67 ms a layer-step
+    of four lanes at the published widths whatever the lanes that are
+    live, against 0.70 with the read as a product on the matmul unit;
+    PERF.md section 6, PRs 51 and 52).  It leaves the pool in the order
+    it rests in: the product wanted the value channels on the sublanes,
+    and a decode chunk then re-laid every layer's pool on entry and on
+    exit."""
     S, heads, d = q.shape
     kv_heads = k.shape[1]
     f32 = jnp.float32

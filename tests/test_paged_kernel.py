@@ -543,3 +543,134 @@ class TestCompilesForTheChip:
         assert f"[{lanes},4096,576]" not in hlo
         assert f"[{lanes},4096,1,576]" not in hlo
         assert f"f32[{pool_rows}," not in hlo
+
+    def test_the_retention_step_at_brumbys_widths(self, topo):
+        """ISSUE 52: ``ops/retention.power_retention_step_fused`` inside
+        the decode chunk it serves — 16 steps of ``paged_decode_forward``
+        over Brumby-14B's first 12 layers at the published widths (40 / 8
+        heads x 128, int8 matrices, four lanes' float32 states: 1.65 GB
+        of pools), the forms an engine that saw a TPU hands it — beside
+        the same chunk in the XLA form.  Every pool the program was given
+        is the pool it returns, and the kernel's chunk holds no more
+        beside them than the XLA form's: no copy of a 137 MB pool."""
+        import os
+        import sys
+
+        from jax.experimental.compilation_cache import compilation_cache
+        from jax.sharding import SingleDeviceSharding
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        for p in (root, os.path.join(root, "benchmark")):
+            if p not in sys.path:
+                sys.path.insert(0, p)
+        from harness import arch
+        from harness.child import program_overrides
+
+        from docqa_tpu.config import load_config
+        from docqa_tpu.engines import paged
+        from docqa_tpu.models.decoder import kernel_forms
+        from docqa_tpu.models.quant import init_quantized_decoder_params
+
+        conf = arch.load_cell_config(os.path.join(
+            root, "benchmark", "configs", "brumby-14b-l12-int8.json"))
+        served = load_config(env={}, overrides=program_overrides(conf))
+        cfg, lanes = served.decoder, served.generate.max_concurrent
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def described(tree):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip), tree)
+
+        n_blocks = lanes * -(-cfg.max_seq_len // BS)
+        params = described(jax.eval_shape(
+            lambda: init_quantized_decoder_params(jax.random.key(0), cfg)))
+        pools = described(jax.eval_shape(
+            lambda: paged.init_paged_pools(cfg, n_blocks, BS)))
+        assert pools["s11"].shape == (lanes, 129, 8, 8256) == (4, 129, 8, 8256)
+        pool_bytes = sum(
+            int(np.prod(x.shape)) * x.dtype.itemsize for x in pools.values())
+        tables = described(jnp.zeros((lanes, n_blocks // lanes), jnp.int32))
+        lane = described(jnp.zeros((lanes,), jnp.int32))
+
+        def chunk(forms):
+            def program(weights, held, table, tok, lengths):
+                def step(t, carry):
+                    held, tok, lengths = carry
+                    logits, held = paged.paged_decode_forward(
+                        weights, cfg, held, table, tok[:, None], lengths,
+                        block_size=BS, rope_len=cfg.max_seq_len,
+                        kernels=forms)
+                    return (held, jnp.argmax(logits[:, 0], -1).astype(
+                        jnp.int32), lengths + 1)
+
+                return jax.lax.fori_loop(
+                    0, served.generate.decode_chunk, step,
+                    (held, tok, lengths))
+
+            return jax.jit(program, donate_argnums=(1,)).lower(
+                params, pools, tables, lane, lane).compile()
+
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            fused = kernel_forms(cfg, on_tpu=True, mesh=None, block_size=BS)
+            assert fused.retention
+            compiled = {form: chunk(forms) for form, forms in (
+                ("kernel", fused), ("xla", fused._replace(retention=False)))}
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+        hlo = compiled["kernel"].as_text()
+        assert "_retention_step_kernel" in hlo
+        assert "_retention_step_kernel" not in compiled["xla"].as_text()
+        # no pool is copied, re-laid or widened on its way to the kernel
+        assert not [
+            line for line in hlo.splitlines()
+            if " = f32[4,129,8,8256]" in line and (
+                " copy(" in line or " transpose(" in line)]
+        memory = {k: c.memory_analysis() for k, c in compiled.items()}
+        for m in memory.values():
+            # as the chip rests them: 8,256 features in 65 registers
+            assert 1.6e9 < pool_bytes <= m.alias_size_in_bytes
+            assert m.alias_size_in_bytes < 1.01 * pool_bytes
+        assert (memory["kernel"].temp_size_in_bytes
+                <= memory["xla"].temp_size_in_bytes + 50e6)
+        assert memory["kernel"].temp_size_in_bytes < 0.6e9
+
+    @pytest.mark.parametrize("heads, kv_heads, head_dim", [
+        (8, 8, 128), (8, 2, 128), (4, 1, 128), (32, 16, 128), (16, 8, 256),
+    ], ids=lambda x: str(x))
+    def test_the_retention_step_at_the_geometries_it_admits(
+            self, topo, heads, kv_heads, head_dim):
+        """What ``retention_kernel_supported`` answers for beside Brumby's
+        40 / 8 x 128 (above, in its program): one query head a kv head,
+        kv heads short of a register's eight sublanes and past them, a
+        head of two registers (257 value rows, a prime: a row a block,
+        and features that end on a whole column) — the op alone, lowered
+        by Mosaic for the described chip."""
+        from jax.sharding import SingleDeviceSharding
+
+        from docqa_tpu.ops import retention
+
+        assert retention.retention_kernel_supported(heads, kv_heads, head_dim)
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        features = head_dim * (head_dim + 1) // 2
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        entries = 2
+        pool = arg((entries, head_dim + 1, kv_heads, features), jnp.float32)
+        compiled = jax.jit(
+            retention.power_retention_step_fused, donate_argnums=(4,),
+        ).lower(
+            arg((entries, heads, head_dim), jnp.bfloat16),
+            arg((entries, kv_heads, head_dim), jnp.bfloat16),
+            arg((entries, kv_heads, head_dim), jnp.bfloat16),
+            arg((entries, kv_heads), jnp.float32), pool,
+            arg((entries,), jnp.int32), arg((), jnp.int32)).compile()
+        assert "_retention_step_kernel" in compiled.as_text()
+        # the pool is the pool it returns
+        pool_bytes = compiled.memory_analysis().alias_size_in_bytes
+        assert pool_bytes >= entries * (head_dim + 1) * kv_heads * features * 4

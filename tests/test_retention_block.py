@@ -50,6 +50,7 @@ from docqa_tpu.models.decoder import (  # noqa: E402
     lane_state_shapes,
 )
 from docqa_tpu.models.quant import should_quantize  # noqa: E402
+from docqa_tpu.models.serving import KernelForms  # noqa: E402
 from harness import arch  # noqa: E402
 from harness.child import program_overrides  # noqa: E402
 
@@ -80,11 +81,12 @@ def tokens():
 
 
 def run_program(cfg, params, tokens, lengths, steps, starts=None,
-                slot_of=None):
+                slot_of=None, kernels=None):
     """Prefill ``lengths[b]`` tokens of lane b in ONE packed dispatch (lane
     b from packed row ``starts[b]``), then ``steps`` teacher-forced decode
     steps: (logits [lanes, 1 + steps, vocab], pools).  ``slot_of``: the
-    state entry each lane is given (default: its own index)."""
+    state entry each lane is given (default: its own index); ``kernels``:
+    the forms the decode steps run (default: what a CPU is given, XLA)."""
     lanes = len(lengths)
     n_blocks = lanes * CAP // BS
     pools = paged.init_paged_pools(cfg, n_blocks, BS)
@@ -116,7 +118,7 @@ def run_program(cfg, params, tokens, lengths, steps, starts=None,
         tok = np.stack([tokens[b, lens[b]:lens[b] + 1] for b in range(lanes)])
         out = paged.paged_decode_forward(
             params, cfg, pools, tables, jnp.asarray(tok), jnp.asarray(lens),
-            block_size=BS, rope_len=CAP)
+            block_size=BS, rope_len=CAP, kernels=kernels)
         assert len(out) == 2
         got.append(np.asarray(out[0]))
         pools = out[1]
@@ -311,9 +313,47 @@ def served(params, tokens):
     return run_program(TOY, params, tokens, LENGTHS, STEPS)
 
 
+# the step's kernel (``ops/retention.py``, ISSUE 52) and nothing else:
+# what an engine that saw a TPU hands a stack whose head is whole
+# registers (TOY's 16-wide one runs the kernel interpreted)
+FUSED = KernelForms(*(f == "retention" for f in KernelForms._fields))
+FORMS = pytest.mark.parametrize("form", ["xla", "kernel"])
+
+
+@pytest.fixture
+def retention_kernel_interpreted(monkeypatch):
+    """``power_retention_step_fused`` as the decode step calls it under
+    ``kernels.retention``, interpreted: the one thing a CPU cannot take
+    from it."""
+    retention = importlib.import_module("docqa_tpu.ops.retention")
+    real = retention.power_retention_step_fused
+    monkeypatch.setattr(
+        retention, "power_retention_step_fused",
+        lambda *args, **kw: real(*args, **{**kw, "interpret": True}))
+
+
+@pytest.fixture
+def served_by(params, tokens, served, retention_kernel_interpreted):
+    """``served`` as the form named decodes it: the kernel's steps start
+    from the same prefill."""
+    def by(form):
+        if form == "xla":
+            return served
+        return run_program(TOY, params, tokens, LENGTHS, STEPS, kernels=FUSED)
+
+    return by
+
+
+@FORMS
 def test_paged_prefill_then_decode_agree_with_the_reference(
-        params, tokens, served):
-    got, _ = served
+        form, params, tokens, served, served_by):
+    got, pools = served_by(form)
+    # one function in two forms: the kernel's steps give the XLA form's
+    # logits and leave its states, to the order of a float32 sum
+    assert np.abs(got - served[0]).max() < 1e-4
+    for name in lane_state_shapes(TOY):
+        a, b = np.asarray(pools[name]), np.asarray(served[1][name])
+        assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max(), name
     want = reference(TOY, params, tokens, LENGTHS, STEPS)
     assert got.shape == want.shape == (2, 1 + STEPS, 256)
     # the prefill's two large products take bfloat16 inputs (sums float32)
@@ -417,26 +457,48 @@ def test_a_prefill_of_n_plus_1_equals_a_prefill_of_n_and_one_step(
             assert np.abs(a - b).max() < 0.02 * np.abs(b).max(), name
 
 
-def test_a_retired_lane_reads_zeros_and_writes_nothing(params, tokens, served):
+@FORMS
+def test_a_retired_lane_reads_zeros_and_writes_nothing(
+        form, params, tokens, served, retention_kernel_interpreted):
+    """Both lanes retired: no entry is owned, and the kernel's one grid
+    step hands its block back as it came.  One of two: the other lane's
+    step is the step it takes beside a live neighbour."""
     _, pools = served
+    kernels = FUSED if form == "kernel" else None
     before = {k: np.asarray(v) for k, v in pools.items()}
-    holes = jnp.full((2, CAP // BS), 2 * CAP // BS, jnp.int32)
+    n_pages = CAP // BS
+    holes = jnp.full((2, n_pages), 2 * n_pages, jnp.int32)
+    args = (jnp.asarray(tokens[:, :1]), jnp.asarray([60, 40]))
     _, after = paged.paged_decode_forward(
-        params, TOY, dict(pools), holes, jnp.asarray(tokens[:, :1]),
-        jnp.asarray([60, 40]), block_size=BS, rope_len=CAP)
+        params, TOY, dict(pools), holes, *args, block_size=BS, rope_len=CAP,
+        kernels=kernels)
     for name, value in after.items():
         assert (np.asarray(value) == before[name]).all(), name
+    tables = jnp.arange(2 * n_pages, dtype=jnp.int32).reshape(2, -1)
+    logits, both = paged.paged_decode_forward(
+        params, TOY, dict(pools), tables, *args, block_size=BS,
+        rope_len=CAP, kernels=kernels)
+    half, one = paged.paged_decode_forward(
+        params, TOY, dict(pools), tables.at[0].set(holes[0]), *args,
+        block_size=BS, rope_len=CAP, kernels=kernels)
+    assert np.abs(np.asarray(half)[1] - np.asarray(logits)[1]).max() < 1e-5
+    for name in lane_state_shapes(TOY):
+        assert (np.asarray(one[name])[0] == before[name][0]).all(), name
+        assert (np.asarray(one[name])[1] == np.asarray(both[name])[1]).all()
+        assert (np.asarray(one[name])[1] != before[name][1]).any(), name
 
 
+@FORMS
 def test_a_lane_given_another_entry_finds_it_through_the_slot_map(
-        params, tokens, served):
+        form, params, tokens, served_by):
     """The lanes' entries swapped in ``state_slot``: the same logits, the
     states in each other's entries — the decode step runs over the pool's
     entries where they lie and hands each the token of the lane that owns
-    it."""
-    got, pools = served
+    it.  Each form against itself: the same sums in the same order."""
+    got, pools = served_by(form)
     swapped, pools_s = run_program(
-        TOY, params, tokens, LENGTHS, STEPS, slot_of=[1, 0])
+        TOY, params, tokens, LENGTHS, STEPS, slot_of=[1, 0],
+        kernels=FUSED if form == "kernel" else None)
     assert np.abs(swapped - got).max() < 1e-5
     for name in lane_state_shapes(TOY):
         assert np.abs(np.asarray(pools_s[name])[::-1]
@@ -476,16 +538,25 @@ def test_the_record_of_the_stack_counts_the_kind():
     assert block.span_attrs == {
         "retention_layers": 4, "state_bytes_a_lane": state}
     assert block.occupancy == {"state_bytes_per_lane": state}
-    forms = kernel_forms(TOY, on_tpu=True, mesh=None, block_size=16)
-    assert not any(forms)  # XLA throughout: no kernel reads this kind yet
+    # the decode step's kernel (ISSUE 52) and nothing else — the prefill's
+    # scan is XLA on every backend —, and not that at a head of 16
+    forms = FUSED
+    wide = kernel_forms(dataclasses.replace(TOY, head_dim=128), on_tpu=True,
+                        mesh=None, block_size=16)
+    assert wide._replace(paged=False) == forms  # no layer asks ``paged``
+    assert not any(kernel_forms(TOY, on_tpu=True, mesh=None, block_size=16))
     assert block.prefill_counts(
         lanes=2, tokens=300, dispatches=2, kernels=forms) == {
         "serve_lane_state_resets": 2, "serve_scan_tokens": 4 * 300}
     assert block.prefill_attrs(100, 2) == {"state_lanes": 2, "scan_rows": 100}
-    counts, samples = block.chunk_counts(lane_steps=8, row=None, kernels=forms)
+    xla = kernel_forms(TOY, on_tpu=False, mesh=None, block_size=16)
+    assert not any(xla)
+    counts, samples = block.chunk_counts(lane_steps=8, row=None, kernels=xla)
     assert counts == {"serve_state_lane_steps": 8,
                       "serve_state_bytes_rw": 2 * state * 8}
     assert samples == {}
+    assert block.chunk_counts(lane_steps=8, row=None, kernels=forms) == (
+        {**counts, "serve_retention_fused_chunks": 1}, {})
     specs = block.param_pspecs("model")
     assert tuple(specs["l0_wq"]) == (None, "model")
     assert tuple(specs["l0_wk"]) == tuple(specs["l0_w_decay"]) == (None, None)

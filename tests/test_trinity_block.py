@@ -66,7 +66,7 @@ TOY = DecoderConfig(
     router_bias=True, router_norm=True,
 )
 RING = hybrid.ring_pages(TOY, BS)
-XLA = KernelForms(False, False, False, False, False)
+XLA = KernelForms(False, False, False, False, False, False)
 
 
 @pytest.fixture(scope="module")
@@ -407,7 +407,7 @@ def test_the_counters_arithmetic_under_the_kernel():
     a global layer the live pages; the pools hold a ring a lane."""
     cfg = dataclasses.replace(TOY, sliding_window=2048, max_seq_len=9728)
     lens = np.asarray([[9200, 9201], [100, 101]])
-    paged_forms = KernelForms(True, False, False, True, True)
+    paged_forms = KernelForms(True, False, False, True, True, False)
     mean, counts = hybrid.window_rows_read(
         cfg, lens, kernels=paged_forms, block_size=16, table_rows=4 * 9728)
     first = (9200 - 2048) // 512 * 512
