@@ -111,7 +111,7 @@ from docqa_tpu.ops.attention import (
 )
 from docqa_tpu.ops.norms import rms_norm
 from docqa_tpu.ops.rope import apply_rope, rope_angles
-from docqa_tpu.ops.scopes import scope
+from docqa_tpu.ops.scopes import layer_kind, scope
 
 Params = Dict[str, jax.Array]
 
@@ -634,31 +634,37 @@ def hybrid_layer_stack(params: Params, cfg: DecoderConfig, ids, positions,
     record = []
     for i, kind in enumerate(cfg.mixer_types):
         p = f"l{i}_"
-        with scope("proj"):
-            y = rms_norm(x, params[p + "attn_norm_g"], eps)
-        branch, taken = MIXERS[kind].branch(
-            params, cfg, i, kind, y, rope, mix)
-        if kind == SPARSE:
-            record.append(taken)
-        with scope("proj"):
-            if cfg.sandwich_norm:
-                branch = rms_norm(branch, params[p + "attn_post_norm_g"], eps)
-            x = _residual(x, r, branch)
-        with scope("mlp"):
-            y = rms_norm(x, params[p + "mlp_norm_g"], eps)
-            if layer_routes(cfg, i):
-                ff, taken = _routed_tiled(y, params, cfg, i, grouped)
+        # each half under its KIND, the phase scopes inside it: a trace
+        # reads the step by (kind, phase, op)
+        with layer_kind(kind):
+            with scope("proj"):
+                y = rms_norm(x, params[p + "attn_norm_g"], eps)
+            branch, taken = MIXERS[kind].branch(
+                params, cfg, i, kind, y, rope, mix)
+            if kind == SPARSE:
                 record.append(taken)
-            else:
-                ff = _swiglu_tiled(y, params, p, dtype)
-            if cfg.sandwich_norm:
-                ff = rms_norm(ff, params[p + "mlp_post_norm_g"], eps)
-            x = _residual(x, r, ff)
-        # the stream is rounded HERE: without the barrier XLA carries it
-        # in excess precision and re-sums every earlier layer's branch
-        # where it needs it, which keeps them all alive (3.4 GB at 9.7k
-        # rows x 32 layers)
-        x = jax.lax.optimization_barrier(x)
+            with scope("proj"):
+                if cfg.sandwich_norm:
+                    branch = rms_norm(
+                        branch, params[p + "attn_post_norm_g"], eps)
+                x = _residual(x, r, branch)
+        routes = layer_routes(cfg, i)
+        with layer_kind("routed" if routes else "dense"):
+            with scope("mlp"):
+                y = rms_norm(x, params[p + "mlp_norm_g"], eps)
+                if routes:
+                    ff, taken = _routed_tiled(y, params, cfg, i, grouped)
+                    record.append(taken)
+                else:
+                    ff = _swiglu_tiled(y, params, p, dtype)
+                if cfg.sandwich_norm:
+                    ff = rms_norm(ff, params[p + "mlp_post_norm_g"], eps)
+                x = _residual(x, r, ff)
+            # the stream is rounded HERE: without the barrier XLA carries
+            # it in excess precision and re-sums every earlier layer's
+            # branch where it needs it, which keeps them all alive (3.4 GB
+            # at 9.7k rows x 32 layers)
+            x = jax.lax.optimization_barrier(x)
     if not record:
         return x, None
     return x, (jnp.stack(record) if routed_layers(cfg)

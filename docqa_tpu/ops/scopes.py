@@ -15,9 +15,10 @@ runs when no trace is open.  JAX's persistent compile cache leaves
 metadata out of its key, so an executable cached BEFORE a scope was
 opened is found again without it: empty that cache to see a new scope.
 
-A scope names a PHASE, never a layer or a pass (the trunks are unrolled in
-Python: the 32 copies of a phase share its scope, and so do the passes of
-a looped trunk).  The vocabulary is closed — a
+A scope names a phase; the layer's KIND is the second axis
+(:func:`layer_kind`, below), never its index or pass (the trunks are
+unrolled in Python: the 32 copies of a phase share its scope, and so do
+the passes of a looped trunk).  The vocabulary is closed — a
 new trunk or kernel opens one of these, or adds its own to the tuple,
 to the table in ``docs/OBSERVABILITY.md`` and to PERF.md §3 in the same
 PR:
@@ -46,6 +47,22 @@ PR:
                  exit gate, once a threshold under 1 evaluates it)
     sample       sampling, token and length bookkeeping, the chunk's
                  result array with its MoE / sparse sums
+
+The second axis, the LAYER KIND (PR 53): the stack of several mixer kinds
+(``models/hybrid.py``) opens ``jax.named_scope("dk.<kind>")`` around each
+half of a layer, OUTSIDE the phase scopes — an op's name stack reads
+``…/dk.window/dq.attend/…`` —, so the 24 ``window`` and the 8 global
+``attention`` layers of one stack no longer share one ``attend``.
+Another prefix than ``dq.`` on purpose: ``xplane_scopes.py`` takes the
+innermost ``dq.<name>`` of an op, and an op under a kind and under no
+phase (a layout copy) must keep reading ``-`` there.
+``benchmark/harness/xplane_kinds.py`` sums a program's device time by
+(kind, scope, op).  Closed too: the keys of ``hybrid.MIXERS`` (the one
+list of mixer kinds; not repeated here) for a layer's mixer half —
+``attn_norm``, the kind's branch with the engine's ``mix``, post-norm and
+residual add — and ``dense`` | ``routed`` for its feed-forward half.  The
+rope tables, ``embed``, ``head`` and ``sample`` lie outside any kind; the
+GQA and the latent trunk have one mixer kind each and open none.
 """
 
 from __future__ import annotations
@@ -64,6 +81,8 @@ DEVICE_SCOPES = (
 # (PERF.md section 7); until then the by-scope table shows it and no
 # declared metric sums it.  ``scope()`` takes a name of either.
 LOOP_SCOPES = ("loop_close",)
+KIND_PREFIX = "dk."
+FFN_KINDS = ("dense", "routed")
 
 
 def scope(name: str):
@@ -75,3 +94,17 @@ def scope(name: str):
             f"{DEVICE_SCOPES + LOOP_SCOPES} (docqa_tpu/ops/scopes.py)"
         )
     return jax.named_scope(PREFIX + name)
+
+
+def layer_kind(name: str):
+    """``jax.named_scope("dk." + name)``; a name that is neither a key
+    of ``hybrid.MIXERS`` nor one of ``FFN_KINDS`` is refused."""
+    # at the call (trace time only): that module imports this one
+    from docqa_tpu.models.hybrid import MIXERS
+
+    if name not in MIXERS and name not in FFN_KINDS:
+        raise ValueError(
+            f"{name!r} is no layer kind: one of "
+            f"{tuple(MIXERS) + FFN_KINDS} (docqa_tpu/ops/scopes.py)"
+        )
+    return jax.named_scope(KIND_PREFIX + name)

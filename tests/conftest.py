@@ -114,6 +114,20 @@ _PR42_PINS = tuple(
 _PR51_BITS = ("tests/benchmark/test_benchmark_schema.py::"
               "test_configuration[brumby-14b-l12-int8]")
 
+# ---- one set of metrics held by equality, where the file means membership
+# (PR 53) ---------------------------------------------------------------------
+# tests/benchmark/test_benchmark_ouro.py::
+# test_what_pr42s_two_pins_held_still_holds pins the per-layer lists "by
+# prefix and membership only" but for ONE line: the set of metrics whose
+# ``workloads`` name record_closed4_jamba2 is held EQUAL to what PR 44 found.
+# ISSUE 53's table lists that cell under eight of its twelve metrics, appended
+# at the end as the driver wants them.  The file is the benchmark's; until a
+# `benchmark` PR makes that line a subset, tests/benchmark/
+# test_benchmark_kinds.py::test_what_pr44s_pin_held_still_holds asserts every
+# other line of it, and that one as a subset.  STRICT, as above.
+_PR44_JOINED = ("tests/benchmark/test_benchmark_ouro.py::"
+                "test_what_pr42s_two_pins_held_still_holds")
+
 
 def pytest_collection_modifyitems(items):
     for item in items:
@@ -139,6 +153,12 @@ def pytest_collection_modifyitems(items):
                 reason="kv_cache_bits pinned to 16 for every file; this "
                 "stack's narrowest pool array is its float32 state (32); "
                 "for a `benchmark` PR to loosen (PERF.md 7)"))
+        elif item.nodeid == _PR44_JOINED:
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="the set of metrics that list record_closed4_jamba2 "
+                "pinned by equality, outdated by any metric that lists the "
+                "cell; for a `benchmark` PR to loosen (PERF.md 7)"))
         elif item.nodeid == _PR40_TAIL:
             item.add_marker(pytest.mark.xfail(
                 strict=True,

@@ -1,9 +1,11 @@
-"""ISSUE 40: the device scopes (``docqa_tpu/ops/scopes.py``) — a closed
+"""ISSUE 40 (and, at the end, ISSUE 53: the second axis, the layer
+kind): the device scopes (``docqa_tpu/ops/scopes.py``) — a closed
 vocabulary, opened where the work is in all three trunks, and HLO
 metadata only.  Per block kind, at toy sizes on the CPU: the compiled
 text of the batcher's prefill AND decode program holds every scope the
 issue's table gives that block, and no ``dq.`` name outside the tuple."""
 
+import contextlib
 import dataclasses
 import os
 import re
@@ -19,7 +21,15 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from docqa_tpu.config import DecoderConfig, GenerateConfig  # noqa: E402
 from docqa_tpu.engines import paged  # noqa: E402
 from docqa_tpu.engines.generate import GenerateEngine  # noqa: E402
-from docqa_tpu.ops.scopes import DEVICE_SCOPES, PREFIX, scope  # noqa: E402
+from docqa_tpu.models import hybrid  # noqa: E402
+from docqa_tpu.ops.scopes import (  # noqa: E402
+    DEVICE_SCOPES,
+    FFN_KINDS,
+    KIND_PREFIX,
+    PREFIX,
+    layer_kind,
+    scope,
+)
 from harness import arch  # noqa: E402
 
 COMMON = {"embed", "proj", "cache_write", "attend", "mlp", "head", "sample"}
@@ -49,13 +59,11 @@ BLOCKS = {
 }
 
 
-@pytest.fixture(scope="module", params=sorted(BLOCKS))
-def compiled(request):
-    """(block, {"prefill" | "decode": compiled HLO text}) of a toy
-    batcher's two programs."""
+def lowered_programs(cfg, package=None):
+    """{"prefill" | "decode": ``Lowered``} of a toy batcher's two
+    programs, traced now."""
     from docqa_tpu.engines.serve import ContinuousBatcher
 
-    package, _scopes, cfg = BLOCKS[request.param]
     sds, i32 = jax.ShapeDtypeStruct, jnp.int32
     gen = dataclasses.replace(
         GenerateConfig(), speculative_k=0, prefix_cache=False,
@@ -76,15 +84,24 @@ def compiled(request):
         lane, flag = sds((4,), i32), sds((4,), jnp.bool_)
         packed = (sds((256,), i32),) * 4 + (lane,) * 2
         tables = sds((4, b.blocks_per_seq), i32)
-        return request.param, {
+        return {
             "prefill": b._get_prefill_fn().lower(
-                params, pools, *packed, rng).compile().as_text(),
+                params, pools, *packed, rng),
             "decode": b._get_decode_fn().lower(
-                params, pools, tables, lane, lane, lane, flag, rng
-            ).compile().as_text(),
+                params, pools, tables, lane, lane, lane, flag, rng),
         }
     finally:
         b.stop()
+
+
+@pytest.fixture(scope="module", params=sorted(BLOCKS))
+def compiled(request):
+    """(block, {"prefill" | "decode": compiled HLO text}) of a toy
+    batcher's two programs."""
+    package, _scopes, cfg = BLOCKS[request.param]
+    return request.param, {
+        program: lowered.compile().as_text()
+        for program, lowered in lowered_programs(cfg, package).items()}
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
@@ -146,3 +163,150 @@ def test_every_scope_is_in_the_documents(document):
         text = f.read()
     at = text.index("Device scopes")
     assert [n for n in DEVICE_SCOPES if f"`{n}`" not in text[at:]] == []
+
+
+# ---- ISSUE 53: the layer KIND, the second axis ------------------------------
+
+KINDS = tuple(hybrid.MIXERS) + FFN_KINDS
+FFN_PHASES = {"mlp", "route", "experts"}
+# the two stacks whose cells read the step by kind, at toy widths: window
+# + global attention over a dense and two routed layers (Trinity), and
+# state-space + attention over dense layers (Jamba2)
+STACKS = {
+    "window_attention_routed": (
+        {"window", "attention", "dense", "routed"}, DecoderConfig(
+            vocab_size=256, hidden_dim=64, num_layers=3, num_heads=4,
+            num_kv_heads=2, head_dim=16, mlp_dim=128, max_seq_len=256,
+            norm_eps=1e-5, block="sparse_linear", dtype="float32",
+            mixer_types=("window", "attention", "window"),
+            sliding_window=48, qk_norm=True, use_output_gate=True,
+            use_output_norm=False, sandwich_norm=True, scale_emb=8.0,
+            first_dense_layers=1, num_experts=16, experts_held=4,
+            experts_held_start=4, experts_per_token=4, expert_dim=32,
+            num_shared_experts=1, routed_scale=2.826,
+            router_score="sigmoid", router_bias=True, router_norm=True)),
+    "mamba_attention": (
+        {"mamba", "attention", "dense"}, DecoderConfig(
+            vocab_size=256, hidden_dim=64, num_layers=2, num_heads=4,
+            num_kv_heads=1, head_dim=16, mlp_dim=128, max_seq_len=256,
+            norm_eps=1e-6, block="sparse_linear", dtype="float32",
+            mixer_types=("mamba", "attention"), qk_norm=False,
+            use_output_gate=False, use_output_norm=False,
+            tie_embeddings=True, ssm_state_dim=8, ssm_conv_width=4,
+            ssm_dt_rank=8, ssm_expand=2)),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(STACKS))
+def stack(request):
+    """(the stack's kinds, {program: (lowered text, lowered text with
+    ``layer_kind`` a no-op, the ``op_name``s of the compiled text)})."""
+    kinds, cfg = STACKS[request.param]
+    real = lowered_programs(cfg)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hybrid, "layer_kind",
+                      lambda name: contextlib.nullcontext())
+        bare = lowered_programs(cfg)
+    return kinds, {
+        program: (
+            real[program].as_text(), bare[program].as_text(),
+            # XLA joins the names of ops it merged with ";"
+            [part for name in re.findall(
+                r'op_name="([^"]*)"', real[program].compile().as_text())
+             for part in name.split(";")])
+        for program in real
+    }
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_a_kind_scope_is_metadata_only(stack, program):
+    """The lowered text of a served program is the parent's, byte for
+    byte: what the compile cache keys and what the chip runs."""
+    _kinds, programs = stack
+    real, bare, names = programs[program]
+    assert real == bare
+    assert KIND_PREFIX not in real
+    assert any(KIND_PREFIX in n for n in names)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_a_layers_ops_carry_one_kind_and_the_phase_inside_it(stack, program):
+    kinds, programs = stack
+    names = programs[program][2]
+    found = {}
+    for name in names:
+        kind = re.findall(r"dk\.([a-z_]+)", name)
+        phase = re.findall(r"dq\.([a-z_]+)", name)
+        assert len(kind) <= 1, name
+        if kind and phase:
+            assert name.index(KIND_PREFIX) < name.index(PREFIX), name
+        if phase and phase[-1] in FFN_PHASES | {"attend", "select"}:
+            assert kind, name  # a layer's body: never outside a kind
+        if phase and phase[-1] in {"embed", "head", "sample"}:
+            assert not kind, name
+        if kind:
+            found.setdefault(kind[0], set()).update(phase[-1:])
+    assert set(found) == kinds
+    for kind, phases in found.items():
+        if kind in FFN_KINDS:
+            assert phases <= FFN_PHASES, (kind, phases)
+        else:  # a mixer half: its projections and what the engine's
+            # ``mix`` closure does
+            assert "proj" in phases and not phases & FFN_PHASES, (
+                kind, phases)
+    assert found["dense"] == {"mlp"}
+    if "routed" in kinds:
+        assert found["routed"] == FFN_PHASES
+        assert found["window"] == found["attention"] == {
+            "proj", "cache_write", "attend"}
+    if "mamba" in kinds:
+        assert found["mamba"] == {"proj", "state"}
+    if program == "decode":  # inside the chunk's loop (XLA may hoist a
+        # constant of the weights, a state-space layer's ``-exp(A_log)``)
+        # (a reducer's own ops carry the tail of the name alone)
+        assert all("/while/body/" in n for n in names if KIND_PREFIX in n
+                   and PREFIX in n and n.startswith("jit("))
+
+
+def test_the_kinds_are_the_mixers_and_the_two_feed_forwards():
+    assert KINDS == ("sparse", "attention", "window", "linear", "retention",
+                     "mamba", "dense", "routed")
+    assert KIND_PREFIX == "dk." and KIND_PREFIX != PREFIX
+    assert set().union(*(k for k, _c in STACKS.values())) <= set(KINDS)
+    assert not set(KINDS) & set(DEVICE_SCOPES)
+    # ``benchmark/harness/xplane_kinds.py`` reads ``dk\.([a-z_]+)``
+    assert all(re.fullmatch(r"[a-z_]+", k) for k in KINDS)
+    # one list of mixer kinds: ``scopes.py`` names none of them in code
+    with open(os.path.join(ROOT, "docqa_tpu", "ops", "scopes.py"),
+              encoding="utf-8") as f:
+        code = f.read().split('"""', 2)[2]
+    assert [k for k in hybrid.MIXERS if f'"{k}"' in code] == []
+
+
+@pytest.mark.parametrize("name", [
+    "x", "", "Window", "dk.window", "layer0", "mlp", "attend", "global"])
+def test_a_kind_outside_the_vocabulary_is_refused(name):
+    with pytest.raises(ValueError, match="no layer kind"):
+        layer_kind(name)
+
+
+@pytest.mark.parametrize("name", KINDS)
+def test_a_kind_encloses_a_phase(name):
+    def step(x):
+        with layer_kind(name):
+            with scope("proj"):
+                return jnp.tanh(x @ x)
+
+    text = jax.jit(step).lower(
+        jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile().as_text()
+    assert f"/{KIND_PREFIX}{name}/{PREFIX}proj/" in text
+
+
+@pytest.mark.parametrize("document", ["PERF.md", "docs/OBSERVABILITY.md"])
+def test_every_kind_is_in_the_documents(document):
+    """PERF.md 3's row of kind scopes (beside the metric that reads
+    each) and the operator's second axis, in the same PR as a kind."""
+    with open(os.path.join(ROOT, document), encoding="utf-8") as f:
+        text = f.read()
+    at = text.index("Device scopes")
+    assert [k for k in KINDS if f"`{KIND_PREFIX}{k}`" not in text[at:]] == []
