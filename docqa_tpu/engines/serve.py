@@ -3036,7 +3036,7 @@ class ContinuousBatcher:
         # from the positions the lanes advanced, which the host holds
         counts, samples = self._block.chunk_counts(
             lane_steps=sum(adv for _, adv in lanes), row=sums_row,
-            kernels=self._kernels,
+            kernels=self._kernels, n_lanes=self.n_slots,
         )
         for name, amount in counts.items():
             DEFAULT_REGISTRY.counter(name).inc(amount)

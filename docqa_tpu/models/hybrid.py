@@ -98,6 +98,7 @@ from docqa_tpu.models.routed import (
     moe_chunk_counts,
     moe_prefill_sums,
     moe_step_sums,
+    routed_fused_counts,
     routed_layers,
     routed_mlp,
     routed_param_schema,
@@ -724,7 +725,8 @@ def sparse_step_sums(cfg: DecoderConfig, record, lengths, active):
     ]).astype(jnp.int32)
 
 
-def hybrid_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
+def hybrid_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels,
+                        **_):
     """One fetched chunk's counters and samples: its ``SPARSE_SUMS`` row
     where a layer selects, else the lane-steps the host holds."""
     counts, samples = {}, {}
@@ -758,14 +760,17 @@ def hybrid_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
     return counts, samples
 
 
-def routed_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels):
+def routed_chunk_counts(cfg: DecoderConfig, *, lane_steps, row, kernels,
+                        n_lanes):
     """The same of a stack that ROUTES: its row holds the expert-choice
-    sums (``models/routed.moe_chunk_counts``), the lane-steps are the
-    host's."""
+    sums (``models/routed.moe_chunk_counts``) and says, by the forms and
+    the lanes a step holds, whether its routed layers stepped in the
+    kernel; the lane-steps are the host's."""
     counts, samples = moe_chunk_counts(row=row)
     state, _ = hybrid_chunk_counts(
         cfg, lane_steps=lane_steps, row=None, kernels=kernels)
-    return {**counts, **state}, samples
+    fused = routed_fused_counts(cfg, kernels=kernels, n_lanes=n_lanes)
+    return {**counts, **state, **fused}, samples
 
 
 def hybrid_prefill_counts(cfg: DecoderConfig, *, lanes, tokens, dispatches,

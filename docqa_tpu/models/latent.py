@@ -55,6 +55,7 @@ from docqa_tpu.models.routed import (  # noqa: F401  (read as latent.*)
     moe_chunk_counts,
     moe_prefill_sums,
     moe_step_sums,
+    routed_fused_counts,
     routed_layers,
     routed_mlp,
     routed_param_schema,
@@ -291,11 +292,16 @@ def latent_param_pspecs(cfg: DecoderConfig, m: str) -> Dict[str, P]:
     return specs
 
 
-def latent_chunk_counts(*, row, kernels, **_):
+def latent_chunk_counts(cfg: DecoderConfig, *, row, kernels, n_lanes, **_):
     """One fetched chunk's counters and samples: its expert-choice sums
-    where the block routes (``models/routed.moe_chunk_counts``), and how
-    its latent layers read the cache."""
-    counts, samples = ({}, {}) if row is None else moe_chunk_counts(row=row)
+    where the block routes (``models/routed.moe_chunk_counts``: with
+    whether its routed layers stepped in the kernel), and how its latent
+    layers read the cache."""
+    counts, samples = ({}, {})
+    if row is not None:
+        counts, samples = moe_chunk_counts(row=row)
+        counts.update(
+            routed_fused_counts(cfg, kernels=kernels, n_lanes=n_lanes))
     if kernels.paged:
         # over ``serve_decode_chunks``: 1.0 where every chunk's latent
         # layers read the lanes' live pages in place, absent elsewhere
@@ -329,7 +335,7 @@ def latent_serving(cfg: DecoderConfig) -> BlockServing:
             "over the paged latent cache (engines/paged.py) only"
         ),
         uses_flash=False,
-        chunk_counts=latent_chunk_counts,
+        chunk_counts=functools.partial(latent_chunk_counts, cfg),
         param_pspecs=functools.partial(latent_param_pspecs, cfg),
         pool_pspecs=lambda: {f"c{i}": P() for i in range(cfg.num_layers)},
         **sums,

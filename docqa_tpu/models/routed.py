@@ -15,12 +15,15 @@ under ``router_norm`` — times ``routed_scale``.
 The process holds the experts ``[experts_held_start, + experts_held)`` and
 computes their part of the sum (plus the shared experts, which every
 holder computes alike); the rest is left out — expert parallelism's local
-half, with no stand-in for the exchange.  That part is ONE grouped product
-over the dispatch's picks sorted by expert (:func:`held_experts_sum`,
-``ops/grouped.py``): a row passes through the experts it picked, an expert
-no row picked is not read — the same form for a prefill's rows and a
-decode step's few lanes.  A trunk hands back the expert ids it took (the
-routing record, benchmark/README.md "A block that routes").
+half, with no stand-in for the exchange.  That part is a GROUPED sum
+(:func:`held_experts_sum`, ``ops/grouped.py``): a row passes through the
+experts it picked, an expert no row picked is not read.  A prefill's many
+rows are sorted by the expert they picked and run as three grouped
+products; a decode step's few lanes (their picks fit one row tile) run
+as ONE kernel a layer that reads the touched experts straight from HBM —
+the same arithmetic at the same rounding points.  A trunk hands back the
+expert ids it took (the routing record, benchmark/README.md "A block that
+routes").
 """
 
 from __future__ import annotations
@@ -32,7 +35,12 @@ import jax
 import jax.numpy as jnp
 
 from docqa_tpu.config import DecoderConfig
-from docqa_tpu.ops.grouped import grouped_matmul, row_tile
+from docqa_tpu.ops.grouped import (
+    grouped_matmul,
+    grouped_swiglu_step,
+    row_tile,
+    step_form,
+)
 from docqa_tpu.ops.scopes import scope
 from docqa_tpu.utils import round_up
 
@@ -135,19 +143,36 @@ def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
     [n, hidden].  ``taken`` [n, k] expert ids as the router numbers them,
     ``gates`` [n, k] float32.
 
-    A GROUPED product (``ops/grouped.py``): the ``n . k`` picks are
-    sorted by the held expert they fell on, ``y``'s rows gathered in that
-    order, and each run of rows multiplied by its own expert's slice of
-    the stacked weights.  A pick on an expert held elsewhere (or ``-1``)
+    A dispatch of MANY rows (a prefill's): a GROUPED product
+    (``ops/grouped.grouped_matmul``) — the ``n . k`` picks are sorted by
+    the held expert they fell on, ``y``'s rows gathered in that order,
+    and each run of rows multiplied by its own expert's slice of the
+    stacked weights.  A pick on an expert held elsewhere (or ``-1``)
     sorts behind every group and is never computed; an expert no row took
-    is never read.  So a prefill of hundreds of rows streams each held
-    expert once under the few rows that took it, and a decode step of a
-    few lanes reads only the experts its tokens touched: its time follows
-    the routing.  ``use_flash``: the product's form
-    (``models/decoder.kernel_forms``'s ``grouped``), nothing else."""
+    is never read: each held expert streams once under the few rows that
+    took it.
+
+    A decode STEP (``ops/grouped.step_form``: the picks fit one row tile)
+    under ``use_flash``: ONE kernel (``ops/grouped.grouped_swiglu_step``)
+    whose stacked operands stay in HBM — every row through each TOUCHED
+    expert, weighed by the gate it gave it; no sort, gather or un-sort,
+    and no expert the step's tokens did not touch crosses the memory's
+    wires (the three ``gmm`` calls' operands were copied whole into fast
+    memory by the compiler, PERF.md section 5, PR 53).  The arithmetic of
+    a pick is the same at the same rounding points; a row's picks are
+    summed in the order the experts are held.  Its time follows the
+    routing.
+
+    ``use_flash``: the product's form (``models/decoder.kernel_forms``'s
+    ``grouped``), nothing else."""
     lo, held = experts_held(cfg)
     n, k = taken.shape
     dtype = y.dtype
+    weights = [
+        params[f"l{i}_e_{name}"].astype(dtype)
+        for name in ("gate", "up", "down")]
+    if use_flash and step_form(n, k):
+        return grouped_swiglu_step(y, taken - lo, gates, *weights)
     local = (taken - lo).reshape(-1)  # [n . k]
     local = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(local, stable=True)  # sorted pick -> flat pick
@@ -160,12 +185,10 @@ def held_experts_sum(y, taken, gates, params: Params, cfg: DecoderConfig,
     rows = y[pick // k]
     product = functools.partial(
         grouped_matmul, group_sizes=sizes, use_flash=use_flash)
-    g = product(rows, params[f"l{i}_e_gate"].astype(dtype), out_dtype=dtype)
-    u = product(rows, params[f"l{i}_e_up"].astype(dtype), out_dtype=dtype)
+    g = product(rows, weights[0], out_dtype=dtype)
+    u = product(rows, weights[1], out_dtype=dtype)
     act = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
-    out = product(
-        act, params[f"l{i}_e_down"].astype(dtype), out_dtype=jnp.float32
-    )
+    out = product(act, weights[2], out_dtype=jnp.float32)
     # un-sort and sum a row's k picks in one pass over the product: a
     # pick held elsewhere points at a row past the groups, which holds
     # whatever was there — never a product
@@ -284,3 +307,13 @@ def moe_chunk_counts(*, row, **_):
     return sums, samples
 
 
+def routed_fused_counts(cfg: DecoderConfig, *, kernels, n_lanes) -> dict:
+    """``serve_routed_fused_chunks`` of one fetched chunk, by the rule
+    :func:`held_experts_sum` goes by (``kernels``: the forms the decode
+    program was built with; ``n_lanes``: the rows of its step): over
+    ``serve_decode_chunks`` it reads 1.0 where every chunk's routed
+    layers stepped in the one kernel that reads the touched experts from
+    HBM, and is absent elsewhere."""
+    if kernels.grouped and step_form(n_lanes, cfg.experts_per_token):
+        return {"serve_routed_fused_chunks": 1}
+    return {}

@@ -19,7 +19,9 @@ class KernelForms(NamedTuple):
     paged: bool  # the decode attention reads a lane's live pages in place
     sparse_paged: bool  # a sparse layer's decode reads its blocks as pages
     scan: bool  # a state-space layer's prefill scan keeps ``h`` on the chip
-    grouped: bool  # a routed layer's products are ``megablox.gmm``
+    # a routed layer's products are Pallas: ``megablox.gmm`` over a
+    # prefill's rows, one step kernel a layer over a decode step's lanes
+    grouped: bool
     ragged: bool  # the cold packed prefill attends in the flash kernel
     # a retention layer's decode step is one pass over the owned entries,
     # in place
@@ -59,7 +61,8 @@ class BlockServing:
     of one dispatch (``seg`` < 0: padding).
 
     What the host counts, pure arithmetic (the batcher increments):
-    ``chunk_counts(lane_steps=, row=, kernels=)`` of a fetched chunk ->
+    ``chunk_counts(lane_steps=, row=, kernels=, n_lanes=)`` of a fetched
+    chunk (``n_lanes``: the rows a decode step holds, the slots) ->
     ({counter: amount}, {histogram: sample}); ``prefill_counts(lanes=,
     tokens=, dispatches=, kernels=)`` of an admission round; ``kv_rows_read
     (lens, kernels=, block_size=, table_rows=) -> (rows read per cache

@@ -761,9 +761,10 @@ TOY128 = dataclasses.replace(TOY, kv_lora_rank=128)
 
 @pytest.fixture
 def latent_kernels_interpreted(monkeypatch):
-    """``paged_latent_flash_decode`` and the grouped product as the forwards
-    call them under ``use_flash``, interpreted: the one thing a CPU cannot
-    take from them."""
+    """``paged_latent_flash_decode`` and the grouped products (a prefill's
+    ``gmm``, a decode step's kernel) as the forwards call them under
+    ``use_flash``, interpreted: the one thing a CPU cannot take from
+    them."""
     import importlib
 
     from docqa_tpu.models import routed
@@ -777,6 +778,9 @@ def latent_kernels_interpreted(monkeypatch):
     monkeypatch.setattr(
         routed, "grouped_matmul",
         lambda *a, **kw: grouped.grouped_matmul(*a, **kw, interpret=True))
+    monkeypatch.setattr(
+        routed, "grouped_swiglu_step",
+        lambda *a: grouped.grouped_swiglu_step(*a, interpret=True))
 
 
 @pytest.mark.parametrize("s", [1, 3], ids=["a-step", "a-verify-step"])
@@ -844,6 +848,7 @@ def test_the_batcher_counts_the_chunks_that_read_live_pages_in_place(
 
     params = PACKAGE.weights.make_decoder_params(TOY128, 2)
     names = ("serve_decode_chunks", "serve_latent_paged_chunks",
+             "serve_routed_fused_chunks",
              "serve_decode_kv_rows_read", "serve_decode_kv_rows_live",
              "serve_moe_picks")
     prompts = [[5 + (7 * i + j) % 500 for j in range(20 + 9 * i)]
@@ -869,6 +874,10 @@ def test_the_batcher_counts_the_chunks_that_read_live_pages_in_place(
         chunks = gained[flash]["serve_decode_chunks"]
         assert chunks > 0
         assert gained[flash]["serve_latent_paged_chunks"] == (
+            chunks if flash else 0)
+        # four slots x four picks: a step to the routed layers' kernel
+        # (ISSUE 54), interpreted here like the attention's
+        assert gained[flash]["serve_routed_fused_chunks"] == (
             chunks if flash else 0)
     # the gather's tokens, until a near-tie of seeded random weights tips
     # (the first token is the prefill's, the same program in both)

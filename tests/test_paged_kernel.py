@@ -674,3 +674,131 @@ class TestCompilesForTheChip:
         # the pool is the pool it returns
         pool_bytes = compiled.memory_analysis().alias_size_in_bytes
         assert pool_bytes >= entries * (head_dim + 1) * kv_heads * features * 4
+
+    @pytest.mark.parametrize("config, lanes, stacked", [
+        ("trinity-mini-ep8-bf16", 4, ("16,2048,1024", "16,1024,2048")),
+        ("deepseek-v2-ep4-bf16", 8, ("40,5120,1536", "40,1536,5120")),
+    ], ids=["trinity", "deepseek-v2"])
+    def test_the_routed_step_inside_the_decode_chunk_it_serves(
+            self, topo, config, lanes, stacked):
+        """ISSUE 54: ``ops/grouped.grouped_swiglu_step`` inside 16 steps of
+        ``paged_decode_forward`` at the published widths, the forms an
+        engine that saw a TPU hands it: one call of the kernel a routed
+        layer and no ``gmm``; the stacked expert tensors reach it as the
+        program's parameters lie — no quarter of one is sliced or copied
+        into fast memory ahead of the call, which is what the compiler did
+        to 51 of the 90 operands of Trinity's ``gmm`` calls (PERF.md
+        section 5, PR 53: ``slice-done`` of ``bf16[4,1024,2048]``)."""
+        import os
+        import re
+        import sys
+
+        from jax.experimental.compilation_cache import compilation_cache
+        from jax.sharding import SingleDeviceSharding
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        for p in (root, os.path.join(root, "benchmark")):
+            if p not in sys.path:
+                sys.path.insert(0, p)
+        from harness import arch
+        from harness.child import program_overrides
+
+        from docqa_tpu.config import load_config
+        from docqa_tpu.engines import paged
+        from docqa_tpu.models.decoder import init_decoder_params, kernel_forms
+        from docqa_tpu.models.routed import routed_layers
+
+        conf = arch.load_cell_config(os.path.join(
+            root, "benchmark", "configs", config + ".json"))
+        served = load_config(env={}, overrides=program_overrides(conf))
+        cfg = served.decoder
+        assert served.generate.max_concurrent == lanes
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def described(tree, dtype=None):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, dtype if dtype and x.ndim > 1 else x.dtype,
+                sharding=one_chip), tree)
+
+        n_blocks = lanes * -(-cfg.max_seq_len // BS)
+        params = described(jax.eval_shape(
+            lambda: init_decoder_params(jax.random.key(0), cfg)),
+            jnp.bfloat16)  # as the benchmark's package draws them
+        pools = described(jax.eval_shape(
+            lambda: paged.init_paged_pools(cfg, n_blocks, BS)))
+        tables = described(jnp.zeros((lanes, n_blocks // lanes), jnp.int32))
+        lane = described(jnp.zeros((lanes,), jnp.int32))
+        forms = kernel_forms(cfg, on_tpu=True, mesh=None, block_size=BS)
+        assert forms.grouped
+
+        def program(weights, held, table, tok, lengths):
+            def step(t, carry):
+                held, tok, lengths = carry
+                logits, held, _record = paged.paged_decode_forward(
+                    weights, cfg, held, table, tok[:, None], lengths,
+                    block_size=BS, rope_len=cfg.max_seq_len, kernels=forms)
+                return (held, jnp.argmax(logits[:, 0], -1).astype(
+                    jnp.int32), lengths + 1)
+
+            return jax.lax.fori_loop(
+                0, served.generate.decode_chunk, step, (held, tok, lengths))
+
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            hlo = jax.jit(program, donate_argnums=(1,)).lower(
+                params, pools, tables, lane, lane).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+        calls = [line for line in hlo.splitlines()
+                 if " custom-call(" in line and "_swiglu_step_kernel" in line]
+        assert len(calls) == routed_layers(cfg) > 0
+        assert not re.search(r"%gmm[.\d]* = ", hlo)  # the sorted form's call
+        # a stacked expert tensor, whole or a leading part of it, is
+        # produced by NOTHING in the program: parameters and the loop's
+        # own tuple plumbing alone carry it to the kernel
+        part = re.compile(
+            r" = bf16\[\d+,(%s)\]" % "|".join(
+                s.split(",", 1)[1] for s in stacked))
+        carried = (" parameter(", " get-tuple-element(", " bitcast(")
+        assert not [
+            line for line in hlo.splitlines()
+            if part.search(line) and not any(w in line for w in carried)]
+        assert any(f"bf16[{s}]" in hlo for s in stacked)
+
+    @pytest.mark.parametrize("held, h, f, lanes, k, tile_mb", [
+        (16, 2048, 1024, 4, 8, 4.0), (40, 5120, 1536, 8, 6, 3.75),
+    ], ids=["trinity", "deepseek-v2"])
+    def test_the_routed_step_alone_at_the_published_widths(
+            self, topo, held, h, f, lanes, k, tile_mb):
+        """The op alone, lowered by Mosaic for the described chip: the
+        three operands' tiles, twice buffered, are what the call asks of
+        fast memory (an expert of Trinity whole, a quarter of one of
+        DeepSeek-V2's), and the stacked tensors enter it as they lie."""
+        from jax.sharding import SingleDeviceSharding
+
+        from docqa_tpu.ops import grouped
+
+        one_chip = SingleDeviceSharding(topo.devices[0])
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        tf = grouped._tile(f, max(grouped._WEIGHT_TILE // h, 128))
+        assert f % tf == 0 and h * tf * 2 / 2 ** 20 == tile_mb
+        bf16 = jnp.bfloat16
+        compiled = jax.jit(grouped.grouped_swiglu_step).lower(
+            arg((lanes, h), bf16), arg((lanes, k), jnp.int32),
+            arg((lanes, k), jnp.float32), arg((held, h, f), bf16),
+            arg((held, h, f), bf16), arg((held, f, h), bf16)).compile()
+        hlo = compiled.as_text()
+        assert "_swiglu_step_kernel" in hlo and " sort(" not in hlo
+        assert not [
+            line for line in hlo.splitlines()
+            if (f" = bf16[{held},{h},{f}]" in line
+                or f" = bf16[{held},{f},{h}]" in line)
+            and " parameter(" not in line]
+        # nothing of the experts' size beside the arguments
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
