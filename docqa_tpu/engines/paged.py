@@ -182,7 +182,9 @@ class BlockAllocator:
     """Free-list allocator over a fixed pool of KV blocks, REFCOUNTED
     for copy-on-write prefix sharing (docqa-prefix).
 
-    LIFO reuse keeps recently-freed blocks hot; allocation is
+    LIFO reuse keeps recently-freed blocks hot — a released table's
+    blocks are handed out again in the order the table held them, so runs
+    of ascending ids survive a release —; allocation is
     all-or-nothing so a half-admitted request never strands blocks.
     A block's refcount is 1 when privately owned and +1 per table the
     prefix cache mapped it into; ``release`` decrements and only a
@@ -327,6 +329,7 @@ class BlockAllocator:
             self._touch_pool_locked(now)
             earned = 0.0
             bases = table.acc_base
+            freed = []
             for i, b in enumerate(table.blocks):
                 self._settle_locked(b, now)
                 if i < len(bases):
@@ -335,8 +338,13 @@ class BlockAllocator:
                 if self._refs[b] == 0:
                     # a SHARED release is not a free: the block returns
                     # only when its last referencing table lets go
-                    self._free.append(b)
+                    freed.append(b)
                     self._in_use -= 1
+            # last block first, so that the stack hands the table's blocks
+            # out again in the order it held them: a run of ascending ids
+            # stays one (the paged kernel fetches a compute block whose
+            # ids are a run with one copy, ``ops/attention.paged_block_runs``)
+            self._free.extend(reversed(freed))
             table.billed_block_seconds = earned
             self._billed += earned
             table.blocks = []

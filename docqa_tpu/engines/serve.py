@@ -121,7 +121,11 @@ from docqa_tpu.engines.paged import (
 from docqa_tpu.engines.generate import accept_drafts, draft_tokens
 from docqa_tpu.engines.qos import QoSPolicy, request_class
 from docqa_tpu.engines.spine import spine_run, spine_submit
-from docqa_tpu.ops.attention import RAGGED_ALIGN
+from docqa_tpu.ops.attention import (
+    RAGGED_ALIGN,
+    paged_block_pages,
+    paged_block_runs,
+)
 from docqa_tpu.ops.sampling import sample
 from docqa_tpu.ops.scopes import scope
 from docqa_tpu.resilience import faults
@@ -575,6 +579,14 @@ def partition_prefill_round(
                 budget = _pick_budget(budgets, rows[i])
                 groups.append([flag, budget, budget - rows[i], [i]])
     return [(flag, budget, members) for flag, budget, _free, members in groups]
+
+
+class _ChunkSnap(list):
+    """The slot -> request map at a decode chunk's DISPATCH, and beside it
+    ``runs``: what :meth:`ContinuousBatcher._table_runs` read of the block
+    tables then (a lane retired before the fetch has none left)."""
+
+    runs: Tuple = ()
 
 
 class ContinuousBatcher:
@@ -2867,7 +2879,9 @@ class ContinuousBatcher:
         ``snap`` is the slot→request mapping at the chunk's DISPATCH time;
         tokens are delivered only to a slot whose occupant is still that
         request (a slot retired while the chunk was in flight decoded one
-        discarded chunk — wasted compute, never misdelivered tokens).
+        discarded chunk — wasted compute, never misdelivered tokens); the
+        worker's is a :class:`_ChunkSnap`, which also keeps the tables'
+        runs as they stood then.
         Returns False when the fetch failed: the device state chained from
         this chunk is poisoned and ``_fail_active`` has reset it."""
         t_fetch0 = _now()
@@ -2961,7 +2975,7 @@ class ContinuousBatcher:
             # written; a lane retired in flight advanced nothing it kept
             lanes.append((
                 req.kv_prompt + max(len(req.tokens) - 1, 0),
-                int(valid_h[slot].sum()),
+                int(valid_h[slot].sum()), slot,
             ))
             if self._slot_req[slot] is not req:
                 continue
@@ -3035,16 +3049,24 @@ class ContinuousBatcher:
         # what the block kind counts of a chunk, from its sums row and
         # from the positions the lanes advanced, which the host holds
         counts, samples = self._block.chunk_counts(
-            lane_steps=sum(adv for _, adv in lanes), row=sums_row,
+            lane_steps=sum(adv for _, adv, _ in lanes), row=sums_row,
             kernels=self._kernels, n_lanes=self.n_slots,
         )
         for name, amount in counts.items():
             DEFAULT_REGISTRY.counter(name).inc(amount)
         for name, sample_ in samples.items():
             DEFAULT_REGISTRY.histogram(name).observe(sample_)
-        rows_read, rows_live = self._chunk_kv_rows(lanes)
+        lens = self._chunk_lens(lanes)
+        rows_read, rows_live = self._chunk_kv_rows(lens)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_read").inc(rows_read)
         DEFAULT_REGISTRY.counter("serve_decode_kv_rows_live").inc(rows_live)
+        for name, amount in zip(
+                ("serve_decode_kv_copies", "serve_decode_kv_blocks",
+                 "serve_decode_kv_run_blocks"),
+                self._chunk_kv_copies(
+                    lens, [slot for _, _, slot in lanes], rows_read,
+                    getattr(snap, "runs", None) or self._table_runs())):
+            DEFAULT_REGISTRY.counter(name).inc(amount)
         if not n_held:
             # every lane the chunk advanced had retired by the time it
             # was fetched (the overshoot chunk dispatched ahead): device
@@ -3056,52 +3078,111 @@ class ContinuousBatcher:
             self._deact_pending.extend(deactivate)
         return True
 
-    def _chunk_kv_rows(self, lanes) -> Tuple[int, int]:
-        """(KV rows fetched, KV rows live) PER CACHE ENTRY — a layer's; a
-        (step, layer)'s under the looped trunk, whose every pass reads its
-        own entry over the same lengths, so the two stay a ratio — over
-        one chunk's steps, as the decode program is built — host
-        arithmetic on what ``_process_chunk`` holds, no device fetch.
-
-        ``lanes``: per lane of the chunk's snapshot, (KV length at
-        dispatch, positions it advanced).  A step attends ``width`` new
-        positions (1, or ``speculative_k``) past what the lane has
-        advanced so far; a lane that stopped keeps attending where it
-        stood.  Live rows are those lengths summed over lanes and steps.
-        Fetched rows: under the paged kernel the live PAGES of each lane
-        (``ceil(len / block_size)`` of them); under the gather reference
-        every slot's whole block table, live or not, each step.  The
-        plain program runs ``chunk`` steps; the speculative one loops on
-        the device until every lane has emitted a chunk, which the host
-        does not see — counted as its fewest possible forwards."""
+    def _chunk_lens(self, lanes) -> np.ndarray:
+        """[lanes, steps]: the KV length each step of a fetched chunk
+        attended.  ``lanes``: per lane of the chunk's snapshot, (KV length
+        at dispatch, positions it advanced, slot).  A step attends
+        ``width`` new positions (1, or ``speculative_k``) past what the
+        lane has advanced so far; a lane that stopped keeps attending
+        where it stood.  The plain program runs ``chunk`` steps; the
+        speculative one loops on the device until every lane has emitted
+        a chunk, which the host does not see — counted as its fewest
+        possible forwards."""
         width = max(self.spec_k, 1)
         steps = self.chunk
         if self.spec_k:
-            most = max((adv for _, adv in lanes), default=0)
+            most = max((adv for _, adv, _ in lanes), default=0)
             steps = max(-(-most // width), 1)
-        read = live = 0
-        for length, adv in lanes:
-            lens = length + width + np.minimum(
-                np.arange(steps) * width, adv
-            )
-            live += int(lens.sum())
-            read += int((-(-lens // self.block_size)).sum()) * self.block_size
+        grown = np.arange(steps) * width
+        return np.array(
+            [length + width + np.minimum(grown, adv)
+             for length, adv, _ in lanes], np.int64,
+        ).reshape(len(lanes), steps)
+
+    def _chunk_kv_rows(self, lens) -> Tuple[int, int]:
+        """(KV rows fetched, KV rows live) PER CACHE ENTRY — a layer's; a
+        (step, layer)'s under the looped trunk, whose every pass reads its
+        own entry over the same lengths, so the two stay a ratio — over
+        one chunk's steps (``lens``: :meth:`_chunk_lens`), as the decode
+        program is built — host arithmetic on what ``_process_chunk``
+        holds, no device fetch.  Live rows are those lengths summed over
+        lanes and steps.  Fetched rows: under the paged kernel the live
+        PAGES of each lane (``ceil(len / block_size)`` of them); under the
+        gather reference every slot's whole block table, live or not,
+        each step."""
+        live = int(lens.sum())
+        read = int((-(-lens // self.block_size)).sum()) * self.block_size
         if self._block.kv_rows_read is not None:
             # a kind that reads by a rule of its own (a layer that selects,
             # layer kinds that read differently): what it counts beside
             read, counts = self._block.kv_rows_read(
-                np.stack([
-                    length + 1 + np.minimum(np.arange(steps), adv)
-                    for length, adv in lanes
-                ]) if lanes else np.zeros((0, steps), np.int64),
-                kernels=self._kernels, block_size=self.block_size,
+                lens, kernels=self._kernels, block_size=self.block_size,
                 table_rows=self.n_slots * self.seq_capacity,
             )
             for name, amount in counts.items():
                 DEFAULT_REGISTRY.counter(name).inc(amount)
         elif not self._kernels.paged:
-            read = steps * self.n_slots * self.seq_capacity
+            read = lens.shape[1] * self.n_slots * self.seq_capacity
         return read, live
+
+    def _table_runs(self):
+        """Per layer kind of the block's ``paged_reads``: [slots, compute
+        blocks] bool, the blocks of each slot's table — its ring for a
+        windowed kind — whose page ids are one ascending run, as the paged
+        kernel's wrapper marks them (``ops/attention.paged_block_runs``,
+        on the host's copy of the tables).  Nothing where no such kernel
+        runs."""
+        if not self._kernels.paged:
+            return ()
+        ppb = paged_block_pages(self.blocks_per_seq, self.block_size)
+        runs = []
+        for _, window in self._block.paged_reads:
+            tables, n_blocks = self._block_rows, self.n_blocks
+            if window is not None and self._ring_pages:
+                tables = self._ring_pages_np[
+                    :, np.arange(self.blocks_per_seq) % self._ring_pages]
+                n_blocks = self._ring_pages_np.size
+            runs.append(paged_block_runs(tables, ppb, n_blocks))
+        return tuple(runs)
+
+    def _chunk_kv_copies(self, lens, slots, rows_read,
+                         runs) -> Tuple[int, int, int]:
+        """(page copies, compute blocks, blocks fetched as ONE run) the
+        paged kernel's program issued over one chunk's steps PER CACHE
+        ENTRY and per pool (K; V's are as many again) — host arithmetic as
+        :meth:`_chunk_kv_rows` is (``lens``: :meth:`_chunk_lens`, ``slots``
+        its lanes' slots), on the kernel's own decisions: a lane's step
+        walks the compute blocks from the first its window still sees to
+        its last; a block whose pages are all live and whose ids are one
+        run (``runs``: :meth:`_table_runs` at dispatch) is one copy, any
+        other a copy a live page.  Where the host cannot see the tables
+        the kernel walks (``paged_reads`` empty: a layer that selects, the
+        latent block's own kernel) every page read is a copy and no block
+        is counted; nothing on the gather reference."""
+        if not (self._kernels.paged or self._kernels.sparse_paged):
+            return 0, 0, 0
+        if not runs or not slots:
+            return rows_read // self.block_size, 0, 0
+        ppb = paged_block_pages(self.blocks_per_seq, self.block_size)
+        pages = -(-lens // self.block_size)
+        lane = np.asarray(slots)[:, None]
+        copies = blocks = run_blocks = layers = 0
+        for (n_layers, window), kind_runs in zip(
+                self._block.paged_reads, runs):
+            # run blocks before block j of each slot's table
+            before = np.pad(np.cumsum(kind_runs, axis=1), ((0, 0), (1, 0)))
+            first = np.zeros_like(pages)
+            if window is not None:
+                first = np.maximum(lens - window, 0) // (
+                    ppb * self.block_size)
+            full = np.maximum(pages // ppb, first)
+            as_runs = int((before[lane, full] - before[lane, first]).sum())
+            copies += n_layers * (
+                int((pages - first * ppb).sum()) - as_runs * (ppb - 1))
+            blocks += n_layers * int((-(-pages // ppb) - first).sum())
+            run_blocks += n_layers * as_runs
+            layers += n_layers
+        return copies // layers, blocks // layers, run_blocks // layers
 
     def _blocks_for_admission(self, req: "_Request") -> int:
         """FRESH blocks an admission would allocate for ``req`` (prompt
@@ -3737,7 +3818,8 @@ class ContinuousBatcher:
                 # retires it in _finalize_admissions below (EOS, budget
                 # < 2) is inactive on the device, and the guard in
                 # _process_chunk drops any slot whose occupant changed.
-                snap = list(self._slot_req)
+                snap = _ChunkSnap(self._slot_req)
+                snap.runs = self._table_runs()
                 try:
                     with span("serve_decode_dispatch", DEFAULT_REGISTRY):
                         packed = spine_run("serve_decode", _decode_on_lane)

@@ -992,6 +992,12 @@ def hybrid_serving(cfg: DecoderConfig) -> BlockServing:
             {ATTENTION, WINDOW, MAMBA} & kinds
             or selects and paged_kernel_supported(
                 cfg.dtype, cfg.num_kv_heads, cfg.head_dim)),
+        # a layer that selects reads through tables the program builds
+        paged_reads=() if selects else tuple(
+            (len(layers_of(cfg, kind)), window)
+            for kind, window in ((ATTENTION, None),
+                                 (WINDOW, cfg.sliding_window))
+            if kind in kinds),
         chunk_counts=chunk_counts,
         prefill_counts=functools.partial(hybrid_prefill_counts, cfg),
         prefill_attrs=functools.partial(hybrid_prefill_attrs, cfg),

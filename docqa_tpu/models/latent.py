@@ -335,6 +335,7 @@ def latent_serving(cfg: DecoderConfig) -> BlockServing:
             "over the paged latent cache (engines/paged.py) only"
         ),
         uses_flash=False,
+        paged_reads=(),  # its kernel takes a page an operand: no copy by hand
         chunk_counts=functools.partial(latent_chunk_counts, cfg),
         param_pspecs=functools.partial(latent_param_pspecs, cfg),
         pool_pspecs=lambda: {f"c{i}": P() for i in range(cfg.num_layers)},

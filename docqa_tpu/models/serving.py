@@ -68,7 +68,13 @@ class BlockServing:
     (lens, kernels=, block_size=, table_rows=) -> (rows read per cache
     entry, {counter: amount})`` where the kind reads KV by a rule of its
     own (a layer that selects; layer kinds that read differently, which
-    count per kind); ``span_attrs`` on a request's ``serve_prefill``
+    count per kind); ``paged_reads``: ((layers, sliding window or None),
+    ...) of the layer kinds whose decode step reads a lane's OWN table
+    through ``ops/attention.paged_flash_decode`` — a windowed kind its
+    ring — which is where the host can say how many copies the kernel
+    issued (``serve_decode_kv_copies``); none for a kind whose tables the
+    program builds (a layer that selects) or that reads through a kernel
+    of its own (the latent block); ``span_attrs`` on a request's ``serve_prefill``
     and ``serve_decode_chunk`` spans, ``prefill_attrs(n_ids, n_lanes)`` on
     the first; ``occupancy`` beside the block-pool gauges; ``lane_state``:
     a lane keeps state beside its rows, found through the slot map in the
@@ -96,6 +102,7 @@ class BlockServing:
     chunk_counts: Callable = _no_counts
     prefill_counts: Callable = _nothing
     kv_rows_read: Optional[Callable] = None
+    paged_reads: Tuple[Tuple[int, Optional[int]], ...] = ((1, None),)
     span_attrs: Mapping = dataclasses.field(default_factory=dict)
     prefill_attrs: Callable = _nothing
     occupancy: Mapping = dataclasses.field(default_factory=dict)

@@ -950,14 +950,41 @@ def paged_kernel_supported(pool_dtype, kv_heads, head_dim, mesh=None) -> bool:
     )
 
 
+def paged_block_pages(table_width: int, block_size: int,
+                      block_rows: Optional[int] = None) -> int:
+    """Pages one compute block of the paged kernel covers, for tables of
+    ``table_width`` entries."""
+    block_rows = PAGED_BLOCK_ROWS if block_rows is None else block_rows
+    return max(1, min(block_rows // block_size, table_width))
+
+
+def paged_block_runs(tables, ppb: int, n_blocks: int):
+    """[S, ceil(NB / ppb)] bool: the compute blocks of each table row whose
+    ``ppb`` ids are ONE ascending run of allocated pages, which the paged
+    kernel fetches with one copy a pool where every page is live (a block
+    that holds a hole is none).  For a device array in the program and for
+    the host's copy of the tables (the batcher's counters) alike."""
+    xp = jnp if isinstance(tables, jax.Array) else np
+    s_, nb = tables.shape
+    pad = -nb % ppb
+    if pad:
+        tables = xp.concatenate(
+            [tables, xp.full((s_, pad), n_blocks, tables.dtype)], axis=1)
+    blocks = tables.reshape(s_, -1, ppb)
+    first = blocks[:, :, :1]
+    return xp.all(blocks == first + xp.arange(ppb), axis=-1) & (
+        first[:, :, 0] + ppb <= n_blocks)
+
+
 def _paged_decode_kernel(
     # scalar prefetch
-    tables_ref,  # [S, NB] int32 block ids; entries >= n_blocks are holes
+    tables_ref,  # [S, NB] int32 block ids (the wrapper clamps holes in bounds)
     lengths_ref,  # [S] int32 valid (and allocated) kv length
     qoff_ref,  # [S] int32 absolute position of q row 0
+    runs_ref,  # [S, ceil(NB / ppb)] int32: the compute block's ids are one run
     # blocks
     q_ref,  # [1, hkv, s * groups, d]: row r of a kv head is q position r // groups
-    k_hbm,  # [n_blocks, block_size * hkv, d] — the layer's pool, left in HBM
+    k_hbm,  # [n_blocks * block_size * hkv, d] — the layer's pool, left in HBM
     v_hbm,
     o_ref,  # [1, hkv, s * groups, d]
     # scratch
@@ -981,14 +1008,23 @@ def _paged_decode_kernel(
     of a group share the head's rows.  While a block is computed the next
     one is in flight: the lane's next block, or, from its last block, the
     NEXT lane's first (the buffers and semaphores outlive a grid step).
-    A lane of length 0 issues no DMA and writes zeros."""
+    A lane of length 0 issues no DMA and writes zeros.
+
+    What a block costs the scalar core follows what the kernel can read
+    of it.  A FULL block (all its pages live) whose ids are one ascending
+    run is two copies, K and V; any other full block a copy a page, the
+    starts unrolled with no test between them; either way ONE wait a pool
+    for the buffer's whole extent.  A lane's last, partial block starts
+    and waits page by page under the length test."""
     lane = pl.program_id(0)
     n_lanes = pl.num_programs(0)
-    n_blocks, page_rows, d = k_hbm.shape
-    hkv = page_rows // block_size
-    ppb = k_buf.shape[1] // page_rows
-    gq = q_ref.shape[2]
+    hkv, gq, d = q_ref.shape[1:]
+    page_rows = block_size * hkv  # a page: consecutive rows of the flat pool
+    buf_rows = k_buf.shape[1]
+    ppb = buf_rows // page_rows
     rows = ppb * block_size  # kv positions a compute block covers
+    # a pool smaller than one compute block holds no run of one
+    runs_fit = k_hbm.shape[0] >= buf_rows
 
     def blocks_of(ln):
         """(first block, end block, live pages) of lane ``ln``."""
@@ -998,30 +1034,70 @@ def _paged_decode_kernel(
             first = jnp.maximum(qoff_ref[ln] - sliding_window + 1, 0) // rows
         return first, pl.cdiv(n_pages, ppb), n_pages
 
+    def copies(page, n_rows, buf, dst_row):
+        """(K copy, V copy) of ``n_rows`` pool rows from the first row of
+        ``page`` on, into buffer ``buf``."""
+        src = pl.ds(pl.multiple_of(page * page_rows, page_rows), n_rows)
+        dst = pl.ds(dst_row, n_rows)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[src], k_buf.at[buf, dst], sems.at[0, buf]),
+            pltpu.make_async_copy(
+                v_hbm.at[src], v_buf.at[buf, dst], sems.at[1, buf]),
+        )
+
+    def page_copies(ln, j, buf, i):
+        return copies(
+            tables_ref[ln, j * ppb + i], page_rows, buf, i * page_rows)
+
     def for_live_pages(ln, j, buf, act):
         n_pages = blocks_of(ln)[2]
 
         def one(i, carry):
             @pl.when(j * ppb + i < n_pages)
             def _():
-                # a hole can only lie past the length (the wrapper clamps
-                # it to the allocated pages); the clamp keeps the DMA in
-                # bounds even if a caller breaks that
-                page = jnp.minimum(tables_ref[ln, j * ppb + i], n_blocks - 1)
-                dst = pl.ds(i * page_rows, page_rows)
-                act(pltpu.make_async_copy(
-                    k_hbm.at[page], k_buf.at[buf, dst], sems.at[0, buf]
-                ))
-                act(pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[buf, dst], sems.at[1, buf]
-                ))
+                for copy in page_copies(ln, j, buf, i):
+                    act(copy)
 
             return carry
 
         jax.lax.fori_loop(0, ppb, one, 0)
 
+    def is_full(ln, j):
+        return (j + 1) * ppb <= blocks_of(ln)[2]
+
     def fetch(ln, j, buf):
-        for_live_pages(ln, j, buf, lambda copy: copy.start())
+        def as_a_run():
+            for copy in copies(tables_ref[ln, j * ppb], buf_rows, buf, 0):
+                copy.start()
+
+        def page_by_page():
+            for i in range(ppb):
+                for copy in page_copies(ln, j, buf, i):
+                    copy.start()
+
+        def whole_block():
+            if runs_fit:
+                jax.lax.cond(runs_ref[ln, j] != 0, as_a_run, page_by_page)
+            else:
+                page_by_page()
+
+        jax.lax.cond(
+            is_full(ln, j), whole_block,
+            lambda: for_live_pages(ln, j, buf, lambda copy: copy.start()))
+
+    def wait(ln, j, buf):
+        def whole_block():
+            # a DMA semaphore counts bytes: the block's copies, however
+            # many they were, add up to the buffer's extent — a descriptor
+            # of that extent, never started, waits for them all at once
+            for pool, sem in ((k_buf, 0), (v_buf, 1)):
+                pltpu.make_async_copy(
+                    pool.at[buf], pool.at[buf], sems.at[sem, buf]).wait()
+
+        jax.lax.cond(
+            is_full(ln, j), whole_block,
+            lambda: for_live_pages(ln, j, buf, lambda copy: copy.wait()))
 
     def fetch_first_of_next_lane(buf):
         @pl.when(lane + 1 < n_lanes)
@@ -1089,7 +1165,7 @@ def _paged_decode_kernel(
         def _():
             fetch_first_of_next_lane(1 - buf)
 
-        for_live_pages(lane, j, buf, lambda copy: copy.wait())
+        wait(lane, j, buf)
 
         kv_pos = j * rows + kv_cols
         mask = (kv_pos < kv_len) & (kv_pos <= q_abs)
@@ -1159,8 +1235,9 @@ def paged_flash_decode(
     q            [S, s, q_heads, d] (s = 1 plain step, K spec verify —
                  read from the shape)
     k/v_pool     [P, kv_heads, d] flat block pool; stays in HBM, viewed as
-                 [n_blocks, block_size * kv_heads, d] (a free reshape: a
-                 page is ``block_size`` consecutive rows)
+                 [P * kv_heads, d] (a free reshape: a page is ``block_size
+                 * kv_heads`` consecutive rows of it, a run of pages one
+                 slice)
     block_tables [S, NB] int32; entries >= n_blocks are holes
     lengths      [S] valid kv length per lane AFTER this step
     q_offset     [S] absolute position of q[:, 0] (default lengths - s)
@@ -1169,7 +1246,9 @@ def paged_flash_decode(
     ``ceil(len / block_size)`` pages only, ``PAGED_BLOCK_ROWS`` kv
     positions at a time, double-buffered with one DMA per page that
     carries every kv head of its 16 tokens (the way
-    ``jax.experimental.pallas.ops.tpu.ragged_paged_attention`` does);
+    ``jax.experimental.pallas.ops.tpu.ragged_paged_attention`` does) —
+    or ONE per compute block whose pages are all live and whose ids are
+    an ascending run (:func:`paged_block_runs`), and one wait a block;
     the arithmetic is ``_flash_kernel``'s (f32 scores, running max,
     denominator and accumulator; bf16 in and out; the same masks).  A
     length is clamped to the lane's ALLOCATED pages, so a hole is never
@@ -1241,8 +1320,7 @@ def _paged_attend_local(q, k_pool, v_pool, block_tables, lengths, q_offset,
     n_rows, hkv, _ = k_pool.shape
     groups = hq // hkv
     n_blocks = n_rows // block_size
-    nb = block_tables.shape[1]
-    ppb = max(1, min(block_rows // block_size, nb))
+    ppb = paged_block_pages(block_tables.shape[1], block_size, block_rows)
     buf_rows = ppb * block_size * hkv
 
     # never past the allocated pages (holes fill a table row's tail)
@@ -1250,11 +1328,13 @@ def _paged_attend_local(q, k_pool, v_pool, block_tables, lengths, q_offset,
         (block_tables < n_blocks).astype(jnp.int32), axis=1
     ) * block_size
     kv_len = jnp.minimum(lengths.astype(jnp.int32), allocated)
+    tables = block_tables.astype(jnp.int32)
 
     # [S, s, hq, d] -> [S, hkv, s * groups, d]; a reshape when s == 1
     qr = q.reshape(S, s, hkv, groups, d).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(S, hkv, s * groups, d)
-    page_shape = (n_blocks, block_size * hkv, d)
+    # a page is ``block_size * hkv`` consecutive rows of the flat pool
+    flat = (n_rows * hkv, d)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -1269,7 +1349,7 @@ def _paged_attend_local(q, k_pool, v_pool, block_tables, lengths, q_offset,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(S,),
             in_specs=[
                 lane_block,
@@ -1290,8 +1370,13 @@ def _paged_attend_local(q, k_pool, v_pool, block_tables, lengths, q_offset,
         out_shape=jax.ShapeDtypeStruct((S, hkv, s * groups, d), q.dtype),
         interpret=interpret, name="_paged_decode_kernel",
     )(
-        block_tables.astype(jnp.int32), kv_len, q_offset.astype(jnp.int32),
-        qr, k_pool.reshape(page_shape), v_pool.reshape(page_shape),
+        # a hole can only lie past the length (clamped to the allocated
+        # pages above); the clamp keeps a DMA in bounds even if a caller
+        # breaks that
+        jnp.minimum(tables, n_blocks - 1), kv_len,
+        q_offset.astype(jnp.int32),
+        paged_block_runs(tables, ppb, n_blocks).astype(jnp.int32),
+        qr, k_pool.reshape(flat), v_pool.reshape(flat),
     )
     out = out.reshape(S, hkv, s, groups, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(S, s, hq, d)

@@ -72,6 +72,24 @@ class TestBlockAllocator:
         t3.release()
         assert a.blocks_in_use == 0 and a.n_free == 6
 
+    def test_a_released_tables_runs_are_handed_out_as_runs(self):
+        """The stack hands a released table's blocks out again in the
+        order the table held them: ascending ids stay ascending round
+        after round, which is what lets the paged kernel fetch a compute
+        block with one copy (``ops/attention.paged_block_runs``)."""
+        a = BlockAllocator(n_blocks=12, block_size=2)
+        t1, t2 = a.new_table(), a.new_table()
+        t1.ensure(10)
+        t2.ensure(6)
+        assert t1.blocks == [0, 1, 2, 3, 4] and t2.blocks == [5, 6, 7]
+        for _ in range(3):  # rounds of release and re-admission
+            t1.release()
+            t2.release()
+            t1, t2 = a.new_table(), a.new_table()
+            t2.ensure(6)  # the most recently freed first (LIFO)
+            t1.ensure(10)
+            assert t2.blocks == [5, 6, 7] and t1.blocks == [0, 1, 2, 3, 4]
+
     def test_release_idempotent_double_free_raises(self):
         a = BlockAllocator(n_blocks=4, block_size=2)
         t = a.new_table()

@@ -4,6 +4,15 @@ live pages out of the pool in place.
 * in interpret mode against the plain reference (``gather_paged_kv`` +
   ``attention_reference``): lengths around a page boundary, scattered and
   shared pages, holes, both step widths, a sliding window, GQA and MHA;
+  — and (PR 57) what the kernel decides a compute block: full or partial,
+  its ids a run or not.  ``interpret=True`` is the generic interpreter: a
+  DMA there copies at its start, a wait never blocks, and a DMA semaphore
+  is an int16 count of ELEMENTS clamped at 32,767 — so these cases hold
+  the data path of each branch (which copies land where), not the byte
+  accounting of the ONE wait a full block makes for all its copies; that
+  is held on the chip (a wrong count hangs the call or reads a stale
+  buffer: the five served geometries agree with the gather there, PERF
+  §6 "PR 57");
 * structurally: the traced decode attention holds no value with the block
   tables' span of rows (no gather, no transpose of the span);
 * the counters ``serve_decode_kv_rows_read`` / ``_live`` as defined, on
@@ -26,33 +35,43 @@ NB = 32  # table entries per lane: a full table is 512 positions
 TOL = 2.0 ** -6  # two bf16 roundings of an O(1) output, as kernel_selfcheck
 
 
-def _pool(rng, hkv, d, dtype):
+def _pool(rng, hkv, d, dtype, n_pages=N_PAGES):
     return jnp.asarray(
-        rng.standard_normal((N_PAGES * BS, hkv, d), np.float32), dtype
+        rng.standard_normal((n_pages * BS, hkv, d), np.float32), dtype
     )
 
 
-def _tables(rng, lengths, shared_pages=0):
+def _tables(rng, lengths, shared_pages=0, nb=NB, n_pages=N_PAGES,
+            order="scattered"):
     """Scattered, out-of-order pages per lane; the tail of every row is
-    holes (``>= N_PAGES``, a different sentinel per entry so a dereference
+    holes (``>= n_pages``, a different sentinel per entry so a dereference
     could not go unnoticed).  ``shared_pages``: lanes 0 and 1 share their
-    first pages (a prefix hit)."""
-    tables = N_PAGES + rng.integers(0, 1000, (len(lengths), NB)).astype(np.int32)
-    pages = iter(rng.permutation(N_PAGES))
+    first pages (a prefix hit).  ``order``: "runs" hands each lane
+    ascending consecutive ids, lane after lane (what a fresh pool does);
+    "broken" the same with two ids swapped inside every fourth page of a
+    lane (a run broken inside a compute block)."""
+    tables = n_pages + rng.integers(0, 1000, (len(lengths), nb)).astype(np.int32)
+    pages = iter(
+        rng.permutation(n_pages) if order == "scattered" else range(n_pages))
     for lane, n in enumerate(lengths):
         for i in range(-(-int(n) // BS)):
             tables[lane, i] = next(pages)
+        if order == "broken":
+            for i in range(1, -(-int(n) // BS) - 1, 4):
+                tables[lane, [i, i + 1]] = tables[lane, [i + 1, i]]
     if shared_pages:
         tables[1, :shared_pages] = tables[0, :shared_pages]
     return tables
 
 
 def _compare(lengths, *, s=1, hq=32, hkv=8, d=128, window=None,
-             dtype=jnp.bfloat16, shared_pages=0, seed=0):
+             dtype=jnp.bfloat16, shared_pages=0, seed=0, nb=NB,
+             n_pages=N_PAGES, order="scattered"):
     rng = np.random.default_rng(seed)
     lengths = np.asarray(lengths, np.int32)
-    k_pool, v_pool = _pool(rng, hkv, d, dtype), _pool(rng, hkv, d, dtype)
-    tables = jnp.asarray(_tables(rng, lengths, shared_pages))
+    k_pool, v_pool = (_pool(rng, hkv, d, dtype, n_pages) for _ in range(2))
+    tables = jnp.asarray(
+        _tables(rng, lengths, shared_pages, nb, n_pages, order))
     q = jnp.asarray(
         rng.standard_normal((len(lengths), s, hq, d), np.float32), dtype
     )
@@ -69,6 +88,10 @@ def _compare(lengths, *, s=1, hq=32, hkv=8, d=128, window=None,
     return err.max(axis=(1, 2, 3)), np.asarray(got, np.float32)
 
 
+# tables of 72 entries (1,152 positions) over 160 pages: a lane of several
+# compute blocks at either block size; few heads keep the pools small
+LONG = dict(nb=72, n_pages=160, hq=8, hkv=4)
+
 @pytest.mark.parametrize("case", [
     dict(lengths=[0, 1, 15, 16]),
     dict(lengths=[17, 389, 0, NB * BS]),  # a full table
@@ -81,9 +104,43 @@ def _compare(lengths, *, s=1, hq=32, hkv=8, d=128, window=None,
     dict(lengths=[16, 389, 0, 100], hq=8, hkv=2, s=4),
     dict(lengths=[15, 389, 17, 0], hq=4, hkv=1),  # one kv head a device
     dict(lengths=[15, 389, 17, 0], hq=8, hkv=4, dtype=jnp.float32),
+    # what the kernel decides a block (PR 57): full or partial, a run or
+    # not.  Lanes of exactly 1, 2, 8 and 16 blocks of 64 rows = a partial,
+    # exactly 1, a partial and exactly 2 blocks of 512: every length ends
+    # on a block's edge
+    dict(lengths=[64, 512, 128, 1024], order="runs", **LONG),
+    dict(lengths=[64, 512, 128, 1024], **LONG),  # the same, no block a run
+    dict(lengths=[1120, 1000, 8], order="runs", **LONG),  # 17 1/2 blocks of 64
+    dict(lengths=[8960, 1120], order="runs", nb=576, n_pages=640, hq=4,
+         hkv=2),  # 17 1/2 blocks of 512, as Trinity's lanes
+    dict(lengths=[1120, 1000, 300], order="broken", **LONG),
+    # the first block the window sees is the lane's last, partial one
+    dict(lengths=[70, 300, 1000, 130], window=6, order="runs", **LONG),
+    dict(lengths=[1100, 300, 1152], window=200, order="runs", **LONG),
+    # the cross-lane prefetch over a lane that has nothing to fetch
+    dict(lengths=[1000, 0, 1000], order="runs", **LONG),
+    dict(lengths=[1000, 0, 0, 1030], **LONG),
+    # a hole inside a block that has live pages (31 of its 32)
+    dict(lengths=[490, 1000, 33], order="runs", **LONG),
+    dict(lengths=[200, 1030], order="runs", nb=72, n_pages=160, hq=2, hkv=1),
+    dict(lengths=[200, 1030], order="runs", nb=72, n_pages=160, hq=4, hkv=2),
+    dict(lengths=[200, 1030], order="broken", nb=72, n_pages=160, hq=8,
+         hkv=4),
+    dict(lengths=[200, 1030], order="runs", nb=72, n_pages=160, hq=16, hkv=8),
+    dict(lengths=[200, 1030], order="runs", nb=72, n_pages=160, hq=16,
+         hkv=16),
+    dict(lengths=[1030, 640, 4, 512], s=4, order="runs", **LONG),
+    dict(lengths=[1030, 640, 4, 512], s=4, order="broken", **LONG),
+    dict(lengths=[200, 1030], order="runs", dtype=jnp.float32, **LONG),
 ], ids=["page_edge", "full_table", "verify", "verify_full", "window",
         "verify_window", "shared_pages", "mha", "gqa_pair", "one_kv_head",
-        "float32_pool"])
+        "float32_pool", "whole_blocks_runs", "whole_blocks_scattered",
+        "blocks_17_and_a_half", "blocks_17_and_a_half_of_512", "broken_runs",
+        "window_first_block_partial", "window_runs", "empty_lane_between",
+        "empty_lanes_scattered", "hole_past_the_length", "runs_1_kv_head",
+        "runs_2_kv_heads", "broken_4_kv_heads", "runs_8_kv_heads",
+        "runs_16_kv_heads", "verify_runs", "verify_broken",
+        "float32_pool_runs"])
 @pytest.mark.parametrize("block_rows", [64, 512], ids=["rows64", "rows512"])
 def test_kernel_matches_the_gather_reference(case, block_rows, monkeypatch):
     # 64 rows: a lane of 389 streams through 7 compute blocks, the window
@@ -231,7 +288,7 @@ class TestKvRowCounters:
         b.stop()
 
     @staticmethod
-    def _fake_chunk(batcher, in_place):
+    def _fake_chunk(batcher, in_place, runs=None):
         """One chunk fetched for two lanes that have since retired: 37
         and 16 positions of K/V at dispatch; the first emitted 4 tokens,
         the second stopped after 1."""
@@ -247,6 +304,11 @@ class TestKvRowCounters:
             req.tokens.extend([5] * delivered)
             lanes.append(req)
         snap = lanes + [None] * (batcher.n_slots - 2)
+        if runs is not None:  # as the worker snapshots a dispatch
+            from docqa_tpu.engines.serve import _ChunkSnap
+
+            snap = _ChunkSnap(snap)
+            snap.runs = runs
         chunk, k = batcher.chunk, batcher.spec_k
         if k:  # [tokens | emitted count | active]
             packed = np.zeros((batcher.n_slots, chunk + 2 * k + 2), np.int32)
@@ -288,6 +350,77 @@ class TestKvRowCounters:
         # a CPU run serves the gather reference
         assert batcher._kernels.paged is False
         assert batcher.engine.use_flash is False
+
+    COPIES = ("serve_decode_kv_copies", "serve_decode_kv_blocks",
+              "serve_decode_kv_run_blocks")
+
+    def _copies_of_the_fake_chunk(self, batcher, in_place, monkeypatch):
+        from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
+
+        # compute blocks of 2 pages, so that the fake lanes hold whole ones
+        monkeypatch.setattr(A, "PAGED_BLOCK_ROWS", 32)
+        batcher._block_rows[0, :3] = (4, 5, 9)  # pages 0-1: a run
+        batcher._block_rows[1, :2] = (7, 6)  # descending: none
+        before = [DEFAULT_REGISTRY.counter(n).value for n in self.COPIES]
+        read, _live = self._fake_chunk(batcher, in_place)
+        return read, [DEFAULT_REGISTRY.counter(n).value - b
+                      for n, b in zip(self.COPIES, before)]
+
+    def test_a_block_that_is_a_run_is_one_copy(self, batcher, monkeypatch):
+        """``serve_decode_kv_copies`` per pool and cache entry: a lane's
+        step is a copy a live page, but ONE for a compute block whose
+        pages are all live and whose ids are an ascending run."""
+        read, (copies, blocks, runs) = self._copies_of_the_fake_chunk(
+            batcher, True, monkeypatch)
+        steps, _rows, pages = self._expected(batcher)
+        # lane 0: 3 pages = a full block (ids 4, 5: a run, one copy) and a
+        # partial one; lane 1: 2 pages = a full block (ids 7, 6: two copies)
+        assert (copies, blocks, runs) == (
+            steps * (2 + 2), steps * (2 + 1), steps * 1)
+        assert read == pages * 16 and copies == pages - runs
+
+    def test_under_the_gather_no_copy_is_counted(self, batcher, monkeypatch):
+        _read, counted = self._copies_of_the_fake_chunk(
+            batcher, False, monkeypatch)
+        assert counted == [0, 0, 0]
+
+    def test_runs_as_they_stood_at_dispatch(self, batcher, monkeypatch):
+        """The worker's snapshot keeps the runs it read when the chunk was
+        dispatched: tables that changed since do not count."""
+        from docqa_tpu.runtime.metrics import DEFAULT_REGISTRY
+
+        monkeypatch.setattr(A, "PAGED_BLOCK_ROWS", 32)
+        batcher._kernels = batcher._kernels._replace(paged=True)
+        batcher._block_rows[0, :3] = (4, 5, 9)
+        at_dispatch = batcher._table_runs()
+        assert at_dispatch[0][0].tolist() == [True, False, False, False]
+        batcher._block_rows[0, :3] = batcher.n_blocks  # retired since
+        assert not batcher._table_runs()[0].any()
+        before = DEFAULT_REGISTRY.counter("serve_decode_kv_run_blocks").value
+        self._fake_chunk(batcher, in_place=True, runs=at_dispatch)
+        gained = DEFAULT_REGISTRY.counter(
+            "serve_decode_kv_run_blocks").value - before
+        assert gained == self._expected(batcher)[0]
+
+
+def test_block_runs_on_the_host_as_in_the_program():
+    """``paged_block_runs``: the wrapper's mark of the compute blocks it
+    fetches with one copy, and the batcher's count of them, are one
+    function of the tables — ascending consecutive ids of allocated pages,
+    a whole block of them."""
+    n_blocks = 40
+    tables = np.array([
+        [3, 4, 5, 6, 10, 11, 12, 13, 40, 40],  # two runs, then holes
+        [6, 5, 4, 3, 0, 1, 2, 4, 8, 9],  # descending; a jump; a short tail
+        [36, 37, 38, 39, 37, 38, 39, 40, 40, 41],  # a hole ends a run
+    ], np.int32)
+    want = [[True, True, False], [False, False, False], [True, False, False]]
+    assert A.paged_block_runs(tables, 4, n_blocks).tolist() == want
+    on_device = jax.jit(
+        lambda t: A.paged_block_runs(t, 4, n_blocks))(jnp.asarray(tables))
+    assert np.asarray(on_device).tolist() == want
+    # a pool smaller than a block holds no run of one
+    assert not A.paged_block_runs(tables[:, :8], 4, 3).any()
 
 
 class TestCompilesForTheChip:
@@ -364,7 +497,8 @@ class TestCompilesForTheChip:
         # kv heads are independent: no collective, and each chip's quarter
         # of the pool is read in place
         assert "all-gather" not in hlo and "all-reduce" not in hlo
-        assert "bf16[1024,32,128]" in hlo
+        # (a chip's 16384 rows x 2 kv heads, flat: a page is 32 of them)
+        assert "bf16[32768,128]" in hlo
 
     def test_on_a_mesh_it_is_shard_mapped(self):
         """GSPMD cannot partition a Mosaic call: cross-lowered for TPU on
