@@ -20,8 +20,10 @@ decisions in which the program's set is not the reference's own.
     python3 benchmark/calibrate.py --config benchmark/configs/<name>.json \
         --seeds 1,2,3 [--retrieval-seeds 1,2,3]
 
-No server, no traffic, no timed window: weights from the seed, the
-program's paged forwards, the reference, the control.  The benchmark's own
+No server, no traffic, no timed window: weights from the seed (each of
+``--seeds`` draws a tree of its own — NOT the one draw the file's
+``weights_seed`` states for the benchmark's runs: a limit is what many
+draws read), the program's paged forwards, the reference, the control.  The benchmark's own
 runs never call this; ``tests/benchmark`` holds the same comparison at a
 size a test run can hold.  PERF.md records what this printed.
 """

@@ -17,6 +17,7 @@ import inspect
 import json
 import os
 import types
+import zlib
 
 ARCH_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "architectures"
@@ -36,13 +37,21 @@ SURFACE = {
 # ``keys.program_overrides`` has to know it
 HARNESS_KEYS = frozenset({
     "architecture", "serving", "corpus", "chips", "deployment", "assumed",
-    "correct", "check", "kv_cache_bits",
+    "correct", "check", "kv_cache_bits", "weights_seed",
 })
 
 
 class ConfigError(ValueError):
     """A configuration file the harness cannot run, with the file (where
     known) and the key in the message."""
+
+
+def stated_weights_seed(name: str) -> int:
+    """The seed a configuration NAMED ``name`` draws its decoder weights
+    from: the CRC-32 of the name, so that nobody chooses it by what it
+    reads.  A deployment serves one set of weights for months; ``--seed``
+    draws the traffic (README.md, "What the seed may draw")."""
+    return zlib.crc32(name.encode("utf-8"))
 
 
 def model_keys(conf: dict) -> dict:
@@ -97,7 +106,8 @@ def load_shapes(conf: dict):
 def load_cell_config(path: str, overlay: str = "") -> dict:
     """The configuration file, with a test's overlay (tiny widths) merged
     over it when given.  An unknown architecture, or a published key its
-    package does not map, is an error here, before anything is started."""
+    package does not map, is an error here, before anything is started; so
+    is a file whose ``weights_seed`` is not the one its name states."""
     with open(path, encoding="utf-8") as f:
         conf = json.load(f)
     if overlay:
@@ -112,4 +122,12 @@ def load_cell_config(path: str, overlay: str = "") -> dict:
         load_shapes(conf).keys.program_overrides(conf)
     except ConfigError as e:
         raise ConfigError(f"{path}: {e}") from None
+    name = os.path.basename(path).removesuffix(".json")
+    stated = stated_weights_seed(name)
+    if conf.get("weights_seed") != stated:
+        raise ConfigError(
+            f'{path}: key "weights_seed": {conf.get("weights_seed")!r} is '
+            f"not the CRC-32 of the configuration's name, {stated} for "
+            f"{name!r}"
+        )
     return conf

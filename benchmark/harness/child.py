@@ -4,7 +4,8 @@ HTTP surface, booted for a benchmark cell.
 What differs from ``scripts/start_all.py`` is set-up only, and none of it
 is on a request's path:
 
-* decoder weights are made on the device from the seed by the
+* decoder weights are made on the device from the configuration's
+  ``weights_seed`` (never from ``--seed``) by the
   ``weights.make_decoder_params`` of the configuration's architecture
   package (``arch.load``) and handed to the program's
   ``GenerateEngine(params=...)`` (the program's own default draws 7e9
@@ -309,6 +310,44 @@ def make_routes(rt, state: State, conf: dict, package, seed: int, stored,
     ]
 
 
+def seed_engines(package, conf: dict, seed: int, state: State) -> None:
+    """Put the benchmark's seeded engines in the program's place, before
+    the runtime is built.  The decoder's weights are the CONFIGURATION's:
+    one draw (``weights_seed``) for every run, as a deployment serves one
+    set of weights for months; ``--seed`` draws the encoder, the sampler
+    and, elsewhere, the corpus, the questions and ``check``'s prompts
+    (README.md, "What the seed may draw, and what it may not")."""
+    import jax
+
+    from docqa_tpu.engines import encoder as encoder_mod
+    from docqa_tpu.engines import generate as generate_mod
+
+    seed31 = seed % (2**31)
+    weights_seed31 = int(conf["weights_seed"]) % (2**31)
+
+    class SeededGenerateEngine(generate_mod.GenerateEngine):
+        """The program's engine, given the benchmark's weights."""
+
+        def __init__(self, dec_cfg, gen=None, mesh=None, params=None, **kw):
+            if params is None:
+                t = time.monotonic()
+                params = package.weights.make_decoder_params(
+                    dec_cfg, weights_seed31, mesh
+                )
+                jax.block_until_ready(params)
+                state.mark("decoder_weights", t)
+            kw.setdefault("seed", seed31)
+            super().__init__(dec_cfg, gen=gen, mesh=mesh, params=params, **kw)
+
+    class SeededEncoderEngine(encoder_mod.EncoderEngine):
+        def __init__(self, enc_cfg, mesh=None, **kw):
+            kw.setdefault("seed", seed31)
+            super().__init__(enc_cfg, mesh=mesh, **kw)
+
+    generate_mod.GenerateEngine = SeededGenerateEngine
+    encoder_mod.EncoderEngine = SeededEncoderEngine
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
@@ -351,34 +390,10 @@ def main() -> int:
     t0 = state.mark("jax_init", t0)
 
     from docqa_tpu.config import load_config
-    from docqa_tpu.engines import encoder as encoder_mod
-    from docqa_tpu.engines import generate as generate_mod
 
     cfg = load_config(env={}, overrides=program_overrides(conf))
     package = arch.load(conf)
-    seed31 = args.seed % (2**31)
-
-    class SeededGenerateEngine(generate_mod.GenerateEngine):
-        """The program's engine, given the benchmark's weights."""
-
-        def __init__(self, dec_cfg, gen=None, mesh=None, params=None, **kw):
-            if params is None:
-                t = time.monotonic()
-                params = package.weights.make_decoder_params(
-                    dec_cfg, seed31, mesh
-                )
-                jax.block_until_ready(params)
-                state.mark("decoder_weights", t)
-            kw.setdefault("seed", seed31)
-            super().__init__(dec_cfg, gen=gen, mesh=mesh, params=params, **kw)
-
-    class SeededEncoderEngine(encoder_mod.EncoderEngine):
-        def __init__(self, enc_cfg, mesh=None, **kw):
-            kw.setdefault("seed", seed31)
-            super().__init__(enc_cfg, mesh=mesh, **kw)
-
-    generate_mod.GenerateEngine = SeededGenerateEngine
-    encoder_mod.EncoderEngine = SeededEncoderEngine
+    seed_engines(package, conf, args.seed, state)
 
     from aiohttp import web
 

@@ -89,6 +89,17 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
+def split_cores(cores):
+    """(the server's cores, the load generator's) of the cores this process
+    may run on: the last two are kept for the parent — its client threads,
+    which no clinic runs on the server's own cores — and the rest go to the
+    child.  None under four cores: then nothing is pinned."""
+    cores = sorted(cores)
+    if len(cores) < 4:
+        return None
+    return cores[:-2], cores[-2:]
+
+
 class Child:
     """The server process, its whole process group, and its log."""
 
@@ -112,10 +123,21 @@ class Child:
             # exits; there is no CPU fallback to print a number from
             env["JAX_PLATFORMS"] = "tpu"
         self.log = open(self.log_path, "wb")
+        # the child inherits the cores this (still single) thread holds at
+        # its start; the parent then moves to its own two, and the client
+        # threads it starts later inherit those
+        split = (split_cores(os.sched_getaffinity(0))
+                 if hasattr(os, "sched_setaffinity") else None)
+        if split:
+            os.sched_setaffinity(0, split[0])
         self.proc = subprocess.Popen(
             cmd, cwd=ROOT, env=env, stdout=self.log, stderr=subprocess.STDOUT,
             start_new_session=True,
         )
+        if split:
+            os.sched_setaffinity(0, split[1])
+        self.affinity = ({"child": split[0], "parent": split[1]} if split
+                         else "left alone")
         self.conn = client.Connection("127.0.0.1", self.port, 120.0)
 
     def alive(self) -> bool:
@@ -435,6 +457,9 @@ def main() -> int:
         "failed": len(failed),
         "metrics": metrics,
         "device": device,
+        "seed": args.seed,
+        "weights_seed": conf["weights_seed"],
+        "affinity": child.affinity,
     }
     if traced and ctx.get("trace") and not args.rehearsal:
         device["busy_s"] = ctx["trace"]["busy_s"]

@@ -131,7 +131,9 @@ def grown(tmp_path_factory):
         "from architectures.mistral import shapes as _m\n\n\n"
         "def decode_step_min_bytes(conf, live_kv_tokens, chips):\n"
         "    return 2 * _m.decode_step_min_bytes(conf, live_kv_tokens, chips)\n")
-    dump(renamed(load(MISTRAL_FILE)),
+    # a file draws the model its OWN name states (ISSUE 58)
+    dump(dict(renamed(load(MISTRAL_FILE)),
+              weights_seed=arch.stated_weights_seed("renamed-7b-int8")),
          tree / "benchmark" / "configs" / "renamed-7b-int8.json")
     overlay = tree / "renamed_overlay.json"
     dump(renamed(load(OVERLAY)), overlay)

@@ -8,6 +8,7 @@ import shutil
 import subprocess
 import sys
 import threading
+import zlib
 
 import pytest
 
@@ -20,7 +21,7 @@ from harness import client  # noqa: E402
 
 OVERLAY = os.path.join(HERE, "data", "tiny_overlay.json")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
-               "compared"}
+               "seed", "weights_seed", "affinity", "compared"}
 
 
 def child_env():
@@ -66,6 +67,14 @@ def test_last_line_has_the_keys_the_driver_reads(rag_untraced):
     assert result["device"]["platform"] == "cpu"
     assert "compared decoder_logit_rel_err" in out
     assert "compared retrieval_score_err" in out
+    # which run it was, and which model it served: the run's seed and the
+    # one the configuration states for its weights (ISSUE 58)
+    assert result["seed"] == 4294967301
+    assert result["weights_seed"] == zlib.crc32(b"mistral-7b-int8")
+    split = result["affinity"]
+    assert split == "left alone" or (
+        len(split["parent"]) == 2 and split["child"]
+        and not set(split["parent"]) & set(split["child"]))
 
 
 def test_the_compared_numbers_are_the_last_lines_of_standard_error(rag_untraced):
